@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import optax
 
 from horovod_tpu.parallel import mesh as mesh_lib
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 from horovod_tpu.parallel.transformer import (
     ParallelGPTConfig,
     make_parallel_train_step,
@@ -48,6 +49,7 @@ def main():
                     help="> 0: Switch-MoE FFNs, experts sharded over dp "
                          "(4-D dp x sp x tp x ep)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     devs = jax.devices()
     need = args.dp * args.sp * args.tp

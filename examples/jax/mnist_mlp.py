@@ -20,6 +20,7 @@ import optax
 from flax import linen as nn
 
 import horovod_tpu as hvd
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 from jax.sharding import PartitionSpec as P
 
 
@@ -48,6 +49,7 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     n = hvd.size()
     model = MLP()

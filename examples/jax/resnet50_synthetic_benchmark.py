@@ -17,6 +17,7 @@ import optax
 
 import horovod_tpu as hvd
 from horovod_tpu.models import ResNet50
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 from jax.sharding import PartitionSpec as P
 
 
@@ -36,6 +37,7 @@ def main():
                     "realistic input path")
     args = ap.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     n = hvd.size()
     model = ResNet50(num_classes=1000, dtype=jnp.bfloat16)

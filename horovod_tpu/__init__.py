@@ -31,7 +31,6 @@ from typing import Any, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: F401
 
-from . import _compat
 from .utils import env as _env
 from .context import (  # noqa: F401
     WORLD_AXIS,
@@ -174,8 +173,7 @@ def spmd(
         # must trigger a recompile, not be silently ignored per mesh.
         cache = {}
 
-        @functools.wraps(f)
-        def wrapper(*args):
+        def compiled():
             m = mesh if mesh is not None else context().mesh
             key = (m, _env.fusion_threshold_bytes())
             mapped = cache.get(key)
@@ -187,7 +185,7 @@ def spmd(
                 # replication invariants; the vma type system can't express
                 # "gather output is replicated" without threading `reduced`
                 # annotations through every user out_spec.
-                mapped = _compat.shard_map(
+                mapped = jax.shard_map(
                     f, mesh=m, in_specs=ispec, out_specs=ospec, check_vma=False
                 )
                 if jit:
@@ -208,7 +206,16 @@ def spmd(
                         compiler_options=opts or None,
                     )
                 cache[key] = mapped
-            return mapped(*args)
+            return mapped
+
+        @functools.wraps(f)
+        def wrapper(*args):
+            return compiled()(*args)
+
+        if jit:
+            # jax.stages.Lowered of the program a call dispatches (its
+            # compiled HLO and memory analysis); nothing executes.
+            wrapper.lower = lambda *args: compiled().lower(*args)
 
         return wrapper
 

@@ -28,12 +28,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
-from jax import core as jax_core
-
-try:  # jax >= 0.4.14 keeps Literal in jax.core; be defensive across lines
-    _Literal = jax_core.Literal
-except AttributeError:  # pragma: no cover - ancient jax
-    from jax._src.core import Literal as _Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 # Cross-device communication primitives by jaxpr name. ``psum_bind`` etc.
 # never appear in jaxprs; these are the canonical post-trace names.
@@ -149,7 +144,7 @@ class WalkResult:
 
 
 def _tainted(var, taint: Set[int]) -> bool:
-    return not isinstance(var, _Literal) and id(var) in taint
+    return not isinstance(var, Literal) and id(var) in taint
 
 
 def _sub_jaxprs_generic(eqn) -> List[Any]:
@@ -158,9 +153,9 @@ def _sub_jaxprs_generic(eqn) -> List[Any]:
     for v in eqn.params.values():
         items = v if isinstance(v, (list, tuple)) else [v]
         for item in items:
-            if isinstance(item, jax_core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 subs.append(item.jaxpr)
-            elif isinstance(item, jax_core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 subs.append(item)
     return subs
 
@@ -306,7 +301,7 @@ class JaxprWalker:
         producers: Dict[int, Any] = {}
         for eqn in body.eqns:
             for v in eqn.invars:
-                if not isinstance(v, _Literal):
+                if not isinstance(v, Literal):
                     uses[id(v)] = uses.get(id(v), 0) + 1
             for ov in eqn.outvars:
                 producers[id(ov)] = eqn
@@ -317,7 +312,7 @@ class JaxprWalker:
                 prod is not None
                 and prod.primitive.name in ("add", "add_any")
                 and any(
-                    not isinstance(v, _Literal) and v is civ
+                    not isinstance(v, Literal) and v is civ
                     for v in prod.invars
                 )
                 and uses.get(id(civ), 0) == 1

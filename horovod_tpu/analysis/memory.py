@@ -67,15 +67,10 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from ..utils import env as _env
 from .jaxpr_walk import COLLECTIVE_PRIMS, aval_nbytes
-
-try:
-    _Literal = jax_core.Literal
-except AttributeError:  # pragma: no cover - ancient jax
-    from jax._src.core import Literal as _Literal
 
 # Report categories, in breakdown order. "workspace" absorbs the batch
 # slice, step counters, guard scalars and anything unclassified.
@@ -218,7 +213,7 @@ class _Linearizer:
 
     def read_bufs(self, invars) -> List[_Buf]:
         return [
-            self.buf_for(v) for v in invars if not isinstance(v, _Literal)
+            self.buf_for(v) for v in invars if not isinstance(v, Literal)
         ]
 
     def emit(self, reads: List[_Buf], writes: List[_Buf], prim: str) -> None:
@@ -275,7 +270,7 @@ class _Linearizer:
                 ops_idx = [
                     k
                     for k, v in enumerate(eqn.invars)
-                    if not isinstance(v, _Literal)
+                    if not isinstance(v, Literal)
                 ]
                 invars = list(sub.invars)
                 n = min(len(ops_idx), len(invars))
@@ -292,7 +287,7 @@ class _Linearizer:
         """Ids of the jaxpr's vars transitively needed by its outputs
         (or by collectives — effects stay live): a reverse DCE pass."""
         live = {
-            id(v) for v in jaxpr.outvars if not isinstance(v, _Literal)
+            id(v) for v in jaxpr.outvars if not isinstance(v, Literal)
         }
         for eqn in reversed(jaxpr.eqns):
             needed = any(id(ov) in live for ov in eqn.outvars) or (
@@ -302,7 +297,7 @@ class _Linearizer:
                 continue
             m = self._invar_mask(eqn)
             for k, v in enumerate(eqn.invars):
-                if isinstance(v, _Literal):
+                if isinstance(v, Literal):
                     continue
                 if m is None or m[k]:
                     live.add(id(v))
@@ -345,7 +340,7 @@ class _Linearizer:
                 stack.append((i, True))
                 m = self._invar_mask(eqns[i])
                 for k, v in enumerate(eqns[i].invars):
-                    if isinstance(v, _Literal):
+                    if isinstance(v, Literal):
                         continue
                     if m is not None and not m[k]:
                         continue
@@ -357,7 +352,7 @@ class _Linearizer:
             {
                 produced_by[id(ov)]
                 for ov in jaxpr.outvars
-                if not isinstance(ov, _Literal) and id(ov) in produced_by
+                if not isinstance(ov, Literal) and id(ov) in produced_by
             }
             | {
                 i
@@ -393,7 +388,7 @@ class _Linearizer:
             ]
         operands = self.read_bufs(used_invars)
         sub = subs[0]
-        ops = [v for v in eqn.invars if not isinstance(v, _Literal)]
+        ops = [v for v in eqn.invars if not isinstance(v, Literal)]
         invars = list(sub.invars)
         n = min(len(ops), len(invars))
         for op, iv in zip(ops[len(ops) - n :], invars[len(invars) - n :]):
@@ -403,7 +398,7 @@ class _Linearizer:
         else:
             self.walk(sub)
         out_bufs = [
-            self.buf_for(ov) if not isinstance(ov, _Literal) else None
+            self.buf_for(ov) if not isinstance(ov, Literal) else None
             for ov in sub.outvars
         ]
         for ov, b in zip(eqn.outvars, out_bufs):
@@ -424,7 +419,7 @@ class _Linearizer:
         # slices (the body aval IS the slice).
         for op, iv in zip(operands[: n_consts + n_carry],
                           sub.invars[: n_consts + n_carry]):
-            if not isinstance(op, _Literal):
+            if not isinstance(op, Literal):
                 self.bind(iv, self.buf_for(op))
         slice_bufs = []
         for iv in sub.invars[n_consts + n_carry :]:
@@ -440,7 +435,7 @@ class _Linearizer:
         self.walk(sub)
         # Final carries alias the body's last carry-out values.
         for ov, bv in zip(eqn.outvars[:n_carry], sub.outvars[:n_carry]):
-            if isinstance(bv, _Literal):
+            if isinstance(bv, Literal):
                 self._out_buf(ov, "scan")
             else:
                 self.bind(ov, self.buf_for(bv))
@@ -454,23 +449,23 @@ class _Linearizer:
         op_bufs = self.read_bufs(eqn.invars)
         carry = eqn.invars[cond_n + body_n :]
         for op, iv in zip(eqn.invars[:cond_n], cond_j.invars[:cond_n]):
-            if not isinstance(op, _Literal):
+            if not isinstance(op, Literal):
                 self.bind(iv, self.buf_for(op))
         for op, iv in zip(carry, cond_j.invars[cond_n:]):
-            if not isinstance(op, _Literal):
+            if not isinstance(op, Literal):
                 self.bind(iv, self.buf_for(op))
         for op, iv in zip(eqn.invars[cond_n : cond_n + body_n],
                           body_j.invars[:body_n]):
-            if not isinstance(op, _Literal):
+            if not isinstance(op, Literal):
                 self.bind(iv, self.buf_for(op))
         for op, iv in zip(carry, body_j.invars[body_n:]):
-            if not isinstance(op, _Literal):
+            if not isinstance(op, Literal):
                 self.bind(iv, self.buf_for(op))
         self.emit(op_bufs, [], "while")
         self.walk(cond_j)
         self.walk(body_j)
         for ov, bv in zip(eqn.outvars, body_j.outvars):
-            if isinstance(bv, _Literal):
+            if isinstance(bv, Literal):
                 self._out_buf(ov, "while")
             else:
                 self.bind(ov, self.buf_for(bv))
@@ -482,7 +477,7 @@ class _Linearizer:
         last_outs = None
         for branch in eqn.params["branches"]:
             sub = branch.jaxpr
-            ops = [v for v in eqn.invars[1:] if not isinstance(v, _Literal)]
+            ops = [v for v in eqn.invars[1:] if not isinstance(v, Literal)]
             invars = list(sub.invars)
             n = min(len(ops), len(invars))
             for op, iv in zip(ops[len(ops) - n :], invars[len(invars) - n :]):
@@ -490,7 +485,7 @@ class _Linearizer:
             self.walk(sub)
             last_outs = sub.outvars
         for ov, bv in zip(eqn.outvars, last_outs or []):
-            if isinstance(bv, _Literal):
+            if isinstance(bv, Literal):
                 self._out_buf(ov, "cond")
             else:
                 self.bind(ov, self.buf_for(bv))
@@ -502,9 +497,9 @@ def _sub_jaxprs(eqn) -> List[Any]:
     for v in eqn.params.values():
         items = v if isinstance(v, (list, tuple)) else [v]
         for item in items:
-            if isinstance(item, jax_core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 subs.append(item.jaxpr)
-            elif isinstance(item, jax_core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 subs.append(item)
     return subs
 
@@ -520,7 +515,7 @@ def _descend_to_body(jaxpr, tag_rows: List[List]):
         eqn = jaxpr.eqns[0]
         produced = {id(v) for v in eqn.outvars}
         if not all(
-            isinstance(v, _Literal) or id(v) in produced
+            isinstance(v, Literal) or id(v) in produced
             for v in jaxpr.outvars
         ):
             break
@@ -686,7 +681,7 @@ def plan_traced(
     out_bufs = [
         lin.buf_for(v)
         for v in body.outvars
-        if not isinstance(v, _Literal)
+        if not isinstance(v, Literal)
     ]
     events = lin.events
     horizon = _assign_lifetimes(lin.buffers, events, out_bufs)
@@ -706,7 +701,7 @@ def plan_traced(
     # unmatched (donation-dropped) ones still free at their last read.
     unmatched = list(out_bufs)
     unmatched_vars = [
-        v for v in body.outvars if not isinstance(v, _Literal)
+        v for v in body.outvars if not isinstance(v, Literal)
     ]
     candidates: List[Dict[str, Any]] = []
     for iv, ib, is_don, cls, label in zip(

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from jax import core as jax_core
+from jax.extend.core import Literal
 
 from .findings import LintFinding, Severity
 from .jaxpr_walk import (
@@ -20,11 +20,6 @@ from .jaxpr_walk import (
     _sub_jaxprs_generic,
     is_low_precision,
 )
-
-try:
-    _Literal = jax_core.Literal
-except AttributeError:  # pragma: no cover
-    from jax._src.core import Literal as _Literal
 
 
 # -- collective consistency ---------------------------------------------
@@ -800,7 +795,7 @@ def _descend_donation(jaxpr, donated: List[bool], labels: List[str]):
         eqn = jaxpr.eqns[0]
         produced = {id(v) for v in eqn.outvars}
         if not all(
-            isinstance(v, _Literal) or id(v) in produced
+            isinstance(v, Literal) or id(v) in produced
             for v in jaxpr.outvars
         ):
             break
@@ -853,7 +848,7 @@ def rule_donation(
     unmatched_out = [
         v
         for v in jaxpr.outvars
-        if not isinstance(v, _Literal)
+        if not isinstance(v, Literal)
     ]
     out: List[LintFinding] = []
     for iv, is_don, label in zip(jaxpr.invars, donated, labels):
@@ -891,7 +886,7 @@ def rule_donation(
         late_reads = []
         for idx in range(prod_idx + 1, len(jaxpr.eqns)):
             if any(
-                not isinstance(v, _Literal) and v is iv
+                not isinstance(v, Literal) and v is iv
                 for v in jaxpr.eqns[idx].invars
             ):
                 late_reads.append((idx, prim_at[idx]))

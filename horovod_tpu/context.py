@@ -25,6 +25,7 @@ model is SPMD over a ``jax.sharding.Mesh``:
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import threading
 from typing import Optional, Sequence, Tuple
@@ -34,8 +35,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh
 
-from . import _compat
 from .exceptions import NotInitializedError
+
+log = logging.getLogger("horovod_tpu")
 
 # Default name of the flat data-parallel world axis.
 WORLD_AXIS = "hvd"
@@ -115,7 +117,16 @@ def init(
                 cross_axes=tuple(cross_axes),
             )
         else:
-            devs = list(devices) if devices is not None else list(jax.devices())
+            if devices is not None:
+                devs = list(devices)
+            else:
+                # The world is whatever JAX found; say so, because on a
+                # host whose accelerator failed to attach that is one CPU.
+                devs = list(jax.devices())
+                log.info(
+                    "hvd.init(): found %d %s device(s), kind %r",
+                    len(devs), devs[0].platform, devs[0].device_kind,
+                )
             if hierarchical:
                 local = max(
                     1, len([d for d in devs if d.process_index == devs[0].process_index])
@@ -181,14 +192,7 @@ def enable_overlap_scheduler(platform: Optional[str] = None) -> Tuple[str, ...]:
 
     Returns the flags appended to ``XLA_FLAGS`` (empty if none).
     """
-    plat = (
-        platform
-        or os.environ.get("JAX_PLATFORMS", "")
-        # Legacy spelling, still honored by the jax 0.4.x line _compat
-        # targets; a CPU run forced through it must stay a no-op even on
-        # a host with libtpu installed.
-        or os.environ.get("JAX_PLATFORM_NAME", "")
-    )
+    plat = platform or os.environ.get("JAX_PLATFORMS", "")
     # Only the PRIMARY platform decides ("tpu,cpu" — TPU with CPU
     # fallback — must still arm the flags).
     primary = plat.split(",")[0].strip().lower()
@@ -235,6 +239,20 @@ def is_initialized() -> bool:
     return _context is not None
 
 
+def device_platform() -> str:
+    """Platform the framework's programs run on: the world mesh's devices
+    once :func:`init` has run, else JAX's default backend. The ONE place
+    kernel and compile-option choices (Pallas compiled vs. interpreted,
+    flash vs. XLA attention, the ``xla_tpu_*`` options) are derived from,
+    so a world built over ``jax.devices("cpu")`` on a TPU host — or over a
+    described TPU topology on a CPU host — picks the paths of the devices
+    it will actually run on."""
+    ctx = _context
+    if ctx is not None:
+        return ctx.mesh.devices.flat[0].platform
+    return jax.default_backend()
+
+
 def context() -> HorovodTpuContext:
     if _context is None:
         raise NotInitializedError()
@@ -262,7 +280,7 @@ def _in_trace(axes: Tuple[str, ...]) -> bool:
     """True when called under a trace with all ``axes`` bound (shard_map)."""
     try:
         for a in axes:
-            _compat.axis_size(a)
+            lax.axis_size(a)
         return True
     except NameError:
         return False
@@ -271,7 +289,7 @@ def _in_trace(axes: Tuple[str, ...]) -> bool:
 def _traced_size(axes: Tuple[str, ...]) -> int:
     size = 1
     for a in axes:
-        size *= int(_compat.axis_size(a))
+        size *= int(lax.axis_size(a))
     return size
 
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..context import device_platform
 from ..ops import actquant as _actquant
 from ..ops.fp8 import fp8_dot_general_cls
 from ..ops.remat import remat_module
@@ -51,8 +52,9 @@ class TransformerConfig:
     type_vocab_size: int = 0
     # Pallas blockwise attention (ops/pallas_kernels.py) — the memory-
     # efficient path for long sequences; dense masks fall back to XLA.
-    # None = auto: on for TPU backends, off elsewhere (CPU interpret mode
-    # is for testing, not speed).
+    # None = auto: on where the world's devices are TPUs
+    # (context.device_platform), off elsewhere; True off-TPU means the
+    # Pallas interpreter, which is for tests, not speed.
     use_flash: Optional[bool] = None
 
 
@@ -89,7 +91,7 @@ class MultiHeadAttention(nn.Module):
         if attn is None:
             use_flash = cfg.use_flash
             if use_flash is None:
-                use_flash = jax.default_backend() == "tpu"
+                use_flash = device_platform() == "tpu"
             if use_flash and mask is None and head_dim % 64 == 0:
                 from ..ops.pallas_kernels import flash_attention
 

@@ -27,13 +27,21 @@ RESNET50_TRAIN_FLOPS_PER_IMAGE = 3 * 4.11e9
 
 
 def peak_tflops(device) -> float:
-    """Nominal bf16 peak for a jax device; NaN when the generation is
-    unknown (CPU mesh, emulators) so MFU math propagates un-claimable."""
+    """Nominal bf16 peak for a jax device. NaN only for a ``cpu`` device
+    (the test mesh), so MFU math there propagates un-claimable; any other
+    platform whose ``device_kind`` is not in :data:`PEAK_TFLOPS_BF16` is
+    an error — a missing row must not turn into a silent ``mfu: null``."""
     kind = getattr(device, "device_kind", "").lower()
     for key, peak in PEAK_TFLOPS_BF16.items():
         if key in kind:
             return peak
-    return float("nan")
+    if getattr(device, "platform", "") == "cpu":
+        return float("nan")
+    raise ValueError(
+        f"no bf16 peak on record for device kind {kind!r} "
+        f"(platform {getattr(device, 'platform', '?')!r}); add it to "
+        "horovod_tpu.obs.flops.PEAK_TFLOPS_BF16 with its source"
+    )
 
 
 def transformer_flops_per_token(
@@ -49,7 +57,8 @@ def mfu(
     tokens_per_sec: float, flops_per_token: float, device=None,
     peak: Optional[float] = None,
 ) -> Optional[float]:
-    """Model FLOPs utilization, or None when the chip peak is unknown."""
+    """Model FLOPs utilization; None on a ``cpu`` device (no peak to
+    claim against — :func:`peak_tflops` raises for any other unknown)."""
     if peak is None:
         import jax
 

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import _compat
 from ..context import _axis_or_world
 from ..exceptions import HorovodTpuError
 
@@ -67,7 +66,7 @@ def adasum_allreduce(tensor, axis=None):
         raise HorovodTpuError("adasum_allreduce expects a single flat axis")
     a = axes[0]
     try:
-        n = int(_compat.axis_size(a))
+        n = int(lax.axis_size(a))
     except NameError as e:
         raise HorovodTpuError(
             f"adasum_allreduce requires mesh axis {a!r} to be bound — wrap "

@@ -47,9 +47,9 @@ from __future__ import annotations
 import math
 from typing import Dict, Optional, Sequence
 
-import jax
 import numpy as np
 
+from ..context import device_platform
 from ..utils import env as _env
 
 # TPU combiner knobs (libtpu DebugOptions extensions; names verified against
@@ -93,15 +93,16 @@ def collective_compiler_options(
         ``HVDTPU_FUSION_THRESHOLD`` (the same knob ``fused_allreduce``
         buckets by, keeping trace-time grouping and compile-time layout on
         one policy).
-      platform: ``"tpu"`` / ``"gpu"`` / ``"cpu"``; defaults to the current
-        JAX backend. CPU returns ``{}`` (no combiner flag exists).
+      platform: ``"tpu"`` / ``"gpu"`` / ``"cpu"``; defaults to
+        :func:`~horovod_tpu.context.device_platform`. CPU returns ``{}``
+        (no combiner flag exists).
     """
     t = int(
         _env.fusion_threshold_bytes() if threshold_bytes is None
         else threshold_bytes
     )
     if platform is None:
-        platform = jax.default_backend()
+        platform = device_platform()
     if platform == "tpu":
         return {name: t for name in _TPU_OPTIONS}
     if platform in ("gpu", "cuda", "rocm"):
@@ -120,7 +121,7 @@ def overlap_compiler_options(platform: Optional[str] = None) -> Dict[str, str]:
     without platform branches.
     """
     if platform is None:
-        platform = jax.default_backend()
+        platform = device_platform()
     if platform == "tpu":
         return dict(_TPU_OVERLAP_OPTIONS)
     if platform in ("gpu", "cuda", "rocm"):

@@ -29,8 +29,8 @@ memory, K/V and Q tiles streamed through VMEM like the forward, and it
 handles cotangents for both outputs (``lse`` receives real gradients
 through the ring combination weights).
 
-On CPU (tests, the driver's virtual-device validation) the kernel runs in
-Pallas interpret mode automatically.
+Where the world's devices are not TPUs (``context.device_platform``: the
+CPU test mesh) the kernels run in Pallas interpret mode automatically.
 """
 
 from __future__ import annotations
@@ -44,17 +44,12 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _SMEM = pltpu.SMEM
-    _VMEM = pltpu.VMEM
-    if not hasattr(pltpu, "CompilerParams"):
-        # Older pallas names the same dataclass TPUCompilerParams.
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SMEM = _VMEM = None
+from ..context import device_platform
+
+_SMEM = pltpu.SMEM
+_VMEM = pltpu.VMEM
 
 __all__ = [
     "flash_attention",
@@ -75,7 +70,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return device_platform() != "tpu"
 
 
 def _head_group(h: int, block_q: int, block_k: int, d: int) -> int:
@@ -330,22 +325,13 @@ def _fwd_pallas(
 
     group = _head_group(h, block_q, block_k, d)
     grid = (b, h // group, sq_pad // block_q, skv_pad // block_k)
-    smem_spec = (
-        pl.BlockSpec((1, 1), lambda bi, hi, qi, kj: (0, 0), memory_space=_SMEM)
-        if _SMEM is not None
-        else pl.BlockSpec((1, 1), lambda bi, hi, qi, kj: (0, 0))
+    smem_spec = pl.BlockSpec(
+        (1, 1), lambda bi, hi, qi, kj: (0, 0), memory_space=_SMEM
     )
 
     def vspec(shape, index_map):
-        if _VMEM is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-        return pl.BlockSpec(shape, index_map)
+        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
 
-    if pltpu is None:  # pragma: no cover - pltpu ships with jax
-        raise RuntimeError(
-            "flash_attention needs jax.experimental.pallas.tpu for scratch "
-            "allocation; use dot_product_attention instead"
-        )
     scratch = [
         _VMEM((group, block_q, d), jnp.float32),
         _VMEM((group, block_q, 1), jnp.float32),
@@ -659,13 +645,11 @@ def _bwd_pallas(
 
     smem_spec = pl.BlockSpec(
         (1, 1), lambda *_: (0, 0),
-        **({"memory_space": _SMEM} if _SMEM is not None else {}),
+        memory_space=_SMEM,
     )
 
     def vspec(shape, index_map):
-        if _VMEM is not None:
-            return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-        return pl.BlockSpec(shape, index_map)
+        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
 
     group = _head_group(h, block_q, block_k, d)
     common_params = dict(
@@ -1062,7 +1046,7 @@ _ADAM_TILE_ROWS = 512  # rows/program: 7 buffers x 512x128 fp32 ≈ 1.8 MB VMEM
 
 
 def _fused_adamw_kernel(
-    count_ref, p_ref, m_ref, v_ref, g_ref, u_ref, mo_ref, vo_ref, *,
+    bc_ref, p_ref, m_ref, v_ref, g_ref, u_ref, mo_ref, vo_ref, *,
     lr: float, b1: float, b2: float, eps: float, eps_root: float,
     weight_decay: float,
 ):
@@ -1073,14 +1057,15 @@ def _fused_adamw_kernel(
     the ``-lr`` scale), so ``fused_update=True`` is the same trajectory
     as the unfused reference up to the fp32-vs-storage-dtype rounding.
     Zero-padded tail rows are fixed points: every term is 0 there.
+    ``bc_ref`` (SMEM) carries the two bias corrections ``1 - b**count``:
+    Mosaic has no scalar ``powf``, so the wrapper computes them.
     """
-    c = (count_ref[0, 0] + 1).astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
     p = p_ref[...].astype(jnp.float32)
     m = (1.0 - b1) * g + b1 * m_ref[...].astype(jnp.float32)
     v = (1.0 - b2) * (g * g) + b2 * v_ref[...].astype(jnp.float32)
-    mhat = m / (1.0 - b1 ** c)
-    vhat = v / (1.0 - b2 ** c)
+    mhat = m / bc_ref[0, 0]
+    vhat = v / bc_ref[0, 1]
     u = mhat / (jnp.sqrt(vhat + eps_root) + eps)
     if weight_decay:
         u = u + weight_decay * p
@@ -1118,11 +1103,9 @@ def fused_adamw_update_pallas(
             x = jnp.pad(x, (0, n_pad - n))
         return x.reshape(rows_pad, _ADAM_LANES)
 
-    count = jnp.asarray(count, jnp.int32).reshape(1, 1)
-    smem_spec = pl.BlockSpec(
-        (1, 1), lambda i: (0, 0),
-        **({"memory_space": _SMEM} if _SMEM is not None else {}),
-    )
+    c = (jnp.asarray(count, jnp.int32) + 1).astype(jnp.float32)
+    bias_corrections = jnp.stack([1.0 - b1 ** c, 1.0 - b2 ** c]).reshape(1, 2)
+    smem_spec = pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=_SMEM)
     tile = pl.BlockSpec((r, _ADAM_LANES), lambda i: (i, 0))
     u, nm, nv = pl.pallas_call(
         functools.partial(
@@ -1138,7 +1121,7 @@ def fused_adamw_update_pallas(
             jax.ShapeDtypeStruct((rows_pad, _ADAM_LANES), v.dtype),
         ],
         interpret=interpret,
-    )(count, prep(p), prep(m), prep(v), prep(g))
+    )(bias_corrections, prep(p), prep(m), prep(v), prep(g))
     return (
         u.reshape(-1)[:n],
         nm.reshape(-1)[:n],
@@ -1191,12 +1174,6 @@ def int8_matmul_pallas(
     ``scales`` has shape ``[N]`` — one scale per output channel, the
     layout :func:`horovod_tpu.ops.quantization.quantize_weight` emits.
     """
-    if pltpu is None:  # pragma: no cover - pltpu ships with jax
-        raise RuntimeError(
-            "int8_matmul_pallas needs jax.experimental.pallas.tpu for "
-            "scratch allocation; use ops.quantization.int8_weight_matmul "
-            "(impl='jax') instead"
-        )
     if interpret is None:
         interpret = _use_interpret()
     if out_dtype is None:
@@ -1300,12 +1277,6 @@ def fp8_matmul_pallas(
     delayed scales.  Zero padding of ragged edges is exact: fp8 zero
     upcasts to fp32 zero.
     """
-    if pltpu is None:  # pragma: no cover - pltpu ships with jax
-        raise RuntimeError(
-            "fp8_matmul_pallas needs jax.experimental.pallas.tpu for "
-            "scratch allocation; use ops.quantization.fp8_matmul "
-            "(impl='jax') instead"
-        )
     if interpret is None:
         interpret = _use_interpret()
     mm, kk = x_q.shape
@@ -1331,7 +1302,7 @@ def fp8_matmul_pallas(
     scale = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     smem_spec = pl.BlockSpec(
         (1, 1), lambda mi, ni, ki: (0, 0),
-        **({"memory_space": _SMEM} if _SMEM is not None else {}),
+        memory_space=_SMEM,
     )
     out = pl.pallas_call(
         _fp8_matmul_kernel,

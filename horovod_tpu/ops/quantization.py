@@ -36,13 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..context import device_platform
 from ..utils import env as _env
 
 __all__ = [
     "QuantSpec",
     "INT8",
     "FP8",
-    "supports_fp8",
     "quant_spec",
     "quantize_blockwise",
     "dequantize_blockwise",
@@ -93,20 +93,10 @@ FP8 = QuantSpec(
 )
 
 
-def supports_fp8() -> bool:
-    """True when this jax build ships the fp8 dtypes (float8_e4m3fn)."""
-    return hasattr(jnp, "float8_e4m3fn")
-
-
 def quant_spec(name: str) -> QuantSpec:
     if name == "int8":
         return INT8
     if name == "fp8":
-        if not supports_fp8():
-            raise RuntimeError(
-                "fp8 wire format requested but this jax build has no "
-                "float8_e4m3fn dtype; use int8"
-            )
         return FP8
     raise ValueError(f"unknown quantization {name!r}; use int8|fp8")
 
@@ -164,7 +154,7 @@ def _use_pallas(spec: QuantSpec, block: int) -> bool:
     return (
         spec.integer
         and block % 128 == 0
-        and jax.default_backend() == "tpu"
+        and device_platform() == "tpu"
     )
 
 
@@ -324,7 +314,7 @@ def int8_weight_matmul(
         )
     x2 = x.reshape(-1, k)
     use_pallas = (
-        impl == "pallas" if impl else jax.default_backend() == "tpu"
+        impl == "pallas" if impl else device_platform() == "tpu"
     )
     if use_pallas:
         from .pallas_kernels import int8_matmul_pallas
@@ -463,7 +453,7 @@ def fp8_matmul(
             f"fp8_matmul shapes disagree: x {x_q.shape} vs w {w_q.shape}"
         )
     use_pallas = (
-        impl == "pallas" if impl else jax.default_backend() == "tpu"
+        impl == "pallas" if impl else device_platform() == "tpu"
     )
     if use_pallas:
         from .pallas_kernels import fp8_matmul_pallas
@@ -513,7 +503,7 @@ def dequantize_blockwise(
     use_pallas = (
         impl == "pallas"
         if impl
-        else (spec_int and block % 128 == 0 and jax.default_backend() == "tpu")
+        else (spec_int and block % 128 == 0 and device_platform() == "tpu")
     )
     if use_pallas:
         from .pallas_kernels import dequantize_blockwise_pallas

@@ -33,7 +33,7 @@ import numpy as np
 import optax
 
 from .context import _axis_or_world as _norm_axes, _in_trace, _traced_size
-from .context import size as _world_size
+from .context import device_platform, size as _world_size
 from .obs import registry as _obs
 from .exceptions import HorovodTpuError
 from .ops.adasum import adasum_allreduce_tree
@@ -169,7 +169,7 @@ def fused_adamw_update(
     new_v)``. ``impl`` forces ``"jax"``/``"pallas"`` (default: Pallas on
     TPU, the twin elsewhere — the quantize_blockwise dispatch rule)."""
     use_pallas = (
-        impl == "pallas" if impl else jax.default_backend() == "tpu"
+        impl == "pallas" if impl else device_platform() == "tpu"
     )
     if use_pallas:
         from .ops.pallas_kernels import fused_adamw_update_pallas

@@ -20,7 +20,6 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
-from .. import _compat
 from ..context import context as _get_context, enable_overlap_scheduler
 from ..obs import registry as _obs
 from ..optimizer import (
@@ -442,7 +441,9 @@ def make_train_step(
     ``HVDTPU_HBM_BUDGET_GB`` when declared, ``donation-missed-reuse``
     flags aliasable-but-undonated buffers. ``step.trace(state, batch)``
     returns the ClosedJaxpr so sweep callers can share one trace
-    between lint and memplan.
+    between lint and memplan; ``step.lower(state, batch)`` returns the
+    ``jax.stages.Lowered`` of the jitted program the step dispatches
+    (its compiled HLO and memory analysis; nothing executes).
 
     **Low-precision compute** (:mod:`horovod_tpu.ops.fp8` /
     :mod:`horovod_tpu.ops.actquant`): ``compute_dtype='fp8'`` (default
@@ -878,7 +879,7 @@ def make_train_step(
             },
         )
 
-    def _finish(step_fn, mapped_for):
+    def _finish(step_fn, mapped_for, jitted_for):
         # Always wrapped: the wrapper itself checks enablement per call,
         # so obs.enable()/disable() after the step is built take effect.
         fn = step_fn
@@ -1031,6 +1032,15 @@ def make_train_step(
         wrapped.certify = lambda state, batch, jaxpr=None: _certify(
             state, batch, mapped_for, jaxpr=jaxpr
         )
+        # The jax.stages.Lowered of the exact jitted program this step
+        # dispatches (same donation, same compiler options):
+        # ``.compile().as_text()`` is its HLO, ``.memory_analysis()`` its
+        # device memory. Arrays or ShapeDtypeStructs; nothing executes.
+        def _lower(state, batch):
+            state = _seeded_for_trace(state)
+            return jitted_for(state).lower(state, batch)
+
+        wrapped.lower = _lower
         wrapped.preflight = _preflight
         wrapped._cert_latch = cert_latch
         wrapped._mapped_for = mapped_for
@@ -1048,18 +1058,16 @@ def make_train_step(
     )
     if not needs_state_specs:
         out_specs = (P(), P(), P()) if has_aux else (P(), P())
-        mapped = _compat.shard_map(
+        mapped = jax.shard_map(
             _step, mesh=m, in_specs=(P(), bspec), out_specs=out_specs,
             check_vma=False,
         )
-        return _finish(
-            jax.jit(
-                mapped,
-                donate_argnums=(0,) if donate else (),
-                compiler_options=copts,
-            ),
-            lambda state: mapped,
+        jitted = jax.jit(
+            mapped,
+            donate_argnums=(0,) if donate else (),
+            compiler_options=copts,
         )
+        return _finish(jitted, lambda state: mapped, lambda state: jitted)
 
     # Structure-dependent path: the opt-state specs depend on the
     # state's structure (which flat buckets the params pack into), so
@@ -1079,7 +1087,7 @@ def make_train_step(
             P(),  # guard scalars (empty subtree when unguarded)
         )
         out_specs = (sspec, P(), P()) if has_aux else (sspec, P())
-        return _compat.shard_map(
+        return jax.shard_map(
             _step,
             mesh=m,
             in_specs=(sspec, bspec),
@@ -1087,7 +1095,7 @@ def make_train_step(
             check_vma=False,
         )
 
-    def step_fn(state: TrainState, batch):
+    def _sharded_jitted(state: TrainState):
         key = jax.tree.structure(state)
         fn = cache.get(key)
         if fn is None:
@@ -1097,9 +1105,12 @@ def make_train_step(
                 compiler_options=copts,
             )
             cache[key] = fn
-        return fn(state, batch)
+        return fn
 
-    return _finish(step_fn, _sharded_mapped)
+    def step_fn(state: TrainState, batch):
+        return _sharded_jitted(state)(state, batch)
+
+    return _finish(step_fn, _sharded_mapped, _sharded_jitted)
 
 
 def init_state(params, wrapped_optimizer, extra=None, guard=None) -> TrainState:

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .. import _compat
 
 
 def top1_dispatch(gate_logits, capacity: int):
@@ -110,7 +109,7 @@ def switch_moe_stacked(
         ``[e_local, ...]`` (the ``ep``-sharded shard of ``[E_total, ...]``).
     Returns: ``([T, D] output, aux_loss)``.
     """
-    n = int(_compat.axis_size(axis))
+    n = int(lax.axis_size(axis))
     t, d = x.shape
     e_total = gate_kernel.shape[-1]
     if e_total % n:

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .. import _compat
 from ..context import _traced_size
 from ..ops.collectives import Average, ReduceOp, Sum
 
@@ -32,7 +31,7 @@ def hierarchical_allreduce(
     moves ``1/local_size`` of the bytes. Works on any shape (internally
     flattened and padded to a multiple of the local axis size).
     """
-    nl = int(_compat.axis_size(local_axis))
+    nl = int(lax.axis_size(local_axis))
     world = _traced_size((local_axis, cross_axis))
     shape, dtype = x.shape, x.dtype
     flat = jnp.ravel(x)

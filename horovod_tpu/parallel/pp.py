@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import _compat
 
 
 def pipeline(
@@ -45,7 +44,7 @@ def pipeline(
     callers typically read them on the last stage or rely on the returned
     value being correct ring-wide via the final collect permute).
     """
-    n = int(_compat.axis_size(axis))
+    n = int(lax.axis_size(axis))
     r = lax.axis_index(axis)
     m = microbatches.shape[0]
     x_shape = microbatches.shape[1:]

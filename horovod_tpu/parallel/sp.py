@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .. import _compat
 
 
 def _online_update(o, m, l, scores, v, scale):
@@ -64,7 +63,7 @@ def ring_attention(q, k, v, *, axis: str, causal: bool = False,
             q, k, v, axis=axis, causal=causal, block_q=block_q,
             block_k=block_k,
         )
-    n = int(_compat.axis_size(axis))
+    n = int(lax.axis_size(axis))
     r = lax.axis_index(axis)
     b, s, h, d = q.shape
     scale = 1.0 / np.sqrt(d)
@@ -105,7 +104,7 @@ def _ring_attention_flash(q, k, v, *, axis: str, causal: bool,
     """Ring attention with the Pallas flash kernel as the per-hop block."""
     from ..ops.pallas_kernels import combine_blocks, flash_attention_with_lse
 
-    n = int(_compat.axis_size(axis))
+    n = int(lax.axis_size(axis))
     r = lax.axis_index(axis)
     b, s, h, d = q.shape
     # Lane-aligned head dims ride the packed kernel layout: [B,S,H,D] ↔
@@ -161,7 +160,7 @@ def ulysses_attention(q, k, v, *, axis: str, causal: bool = False,
     divisible by the axis size. Built on the same primitive as the
     reference's ``hvd.alltoall``.
     """
-    n = int(_compat.axis_size(axis))
+    n = int(lax.axis_size(axis))
     b, s, h, d = q.shape
     if h % n:
         raise ValueError(f"heads {h} not divisible by sp axis size {n}")

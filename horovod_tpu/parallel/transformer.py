@@ -42,7 +42,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import _compat
 from ..ops import remat as _remat
 from ..ops.fusion import fused_allreduce
 from ..ops.collectives import Sum
@@ -264,7 +263,7 @@ def loss_fn(params, tokens, cfg: ParallelGPTConfig):
     final global position is masked.
     """
     sp = cfg.sp_axis
-    n_sp = int(_compat.axis_size(sp))
+    n_sp = int(lax.axis_size(sp))
     r_sp = lax.axis_index(sp)
     b, s = tokens.shape
 
@@ -330,7 +329,7 @@ def make_parallel_train_step(
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    mapped = _compat.shard_map(
+    mapped = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(specs, opt_specs, tok_spec),

@@ -15,6 +15,8 @@ see :func:`discover_tpu_hosts`.
 from __future__ import annotations
 
 import dataclasses
+import glob
+import math
 import os
 from typing import List, Optional
 
@@ -107,26 +109,52 @@ def get_host_assignments(
     return assignments
 
 
-def discover_tpu_hosts() -> List[HostInfo]:
+def _chips_from_bounds(bounds: str) -> int:
+    return math.prod(int(d) for d in bounds.split(","))  # e.g. "2,2,1"
+
+
+def _local_chip_count() -> int:
+    """Chips attached to this host, counted WITHOUT initialising a JAX
+    backend: the launcher is the parent of the worker it spawns, a chip
+    belongs to one process at a time, and a parent that has asked JAX for
+    its devices holds the chip the worker needs. The kernel's device nodes
+    win (``/dev/accel<N>``, or one ``/dev/vfio/<N>`` group per chip): the
+    TPU runtime's ``TPU_CHIPS_PER_HOST_BOUNDS`` describes the whole host
+    even where this machine was handed one chip of it. 0 when neither is
+    there."""
+    nodes = len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+    if nodes:
+        return nodes
+    bounds = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS", "")
+    return _chips_from_bounds(bounds) if bounds else 0
+
+
+def discover_tpu_hosts(default_slots: Optional[int] = None) -> List[HostInfo]:
     """Derive the host list from the TPU pod-slice environment.
 
     Replaces the reference's ssh/NIC discovery probe
     (``horovod/runner/driver/driver_service.py:122-257``): on Cloud TPU the
     topology is published in env vars / the metadata-derived
     ``TPU_WORKER_HOSTNAMES`` list, and each worker's chip count in
-    ``TPU_CHIPS_PER_HOST_BOUNDS`` (fall back to local device count).
+    ``TPU_CHIPS_PER_HOST_BOUNDS``. A slice of one host (or no hostname
+    list) is one local process driving the chips :func:`_local_chip_count`
+    finds; on a host where it finds none, ``default_slots`` (the
+    launcher's ``-np``) or an error naming the arguments that settle it.
+    JAX is never asked.
     """
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
-    if hostnames:
-        names = [h.strip() for h in hostnames.split(",") if h.strip()]
-        chips = 4
+    names = [h.strip() for h in hostnames.split(",") if h.strip()]
+    if len(names) > 1:
         bounds = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS", "")
-        if bounds:  # e.g. "2,2,1"
-            dims = [int(x) for x in bounds.split(",")]
-            chips = 1
-            for d in dims:
-                chips *= d
+        chips = _chips_from_bounds(bounds) if bounds else 4
         return [HostInfo(n, chips) for n in names]
-    import jax
-
-    return [HostInfo("localhost", max(1, jax.local_device_count()))]
+    slots = _local_chip_count() or default_slots
+    if not slots:
+        raise ValueError(
+            "cannot tell how many chips this host has (no TPU_* "
+            "environment, no /dev/accel* or /dev/vfio/* device nodes); "
+            "pass -H/--hostfile or -np"
+        )
+    return [HostInfo(names[0] if names else "localhost", slots)]

@@ -165,7 +165,7 @@ def _resolve_hosts(args):
     if args.hostfile:
         with open(args.hostfile) as f:
             return parse_hosts(",".join(l.strip() for l in f if l.strip()))
-    return discover_tpu_hosts()
+    return discover_tpu_hosts(default_slots=args.num_proc)
 
 
 def run_commandline(argv: List[str] = None) -> int:
@@ -205,7 +205,11 @@ def run_commandline(argv: List[str] = None) -> int:
             adopt=args.adopt,
         )
 
-    hosts = _resolve_hosts(args)
+    try:
+        hosts = _resolve_hosts(args)
+    except ValueError as e:
+        print(f"hvdtpu-run: {e}", file=sys.stderr)
+        return 2
     if args.num_proc:
         # Trim the host list to cover the requested worker count.
         total, kept = 0, []

@@ -137,7 +137,12 @@ def test_act_quant_step_trains(world8):
 
 
 def test_act_quant_gradients_track_plain(world8):
-    params, batch, loss_fn = _mlp_setup()
+    # 1024 samples, not 16: int8 rounding flips a few ReLUs, and over 16
+    # samples one flip moves a leaf's gradient by several percent, so the
+    # error depended on the parameter draw (1-11% across seeds; the
+    # installed jax draws other parameters from PRNGKey(0) than the one
+    # this bound was set on). At 1024 it is 1-3% for every seed tried.
+    params, batch, loss_fn = _mlp_setup(batch=1024)
 
     def armed(p, b):
         with aq.activate("int8"):

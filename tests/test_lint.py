@@ -20,7 +20,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu import _compat
 from horovod_tpu.analysis import (
     LintError,
     Severity,
@@ -46,7 +45,7 @@ def _loss(p, b):
 
 
 def _mapped(world8, fn, out_specs=P()):
-    return _compat.shard_map(
+    return jax.shard_map(
         fn,
         mesh=world8.mesh,
         in_specs=(P(), P("hvd")),

@@ -54,6 +54,37 @@ def metrics_env(tmp_path, monkeypatch):
     reg_mod._enabled = None
 
 
+# ---- peak table ------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "platform,kind,want",
+    [
+        ("tpu", "TPU v5 lite", 197.0),
+        ("tpu", "TPU v4", 275.0),
+        ("cpu", "cpu", None),  # NaN: nothing to claim against
+        ("tpu", "TPU v9 imaginary", ValueError),
+        ("gpu", "NVIDIA H100", ValueError),
+    ],
+)
+def test_peak_tflops_unknown_accelerator_is_an_error(platform, kind, want):
+    """Only a cpu device may have no peak; an accelerator whose kind is
+    missing from the table raises instead of yielding ``mfu: null``."""
+    import types
+
+    from horovod_tpu.obs import flops
+
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="PEAK_TFLOPS_BF16"):
+            flops.peak_tflops(dev)
+    elif want is None:
+        assert np.isnan(flops.peak_tflops(dev))
+        assert flops.mfu(1.0, 1.0, device=dev) is None
+    else:
+        assert flops.peak_tflops(dev) == want
+
+
 # ---- registry --------------------------------------------------------------
 
 

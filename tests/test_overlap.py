@@ -293,7 +293,6 @@ def test_enable_overlap_scheduler_autodetects_gpu(monkeypatch):
     import types
 
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.delenv("JAX_PLATFORM_NAME", raising=False)
     monkeypatch.delenv("TPU_NAME", raising=False)
     monkeypatch.setenv("XLA_FLAGS", "")
     monkeypatch.setattr(
@@ -308,18 +307,18 @@ def test_enable_overlap_scheduler_autodetects_gpu(monkeypatch):
     assert added == ("--xla_gpu_enable_latency_hiding_scheduler=true",)
 
 
-def test_enable_overlap_scheduler_legacy_platform_name(monkeypatch):
-    # JAX_PLATFORM_NAME=cpu (the legacy spelling) must be a no-op even
-    # when libtpu is importable — same contract as JAX_PLATFORMS=cpu.
+def test_enable_overlap_scheduler_autodetects_tpu(monkeypatch):
+    # JAX_PLATFORMS unset and libtpu importable: the empty-platform probe
+    # arms the TPU knobs.
     import importlib.util as _ilu
 
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setenv("JAX_PLATFORM_NAME", "cpu")
     monkeypatch.setenv("XLA_FLAGS", "")
     monkeypatch.setattr(
         _ilu, "find_spec", lambda name, *a, **kw: object()
     )  # libtpu "present"
-    assert hvd.enable_overlap_scheduler() == ()
+    added = hvd.enable_overlap_scheduler()
+    assert "--xla_tpu_enable_latency_hiding_scheduler=true" in added
 
 
 def test_enable_overlap_scheduler_tpu_sets_flags(monkeypatch):

@@ -117,7 +117,6 @@ def test_combine_blocks_recovers_full_attention():
 def test_ring_attention_flash_matches_xla_ring(world8):
     # use_flash=True under shard_map reproduces the pure-XLA ring result.
     import horovod_tpu as hvd
-    from horovod_tpu import _compat
     from horovod_tpu.parallel.sp import ring_attention
 
     n = 8
@@ -128,7 +127,7 @@ def test_ring_attention_flash_matches_xla_ring(world8):
 
     for causal in (False, True):
         def run(use_flash, causal=causal):
-            f = _compat.shard_map(
+            f = jax.shard_map(
                 lambda q, k, v: ring_attention(
                     q, k, v, axis=hvd.WORLD_AXIS, causal=causal,
                     use_flash=use_flash, block_q=8, block_k=8,

@@ -11,7 +11,6 @@ pytestmark = pytest.mark.slow
 from jax.sharding import Mesh, PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu import _compat
 from horovod_tpu.parallel import mesh as mesh_lib
 from horovod_tpu.parallel.transformer import (
     ParallelGPTConfig,
@@ -70,7 +69,7 @@ def test_parallel_forward_matches_dense():
 
     expected = _reference_forward(params, tokens, cfg)
 
-    mapped = _compat.shard_map(
+    mapped = jax.shard_map(
         lambda p, t: forward(p, t, cfg),
         mesh=mesh,
         in_specs=(param_specs(cfg), P("dp", "sp")),
@@ -96,7 +95,7 @@ def test_parallel_loss_matches_dense():
     )
     expected = ce.mean()
 
-    mapped = _compat.shard_map(
+    mapped = jax.shard_map(
         lambda p, t: loss_fn(p, t, cfg),
         mesh=mesh,
         in_specs=(param_specs(cfg), P("dp", "sp")),
@@ -144,7 +143,7 @@ def test_switch_moe_stacked_matches_dense_routing(world8):
         return jnp.einsum("egd,edk->egk", jnp.tanh(toks), wl)
 
     mesh = hvd.context().mesh
-    out = _compat.shard_map(
+    out = jax.shard_map(
         lambda xs, ws: switch_moe_stacked(
             xs, gate, expert_fn, ws, axis=hvd.WORLD_AXIS,
             capacity_factor=2.0,
@@ -207,7 +206,7 @@ def test_moe_forward_aux_positive():
     mesh = _mesh222()
     params = init_params(cfg, jax.random.PRNGKey(2))
     tokens = jnp.zeros((4, 32), jnp.int32)
-    logits, aux = _compat.shard_map(
+    logits, aux = jax.shard_map(
         lambda p, t: forward_with_aux(p, t, cfg),
         mesh=mesh,
         in_specs=(param_specs(cfg), P("dp", "sp")),

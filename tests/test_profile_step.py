@@ -44,7 +44,7 @@ def test_converter_absent_is_actionable(monkeypatch):
         ps._load_converter()
     msg = str(ei.value)
     assert "tensorflow>=2.x" in msg
-    assert "--keep" in msg  # tells the user how to salvage the trace
+    assert "--out-dir" in msg  # tells the user how to salvage the trace
 
 
 def test_converter_absent_from_xplane_entry(monkeypatch, tmp_path):

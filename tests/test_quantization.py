@@ -65,7 +65,6 @@ def test_blockwise_zero_block_and_ragged_tail():
     assert not np.any(np.asarray(xd[:16]))
 
 
-@pytest.mark.skipif(not qz.supports_fp8(), reason="no fp8 dtypes in jax")
 def test_fp8_roundtrip():
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(512).astype(np.float32) * 50)

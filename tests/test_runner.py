@@ -531,6 +531,84 @@ def test_check_build_flag(capsys, monkeypatch):
     assert "native TCP" in out
 
 
+_LAUNCHER_PARENT_PROBES = {
+    # What hvdtpu-run does with no -H/--hostfile: count the local chips.
+    "discover_tpu_hosts": (
+        "import os\n"
+        "os.environ['TPU_CHIPS_PER_HOST_BOUNDS'] = '2,2,1'\n"
+        "from horovod_tpu.runner.hosts import discover_tpu_hosts\n"
+        "hosts = discover_tpu_hosts()\n"
+        "assert [h.hostname for h in hosts] == ['localhost'], hosts\n"
+        "assert hosts[0].slots >= 1\n"
+    ),
+    "check_build": (
+        "import horovod_tpu.native as native\n"
+        "native.build = lambda force=False: ''\n"
+        "from horovod_tpu.runner.launch import run_commandline\n"
+        "assert run_commandline(['--check-build']) == 0\n"
+    ),
+}
+
+
+@pytest.mark.parametrize("probe", sorted(_LAUNCHER_PARENT_PROBES))
+def test_launcher_parent_stays_off_jax(probe):
+    """A chip belongs to one process: the launcher parent must not
+    initialise a JAX backend (importing jax is harmless), or it holds the
+    chip the worker it spawns needs."""
+    script = _LAUNCHER_PARENT_PROBES[probe] + (
+        "import jax._src.xla_bridge as xb\n"
+        "assert not xb.backends_are_initialized(), 'parent touched JAX'\n"
+        "print('OFF_JAX')\n"
+    )
+    env = {k: v for k, v in os.environ.items() if not k.startswith("TPU_")}
+    out = subprocess.run(
+        [sys.executable, "-c", script], env={**env, "PYTHONPATH": REPO},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OFF_JAX" in out.stdout
+
+
+def test_discover_tpu_hosts_without_chips(monkeypatch):
+    """No TPU env and no device nodes: -np settles it, else a clear error
+    — never a guess from a backend the parent would have to start."""
+    from horovod_tpu.runner import hosts as hosts_mod
+
+    for var in ("TPU_WORKER_HOSTNAMES", "TPU_CHIPS_PER_HOST_BOUNDS"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(hosts_mod.glob, "glob", lambda pattern: [])
+    got = hosts_mod.discover_tpu_hosts(default_slots=2)
+    assert [(h.hostname, h.slots) for h in got] == [("localhost", 2)]
+    with pytest.raises(ValueError, match="-np"):
+        hosts_mod.discover_tpu_hosts()
+    # The CLI turns that into a usage error, not a traceback.
+    assert run_commandline(["--", sys.executable, "-c", "pass"]) == 2
+
+
+def test_discover_tpu_hosts_counts_device_nodes(monkeypatch):
+    from horovod_tpu.runner import hosts as hosts_mod
+
+    for var in ("TPU_WORKER_HOSTNAMES", "TPU_CHIPS_PER_HOST_BOUNDS"):
+        monkeypatch.delenv(var, raising=False)
+    nodes = {"/dev/accel[0-9]*": ["/dev/accel0", "/dev/accel1"]}
+    monkeypatch.setattr(
+        hosts_mod.glob, "glob", lambda pattern: nodes.get(pattern, [])
+    )
+    assert hosts_mod.discover_tpu_hosts()[0].slots == 2
+    # The one-chip machine of a 2x2 host: the env describes the whole host,
+    # the device node is what this machine was handed.
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    nodes.clear()
+    nodes["/dev/vfio/[0-9]*"] = ["/dev/vfio/3"]
+    got = hosts_mod.discover_tpu_hosts()
+    assert [(h.hostname, h.slots) for h in got] == [("localhost", 1)]
+    # Several hosts: theirs cannot be seen from here, the bounds decide.
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "w0,w1")
+    got = hosts_mod.discover_tpu_hosts()
+    assert [(h.hostname, h.slots) for h in got] == [("w0", 4), ("w1", 4)]
+
+
 def test_rendezvous_hmac_auth():
     """Per-job HMAC (reference secret.py): signed requests pass, unsigned
     or wrong-key requests are rejected."""

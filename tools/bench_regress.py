@@ -57,8 +57,8 @@ _GATE_FIELDS = {
 
 
 def metric_lines(text: str) -> Dict[str, dict]:
-    """``{metric_name: record}`` from bench stdout. Later lines win so a
-    retried model keeps only its final capture."""
+    """``{metric_name: record}`` from bench stdout. Later lines win: a
+    metric printed twice keeps its last record."""
     out: Dict[str, dict] = {}
     for line in text.splitlines():
         line = line.strip()

@@ -2857,6 +2857,11 @@ def main() -> int:
     names = (
         SCENARIO_NAMES if args.scenario == "all" else [args.scenario]
     )
+    # The soak is a CPU program end to end: its workers are pinned to the
+    # CPU platform, and the in-process scenarios (serve, decode, stream)
+    # use JAX in THIS process — which must not attach a chip and then
+    # hold it while it spawns workers.
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     report = run_all(names, steps=args.steps, seed=args.seed)
     if args.json:
         print(json.dumps(report))

@@ -14,7 +14,8 @@ efficiency at v4-32" north star, in three parts:
 2. **Analytic ICI model** — ring-allreduce time from published per-link
    ICI bandwidths (assumptions stated in :func:`ici_specs`, bandwidth
    table shared with ``horovod_tpu.obs.overlap``), combined with
-   the measured single-chip step times from ``BENCH_r04`` and the
+   single-chip step times read before PR 1 on another installation
+   (``MODELS`` below; today's code: not measured) and the
    audited wire bytes to model weak-scaling efficiency at 8/16/32 chips,
    with and without compute/communication overlap credit.  The overlap
    credit is structural, not assumed: each fusion bucket's all-reduce
@@ -90,11 +91,12 @@ def _divisible_accum(model_key, requested):
     return max(k for k in range(1, min(requested, per) + 1) if per % k == 0)
 
 
-# Measured single-chip device step times (bench.py method: in-program
-# fori_loop, host-fetch closed, median of 5 windows; round-5 numbers —
-# docs/perf_analysis_r05.md) and per-step gradient bytes (fp32 grads =
-# 4 bytes/param; the audit below re-derives the bytes from the actual
-# fusion buckets).
+# Single-chip device step times (bench.py method: in-program fori_loop,
+# host-fetch closed, median of 5 windows; round-5 readings from before
+# PR 1, on another installation — docs/perf_analysis_r05.md; today's code
+# is not measured, so the modeled efficiencies are inputs to a model, not
+# results) and per-step gradient bytes (fp32 grads = 4 bytes/param; the
+# audit below re-derives the bytes from the actual fusion buckets).
 MODELS = {
     "bert_base_mlm_32x512": {"step_ms_v5e": 109.5, "backward_fraction": 0.62},
     "gpt2_small_16x1024": {"step_ms_v5e": 128.8, "backward_fraction": 0.62},
@@ -381,7 +383,6 @@ def audit(model_key, n_devices=8, sharded=False, accum=1, compression=None):
             "(the --model all driver sets this automatically)"
         )
     import horovod_tpu as hvd
-    from horovod_tpu import _compat
     from horovod_tpu.utils import timeline as tl
 
     hvd.init(devices=jax.devices("cpu")[:n_devices])
@@ -394,7 +395,7 @@ def audit(model_key, n_devices=8, sharded=False, accum=1, compression=None):
     tl.start_timeline(path)
 
     mapped = jax.jit(
-        _compat.shard_map(
+        jax.shard_map(
             step,
             mesh=hvd.context().mesh,
             in_specs=in_specs,
@@ -465,7 +466,6 @@ def lint_audit(model_key, n_devices=8, sharded=False, accum=1,
             f"--xla_force_host_platform_device_count={n_devices}"
         )
     import horovod_tpu as hvd
-    from horovod_tpu import _compat
     from horovod_tpu.analysis import collect, lint_traced, ring_wire_bytes
     from horovod_tpu.ops.fusion import (
         bucket_byte_layout,
@@ -480,7 +480,7 @@ def lint_audit(model_key, n_devices=8, sharded=False, accum=1,
         compression=compression,
     )
     comp = _resolve_compression(compression) if compression else None
-    mapped = _compat.shard_map(
+    mapped = jax.shard_map(
         step,
         mesh=hvd.context().mesh,
         in_specs=in_specs,
@@ -590,7 +590,6 @@ def audit_topology(model_key, topology="v5e:2x4", extra_threshold=32 << 20,
     from jax.sharding import Mesh, PartitionSpec as P
 
     import horovod_tpu as hvd
-    from horovod_tpu import _compat
     from horovod_tpu.ops.layout import (
         collective_compiler_options,
         predict_bucket_layout,
@@ -610,7 +609,7 @@ def audit_topology(model_key, topology="v5e:2x4", extra_threshold=32 << 20,
     )
 
     mapped = jax.jit(
-        _compat.shard_map(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=in_specs,
@@ -752,8 +751,8 @@ def main():
         default=None,
         metavar="NAME",
         help="AOT-compile real TPU HLO for this topology (default v5e:2x4) "
-        "instead of the virtual-CPU-mesh audit; needs the TPU PJRT plugin "
-        "but no chips",
+        "instead of the virtual-CPU-mesh audit; needs the TPU compiler "
+        "(libtpu) but no chips",
     )
     ap.add_argument(
         "--sharded",
@@ -989,8 +988,11 @@ def main():
                 check=True,
             )
             row = json.loads(out.stdout.strip().splitlines()[-1])
-            # TPU-HLO layout audit rides in a sibling subprocess (it must
-            # NOT force the CPU platform — it needs the TPU PJRT plugin).
+            # TPU-HLO layout audit rides in a sibling subprocess, started
+            # after the CPU child has exited: it loads the TPU compiler,
+            # whose lock file allows one loader at a time. It compiles for
+            # a DESCRIBED topology, so it is pinned to the CPU platform
+            # and never attaches a chip this host may have.
             topo = subprocess.run(
                 [
                     sys.executable,
@@ -1003,7 +1005,7 @@ def main():
                 + fwd,
                 capture_output=True,
                 text=True,
-                env=os.environ.copy(),
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             )
             if topo.returncode == 0:
                 row["tpu_hlo_audit"] = json.loads(

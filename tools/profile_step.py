@@ -8,7 +8,7 @@ category rollup (conv / BN-reduce / elementwise / other), which is the
 evidence base for the conv+BN fusion work (VERDICT r2 #1).
 
 Usage:
-    python tools/profile_step.py [--model resnet50] [--top 40] [--keep]
+    python tools/profile_step.py [--model resnet50] [--top 40] [--out-dir DIR]
 """
 
 import argparse
@@ -48,8 +48,8 @@ def _load_converter():
         raise ConverterUnavailable(
             "per-HLO stats need TensorFlow's bundled xplane converter: "
             "install tensorflow>=2.x (the captured trace itself only needs "
-            "jax; re-run with --keep to retain the trace dir and convert "
-            "elsewhere). Original error: " + str(e)
+            "jax; re-run with --out-dir DIR to keep the trace and convert "
+            "it elsewhere). Original error: " + str(e)
         ) from e
     return pp
 
@@ -100,7 +100,11 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="resnet50")
     ap.add_argument("--top", type=int, default=40)
-    ap.add_argument("--keep", action="store_true", help="keep the trace dir")
+    ap.add_argument(
+        "--out-dir",
+        help="write the trace here and keep it (e.g. chiprun_out/prof, so "
+        "a chip call brings it home); default: a temporary directory",
+    )
     ap.add_argument("--json", help="dump all rows (all columns) to this path")
     args = ap.parse_args()
 
@@ -111,7 +115,9 @@ def main():
     from jax.sharding import PartitionSpec as P
 
     import horovod_tpu as hvd
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     hvd.init()
     n = hvd.size()
     wa = hvd.WORLD_AXIS
@@ -195,7 +201,7 @@ def main():
     else:
         raise SystemExit(f"unknown model {args.model}")
 
-    logdir = tempfile.mkdtemp(prefix="hvdtpu_prof_") if not args.keep else "/tmp/hvdtpu_prof"
+    logdir = args.out_dir or tempfile.mkdtemp(prefix="hvdtpu_prof_")
     capture(run, args0, logdir)
     try:
         rows = parse_hlo_stats(xplane_to_hlo_stats(logdir))
@@ -203,7 +209,7 @@ def main():
         print(f"error: {e}", file=sys.stderr)
         print(f"trace dir (raw xplane): {logdir}", file=sys.stderr)
         raise SystemExit(2)
-    if args.keep:
+    if args.out_dir:
         print(f"trace dir: {logdir}", file=sys.stderr)
     if args.json:
         with open(args.json, "w") as f:
