@@ -31,7 +31,7 @@ measured (``PERF.md``).
 
 Where the time went then (full per-HLO device-trace analysis:
 ``docs/perf_analysis_resnet_r03.md``, captured with
-``tools/profile_step.py``): the 46.8 ms device step is 60% backward-conv
+``tools/profile_step.py``, since replaced by ``benchmark/split.py``): the 46.8 ms device step is 60% backward-conv
 fusions, 18% forward-conv fusions — and XLA **already fuses the BN batch
 stats and BN-backward reductions into those conv fusions**
 (standalone forward BN-stats reduces: 0.35 ms/step). The dominant

@@ -107,6 +107,11 @@ def init(
         (``nccl_operations.cc:292-364``).
     """
     global _context
+    from .obs import build as _build
+
+    # Counts and times every trace, lowering and compile by function
+    # name from here on (obs/build.py): on by default, builds are rare.
+    _build.install()
     with _lock:
         if mesh is not None:
             axes = tuple(world_axes) if world_axes else tuple(mesh.axis_names)

@@ -192,27 +192,35 @@ class ShardedBatches:
 
 def prefetch_to_device(iterator, depth: Optional[int] = None, *,
                        sharding=None) -> Iterator:
-    """Double-buffered host→device input prefetch.
+    """Host→device input staging, ``depth`` batches ahead.
 
     Wrap a batch iterator (e.g. :class:`ShardedBatches`) so each element
     is staged onto device with ``jax.device_put`` up to ``depth`` items
-    before the training loop asks for it. ``jax.device_put`` enqueues the
-    transfer asynchronously, so with ``depth>=2`` (the default,
-    ``HVDTPU_PREFETCH_DEPTH``) the host-side slicing + H2D copy of batch
-    ``n+1`` runs while the device executes step ``n`` — the host-dispatch
-    slice of the per-step breakdown (``step.host_dispatch_ms``) leaves
-    the critical path. Ordering is preserved and the wrapper is exactly
-    as long as its input (exhaustion passes through; no batch is dropped
-    or duplicated).
+    (default ``HVDTPU_PREFETCH_DEPTH``) before the training loop asks
+    for it. There is no thread: the buffer is a deque refilled
+    synchronously inside the consumer's ``next()``, so the source
+    iterator's own work for batch ``n+depth`` runs on the loop's thread.
+    What overlaps the device is the copy: ``device_put`` enqueues the
+    transfer and returns, and a loop that dispatches steps
+    asynchronously refills while earlier steps still execute. Ordering
+    is preserved and the wrapper is exactly as long as its input
+    (exhaustion passes through; no batch is dropped or duplicated).
 
     ``sharding`` (a ``jax.sharding.Sharding`` or device) is forwarded to
     ``device_put`` so batches can land pre-sharded over the world mesh.
-    On CPU test platforms ``device_put`` is effectively synchronous and
-    the wrapper degrades to a small deque — same semantics, no overlap.
+    On CPU test platforms ``device_put`` is effectively synchronous —
+    same semantics, no overlap.
 
-    With the metrics plane on, gauges ``prefetch.depth`` /
-    ``prefetch.occupancy`` (buffer fill seen at each yield) and counter
-    ``prefetch.batches`` land in the exported records.
+    Spans (:func:`horovod_tpu.obs.trace.span`: profiler annotation
+    always, ring event with ``HVDTPU_TRACE``): ``hvd.input.fill`` covers
+    one refill (``stalled``: the buffer was empty, so the consumer waited
+    for it; ``occupancy`` at entry; ``depth``) and, inside it,
+    ``hvd.input.put`` each ``device_put`` alone, apart from the source's
+    ``next()``. Neither is held open across a ``yield``. Always on:
+    histogram ``input.put_ms`` and counter ``input.stalled`` (stalled
+    refills; the first refill of a run is one). With the metrics plane
+    on, gauges ``prefetch.depth`` / ``prefetch.occupancy`` (buffer fill
+    seen at each yield) and counter ``prefetch.batches``.
     """
     if depth is None:
         depth = _env.prefetch_depth()
@@ -221,49 +229,61 @@ def prefetch_to_device(iterator, depth: Optional[int] = None, *,
         # time instead of at the first (possibly much later) next().
         raise ValueError(f"prefetch depth must be >= 1, got {depth}")
 
-    import jax  # deferred: the rest of this module is jax-free numpy
+    import time as _time
 
-    def put(item):
-        if sharding is not None:
-            return jax.device_put(item, sharding)
-        return jax.device_put(item)
+    import jax  # deferred: the rest of this module is jax-free numpy
 
     from .obs import goodput as _goodput
     from .obs import trace as _trace
 
+    def put(item):
+        with _trace.span("hvd.input.put", "data"):
+            t0 = _time.perf_counter()
+            if sharding is not None:
+                out = jax.device_put(item, sharding)
+            else:
+                out = jax.device_put(item)
+            _obs.always().histogram("input.put_ms").observe(
+                (_time.perf_counter() - t0) * 1e3
+            )
+        return out
+
     def gen():
         queue: collections.deque = collections.deque()
         it = iter(iterator)
-        import time as _time
-
+        exhausted = False
         while True:
-            was_empty = not queue
-            timed = _trace.enabled() or _goodput.enabled()
-            t0 = _time.perf_counter() if timed else 0.0
-            w0 = _time.time()
-            filled = 0
-            while len(queue) < depth:
-                try:
-                    queue.append(put(next(it)))
-                    filled += 1
-                except StopIteration:
-                    break
-            if filled and was_empty and _goodput.enabled():
-                # Empty buffer at entry: this fill ran on the consumer's
-                # critical path — goodput-visible input stall.
-                _goodput.record_input_stall(w0, _time.perf_counter() - t0)
-            if filled and _trace.enabled():
+            if not exhausted:
+                was_empty = not queue
+                goodput_on = _goodput.enabled()
+                if goodput_on:
+                    w0, t0 = _time.time(), _time.perf_counter()
+                filled = 0
                 # The data-fetch + H2D-enqueue slice. An empty buffer at
                 # entry means the consumer OUTRAN the prefetcher — this
                 # span was a stall on the step's critical path, not
-                # overlapped background work; the occupancy arg is how
-                # the merged timeline tells the two apart.
-                _trace.complete(
-                    "prefetch.fill", "data", w0,
-                    _time.perf_counter() - t0,
-                    args={"filled": filled, "stalled": was_empty,
-                          "occupancy": len(queue), "depth": depth},
-                )
+                # overlapped background work; ``stalled`` is how a
+                # timeline tells the two apart.
+                with _trace.span(
+                    "hvd.input.fill", "data", stalled=was_empty,
+                    occupancy=len(queue), depth=depth,
+                ):
+                    while len(queue) < depth:
+                        try:
+                            item = next(it)
+                        except StopIteration:
+                            exhausted = True
+                            break
+                        queue.append(put(item))
+                        filled += 1
+                if filled and was_empty:
+                    _obs.always().counter("input.stalled").inc()
+                    if goodput_on:
+                        # This fill ran on the consumer's critical path:
+                        # goodput-visible input stall.
+                        _goodput.record_input_stall(
+                            w0, _time.perf_counter() - t0
+                        )
             if not queue:
                 return
             # Enablement checked per yield (one cached boolean), matching

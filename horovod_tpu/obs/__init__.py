@@ -45,6 +45,7 @@ from __future__ import annotations
 
 from .registry import (  # noqa: F401
     MetricsRegistry,
+    always,
     enabled,
     enable,
     disable,
@@ -57,6 +58,7 @@ from .export import (  # noqa: F401
     reporter,
     snapshot,
 )
+from . import build  # noqa: F401
 from . import flops  # noqa: F401
 from . import goodput  # noqa: F401
 from . import overlap  # noqa: F401
@@ -65,6 +67,7 @@ from . import trace  # noqa: F401
 __all__ = [
     "MetricsRegistry",
     "MetricsReporter",
+    "always",
     "enabled",
     "enable",
     "disable",
@@ -73,6 +76,7 @@ __all__ = [
     "reporter",
     "flush",
     "snapshot",
+    "build",
     "flops",
     "goodput",
     "overlap",

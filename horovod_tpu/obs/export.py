@@ -77,9 +77,11 @@ def _prom_name(name: str) -> str:
 
 
 def snapshot() -> dict:
-    """Registry + native counters as one export-shaped dict."""
+    """Registry + native counters as one export-shaped dict. With the
+    plane off it still holds the always-on series
+    (:func:`horovod_tpu.obs.registry.always`)."""
     rank, world = _rank_world()
-    snap = _registry.metrics().snapshot()
+    snap = _registry.always().snapshot()
     native = read_native()
     counters = dict(snap["counters"])
     gauges = dict(snap["gauges"])

@@ -296,3 +296,12 @@ def metrics() -> MetricsRegistry:
     """The process registry when enabled, else the no-op registry —
     call sites never branch themselves."""
     return _registry if enabled() else null_registry
+
+
+def always() -> MetricsRegistry:
+    """The process registry whether or not the plane is on: for the few
+    series that are rare or cost under a microsecond and that a reader
+    can only ask for after the run (``build.*``, ``step.jit_dispatch_ms``,
+    ``input.put_ms``, ``input.stalled``). ``HVDTPU_METRICS`` keeps gating
+    the exporters and everything per-step that costs more."""
+    return _registry

@@ -12,9 +12,9 @@ eager collectives to every plane this repo owns).
 Design constraints, in the registry's order:
 
 1. **Near-zero cost when off.** Every site guards on :func:`enabled`
-   (one cached module-bool read); :func:`span` returns a shared no-op
-   context manager, :func:`instant`/:func:`complete` fall through
-   without allocating.
+   (one cached module-bool read); :func:`instant`/:func:`complete` fall
+   through without allocating, and :func:`span` opens only its second
+   sink.
 2. **Bounded memory when on.** Events land in a fixed-capacity ring
    (``HVDTPU_TRACE_BUFFER``, default 4096): a week-long job keeps the
    *last* N events — exactly what a flight recorder wants — and an
@@ -28,7 +28,20 @@ Design constraints, in the registry's order:
    breach, before a chaos ``crash``/``hang`` executes, and from
    ``tools/chaos_soak.py``'s deadline teardown.
 
-Clock model: timestamps are **wall-clock microseconds** per process.
+**One span API, two sinks.** :func:`span` always opens a
+``jax.profiler.TraceAnnotation`` of the same name (with the span's
+arguments), so under ``jax.profiler.trace`` the program's spans lie on
+the profiler's clock beside the device's operations; it does nothing
+unless a profiler session is active (about a microsecond of Python), and
+a process that never imported JAX (the elastic driver) skips it. The
+ring event is written only with ``HVDTPU_TRACE`` on. The train step's
+hot path records through it (docs/api.md has the span table):
+``hvd.step.dispatch`` > ``hvd.step.jit`` / ``hvd.step.lint`` /
+``hvd.step.preflight``, ``hvd.step.sync``, ``hvd.input.fill`` >
+``hvd.input.put``; ``hvd.build`` (:mod:`horovod_tpu.obs.build`) is ring
+only, JAX's own compile annotations are in the profile already.
+
+Clock model: ring timestamps are **wall-clock microseconds** per process.
 Cross-host clocks skew, so ranks record ``clock_sync`` instants when
 they observe a driver-published round timestamp (``elastic.worker.
 join_world``); ``tools/hvdtpu_trace.py`` recovers each rank's offset as
@@ -42,6 +55,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -74,25 +88,45 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _annotation(name: str, args: dict):
+    """The profiler-side sink of a span: a ``TraceAnnotation``, which is
+    inert without an active profiler session. Never imports JAX itself: a
+    process without it has no profiler to write to."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return _NULL_SPAN
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args)
+
 
 class _Span:
     """One open span: records ``B`` on the thread's open-stack at entry,
-    retires to a single ``X`` (complete) ring event at exit."""
+    retires to a single ``X`` (complete) ring event at exit. ``ann`` is
+    the profiler-side sink, opened and closed around it."""
 
-    __slots__ = ("_rec", "_frame")
+    __slots__ = ("_rec", "_frame", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str,
-                 args: Optional[dict]):
+                 args: Optional[dict], ann=_NULL_SPAN):
         self._rec = rec
         self._frame = {"name": name, "cat": cat, "ts": 0, "args": args}
+        self._ann = ann
 
     def __enter__(self):
+        self._ann.__enter__()
         self._frame["ts"] = _now_us()
         self._rec._push_open(self._frame)
         return self
 
     def __exit__(self, *exc):
         self._rec._pop_open(self._frame)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -343,10 +377,13 @@ def _reset_for_tests() -> None:
 
 
 def span(name: str, cat: str = "app", **args):
-    """Context manager timing one phase; the shared no-op when off."""
+    """Context manager over one phase, with two sinks: a profiler
+    annotation always (inert without a profiler session), the ring event
+    only when the plane is on."""
+    ann = _annotation(name, args)
     if not enabled():
-        return _NULL_SPAN
-    return recorder().span(name, cat, **args)
+        return ann
+    return _Span(recorder(), name, cat, args or None, ann)
 
 
 def instant(name: str, cat: str = "app", args: Optional[dict] = None,

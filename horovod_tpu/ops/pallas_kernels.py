@@ -385,6 +385,7 @@ def _fwd_pallas(
             transcendentals=b * h * sq_pad * skv_pad,
         ),
         interpret=interpret,
+        name="hvd_flash_fwd",
     )(*scalars, qr, kr, vr)
 
     if packed:
@@ -718,6 +719,7 @@ def _bwd_pallas(
             _VMEM((group, block_k, d), jnp.float32),
         ],
         **common_params,
+        name="hvd_flash_bwd_dkv",
     )(*scalars, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
@@ -742,6 +744,7 @@ def _bwd_pallas(
         out_shape=dq_shape,
         scratch_shapes=[_VMEM((group, block_q, d), jnp.float32)],
         **common_params,
+        name="hvd_flash_bwd_dq",
     )(*scalars, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
     if packed:
@@ -996,6 +999,7 @@ def quantize_blockwise_pallas(
             jax.ShapeDtypeStruct((8, nb_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_quantize_blockwise",
     )(rows)
     return q[:nb], s[0, :nb]
 
@@ -1023,6 +1027,7 @@ def dequantize_blockwise_pallas(
         out_specs=pl.BlockSpec((r, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb_pad, block), out_dtype),
         interpret=interpret,
+        name="hvd_dequantize_blockwise",
     )(q_rows, s_rows)
     return out[:nb]
 
@@ -1121,6 +1126,7 @@ def fused_adamw_update_pallas(
             jax.ShapeDtypeStruct((rows_pad, _ADAM_LANES), v.dtype),
         ],
         interpret=interpret,
+        name="fused_adamw_update",
     )(bias_corrections, prep(p), prep(m), prep(v), prep(g))
     return (
         u.reshape(-1)[:n],
@@ -1226,6 +1232,7 @@ def int8_matmul_pallas(
             transcendentals=0,
         ),
         interpret=interpret,
+        name="int8_matmul",
     )(xr, wr, s_rows)
     return out[:mm, :nn]
 
@@ -1326,6 +1333,7 @@ def fp8_matmul_pallas(
             transcendentals=0,
         ),
         interpret=interpret,
+        name="fp8_matmul",
     )(scale, xr, wr)
     return out[:mm, :nn]
 

@@ -417,47 +417,64 @@ def DistributedOptimizer(
             count=jnp.zeros((), jnp.int32), residual=residual,
         )
 
+    # The phases of a step name themselves in the compiled program:
+    # ``hvd_reduce`` is the gradient exchange, ``hvd_update`` the inner
+    # optimizer (dp._step adds ``hvd_grad`` and ``hvd_loss_avg``). The
+    # scopes reach the device trace through each instruction's op_name.
     def update(grads, state: DistributedOptState, params=None):
         if quantized:
-            reduced, new_res = quantized_fused_allreduce(
-                grads,
-                state.residual,
-                op=op,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-                axis=axis,
-                threshold_bytes=threshold_bytes,
-                compression=compression,
-                stagger=stagger,
-            )
+            with jax.named_scope("hvd_reduce"):
+                reduced, new_res = quantized_fused_allreduce(
+                    grads,
+                    state.residual,
+                    op=op,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor,
+                    axis=axis,
+                    threshold_bytes=threshold_bytes,
+                    compression=compression,
+                    stagger=stagger,
+                )
             _record_grad_bytes(grads)
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            with jax.named_scope("hvd_update"):
+                updates, inner = optimizer.update(
+                    reduced, state.inner, params
+                )
             return updates, DistributedOptState(
                 inner, None, state.count + 1, new_res
             )
         if bpps == 1:
-            reduced = _reduce_grads(
-                grads, op, compression, prescale_factor, postscale_factor,
-                axis, threshold_bytes, stagger,
-            )
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            with jax.named_scope("hvd_reduce"):
+                reduced = _reduce_grads(
+                    grads, op, compression, prescale_factor,
+                    postscale_factor, axis, threshold_bytes, stagger,
+                )
+            with jax.named_scope("hvd_update"):
+                updates, inner = optimizer.update(
+                    reduced, state.inner, params
+                )
             return updates, DistributedOptState(inner, None, state.count + 1)
 
-        acc = jax.tree.map(jnp.add, state.acc, grads)
+        with jax.named_scope("hvd_grad"):
+            acc = jax.tree.map(jnp.add, state.acc, grads)
         count = state.count + 1
         do_sync = (count % bpps) == 0
 
         def sync_branch(operands):
             acc_, inner_ = operands
-            agg = acc_
-            if average_aggregated_gradients:
-                agg = jax.tree.map(lambda g: g / bpps, agg)
-            reduced = _reduce_grads(
-                agg, op, compression, prescale_factor, postscale_factor,
-                axis, threshold_bytes, stagger,
-            )
-            updates, new_inner = optimizer.update(reduced, inner_, params)
-            zeroed = jax.tree.map(jnp.zeros_like, acc_)
+            with jax.named_scope("hvd_reduce"):
+                agg = acc_
+                if average_aggregated_gradients:
+                    agg = jax.tree.map(lambda g: g / bpps, agg)
+                reduced = _reduce_grads(
+                    agg, op, compression, prescale_factor, postscale_factor,
+                    axis, threshold_bytes, stagger,
+                )
+            with jax.named_scope("hvd_update"):
+                updates, new_inner = optimizer.update(
+                    reduced, inner_, params
+                )
+                zeroed = jax.tree.map(jnp.zeros_like, acc_)
             return updates, new_inner, zeroed
 
         def skip_branch(operands):
@@ -754,54 +771,63 @@ def ShardedDistributedOptimizer(
             )
         _record_grad_bytes(grads)
         new_res = state.residual
-        if quantized:
-            g_shards, spec, new_res = quantized_fused_reducescatter(
-                grads,
-                state.residual,
-                op=op,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-                axis=axes,
-                threshold_bytes=threshold_bytes,
-                compression=compression,
+        # Scopes as in DistributedOptimizer: the reduce-scatter and the
+        # all-gather are both ``hvd_reduce``, the shard update between
+        # them ``hvd_update``.
+        with jax.named_scope("hvd_reduce"):
+            if quantized:
+                g_shards, spec, new_res = quantized_fused_reducescatter(
+                    grads,
+                    state.residual,
+                    op=op,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor,
+                    axis=axes,
+                    threshold_bytes=threshold_bytes,
+                    compression=compression,
+                    stagger=stagger,
+                )
+            else:
+                g_shards, spec = fused_reducescatter(
+                    grads,
+                    op=op,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor,
+                    axis=axes,
+                    threshold_bytes=threshold_bytes,
+                    compression=compression,
+                    stagger=stagger,
+                )
+        with jax.named_scope("hvd_update"):
+            p_buffers, _ = pack(
+                params, threshold_bytes,
+                pad_multiple=_pad_mult(_traced_size(axes)),
+            )
+            if [int(b.shape[0]) for b in p_buffers] != list(
+                spec.padded_sizes()
+            ):
+                raise HorovodTpuError(
+                    "gradient and parameter bucket layouts differ "
+                    f"({[int(b.shape[0]) for b in p_buffers]} vs "
+                    f"{list(spec.padded_sizes())}); the sharded update "
+                    "needs grads to pack like params (same tree, shapes "
+                    "and dtypes — mixed grad/param precision is not "
+                    "supported)"
+                )
+            p_shards = shard_slice(p_buffers, axis=axes)
+            if fused_update:
+                u_shards, inner = _fused_flat_update(
+                    g_shards, state.inner, p_shards, fused_spec
+                )
+            else:
+                u_shards, inner = optimizer.update(
+                    g_shards, state.inner, p_shards
+                )
+        with jax.named_scope("hvd_reduce"):
+            updates = fused_allgather(
+                u_shards, spec, axis=axes, compression=gather_compression,
                 stagger=stagger,
             )
-        else:
-            g_shards, spec = fused_reducescatter(
-                grads,
-                op=op,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-                axis=axes,
-                threshold_bytes=threshold_bytes,
-                compression=compression,
-                stagger=stagger,
-            )
-        p_buffers, _ = pack(
-            params, threshold_bytes,
-            pad_multiple=_pad_mult(_traced_size(axes)),
-        )
-        if [int(b.shape[0]) for b in p_buffers] != list(spec.padded_sizes()):
-            raise HorovodTpuError(
-                "gradient and parameter bucket layouts differ "
-                f"({[int(b.shape[0]) for b in p_buffers]} vs "
-                f"{list(spec.padded_sizes())}); the sharded update needs "
-                "grads to pack like params (same tree, shapes and dtypes "
-                "— mixed grad/param precision is not supported)"
-            )
-        p_shards = shard_slice(p_buffers, axis=axes)
-        if fused_update:
-            u_shards, inner = _fused_flat_update(
-                g_shards, state.inner, p_shards, fused_spec
-            )
-        else:
-            u_shards, inner = optimizer.update(
-                g_shards, state.inner, p_shards
-            )
-        updates = fused_allgather(
-            u_shards, spec, axis=axes, compression=gather_compression,
-            stagger=stagger,
-        )
         return updates, ShardedOptState(
             inner=inner,
             count=state.count + 1,

@@ -163,22 +163,33 @@ def accumulate_gradients(
 def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
                      overlap: bool = False, accum_steps: int = 1,
                      quantized: bool = False, fp8: bool = False) -> Callable:
-    """Metrics wrapper for a built train step.
+    """Outermost wrapper of a built train step: the ``hvd.step.dispatch``
+    span always, the metrics / trace / goodput bookkeeping when a plane
+    is on.
 
     The enablement check is per *call*, not per build, so the documented
     ``hvd.obs.enable()``/``disable()`` work on an already-built step:
-    disabled calls pay one cached-boolean check and fall straight
-    through to the jitted fn. When enabled, each call records
-    host-dispatch time (the jitted call returning — Python +
-    tracing-cache + transfer-enqueue cost) vs device time (a
-    ``block_until_ready`` bracket over the outputs) as histograms plus
-    step/token counters and throughput/MFU gauges; the reporter is
-    ticked with the step count so JSONL/Prometheus flushes and the
-    psum'd rank-0 summary ride the training loop with no extra threads.
-    The bracket serializes host and device per step — honest breakdown,
-    not peak pipelining — which is why it only runs with the plane on
-    (the <1% regression budget applies to the plane OFF).
+    with every plane off a call pays one cached-boolean check per plane
+    and the span (a profiler annotation, inert without a session).
+
+    With a plane on the wrapper never waits for the step it has just
+    dispatched. It dispatches step i, then blocks on step i-1's *loss*
+    (``hvd.step.sync``; never on the state, never on step i's output)
+    and stamps the clock: the loop of a user who logs the loss one step
+    late, so the device always holds a queued step and turning a plane
+    on does not change what it measures. Each stamp books the step it
+    closes: ``step.total_ms`` is the gap since the previous stamp (or
+    since this step's dispatch began, where that is later: the first
+    step, or a loop that paused), ``step.host_dispatch_ms`` the time its
+    ``step(state, batch)`` call took to return, ``step.device_ms`` the
+    rest of the gap. N calls book N-1 steps; the last one stays pending.
+    The ring's ``step`` / ``step.host_dispatch`` / ``step.device``
+    events and ``goodput.record_step`` get the same three quantities;
+    the reporter is ticked with the call count so JSONL/Prometheus
+    flushes and the psum'd rank-0 summary ride the training loop with no
+    extra threads.
     """
+    from ..obs import build as _build
     from ..obs import export as _export
     from ..obs import flops as _flops
     from ..obs import goodput as _goodput
@@ -190,66 +201,91 @@ def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
     # and diverges after an elastic rescale (a fresh worker starts at 0
     # while survivors carry their history), which would leave ranks
     # entering the blocking summary allreduce on different iterations —
-    # so the collective is keyed to this wrapper-local counter instead,
-    # reset to zero on every (re)build, which rescales perform on all
-    # ranks in lockstep.
+    # so the collective is keyed to this wrapper-local call counter
+    # instead, reset to zero on every (re)build, which rescales perform
+    # on all ranks in lockstep.
     local_step = 0
+    # The dispatched step no stamp has closed yet, (loss, perf_counter
+    # and wall clock at its dispatch, dispatch seconds, call number), and
+    # the perf_counter of the last stamp.
+    pending = None
+    last_stamp = None
+
+    def book(step, t_done):
+        """A stamp closed ``step``: histograms, gauges, ring, ledger."""
+        nonlocal peak, last_stamp
+        _, t0, w0, dispatch, n = step
+        begin = t0 if last_stamp is None else max(t0, last_stamp)
+        last_stamp = t_done
+        total = t_done - begin
+        device = max(0.0, total - dispatch)
+        w_begin = w0 + (begin - t0)
+        if _trace.enabled():
+            # The step and its two bookkeeping slices as nested X events
+            # (wall-clock ts so the merge tool can align ranks). Where
+            # the host really was is in the hvd.step.* spans.
+            rec = _trace.recorder()
+            w_us, disp_us = int(w_begin * 1e6), int(dispatch * 1e6)
+            rec.complete(
+                "step", "train", w_us, int(total * 1e6), args={"step": n}
+            )
+            rec.complete("step.host_dispatch", "train", w_us, disp_us)
+            rec.complete(
+                "step.device", "train", w_us + disp_us, int(device * 1e6)
+            )
+        _goodput.record_step(w_begin, total, dispatch, device)
+        reg = _obs.metrics()
+        reg.histogram("step.total_ms").observe(total * 1e3)
+        reg.histogram("step.host_dispatch_ms").observe(dispatch * 1e3)
+        reg.histogram("step.device_ms").observe(device * 1e3)
+        if total <= 0:
+            return
+        reg.gauge("step.per_sec").set(1.0 / total)
+        if tokens_per_step:
+            reg.gauge("step.tokens_per_sec").set(tokens_per_step / total)
+        if flops_per_step:
+            if peak is None:
+                peak = _flops.peak_tflops(jax.devices()[0])
+            # mfu() treats its first two args as (units/sec, flops/unit);
+            # with one step as the unit that's steps/sec × flops/step.
+            m = _flops.mfu(1.0 / total, flops_per_step, peak=peak)
+            if m is not None:
+                reg.gauge("step.mfu").set(m)
 
     def wrapped(state, batch):
-        nonlocal peak, local_step
-        trace_on = _trace.enabled()
-        goodput_on = _goodput.enabled()
-        if not _obs.enabled() and not trace_on and not goodput_on:
-            return fn(state, batch)
+        nonlocal local_step, pending, last_stamp
+        n = local_step
+        local_step = n + 1
+        # obs/build.py books a build that happens inside this call
+        # against the call's number.
+        _build.in_step.call = n
+        try:
+            if not (_obs.enabled() or _trace.enabled()
+                    or _goodput.enabled()):
+                pending = last_stamp = None
+                with _trace.span("hvd.step.dispatch", "train", step=n):
+                    return fn(state, batch)
+            w0 = time.time()
+            t0 = time.perf_counter()
+            with _trace.span("hvd.step.dispatch", "train", step=n):
+                out = fn(state, batch)
+            dispatch = time.perf_counter() - t0
+        finally:
+            _build.in_step.call = None
+        closed, pending = pending, (out[1], t0, w0, dispatch, n)
+        if closed is not None:
+            prev_loss, *_, prev_n = closed
+            with _trace.span("hvd.step.sync", "train", step=prev_n):
+                jax.block_until_ready(prev_loss)
+            book(closed, time.perf_counter())
         reg = _obs.metrics()
-        w0 = time.time()
-        t0 = time.perf_counter()
-        out = fn(state, batch)
-        t_dispatch = time.perf_counter()
-        jax.block_until_ready(out)
-        t_done = time.perf_counter()
-        total = t_done - t0
-        if trace_on:
-            # Span plane: the same bracket as three nested X events —
-            # the step, its host-dispatch slice (Python + tracing cache
-            # + transfer enqueue), and the device block. Wall-clock ts
-            # so the merge tool can align ranks; one recorder resolve,
-            # three ring appends.
-            rec = _trace.recorder()
-            w0_us = int(w0 * 1e6)
-            disp_us = int((t_dispatch - t0) * 1e6)
-            rec.complete(
-                "step", "train", w0_us, int(total * 1e6),
-                args={"step": local_step},
-            )
-            rec.complete("step.host_dispatch", "train", w0_us, disp_us)
-            rec.complete(
-                "step.device", "train", w0_us + disp_us,
-                int((t_done - t_dispatch) * 1e6),
-            )
-        if goodput_on:
-            # Goodput ledger: the same bracket attributed wall-second by
-            # wall-second (host_dispatch + compute, with the exposed_comm
-            # tail carved out against the rolling-min device baseline).
-            _goodput.record_step(
-                w0, total, t_dispatch - t0, t_done - t_dispatch
-            )
-        reg.histogram("step.total_ms").observe(total * 1e3)
-        reg.histogram("step.host_dispatch_ms").observe((t_dispatch - t0) * 1e3)
-        reg.histogram("step.device_ms").observe((t_done - t_dispatch) * 1e3)
         reg.counter("step.count").inc()
         # Overlap-pipeline shape of this step (how bench.py --overlap and
         # hvdtpu_top tell the on/off runs apart in the exported records).
         reg.gauge("overlap.enabled").set(1.0 if overlap else 0.0)
         reg.gauge("overlap.accum_steps").set(accum_steps)
-        local_step += 1
-        if total > 0:
-            reg.gauge("step.per_sec").set(1.0 / total)
         if tokens_per_step:
             reg.counter("step.tokens").inc(int(tokens_per_step))
-            reg.gauge("step.tokens_per_sec").set(
-                tokens_per_step / total if total > 0 else 0.0
-            )
         if quantized and _obs.enabled() and local_step % 10 == 1:
             # First step, then every 10. Live EF health: a residual norm
             # that grows without bound means the quantizer is dropping
@@ -257,8 +293,10 @@ def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
             # gradient's dynamic range). This is an eager reduction over
             # the GLOBAL residual state (world x gradient-sized fp32),
             # so it is sampled every 10th step rather than paid on each
-            # one — and METRICS-plane-only (a trace-only run must not
-            # pay a real reduction for a gauge the null registry drops).
+            # one (it waits for this step, the one block the lagged
+            # stamps leave) — and METRICS-plane-only (a trace-only run
+            # must not pay a real reduction for a gauge the null
+            # registry drops).
             norm = ef_residual_norm(out[0].opt_state)
             if norm is not None:
                 reg.gauge("quant.residual_norm").set(norm)
@@ -276,14 +314,6 @@ def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
                 reg.gauge("fp8.cast_residual_norm").set(
                     g["fp8.cast_residual_norm"]
                 )
-        if flops_per_step and total > 0:
-            if peak is None:
-                peak = _flops.peak_tflops(jax.devices()[0])
-            # mfu() treats its first two args as (units/sec, flops/unit);
-            # with one step as the unit that's steps/sec × flops/step.
-            m = _flops.mfu(1.0 / total, flops_per_step, peak=peak)
-            if m is not None:
-                reg.gauge("step.mfu").set(m)
         _export.reporter().tick(step=local_step)
         return out
 
@@ -698,10 +728,18 @@ def make_train_step(
             **overlap_compiler_options(platform),
         } or None
 
-    def _step(state: TrainState, batch):
-        loss, aux, grads = accumulate_gradients(
-            loss_fn, state.params, batch, accum_steps, has_aux=has_aux
-        )
+    # The jitted function has a name of its own, so its builds are not
+    # mixed with anything else called ``_step`` (obs/build.py), and it
+    # names its phases: every operation of the compiled step lies in
+    # exactly one of ``hvd_grad`` (JAX marks forward against backward
+    # inside it: ``jvp(...)`` / ``transpose(jvp(...))``), ``hvd_reduce``
+    # and ``hvd_update`` (both opened inside ``opt.update``, which does
+    # both: optimizer.py) and ``hvd_loss_avg``.
+    def hvd_train_step(state: TrainState, batch):
+        with jax.named_scope("hvd_grad"):
+            loss, aux, grads = accumulate_gradients(
+                loss_fn, state.params, batch, accum_steps, has_aux=has_aux
+            )
         if guard_cfg is not None:
             # In-graph gradient guard: screen BEFORE anything commits.
             # The update (and its collectives) still executes
@@ -712,17 +750,20 @@ def make_train_step(
             # counter does not advance (the pipeline retries).
             from ..optimizer import guarded_commit
 
-            ok, _gnorm, new_guard = _guard_check(
-                grads, state.guard, guard_cfg, axis=axis
-            )
+            with jax.named_scope("hvd_grad"):
+                ok, _gnorm, new_guard = _guard_check(
+                    grads, state.guard, guard_cfg, axis=axis
+                )
             updates, new_opt = opt.update(
                 grads, state.opt_state, state.params
             )
-            cand = optax.apply_updates(state.params, updates)
-            params, opt_state = guarded_commit(
-                ok, cand, new_opt, state.params, state.opt_state
-            )
-            loss = allreduce(loss, op=Average, axis=axis)
+            with jax.named_scope("hvd_update"):
+                cand = optax.apply_updates(state.params, updates)
+                params, opt_state = guarded_commit(
+                    ok, cand, new_opt, state.params, state.opt_state
+                )
+            with jax.named_scope("hvd_loss_avg"):
+                loss = allreduce(loss, op=Average, axis=axis)
             new_state = TrainState(
                 params,
                 opt_state,
@@ -734,8 +775,10 @@ def make_train_step(
                 return new_state, loss, aux
             return new_state, loss
         updates, new_opt = opt.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        loss = allreduce(loss, op=Average, axis=axis)
+        with jax.named_scope("hvd_update"):
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("hvd_loss_avg"):
+            loss = allreduce(loss, op=Average, axis=axis)
         new_state = TrainState(
             params, new_opt, state.step + 1, state.extra, state.guard
         )
@@ -745,7 +788,7 @@ def make_train_step(
 
     def _seeded_for_trace(state):
         if guard_cfg is not None and state.guard is None:
-            # The on-demand lint surface traces _step directly, before
+            # The on-demand lint surface traces the step directly, before
             # the guard wrapper's first-call seeding has run — give the
             # trace the same seeded structure the wrapper would.
             from ..guard import fresh_state as _guard_fresh
@@ -882,7 +925,21 @@ def make_train_step(
     def _finish(step_fn, mapped_for, jitted_for):
         # Always wrapped: the wrapper itself checks enablement per call,
         # so obs.enable()/disable() after the step is built take effect.
-        fn = step_fn
+        from ..obs import trace as _trace
+
+        # Innermost: JAX's own dispatch of the jitted function, as a span
+        # and (on by default, one perf_counter pair) a histogram; what
+        # ``hvd.step.dispatch`` takes beyond it is this module's wrappers.
+        def jit_call(state, batch):
+            with _trace.span("hvd.step.jit", "train"):
+                t0 = time.perf_counter()
+                out = step_fn(state, batch)
+                _obs.always().histogram("step.jit_dispatch_ms").observe(
+                    (time.perf_counter() - t0) * 1e3
+                )
+            return out
+
+        fn = jit_call
         if lint_mode:
             from ..analysis import LintError
             from ..analysis import errors as _lint_errors
@@ -898,14 +955,15 @@ def make_train_step(
                 # again, not dispatch the broken program unlinted.
                 nonlocal linted
                 if not linted:
-                    findings = _lint_findings(state, batch, mapped_for)
+                    with _trace.span("hvd.step.lint", "train"):
+                        findings = _lint_findings(state, batch, mapped_for)
                     errs = _lint_errors(findings)
                     if lint_mode == "raise" and errs:
                         raise LintError(errs)
                     linted = True
                     for f in findings:
                         warnings.warn(f"hvdtpu lint: {f}", stacklevel=2)
-                return step_fn(state, batch)
+                return jit_call(state, batch)
 
             fn = checked
 
@@ -939,7 +997,8 @@ def make_train_step(
             # tag (tune.AutotunedStep) to avoid racing the pre-rebuild
             # KV entry.
             if not cert_latch["done"]:
-                _preflight(state, batch)
+                with _trace.span("hvd.step.preflight", "train"):
+                    _preflight(state, batch)
                 cert_latch["done"] = True
             return inner(state, batch)
 
@@ -1059,8 +1118,8 @@ def make_train_step(
     if not needs_state_specs:
         out_specs = (P(), P(), P()) if has_aux else (P(), P())
         mapped = jax.shard_map(
-            _step, mesh=m, in_specs=(P(), bspec), out_specs=out_specs,
-            check_vma=False,
+            hvd_train_step, mesh=m, in_specs=(P(), bspec),
+            out_specs=out_specs, check_vma=False,
         )
         jitted = jax.jit(
             mapped,
@@ -1088,7 +1147,7 @@ def make_train_step(
         )
         out_specs = (sspec, P(), P()) if has_aux else (sspec, P())
         return jax.shard_map(
-            _step,
+            hvd_train_step,
             mesh=m,
             in_specs=(sspec, bspec),
             out_specs=out_specs,

@@ -356,8 +356,8 @@ def test_goodput_trace_crosscheck(tmp_path, capsys):
     _write_trace(tdir / "trace_h.json", [
         ("step.device", 0, 3_000_000, {}),
         ("step.device", 4_000_000, 3_000_000, {}),
-        ("prefetch.fill", 0, 2_000_000, {"stalled": True}),
-        ("prefetch.fill", 3_000_000, 9_000_000, {"stalled": False}),
+        ("hvd.input.fill", 0, 2_000_000, {"stalled": True}),
+        ("hvd.input.fill", 3_000_000, 9_000_000, {"stalled": False}),
     ])
     assert tool.main(["--dir", str(mdir), "--trace", str(tdir),
                       "--json"]) == 0

@@ -1,0 +1,332 @@
+"""The step names itself: phase scopes and kernel names in the compiled
+program, the program's spans on the profiler's clock, the lagged stamps
+that replaced the blocking bracket, and the counters that are on by
+default (ISSUE 24)."""
+
+import ast
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from conftest import cpu_devices
+
+PHASE_SCOPES = ("hvd_grad", "hvd_reduce", "hvd_update", "hvd_loss_avg")
+
+
+def _loss(params, batch):
+    x, y = batch
+    return jnp.mean((x @ params["w"] - y) ** 2)
+
+
+def _state_and_batch(dp, opt):
+    state = dp.init_state({"w": jnp.ones((4, 2))}, opt)
+    return state, (jnp.ones((8, 4)), jnp.zeros((8, 2)))
+
+
+@pytest.fixture
+def world():
+    import horovod_tpu as hvd
+
+    hvd.init(devices=cpu_devices(8))
+    yield hvd
+    hvd.shutdown()
+
+
+@pytest.fixture
+def planes_off(monkeypatch):
+    """Every plane off, whatever the environment says."""
+    from horovod_tpu.obs import goodput, registry, trace
+
+    for var in ("HVDTPU_METRICS", "HVDTPU_TRACE", "HVDTPU_GOODPUT"):
+        monkeypatch.delenv(var, raising=False)
+    registry._enabled = None
+    trace._reset_for_tests()
+    assert not (registry.enabled() or trace.enabled() or goodput.enabled())
+    yield
+    registry._enabled = None
+    trace._reset_for_tests()
+
+
+@pytest.fixture
+def trace_on(tmp_path):
+    from horovod_tpu.obs import trace
+
+    trace._reset_for_tests()
+    rec = trace.enable(directory=str(tmp_path), capacity=256)
+    yield rec
+    trace._reset_for_tests()
+
+
+# ---- device side: scopes and kernel names --------------------------------
+
+
+@pytest.mark.parametrize(
+    "kwargs", [{}, {"sharded": True}, {"guard": True}],
+    ids=["replicated", "sharded", "guarded"],
+)
+def test_lowered_step_names_its_phases(world, kwargs):
+    from horovod_tpu.parallel import dp
+
+    step, opt = dp.make_train_step(_loss, optax.adamw(1e-2), **kwargs)
+    state, batch = _state_and_batch(dp, opt)
+    text = step.lower(state, batch).as_text(debug_info=True)
+    for scope in PHASE_SCOPES:
+        assert f'"{scope}' in text, scope
+    # the jitted function has a name of its own
+    assert "jit_hvd_train_step" in text or "jit(hvd_train_step)" in text
+
+
+def test_every_pallas_call_has_a_distinct_name():
+    path = os.path.join(
+        os.path.dirname(__file__), "..", "horovod_tpu", "ops",
+        "pallas_kernels.py",
+    )
+    names = []
+    for node in ast.walk(ast.parse(open(path).read())):
+        if (
+            isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == "pallas_call"
+        ):
+            given = [k.value for k in node.keywords if k.arg == "name"]
+            assert given and isinstance(given[0], ast.Constant), (
+                f"pallas_call at line {node.lineno} has no literal name="
+            )
+            names.append(given[0].value)
+    assert len(names) == 8
+    assert len(set(names)) == len(names), names
+    assert {"hvd_flash_fwd", "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"} <= set(
+        names
+    )
+
+
+def test_kernel_name_reaches_the_lowered_program(world):
+    """``pallas_call(name=)`` opens a scope of that name: the flash
+    kernels are told apart by label in the lowered text."""
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def f(q):
+        return flash_attention(q, q, q, causal=True).sum()
+
+    text = jax.jit(jax.grad(f)).lower(q).as_text(debug_info=True)
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"):
+        assert name in text, name
+
+
+# ---- host side: the spans on the profiler's clock ------------------------
+
+
+def test_profile_holds_the_programs_spans(world, planes_off, tmp_path):
+    """Three steps under ``jax.profiler.trace`` on the CPU, read back
+    with ``ProfileData``: ``hvd.step.dispatch`` contains ``hvd.step.jit``
+    and ``hvd.input.fill`` contains ``hvd.input.put``."""
+    from jax.profiler import ProfileData
+    from horovod_tpu.parallel import dp
+
+    hvd = world
+    step, opt = dp.make_train_step(_loss, optax.sgd(0.01))
+    state, batch = _state_and_batch(dp, opt)
+    host = jax.tree.map(np.asarray, batch)
+    batches = hvd.prefetch_to_device(host for _ in range(8))
+    for _ in range(2):  # compile outside the trace
+        state, loss = step(state, next(batches))
+    loss.block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            state, loss = step(state, next(batches))
+        loss.block_until_ready()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("hvd."):
+                    spans.setdefault(ev.name, []).append((
+                        ev.start_ns, ev.start_ns + ev.duration_ns,
+                        dict(ev.stats),
+                    ))
+    assert len(spans["hvd.step.dispatch"]) == 3
+    assert len(spans["hvd.step.jit"]) == 3
+    assert len(spans["hvd.input.put"]) == 3
+
+    def inside(inner, outer):
+        return all(
+            any(os_ <= s and e <= oe for os_, oe, _ in spans[outer])
+            for s, e, _ in spans[inner]
+        )
+
+    assert inside("hvd.step.jit", "hvd.step.dispatch")
+    assert inside("hvd.input.put", "hvd.input.fill")
+    # the step number rides the annotation as an argument
+    assert sorted(a["step"] for _, _, a in spans["hvd.step.dispatch"]) == [
+        2, 3, 4
+    ]
+    assert "hvd.step.sync" not in spans  # no plane on: nothing blocks
+
+
+# ---- the lagged stamps ---------------------------------------------------
+
+
+class _Recorded:
+    """Stands in for a device array: records who waited for it."""
+
+    def __init__(self, log, tag):
+        self.log, self.tag = log, tag
+
+    def block_until_ready(self):
+        self.log.append(self.tag)
+        return self
+
+
+def test_wrapper_blocks_only_on_the_previous_loss(trace_on):
+    """With a plane on the wrapper dispatches step i, then waits for step
+    i-1's loss: never for the state, never for the step it has just
+    dispatched. The ring's step events keep their names."""
+    from horovod_tpu.parallel import dp
+
+    log, calls = [], []
+
+    def fn(state, batch):
+        k = len(calls)
+        calls.append(k)
+        return _Recorded(log, f"state{k}"), _Recorded(log, f"loss{k}")
+
+    step = dp._instrument_step(fn, None, None)
+    waited_after_call = []
+    for _ in range(4):
+        step(None, None)
+        waited_after_call.append(list(log))
+    assert waited_after_call == [
+        [], ["loss0"], ["loss0", "loss1"], ["loss0", "loss1", "loss2"],
+    ]
+    names = [ev["name"] for ev in trace_on._ring]
+    for name in ("step", "step.host_dispatch", "step.device",
+                 "hvd.step.dispatch", "hvd.step.sync"):
+        assert name in names, name
+    steps = [ev for ev in trace_on._ring if ev["name"] == "step"]
+    assert [ev["args"]["step"] for ev in steps] == [0, 1, 2]  # N-1 booked
+    for ev in steps:  # the two slices tile the step
+        parts = [
+            e for e in trace_on._ring
+            if e["name"] in ("step.host_dispatch", "step.device")
+            and ev["ts"] <= e["ts"] <= ev["ts"] + ev["dur"]
+        ]
+        assert parts
+    assert trace_on.open_spans() == []
+
+
+def test_wrapper_off_path_blocks_on_nothing(planes_off):
+    from horovod_tpu.parallel import dp
+
+    log = []
+    step = dp._instrument_step(
+        lambda s, b: (_Recorded(log, "state"), _Recorded(log, "loss")),
+        None, None,
+    )
+    for _ in range(3):
+        step(None, None)
+    assert log == []
+
+
+# ---- counters that are on by default -------------------------------------
+
+
+def test_always_on_counters_with_every_plane_off(world, planes_off):
+    """``hvd.obs.snapshot()`` holds the step's builds, the jit dispatch
+    and the input put with no plane on. The second call sees the state the
+    first returned, mesh-sharded where the initial one sat on one device,
+    and lowers again (ROADMAP D1b): the counter says so."""
+    from horovod_tpu.parallel import dp
+
+    hvd = world
+
+    def lowerings():
+        return hvd.obs.snapshot()["counters"].get(
+            "build.lowerings.hvd_train_step", 0
+        )
+
+    def observed(name):
+        return hvd.obs.snapshot()["histograms"].get(name, {}).get("count", 0)
+
+    step, opt = dp.make_train_step(_loss, optax.sgd(0.01))
+    state, batch = _state_and_batch(dp, opt)
+    host = jax.tree.map(np.asarray, batch)
+    batches = hvd.prefetch_to_device(host for _ in range(4))
+    before, jit_before = lowerings(), observed("step.jit_dispatch_ms")
+    put_before = observed("input.put_ms")
+    stalled_before = hvd.obs.snapshot()["counters"].get("input.stalled", 0)
+    state, _ = step(state, next(batches))
+    assert lowerings() - before == 1
+    state, _ = step(state, next(batches))
+    assert lowerings() - before == 2
+    state, loss = step(state, next(batches))
+    assert lowerings() - before == 2
+    loss.block_until_ready()
+    snap = hvd.obs.snapshot()
+    assert observed("step.jit_dispatch_ms") - jit_before == 3
+    assert observed("input.put_ms") - put_before == 4  # depth 2 ahead
+    assert snap["counters"]["input.stalled"] - stalled_before == 1
+    assert snap["gauges"]["build.lower_s.hvd_train_step"] > 0
+    assert snap["counters"]["build.compiles.hvd_train_step"] >= 2
+    # the plane itself stayed off: nothing per-step was booked
+    assert "step.count" not in snap["counters"]
+
+
+def test_build_listener_keys_three_names_as_one():
+    from horovod_tpu.obs import build
+
+    assert (
+        build.function_key("hvd_train_step")
+        == build.function_key("jit(hvd_train_step)")
+        == build.function_key("jit_hvd_train_step")
+        == "hvd_train_step"
+    )
+
+
+def test_flight_ring_says_which_step_call_rebuilt(world, trace_on,
+                                                  monkeypatch):
+    """Each build is a ring span with its phase, its function and the
+    call number of the step wrapper it happened inside."""
+    from horovod_tpu.obs import build
+    from horovod_tpu.parallel import dp
+
+    monkeypatch.setattr(build, "RING_MIN_S", 0.0)
+    step, opt = dp.make_train_step(_loss, optax.sgd(0.01))
+    state, batch = _state_and_batch(dp, opt)
+    for _ in range(3):
+        state, loss = step(state, batch)
+    jax.block_until_ready(loss)
+    builds = [
+        ev["args"] for ev in trace_on._ring
+        if ev["name"] == "hvd.build" and ev["args"]["fn"] == "hvd_train_step"
+    ]
+    lowered_in = [a["step_call"] for a in builds if a["phase"] == "lower"]
+    assert lowered_in == [0, 1]  # the first call, and D1b's second
+    assert {a["phase"] for a in builds} == {"trace", "lower", "compile"}
+
+
+def test_prefetch_spans_close_before_the_yield(world, trace_on):
+    hvd = world
+    host = (np.ones((8, 4), np.float32), np.zeros((8, 2), np.float32))
+    batches = hvd.prefetch_to_device((host for _ in range(3)), depth=2)
+    next(batches)
+    assert trace_on.open_spans() == []
+    fills = [ev for ev in trace_on._ring if ev["name"] == "hvd.input.fill"]
+    puts = [ev for ev in trace_on._ring if ev["name"] == "hvd.input.put"]
+    assert len(fills) == 1 and len(puts) == 2
+    assert fills[0]["args"] == {"stalled": True, "occupancy": 0, "depth": 2}
+    for p in puts:
+        assert fills[0]["ts"] <= p["ts"]
+        assert p["ts"] + p["dur"] <= fills[0]["ts"] + fills[0]["dur"]
+    assert len(list(batches)) == 2  # as long as its input
+    later = [ev for ev in trace_on._ring if ev["name"] == "hvd.input.fill"]
+    assert [ev["args"]["stalled"] for ev in later] == [True, False, False]

@@ -10,6 +10,7 @@ on one clock-aligned timeline).
 import importlib.util
 import json
 import os
+import sys
 import threading
 import time
 
@@ -41,10 +42,14 @@ def trace_env(tmp_path):
 
 
 def test_disabled_by_default_and_truly_noop(monkeypatch):
-    """With HVDTPU_TRACE unset, every site is a no-op: span() returns
-    the one shared null context manager and the recorder object is
-    never even constructed — the strongest form of the trace-off
-    overhead guard (no allocation, no ring, nothing to pay)."""
+    """With HVDTPU_TRACE unset, no site touches the ring: span() opens
+    only its profiler-side sink (a ``jax.profiler.TraceAnnotation``,
+    inert without a profiler session; the shared null context manager in
+    a process that never imported JAX) and the recorder object is never
+    even constructed — the strongest form of the trace-off overhead
+    guard (no ring, nothing to pay)."""
+    import jax.profiler
+
     from horovod_tpu.obs import trace
 
     monkeypatch.delenv("HVDTPU_TRACE", raising=False)
@@ -53,9 +58,14 @@ def test_disabled_by_default_and_truly_noop(monkeypatch):
         assert not trace.enabled()
         s1 = trace.span("a", "train", step=1)
         s2 = trace.span("b", "serve")
-        assert s1 is s2 is trace._NULL_SPAN
+        assert isinstance(s1, jax.profiler.TraceAnnotation)
+        assert isinstance(s2, jax.profiler.TraceAnnotation)
         with s1:
             pass
+        with monkeypatch.context() as m:
+            m.setattr(trace, "_TraceAnnotation", None)
+            m.delitem(sys.modules, "jax")
+            assert trace.span("c", "driver") is trace._NULL_SPAN
         trace.instant("x", cat="chaos", args={"k": 1})
         trace.complete("y", "train", time.time(), 0.01)
         trace.clock_sync(123.0)

@@ -43,7 +43,7 @@ from horovod_tpu.obs.goodput import CATEGORIES, RUNBOOK_ROWS  # noqa: E402
 TRACE_SOURCES: Dict[str, Tuple[str, ...]] = {
     "compute": ("step.device", "serve.decode.round"),
     "host_dispatch": ("step.host_dispatch",),
-    "input_stall": ("prefetch.fill",),
+    "input_stall": ("hvd.input.fill",),
     "checkpoint": (),
     "rescale_downtime": ("elastic.join", "round.publish", "lease.expiry"),
 }
@@ -143,7 +143,7 @@ def trace_crosscheck(
         args = ev.get("args") or {}
         # A prefetch fill only fed the ledger when it stalled the
         # consumer (the span records both kinds; the arg disambiguates).
-        if name == "prefetch.fill" and not args.get("stalled"):
+        if name == "hvd.input.fill" and not args.get("stalled"):
             continue
         span_secs[name] = span_secs.get(name, 0.0) + float(
             ev.get("dur", 0)
