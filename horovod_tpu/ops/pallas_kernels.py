@@ -22,6 +22,8 @@ model in ``models/``.  This module provides it:
 Causality across ring steps needs *global* positions, so the kernel takes
 ``q_offset``/``kv_offset`` (traced scalars, prefetched to SMEM): block r
 of an ``sp``-sharded sequence holds global rows ``r*S .. (r+1)*S-1``.
+Causal calls do score work only under the diagonal, at the granularity of
+a compute tile finer than the copied block ("Causal tile geometry" below).
 
 Backward is a pair of Pallas kernels recomputing probabilities from the
 saved ``lse`` (the standard flash residual trick): exact, O(S) residual
@@ -36,7 +38,7 @@ CPU test mesh) the kernels run in Pallas interpret mode automatically.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..context import device_platform
+from ..obs import registry as _registry
 
 _SMEM = pltpu.SMEM
 _VMEM = pltpu.VMEM
@@ -63,6 +66,7 @@ __all__ = [
 ]
 
 _NEG_INF = float(np.finfo(np.float32).min)
+_LANES = 128
 
 
 def _round_up(x: int, m: int) -> int:
@@ -73,7 +77,8 @@ def _use_interpret() -> bool:
     return device_platform() != "tpu"
 
 
-def _head_group(h: int, block_q: int, block_k: int, d: int) -> int:
+def _head_group(h: int, block_q: int, block_k: int, d: int,
+                packed: bool) -> int:
     """Heads per program.  At short sequences a single head's two
     ``d``-thin matmuls underfill the MXU pipeline and per-program overhead
     (scalar DMAs, grid bookkeeping) dominates, so each program handles a
@@ -82,15 +87,187 @@ def _head_group(h: int, block_q: int, block_k: int, d: int) -> int:
     and the compiler stacks per-head fp32 score transients on top, so cap
     the estimated block working set at ~4 MB (g=12 at S=512, D=64
     measured 18.4 MB of scoped vmem — over the 16 MB limit) and divide
-    ``h`` evenly."""
-    for g in (12, 8, 6, 4, 3, 2):
-        if h % g:
-            continue
+    ``h`` evenly.  The blocks are the copied (DMA) blocks: a causal call
+    walks them in smaller compute tiles (:func:`_compute_tile`), which
+    shrinks the score transients but not what this budget counts.
+
+    In the packed layout a group is the lane axis of the copied block, and
+    Mosaic takes a block's minor dimension only as a multiple of the 128
+    lanes or as the whole array's: 3 heads of 64 (192 lanes) are refused,
+    2 and 6 of 6 are not.  Where no legal group fits the budget the
+    smallest legal one is taken, and the compiler has the last word."""
+    def legal(g):
+        return not packed or (g * d) % _LANES == 0 or g == h
+
+    groups = [g for g in (12, 8, 6, 4, 3, 2, 1) if h % g == 0 and legal(g)]
+    for g in groups:
         acc = g * block_q * d * 4
         blocks = 2 * g * (block_q + 2 * block_k + block_q) * d * 2
         if acc + blocks <= 4 << 20:
             return g
-    return 1
+    return groups[-1] if groups else h
+
+
+# ---------------------------------------------------------------------------
+# Causal tile geometry.  A causal call walks each copied [block_q, block_k]
+# score block in compute tiles of [tq, tk] and sorts them into three classes
+# by position alone:
+#   above the diagonal (every entry masked)       -> not visited (and a
+#                                                    block of only such
+#                                                    tiles is not fetched);
+#   below it (every entry valid, no padding)      -> the unmasked path, the
+#                                                    one non-causal calls run;
+#   straddling it (or holding padded columns, or
+#   padded q rows in the backward)                -> the masked path.
+# For one q tile the K/V tiles of a block come in that order — interior,
+# straddling, skipped — so two counts describe it (``_tile_spans``).  The q
+# tile then takes its visited tiles as one slab (``_for_causal_tiles``):
+# unmasked if the whole block is interior, else masked.  The offsets stay
+# traced scalars (ring attention passes ``rank * s``), so the class is a
+# branch on scalars inside the kernel.
+#
+# The copied K/V block is the kernel's to widen (``_resident_kv``): what a
+# q row pays per visit of a K/V block it pays once if the whole K/V is
+# resident, and a copied block wider than the compute tile costs no score
+# work because the classes are finer than it.
+# ---------------------------------------------------------------------------
+
+
+def _compute_tile(block: int, interpret: bool) -> int:
+    """Edge of the compute tile for a copied block of ``block`` rows (or
+    columns) of a causal call: half the block, halved again while it is
+    over 256, as far as the layout allows — a multiple of the 128 lanes
+    of a vreg when compiled (the tile's columns are the scores' lane axis
+    and its rows the lane axis of the backward's row statistics), of the
+    8 sublanes of one in the interpreter, where small test shapes must
+    still cross every class.  Measured on the v5e at s 1024, d 64
+    (PERF.md, PR 25): 128 loses what 256 gains, because the per-row
+    softmax bookkeeping is paid per q tile and does not shrink with it."""
+    floor = 8 if interpret else _LANES
+    tile = block
+    while tile % (2 * floor) == 0 and (tile == block or tile > 256):
+        tile //= 2
+    return tile
+
+
+_SLAB_TILES = 4
+
+
+def _resident_kv(block_k: int, tk: int, skv_pad: int) -> int:
+    """K/V rows a causal call copies per grid step, given the caller's
+    ``block_k``, its compute tile and the padded K/V length: as many whole
+    ``block_k`` blocks as ``_SLAB_TILES`` tiles hold and as divide the K/V
+    evenly (no more padding than the caller's block gives).  At s 1024,
+    d 64 that is all of K/V, 1024 rows in four tiles of 256 (measured on
+    the v5e, PERF.md PR 25: 2,602 us a layer against 3,050 at 512 rows).
+    The bound is in tiles because each slab width is a traced body of its
+    own and the widest slab's fp32 scores, ``[tq, 4 tk]``, are the
+    kernels' largest transient; the bytes are ``_head_group``'s to fit."""
+    blocks = skv_pad // block_k
+    for m in range(min(blocks, _SLAB_TILES * tk // block_k), 0, -1):
+        if blocks % m == 0:
+            return m * block_k
+    return block_k
+
+
+def _clip(x, lo, hi):
+    if isinstance(x, int):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
+def _tile_spans(row0, col0, cols_real, q_padded, *, tq: int, tk: int,
+                nkt: int):
+    """Classes of the ``nkt`` K/V compute tiles of one block against one
+    q tile, as ``(n_interior, n_visited)``: tiles ``[0, n_interior)`` hold
+    only valid entries, ``[n_interior, n_visited)`` straddle the diagonal
+    or hold padding, ``[n_visited, nkt)`` hold no valid entry.
+
+    ``row0``: global position of the q tile's first row; ``col0``: global
+    position of the block's first column; ``cols_real``: how many of the
+    block's columns lie before the K/V length (any integer); ``q_padded``:
+    the q tile holds padded rows whose ``lse`` is ``-inf`` (backward only).
+    Python ints give Python ints (the build-time counter and the tests);
+    traced scalars give traced scalars (the kernels)."""
+    span = nkt * tk
+    # tile j is visited iff its first column col0 + j*tk <= row0 + tq - 1
+    n_visited = _clip(row0 + tq - col0 + tk - 1, 0, span) // tk
+    # ... and interior iff its last column col0 + (j+1)*tk - 1 <= row0
+    below = _clip(row0 - col0 + 1, 0, span) // tk
+    unpadded = _clip(cols_real, 0, span) // tk
+    if isinstance(below, int) and isinstance(unpadded, int):
+        n_interior = 0 if q_padded else min(below, unpadded)
+    else:
+        n_interior = jnp.where(q_padded, 0, jnp.minimum(below, unpadded))
+    return n_interior, n_visited
+
+
+def _for_causal_tiles(tile, row0, col0, cols_real, rows_real, *,
+                      block_q: int, block_k: int, tq: int, tk: int):
+    """Inside a kernel: call ``tile(r, width, masked)`` once for every q
+    tile of one copied block that sees a valid entry of it: ``r`` the
+    tile's first row within the block (traced), ``width`` (static) how
+    many of the block's leading columns it visits, a whole number of
+    K/V tiles.  A q tile takes its visited columns as ONE slab: the
+    running max / sum / accumulator are read, rescaled and written once
+    per slab, and that per-row work, not the entries, is what narrow
+    tiles multiply.  The slab is unmasked when every tile of the block is
+    interior, else masked as a whole.  ``row0`` / ``col0``: global
+    positions of the block's first row / column; ``rows_real``: how many
+    of the block's rows are real (``None``: padded rows need no guard,
+    as in the forward).  ``tile`` is traced once per width and path.
+    Masking only the straddling tiles (the interior ones as an unmasked
+    slab, then each straddling tile) was measured in the two backward
+    kernels, which keep no per-row state, and lost 25% / 20% to the
+    second update per q tile (PERF.md, PR 25)."""
+    nkt = block_k // tk
+
+    def q_tile(i, carry):
+        r = i * tq
+        q_padded = False if rows_real is None else r + tq > rows_real
+        n_interior, n_visited = _tile_spans(
+            row0 + r, col0, cols_real, q_padded, tq=tq, tk=tk, nkt=nkt
+        )
+        pl.when(n_interior == nkt)(lambda: tile(r, block_k, False))
+        for w in range(1, nkt + 1):
+            pl.when(jnp.logical_and(n_visited == w, n_interior < nkt))(
+                functools.partial(tile, r, w * tk, True)
+            )
+        return carry
+
+    lax.fori_loop(0, block_q // tq, q_tile, 0)
+
+
+def _count_tiles(q_offset: int, kv_offset: int, *, sq: int, skv: int,
+                 sq_pad: int, skv_pad: int, block_q: int, block_k: int,
+                 tq: int, tk: int, guard_q_pad: bool):
+    """``(visited, masked, skipped)`` compute tiles of one batch element
+    and head, by the kernels' own rule (``_tile_spans``; a q tile's
+    visited tiles of one block run masked unless all are interior)."""
+    visited = masked = 0
+    for q0 in range(0, sq_pad, tq):
+        for k0 in range(0, skv_pad, block_k):
+            n_interior, n_visited = _tile_spans(
+                q_offset + q0, kv_offset + k0, skv - k0,
+                guard_q_pad and q0 + tq > sq,
+                tq=tq, tk=tk, nkt=block_k // tk,
+            )
+            visited += n_visited
+            if n_interior < block_k // tk:  # the slab is masked as a whole
+                masked += n_visited
+    return visited, masked, (sq_pad // tq) * (skv_pad // tk) - visited
+
+
+def _book_tiles(static_offsets, **geometry) -> None:
+    """Build-time counters of one causal ``pallas_call`` (always on, like
+    ``build.*``): what the tile geometry makes of it."""
+    reg = _registry.always()
+    if static_offsets is None:
+        reg.counter("flash.calls.dynamic_offsets").inc()
+        return
+    counts = _count_tiles(*static_offsets, **geometry)
+    for name, n in zip(("visited", "masked", "skipped"), counts):
+        reg.counter(f"flash.tiles.{name}").inc(n)
 
 
 # ---------------------------------------------------------------------------
@@ -98,16 +275,17 @@ def _head_group(h: int, block_q: int, block_k: int, d: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _head(ref, g, d, packed):
-    """Per-head block accessor.  ``packed=False``: heads on a leading
-    block dim (``ref[0, g]`` — page-select slice).  ``packed=True``:
-    heads packed in the minor (lane) axis of a ``[1, rows, G*d]`` block —
-    a static lane slice at ``g*d`` (Mosaic supports 64-aligned lane
-    slicing; probed on v5e), which lets q/k/v arrive in the projection's
-    native ``[B, S, H*D]`` layout with no relayout anywhere."""
+def _head(ref, g, d, packed, rows=slice(None)):
+    """Per-head block accessor, optionally of a row range only.
+    ``packed=False``: heads on a leading block dim (``ref[0, g]`` —
+    page-select slice).  ``packed=True``: heads packed in the minor
+    (lane) axis of a ``[1, rows, G*d]`` block — a static lane slice at
+    ``g*d`` (Mosaic supports 64-aligned lane slicing; probed on v5e),
+    which lets q/k/v arrive in the projection's native ``[B, S, H*D]``
+    layout with no relayout anywhere."""
     if packed:
-        return ref[0, :, g * d:(g + 1) * d]
-    return ref[0, g]
+        return ref[0, rows, g * d:(g + 1) * d]
+    return ref[0, g, rows, :]
 
 
 def _head_store(ref, g, d, packed, value):
@@ -115,6 +293,58 @@ def _head_store(ref, g, d, packed, value):
         ref[0, :, g * d:(g + 1) * d] = value
     else:
         ref[0, g] = value
+
+
+def _block_dims(q_ref, k_ref, packed: bool, d: int):
+    """``(group, block_q, block_k)`` of a kernel's q and K/V blocks."""
+    if packed:
+        return q_ref.shape[2] // d, q_ref.shape[1], k_ref.shape[1]
+    return q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+
+
+def _valid_mask(geom, row0, col0, tq: int, tk: int, causal: bool):
+    """[tq, tk] validity of the scores whose first row sits at global
+    position ``row0`` and whose first column is K/V column ``col0``."""
+    col = col0 + lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+    valid = col < geom[2]  # mask K/V padding
+    if causal:
+        q_pos = row0 + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        valid = jnp.logical_and(valid, q_pos >= geom[1] + col)
+    return valid
+
+
+def _drive_tiles(update, geom, qi, kj, *, q_len: Optional[int],
+                 block_q: int, block_k: int, causal: bool, masked: bool,
+                 tiles: Tuple[int, int]):
+    """Drive a kernel's ``update(rq, rk, valid)`` over one (q block, K/V
+    block) pair: ``rq`` / ``rk`` select the q / K/V rows, ``valid`` is
+    their validity mask or ``None`` on the unmasked path.  Causal: slab by
+    slab (``_for_causal_tiles``); else the whole block at once, masked only
+    if ``masked``.  ``geom``: the scalars ``(q_offset, kv_offset,
+    kv_len)``; ``q_len``: the real q length where padded q rows need the
+    masked path (the backward), else ``None``."""
+    row0 = geom[0] + qi * block_q  # global position of the block's row 0
+    if causal:
+        tq, _ = tiles
+
+        def tile(r, width, tile_masked):
+            valid = _valid_mask(
+                geom, row0 + r, kj * block_k, tq, width, True
+            ) if tile_masked else None
+            update(pl.ds(pl.multiple_of(r, tq), tq), slice(0, width), valid)
+
+        _for_causal_tiles(
+            tile, row0, geom[1] + kj * block_k,
+            geom[2] - kj * block_k,
+            None if q_len is None else q_len - qi * block_q,
+            block_q=block_q, block_k=block_k, tq=tq, tk=tiles[1],
+        )
+    else:
+        update(
+            slice(None), slice(None),
+            _valid_mask(geom, row0, kj * block_k, block_q, block_k, False)
+            if masked else None,
+        )
 
 
 def _fwd_kernel(
@@ -133,6 +363,7 @@ def _fwd_kernel(
     sm_scale: float,
     causal: bool,
     masked: bool,
+    tiles: Tuple[int, int],
     packed: bool = False,
     d: int = 0,
 ):
@@ -154,89 +385,73 @@ def _fwd_kernel(
     loop below is a static unroll.  Heads sit on a LEADING block dim
     (page-select slicing — Mosaic cannot relayout a middle-axis slice).
 
-    q_ref: [1, G, block_q, d]; k_ref/v_ref: [1, G, block_k, d];
-    o_ref: [1, G, block_q, d]; lse_ref: [1, G, 8, block_q] (8 = min
-    sublane tile; caller reads sublane 0).
-    """
-    q_off = qoff_ref[0, 0]
-    kv_off = kvoff_ref[0, 0]
-    kv_len = kvlen_ref[0, 0]
+    A causal call walks the block in ``tiles = (tq, tk)`` compute tiles
+    (see "Causal tile geometry"): tiles above the diagonal are not
+    visited, tiles below it run the unmasked update.  Any other call
+    updates the whole block at once, masked only if K/V is padded.
 
-    if packed:
-        group = q_ref.shape[2] // d
-        block_q = q_ref.shape[1]
-        block_k = k_ref.shape[1]
-    else:
-        group = q_ref.shape[1]
-        block_q = q_ref.shape[2]
-        block_k = k_ref.shape[2]
+    qoff_ref / kvoff_ref / kvlen_ref: SMEM int32 [1, 1]; q_ref:
+    [1, G, block_q, d]; k_ref/v_ref: [1, G, block_k, d]; o_ref:
+    [1, G, block_q, d]; lse_ref: [1, G, 8, block_q] (8 = min sublane
+    tile; caller reads sublane 0).
+    """
+    geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
+    group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
-
     @pl.when(kj == 0)
     def _init():
         acc_ref[:, :, :] = jnp.zeros_like(acc_ref)
         m_ref[:, :, :] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:, :, :] = jnp.zeros_like(l_ref)
 
-    # Causal speedup: skip K/V tiles entirely in this Q block's future.
-    q_max = q_off + (qi + 1) * block_q - 1
-    kv_min = kv_off + kj * block_k
-    run = (kv_min <= q_max) if causal else (kj >= 0)
-
-    @pl.when(run)
-    def _update():
-        # Geometry shared by every head in the group.  ``masked`` is
-        # static: non-causal, unpadded calls skip the validity-mask
-        # passes entirely — the kernel is VPU-bound at short S, so every
-        # elementwise pass over the [block_q, block_k] scores counts.
-        if masked:
-            q_pos = q_off + qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0
-            )
-            col = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1
-            )
-            valid = col < kv_len  # mask K/V padding
-            if causal:
-                valid = jnp.logical_and(valid, q_pos >= kv_off + col)
-
+    def update(rq, rk, valid):
+        """Online-softmax update of q rows ``rq`` with K/V rows ``rk``.
+        ``valid=None`` is the unmasked path: non-causal unpadded calls
+        and interior causal tiles skip the validity passes entirely — the
+        kernel is VPU-bound at short S, so every elementwise pass over
+        the scores counts."""
         for g in range(group):
             # Matmul inputs stay in their storage dtype (bf16 on TPU):
             # the MXU is native bf16xbf16->fp32; upcasting to fp32 first
             # costs ~4-6 MXU passes per dot (measured 15% kernel
             # efficiency before this).  Softmax statistics are fp32.
             s = jax.lax.dot_general(
-                _head(q_ref, g, d, packed),
-                _head(k_ref, g, d, packed),
+                _head(q_ref, g, d, packed, rq),
+                _head(k_ref, g, d, packed, rk),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * sm_scale  # [block_q, block_k] fp32
-            if masked:
+            ) * sm_scale  # [rows, cols] fp32
+            if valid is not None:
                 s = jnp.where(valid, s, _NEG_INF)
 
-            m = m_ref[g, :, :]
-            l = l_ref[g, :, :]
+            m = m_ref[g, rq, :]
+            l = l_ref[g, rq, :]
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             # m_new == NEG_INF only for rows with no valid column so far;
             # keep exponent args finite there (p is zeroed by the mask).
-            m_safe = jnp.maximum(m_new, _NEG_INF / 2) if masked else m_new
+            m_safe = m_new if valid is None else jnp.maximum(
+                m_new, _NEG_INF / 2
+            )
             p = jnp.exp(s - m_safe)
-            if masked:
+            if valid is not None:
                 p = jnp.where(valid, p, 0.0)
             corr = jnp.exp(m - m_safe)
-            l_ref[g, :, :] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            m_ref[g, :, :] = m_new
+            l_ref[g, rq, :] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[g, rq, :] = m_new
             # p in the V dtype for a native-MXU dot (fp32 accumulate
             # keeps the reduction exact; the p rounding is the standard
             # flash trade).
-            acc_ref[g, :, :] = acc_ref[g, :, :] * corr + jax.lax.dot_general(
+            acc_ref[g, rq, :] = acc_ref[g, rq, :] * corr + jax.lax.dot_general(
                 p.astype(v_ref.dtype),
-                _head(v_ref, g, d, packed),
+                _head(v_ref, g, d, packed, rk),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+
+    _drive_tiles(update, geom, qi, kj, q_len=None, block_q=block_q,
+                 block_k=block_k, causal=causal, masked=masked, tiles=tiles)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -261,6 +476,116 @@ def _fwd_kernel(
             )
 
 
+class _Plan(NamedTuple):
+    """Shapes and tiling of one flash call, shared by its three kernels."""
+
+    packed: bool
+    b: int
+    h: int
+    d: int
+    sq: int
+    skv: int
+    block_q: int
+    block_k: int
+    sq_pad: int
+    skv_pad: int
+    group: int
+    tiles: Tuple[int, int]  # compute tile; the whole block unless causal
+    interpret: bool
+
+    def pad_seq(self, x, s: int, s_pad: int):
+        if s_pad != s:
+            pads = [(0, 0)] * x.ndim
+            pads[1 if self.packed else 2] = (0, s_pad - s)
+            x = jnp.pad(x, pads)
+        return x
+
+    def tile_geometry(self, guard_q_pad: bool) -> dict:
+        tq, tk = self.tiles
+        return dict(
+            sq=self.sq, skv=self.skv, sq_pad=self.sq_pad,
+            skv_pad=self.skv_pad, block_q=self.block_q,
+            block_k=self.block_k, tq=tq, tk=tk, guard_q_pad=guard_q_pad,
+        )
+
+
+def _plan(q, k, *, causal: bool, block_q: int, block_k: int,
+          interpret: Optional[bool], n_heads: int) -> _Plan:
+    packed = n_heads > 0
+    if packed:
+        b, sq, hd = q.shape
+        h = n_heads
+        d = hd // h
+        skv = k.shape[1]
+    else:
+        b, h, sq, d = q.shape
+        skv = k.shape[2]
+    if interpret is None:
+        interpret = _use_interpret()
+    block_q = min(block_q, _round_up(sq, 8))
+    block_k = min(block_k, _round_up(skv, 8))
+    skv_pad = _round_up(skv, block_k)
+    tiles = (block_q, block_k)
+    if causal:
+        tiles = (_compute_tile(block_q, interpret),
+                 _compute_tile(block_k, interpret))
+        block_k = _resident_kv(block_k, tiles[1], skv_pad)
+    return _Plan(
+        packed, b, h, d, sq, skv, block_q, block_k,
+        _round_up(sq, block_q), skv_pad,
+        _head_group(h, block_q, block_k, d, packed), tiles, interpret,
+    )
+
+
+def _geometry(q_offset, kv_offset, skv: int):
+    """The kernels' three scalar operands: int32 ``[1, 1]`` each,
+    ``q_offset``, ``kv_offset``, ``kv_len`` (XLA hands a ``[1, 1]``
+    constant to the kernel as it is; a longer vector costs a copy per
+    call)."""
+    return [
+        jnp.asarray(x, jnp.int32).reshape(1, 1)
+        for x in (q_offset, kv_offset, skv)
+    ]
+
+
+def _last_kv_block(qi, geom, p: _Plan):
+    """Index of the last K/V block that q block ``qi`` of a causal call
+    can see (``geom``: the scalar-prefetch refs, as index maps get them).  Clamping a skipped step's K/V index map to it repeats the
+    previous step's block, which the pipeline does not fetch again."""
+    seen = geom[0][0, 0] + (qi + 1) * p.block_q - 1 - geom[1][0, 0]
+    return jnp.clip(seen, 0, p.skv_pad - 1) // p.block_k
+
+
+def _first_q_block(kj, geom, p: _Plan):
+    """Index of the first q block that sees K/V block ``kj`` of a causal
+    call (the dK/dV kernel's skipped steps come first)."""
+    before = geom[1][0, 0] + kj * p.block_k - geom[0][0, 0]
+    return jnp.clip(before, 0, p.sq_pad - 1) // p.block_q
+
+
+def _vspec(shape, index_map):
+    return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
+
+
+def _grid_spec(causal: bool, *, grid, in_specs, out_specs, scratch_shapes):
+    """Grid spec of a flash kernel whose first three operands are
+    ``_geometry``'s.  Causal: scalar prefetch, so the index maps can read
+    the offsets and clamp a skipped step to a block already there (they
+    get the three refs as last arguments).  Else SMEM inputs like any
+    other: the index maps need nothing of them, and the compiled step
+    stays what it was before the kernels knew tiles."""
+    if causal:
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes,
+        )
+    scalar = pl.BlockSpec((1, 1), lambda *_: (0, 0), memory_space=_SMEM)
+    return pl.GridSpec(
+        grid=grid, in_specs=[scalar] * 3 + list(in_specs),
+        out_specs=out_specs, scratch_shapes=scratch_shapes,
+    )
+
+
 def _fwd_pallas(
     q,
     k,
@@ -274,6 +599,7 @@ def _fwd_pallas(
     block_k: int,
     interpret: Optional[bool],
     n_heads: int = 0,
+    static_offsets: Optional[Tuple[int, int]] = None,
 ):
     """Run the kernel.
 
@@ -288,91 +614,91 @@ def _fwd_pallas(
     the r4 head-major path still paid the ``[B,S,H·D]→[B,H,S,D]``
     transpose by letting XLA fold it into the projection dots, which then
     ran at ~43%% of peak (``docs/perf_analysis_bert_r04.md``).
+
+    ``static_offsets``: the two offsets where the caller gave Python
+    ints, for the build-time tile counters only.
     """
-    packed = n_heads > 0
-    if packed:
-        b, sq, hd = q.shape
-        h = n_heads
-        d = hd // h
-        skv = k.shape[1]
-    else:
-        b, h, sq, d = q.shape
-        skv = k.shape[2]
-    if interpret is None:
-        interpret = _use_interpret()
-
-    block_q = min(block_q, _round_up(sq, 8))
-    block_k = min(block_k, _round_up(skv, 8))
-    sq_pad = _round_up(sq, block_q)
-    skv_pad = _round_up(skv, block_k)
-
-    seq_axis = 1 if packed else 2
-
-    def pad_seq(x, s, s_pad):
-        if s_pad != s:
-            pads = [(0, 0)] * x.ndim
-            pads[seq_axis] = (0, s_pad - s)
-            x = jnp.pad(x, pads)
-        return x
-
-    qr = pad_seq(q, sq, sq_pad)
-    kr = pad_seq(k, skv, skv_pad)
-    vr = pad_seq(v, skv, skv_pad)
-    scalars = [
-        jnp.asarray(x, jnp.int32).reshape(1, 1)
-        for x in (q_offset, kv_offset, skv)
-    ]
-
-    group = _head_group(h, block_q, block_k, d)
-    grid = (b, h // group, sq_pad // block_q, skv_pad // block_k)
-    smem_spec = pl.BlockSpec(
-        (1, 1), lambda bi, hi, qi, kj: (0, 0), memory_space=_SMEM
+    p = _plan(q, k, causal=causal, block_q=block_q, block_k=block_k,
+              interpret=interpret, n_heads=n_heads)
+    if causal:
+        _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
+    return _flash_fwd_call(
+        q, k, v, _geometry(q_offset, kv_offset, p.skv),
+        p=p, sm_scale=sm_scale, causal=causal,
     )
 
-    def vspec(shape, index_map):
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
 
-    scratch = [
-        _VMEM((group, block_q, d), jnp.float32),
-        _VMEM((group, block_q, 1), jnp.float32),
-        _VMEM((group, block_q, 1), jnp.float32),
-    ]
+# The two calls below are jitted so that a model's layers share one trace
+# and one lowering of each kernel: JAX caches neither for a bare
+# ``pallas_call``, and twelve layers would trace and lower the head-
+# unrolled bodies 36 times (XLA inlines the calls; the compiled step is
+# the same).
+@functools.partial(
+    jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
+)
+def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
+                    causal: bool):
+    b, h, d, group = p.b, p.h, p.d, p.group
+    block_q, block_k, sq_pad, skv_pad = (
+        p.block_q, p.block_k, p.sq_pad, p.skv_pad
+    )
+    qr = p.pad_seq(q, p.sq, sq_pad)
+    kr = p.pad_seq(k, p.skv, skv_pad)
+    vr = p.pad_seq(v, p.skv, skv_pad)
 
-    if packed:
-        q_spec = vspec(
-            (1, block_q, group * d), lambda bi, hi, qi, kj: (bi, qi, hi)
+    def kv_block(qi, kj, geom):
+        if not causal:
+            return kj
+        return jnp.minimum(kj, _last_kv_block(qi, geom, p))
+
+    if p.packed:
+        q_spec = _vspec(
+            (1, block_q, group * d), lambda bi, hi, qi, kj, *geom: (bi, qi, hi)
         )
-        kv_spec = vspec(
-            (1, block_k, group * d), lambda bi, hi, qi, kj: (bi, kj, hi)
+        kv_spec = _vspec(
+            (1, block_k, group * d),
+            lambda bi, hi, qi, kj, *geom: (bi, kv_block(qi, kj, geom), hi),
         )
-        o_spec = q_spec
         o_shape = jax.ShapeDtypeStruct((b, sq_pad, h * d), q.dtype)
     else:
-        q_spec = vspec(
-            (1, group, block_q, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)
+        q_spec = _vspec(
+            (1, group, block_q, d),
+            lambda bi, hi, qi, kj, *geom: (bi, hi, qi, 0),
         )
-        kv_spec = vspec(
-            (1, group, block_k, d), lambda bi, hi, qi, kj: (bi, hi, kj, 0)
+        kv_spec = _vspec(
+            (1, group, block_k, d),
+            lambda bi, hi, qi, kj, *geom: (
+                bi, hi, kv_block(qi, kj, geom), 0),
         )
-        o_spec = q_spec
         o_shape = jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype)
 
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, causal=causal,
-            masked=causal or skv_pad != skv, packed=packed, d=d,
+            masked=causal or skv_pad != p.skv, tiles=p.tiles,
+            packed=p.packed, d=d,
         ),
-        grid=grid,
-        in_specs=[smem_spec, smem_spec, smem_spec, q_spec, kv_spec, kv_spec],
-        out_specs=[
-            o_spec,
-            vspec((1, group, 8, block_q), lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
-        ],
+        grid_spec=_grid_spec(
+            causal,
+            grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[
+                q_spec,
+                _vspec(
+                    (1, group, 8, block_q),
+                    lambda bi, hi, qi, kj, *geom: (bi, hi, 0, qi),
+                ),
+            ],
+            scratch_shapes=[
+                _VMEM((group, block_q, d), jnp.float32),
+                _VMEM((group, block_q, 1), jnp.float32),
+                _VMEM((group, block_q, 1), jnp.float32),
+            ],
+        ),
         out_shape=[
             o_shape,
             jax.ShapeDtypeStruct((b, h, 8, sq_pad), jnp.float32),
         ],
-        scratch_shapes=scratch,
         # batch/head/qi programs are independent; only the K/V stream (kj)
         # carries state — lets Mosaic parallelize/pipeline the outer grid.
         compiler_params=pltpu.CompilerParams(
@@ -384,15 +710,15 @@ def _fwd_pallas(
             + b * h * sq_pad * d * qr.dtype.itemsize,
             transcendentals=b * h * sq_pad * skv_pad,
         ),
-        interpret=interpret,
+        interpret=p.interpret,
         name="hvd_flash_fwd",
-    )(*scalars, qr, kr, vr)
+    )(*geom, qr, kr, vr)
 
-    if packed:
-        out = out[:, :sq]  # [B,Sq,H*D]
+    if p.packed:
+        out = out[:, :p.sq]  # [B,Sq,H*D]
     else:
-        out = out[:, :, :sq]  # [B,H,Sq,D]
-    lse = lse[:, :, 0, :sq]  # [B,H,Sq]
+        out = out[:, :, :p.sq]  # [B,H,Sq,D]
+    lse = lse[:, :, 0, :p.sq]  # [B,H,Sq]
     return out, lse
 
 
@@ -404,46 +730,40 @@ def _fwd_pallas(
 #     p  = exp(s - lse)           (masked)
 #     ds = p ⊙ (dP − Δ) + g_lse ⊙ p,   Δ = rowsum(g ⊙ out)
 #     dq = ds·K·scale, dk = dsᵀ·Q·scale, dv = pᵀ·g
+# Causal calls walk each block pair in the forward's compute tiles and
+# classes; both kernels take a q tile and loop over its K/V tiles (the
+# dk/dv accumulators do not care in which order their tiles are met).
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p_ds(qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref,
-                    glse_ref, q_ref, k_ref, v_ref, g_ref, qi, kj, g, *,
-                    sm_scale: float, causal: bool, masked: bool,
+def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
+                    g_ref, g, rq, rk, valid, *, sm_scale: float,
                     packed: bool = False, d: int = 0):
-    """Shared per-(q-block, k-tile, head) recompute: returns
-    (p, ds, q_blk, g_blk).
+    """Shared per-(q rows ``rq``, K/V rows ``rk``, head) recompute:
+    returns (p, ds, q_blk, g_blk, k_blk).  ``valid=None`` is the unmasked
+    path.
 
     Padded / fully-masked Q rows carry ``lse == -inf`` and zero ``g``;
-    ``row_ok`` zeroes their ``p`` so they contribute nothing.
+    ``row_ok`` zeroes their ``p`` so they contribute nothing (a tile that
+    holds such rows is never classed interior).
     """
-    block_q = q_ref.shape[1] if packed else q_ref.shape[2]
-    block_k = k_ref.shape[1] if packed else k_ref.shape[2]
     # Storage-dtype (bf16) matmul inputs with fp32 accumulation — see the
     # forward kernel note; only the softmax/ds algebra runs in fp32.
-    q_blk = _head(q_ref, g, d, packed)
-    g_blk = _head(g_ref, g, d, packed)
-    k_blk = _head(k_ref, g, d, packed)
-    v_blk = _head(v_ref, g, d, packed)
+    q_blk = _head(q_ref, g, d, packed, rq)
+    g_blk = _head(g_ref, g, d, packed, rq)
+    k_blk = _head(k_ref, g, d, packed, rk)
+    v_blk = _head(v_ref, g, d, packed, rk)
+    rows = q_blk.shape[0]
 
     s = jax.lax.dot_general(
         q_blk,
         k_blk,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * sm_scale  # [block_q, block_k] fp32
+    ) * sm_scale  # [rows, cols] fp32
 
-    lse_row = lse_ref[0, g, 0, :].reshape(block_q, 1)
-    if masked:
-        col = kj * block_k + lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1
-        )
-        valid = col < kvlen_ref[0, 0]
-        if causal:
-            q_pos = qoff_ref[0, 0] + qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0
-            )
-            valid = jnp.logical_and(valid, q_pos >= kvoff_ref[0, 0] + col)
+    lse_row = lse_ref[0, g, 0, rq].reshape(rows, 1)
+    if valid is not None:
         row_ok = lse_row > _NEG_INF / 4  # -inf rows: no valid keys anywhere
         lse_safe = jnp.where(row_ok, lse_row, 0.0)
         p = jnp.where(
@@ -458,61 +778,50 @@ def _recompute_p_ds(qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    delta_row = delta_ref[0, g, 0, :].reshape(block_q, 1)
-    glse_row = glse_ref[0, g, 0, :].reshape(block_q, 1)
+    delta_row = delta_ref[0, g, 0, rq].reshape(rows, 1)
+    glse_row = glse_ref[0, g, 0, rq].reshape(rows, 1)
     ds = p * (dp - delta_row) + glse_row * p
-    return p, ds, q_blk, g_blk
+    return p, ds, q_blk, g_blk, k_blk
 
 
 def _bwd_kernel_dkdv(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
     q_ref, k_ref, v_ref, g_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-    *, sm_scale: float, causal: bool, masked: bool,
-    packed: bool = False, d: int = 0,
+    *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
+    q_len: int, packed: bool = False, d: int = 0,
 ):
     """grid (b, h-group, kj, qi): each K tile accumulates over streamed
     Q blocks; the per-head loop is a static unroll (see forward)."""
     qi = pl.program_id(3)
     kj = pl.program_id(2)
     nq = pl.num_programs(3)
-    if packed:
-        group = q_ref.shape[2] // d
-        block_q = q_ref.shape[1]
-        block_k = k_ref.shape[1]
-    else:
-        group = q_ref.shape[1]
-        block_q = q_ref.shape[2]
-        block_k = k_ref.shape[2]
+    geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
+    group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:, :, :] = jnp.zeros_like(dk_acc)
         dv_acc[:, :, :] = jnp.zeros_like(dv_acc)
 
-    # Causal: Q blocks entirely before this K tile contribute nothing.
-    q_max = qoff_ref[0, 0] + (qi + 1) * block_q - 1
-    kv_min = kvoff_ref[0, 0] + kj * block_k
-    run = (kv_min <= q_max) if causal else (qi >= 0)
-
-    @pl.when(run)
-    def _update():
+    def update(rq, rk, valid):
         for g in range(group):
-            p, ds, q_blk, g_blk = _recompute_p_ds(
-                qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref,
-                glse_ref, q_ref, k_ref, v_ref, g_ref, qi, kj, g,
-                sm_scale=sm_scale, causal=causal, masked=masked,
-                packed=packed, d=d,
+            p, ds, q_blk, g_blk, _ = _recompute_p_ds(
+                lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
+                g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
             )
-            dv_acc[g, :, :] = dv_acc[g, :, :] + jax.lax.dot_general(
+            dv_acc[g, rk, :] = dv_acc[g, rk, :] + jax.lax.dot_general(
                 p.astype(g_blk.dtype), g_blk,
                 dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dk_acc[g, :, :] = dk_acc[g, :, :] + jax.lax.dot_general(
+            dk_acc[g, rk, :] = dk_acc[g, rk, :] + jax.lax.dot_general(
                 ds.astype(q_blk.dtype), q_blk,
                 dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale
+
+    _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
+                 block_k=block_k, causal=causal, masked=masked, tiles=tiles)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -528,46 +837,35 @@ def _bwd_kernel_dkdv(
 def _bwd_kernel_dq(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
     q_ref, k_ref, v_ref, g_ref, dq_ref, dq_acc,
-    *, sm_scale: float, causal: bool, masked: bool,
-    packed: bool = False, d: int = 0,
+    *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
+    q_len: int, packed: bool = False, d: int = 0,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles; the per-head loop is a static unroll (see forward)."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
-    if packed:
-        group = q_ref.shape[2] // d
-        block_q = q_ref.shape[1]
-        block_k = k_ref.shape[1]
-    else:
-        group = q_ref.shape[1]
-        block_q = q_ref.shape[2]
-        block_k = k_ref.shape[2]
+    geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
+    group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
 
     @pl.when(kj == 0)
     def _init():
         dq_acc[:, :, :] = jnp.zeros_like(dq_acc)
 
-    q_max = qoff_ref[0, 0] + (qi + 1) * block_q - 1
-    kv_min = kvoff_ref[0, 0] + kj * block_k
-    run = (kv_min <= q_max) if causal else (kj >= 0)
-
-    @pl.when(run)
-    def _update():
+    def update(rq, rk, valid):
         for g in range(group):
-            _, ds, _, _ = _recompute_p_ds(
-                qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref,
-                glse_ref, q_ref, k_ref, v_ref, g_ref, qi, kj, g,
-                sm_scale=sm_scale, causal=causal, masked=masked,
-                packed=packed, d=d,
+            _, ds, _, _, k_blk = _recompute_p_ds(
+                lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
+                g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
             )
-            k_blk = _head(k_ref, g, d, packed)
-            dq_acc[g, :, :] = dq_acc[g, :, :] + jax.lax.dot_general(
+            dq_acc[g, rq, :] = dq_acc[g, rq, :] + jax.lax.dot_general(
                 ds.astype(k_blk.dtype), k_blk,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale
+
+    _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
+                 block_k=block_k, causal=causal, masked=masked, tiles=tiles)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -581,36 +879,33 @@ def _bwd_pallas(
     q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, *,
     sm_scale: float, causal: bool, block_q: int, block_k: int,
     interpret: Optional[bool], n_heads: int = 0,
+    static_offsets: Optional[Tuple[int, int]] = None,
 ):
-    packed = n_heads > 0
-    if packed:
-        b, sq, hd = q.shape
-        h = n_heads
-        d = hd // h
-        skv = k.shape[1]
-    else:
-        b, h, sq, d = q.shape
-        skv = k.shape[2]
-    if interpret is None:
-        interpret = _use_interpret()
-    block_q = min(block_q, _round_up(sq, 8))
-    block_k = min(block_k, _round_up(skv, 8))
-    sq_pad = _round_up(sq, block_q)
-    skv_pad = _round_up(skv, block_k)
+    p = _plan(q, k, causal=causal, block_q=block_q, block_k=block_k,
+              interpret=interpret, n_heads=n_heads)
+    if causal:
+        # one count for each of the two kernels
+        for _ in range(2):
+            _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=True))
+    return _flash_bwd_call(
+        q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
+        g_lse, p=p, sm_scale=sm_scale, causal=causal,
+    )
 
-    seq_axis = 1 if packed else 2
 
-    def pad_seq(x, s, s_pad):
-        if s_pad != s:
-            pads = [(0, 0)] * x.ndim
-            pads[seq_axis] = (0, s_pad - s)
-            x = jnp.pad(x, pads)
-        return x
-
-    qr = pad_seq(q, sq, sq_pad)
-    kr = pad_seq(k, skv, skv_pad)
-    vr = pad_seq(v, skv, skv_pad)
-    gr = pad_seq(g_out.astype(q.dtype), sq, sq_pad)
+@functools.partial(
+    jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
+)
+def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
+                    sm_scale: float, causal: bool):
+    b, h, d, group, sq, skv = p.b, p.h, p.d, p.group, p.sq, p.skv
+    block_q, block_k, sq_pad, skv_pad = (
+        p.block_q, p.block_k, p.sq_pad, p.skv_pad
+    )
+    qr = p.pad_seq(q, sq, sq_pad)
+    kr = p.pad_seq(k, skv, skv_pad)
+    vr = p.pad_seq(v, skv, skv_pad)
+    gr = p.pad_seq(g_out.astype(q.dtype), sq, sq_pad)
 
     # Row statistics in the kernel's [b, h, 8, sq_pad] layout (8 = min
     # sublane tile; kernels read sublane 0).
@@ -621,7 +916,7 @@ def _bwd_pallas(
                         constant_values=pad_value)
         return jnp.broadcast_to(x[:, :, None, :], (b, h, 8, sq_pad))
 
-    if packed:
+    if p.packed:
         # [B,S,H*D] → per-head row dot via a free reshape (no transpose).
         delta = jnp.einsum(
             "bqhd,bqhd->bhq",
@@ -639,115 +934,98 @@ def _bwd_pallas(
     glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
     glse_rows = rows(glse.astype(jnp.float32), 0.0)
 
-    scalars = [
-        jnp.asarray(x, jnp.int32).reshape(1, 1)
-        for x in (q_offset, kv_offset, skv)
-    ]
-
-    smem_spec = pl.BlockSpec(
-        (1, 1), lambda *_: (0, 0),
-        memory_space=_SMEM,
+    kernel_params = dict(
+        sm_scale=sm_scale, causal=causal,
+        masked=causal or skv_pad != skv or sq_pad != sq,
+        tiles=p.tiles, q_len=sq, packed=p.packed, d=d,
     )
-
-    def vspec(shape, index_map):
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-
-    group = _head_group(h, block_q, block_k, d)
-    common_params = dict(
+    call_params = dict(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),
-        interpret=interpret,
+        interpret=p.interpret,
     )
+    def specs(order):
+        """(row-statistics, q-side, K/V-side) block specs for a grid
+        whose last two axes are ``order``: "kq" (dK/dV: q streams
+        innermost, its skipped steps clamped to the first q block
+        needed) or "qk" (dQ: K/V streams innermost, clamped to the last
+        K/V block needed)."""
 
-    def q_spec(index_map_qi):
-        if packed:
-            return vspec((1, block_q, group * d), index_map_qi)
-        return vspec((1, group, block_q, d), index_map_qi)
+        def blocks(i, j, geom):
+            qi, kj = (j, i) if order == "kq" else (i, j)
+            if causal and order == "kq":
+                qi = jnp.maximum(qi, _first_q_block(kj, geom, p))
+            elif causal:
+                kj = jnp.minimum(kj, _last_kv_block(qi, geom, p))
+            return qi, kj
 
-    def kv_spec(index_map_kj):
-        if packed:
-            return vspec((1, block_k, group * d), index_map_kj)
-        return vspec((1, group, block_k, d), index_map_kj)
+        def q_map(bi, hi, i, j, *geom):
+            qi, _ = blocks(i, j, geom)
+            return (bi, qi, hi) if p.packed else (bi, hi, qi, 0)
 
-    if packed:
-        # [B, S, H*D] packed blocks: seq index first, head index last.
-        qmap_kv_grid = lambda bi, hi, kj, qi: (bi, qi, hi)  # noqa: E731
-        kmap_kv_grid = lambda bi, hi, kj, qi: (bi, kj, hi)  # noqa: E731
-        qmap_q_grid = lambda bi, hi, qi, kj: (bi, qi, hi)  # noqa: E731
-        kmap_q_grid = lambda bi, hi, qi, kj: (bi, kj, hi)  # noqa: E731
-        dkv_shape = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
-            (b, skv_pad, h * d), x.dtype
+        def kv_map(bi, hi, i, j, *geom):
+            _, kj = blocks(i, j, geom)
+            return (bi, kj, hi) if p.packed else (bi, hi, kj, 0)
+
+        def stat_map(bi, hi, i, j, *geom):
+            qi, _ = blocks(i, j, geom)
+            return (bi, hi, 0, qi)
+
+        q_shape = (1, block_q, group * d) if p.packed else (
+            1, group, block_q, d)
+        kv_shape = (1, block_k, group * d) if p.packed else (
+            1, group, block_k, d)
+        return (
+            _vspec((1, group, 8, block_q), stat_map),
+            _vspec(q_shape, q_map),
+            _vspec(kv_shape, kv_map),
         )
-        dq_shape = jax.ShapeDtypeStruct((b, sq_pad, h * d), q.dtype)
-    else:
-        qmap_kv_grid = lambda bi, hi, kj, qi: (bi, hi, qi, 0)  # noqa: E731
-        kmap_kv_grid = lambda bi, hi, kj, qi: (bi, hi, kj, 0)  # noqa: E731
-        qmap_q_grid = lambda bi, hi, qi, kj: (bi, hi, qi, 0)  # noqa: E731
-        kmap_q_grid = lambda bi, hi, qi, kj: (bi, hi, kj, 0)  # noqa: E731
-        dkv_shape = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
-            (b, h, skv_pad, d), x.dtype
+
+    def shape_like(x, s_pad):
+        return jax.ShapeDtypeStruct(
+            (b, s_pad, h * d) if p.packed else (b, h, s_pad, d), x.dtype
         )
-        dq_shape = jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype)
 
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
+    stat_spec, q_spec, kv_spec = specs("kq")
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel_dkdv, sm_scale=sm_scale, causal=causal,
-            masked=causal or skv_pad != skv or sq_pad != sq,
-            packed=packed, d=d,
+        functools.partial(_bwd_kernel_dkdv, **kernel_params),
+        grid_spec=_grid_spec(
+            causal,
+            grid=(b, h // group, skv_pad // block_k, sq_pad // block_q),
+            in_specs=[stat_spec, stat_spec, stat_spec,
+                      q_spec, kv_spec, kv_spec, q_spec],
+            out_specs=[kv_spec, kv_spec],
+            scratch_shapes=[
+                _VMEM((group, block_k, d), jnp.float32),
+                _VMEM((group, block_k, d), jnp.float32),
+            ],
         ),
-        grid=(b, h // group, skv_pad // block_k, sq_pad // block_q),
-        in_specs=[
-            smem_spec, smem_spec, smem_spec,
-            vspec((1, group, 8, block_q), lambda bi, hi, kj, qi: (bi, hi, 0, qi)),
-            vspec((1, group, 8, block_q), lambda bi, hi, kj, qi: (bi, hi, 0, qi)),
-            vspec((1, group, 8, block_q), lambda bi, hi, kj, qi: (bi, hi, 0, qi)),
-            q_spec(qmap_kv_grid),
-            kv_spec(kmap_kv_grid),
-            kv_spec(kmap_kv_grid),
-            q_spec(qmap_kv_grid),
-        ],
-        out_specs=[
-            kv_spec(kmap_kv_grid),
-            kv_spec(kmap_kv_grid),
-        ],
-        out_shape=[dkv_shape(k), dkv_shape(v)],
-        scratch_shapes=[
-            _VMEM((group, block_k, d), jnp.float32),
-            _VMEM((group, block_k, d), jnp.float32),
-        ],
-        **common_params,
+        out_shape=[shape_like(k, skv_pad), shape_like(v, skv_pad)],
+        **call_params,
         name="hvd_flash_bwd_dkv",
-    )(*scalars, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
+    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
+    stat_spec, q_spec, kv_spec = specs("qk")
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel_dq, sm_scale=sm_scale, causal=causal,
-            masked=causal or skv_pad != skv or sq_pad != sq,
-            packed=packed, d=d,
+        functools.partial(_bwd_kernel_dq, **kernel_params),
+        grid_spec=_grid_spec(
+            causal,
+            grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
+            in_specs=[stat_spec, stat_spec, stat_spec,
+                      q_spec, kv_spec, kv_spec, q_spec],
+            out_specs=q_spec,
+            scratch_shapes=[_VMEM((group, block_q, d), jnp.float32)],
         ),
-        grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
-        in_specs=[
-            smem_spec, smem_spec, smem_spec,
-            vspec((1, group, 8, block_q), lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
-            vspec((1, group, 8, block_q), lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
-            vspec((1, group, 8, block_q), lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
-            q_spec(qmap_q_grid),
-            kv_spec(kmap_q_grid),
-            kv_spec(kmap_q_grid),
-            q_spec(qmap_q_grid),
-        ],
-        out_specs=q_spec(qmap_q_grid),
-        out_shape=dq_shape,
-        scratch_shapes=[_VMEM((group, block_q, d), jnp.float32)],
-        **common_params,
+        out_shape=shape_like(q, sq_pad),
+        **call_params,
         name="hvd_flash_bwd_dq",
-    )(*scalars, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
+    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
-    if packed:
+    if p.packed:
         return (
             dq[:, :sq].astype(q.dtype),
             dk[:, :skv].astype(k.dtype),
@@ -761,10 +1039,10 @@ def _bwd_pallas(
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
 def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-           interpret, n_heads=0):
+           interpret, n_heads=0, static_offsets=None):
     return _fwd_pallas(
         q,
         k,
@@ -777,19 +1055,21 @@ def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         block_k=block_k,
         interpret=interpret,
         n_heads=n_heads,
+        static_offsets=static_offsets,
     )
 
 
 def _flash_fwd(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
-               block_k, interpret, n_heads=0):
+               block_k, interpret, n_heads=0, static_offsets=None):
     out, lse = _flash(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-        interpret, n_heads
+        interpret, n_heads, static_offsets
     )
     return (out, lse), (q, k, v, q_offset, kv_offset, out, lse)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads, res, g):
+def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
+               static_offsets, res, g):
     q, k, v, q_offset, kv_offset, out, lse = res
     g_out, g_lse = g
     dq, dk, dv = _bwd_pallas(
@@ -808,6 +1088,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads, res, g):
         block_k=block_k,
         interpret=interpret,
         n_heads=n_heads,
+        static_offsets=static_offsets,
     )
     # Integer offsets take float0 cotangents.
     zero = np.zeros((), dtype=jax.dtypes.float0)
@@ -875,6 +1156,12 @@ def flash_attention_with_lse(
         raise ValueError(
             f"layout must be 'bshd', 'bhsd' or 'bsm', got {layout!r}"
         )
+    # Offsets given as Python ints (the model path: 0, 0) are also kept
+    # static, for the build-time tile counters; the kernels read the
+    # traced scalars either way.
+    static_offsets = None
+    if all(isinstance(x, (int, np.integer)) for x in (q_offset, kv_offset)):
+        static_offsets = (int(q_offset), int(kv_offset))
     out, lse = _flash(
         q,
         k,
@@ -887,6 +1174,7 @@ def flash_attention_with_lse(
         int(block_k),
         interpret,
         int(n_heads) if packed else 0,
+        static_offsets,
     )
     if layout == "bshd":
         out = jnp.moveaxis(out, 1, 2)

@@ -217,3 +217,262 @@ def test_flash_bsm_requires_n_heads():
     x = jnp.zeros((1, 16, 32), jnp.float32)
     with pytest.raises(ValueError, match="n_heads"):
         flash_attention(x, x, x, layout="bsm")
+
+
+# ---------------------------------------------------------------------------
+# Causal tile geometry: a causal call walks each copied block in compute
+# tiles, skips those above the diagonal and runs those below it unmasked.
+# Blocks of 16 / 32 rows give tiles of 8 / 16 in the interpreter, so these
+# shapes hold two tiles per block edge and several blocks per sequence (a
+# causal call widens its copied K/V block only by whole blocks that divide
+# the K/V evenly, so 3 or 5 blocks stay 3 or 5).  The last two are the tile
+# sizes the chip compiles, 128 and 256, run here in the interpreter.
+# ---------------------------------------------------------------------------
+
+# name: (sq, skv, block or (block_q, block_k), layout)
+_GEOMETRIES = {
+    "s96-b32-bsm": (96, 96, 32, "bsm"),
+    "s160-b32-bhsd": (160, 160, 32, "bhsd"),
+    "s96-b16-bsm": (96, 96, 16, "bsm"),
+    "sq64-skv96-b32-bhsd": (64, 96, 32, "bhsd"),
+    "s72-padded-b32-bsm": (72, 72, 32, "bsm"),
+    # the models' shape: K/V block twice the q block, 4 tiles wide
+    "s128-bq32-bk64-bsm": (128, 128, (32, 64), "bsm"),
+    "s512-b256-tile128-bsm": (512, 512, 256, "bsm"),
+    "s1024-b512-tile256-bsm": (1024, 1024, 512, "bsm"),
+}
+# name: (q_offset, kv_offset) as functions of (sq, skv)
+_OFFSETS = {
+    "model": lambda sq, skv: (0, 0),
+    "past-hop": lambda sq, skv: (skv, 0),
+    "future-hop": lambda sq, skv: (0, sq),
+    "mid-tile": lambda sq, skv: (5, 0),
+}
+
+
+def _offset_reference(q, k, v, q_offset, kv_offset):
+    """(out, lse) of causal attention at global offsets, [B,S,H,D] in;
+    rows with no visible key give zero output and -inf lse."""
+    sq, skv = q.shape[1], k.shape[1]
+    mask = (q_offset + jnp.arange(sq))[:, None] >= (
+        kv_offset + jnp.arange(skv)
+    )[None, :]
+    seen = mask.any(axis=1)
+    out = dot_product_attention(q, k, v, causal=False, mask=mask)
+    out = jnp.where(seen[None, :, None, None], out, 0.0)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    lse = jax.scipy.special.logsumexp(
+        jnp.where(mask, s, -jnp.inf), axis=-1
+    )
+    return out, lse
+
+
+def _flash_at(q, k, v, q_offset, kv_offset, block, layout):
+    """flash (out, lse) for [B,S,H,D] inputs through ``layout``."""
+    b, sq, h, d = q.shape
+    block_q, block_k = block if isinstance(block, tuple) else (block, block)
+    if layout == "bsm":
+        pack = lambda x: x.reshape(b, x.shape[1], h * d)  # noqa: E731
+        out, lse = flash_attention_with_lse(
+            pack(q), pack(k), pack(v), causal=True, q_offset=q_offset,
+            kv_offset=kv_offset, block_q=block_q, block_k=block_k,
+            layout="bsm", n_heads=h,
+        )
+        return out.reshape(b, sq, h, d), lse
+    mv = lambda x: jnp.moveaxis(x, 2, 1)  # noqa: E731
+    out, lse = flash_attention_with_lse(
+        mv(q), mv(k), mv(v), causal=True, q_offset=q_offset,
+        kv_offset=kv_offset, block_q=block_q, block_k=block_k,
+        layout="bhsd",
+    )
+    return jnp.moveaxis(out, 1, 2), lse
+
+
+@pytest.mark.parametrize(
+    "geometry,offsets",
+    [
+        (g, o) for g in _GEOMETRIES for o in _OFFSETS
+        # the hops are covered at the small shapes
+        if _GEOMETRIES[g][0] < 512 or o in ("model", "mid-tile")
+    ],
+)
+def test_causal_tiles_match_reference(geometry, offsets):
+    """Forward, lse and the three gradients across every tile class."""
+    sq, skv, block, layout = _GEOMETRIES[geometry]
+    q_offset, kv_offset = _OFFSETS[offsets](sq, skv)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(11), 1, sq, 2, 16, skv=skv)
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(lse ** 2)
+        return f
+
+    flash = lambda q, k, v: _flash_at(  # noqa: E731
+        q, k, v, q_offset, kv_offset, block, layout
+    )
+    ref = lambda q, k, v: _offset_reference(  # noqa: E731
+        q, k, v, q_offset, kv_offset
+    )
+    out, lse = flash(q, k, v)
+    out_ref, lse_ref = ref(q, k, v)
+    np.testing.assert_allclose(out, out_ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, lse_ref, atol=2e-5, rtol=2e-5)
+    g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "q_offset,kv_offset,sq,skv,block,tile",
+    [
+        (0, 0, 1024, 1024, 512, 128),
+        (0, 0, 1024, 1024, 512, 256),
+        (0, 0, 96, 96, 32, 8),
+        (5, 0, 96, 96, 32, 8),
+        (96, 0, 96, 96, 32, 8),
+        (0, 96, 96, 96, 32, 8),
+        (0, 0, 72, 72, 32, 8),
+        (3, 7, 64, 90, 32, 16),
+        (40, 0, 64, 200, 64, 16),
+    ],
+)
+def test_tile_classes_against_brute_force(q_offset, kv_offset, sq, skv,
+                                          block, tile):
+    """No tile classed interior holds a masked entry (or, under the
+    backward's guard, a padded q row); no skipped tile holds a valid
+    one; the counts are the classifier's."""
+    from horovod_tpu.ops.pallas_kernels import _count_tiles, _tile_spans
+
+    sq_pad = -(-sq // block) * block
+    skv_pad = -(-skv // block) * block
+    rows = np.arange(sq_pad)[:, None]
+    cols = np.arange(skv_pad)[None, :]
+    valid = (q_offset + rows >= kv_offset + cols) & (cols < skv)
+    nkt = block // tile
+    for guard in (False, True):
+        ok = valid & (rows < sq) if guard else valid
+        visited = masked = 0
+        for q0 in range(0, sq_pad, tile):
+            for k0 in range(0, skv_pad, block):
+                n_interior, n_visited = _tile_spans(
+                    q_offset + q0, kv_offset + k0, skv - k0,
+                    guard and q0 + tile > sq, tq=tile, tk=tile, nkt=nkt,
+                )
+                assert 0 <= n_interior <= n_visited <= nkt
+                visited += n_visited
+                if n_interior < nkt:  # the q tile's slab is masked whole
+                    masked += n_visited
+                for j in range(nkt):
+                    entries = ok[q0:q0 + tile,
+                                 k0 + j * tile:k0 + (j + 1) * tile]
+                    if j < n_interior:
+                        assert entries.all(), (q0, k0, j)
+                    elif j >= n_visited:
+                        assert not valid[
+                            q0:q0 + tile, k0 + j * tile:k0 + (j + 1) * tile
+                        ].any(), (q0, k0, j)
+        total = (sq_pad // tile) * (skv_pad // tile)
+        assert _count_tiles(
+            q_offset, kv_offset, sq=sq, skv=skv, sq_pad=sq_pad,
+            skv_pad=skv_pad, block_q=block, block_k=block, tq=tile,
+            tk=tile, guard_q_pad=guard,
+        ) == (visited, masked, total - visited)
+
+
+@pytest.mark.parametrize(
+    "block,interpret,tile",
+    [(512, False, 256), (256, False, 128), (128, False, 128),
+     (200, False, 200), (64, True, 32), (32, True, 16), (16, True, 8),
+     (40, True, 40)],
+)
+def test_compute_tile_rule(block, interpret, tile):
+    from horovod_tpu.ops.pallas_kernels import _compute_tile
+
+    assert _compute_tile(block, interpret) == tile
+
+
+@pytest.mark.parametrize(
+    "block_k,tk,skv_pad,resident",
+    [(512, 256, 1024, 1024), (512, 256, 2048, 1024), (512, 256, 1536, 512),
+     (512, 256, 512, 512), (256, 128, 1024, 512), (128, 128, 1024, 512),
+     (1024, 256, 2048, 1024), (2048, 256, 2048, 2048), (32, 16, 96, 32),
+     (16, 8, 96, 32), (64, 32, 128, 128)],
+)
+def test_resident_kv_rule(block_k, tk, skv_pad, resident):
+    """Whole caller blocks, at most four tiles, dividing the K/V."""
+    from horovod_tpu.ops.pallas_kernels import _resident_kv
+
+    assert _resident_kv(block_k, tk, skv_pad) == resident
+    assert skv_pad % resident == 0 and resident % block_k == 0
+
+
+@pytest.mark.parametrize("block_k", [512, 1024])
+@pytest.mark.parametrize("h", [1, 2, 4, 5, 6, 8, 10, 12, 16, 18, 20, 24])
+def test_head_group_is_a_legal_packed_block(h, block_k):
+    """Heads of 64 packed in the lane axis: a group is 128-lane aligned
+    or all of the heads (3 of 6 heads, 192 lanes, is what Mosaic refused
+    at ``block_k`` 1024)."""
+    from horovod_tpu.ops.pallas_kernels import _head_group
+
+    g = _head_group(h, 512, block_k, 64, True)
+    assert h % g == 0 and ((g * 64) % 128 == 0 or g == h)
+    # head-major blocks carry the group on a leading dim: no lane rule
+    assert h % _head_group(h, 512, block_k, 64, False) == 0
+
+
+def _flash_counters():
+    from horovod_tpu.obs import registry
+
+    reg = registry.always()
+    return {
+        name: reg.counter(name).get()
+        for name in ("flash.tiles.visited", "flash.tiles.masked",
+                     "flash.tiles.skipped", "flash.calls.dynamic_offsets")
+    }
+
+
+def _counted(fn, *args):
+    before = _flash_counters()
+    jax.eval_shape(fn, *args)
+    after = _flash_counters()
+    return tuple(after[k] - before[k] for k in before)
+
+
+def test_flash_tile_counters_at_gpt2_shape():
+    """Build-time counters: one batch element and head of the GPT-2 cell
+    (s 1024, the whole K/V resident, 256 x 256 tiles: of the 16, 6 lie
+    above the diagonal and are skipped, and each q tile takes its visited
+    tiles as one slab that ends on the diagonal, so masked)."""
+    def x(s):
+        return (jax.ShapeDtypeStruct((1, s, 768), jnp.bfloat16),) * 3
+
+    def fwd(causal, **blocks):
+        return lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, layout="bsm", n_heads=12, **blocks
+        )
+
+    def grad(fn):
+        return jax.grad(
+            lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )
+
+    assert _counted(fwd(True), *x(1024)) == (10, 10, 6, 0)
+    assert _counted(fwd(False), *x(1024)) == (0, 0, 0, 0)
+    # forward, dK/dV and dQ each book their own pallas_call
+    assert _counted(grad(fwd(True)), *x(1024)) == (30, 30, 18, 0)
+    # s 2048: two K/V blocks of 1024, so the last four q tiles find the
+    # first block all interior and take it unmasked
+    assert _counted(fwd(True), *x(2048)) == (36, 20, 28, 0)
+    assert _counted(grad(fwd(True)), *x(2048)) == (108, 60, 84, 0)
+    def ring_hop(q, k, v, r):
+        return flash_attention_with_lse(
+            q, k, v, causal=True, q_offset=r * 1024, kv_offset=0,
+            layout="bsm", n_heads=12,
+        )
+
+    r = jax.ShapeDtypeStruct((), jnp.int32)
+    assert _counted(ring_hop, *x(1024), r) == (0, 0, 0, 1)

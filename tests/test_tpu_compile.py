@@ -55,23 +55,29 @@ def _compile(fn, sharding, *shapes):
 
 
 @pytest.mark.parametrize(
-    "batch,seq,causal",
-    [(16, 1024, True), (32, 512, False)],
-    ids=["gpt2-16x1024-causal", "bert-32x512"],
+    "batch,seq,causal,heads",
+    [(16, 1024, True, 12), (16, 1024, True, 6), (16, 1024, True, 16),
+     (4, 2048, True, 12), (16, 640, True, 18), (32, 512, False, 12)],
+    ids=["gpt2-16x1024-causal", "6-heads-causal", "16-heads-causal",
+         "s2048-causal", "18-heads-s640-causal", "bert-32x512"],
 )
-def test_flash_attention_fwd_bwd_compiles(v5e, batch, seq, causal):
-    """Packed ``bsm`` layout at d_model 768 / 12 heads, as
-    ``models/transformer.py`` calls it; forward and both backward
-    kernels."""
+def test_flash_attention_fwd_bwd_compiles(v5e, batch, seq, causal, heads):
+    """Packed ``bsm`` layout with heads of 64, as ``models/transformer.py``
+    calls it (no block sizes given); forward and both backward kernels.
+    The causal cases cross what the plan decides from the shape: the whole
+    K/V of 1024 resident (12 heads: groups of 4), head counts whose
+    budget-sized group would be 3 heads, 192 lanes, which Mosaic refuses
+    (6, 18), a padded length, and two K/V blocks (s 2048: the forward's
+    unmasked slab)."""
 
     def loss(q, k, v):
         out = pk.flash_attention(
-            q, k, v, causal=causal, layout="bsm", n_heads=12,
+            q, k, v, causal=causal, layout="bsm", n_heads=heads,
             interpret=False,
         )
         return out.astype(jnp.float32).sum()
 
-    qkv = ((batch, seq, 768), jnp.bfloat16)
+    qkv = ((batch, seq, 64 * heads), jnp.bfloat16)
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, qkv, qkv, qkv)
     assert hlo.count("tpu_custom_call") >= 3  # fwd + dkdv + dq
 

@@ -1,86 +1,146 @@
-"""Microbench flash attention fwd/bwd on the chip.
+"""Kernels only, on the chip: the three flash kernels by name.
 
-Usage: ``python tools/bench_attention.py``.  Reports achieved TF/s at the
-BERT-base shape using the ``4*B*H*S^2*D`` convention (x3.5 for fwd+bwd).
-Today's code: not measured (the only readings predate PR 1, on another
-installation).
+    python tools/bench_attention.py [--tree CHECKOUT] [--iters 8]
+        [--heads 12] [--shape NAME] [--block-q N --block-k N]
+
+Runs forward + backward of ``flash_attention`` alone (one layer's worth) at
+the shapes of the benchmark's flash cells — GPT-2-small (16 x 1024, causal)
+and BERT-base MLM (32 x 512, non-causal), packed ``bsm`` layout, ``--heads`` heads
+of 64, bf16, the kernels' own block sizes as the models leave them —
+under ``jax.profiler.trace`` and prints one JSON line per shape: the
+median device microseconds of ``hvd_flash_fwd`` / ``hvd_flash_bwd_dkv`` /
+``hvd_flash_bwd_dq`` per call, read from the device plane's ``XLA Ops``
+line, and the largest absolute error of the three gradients against
+float32 ``jax.numpy`` attention at batch 2. ``--tree`` imports
+``horovod_tpu`` from another checkout (a parent commit unpacked beside
+this one), so two commits can be timed in one chip call. This is where a
+kernel change is judged before a cell is run; ``benchmark/split.py`` gives
+the same three names inside a whole step.
 """
+import argparse
+import glob
+import json
 import os
 import sys
-import time
+import tempfile
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+import numpy as np
+from jax.profiler import ProfileData
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from horovod_tpu.ops.pallas_kernels import flash_attention  # noqa: E402
-from horovod_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
-
-B, S, H, D = 32, 512, 12, 64
-ITERS = 200
+KERNELS = ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd")
+HEAD_DIM = 64
+# name: (batch, sequence, causal)
+SHAPES = {"gpt2-16x1024-causal": (16, 1024, True),
+          "bert-32x512": (32, 512, False)}
 
 
-def timed_loop(fn, *args):
-    """Seconds per iteration of ``fn`` inside one carry-dependent
-    ``fori_loop`` of ``ITERS`` iterations (one dispatch, so the host's
-    per-call cost is 1/ITERS of a reading), closed by
-    ``block_until_ready``; best of three."""
+def reference(q, k, v, w, causal):
+    """Loss of float32 attention written out in ``jax.numpy``."""
+    b, s, width = q.shape
+    split = lambda x: x.astype(jnp.float32).reshape(b, s, -1, HEAD_DIM)  # noqa: E731
+    q, k, v = split(q), split(k), split(v)
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, precision="highest"
+    ) / np.sqrt(q.shape[-1])
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    out = jnp.einsum(
+        "bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v,
+        precision="highest",
+    )
+    return (out.reshape(b, s, width) * w).sum()
 
-    @jax.jit
-    def go(*a):
-        def body(_, carry):
-            out = fn(*carry)
-            # True data dependence on out (x*0.0 gets folded; minimum
-            # does not) so XLA cannot hoist the body.
-            new_q = jnp.minimum(carry[0], out)
-            return (new_q,) + carry[1:]
 
-        final = lax.fori_loop(0, ITERS, body, a)
-        return jnp.sum(final[0][0, 0, 0])
-
-    jax.block_until_ready(go(*args))  # compile
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        jax.block_until_ready(go(*args))
-        best = min(best, time.perf_counter() - t0)
-    return best / ITERS
+def kernel_us(fn, argv, iters):
+    """Median device microseconds per call of each flash kernel."""
+    for _ in range(2):  # compile, then settle
+        jax.block_until_ready(fn(*argv))
+    trace_dir = tempfile.mkdtemp(prefix="flash_trace")
+    with jax.profiler.trace(trace_dir):
+        for _ in range(iters):
+            out = fn(*argv)
+        jax.block_until_ready(out)
+    durations = {name: [] for name in KERNELS}
+    for path in glob.glob(
+        os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb")
+    ):
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/device:TPU:0"):
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    name = next((k for k in KERNELS if k in ev.name), None)
+                    if name:
+                        durations[name].append(ev.duration_ns / 1e3)
+    if not all(durations.values()):
+        raise SystemExit(f"kernels missing from the trace: {durations}")
+    return {name: float(np.median(v)) for name, v in durations.items()}
 
 
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tree", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--iters", type=int, default=8)
+    ap.add_argument("--heads", type=int, default=12)
+    ap.add_argument("--shape", choices=sorted(SHAPES), action="append",
+                    help="only this shape (may repeat); default: all")
+    ap.add_argument("--block-q", type=int)
+    ap.add_argument("--block-k", type=int)
+    args = ap.parse_args()
+    sys.path.insert(0, args.tree)
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
+
     enable_compile_cache()
-    if jax.devices()[0].platform != "tpu":
+    device = jax.devices()[0]
+    if device.platform != "tpu":
         raise SystemExit(
-            "bench_attention times the compiled kernel; no TPU found "
-            f"({jax.devices()[0].platform}) and the interpreter's time "
-            "means nothing"
+            "bench_attention times the compiled kernels; no TPU found "
+            f"({device.platform}) and the interpreter's time means nothing"
         )
-    key = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, S, H, D), jnp.bfloat16)
-    k = jax.random.normal(kk, (B, S, H, D), jnp.bfloat16)
-    v = jax.random.normal(kv, (B, S, H, D), jnp.bfloat16)
+    for shape in args.shape or SHAPES:
+        b, s, causal = SHAPES[shape]
+        blocks = {}
+        if args.block_q:
+            blocks["block_q"] = args.block_q
+        if args.block_k:
+            blocks["block_k"] = args.block_k
 
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=False)
+        def loss(q, k, v, w, causal=causal, blocks=blocks):
+            out = flash_attention(
+                q, k, v, causal=causal, layout="bsm", n_heads=args.heads,
+                **blocks
+            )
+            return (out.astype(jnp.float32) * w).sum()
 
-    dt = timed_loop(fwd, q, k, v)
-    fl = 4 * B * H * S * S * D
-    print(f"fwd: {dt*1e3:.3f} ms  {fl/dt/1e12:.1f} TF/s")
-
-    def fwdbwd(q, k, v):
-        out, grads = jax.value_and_grad(
-            lambda q, k, v: flash_attention(q, k, v, causal=False)
-            .astype(jnp.float32)
-            .sum(),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        return grads[0]
-
-    dt = timed_loop(fwdbwd, q, k, v)
-    print(f"fwd+bwd: {dt*1e3:.3f} ms  {3.5*fl/dt/1e12:.1f} TF/s")
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        argv = [
+            jax.random.normal(
+                key, (b, s, args.heads * HEAD_DIM), jnp.float32
+            )
+            for key in keys
+        ]
+        argv = [x.astype(jnp.bfloat16) for x in argv[:3]] + argv[3:]
+        flash = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        us = kernel_us(flash, argv, args.iters)
+        small = [x[:2] for x in argv]
+        exact = jax.jit(
+            jax.grad(reference, argnums=(0, 1, 2)), static_argnums=4
+        )(*small, causal)
+        errors = [
+            float(jnp.max(jnp.abs(a.astype(jnp.float32) - e)))
+            for a, e in zip(flash(*small), exact)
+        ]
+        print(json.dumps(dict(
+            tree=args.tree, shape=shape, heads=args.heads, blocks=blocks,
+            device_kind=device.device_kind, us_per_call=us,
+            total_us=sum(us.values()), grad_abs_err_vs_f32=errors,
+        )), flush=True)
 
 
 if __name__ == "__main__":
