@@ -75,7 +75,7 @@ def main(args) -> int:
         for batch in pool:
             state, loss = step(state, jax.device_put(batch, sharding))
             system.append(float(loss))
-        del state
+        reference.release(state)  # the reference gets the device
         theirs = plain(family.init_params(key), pool)
         found = reference.compare(system, theirs, tol)
         rel.append(found["rel_diff"])
@@ -87,7 +87,8 @@ def main(args) -> int:
             ]
         harness.emit("agreement_seed", seed=seed, system=system,
                      reference=theirs, rel_diff=found["rel_diff"],
-                     agree=found["agree"], **extra)
+                     agree=found["agree"], moments=plain.phase["moments"],
+                     **extra)
 
     by_step = list(zip(*rel))
     harness.emit(

@@ -1,0 +1,10 @@
+"""Milliseconds per step in flash attention's backward kernel for dK and dV: the
+Mosaic calls the program named ``hvd_flash_bwd_dkv`` (device trace, worst
+device). With its two siblings it sums to ``flash_ms``. Nothing to read
+where the cell's attention bypasses the kernels."""
+
+from benchmark.lib.by_name import kernel_ms
+
+
+def read(run):
+    return kernel_ms(run, "hvd_flash_bwd_dkv")

@@ -196,17 +196,8 @@ def measure(cell: Cell, *, bench_dir: str, seed: int, seconds: float,
         padding_share=data_lib.padding_share(pool),
     )
 
-    # -- the plain reference, on one device, before the system steps ------
-    ref = traffic["reference"]
-    t0 = time.perf_counter()
-    counter.take()
-    ref_losses = reference.make_reference(
-        family.reference_loss, optimizer, micro_batch=ref["micro_batch"]
-    )(family.init_params(key), pool[:ref["steps"]])
-    emit("reference", rehearsal=rehearsal, losses=ref_losses,
-         seconds=time.perf_counter() - t0, cache=counter.take())
-
-    # -- warm-up: the first steps are compared, then until nothing compiles
+    # -- warm-up: until nothing compiles; the first losses are compared with
+    # the reference's once the window has closed
     warm_losses, compiles = [], []
     batch, quiet = first, 0
     while True:
@@ -216,21 +207,18 @@ def measure(cell: Cell, *, bench_dir: str, seed: int, seconds: float,
         compiles.append(counter.take())
         warm_losses.append(float(loss))
         quiet = quiet + 1 if compiles[-1]["compile_requests"] == 0 else 0
-        if len(warm_losses) >= WARMUP_MIN_STEPS and quiet >= QUIET_STEPS:
+        if quiet >= QUIET_STEPS and len(warm_losses) >= max(
+            WARMUP_MIN_STEPS, traffic["reference"]["steps"]
+        ):
             break
         if len(warm_losses) >= WARMUP_MAX_STEPS:
             raise RuntimeError(
                 f"still compiling after {WARMUP_MAX_STEPS} steps: {compiles}"
             )
         batch = next(batches)
-    agreement = reference.compare(
-        warm_losses[:ref["steps"]], ref_losses,
-        reference.tolerance(ref.get("loss_rel_tol")),
-    )
     warmup_recompiles = sum(c["compile_requests"] for c in compiles[1:])
     emit("warmup", steps=len(warm_losses), losses=warm_losses,
-         compiles_per_step=compiles, recompiles_after_first=warmup_recompiles,
-         agreement=agreement)
+         compiles_per_step=compiles, recompiles_after_first=warmup_recompiles)
 
     # -- the window -------------------------------------------------------
     counter.take()
@@ -267,60 +255,114 @@ def measure(cell: Cell, *, bench_dir: str, seed: int, seconds: float,
         peak_bytes_in_use=runtime_peaks, planned_peak_bytes=plan_peak,
     )
 
-    # -- correct ----------------------------------------------------------
-    expect = traffic.get("expect", {})
-    reasons = []
-    if not agreement["agree"]:
-        reasons.append(f"losses differ from the reference: {agreement}")
-    if failed:
-        reasons.append(f"{failed} non-finite losses in the window")
-    if in_window["compile_requests"]:
-        reasons.append(f"compilation inside the window: {in_window}")
-    if rate["steps"] < MIN_STEPS and not rehearsal:
-        reasons.append(f"only {rate['steps']} steps completed, need {MIN_STEPS}")
-    if not rehearsal and "pallas_calls" in expect and (
-        built["pallas_calls"] != expect["pallas_calls"]
-    ):
-        reasons.append(
-            f"{built['pallas_calls']} Pallas calls in the compiled step, "
-            f"expected {expect['pallas_calls']}"
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind, "count": cell.chips,
+        # The plan of the compiled step or the runtime's high-water mark,
+        # whichever is larger: on this runtime the counter sees live arrays
+        # and not the program's temporaries (PERF.md). Read before the
+        # reference runs: a process's peak never falls again.
+        "memory_peak_bytes": max(
+            [plan_peak] + [p for p in runtime_peaks if p]
+        ),
+    }
+    trace = None
+    if traced and not rehearsal:
+        state, trace = traced_steps(
+            jax, step, state, batches, built, gaps, trace_dump
         )
-    if len(shard_devices) != cell.chips:
-        reasons.append(
-            f"batch shards sit on {len(shard_devices)} device(s), "
-            f"want {cell.chips}"
-        )
-    if expect.get("all_reduce") and not (
-        built["collectives"]["compiled"]["all-reduce"]
-    ):
-        reasons.append("no all-reduce in the compiled step")
+        device["busy_s"] = trace.busy_s_mean
+        device["window_s"] = trace.window_s
 
-    record = {
-        "correct": not reasons, "reasons": reasons,
+    # -- the plain reference, with the device to itself -------------------
+    del first, batch, batches
+    reference.release(state)
+    ref = traffic["reference"]
+    ref_losses = reference_phase(
+        jax, family, optimizer, key, pool[:ref["steps"]],
+        ref["micro_batch"], counter, devices[0], rehearsal=rehearsal,
+    )
+    agreement = reference.compare(
+        warm_losses[:ref["steps"]], ref_losses,
+        reference.tolerance(ref.get("loss_rel_tol")),
+    )
+    emit("agreement", **agreement)
+
+    # -- correct: each number compared, its limit, and whether it holds ----
+    expect = traffic.get("expect", {})
+    tol = agreement["tolerance_rel"]
+    checks = [
+        (f"loss_rel_diff_step{i + 1}", x, tol, x <= tol)
+        for i, x in enumerate(agreement["rel_diff"])
+    ]
+    n_compiled = in_window["compile_requests"]
+    checks += [
+        ("losses_compared_exactly", len(agreement["rel_diff"]), ref["steps"],
+         len(agreement["rel_diff"]) == ref["steps"]),
+        ("non_finite_losses", failed, 0, not failed),
+        ("compiles_in_window", n_compiled, 0, not n_compiled),
+        ("batch_shard_devices_exactly", len(shard_devices), cell.chips,
+         len(shard_devices) == cell.chips),
+    ]
+    if not rehearsal:
+        checks.append(("steps_completed_at_least", rate["steps"], MIN_STEPS,
+                       rate["steps"] >= MIN_STEPS))
+    if not rehearsal and "pallas_calls" in expect:
+        checks.append(("pallas_calls_exactly", built["pallas_calls"],
+                       expect["pallas_calls"],
+                       built["pallas_calls"] == expect["pallas_calls"]))
+    if expect.get("all_reduce"):
+        n_all_reduce = built["collectives"]["compiled"]["all-reduce"]
+        checks.append(("all_reduces_at_least", n_all_reduce, 1,
+                       n_all_reduce >= 1))
+    reasons = [f"{name}: {value}, limit {limit}"
+               for name, value, limit, holds in checks if not holds]
+    compared = {name: [value, limit] for name, value, limit, _ in checks}
+
+    return {
+        "correct": not reasons, "reasons": reasons, "compared": compared,
         "attempted": win["dispatched"], "failed": failed,
         "cell": cell, "family": family, "peak": peak, "built": built,
         "setup_s": setup_s, "rate": rate, "gaps_ms": gaps, "window": win,
         "warmup_recompiles": warmup_recompiles,
-        "tokens_per_step": tokens_per_step, "trace": None,
-        "device": {
-            "platform": devices[0].platform,
-            "kind": devices[0].device_kind, "count": cell.chips,
-            # The plan of the compiled step or the runtime's high-water
-            # mark, whichever is larger: on this runtime the counter sees
-            # live arrays and not the program's temporaries (PERF.md).
-            "memory_peak_bytes": max(
-                [plan_peak] + [p for p in runtime_peaks if p]
-            ),
-        },
+        "tokens_per_step": tokens_per_step, "trace": trace,
+        "device": device,
     }
-    if traced and not rehearsal:
-        record["trace"] = traced_steps(
-            jax, step, state, batches, built, gaps, trace_dump
-        )
-        t = record["trace"]
-        record["device"]["busy_s"] = t.busy_s_mean
-        record["device"]["window_s"] = t.window_s
-    return record
+
+
+def reference_phase(jax, family, optimizer, key, batches, micro_batch,
+                    counter, device, *, rehearsal: bool) -> list:
+    """The plain reference's losses over ``batches`` from the parameters the
+    system started from, drawn again from ``key``. It runs once the window
+    has closed, ``memory_peak_bytes`` is read and the system's state is
+    released, so it has the device to itself and is no part of ``setup_s``;
+    the ``reference`` line says what it held and what it left behind."""
+
+    def in_use():
+        stats = device.memory_stats() or {}
+        return stats.get("bytes_in_use"), stats.get("peak_bytes_in_use")
+
+    t0 = time.perf_counter()
+    counter.take()
+    live_before = len(jax.live_arrays())
+    bytes_before, peak_before = in_use()
+    losses = reference.make_reference(
+        family.reference_loss, optimizer, micro_batch=micro_batch
+    )
+    ref_losses = losses(family.init_params(key), batches)
+    bytes_after, peak_after = in_use()
+    emit(
+        "reference", rehearsal=rehearsal, losses=ref_losses,
+        seconds=time.perf_counter() - t0, cache=counter.take(),
+        **losses.phase,
+        # The runtime's high-water mark is the process's: it is the
+        # phase's own where the phase passed what the system had reached.
+        peak_bytes_in_use_before=peak_before, peak_bytes_in_use=peak_after,
+        bytes_in_use_before=bytes_before, bytes_in_use_after=bytes_after,
+        live_arrays_before=live_before,
+        live_arrays_after=len(jax.live_arrays()),
+    )
+    return ref_losses
 
 
 def traced_steps(jax, step, state, batches, built, untraced_gaps_ms,
@@ -359,8 +401,12 @@ def traced_steps(jax, step, state, batches, built, untraced_gaps_ms,
     emit(
         "trace", xplane_bytes=size, events=len(events),
         window_s=summary.window_s, steps=summary.steps,
-        devices=[dataclasses.asdict(d) | {"idle_share": d.idle_share}
-                 for d in summary.devices],
+        devices=[
+            {f.name: getattr(d, f.name) for f in dataclasses.fields(d)
+             if f.name != "op_seconds"}  # thousands of names: readers only
+            | {"idle_share": d.idle_share, "ops_named": len(d.op_seconds)}
+            for d in summary.devices
+        ],
         traced_step_ms_p50=stats.percentile(traced_gaps, 50),
         untraced_step_ms_p50=stats.percentile(untraced_gaps_ms, 50),
         tracing_overhead=(
@@ -368,7 +414,7 @@ def traced_steps(jax, step, state, batches, built, untraced_gaps_ms,
             / stats.percentile(untraced_gaps_ms, 50) - 1.0
         ),
     )
-    return summary
+    return state, summary
 
 
 def end_to_end(record: dict) -> dict:
@@ -421,6 +467,8 @@ def result_line(record: dict, bench_dir: str, traced: bool) -> dict:
             ],
             "idle_gaps": worst.idle_gaps,
         }
+    # last: each number ``correct`` was decided from, beside its limit
+    line["compared"] = record["compared"]
     return line
 
 
@@ -454,4 +502,8 @@ def main(argv, *, root: str, bench_dir: str, t_start: float) -> int:
         emit("incorrect", reasons=record["reasons"])
     print(json.dumps(result_line(record, bench_dir, bool(args.trace))),
           flush=True)
+    # the same numbers as the last lines of standard error
+    for name, (value, limit) in record["compared"].items():
+        print(f"compared {name}: {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     return 0

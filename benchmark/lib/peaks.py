@@ -20,6 +20,9 @@ class Peak:
     int8_ops: float  # OP/s
     hbm_bytes_per_s: float
     hbm_bytes: float
+    # what the runtime lets a process hold (``memory_stats()["bytes_limit"]``
+    # on the chip): what a plan has to fit, as a rehearsal sees it
+    usable_hbm_bytes: int
     ici_bits_per_s: float
     source: str
 
@@ -30,6 +33,7 @@ PEAKS = {
         int8_ops=393e12,
         hbm_bytes_per_s=819e9,
         hbm_bytes=16e9,
+        usable_hbm_bytes=16_909_336_064,  # my chip run, PR 26
         ici_bits_per_s=1600e9,
         source='Google Cloud documentation, "TPU v5e"',
     ),

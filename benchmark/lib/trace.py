@@ -34,7 +34,9 @@ the first ``sync`` span to the end of the last one — whole steady steps):
 * ``other``: the union of every op that is neither; with synchronous
   collectives this equals busy - kernels - collective.
 * an idle gap is a maximal interval with no op; it is attributed to the
-  loop span that overlaps it longest, or to ``none``.
+  loop span that overlaps it longest, or to ``none``;
+* ``op_seconds``: the summed durations, inside the window, of every op by
+  its name, for the readers that ask by scope or by kernel name.
 """
 
 from __future__ import annotations
@@ -205,6 +207,9 @@ class DeviceSummary:
     other_s: float
     top_ops: list  # [[name, seconds]], most time first
     idle_gaps: list  # [[loop span or "none", seconds]], longest first
+    # seconds inside the window of EVERY operation, by its trace name: what
+    # a reader sums by scope or by kernel name (``lib/by_name.py``)
+    op_seconds: dict = dataclasses.field(default_factory=dict, repr=False)
 
     @property
     def idle_share(self) -> float:
@@ -284,6 +289,7 @@ def summarize_device(device: int, ops: Sequence, host_spans: Sequence,
         other_s=length(other) / unit_per_s,
         top_ops=[[n, t / unit_per_s] for n, t in top],
         idle_gaps=named_gaps,
+        op_seconds={n: t / unit_per_s for n, t in totals.items()},
     )
 
 

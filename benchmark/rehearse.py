@@ -12,7 +12,11 @@ name, and there is no result line.
     JAX_PLATFORMS=cpu python3 benchmark/rehearse.py compile --workload <name>
         the cell's real step and its reference's gradient program, compiled
         for a DESCRIBED v5e:2x2 (no chip attached): what the chip's compiler
-        refuses, the planned bytes, the Pallas calls and the collectives
+        refuses, the planned bytes, the Pallas calls and the collectives, and
+        the reference phase's planned peak (parameters, optimizer state and
+        accumulator beside the gradient program) with whether it fits the
+        chip with the optimizer state on the device, on the host, or not at
+        all: a cell is sized here, with no chip
 """
 
 import os
@@ -55,7 +59,8 @@ def rehearse_compile(args) -> int:
     import horovod_tpu as hvd
     from horovod_tpu.parallel import dp
 
-    from benchmark.lib import compile_info, harness, resolve
+    from benchmark.lib import compile_info, harness, reference, resolve
+    from benchmark.lib.peaks import peak_for
 
     cell = harness.load_cell(ROOT, BENCH_DIR, args.workload)
     topo = topologies.get_topology_desc(
@@ -67,7 +72,9 @@ def rehearse_compile(args) -> int:
     family = resolve.load_family(BENCH_DIR, traffic["family"]).build(
         cell.config, traffic
     )
-    step, wrapped, _ = harness.build_step(cell, family, hvd, dp, optax)
+    step, wrapped, optimizer = harness.build_step(
+        cell, family, hvd, dp, optax
+    )
     key = jax.ShapeDtypeStruct((2,), jax.numpy.uint32)
     params = jax.eval_shape(family.init_params, key)
     state = jax.eval_shape(lambda p: dp.init_state(p, wrapped), params)
@@ -107,9 +114,27 @@ def rehearse_compile(args) -> int:
         on_one(params),
         on_one({k: v[:micro] for k, v in host.items()}),
     ).compile()
+    ref_plan = compile_info.planned_bytes(ref.memory_analysis())
     harness.emit(
-        "described_compile_reference", micro_batch=micro,
-        plan=compile_info.planned_bytes(ref.memory_analysis()),
+        "described_compile_reference", micro_batch=micro, plan=ref_plan
+    )
+    # What the reference phase will hold beside that program (parameters,
+    # optimizer state, accumulator), and where the state will wait: the
+    # decision run.py takes from the same bytes on the chip.
+    limit = peak_for(topo.devices[0].device_kind).usable_hbm_bytes
+    phase = reference.plan_phase(
+        param_bytes=reference.tree_bytes(params),
+        state_bytes=reference.tree_bytes(
+            jax.eval_shape(optimizer.init, params)
+        ),
+        grad_plan_bytes=ref_plan["peak_bytes"], bytes_limit=limit,
+    )
+    harness.emit(
+        "described_reference_phase", **phase,
+        fits={"device": "with the optimizer state on the device",
+              "host": "only with the optimizer state waiting on the host",
+              "nowhere": "not at all"}[phase["moments"]],
+        system_step_fits=built["plan"]["peak_bytes"] <= limit,
     )
     return 0
 

@@ -58,12 +58,12 @@ def test_plain_loss_and_gradient_match_the_program(workload):
 
 def test_micro_batches_give_the_whole_batch_gradient():
     family, pool = _tiny("bert-base.cls-b96-s128-pad")
-    params = family.init_params(jax.random.PRNGKey(1))
     opt = optax.adamw(3e-4)
-    def losses(micro_batch):
+
+    def losses(micro_batch):  # the reference consumes its parameters
         return reference.make_reference(
             family.reference_loss, opt, micro_batch=micro_batch
-        )(params, pool)
+        )(family.init_params(jax.random.PRNGKey(1)), pool)
 
     assert losses(4) == pytest.approx(losses(2), rel=1e-5)
     with pytest.raises(ValueError):

@@ -113,6 +113,63 @@ def test_a_new_cell_is_files_and_entries_only(copy):
     assert {k: v for k, v in after.items() if k in before} == before
 
 
+def test_a_reader_by_scope_and_by_kernel_name_is_a_file(copy):
+    """A later PR's reader asks the traced operations by name: a scope
+    segment of the ``op_name`` path, a prefix of a ``pallas_call`` name. Over
+    the events recorded on the chip (PR 24's fixture, four devices) it gives
+    what ``lib/scopes.split`` gives, and the kernels sum to ``flash_ms``."""
+    import gzip
+
+    from benchmark.lib import scopes, trace as T
+
+    bench = os.path.join(copy, "benchmark")
+    before = _digests(bench)
+    with open(os.path.join(bench, "layer_metrics", "update_ms.py"), "w") as f:
+        f.write("from benchmark.lib.by_name import scope_ms\n\n\n"
+                "def read(run):\n    return scope_ms(run, 'hvd_update')\n")
+    with open(os.path.join(bench, "layer_metrics", "dkv_ms.py"), "w") as f:
+        f.write("from benchmark.lib.by_name import kernel_ms\n\n\n"
+                "def read(run):\n"
+                "    return kernel_ms(run, 'hvd_flash_bwd_dkv')\n")
+    fixture = os.path.join(
+        BENCH, "tests", "fixtures",
+        "gpt2-small.b16-s1024.dp4.3steps.split.json.gz",
+    )
+    with gzip.open(fixture, "rt") as f:
+        rec = json.load(f)
+    names = dict(kernel_names=rec["kernel_names"],
+                 collective_names=rec["collective_names"])
+    run = {
+        "trace": T.summarize(rec["events"], **names),
+        "built": {"labels": rec["labels"],
+                  "pallas_call_names": rec["kernel_names"]},
+    }
+    split = scopes.split(rec["events"], rec["labels"], mixed=rec["mixed"],
+                         **names)["devices"]
+    read = lambda name: resolve.load_layer_metric(  # noqa: E731
+        bench, name
+    ).read(run)
+    assert read("update_ms") == pytest.approx(
+        max(d["phases_ms"]["update"] for d in split), rel=1e-9
+    )
+    assert read("update_ms") == pytest.approx(3.44, abs=0.01)
+    assert read("dkv_ms") == pytest.approx(
+        max(d["kernels_ms"]["hvd_flash_bwd_dkv"] for d in split), rel=1e-9
+    )
+    # the committed readers: one file each, and together they are flash_ms
+    parts = [read(f"flash_{k}_ms") for k in ("fwd", "bwd_dkv", "bwd_dq")]
+    assert parts == pytest.approx([14.21, 14.04, 10.38], abs=0.01)
+    assert sum(parts) == pytest.approx(read("flash_ms"), rel=1e-3)
+    # nothing by that name: nothing to report, never a zero
+    from benchmark.lib import by_name
+
+    assert by_name.scope_ms(run, "update") is None  # whole segments only
+    assert by_name.kernel_ms(run, "no_such_kernel") is None
+    assert by_name.kernel_ms({**run, "trace": None}, "hvd_flash_fwd") is None
+    after = _digests(bench)
+    assert {k: v for k, v in after.items() if k in before} == before
+
+
 def test_unknown_names_are_errors(copy):
     manifest = resolve.load_manifest(copy)
     with pytest.raises(resolve.NoSuchEntry):
