@@ -31,6 +31,12 @@ from the jaxpr, on CPU, before a single device-second is spent:
   bytes* (peak per-device HBM, donation/remat/sharding deltas, the
   ``oom-risk``/``donation-missed-reuse``/``peak-regression`` rules).
 
+* :func:`collective_schedule` (:mod:`.schedule`) — the one reading of a
+  COMPILED step here: from ``compiled.as_text()``, the synchronous and
+  asynchronous gradient collectives, their bytes, and what the schedule
+  puts between each pair's start and done (how far the overlapped
+  exchange engaged; ``tools/comm_audit.py --schedule``).
+
 Entry points that wrap this for daily use: ``parallel.dp.make_train_step
 (lint=...)`` (every built step can self-lint, and exposes
 ``step.memplan()``), ``tools/hvdtpu_lint.py`` / ``tools/
@@ -68,6 +74,7 @@ from .memory import (  # noqa: F401
     MemoryPlan,
     plan_traced,
 )
+from .schedule import collective_schedule  # noqa: F401
 from . import rules as _rules
 
 

@@ -407,7 +407,11 @@ def rule_fusion_parity(
                         )
                     )
     else:
-        predicted = _predicted_buckets(params, threshold_bytes, 1)
+        # The replicated exchange reaches the jaxpr as one ``psum`` per
+        # gradient leaf (``lax.psum`` over a tuple binds one equation per
+        # operand; which leaves share a launch is the compiler's combiner
+        # and its threshold, ops/layout.py): predict every leaf alone.
+        predicted = _predicted_buckets(params, 0, 1)
         if wire_dtype:
             predicted = _wire_cast(predicted, wire_dtype)
         groups = [

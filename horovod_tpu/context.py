@@ -195,6 +195,16 @@ def enable_overlap_scheduler(platform: Optional[str] = None) -> Tuple[str, ...]:
       :func:`~horovod_tpu.ops.layout.overlap_compiler_options` (which
       ``make_train_step(overlap=True)`` always passes) still apply.
 
+    ``make_train_step`` does not call this (since PR 29): it passes the
+    same options per compile, which every backend that knows them
+    accepts. The environment form is for callers who arm the scheduler
+    for a whole process before ``hvd.init()``, and it is unforgiving: a
+    process whose XLA does not know one of the ``xla_tpu_*`` names dies
+    at backend start-up (``parse_flags_from_env.cc: Unknown flags in
+    XLA_FLAGS``; seen in ISSUE 29's session, where a process that had
+    the flags written into its environment compiled for a described
+    chip).
+
     Returns the flags appended to ``XLA_FLAGS`` (empty if none).
     """
     plat = platform or os.environ.get("JAX_PLATFORMS", "")

@@ -18,9 +18,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..context import context as _get_context, enable_overlap_scheduler
+from ..context import _axis_or_world, context as _get_context
 from ..obs import registry as _obs
 from ..optimizer import (
     DistributedOptimizer,
@@ -30,7 +30,11 @@ from ..optimizer import (
 )
 from ..ops.collectives import Average, ReduceOp, allreduce
 from ..ops.compression import Compression, is_quantized
-from ..ops.layout import collective_compiler_options, overlap_compiler_options
+from ..ops.layout import (
+    collective_compiler_options,
+    overlap_compiler_options,
+    overlap_threshold_bytes,
+)
 from ..utils import env as _env
 
 
@@ -380,21 +384,41 @@ def make_train_step(
     (:mod:`horovod_tpu.obs.flops` has the shared model). Both are
     ignored, costing nothing, when metrics are off.
 
+    **Overlapped gradient exchange** (the default, since PR 29): when
+    the reduction axis spans more than one device, the replicated step
+    (``sharded=False``, no wire quantization) is compiled with the
+    options that lower every single-operand gradient all-reduce to an
+    asynchronous start/done pair
+    (:func:`~horovod_tpu.ops.layout.overlap_compiler_options`) and with
+    the combiner held to
+    :func:`~horovod_tpu.ops.layout.overlap_threshold_bytes`, so that
+    every gradient leaf of 1 MiB or more keeps a reduction of its own:
+    the compiled schedule puts weight-gradient matmuls and optimizer
+    updates of other parameters between start and done. Nothing to
+    switch on, no new argument; the same trace, the same float32 sums
+    over the same devices. The step also places a state that is not on
+    the mesh (``init_state`` leaves it on the default device) before it
+    dispatches it, and ``step.lower`` lowers for that place, so the second
+    call builds nothing. With one device on the axis the step is built exactly as
+    before: no compiler option, the same compiled program.
+    :func:`horovod_tpu.analysis.collective_schedule` reads how far the
+    overlap engaged off ``step.lower(...).compile().as_text()``.
+
     **Overlap pipeline** (opt-in; defaults read the ``HVDTPU_OVERLAP*``
     knobs): ``accum_steps=K`` microbatches the step through
     :func:`accumulate_gradients` — K forward/backward passes over 1/K
     batch slices, gradients accumulated locally, ONE fused reduction of
     the mean gradient per step (wire bytes identical to ``accum_steps=1``).
-    ``overlap=True`` arms the comm/compute overlap machinery around it:
-    per-bucket staggered dispatch in readiness order (reverse-layer
-    packing + ``optimization_barrier`` chaining, see ``ops/fusion.py``;
-    ``stagger=False`` lets the scheduler free-order buckets, an explicit
-    ``stagger=True`` chains them even without ``overlap``'s compile
-    options — default reads ``HVDTPU_OVERLAP_STAGGER``),
-    the XLA latency-hiding-scheduler / async-collective compile options
-    (:func:`~horovod_tpu.ops.layout.overlap_compiler_options`, plus the
-    best-effort env flags via
-    :func:`~horovod_tpu.context.enable_overlap_scheduler`). Both knobs
+    ``overlap=True`` adds per-bucket staggered dispatch in readiness
+    order (reverse-layer packing + ``optimization_barrier`` chaining,
+    see ``ops/fusion.py``; ``stagger=False`` lets the scheduler
+    free-order buckets, an explicit ``stagger=True`` chains them even
+    without ``overlap`` — default reads ``HVDTPU_OVERLAP_STAGGER``) and
+    passes the same compile options on the ``sharded=True`` and
+    quantized paths too. Per-compile options only: the step's path never
+    writes ``XLA_FLAGS``
+    (:func:`~horovod_tpu.context.enable_overlap_scheduler` is for callers
+    who set the environment before ``hvd.init()``). Both knobs
     work on the replicated and ``sharded=True`` paths, preserve donation,
     and are numerically the plain step within fp tolerance (the
     accumulation reorders the sum; ``tests/test_overlap.py``). On CPU
@@ -711,20 +735,28 @@ def make_train_step(
             error_feedback=error_feedback,
         )
 
-    # Compile options for the overlap pipeline: the fusion threshold must
-    # own the collective layout (else the backend combiner merges every
-    # bucket into one all-reduce and there is nothing to overlap), and the
-    # latency-hiding scheduler must be on to actually interleave. Both
-    # resolve to {} on the CPU test platform → plain jit.
+    # The compile-time half of the gradient exchange (ops/layout.py).
+    # Wherever the replicated step's reduction axis spans more than one
+    # device the compiler is told to lower all-reduces asynchronously and
+    # to merge no gradient leaf that passes the size rule with another:
+    # ``reduction_limit`` is the most bytes one reduction of this step
+    # holds, and ``certify``'s wire layout follows it. ``overlap=True``
+    # passes the same options on the other paths, at the fusion threshold.
+    # Per-compile options only; {} on the CPU test platform and none at
+    # all on one device, so that step compiles as it always did.
+    overlapped_exchange = (
+        distribute_optimizer and not sharded and not quantized
+        and int(np.prod([m.shape[a] for a in _axis_or_world(axis)])) > 1
+    )
+    reduction_limit = (
+        overlap_threshold_bytes(threshold_bytes) if overlapped_exchange
+        else threshold_bytes
+    )
     copts = None
-    if overlap:
+    if overlap or overlapped_exchange:
         platform = m.devices.flat[0].platform
-        if platform == "tpu":
-            # Best-effort env flags too: inert for this already-initialized
-            # backend but inherited by child processes (elastic workers).
-            enable_overlap_scheduler(platform=platform)
         copts = {
-            **collective_compiler_options(threshold_bytes, platform=platform),
+            **collective_compiler_options(reduction_limit, platform=platform),
             **overlap_compiler_options(platform),
         } or None
 
@@ -901,7 +933,7 @@ def make_train_step(
         else:
             wire = [
                 [d, int(n)]
-                for d, n in bucket_byte_layout(state.params, threshold_bytes)
+                for d, n in bucket_byte_layout(state.params, reduction_limit)
             ]
         return _analysis.schedule_cert(
             jaxpr,
@@ -922,7 +954,7 @@ def make_train_step(
             },
         )
 
-    def _finish(step_fn, mapped_for, jitted_for):
+    def _finish(step_fn, mapped_for, jitted_for, as_dispatched=lambda s: s):
         # Always wrapped: the wrapper itself checks enablement per call,
         # so obs.enable()/disable() after the step is built take effect.
         from ..obs import trace as _trace
@@ -1096,7 +1128,7 @@ def make_train_step(
         # ``.compile().as_text()`` is its HLO, ``.memory_analysis()`` its
         # device memory. Arrays or ShapeDtypeStructs; nothing executes.
         def _lower(state, batch):
-            state = _seeded_for_trace(state)
+            state = as_dispatched(_seeded_for_trace(state))
             return jitted_for(state).lower(state, batch)
 
         wrapped.lower = _lower
@@ -1126,7 +1158,33 @@ def make_train_step(
             donate_argnums=(0,) if donate else (),
             compiler_options=copts,
         )
-        return _finish(jitted, lambda state: mapped, lambda state: jitted)
+        if not overlapped_exchange or m.is_multi_process:
+            return _finish(jitted, lambda state: mapped, lambda state: jitted)
+        # The step returns its state replicated over the mesh. A state that
+        # arrives otherwise (``init_state`` leaves it on the default device)
+        # makes the second call build a second program for the new input
+        # shardings (ROADMAP D1b), and the overlapped exchange's programs
+        # are the larger ones to build and to load: such a state is placed
+        # before it is dispatched, and ``step.lower`` lowers for that place.
+        on_mesh = NamedSharding(m, P())
+
+        def placed(state: TrainState, put):
+            at = getattr(state.step, "sharding", None)
+            if at is not None and at.is_equivalent_to(on_mesh, 0):
+                return state
+            return jax.tree.map(lambda x: put(x, on_mesh), state)
+
+        def abstract(x, sharding):
+            return jax.ShapeDtypeStruct(
+                np.shape(x), jnp.result_type(x), sharding=sharding
+            )
+
+        return _finish(
+            lambda state, batch: jitted(placed(state, jax.device_put), batch),
+            lambda state: mapped,
+            lambda state: jitted,
+            lambda state: placed(state, abstract),
+        )
 
     # Structure-dependent path: the opt-state specs depend on the
     # state's structure (which flat buckets the params pack into), so

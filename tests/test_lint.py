@@ -207,12 +207,12 @@ class TestRulesFire:
         assert "donation-dropped" in _rules(f)
 
     def test_fusion_parity_break(self, world8):
-        # Policy predicts ONE default-threshold bucket; the step shreds
-        # the reduction into per-leaf launches via a 4-byte threshold.
+        # The policy predicts one psum per gradient leaf (what the
+        # replicated exchange reaches the jaxpr as); the step reduces
+        # only part of the tree and leaves ``b`` unreduced.
         def step(p, b):
-            return fused_allreduce(
-                jax.grad(_loss)(p, b), threshold_bytes=4
-            )["w"]
+            grads = jax.grad(_loss)(p, b)
+            return fused_allreduce({"w": grads["w"]})["w"]
 
         f = lint_traced(
             _mapped(world8, step),
@@ -228,9 +228,11 @@ class TestRulesFire:
             return fused_allreduce(jax.grad(_loss)(p, b))["w"]
 
         def two_buckets(p, b):
-            return fused_allreduce(
-                jax.grad(_loss)(p, b), threshold_bytes=64
-            )["w"]
+            # Not a bucket boundary any more (every leaf is its own psum
+            # in the jaxpr whatever the threshold): a build that reduces
+            # one leaf of the two.
+            grads = jax.grad(_loss)(p, b)
+            return fused_allreduce({"w": grads["w"]})["w"]
 
         same = compare_collectives(
             _mapped(world8, one_bucket),

@@ -18,7 +18,11 @@ import horovod_tpu as hvd
 from horovod_tpu.obs import overlap as obs_overlap
 from horovod_tpu.obs import registry as obs_registry
 from horovod_tpu.ops.fusion import fused_allreduce, pack, unpack
-from horovod_tpu.ops.layout import overlap_compiler_options
+from horovod_tpu.ops.layout import (
+    ASYNC_LEAF_BYTES,
+    overlap_compiler_options,
+    overlap_threshold_bytes,
+)
 from horovod_tpu.parallel import dp
 from horovod_tpu.parallel.dp import accumulate_gradients
 
@@ -229,6 +233,209 @@ def test_stagger_is_numerically_identity(world8):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
         )
+
+
+# -- the overlapped exchange: the combiner's limit, the state's place ------
+
+
+@pytest.mark.parametrize("asked, limit", [
+    (None, ASYNC_LEAF_BYTES),        # the 64 MB default never survives
+    (128 << 20, ASYNC_LEAF_BYTES),
+    (ASYNC_LEAF_BYTES, ASYNC_LEAF_BYTES),
+    (4096, 4096),                    # a smaller threshold is the caller's
+])
+def test_overlap_threshold_is_the_fusion_threshold_up_to_the_size_rule(
+    asked, limit
+):
+    assert overlap_threshold_bytes(asked) == limit
+
+
+@pytest.mark.parametrize("kwargs, n_devices, limit", [
+    ({}, 8, ASYNC_LEAF_BYTES),
+    ({"threshold_bytes": 4096}, 8, 4096),
+    ({}, 1, None),
+    ({"sharded": True}, 8, None),
+    ({"compression": hvd.Compression.int8}, 8, None),
+    ({"overlap": True, "sharded": True}, 8, "fusion threshold"),
+])
+def test_compile_options_follow_the_mesh_and_the_path(
+    monkeypatch, kwargs, n_devices, limit
+):
+    """What ``make_train_step`` tells the compiler: on the replicated path
+    with more than one device on the axis, the overlap options and the
+    combiner at the exchange's limit; on one device nothing at all; on the
+    sharded and quantized paths only with ``overlap=True``, at the fusion
+    threshold."""
+    from conftest import cpu_devices
+
+    monkeypatch.setattr(dp, "overlap_compiler_options",
+                        lambda platform: {"overlap": "on"})
+    monkeypatch.setattr(dp, "collective_compiler_options",
+                        lambda t, platform: {"combiner": t})
+    seen = {}
+    real_jit = jax.jit
+
+    def spy(fn, **kw):
+        if "compiler_options" in kw:  # the step's own jit; CPU knows no option
+            seen.update(kw)
+            kw = {k: v for k, v in kw.items() if k != "compiler_options"}
+        return real_jit(fn, **kw)
+
+    monkeypatch.setattr(jax, "jit", spy)
+    hvd.init(devices=cpu_devices(n_devices))
+    try:
+        step, opt = dp.make_train_step(_loss, optax.adamw(1e-2), **kwargs)
+        state = dp.init_state(_copy(_params()), opt)
+        state, loss = step(state, _batch())
+        jax.block_until_ready(loss)
+    finally:
+        hvd.shutdown()
+    if limit is None:
+        assert seen.get("compiler_options") is None
+    else:
+        if limit == "fusion threshold":  # left to layout.py's default
+            limit = None
+        assert seen["compiler_options"] == {"overlap": "on", "combiner": limit}
+
+
+def test_state_is_placed_once_only_where_the_axis_spans_devices(monkeypatch):
+    """On more than one device the replicated step places a state that is
+    not on the mesh before it dispatches it (the one ``init_state`` made,
+    never the one it returned), ``step.lower`` lowers for that place, and
+    so the cell's flow (lower, compile, call, call) builds one program;
+    with one device on the axis the step is built as it always was
+    (ROADMAP D1b's second build included)."""
+    from conftest import cpu_devices
+
+    def lowerings():
+        return hvd.obs.snapshot()["counters"].get(
+            "build.lowerings.hvd_train_step", 0
+        )
+
+    puts = []
+    real_put = jax.device_put
+
+    def counting_put(x, *args, **kwargs):
+        puts.append(type(x).__name__)
+        return real_put(x, *args, **kwargs)
+
+    seen = {}
+    for n in (8, 1):
+        hvd.init(devices=cpu_devices(n))
+        try:
+            step, opt = dp.make_train_step(_loss, optax.adamw(1e-2))
+            state = dp.init_state(_copy(_params()), opt)
+            n_leaves = len(jax.tree.leaves(state))
+            before = lowerings()
+            step.lower(state, _batch()).compile()
+            monkeypatch.setattr(jax, "device_put", counting_put)
+            for i in range(3):
+                state, loss = step(state, _batch(seed=i))
+            jax.block_until_ready(loss)
+            monkeypatch.setattr(jax, "device_put", real_put)
+            seen[n] = (lowerings() - before, len(puts) // n_leaves)
+            del puts[:]
+        finally:
+            hvd.shutdown()
+    assert seen == {8: (1, 1), 1: (2, 0)}
+
+
+_TPU_SCHEDULED_HLO = """\
+HloModule jit_step, is_scheduled=true
+
+%add (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %s = f32[] add(%a, %b)
+}
+
+%fused_start (p: bf16[8,4]) -> (bf16[8,4], f32[8,4], u32[]) {
+  %p = bf16[8,4]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.7 = f32[8,4]{1,0:T(8,128)} all-reduce(%p), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(step)/shard_map/hvd_reduce/psum"}
+  ROOT %cc = (bf16[8,4]{1,0}, f32[8,4]{1,0}, u32[]) custom-call(%all-reduce.7), custom_call_target="AsyncCollectiveStart"
+}
+
+%fused_done (q: bf16[8,4], r: f32[8,4], f: u32[]) -> f32[8,4] {
+  %q = bf16[8,4]{1,0} parameter(0)
+  %r = f32[8,4]{1,0} parameter(1)
+  %f = u32[] parameter(2)
+  %all-reduce.9 = f32[8,4]{1,0} all-reduce(%q), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(step)/shard_map/hvd_reduce/psum"}
+  ROOT %cd = f32[8,4]{1,0} custom-call(%q, %r, %all-reduce.9, %f), custom_call_target="AsyncCollectiveDone"
+}
+
+%fused_dw (x: bf16[16,8], y: bf16[16,4]) -> f32[8,4] {
+  %x = bf16[16,8]{1,0} parameter(0)
+  %y = bf16[16,4]{1,0} parameter(1)
+  ROOT %conv = f32[8,4]{1,0} convolution(%x, %y), dim_labels=fb_io->bf
+}
+
+%fused_adam (g: f32[8,4], w: f32[8,4]) -> f32[8,4] {
+  %g = f32[8,4]{1,0} parameter(0)
+  %w = f32[8,4]{1,0} parameter(1)
+  ROOT %upd = f32[8,4]{1,0} subtract(%w, %g), metadata={op_name="jit(step)/shard_map/hvd_update/sub"}
+}
+
+ENTRY %main (g0: bf16[8,4], x: bf16[16,8], y: bf16[16,4], w: f32[8,4], small: f32[3], l: f32[]) -> f32[8,4] {
+  %g0 = bf16[8,4]{1,0} parameter(0)
+  %x = bf16[16,8]{1,0} parameter(1)
+  %y = bf16[16,4]{1,0} parameter(2)
+  %w = f32[8,4]{1,0} parameter(3)
+  %small = f32[3]{0} parameter(4)
+  %l = f32[] parameter(5)
+  %async-collective-start.1 = (bf16[8,4]{1,0}, f32[8,4]{1,0}, u32[]) fusion(%g0), kind=kCustom, calls=%fused_start
+  %gte.0 = bf16[8,4]{1,0} get-tuple-element(%async-collective-start.1), index=0
+  %gte.1 = f32[8,4]{1,0} get-tuple-element(%async-collective-start.1), index=1
+  %gte.2 = u32[] get-tuple-element(%async-collective-start.1), index=2
+  %fusion.dw = f32[8,4]{1,0} fusion(%x, %y), kind=kOutput, calls=%fused_dw
+  %fusion.adam = f32[8,4]{1,0} fusion(%fusion.dw, %w), kind=kLoop, calls=%fused_adam
+  %async-collective-done.1 = f32[8,4]{1,0} fusion(%gte.0, %gte.1, %gte.2), kind=kCustom, calls=%fused_done, metadata={op_name="jit(step)/shard_map/hvd_reduce/psum"}
+  %all-reduce.3 = (f32[3]{0}, f32[3]{0}) all-reduce(%small, %small), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(step)/shard_map/hvd_reduce/psum"}
+  %all-reduce.4 = f32[] all-reduce(%l), channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(step)/shard_map/hvd_loss_avg/psum"}
+  ROOT %out = f32[8,4]{1,0} add(%async-collective-done.1, %fusion.adam)
+}
+"""
+
+_PLAIN_ASYNC_HLO = """\
+HloModule m, is_scheduled=true
+
+ENTRY %main (g: f32[8,4], x: f32[8,8]) -> f32[8,4] {
+  %g = f32[8,4]{1,0} parameter(0)
+  %x = f32[8,8]{1,0} parameter(1)
+  %all-reduce-start.2 = (f32[8,4]{1,0}, f32[8,4]{1,0}) all-reduce-start(%g), replica_groups={{0,1}}, to_apply=%add
+  %dot.5 = f32[8,4]{1,0} dot(%x, %g), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %all-reduce-done.2 = f32[8,4]{1,0} all-reduce-done(%all-reduce-start.2)
+  ROOT %out = f32[8,4]{1,0} add(%all-reduce-done.2, %dot.5)
+}
+"""
+
+
+def test_collective_schedule_reads_pairs_and_what_lies_between():
+    from horovod_tpu.analysis import collective_schedule
+
+    sched = collective_schedule(_TPU_SCHEDULED_HLO)
+    assert (sched["n_async"], sched["n_sync"]) == (1, 1)
+    (pair,) = sched["async"]
+    assert pair["name"] == "async-collective-start.1"
+    assert pair["done"] == "async-collective-done.1"
+    assert pair["bytes"] == 8 * 4 * 4 and pair["operands"] == 1
+    assert pair["index"] < pair["done_index"]
+    assert (pair["matmuls_between"], pair["updates_between"]) == (1, 1)
+    (sync,) = sched["sync"]  # the loss average is no gradient collective
+    assert sync["name"] == "all-reduce.3" and sync["operands"] == 2
+    assert sync["bytes"] == 2 * 3 * 4
+    assert sched["async_bytes_share"] == pytest.approx(128 / (128 + 24))
+    assert collective_schedule(_TPU_SCHEDULED_HLO, scope=None)["n_sync"] == 2
+
+
+def test_collective_schedule_reads_plain_start_done_pairs():
+    from horovod_tpu.analysis import collective_schedule
+
+    sched = collective_schedule(_PLAIN_ASYNC_HLO, scope=None)
+    assert (sched["n_async"], sched["n_sync"]) == (1, 0)
+    (pair,) = sched["async"]
+    assert pair["bytes"] == 8 * 4 * 4  # the result, not operand + result
+    assert pair["matmuls_between"] == 1
+    assert sched["async_bytes_share"] == 1.0
 
 
 # -- scheduler enablement ------------------------------------------------

@@ -24,10 +24,10 @@ from horovod_tpu.ops import pallas_kernels as pk
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """Sharding on one described v5e chip; the persistent compile cache is
-    off meanwhile (an entry compiled for a described chip cannot be read
-    back without one, and the next compile would warn)."""
+def v5e_topology():
+    """The described ``v5e:2x2``; the persistent compile cache is off
+    meanwhile (an entry compiled for a described chip cannot be read back
+    without one, and the next compile would warn)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -40,9 +40,15 @@ def v5e():
     was_enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_topology):
+    """Sharding on one described v5e chip."""
+    return SingleDeviceSharding(v5e_topology.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -143,3 +149,96 @@ def test_blockwise_quantize_dequantize_compile(v5e, wire_dtype, qmax, integer):
     assert "tpu_custom_call" in _compile(
         dequant, v5e, ((nb, block), wire_dtype), ((nb,), jnp.float32)
     )
+
+
+# -- the data-parallel step's gradient exchange (PR 29) ----------------------
+
+
+def _described_lm_step(topo, n_devices):
+    """A replicated ``make_train_step`` of a small ``transformer_lm`` (GPT-2
+    blocks, tied head) over ``n_devices`` described chips, every default,
+    with abstract state and batch placed on the mesh. Every matrix of a
+    block passes the exchange's size rule (512 x 512 float32 = 1 MiB)."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models.gpt2 import GPT2Config, GPT2LMModel
+    from horovod_tpu.parallel import dp
+
+    hvd.init(devices=topo.devices[:n_devices])
+    mesh = hvd.mesh()
+    model = GPT2LMModel(GPT2Config(
+        vocab_size=2048, max_len=256, d_model=512, n_heads=8, n_layers=4,
+        d_ff=2048,
+    ))
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        logits = model.apply({"params": params}, tokens[:, :-1])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, tokens[:, 1:]
+        ).mean()
+
+    step, wrapped = dp.make_train_step(loss_fn, optax.adamw(3e-4))
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    state = jax.eval_shape(lambda p: dp.init_state(p, wrapped), params)
+
+    def placed(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+            tree,
+        )
+
+    batch = {"tokens": jax.ShapeDtypeStruct((8 * n_devices, 257), jnp.int32)}
+    return step, placed(state, P()), placed(batch, P(hvd.WORLD_AXIS))
+
+
+def test_replicated_step_hides_its_gradient_exchange(v5e_topology):
+    """On four chips the default step's gradient all-reduces compile to
+    asynchronous pairs with work between start and done: at least 90% of
+    the gradient bytes, each pair around a matmul or an ``hvd_update``
+    fusion, and no synchronous all-reduce over the size rule."""
+    import horovod_tpu as hvd
+    from horovod_tpu.analysis import collective_schedule
+    from horovod_tpu.ops.layout import ASYNC_LEAF_BYTES
+
+    try:
+        step, state, batch = _described_lm_step(v5e_topology, 4)
+        hlo = step.lower(state, batch).compile().as_text()
+    finally:
+        hvd.shutdown()
+    sched = collective_schedule(hlo)
+    assert sched["n_async"] >= 20, sched["n_async"]
+    assert sched["async_bytes_share"] >= 0.9, sched["async_bytes_share"]
+    for pair in sched["async"]:
+        assert pair["index"] < pair["done_index"]
+        assert pair["matmuls_between"] + pair["updates_between"] >= 1, pair
+    assert all(r["bytes"] < ASYNC_LEAF_BYTES for r in sched["sync"]), (
+        sched["sync"]
+    )
+
+
+def test_replicated_step_on_one_device_is_the_plain_program(v5e_topology):
+    """With one device on the reduction axis the step is built as it always
+    was: no compiler option, no collective, and text identical to a plain
+    ``jax.jit`` of the same mapped function."""
+    import horovod_tpu as hvd
+    from horovod_tpu.analysis import collective_schedule
+
+    try:
+        step, state, batch = _described_lm_step(v5e_topology, 1)
+        hlo = step.lower(state, batch).compile().as_text()
+        plain = jax.jit(
+            step._mapped_for(state), donate_argnums=(0,)
+        ).lower(state, batch).compile().as_text()
+    finally:
+        hvd.shutdown()
+    assert hlo == plain
+    sched = collective_schedule(hlo, scope=None)
+    assert sched["n_sync"] == sched["n_async"] == 0
+    assert "async-collective" not in hlo

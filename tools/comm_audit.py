@@ -26,6 +26,16 @@ efficiency at v4-32" north star, in three parts:
 3. ``--write-scaling-json`` merges 1+2 with the measured CPU-mesh rows
    from ``bench_scaling.py`` into ``SCALING_rNN.json``.
 
+4. ``--schedule`` (PR 29) — what the compiled step DOES with the exchange:
+   ``dp.make_train_step`` of the model, every default, compiled for a
+   described TPU topology and read by
+   ``horovod_tpu.analysis.collective_schedule``: synchronous all-reduces,
+   asynchronous start/done pairs, and the matmul / update fusions the
+   schedule puts between each pair. The overlap credit of part 2 is a
+   model; this is the program. What the pairs hide is a chip's to say
+   (``PERF.md`` section 6: 8.66 ms exposed became 1.7, and the work
+   beside the reductions slowed by 4).
+
 The CPU-mesh rows remain labeled as correctness-only lower bounds (one
 shared host core); the modeled rows are what speaks to real-ICI scaling,
 with every assumption in the artifact.
@@ -673,6 +683,81 @@ def audit_topology(model_key, topology="v5e:2x4", extra_threshold=32 << 20,
     return row
 
 
+def schedule_audit(model_key, topology="v5e:2x2"):
+    """Compile ``dp.make_train_step`` of the model, every default, for a
+    DESCRIBED TPU topology (no chip) and read what the compiled schedule
+    does with the gradient exchange: synchronous all-reduces, asynchronous
+    start/done pairs and what lies between each pair
+    (``horovod_tpu.analysis.collective_schedule``). An order, never a
+    time."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.analysis import collective_schedule
+    from horovod_tpu.parallel import dp
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name=topology)
+    hvd.init(devices=topo.devices)
+    mesh = hvd.mesh()
+    n = len(topo.devices)
+    if model_key.startswith("gpt2"):
+        from horovod_tpu.models.gpt2 import GPT2Config, GPT2LMModel
+
+        model, batch, seq = GPT2LMModel(GPT2Config.small()), 16, 1024
+        tokens = jax.ShapeDtypeStruct((batch * n, seq + 1), jnp.int32)
+
+        def loss_fn(p, b):
+            logits = model.apply({"params": p}, b[:, :-1])
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits, b[:, 1:]
+            ).mean()
+    elif model_key.startswith("bert"):
+        from horovod_tpu.models.bert import BertConfig, BertModel
+
+        model, batch, seq = BertModel(BertConfig.base()), 32, 512
+        tokens = jax.ShapeDtypeStruct((batch * n, seq), jnp.int32)
+
+        def loss_fn(p, b):  # a target at every position, as the MLM cell
+            logits = model.apply({"params": p}, b)
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits, b
+            ).mean()
+    else:
+        raise SystemExit("--schedule supports the gpt2 and bert models")
+    step, wrapped = dp.make_train_step(loss_fn, optax.adamw(1e-4))
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((2, seq), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    state = jax.eval_shape(lambda p: dp.init_state(p, wrapped), params)
+
+    def placed(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+            tree,
+        )
+
+    hlo = step.lower(
+        placed(state, P()), placed(tokens, P(hvd.WORLD_AXIS))
+    ).compile().as_text()
+    sched = collective_schedule(hlo)
+    keep = ("name", "index", "done_index", "bytes", "operands",
+            "matmuls_between", "updates_between", "kernels_between")
+    return {
+        "model": model_key,
+        "topology": f"{topology} (described, nothing ran)",
+        "n_devices": n,
+        **{k: v for k, v in sched.items() if k not in ("sync", "async")},
+        "sync": [{k: r[k] for k in keep if k in r} for r in sched["sync"]],
+        "async": [{k: r[k] for k in keep if k in r} for r in sched["async"]],
+    }
+
+
 def model_scaling(audit_row, chip="v5e", layout_n_ars=None):
     """Analytic weak-scaling rows for the audited model on real ICI.
 
@@ -800,9 +885,26 @@ def main():
         "respawns — the whole multi-model sweep runs in one process on "
         "plain CPU CI",
     )
+    ap.add_argument(
+        "--schedule",
+        action="store_true",
+        help="compile dp.make_train_step of --model (gpt2 or bert), every "
+        "default, for the described --topology (default v5e:2x2) and print "
+        "its collective schedule: synchronous all-reduces, asynchronous "
+        "pairs and what lies between each (no chip; an order, not a time)",
+    )
     ap.add_argument("--write-scaling-json", metavar="PATH")
     args = ap.parse_args()
     args.model = aliases.get(args.model, args.model)
+
+    if args.schedule:
+        if args.model == "all":
+            raise SystemExit("--schedule needs one --model")
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        print(json.dumps(
+            schedule_audit(args.model, args.topology or "v5e:2x2"), indent=1
+        ))
+        return
 
     if args.lint:
         # One process, no backends warmed yet: force the virtual device
