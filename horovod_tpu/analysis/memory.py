@@ -50,8 +50,8 @@ fully-materialized schedule), layout padding, compiler scratch, and the
 runtime's fixed overhead (framework + executable buffers). The declared
 contract is *relative* fidelity — donation / remat / sharding / world
 deltas — plus an absolute resident-bytes check within
-``HVDTPU_MEMPLAN_TOLERANCE`` (``tests/test_memplan.py``,
-``bench.py``'s ``mem_plan`` gate).
+``HVDTPU_MEMPLAN_TOLERANCE`` (:func:`compare_to_measured`, held by
+``tests/test_memplan.py``).
 
 Surfaces: lint rules ``oom-risk`` / ``donation-missed-reuse`` /
 ``peak-regression`` (:mod:`.rules`), ``step.memplan(state, batch)``
@@ -832,50 +832,19 @@ def snapshot_live_ids() -> Set[int]:
     return {id(a) for a in jax.live_arrays()}
 
 
-def measure_step_bytes(run_fn) -> Tuple[int, str]:
-    """Run ``run_fn()`` and measure actual memory. TPU/GPU devices:
-    ``memory_stats()['peak_bytes_in_use']`` is the PROCESS-LIFETIME
-    high-water mark, so the step's own peak is taken as the delta above
-    the pre-step residency (``bytes_in_use`` before the call); when the
-    call records no NEW peak (some earlier phase already drove the mark
-    higher) the measurement is inconclusive and the source says so.
-    CPU hosts report the post-step ``jax.live_arrays`` total (resident
-    state, comparable to ``plan.global_state_bytes``). Returns
-    ``(bytes, source)`` with source ``"device_peak"``,
-    ``"device_peak_stale"`` (inconclusive) or ``"live_arrays"``."""
-    dev = jax.devices()[0]
-    stats_before = None
-    if dev.platform != "cpu":
-        try:
-            stats_before = dev.memory_stats()
-        except Exception:  # pragma: no cover - backend without stats
-            stats_before = None
-    out = run_fn()
-    jax.block_until_ready(out)
-    if stats_before is not None:
-        stats = dev.memory_stats()
-        peak = stats.get("peak_bytes_in_use")
-        if peak is not None:
-            peak_before = stats_before.get("peak_bytes_in_use", 0)
-            in_use_before = stats_before.get("bytes_in_use", 0)
-            if peak > peak_before:
-                return int(peak - in_use_before), "device_peak"
-            # No new high-water mark during this call: the lifetime
-            # peak predates it and says nothing about THIS step.
-            return int(peak), "device_peak_stale"
-    return live_array_bytes(), "live_arrays"
-
-
 def compare_to_measured(
     plan: MemoryPlan, measured: int, source: str,
     tolerance: Optional[float] = None,
 ) -> Dict[str, Any]:
-    """The drift gate ``bench.py`` emits as ``mem_plan``: predicted vs
-    actual with a relative-error tolerance (``HVDTPU_MEMPLAN_TOLERANCE``
-    default). ``live_arrays`` compares resident state;
-    ``device_peak`` compares the modeled peak (an upper bound on the
-    compiled schedule, so only the *under*-prediction side is a hard
-    failure there)."""
+    """The drift gate: predicted vs actual with a relative-error
+    tolerance (``HVDTPU_MEMPLAN_TOLERANCE`` default). ``source`` says
+    what ``measured`` is: ``live_arrays`` (the post-step
+    ``jax.live_arrays`` total) compares resident state; ``device_peak``
+    (a step's own ``peak_bytes_in_use`` delta) compares the modeled peak,
+    an upper bound on the compiled schedule, so only the
+    *under*-prediction side is a hard failure there;
+    ``device_peak_stale`` (the lifetime peak predates the step) gives no
+    verdict."""
     if tolerance is None:
         tolerance = _env.memplan_tolerance()
     predicted = (

@@ -18,8 +18,8 @@ runs*. This package is that metrics plane:
   process-cumulative counters (``hvt_metrics_*`` C ABI, csrc/metrics.h:
   negotiation cycles, fused tensors, response-cache hits/misses,
   shm-vs-TCP bytes) into every export without forcing a native build.
-* :mod:`~horovod_tpu.obs.flops` — the analytic flop/peak model shared
-  with ``bench.py`` so step instrumentation can report MFU.
+* :mod:`~horovod_tpu.obs.flops` — the analytic flop/peak model behind
+  the step instrumentation's MFU gauge.
 * :mod:`~horovod_tpu.obs.trace` — the span-level tracing plane +
   crash/hang flight recorder (``HVDTPU_TRACE``): ring-buffered
   Perfetto ``trace_event`` spans across every plane, dumped per rank
@@ -61,7 +61,6 @@ from .export import (  # noqa: F401
 from . import build  # noqa: F401
 from . import flops  # noqa: F401
 from . import goodput  # noqa: F401
-from . import overlap  # noqa: F401
 from . import trace  # noqa: F401
 
 __all__ = [
@@ -79,6 +78,5 @@ __all__ = [
     "build",
     "flops",
     "goodput",
-    "overlap",
     "trace",
 ]

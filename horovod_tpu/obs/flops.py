@@ -1,10 +1,8 @@
-"""Analytic FLOP / peak-throughput model shared by bench.py and the
-step-metrics instrumentation.
-
-One home for the numbers so ``bench.py``'s reported MFU and the live
-``step.mfu`` gauge in the metrics plane can never disagree: the nominal
-bf16 peaks per TPU generation, the transformer 6N+attention rule of
-thumb, and the ResNet-50 constant bench.py documents.
+"""Analytic FLOP / peak-throughput model behind the step-metrics
+instrumentation's ``step.mfu`` gauge: the nominal bf16 peaks per TPU
+generation, the transformer 6N+attention rule of thumb, and a ResNet-50
+constant. (The benchmark keeps its own, ``benchmark/lib/flops.py`` and
+``peaks.py``: it may import nothing of the program it measures.)
 """
 
 from __future__ import annotations
@@ -12,7 +10,7 @@ from __future__ import annotations
 from typing import Optional
 
 # Nominal bf16 peak by TPU generation (per chip). Sources: public TPU
-# system documentation; bench.py's MFU lines are computed against these.
+# system documentation.
 PEAK_TFLOPS_BF16 = {
     "v4": 275.0,
     "v5 lite": 197.0,  # v5e
@@ -49,7 +47,7 @@ def transformer_flops_per_token(
 ) -> float:
     """Training FLOPs per token: the 6N convention (matmul-participating
     params only — pass ``n_params`` with embedding lookup tables already
-    excluded, as bench.py does) plus the 12*L*s*d attention term."""
+    excluded) plus the 12*L*s*d attention term."""
     return 6.0 * n_params + 12.0 * n_layers * seq_len * d_model
 
 

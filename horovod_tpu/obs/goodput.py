@@ -15,13 +15,13 @@ Categories (also the runbook triage rows ``tools/check_metric_names.py``
 enforces against ``docs/runbook.md``):
 
 ====================  ====================================================
-``compute``           device busy on useful work (step device bracket,
-                      decode rounds)
+``compute``           the step's device bracket (everything after the
+                      jitted call returned, exposed communication and
+                      stragglers included: only a device trace tells them
+                      apart) and decode rounds
 ``host_dispatch``     jitted-call return path: Python + tracing cache +
                       transfer enqueue
 ``input_stall``       prefetch queue empty when the step needed a batch
-``exposed_comm``      device-time excess over the rolling-min baseline —
-                      the non-overlapped collective / straggler stretch
 ``checkpoint``        blocking save bracket
 ``guard_retry``       steps discarded by the gradient guard
 ``rescale_downtime``  elastic world rebuild: join/rejoin brackets,
@@ -69,7 +69,6 @@ CATEGORIES: Tuple[str, ...] = (
     "compute",
     "host_dispatch",
     "input_stall",
-    "exposed_comm",
     "checkpoint",
     "guard_retry",
     "rescale_downtime",
@@ -95,16 +94,10 @@ PRIORITY: Dict[str, int] = {
     "serve_swap": 50,
     "serve_queue": 40,
     "serve_idle": 30,
-    "exposed_comm": 20,
     "host_dispatch": 10,
     "compute": 0,
     "other": -1,  # residual only; never attached to an interval
 }
-
-# Samples of device time kept for the exposed_comm rolling-min baseline,
-# and the warmup before the estimator trusts it.
-_BASELINE_SAMPLES = 64
-_BASELINE_WARMUP = 5
 
 # Runbook triage row per category — the report tool links each downtime
 # cause to its remediation row, and the goodput-runbook lint gate checks
@@ -113,7 +106,6 @@ RUNBOOK_ROWS: Dict[str, str] = {
     "compute": "goodput: compute",
     "host_dispatch": "goodput: host_dispatch",
     "input_stall": "goodput: input_stall",
-    "exposed_comm": "goodput: exposed_comm",
     "checkpoint": "goodput: checkpoint",
     "guard_retry": "goodput: guard_retry",
     "rescale_downtime": "goodput: rescale_downtime",
@@ -178,9 +170,6 @@ class GoodputLedger:
         # Carried over an adoption: the predecessor's totals + elapsed.
         self._carried: Dict[str, float] = {c: 0.0 for c in CATEGORIES}
         self._carried_elapsed = 0.0
-        # exposed_comm estimator state: recent device-bracket durations;
-        # the rolling min is the no-interference baseline.
-        self._device_samples: List[float] = []
         # Last step bracket, for guard-skip reclassification (the guard
         # verdict for step N is read at step N+1).
         self._last_step: Optional[Tuple[float, float]] = None
@@ -218,23 +207,16 @@ class GoodputLedger:
         self, w0: float, total_s: float, dispatch_s: float, device_s: float
     ) -> None:
         """One training-step bracket: ``[w0, w0+dispatch_s]`` is
-        host_dispatch, the rest compute — minus the exposed_comm tail,
-        the device time in excess of the rolling-min baseline (lockstep
-        collectives stretch every rank's device bracket when one rank
-        straggles, so the excess is the exposed communication)."""
+        host_dispatch, the rest compute (``device_s``, as the step
+        wrapper books it: ``total_s - dispatch_s``). A bracket that
+        stretches stays compute: the host clock cannot say whether the
+        device waited on a collective, a straggler or its own work."""
         if total_s <= 0:
             return
         self.add("host_dispatch", w0, dispatch_s)
-        compute_s = max(0.0, total_s - dispatch_s)
-        self.add("compute", w0 + dispatch_s, compute_s)
+        self.add("compute", w0 + dispatch_s, max(0.0, total_s - dispatch_s))
         with self._lock:
             self._last_step = (w0, total_s)
-            excess = self._baseline_excess_locked(device_s)
-        if excess > 0:
-            # Carve the tail of the device slice: exposed_comm outranks
-            # compute in the sweep, so this reclassifies, not double
-            # counts.
-            self.add("exposed_comm", w0 + total_s - excess, excess)
 
     def record_guard_skip(self) -> None:
         """The guard discarded the previous step: reclassify its bracket
@@ -273,15 +255,6 @@ class GoodputLedger:
             self._origin = start
         if self._last_ts is None or end > self._last_ts:
             self._last_ts = end
-
-    def _baseline_excess_locked(self, device_s: float) -> float:
-        samples = self._device_samples
-        samples.append(device_s)
-        if len(samples) > _BASELINE_SAMPLES:
-            del samples[0]
-        if len(samples) < _BASELINE_WARMUP:
-            return 0.0
-        return max(0.0, device_s - min(samples))
 
     def _settle_oldest_locked(self) -> None:
         """Fold the oldest half of the pending window into settled
@@ -512,7 +485,7 @@ def record_serve(kind: str, w0: float, duration_s: float) -> None:
 def publish(source: Optional[GoodputLedger] = None) -> Dict[str, object]:
     """Export a ledger snapshot as gauges — the ONLY place ``goodput.*``
     metric names are written (single-owner scan). Returns the snapshot
-    so callers (bench, driver) can reuse the consistent read."""
+    so callers (the driver) can reuse the consistent read."""
     src = source if source is not None else ledger()
     snap = src.snapshot()
     reg = _obs.metrics()

@@ -284,8 +284,8 @@ def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
             book(closed, time.perf_counter())
         reg = _obs.metrics()
         reg.counter("step.count").inc()
-        # Overlap-pipeline shape of this step (how bench.py --overlap and
-        # hvdtpu_top tell the on/off runs apart in the exported records).
+        # Overlap-pipeline shape of this step (how hvdtpu_top and a reader
+        # of the exported records tell an overlap=True build from the default).
         reg.gauge("overlap.enabled").set(1.0 if overlap else 0.0)
         reg.gauge("overlap.accum_steps").set(accum_steps)
         if tokens_per_step:

@@ -110,7 +110,7 @@ def _ring_attention_flash(q, k, v, *, axis: str, causal: bool,
     # Lane-aligned head dims ride the packed kernel layout: [B,S,H,D] ↔
     # [B,S,H·D] are FREE reshapes (adjacent minor dims), so every ring
     # hop runs with zero relayout — the bshd path instead pays a
-    # [B,S,H,D]→[B,H,S,D] transpose per hop (docs/perf_analysis_r05.md).
+    # [B,S,H,D]→[B,H,S,D] transpose per hop.
     packed = d % 64 == 0
 
     o = jnp.zeros((b, s, h, d), jnp.float32)

@@ -22,8 +22,7 @@ arXiv:1802.05799 §5; the GP/EI tuner is in-tree as
   re-learned).
 
 Surfaces: ``HVDTPU_AUTOTUNE=1``, ``make_train_step(autotune=...)``,
-``ServePool(autotune=...)``, ``bench.py --autotune``, the
-``hvdtpu_top`` autotune panel, and ``chaos_soak.py autotune``.
+``ServePool(autotune=...)``, the ``hvdtpu_top`` autotune panel, and ``chaos_soak.py autotune``.
 """
 
 from __future__ import annotations

@@ -27,7 +27,7 @@ The closed loop has two halves:
 
 Both halves also run without a driver: :class:`LocalConfigSource` wires
 the client straight to its own search for single-process tuning
-(``bench.py --autotune``, notebooks).
+(scripts, notebooks).
 """
 
 from __future__ import annotations

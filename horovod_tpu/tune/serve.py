@@ -5,8 +5,7 @@ searches the :func:`~horovod_tpu.tune.knobs.serve_space` —
 ``HVDTPU_SERVE_BATCH_TIMEOUT_MS`` (the batch fill window: too short
 wastes device batches on single requests, too long queues latency) and
 the autoscaler watermarks — scoring each trial as ``-p95`` of the
-``serve.request_ms`` histogram under whatever load the pool is serving
-(``bench.py --serve --autotune`` provides the closed-loop load).
+``serve.request_ms`` histogram under whatever load the pool is serving.
 
 Every serve knob is **cheap**: trials flip the live
 ``Dispatcher.batch_timeout_ms`` / policy watermarks in place between

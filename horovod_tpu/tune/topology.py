@@ -10,9 +10,8 @@ cross the slow boundary.
 
 This module turns that argument into the **seed** of the autotuner's
 categorical layout arm: :func:`choose_layout` picks the prior from the
-mesh shape and the measured ``cross_bytes_fraction`` (``bench_scaling``
-already computes it — the fraction of ring bytes that crosses the
-slice boundary), and the search keeps the arm only as long as the data
+mesh shape and a measured ``cross_bytes_fraction`` (the fraction of ring
+bytes that crosses the slice boundary) where the caller has one, and the search keeps the arm only as long as the data
 agrees. ``HVDTPU_COLLECTIVE_LAYOUT=flat|hierarchical`` pins the choice
 and removes the arm entirely.
 """
@@ -59,7 +58,7 @@ def choose_layout(mesh_shape: Dict[str, int],
     if cross_bytes_fraction is None:
         # No measurement: a multi-level mesh's ring crosses the boundary
         # for 1/local_size of its bytes per cross step — estimate from
-        # the shape the way bench_scaling derives it.
+        # the shape.
         local = 1
         for a, n in mesh_shape.items():
             if a not in cross_axes:

@@ -2,8 +2,8 @@
 
 Every run on a fresh machine starts with no compiled code and the GPT-2
 step alone takes about half a minute to compile, so the entry-point
-scripts (``chip_smoke.py``, ``bench.py``, the examples, the profiling
-tools) turn the cache on before their first compile. Tests do not.
+scripts (``chip_smoke.py``, the benchmark's cells, the examples, the
+profiling tools) turn the cache on before their first compile. Tests do not.
 """
 
 from __future__ import annotations

@@ -230,7 +230,6 @@ DECLARED_ENV_VARS = (
     "HVDTPU_REPLAY_WINDOW",
     "HVDTPU_SPAWN_ROUND",  # elastic round a worker was spawned in
     # Tooling.
-    "HVDTPU_SCALING_REEXEC",  # bench_scaling.py re-exec marker
     "HVDTPU_TEST_WORKDIR",  # tests/elastic_harness.py scratch dir
     "HVDTPU_TEST_SOAK_STEPS",  # tools/chaos_soak.py worker step target
     "HVDTPU_TEST_STREAM_SEED",  # chaos_soak.py stream-scenario param seed
@@ -441,8 +440,9 @@ def memplan_baselines_path() -> str:
 
 def memplan_tolerance() -> float:
     """Relative error allowed between the memory planner's prediction
-    and the measured bytes before ``bench.py``'s ``mem_plan`` gate (and
-    ``tests/test_memplan.py``) reports drift. Must lie in (0, 1]."""
+    and the measured bytes before the drift gate
+    (``analysis.memory.memplan_gate``, ``tests/test_memplan.py``) reports
+    drift. Must lie in (0, 1]."""
     tol = get_float(MEMPLAN_TOLERANCE, DEFAULT_MEMPLAN_TOLERANCE)
     if not 0.0 < tol <= 1.0:
         raise ValueError(

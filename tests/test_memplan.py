@@ -6,7 +6,7 @@ seeded-broken step; the honest models hold):
 * **measured**: the planner's resident-bytes accounting matches what a
   real step actually leaves allocated on a CPU host
   (``jax.live_arrays``) within the declared tolerance, for mlp and
-  bert-tiny — the ``bench.py mem_plan`` gate in miniature;
+  bert-tiny — the drift gate (``memory.compare_to_measured``);
 * **models**: donation on/off, remat ``full < dots_saveable < none``
   activation ordering, ZeRO-1 ~1/N opt-state at world 4 and 8;
 * **rules**: ``oom-risk`` / ``donation-missed-reuse`` /
@@ -89,42 +89,41 @@ def _abstract_plan(step, opt, make_params, batch, **kw):
 class TestMeasured:
     """Prediction vs a real step's allocation on the CPU host."""
 
-    @pytest.mark.parametrize("name", ["mlp", "bert"])
-    def test_resident_within_tolerance(self, world8, name):
-        spec = harness.get_spec(name)
-        step, opt = dp.make_train_step(
-            spec.loss_fn, optax.adamw(1e-4), lint=False
-        )
-        params = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype),
-            jax.eval_shape(spec.make_params),
-        )
+    @staticmethod
+    def _gate(loss_fn, params, batch):
+        """Plan the build, run ONE real step, gate the plan against the
+        live-bytes delta (old state donated away, new state + loss
+        appear) plus the still-live batch: the resident footprint the
+        plan's outer avals predict. Consumes ``params`` (donated)."""
+        step, opt = dp.make_train_step(loss_fn, optax.adamw(1e-4), lint=False)
         state = dp.init_state(params, opt)
-        batch = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), spec.batch
-        )
         plan = step.memplan(state, batch)
         before = _mem.snapshot_live_ids()
         out = step(state, batch)
         jax.block_until_ready(out)
-        # Live-bytes delta (old state donated away, new state + loss
-        # appear) plus the still-live batch = the resident footprint
-        # the plan's outer avals predict.
         measured = _mem.live_array_bytes(exclude_ids=before) + sum(
             int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
             for l in jax.tree.leaves(batch)
         )
-        rec = _mem.compare_to_measured(plan, measured, "live_arrays")
+        return _mem.compare_to_measured(plan, measured, "live_arrays")
+
+    @pytest.mark.parametrize("name", ["mlp", "bert"])
+    def test_resident_within_tolerance(self, world8, name):
+        spec = harness.get_spec(name)
+        params = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(spec.make_params),
+        )
+        batch = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), spec.batch
+        )
+        rec = self._gate(spec.loss_fn, params, batch)
         assert rec["ok"], rec
 
-    def test_bench_helper_emits_gate(self, world8):
-        """The exact helper ``bench.py`` calls for its ``mem_plan``
-        JSON field, on the mlp shapes (gpt2-small is a hardware-scale
-        bench; the helper logic is identical)."""
-        import bench
-
-        loss_fn, params, batch = _mlp_concrete()
-        rec = bench._mem_plan_record(loss_fn, params, batch)
+    def test_gate_record_on_concrete_mlp(self, world8):
+        """What the gate returns for a concrete (not zoo) model: the
+        verdict, its source and the plan's breakdown by category."""
+        rec = self._gate(*_mlp_concrete())
         assert rec["ok"] is True, rec
         assert rec["source"] == "live_arrays"
         assert rec["predicted_peak_bytes"] >= rec["predicted_resident_bytes"] // 2

@@ -15,7 +15,6 @@ import optax
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.obs import overlap as obs_overlap
 from horovod_tpu.obs import registry as obs_registry
 from horovod_tpu.ops.fusion import fused_allreduce, pack, unpack
 from horovod_tpu.ops.layout import (
@@ -594,47 +593,6 @@ def test_prefetch_records_occupancy_gauges():
 
 
 # -- overlap telemetry ---------------------------------------------------
-
-
-def test_record_overlap_pair_accounting():
-    # 100 ms serial step, 20 ms of comm; overlapped step 85 ms →
-    # compute 80 ms, exposed 5 ms, efficiency 0.75.
-    out = obs_overlap.record_overlap_pair(85.0, 100.0, comm_ms_total=20.0)
-    assert out["exposed_comm_ms"] == pytest.approx(5.0)
-    assert out["overlap_efficiency"] == pytest.approx(0.75)
-    assert out["speedup"] == pytest.approx(100.0 / 85.0)
-
-
-def test_record_overlap_pair_unknown_chip_reports_null():
-    # CPU devices have no ICI model: efficiency must be None, not a
-    # fabricated number.
-    out = obs_overlap.record_overlap_pair(
-        9.0, 10.0, wire_bytes=1 << 20, n_chips=8, device=jax.devices("cpu")[0]
-    )
-    assert out["overlap_efficiency"] is None
-    assert out["total_comm_ms"] is None
-    assert out["speedup"] == pytest.approx(10.0 / 9.0)
-
-
-def test_record_overlap_pair_sets_gauges():
-    obs_registry.enable()
-    try:
-        obs_overlap.record_overlap_pair(8.0, 10.0, comm_ms_total=4.0)
-        reg = obs_registry.metrics()
-        assert reg.gauge("overlap.total_comm_ms").get() == 4.0
-        assert 0.0 <= reg.gauge("overlap.efficiency").get() <= 1.0
-    finally:
-        obs_registry.disable()
-
-
-def test_ring_allreduce_ms_known_chip():
-    class FakeDev:
-        device_kind = "TPU v5e"
-
-    # 1 GB over 8 chips at 90 GB/s ring: 2*(7/8) GB / 90 GB/s ≈ 19.4 ms.
-    ms = obs_overlap.ring_allreduce_ms(1 << 30, 8, FakeDev())
-    assert ms == pytest.approx(2 * 7 / 8 * (1 << 30) / 90e9 * 1e3)
-    assert obs_overlap.ring_allreduce_ms(1 << 30, 1, FakeDev()) == 0.0
 
 
 def test_step_gauges_mark_overlap_shape(world8):

@@ -47,7 +47,7 @@ CC_KNOB = re.compile(r'Knob(?:Int|Double|Bool|Str|Env)\(\s*"([A-Z0-9_]+)"')
 CC_GETENV = re.compile(r'GetEnv(?:Int|Double|Bool|Str)\(\s*"(HVDTPU_[A-Z0-9_]+)"')
 
 SCAN_DIRS = ("horovod_tpu", "csrc", "tools", "examples", "tests")
-SCAN_ROOT_FILES = ("bench.py", "bench_scaling.py", "__graft_entry__.py")
+SCAN_ROOT_FILES = ("__graft_entry__.py",)
 SCAN_EXT = (".py", ".cc", ".h")
 
 

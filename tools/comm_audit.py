@@ -1,10 +1,10 @@
-"""Communication audit + analytic ICI scaling model (VERDICT r3 #3).
+"""Communication audit + analytic ICI scaling model.
 
-Builds the evidence package behind BASELINE.md's ">=90% scaling
-efficiency at v4-32" north star, in three parts:
+What the framework puts on the wire, what a ring would take for it, and
+what the compiled step does with it, in three parts:
 
 1. **Per-step communication audit** — the data-parallel training step of
-   each benched model is traced with the framework timeline (the
+   each audited model is traced with the framework timeline (the
    ``FUSE_BUCKETS`` events record how many gradient tensors were fused
    into how many variadic collectives of what size) and compiled for an
    8-device mesh; the compiled HLO is scanned for collective ops and
@@ -12,8 +12,7 @@ efficiency at v4-32" north star, in three parts:
    the wire*: bytes per step, collective launch count, bucket layout.
 
 2. **Analytic ICI model** — ring-allreduce time from published per-link
-   ICI bandwidths (assumptions stated in :func:`ici_specs`, bandwidth
-   table shared with ``horovod_tpu.obs.overlap``), combined with
+   ICI bandwidths (assumptions stated in :func:`ici_specs`), combined with
    single-chip step times read before PR 1 on another installation
    (``MODELS`` below; today's code: not measured) and the
    audited wire bytes to model weak-scaling efficiency at 8/16/32 chips,
@@ -23,10 +22,7 @@ efficiency at v4-32" north star, in three parts:
    launch bucket k while the backward pass still produces buckets k+1…
    (single-program dataflow — there is no "hook ordering" problem).
 
-3. ``--write-scaling-json`` merges 1+2 with the measured CPU-mesh rows
-   from ``bench_scaling.py`` into ``SCALING_rNN.json``.
-
-4. ``--schedule`` (PR 29) — what the compiled step DOES with the exchange:
+3. ``--schedule`` (PR 29) — what the compiled step DOES with the exchange:
    ``dp.make_train_step`` of the model, every default, compiled for a
    described TPU topology and read by
    ``horovod_tpu.analysis.collective_schedule``: synchronous all-reduces,
@@ -36,9 +32,8 @@ efficiency at v4-32" north star, in three parts:
    (``PERF.md`` section 6: 8.66 ms exposed became 1.7, and the work
    beside the reductions slowed by 4).
 
-The CPU-mesh rows remain labeled as correctness-only lower bounds (one
-shared host core); the modeled rows are what speaks to real-ICI scaling,
-with every assumption in the artifact.
+The modeled rows are a model with every assumption stated; a time on the
+interconnect is the ``.dp4`` cell's to give (``PERF.md``).
 
 Reference anchor: the reference documents its scaling claim the same
 way — measured throughput at n GPUs vs n x single-GPU
@@ -56,38 +51,49 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Per-chip ICI assumptions (one-way GB/s per link and links usable by a
-# single ring).  A DP all-reduce rides one ring around the torus axis, so
-# the usable bandwidth is one link pair (both directions) = 2x one-way.
-# The bandwidth half is OWNED by ``horovod_tpu.obs.overlap``
-# (``ICI_ONEWAY_GBPS_PER_LINK`` / ``ICI_RING_LINKS`` — the same table
-# behind the bench-side overlap gauges) and pulled in lazily via
-# :func:`ici_specs`, so this audit and ``bench.py --overlap`` can never
-# disagree on the ring model.  Peak TFLOP/s stays local: it feeds the
-# compute column, not the wire model.
+# Per-chip ICI ring assumptions: one-way GB/s per link, and the links a
+# single bidirectional ring uses. A DP all-reduce rides one ring around
+# the torus axis, so the usable bandwidth is one link pair (both
+# directions) = 2x one-way. Sources: public TPU system documentation / the
+# scaling book's hardware tables.
+ICI_ONEWAY_GBPS_PER_LINK = {
+    "v4": 50.0,  # 3D torus, 6 links/chip
+    "v5e": 45.0,  # 2D torus, 4 links/chip
+    "v5p": 90.0,
+    "v6e": 90.0,
+}
+ICI_RING_LINKS = 2
+# Peak TFLOP/s feeds the compute column, not the wire model.
 _CHIP_PEAK_TFLOPS_BF16 = {
     "v5e": 197.0,
     "v4": 275.0,
 }
 
-
 def ici_specs():
-    """Chip -> {oneway_gbps_per_link, ring_links, peak_tflops_bf16}.
-
-    Imported lazily (this tool keeps heavy imports out of module scope so
-    ``--help`` and the subprocess respawns stay cheap)."""
-    from horovod_tpu.obs import overlap as _overlap_model
-
+    """Chip -> {oneway_gbps_per_link, ring_links, peak_tflops_bf16}."""
     return {
         chip: {
-            "oneway_gbps_per_link": _overlap_model.ICI_ONEWAY_GBPS_PER_LINK[
-                chip
-            ],
-            "ring_links": _overlap_model.ICI_RING_LINKS,
+            "oneway_gbps_per_link": ICI_ONEWAY_GBPS_PER_LINK[chip],
+            "ring_links": ICI_RING_LINKS,
             "peak_tflops_bf16": tflops,
         }
         for chip, tflops in _CHIP_PEAK_TFLOPS_BF16.items()
     }
+
+
+def ring_allreduce_ms(wire_bytes, n_chips, chip):
+    """Ring-allreduce time for ``wire_bytes`` of gradients over ``n_chips``
+    of family ``chip``: the slowest link moves ``2(n-1)/n * bytes``. 0.0
+    when n_chips < 2 (nothing on the wire); None for a family the table
+    does not hold: never a number from an unknown bandwidth."""
+    if n_chips < 2:
+        return 0.0
+    oneway = ICI_ONEWAY_GBPS_PER_LINK.get(chip)
+    if oneway is None:
+        return None
+    gbps = oneway * ICI_RING_LINKS
+    return (2 * (n_chips - 1) / n_chips) * wire_bytes / (gbps * 1e9) * 1e3
+
 
 # Per-shard batch on the 8-device audit mesh (global batch / 8):
 # accumulate_gradients slices the shard, so accum_steps must divide this.
@@ -101,12 +107,12 @@ def _divisible_accum(model_key, requested):
     return max(k for k in range(1, min(requested, per) + 1) if per % k == 0)
 
 
-# Single-chip device step times (bench.py method: in-program fori_loop,
-# host-fetch closed, median of 5 windows; round-5 readings from before
-# PR 1, on another installation — docs/perf_analysis_r05.md; today's code
-# is not measured, so the modeled efficiencies are inputs to a model, not
-# results) and per-step gradient bytes (fp32 grads = 4 bytes/param; the
-# audit below re-derives the bytes from the actual fusion buckets).
+# Single-chip device step times (readings from before PR 1, on another
+# installation, of an in-program loop that is gone; the ledger's cells time
+# today's step, ``PERF.md`` section 5, so the modeled efficiencies are
+# inputs to a model, not results) and per-step gradient bytes (fp32 grads
+# = 4 bytes/param; the audit below re-derives the bytes from the actual
+# fusion buckets).
 MODELS = {
     "bert_base_mlm_32x512": {"step_ms_v5e": 109.5, "backward_fraction": 0.62},
     "gpt2_small_16x1024": {"step_ms_v5e": 128.8, "backward_fraction": 0.62},
@@ -123,8 +129,7 @@ def _resolve_compression(name):
 def _build_step(model_key, abstract=False, sharded=False, accum=1,
                 compression=None):
     """Return (step_fn, in_specs, out_specs, args, grad_param_tree) for
-    the model's DP step — the same step bench.py times, on the virtual
-    CPU mesh.
+    the model's DP step on the virtual CPU mesh.
 
     ``abstract=True`` builds params/opt-state as ShapeDtypeStructs via
     ``jax.eval_shape`` (no compute, no backend) — required for the TPU
@@ -758,36 +763,21 @@ def schedule_audit(model_key, topology="v5e:2x2"):
     }
 
 
-def model_scaling(audit_row, chip="v5e", layout_n_ars=None):
-    """Analytic weak-scaling rows for the audited model on real ICI.
-
-    ``layout_n_ars``: all-reduce count in the framework-controlled compiled
-    TPU HLO (from :func:`audit_topology`). The with-overlap column is only
-    credited when the measured layout actually has >=2 distinct collectives
-    to pipeline against the backward pass; with one merged all-reduce the
-    overlap column collapses to the no-overlap value."""
+def model_scaling(audit_row, chip="v5e"):
+    """Analytic weak-scaling rows for the audited model on real ICI: the
+    ring's time beside the step, with none of it hidden and with all that
+    fits under the backward pass hidden. A model; what the compiled step
+    hides is ``--schedule``'s to show and the ``.dp4`` cell's to time."""
     spec = ici_specs()[chip]
     key = audit_row["model"]
     meta = MODELS[key]
     step_ms = meta["step_ms_v5e"]
     wire_bytes = audit_row["gradient_bytes_per_step"]
-    ring_gbps = spec["oneway_gbps_per_link"] * spec["ring_links"]
-    overlap_ok = layout_n_ars is None or layout_n_ars >= 2
     rows = []
     for n in (8, 16, 32):
-        # Ring allreduce moves 2(n-1)/n x bytes over the slowest link.
-        comm_ms = (2 * (n - 1) / n) * wire_bytes / (ring_gbps * 1e9) * 1e3
+        comm_ms = ring_allreduce_ms(wire_bytes, n, chip)
         bwd_ms = step_ms * meta["backward_fraction"]
-        # With k buckets the last bucket's all-reduce cannot overlap (its
-        # gradients are produced last); credit the overlap window only to
-        # the first k-1 buckets' share of the traffic.
-        if overlap_ok and layout_n_ars:
-            overlappable = comm_ms * (layout_n_ars - 1) / layout_n_ars
-            exposed_ms = comm_ms - min(overlappable, bwd_ms)
-        elif overlap_ok:
-            exposed_ms = max(0.0, comm_ms - bwd_ms)
-        else:
-            exposed_ms = comm_ms
+        exposed_ms = max(0.0, comm_ms - bwd_ms)
         rows.append(
             {
                 "n_chips": n,
@@ -809,11 +799,6 @@ def model_scaling(audit_row, chip="v5e", layout_n_ars=None):
             "single_chip_step_ms": step_ms,
             "backward_fraction_overlappable": meta["backward_fraction"],
             "wire_dtype": "fp32 (grad dtype; fp16 compression would halve bytes)",
-            "overlap_credit": (
-                f"measured layout: {layout_n_ars} all-reduces; last bucket "
-                "never overlapped" if layout_n_ars else
-                "structural (no measured layout)"
-            ),
         },
         "rows": rows,
     }
@@ -849,8 +834,7 @@ def main():
         "--parity",
         action="store_true",
         help="audit BOTH optimizer paths for --model and report the "
-        "sharded/psum ring-wire byte ratio (the <=1.1x parity check the "
-        "bench harness consumes)",
+        "sharded/psum ring-wire byte ratio (the <=1.1x parity check)",
     )
     ap.add_argument(
         "--microbatch",
@@ -893,7 +877,6 @@ def main():
         "its collective schedule: synchronous all-reduces, asynchronous "
         "pairs and what lies between each (no chip; an order, not a time)",
     )
-    ap.add_argument("--write-scaling-json", metavar="PATH")
     args = ap.parse_args()
     args.model = aliases.get(args.model, args.model)
 
@@ -1061,9 +1044,9 @@ def main():
     results = []
     for key in keys:
         # Each audit needs a fresh backend world; run in a subprocess when
-        # auditing several models (or when the parent lacks the virtual
-        # devices — the subprocess env always carries the flag).
-        if len(keys) > 1 or args.write_scaling_json:
+        # auditing several models (the subprocess env always carries the
+        # virtual-device flag).
+        if len(keys) > 1:
             # Clamp the forwarded K per model (gpt2's per-shard batch is
             # 2 on the audit mesh; a blanket K=4 would abort the whole
             # multi-model sweep at trace time).
@@ -1139,58 +1122,7 @@ def main():
             print(json.dumps(row), flush=True)
             return
 
-    if args.write_scaling_json:
-        measured = None
-        bench_scaling = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "bench_scaling.py",
-        )
-        out = subprocess.run(
-            [sys.executable, bench_scaling],
-            capture_output=True,
-            text=True,
-            check=True,
-        )
-        measured = json.loads(out.stdout.strip().splitlines()[-1])
-        # Re-derive the modeled scaling with the measured TPU-HLO layout:
-        # overlap credit requires >=2 all-reduces in the framework layout.
-        for r in results:
-            topo_row = r.get("tpu_hlo_audit") or {}
-            n_ars = (topo_row.get("framework_layout") or {}).get(
-                "n_all_reduce"
-            )
-            r["modeled_ici_scaling"] = {
-                chip: model_scaling(r, chip, layout_n_ars=n_ars)
-                for chip in ici_specs()
-            }
-        package = {
-            "metric": "scaling_evidence_package",
-            # Headline the CONSERVATIVE model (zero overlap credit) so the
-            # artifact cannot overstate the north-star claim.
-            "value": min(
-                r["modeled_ici_scaling"]["v4"]["rows"][-1][
-                    "efficiency_no_overlap"
-                ]
-                for r in results
-            ),
-            "unit": "min modeled efficiency at v4-32, zero overlap credited",
-            "measured_cpu_mesh": measured,
-            "comm_audit": results,
-            "provenance": (
-                "audit: timeline FUSE_BUCKETS + compiled 8-device CPU HLO "
-                "scan + REAL TPU HLO via PJRT topology AOT "
-                "(tools/comm_audit.py --topology, v5e:2x4); model: ring "
-                "allreduce over stated ICI link bandwidths against "
-                "round-5 measured step times (docs/perf_analysis_r05.md); "
-                "overlap credit gated on the measured framework layout "
-                "(>=2 all-reduces; last bucket never credited)"
-            ),
-        }
-        with open(args.write_scaling_json, "w") as f:
-            json.dump(package, f, indent=1)
-        print(f"wrote {args.write_scaling_json}")
-    else:
-        print(json.dumps(results, indent=1))
+    print(json.dumps(results, indent=1))
 
 
 if __name__ == "__main__":

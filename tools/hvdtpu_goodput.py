@@ -155,10 +155,6 @@ def trace_crosscheck(
         if not any(n in span_secs for n in sources):
             continue
         ledger_s = job["totals"][cat]
-        # exposed_comm is carved OUT of the device span, so the trace's
-        # device total naturally exceeds the ledger's compute by it.
-        if cat == "compute":
-            ledger_s += job["totals"]["exposed_comm"]
         big = max(ledger_s, trace_s)
         ok = big < 1.0 or abs(ledger_s - trace_s) <= tolerance * big
         out.append({
