@@ -11,8 +11,9 @@ model in ``models/``.  This module provides it:
 * :func:`flash_attention` — blockwise online-softmax attention
   (Dao et al., FlashAttention) as a Pallas kernel: Q blocks stay resident
   in VMEM, K/V stream through in ``block_k`` tiles, the MXU sees
-  ``[block_q, d] x [d, block_k]`` matmuls, and the S×S score matrix is
-  never materialized in HBM.
+  ``[block_k, d] x [d, block_q]`` matmuls (the scores are held
+  keys-by-queries, so what is kept per q row lies along the lanes), and
+  the S×S score matrix is never materialized in HBM.
 * :func:`flash_attention_with_lse` — same kernel, additionally returning
   the per-row log-sum-exp.  ``(out, lse)`` pairs are the composable form:
   ring attention merges one pair per ring hop with
@@ -137,10 +138,11 @@ def _compute_tile(block: int, interpret: bool) -> int:
     """Edge of the compute tile for a copied block of ``block`` rows (or
     columns) of a causal call: half the block, halved again while it is
     over 256, as far as the layout allows — a multiple of the 128 lanes
-    of a vreg when compiled (the tile's columns are the scores' lane axis
-    and its rows the lane axis of the backward's row statistics), of the
-    8 sublanes of one in the interpreter, where small test shapes must
-    still cross every class.  Measured on the v5e at s 1024, d 64
+    of a vreg when compiled (the tile's rows are the lane axis of the
+    keys-by-queries scores, of the row statistics and of the ``[d, rows]``
+    accumulators; its columns are the contraction the MXU takes 128 deep),
+    of the 8 sublanes of one in the interpreter, where small test shapes
+    must still cross every class.  Measured on the v5e at s 1024, d 64
     (PERF.md, PR 25): 128 loses what 256 gains, because the per-row
     softmax bookkeeping is paid per q tile and does not shrink with it."""
     floor = 8 if interpret else _LANES
@@ -303,12 +305,13 @@ def _block_dims(q_ref, k_ref, packed: bool, d: int):
 
 
 def _valid_mask(geom, row0, col0, tq: int, tk: int, causal: bool):
-    """[tq, tk] validity of the scores whose first row sits at global
-    position ``row0`` and whose first column is K/V column ``col0``."""
-    col = col0 + lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+    """[tk, tq] validity of the keys-by-queries scores whose first q row
+    sits at global position ``row0`` and whose first K/V column is
+    ``col0``: columns on sublanes, q positions on lanes."""
+    col = col0 + lax.broadcasted_iota(jnp.int32, (tk, 1), 0)
     valid = col < geom[2]  # mask K/V padding
     if causal:
-        q_pos = row0 + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        q_pos = row0 + lax.broadcasted_iota(jnp.int32, (1, tq), 1)
         valid = jnp.logical_and(valid, q_pos >= geom[1] + col)
     return valid
 
@@ -318,9 +321,9 @@ def _drive_tiles(update, geom, qi, kj, *, q_len: Optional[int],
                  tiles: Tuple[int, int]):
     """Drive a kernel's ``update(rq, rk, valid)`` over one (q block, K/V
     block) pair: ``rq`` / ``rk`` select the q / K/V rows, ``valid`` is
-    their validity mask or ``None`` on the unmasked path.  Causal: slab by
-    slab (``_for_causal_tiles``); else the whole block at once, masked only
-    if ``masked``.  ``geom``: the scalars ``(q_offset, kv_offset,
+    their ``[K/V rows, q rows]`` validity mask or ``None`` on the unmasked
+    path.  Causal: slab by slab (``_for_causal_tiles``); else the whole
+    block at once, masked only if ``masked``.  ``geom``: the scalars ``(q_offset, kv_offset,
     kv_len)``; ``q_len``: the real q length where padded q rows need the
     masked path (the backward), else ``None``."""
     row0 = geom[0] + qi * block_q  # global position of the block's row 0
@@ -385,6 +388,18 @@ def _fwd_kernel(
     loop below is a static unroll.  Heads sit on a LEADING block dim
     (page-select slicing — Mosaic cannot relayout a middle-axis slice).
 
+    The scores are held keys-by-queries, ``sᵀ = K·Qᵀ`` as ``[cols, rows]``,
+    so everything kept per q row lies along the lanes: the running max
+    and sum are ``[1, rows]`` vectors (a reduction over a score column is
+    a reduction over sublanes, and they broadcast back over sublanes as
+    they lie), ``lse`` leaves in the layout it is stored in, and the
+    accumulator is ``accᵀ = Vᵀ·pᵀ``, ``[d, rows]``, all of whose lanes
+    are live at ``d`` 64.  What is transposed is small: ``V``'s
+    ``[cols, d]`` tile per update and the accumulator once per q block.
+    Against ``[rows, 1]`` statistics beside ``[rows, cols]`` scores this
+    took 13% off the kernel at s 1024, causal, and 56% at s 512 (PERF.md,
+    PR 31).
+
     A causal call walks the block in ``tiles = (tq, tk)`` compute tiles
     (see "Causal tile geometry"): tiles above the diagonal are not
     visited, tiles below it run the unmasked update.  Any other call
@@ -393,7 +408,8 @@ def _fwd_kernel(
     qoff_ref / kvoff_ref / kvlen_ref: SMEM int32 [1, 1]; q_ref:
     [1, G, block_q, d]; k_ref/v_ref: [1, G, block_k, d]; o_ref:
     [1, G, block_q, d]; lse_ref: [1, G, 8, block_q] (8 = min sublane
-    tile; caller reads sublane 0).
+    tile; caller reads sublane 0); acc_ref: [G, d, block_q]; m_ref /
+    l_ref: [G, 1, block_q].
     """
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
@@ -417,38 +433,38 @@ def _fwd_kernel(
             # the MXU is native bf16xbf16->fp32; upcasting to fp32 first
             # costs ~4-6 MXU passes per dot (measured 15% kernel
             # efficiency before this).  Softmax statistics are fp32.
-            s = jax.lax.dot_general(
-                _head(q_ref, g, d, packed, rq),
+            s_t = jax.lax.dot_general(
                 _head(k_ref, g, d, packed, rk),
+                _head(q_ref, g, d, packed, rq),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * sm_scale  # [rows, cols] fp32
+            ) * sm_scale  # [cols, rows] fp32
             if valid is not None:
-                s = jnp.where(valid, s, _NEG_INF)
+                s_t = jnp.where(valid, s_t, _NEG_INF)
 
-            m = m_ref[g, rq, :]
-            l = l_ref[g, rq, :]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            m = m_ref[g, :, rq]  # [1, rows]
+            l = l_ref[g, :, rq]
+            m_new = jnp.maximum(m, jnp.max(s_t, axis=0, keepdims=True))
             # m_new == NEG_INF only for rows with no valid column so far;
             # keep exponent args finite there (p is zeroed by the mask).
             m_safe = m_new if valid is None else jnp.maximum(
                 m_new, _NEG_INF / 2
             )
-            p = jnp.exp(s - m_safe)
+            p_t = jnp.exp(s_t - m_safe)
             if valid is not None:
-                p = jnp.where(valid, p, 0.0)
+                p_t = jnp.where(valid, p_t, 0.0)
             corr = jnp.exp(m - m_safe)
-            l_ref[g, rq, :] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            m_ref[g, rq, :] = m_new
+            l_ref[g, :, rq] = l * corr + jnp.sum(p_t, axis=0, keepdims=True)
+            m_ref[g, :, rq] = m_new
             # p in the V dtype for a native-MXU dot (fp32 accumulate
             # keeps the reduction exact; the p rounding is the standard
-            # flash trade).
-            acc_ref[g, rq, :] = acc_ref[g, rq, :] * corr + jax.lax.dot_general(
-                p.astype(v_ref.dtype),
+            # flash trade).  Vᵀ·pᵀ: the thin operand is the one turned.
+            acc_ref[g, :, rq] = acc_ref[g, :, rq] * corr + jax.lax.dot_general(
                 _head(v_ref, g, d, packed, rk),
-                dimension_numbers=(((1,), (0,)), ((), ())),
+                p_t.astype(v_ref.dtype),
+                dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
+            )  # [d, rows]
 
     _drive_tiles(update, geom, qi, kj, q_len=None, block_q=block_q,
                  block_k=block_k, causal=causal, masked=masked, tiles=tiles)
@@ -456,7 +472,7 @@ def _fwd_kernel(
     @pl.when(kj == nk - 1)
     def _finalize():
         for g in range(group):
-            l = l_ref[g, :, :]
+            l = l_ref[g, :, :]  # [1, block_q]
             if masked:
                 has_any = l > 0.0
                 l_safe = jnp.where(has_any, l, 1.0)
@@ -469,10 +485,10 @@ def _fwd_kernel(
                 lse = m_ref[g, :, :] + jnp.log(l_safe)
             _head_store(
                 o_ref, g, d, packed,
-                (acc_ref[g, :, :] / l_safe).astype(o_ref.dtype),
+                (acc_ref[g, :, :] / l_safe).T.astype(o_ref.dtype),
             )
             lse_ref[0, g] = jnp.broadcast_to(
-                lse.reshape(1, block_q), (lse_ref.shape[2], block_q)
+                lse, (lse_ref.shape[2], block_q)
             )
 
 
@@ -690,9 +706,9 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
                 ),
             ],
             scratch_shapes=[
-                _VMEM((group, block_q, d), jnp.float32),
-                _VMEM((group, block_q, 1), jnp.float32),
-                _VMEM((group, block_q, 1), jnp.float32),
+                _VMEM((group, d, block_q), jnp.float32),
+                _VMEM((group, 1, block_q), jnp.float32),
+                _VMEM((group, 1, block_q), jnp.float32),
             ],
         ),
         out_shape=[
@@ -733,6 +749,19 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
 # Causal calls walk each block pair in the forward's compute tiles and
 # classes; both kernels take a q tile and loop over its K/V tiles (the
 # dk/dv accumulators do not care in which order their tiles are met).
+#
+# Both kernels hold their score tile keys-by-queries like the forward,
+# ``sᵀ = K·Qᵀ`` and ``dPᵀ = V·gᵀ`` as ``[cols, rows]``: the row statistics
+# (``lse``, ``Δ``, ``g_lse``), stored with the rows on lanes, are
+# ``[1, rows]`` reads that broadcast over sublanes as they lie.  In dk/dv
+# the accumulating products ``pᵀ·g`` and ``dsᵀ·Q`` are then plain
+# ``[cols, rows] x [rows, d]`` matmuls (the MXU takes a transposed right
+# operand for nothing and a transposed left operand not at all; the other
+# way round Mosaic transposed ``p`` and ``ds``, the kernel's largest
+# arrays, on the XLU twice per update).  dq accumulates ``dqᵀ = Kᵀ·dsᵀ``
+# as ``[d, rows]``: the thin ``[cols, d]`` K tile is the operand turned,
+# and the accumulator is turned back once per q block.  Same mathematics
+# and dtypes as queries-by-keys; measured in PERF.md, PR 31.
 # ---------------------------------------------------------------------------
 
 
@@ -740,8 +769,9 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
                     g_ref, g, rq, rk, valid, *, sm_scale: float,
                     packed: bool = False, d: int = 0):
     """Shared per-(q rows ``rq``, K/V rows ``rk``, head) recompute:
-    returns (p, ds, q_blk, g_blk, k_blk).  ``valid=None`` is the unmasked
-    path.
+    returns (pᵀ, dsᵀ, q_blk, g_blk, k_blk), the two score-sized arrays
+    keys-by-queries, ``[cols, rows]``, like ``valid`` (``None`` is the
+    unmasked path); the row statistics are read as stored, ``[1, rows]``.
 
     Padded / fully-masked Q rows carry ``lse == -inf`` and zero ``g``;
     ``row_ok`` zeroes their ``p`` so they contribute nothing (a tile that
@@ -753,35 +783,34 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
     g_blk = _head(g_ref, g, d, packed, rq)
     k_blk = _head(k_ref, g, d, packed, rk)
     v_blk = _head(v_ref, g, d, packed, rk)
-    rows = q_blk.shape[0]
 
-    s = jax.lax.dot_general(
-        q_blk,
+    s_t = jax.lax.dot_general(
         k_blk,
+        q_blk,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * sm_scale  # [rows, cols] fp32
+    ) * sm_scale  # [cols, rows] fp32
 
-    lse_row = lse_ref[0, g, 0, rq].reshape(rows, 1)
+    lse_row = lse_ref[0, g, 0:1, rq]  # [1, rows]
     if valid is not None:
         row_ok = lse_row > _NEG_INF / 4  # -inf rows: no valid keys anywhere
         lse_safe = jnp.where(row_ok, lse_row, 0.0)
-        p = jnp.where(
-            jnp.logical_and(valid, row_ok), jnp.exp(s - lse_safe), 0.0
+        p_t = jnp.where(
+            jnp.logical_and(valid, row_ok), jnp.exp(s_t - lse_safe), 0.0
         )
     else:
-        p = jnp.exp(s - lse_row)
+        p_t = jnp.exp(s_t - lse_row)
 
-    dp = jax.lax.dot_general(
-        g_blk,
+    dp_t = jax.lax.dot_general(
         v_blk,
+        g_blk,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    delta_row = delta_ref[0, g, 0, rq].reshape(rows, 1)
-    glse_row = glse_ref[0, g, 0, rq].reshape(rows, 1)
-    ds = p * (dp - delta_row) + glse_row * p
-    return p, ds, q_blk, g_blk, k_blk
+    delta_row = delta_ref[0, g, 0:1, rq]
+    glse_row = glse_ref[0, g, 0:1, rq]
+    ds_t = p_t * (dp_t - delta_row) + glse_row * p_t
+    return p_t, ds_t, q_blk, g_blk, k_blk
 
 
 def _bwd_kernel_dkdv(
@@ -805,18 +834,18 @@ def _bwd_kernel_dkdv(
 
     def update(rq, rk, valid):
         for g in range(group):
-            p, ds, q_blk, g_blk, _ = _recompute_p_ds(
+            p_t, ds_t, q_blk, g_blk, _ = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
             )
             dv_acc[g, rk, :] = dv_acc[g, rk, :] + jax.lax.dot_general(
-                p.astype(g_blk.dtype), g_blk,
-                dimension_numbers=(((0,), (0,)), ((), ())),
+                p_t.astype(g_blk.dtype), g_blk,
+                dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             dk_acc[g, rk, :] = dk_acc[g, rk, :] + jax.lax.dot_general(
-                ds.astype(q_blk.dtype), q_blk,
-                dimension_numbers=(((0,), (0,)), ((), ())),
+                ds_t.astype(q_blk.dtype), q_blk,
+                dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale
 
@@ -841,7 +870,8 @@ def _bwd_kernel_dq(
     q_len: int, packed: bool = False, d: int = 0,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
-    K tiles; the per-head loop is a static unroll (see forward)."""
+    K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
+    static unroll (see forward)."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -854,15 +884,15 @@ def _bwd_kernel_dq(
 
     def update(rq, rk, valid):
         for g in range(group):
-            _, ds, _, _, k_blk = _recompute_p_ds(
+            _, ds_t, _, _, k_blk = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
             )
-            dq_acc[g, rq, :] = dq_acc[g, rq, :] + jax.lax.dot_general(
-                ds.astype(k_blk.dtype), k_blk,
-                dimension_numbers=(((1,), (0,)), ((), ())),
+            dq_acc[g, :, rq] = dq_acc[g, :, rq] + jax.lax.dot_general(
+                k_blk, ds_t.astype(k_blk.dtype),
+                dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * sm_scale
+            ) * sm_scale  # [d, rows]
 
     _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
                  block_k=block_k, causal=causal, masked=masked, tiles=tiles)
@@ -871,7 +901,7 @@ def _bwd_kernel_dq(
     def _finalize():
         for g in range(group):
             _head_store(
-                dq_ref, g, d, packed, dq_acc[g, :, :].astype(dq_ref.dtype)
+                dq_ref, g, d, packed, dq_acc[g, :, :].T.astype(dq_ref.dtype)
             )
 
 
@@ -1018,7 +1048,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             in_specs=[stat_spec, stat_spec, stat_spec,
                       q_spec, kv_spec, kv_spec, q_spec],
             out_specs=q_spec,
-            scratch_shapes=[_VMEM((group, block_q, d), jnp.float32)],
+            scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
         ),
         out_shape=shape_like(q, sq_pad),
         **call_params,

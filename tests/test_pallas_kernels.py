@@ -82,21 +82,45 @@ def test_flash_offsets_shift_causal_mask():
     assert np.all(np.isneginf(np.asarray(lse2)))
 
 
+# name: (sq, skv, layout, whether ``lse`` gets a cotangent).  The padded
+# cases leave padded q rows AND padded K/V columns in the one block a
+# non-causal backward update takes (blocks of 16: 40 -> 48 rows, 56 -> 64
+# columns), under a non-zero ``g_lse``.
+_GRAD_CASES = {
+    "even-bshd": (48, 48, "bshd", False),
+    "padded-lse-bsm": (40, 56, "bsm", True),
+    "padded-lse-bhsd": (40, 56, "bhsd", True),
+}
+
+
+@pytest.mark.parametrize("case", list(_GRAD_CASES))
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_grads_match_reference(causal):
-    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 1, 48, 2, 16)
+def test_flash_grads_match_reference(causal, case):
+    sq, skv, layout, with_lse = _GRAD_CASES[case]
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 1, sq, 2, 16, skv=skv)
 
-    def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16)
-        return jnp.sum(jnp.sin(o))
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            total = jnp.sum(jnp.sin(out))
+            return total + jnp.sum(lse ** 2) if with_lse else total
+        return f
 
-    def loss_ref(q, k, v):
-        return jnp.sum(jnp.sin(dot_product_attention(q, k, v, causal=causal)))
+    def ref(q, k, v):
+        if causal:
+            return _offset_reference(q, k, v, 0, 0)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        return (dot_product_attention(q, k, v, causal=False),
+                jax.scipy.special.logsumexp(scores, axis=-1))
 
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    flash = lambda q, k, v: _flash_at(  # noqa: E731
+        q, k, v, 0, 0, 16, layout, causal=causal
+    )
+    g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    tol = 1e-4 if with_lse else 5e-5
     for a, b in zip(g1, g2):
-        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+        np.testing.assert_allclose(a, b, atol=tol, rtol=tol)
 
 
 def test_combine_blocks_recovers_full_attention():
@@ -236,6 +260,8 @@ _GEOMETRIES = {
     "s96-b16-bsm": (96, 96, 16, "bsm"),
     "sq64-skv96-b32-bhsd": (64, 96, 32, "bhsd"),
     "s72-padded-b32-bsm": (72, 72, 32, "bsm"),
+    # one padded q tile (rows 8..15 of a 16-row block, 12 real)
+    "s12-padded-b16-bhsd": (12, 12, 16, "bhsd"),
     # the models' shape: K/V block twice the q block, 4 tiles wide
     "s128-bq32-bk64-bsm": (128, 128, (32, 64), "bsm"),
     "s512-b256-tile128-bsm": (512, 512, 256, "bsm"),
@@ -247,6 +273,9 @@ _OFFSETS = {
     "past-hop": lambda sq, skv: (skv, 0),
     "future-hop": lambda sq, skv: (0, sq),
     "mid-tile": lambda sq, skv: (5, 0),
+    # rows 0..9 see no key: real rows whose ``lse`` is -inf (at s 12 two of
+    # them share the padded q tile with two rows that do see keys)
+    "keys-ahead": lambda sq, skv: (0, 10),
 }
 
 
@@ -267,24 +296,24 @@ def _offset_reference(q, k, v, q_offset, kv_offset):
     return out, lse
 
 
-def _flash_at(q, k, v, q_offset, kv_offset, block, layout):
+def _flash_at(q, k, v, q_offset, kv_offset, block, layout, causal=True):
     """flash (out, lse) for [B,S,H,D] inputs through ``layout``."""
     b, sq, h, d = q.shape
     block_q, block_k = block if isinstance(block, tuple) else (block, block)
+    kwargs = dict(
+        causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+        block_q=block_q, block_k=block_k, layout=layout,
+    )
+    if layout == "bshd":
+        return flash_attention_with_lse(q, k, v, **kwargs)
     if layout == "bsm":
         pack = lambda x: x.reshape(b, x.shape[1], h * d)  # noqa: E731
         out, lse = flash_attention_with_lse(
-            pack(q), pack(k), pack(v), causal=True, q_offset=q_offset,
-            kv_offset=kv_offset, block_q=block_q, block_k=block_k,
-            layout="bsm", n_heads=h,
+            pack(q), pack(k), pack(v), n_heads=h, **kwargs
         )
         return out.reshape(b, sq, h, d), lse
     mv = lambda x: jnp.moveaxis(x, 2, 1)  # noqa: E731
-    out, lse = flash_attention_with_lse(
-        mv(q), mv(k), mv(v), causal=True, q_offset=q_offset,
-        kv_offset=kv_offset, block_q=block_q, block_k=block_k,
-        layout="bhsd",
-    )
+    out, lse = flash_attention_with_lse(mv(q), mv(k), mv(v), **kwargs)
     return jnp.moveaxis(out, 1, 2), lse
 
 
@@ -476,3 +505,105 @@ def test_flash_tile_counters_at_gpt2_shape():
 
     r = jax.ShapeDtypeStruct((), jnp.int32)
     assert _counted(ring_hop, *x(1024), r) == (0, 0, 0, 1)
+
+
+# ---------------------------------------------------------------------------
+# Orientation of the score tile, read from the traced kernels (no chip: the
+# ``pallas_call``'s jaxpr at the two cells' block shapes).  The orientation
+# is unconditional, so there is nothing to count at run time; these fail if
+# a later edit turns a tile back.
+# ---------------------------------------------------------------------------
+
+# name: (sequence, causal): GPT-2's cell and BERT's MLM cell, one batch row
+_CELL_SHAPES = {"gpt2-s1024-causal": (1024, True), "mlm-s512": (512, False)}
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    from horovod_tpu.analysis.jaxpr_walk import _sub_jaxprs_generic
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs_generic(eqn):
+            yield from _walk(sub)
+
+
+def _kernel_eqns(kernel, cell):
+    """Equations of the traced body of the flash kernel named ``kernel``
+    inside forward + backward at ``cell``'s shape, as the chip compiles it
+    (12 heads of 64, packed, bf16, ``interpret=False``; nothing lowered)."""
+    s, causal = _CELL_SHAPES[cell]
+    x = jax.ShapeDtypeStruct((1, s, 768), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, causal=causal, layout="bsm", n_heads=12,
+            interpret=False,
+        ).astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    calls = [
+        e for e in _walk(traced.jaxpr)
+        if e.primitive.name == "pallas_call" and e.params["name"] == kernel
+    ]
+    assert len(calls) == 1, (kernel, len(calls))
+    return list(_walk(calls[0].params["jaxpr"]))
+
+
+def _column_reshapes(eqns):
+    """Reshapes into a ``[rows, 1]`` column (a lane vector relaid)."""
+    return [
+        e for e in eqns if e.primitive.name == "reshape"
+        and e.outvars[0].aval.shape[-1] == 1
+    ]
+
+
+@pytest.mark.parametrize("cell", list(_CELL_SHAPES))
+def test_dkv_kernel_scores_keys_by_queries(cell):
+    """dK/dV: all four matmuls per head contract dimension 1 of their left
+    operand (``K Qᵀ``, ``V gᵀ``, ``pᵀ g``, ``dsᵀ Q``: no operand for Mosaic
+    to transpose), the body holds no transpose, and no row statistic is
+    relaid from its stored lane vector into a ``[rows, 1]`` column."""
+    eqns = _kernel_eqns("hvd_flash_bwd_dkv", cell)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert dots and len(dots) % 4 == 0
+    for dot in dots:
+        (lhs_contract, _), _ = dot.params["dimension_numbers"]
+        assert tuple(lhs_contract) == (1,), dot
+    assert not [e for e in eqns if e.primitive.name == "transpose"]
+    assert not _column_reshapes(eqns)
+    # the score-sized operands are [cols, rows]: K/V rows first
+    tile = 256 if _CELL_SHAPES[cell][1] else 512
+    for dot in dots:
+        lhs, rhs = (v.aval.shape for v in dot.invars)
+        if lhs[1] != 64:  # pᵀ or dsᵀ against g or Q
+            assert lhs[1] == tile and rhs == (tile, 64), (lhs, rhs)
+
+
+@pytest.mark.parametrize("cell", list(_CELL_SHAPES))
+@pytest.mark.parametrize(
+    "kernel,matmuls", [("hvd_flash_fwd", 2), ("hvd_flash_bwd_dq", 3)]
+)
+def test_fwd_and_dq_kernels_keep_rows_on_lanes(kernel, matmuls, cell):
+    """The two kernels that accumulate over q rows: nothing kept per q row
+    is ever a ``[rows, 1]`` column, the only left operand a matmul
+    contracts on dimension 0 (so the only one Mosaic must transpose) is a
+    thin ``[cols, 64]`` K or V tile against the ``[cols, rows]`` scores,
+    and the only transposes are of the ``[64, block_q]`` accumulators,
+    once per head where the q block is written."""
+    eqns = _kernel_eqns(kernel, cell)
+    assert not _column_reshapes(eqns)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert dots and len(dots) % matmuls == 0
+    for dot in dots:
+        (lhs_contract, rhs_contract), _ = dot.params["dimension_numbers"]
+        lhs, rhs = (v.aval.shape for v in dot.invars)
+        if tuple(lhs_contract) == (0,):
+            assert lhs[1] == 64 and tuple(rhs_contract) == (0,), (lhs, rhs)
+            assert rhs[0] == lhs[0] and rhs[1] in (256, 512), (lhs, rhs)
+    turned = [
+        tuple(e.invars[0].aval.shape) for e in eqns
+        if e.primitive.name == "transpose"
+    ]
+    # one per head of the program's group
+    assert set(turned) == {(64, 512)} and 12 % len(turned) == 0, turned
