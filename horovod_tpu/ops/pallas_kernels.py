@@ -753,16 +753,38 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
 # Both kernels hold their score tile keys-by-queries like the forward,
 # ``sᵀ = K·Qᵀ`` and ``dPᵀ = V·gᵀ`` as ``[cols, rows]``: the row statistics
 # (``lse``, ``Δ``, ``g_lse``), stored with the rows on lanes, are
-# ``[1, rows]`` reads that broadcast over sublanes as they lie.  In dk/dv
-# the accumulating products ``pᵀ·g`` and ``dsᵀ·Q`` are then plain
-# ``[cols, rows] x [rows, d]`` matmuls (the MXU takes a transposed right
-# operand for nothing and a transposed left operand not at all; the other
-# way round Mosaic transposed ``p`` and ``ds``, the kernel's largest
-# arrays, on the XLU twice per update).  dq accumulates ``dqᵀ = Kᵀ·dsᵀ``
-# as ``[d, rows]``: the thin ``[cols, d]`` K tile is the operand turned,
-# and the accumulator is turned back once per q block.  Same mathematics
-# and dtypes as queries-by-keys; measured in PERF.md, PR 31.
+# ``[1, rows]`` reads that broadcast over sublanes as they lie.
+#
+# All three accumulating products stream their THIN operand and leave the
+# score-sized one standing in the MXU: dk/dv accumulates ``dvᵀ = gᵀ·p`` and
+# ``dkᵀ = Qᵀ·ds`` as ``[d, cols]`` (left operand the ``[rows, d]`` g / Q
+# tile, contracted on its rows; right operand ``pᵀ`` / ``dsᵀ`` as they
+# lie, contracted on theirs), dq accumulates ``dqᵀ = Kᵀ·dsᵀ`` as
+# ``[d, rows]``.  The MXU takes a transposed right operand for nothing and
+# a transposed left operand not at all, so what Mosaic turns on the XLU is
+# the thin tile, never ``p`` or ``ds``, the kernels' largest arrays; each
+# accumulator is turned back once per head where its block is written.
+# At d 64 the other way round (``pᵀ·g``, ``[cols, rows] x [rows, 64]``)
+# streamed every row of the scores through passes whose output filled 64
+# of the MXU's 128 columns: dk/dv 815 -> 757 us a layer at s 1024 causal,
+# 718 -> 524 at s 512 (PERF.md, PR 34).  With heads of 128 that pass is
+# full, and dk/dv keeps ``pᵀ·g`` / ``dsᵀ·Q`` into ``[cols, d]``
+# (``_dkv_streams_thin``).  Same mathematics and dtypes as queries-by-keys.
 # ---------------------------------------------------------------------------
+
+
+def _dkv_streams_thin(d: int) -> bool:
+    """Whether dK/dV's two accumulating matmuls stream the thin
+    ``[rows, d]`` operand (``dvᵀ = gᵀ·p``, ``dkᵀ = Qᵀ·ds``, accumulators
+    ``[d, cols]``) and leave ``pᵀ`` / ``dsᵀ`` standing in the MXU: where
+    a head is narrower than the 128 lanes, so that ``pᵀ·g`` would stream
+    every row of the scores through passes whose output fills ``d`` of 128
+    columns.  At ``d`` 128 that pass is full as it is and the turned form
+    only adds stationary-operand loads: measured there 474 against 431 us a
+    layer at s 1024 causal and 290 against 290 at s 512 (PERF.md, PR 34).
+    Static, from a shape the kernel already has; counted at build time as
+    ``flash.dkv.thin_streamed``."""
+    return d < _LANES
 
 
 def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
@@ -820,17 +842,37 @@ def _bwd_kernel_dkdv(
     q_len: int, packed: bool = False, d: int = 0,
 ):
     """grid (b, h-group, kj, qi): each K tile accumulates over streamed
-    Q blocks; the per-head loop is a static unroll (see forward)."""
+    Q blocks; the per-head loop is a static unroll (see forward).  Heads
+    narrower than the lanes stream their thin operand
+    (:func:`_dkv_streams_thin`): the accumulators are ``dkᵀ`` / ``dvᵀ``,
+    ``[G, d, block_k]``, the K/V rows on lanes (a causal slab is a static
+    lane slice from 0, a whole number of K/V tiles), turned back once per
+    head where the K/V block is written.  Else ``[G, block_k, d]``."""
     qi = pl.program_id(3)
     kj = pl.program_id(2)
     nq = pl.num_programs(3)
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
+    thin = _dkv_streams_thin(d)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:, :, :] = jnp.zeros_like(dk_acc)
         dv_acc[:, :, :] = jnp.zeros_like(dv_acc)
+
+    def product(scores_t, blk):
+        """``blkᵀ·scores`` as ``[d, cols]`` (the ``[rows, d]`` g / Q tile
+        streamed and turned, ``pᵀ`` / ``dsᵀ`` standing as the right
+        operand), or ``scores_t·blk`` as ``[cols, d]``; fp32."""
+        scores_t = scores_t.astype(blk.dtype)
+        lhs, rhs, contract = (
+            (blk, scores_t, ((0,), (1,))) if thin
+            else (scores_t, blk, ((1,), (0,)))
+        )
+        return jax.lax.dot_general(
+            lhs, rhs, dimension_numbers=(contract, ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     def update(rq, rk, valid):
         for g in range(group):
@@ -838,16 +880,9 @@ def _bwd_kernel_dkdv(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
             )
-            dv_acc[g, rk, :] = dv_acc[g, rk, :] + jax.lax.dot_general(
-                p_t.astype(g_blk.dtype), g_blk,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dk_acc[g, rk, :] = dk_acc[g, rk, :] + jax.lax.dot_general(
-                ds_t.astype(q_blk.dtype), q_blk,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale
+            at = (g, slice(None), rk) if thin else (g, rk, slice(None))
+            dv_acc[at] = dv_acc[at] + product(p_t, g_blk)
+            dk_acc[at] = dk_acc[at] + product(ds_t, q_blk) * sm_scale
 
     _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
                  block_k=block_k, causal=causal, masked=masked, tiles=tiles)
@@ -855,12 +890,9 @@ def _bwd_kernel_dkdv(
     @pl.when(qi == nq - 1)
     def _finalize():
         for g in range(group):
-            _head_store(
-                dk_ref, g, d, packed, dk_acc[g, :, :].astype(dk_ref.dtype)
-            )
-            _head_store(
-                dv_ref, g, d, packed, dv_acc[g, :, :].astype(dv_ref.dtype)
-            )
+            for ref, acc in ((dk_ref, dk_acc), (dv_ref, dv_acc)):
+                grad = acc[g, :, :].T if thin else acc[g, :, :]
+                _head_store(ref, g, d, packed, grad.astype(ref.dtype))
 
 
 def _bwd_kernel_dq(
@@ -917,6 +949,8 @@ def _bwd_pallas(
         # one count for each of the two kernels
         for _ in range(2):
             _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=True))
+    if _dkv_streams_thin(p.d):
+        _registry.always().counter("flash.dkv.thin_streamed").inc()
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
         g_lse, p=p, sm_scale=sm_scale, causal=causal,
@@ -1020,6 +1054,8 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
 
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
     stat_spec, q_spec, kv_spec = specs("kq")
+    dkv_acc = (group, d, block_k) if _dkv_streams_thin(d) else (
+        group, block_k, d)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel_dkdv, **kernel_params),
         grid_spec=_grid_spec(
@@ -1028,10 +1064,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             in_specs=[stat_spec, stat_spec, stat_spec,
                       q_spec, kv_spec, kv_spec, q_spec],
             out_specs=[kv_spec, kv_spec],
-            scratch_shapes=[
-                _VMEM((group, block_k, d), jnp.float32),
-                _VMEM((group, block_k, d), jnp.float32),
-            ],
+            scratch_shapes=[_VMEM(dkv_acc, jnp.float32)] * 2,
         ),
         out_shape=[shape_like(k, skv_pad), shape_like(v, skv_pad)],
         **call_params,

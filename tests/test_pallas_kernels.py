@@ -82,22 +82,31 @@ def test_flash_offsets_shift_causal_mask():
     assert np.all(np.isneginf(np.asarray(lse2)))
 
 
-# name: (sq, skv, layout, whether ``lse`` gets a cotangent).  The padded
-# cases leave padded q rows AND padded K/V columns in the one block a
-# non-causal backward update takes (blocks of 16: 40 -> 48 rows, 56 -> 64
-# columns), under a non-zero ``g_lse``.
+# name: (sq, skv, block or (block_q, block_k), head width, layout, whether
+# ``lse`` gets a cotangent).  The padded cases leave padded q rows AND
+# padded K/V columns in the one block a non-causal backward update takes
+# (blocks of 16: 40 -> 48 rows, 56 -> 64 columns), under a non-zero
+# ``g_lse``.  The cross cases give dK/dV's ``[d, block_k]`` accumulators a
+# lane axis that is no multiple of 128 (one K/V block of 200 columns, the
+# whole K/V; 136 in two blocks of 72, the second half padding) beside a q
+# length that differs from it.  Heads of 128 take dK/dV's other form
+# (``[block_k, d]`` accumulators, ``_dkv_streams_thin``).
 _GRAD_CASES = {
-    "even-bshd": (48, 48, "bshd", False),
-    "padded-lse-bsm": (40, 56, "bsm", True),
-    "padded-lse-bhsd": (40, 56, "bhsd", True),
+    "even-bshd": (48, 48, 16, 16, "bshd", False),
+    "padded-lse-bsm": (40, 56, 16, 16, "bsm", True),
+    "padded-lse-bhsd": (40, 56, 16, 16, "bhsd", True),
+    "cross-skv200-lse-bsm": (72, 200, (32, 256), 16, "bsm", True),
+    "cross-skv200-lse-bhsd": (72, 200, (32, 256), 16, "bhsd", True),
+    "cross-skv136-bk72-lse-bhsd": (200, 136, (64, 72), 16, "bhsd", True),
+    "d128-padded-lse-bsm": (40, 56, 16, 128, "bsm", True),
 }
 
 
 @pytest.mark.parametrize("case", list(_GRAD_CASES))
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_grads_match_reference(causal, case):
-    sq, skv, layout, with_lse = _GRAD_CASES[case]
-    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 1, sq, 2, 16, skv=skv)
+    sq, skv, block, d, layout, with_lse = _GRAD_CASES[case]
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 1, sq, 2, d, skv=skv)
 
     def loss(fn):
         def f(q, k, v):
@@ -114,7 +123,7 @@ def test_flash_grads_match_reference(causal, case):
                 jax.scipy.special.logsumexp(scores, axis=-1))
 
     flash = lambda q, k, v: _flash_at(  # noqa: E731
-        q, k, v, 0, 0, 16, layout, causal=causal
+        q, k, v, 0, 0, block, layout, causal=causal
     )
     g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
@@ -264,6 +273,11 @@ _GEOMETRIES = {
     "s12-padded-b16-bhsd": (12, 12, 16, "bhsd"),
     # the models' shape: K/V block twice the q block, 4 tiles wide
     "s128-bq32-bk64-bsm": (128, 128, (32, 64), "bsm"),
+    # cross lengths, K/V no multiple of 128 and padded: a resident K/V
+    # block of four tiles (every slab width of the ``[d, block_k]`` dK/dV
+    # accumulators' lane slice), and one of two tiles in three blocks
+    "sq80-skv200-bq16-bk128-bsm": (80, 200, (16, 128), "bsm"),
+    "sq96-skv168-bq32-bk64-bhsd": (96, 168, (32, 64), "bhsd"),
     "s512-b256-tile128-bsm": (512, 512, 256, "bsm"),
     "s1024-b512-tile256-bsm": (1024, 1024, 512, "bsm"),
 }
@@ -511,7 +525,10 @@ def test_flash_tile_counters_at_gpt2_shape():
 # Orientation of the score tile, read from the traced kernels (no chip: the
 # ``pallas_call``'s jaxpr at the two cells' block shapes).  The orientation
 # is unconditional, so there is nothing to count at run time; these fail if
-# a later edit turns a tile back.
+# a later edit turns a tile back.  The one form that follows a shape is
+# which operand dK/dV's accumulating matmuls stream (the head width,
+# ``_dkv_streams_thin``): read here at 64 and at 128, and counted at build
+# time as ``flash.dkv.thin_streamed``.
 # ---------------------------------------------------------------------------
 
 # name: (sequence, causal): GPT-2's cell and BERT's MLM cell, one batch row
@@ -528,16 +545,17 @@ def _walk(jaxpr):
             yield from _walk(sub)
 
 
-def _kernel_eqns(kernel, cell):
+def _kernel_eqns(kernel, cell, heads=12):
     """Equations of the traced body of the flash kernel named ``kernel``
     inside forward + backward at ``cell``'s shape, as the chip compiles it
-    (12 heads of 64, packed, bf16, ``interpret=False``; nothing lowered)."""
+    (768 columns in ``heads`` heads, 12 of 64 as the models have them,
+    packed, bf16, ``interpret=False``; nothing lowered)."""
     s, causal = _CELL_SHAPES[cell]
     x = jax.ShapeDtypeStruct((1, s, 768), jnp.bfloat16)
 
     def loss(q, k, v):
         return flash_attention(
-            q, k, v, causal=causal, layout="bsm", n_heads=12,
+            q, k, v, causal=causal, layout="bsm", n_heads=heads,
             interpret=False,
         ).astype(jnp.float32).sum()
 
@@ -560,24 +578,105 @@ def _column_reshapes(eqns):
 
 @pytest.mark.parametrize("cell", list(_CELL_SHAPES))
 def test_dkv_kernel_scores_keys_by_queries(cell):
-    """dK/dV: all four matmuls per head contract dimension 1 of their left
-    operand (``K Qᵀ``, ``V gᵀ``, ``pᵀ g``, ``dsᵀ Q``: no operand for Mosaic
-    to transpose), the body holds no transpose, and no row statistic is
-    relaid from its stored lane vector into a ``[rows, 1]`` column."""
+    """dK/dV: the two score matmuls a head are ``K Qᵀ`` and ``V gᵀ``,
+    ``[cols, 64] x [rows, 64]ᵀ`` (nothing for Mosaic to transpose); the two
+    accumulating ones stream the thin operand, ``gᵀ p`` and ``Qᵀ ds``: the
+    ``[rows, 64]`` tile on the left, contracted on dimension 0, against the
+    score-sized ``[cols, rows]`` ``pᵀ`` / ``dsᵀ`` contracted on dimension 1,
+    into ``[64, cols]``.  The only transposes are of those ``[64, block_k]``
+    accumulators, two a head where the K/V block is written, and no row
+    statistic is relaid from its stored lane vector into a ``[rows, 1]``
+    column."""
+    causal = _CELL_SHAPES[cell][1]
+    rows = 256 if causal else 512  # the q tile
+    widths = (256, 512, 768, 1024) if causal else (512,)  # K/V slabs
     eqns = _kernel_eqns("hvd_flash_bwd_dkv", cell)
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
     assert dots and len(dots) % 4 == 0
+    scores, accumulating = [], []
     for dot in dots:
-        (lhs_contract, _), _ = dot.params["dimension_numbers"]
+        (lhs_contract, rhs_contract), _ = dot.params["dimension_numbers"]
+        lhs, rhs = (v.aval.shape for v in dot.invars)
+        if tuple(lhs_contract) == (1,):
+            scores.append(dot)
+            assert tuple(rhs_contract) == (1,), dot
+            assert lhs[0] in widths and lhs[1] == 64, (lhs, rhs)
+            assert rhs == (rows, 64), (lhs, rhs)
+        else:
+            accumulating.append(dot)
+            assert tuple(lhs_contract) == (0,), dot
+            assert tuple(rhs_contract) == (1,), dot
+            assert lhs == (rows, 64), (lhs, rhs)
+            assert rhs[0] in widths and rhs[1] == rows, (lhs, rhs)
+            assert dot.outvars[0].aval.shape == (64, rhs[0])
+            assert dot.outvars[0].aval.dtype == jnp.float32
+    assert len(scores) == len(accumulating) == len(dots) // 2
+    turned = [
+        tuple(e.invars[0].aval.shape) for e in eqns
+        if e.primitive.name == "transpose"
+    ]
+    # dKᵀ and dVᵀ, once per head of the program's group
+    assert set(turned) == {(64, widths[-1])}, turned
+    assert len(turned) % 2 == 0 and 12 % (len(turned) // 2) == 0, turned
+    assert not _column_reshapes(eqns)
+
+
+@pytest.mark.parametrize("cell", list(_CELL_SHAPES))
+def test_dkv_kernel_at_heads_of_128_streams_the_scores(cell):
+    """Heads as wide as the lanes (6 x 128, the same 768 columns): ``pᵀ g``
+    is a full-width pass, so dK/dV keeps ``[cols, 128]`` accumulators: all
+    four matmuls a head contract dimension 1 of their left operand, the
+    accumulating ones ``[cols, rows] x [rows, 128]``, and the body holds no
+    transpose (measured: the turned form is 10% slower there at s 1024
+    causal, PERF.md PR 34)."""
+    causal = _CELL_SHAPES[cell][1]
+    rows = 256 if causal else 512
+    eqns = _kernel_eqns("hvd_flash_bwd_dkv", cell, heads=6)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert dots and len(dots) % 4 == 0
+    accumulating = 0
+    for dot in dots:
+        (lhs_contract, rhs_contract), _ = dot.params["dimension_numbers"]
+        lhs, rhs = (v.aval.shape for v in dot.invars)
         assert tuple(lhs_contract) == (1,), dot
+        if tuple(rhs_contract) == (0,):  # pᵀ or dsᵀ against g or Q
+            accumulating += 1
+            assert lhs[1] == rows and rhs == (rows, 128), (lhs, rhs)
+    assert accumulating == len(dots) // 2
     assert not [e for e in eqns if e.primitive.name == "transpose"]
     assert not _column_reshapes(eqns)
-    # the score-sized operands are [cols, rows]: K/V rows first
-    tile = 256 if _CELL_SHAPES[cell][1] else 512
-    for dot in dots:
-        lhs, rhs = (v.aval.shape for v in dot.invars)
-        if lhs[1] != 64:  # pᵀ or dsᵀ against g or Q
-            assert lhs[1] == tile and rhs == (tile, 64), (lhs, rhs)
+
+
+@pytest.mark.parametrize(
+    "heads,d,layout,booked",
+    [(12, 64, "bsm", 1), (6, 128, "bsm", 0), (8, 96, "bsm", 1),
+     (2, 32, "bhsd", 1), (1, 256, "bhsd", 0)],
+    ids=["12x64", "6x128", "8x96", "bhsd-d32", "bhsd-d256"],
+)
+def test_dkv_thin_streamed_counter_follows_head_width(
+        heads, d, layout, booked):
+    """Build-time counter ``flash.dkv.thin_streamed``: one per backward
+    built whose heads are narrower than the 128 lanes, none at 128 and
+    over; the forward alone books nothing."""
+    from horovod_tpu.obs import registry
+
+    counter = registry.always().counter("flash.dkv.thin_streamed")
+    x = jax.ShapeDtypeStruct(
+        (1, 256, heads * d) if layout == "bsm" else (1, heads, 256, d),
+        jnp.bfloat16,
+    )
+
+    def fwd(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, layout=layout,
+            n_heads=heads if layout == "bsm" else 0,
+        ).astype(jnp.float32).sum()
+
+    before = counter.get()
+    jax.eval_shape(fwd, x, x, x)
+    assert counter.get() == before
+    jax.eval_shape(jax.grad(fwd, argnums=(0, 1, 2)), x, x, x)
+    assert counter.get() - before == booked
 
 
 @pytest.mark.parametrize("cell", list(_CELL_SHAPES))

@@ -88,6 +88,45 @@ def test_flash_attention_fwd_bwd_compiles(v5e, batch, seq, causal, heads):
     assert hlo.count("tpu_custom_call") >= 3  # fwd + dkdv + dq
 
 
+# name: (heads, sq, skv, d, dtype, causal)
+_BHSD_CASES = {
+    "d16-causal": (4, 512, 512, 16, jnp.bfloat16, True),
+    "d32-float32": (4, 512, 512, 32, jnp.float32, False),
+    "d80-causal": (4, 1024, 1024, 80, jnp.bfloat16, True),
+    "d128-causal": (6, 1024, 1024, 128, jnp.bfloat16, True),
+    "d128": (6, 512, 512, 128, jnp.bfloat16, False),
+    "padded-s200": (4, 200, 200, 64, jnp.bfloat16, False),
+    "cross-sq72-skv200": (4, 72, 200, 64, jnp.bfloat16, False),
+    "cross-sq640-skv1000-causal": (4, 640, 1000, 64, jnp.bfloat16, True),
+    "cross-float32-sq136-skv40": (2, 136, 40, 32, jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_BHSD_CASES))
+def test_flash_attention_bhsd_shapes_compile(v5e, case):
+    """The head-major fallback, forward and both backward kernels under a
+    cotangent for ``lse`` too, at what no model here runs: head widths
+    under, off and at the 128 lanes, float32, K/V lengths that are no
+    multiple of 128 (dK/dV's ``[d, block_k]`` accumulators then have a
+    ragged lane axis and are turned back ragged) and differ from the q
+    length, causal slabs over a padded K/V."""
+    heads, sq, skv, d, dtype, causal = _BHSD_CASES[case]
+
+    def loss(q, k, v):
+        out, lse = pk.flash_attention_with_lse(
+            q, k, v, causal=causal, layout="bhsd", interpret=False
+        )
+        lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
+        return out.astype(jnp.float32).sum() + (lse ** 2).sum()
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), v5e,
+        ((2, heads, sq, d), dtype), ((2, heads, skv, d), dtype),
+        ((2, heads, skv, d), dtype),
+    )
+    assert hlo.count("tpu_custom_call") >= 3  # fwd + dkdv + dq
+
+
 def test_fused_adamw_compiles(v5e):
     n = 124 * 1024 * 1024  # a GPT-2-small-sized fp32 flat buffer
 

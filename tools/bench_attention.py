@@ -1,12 +1,14 @@
 """Kernels only, on the chip: the three flash kernels by name.
 
     python tools/bench_attention.py [--tree CHECKOUT] [--iters 8]
-        [--heads 12] [--shape NAME] [--block-q N --block-k N]
+        [--heads 12] [--head-dim 64] [--shape NAME]
+        [--block-q N --block-k N]
 
 Runs forward + backward of ``flash_attention`` alone (one layer's worth) at
 the shapes of the benchmark's flash cells — GPT-2-small (16 x 1024, causal)
 and BERT-base MLM (32 x 512, non-causal), packed ``bsm`` layout, ``--heads`` heads
-of 64, bf16, the kernels' own block sizes as the models leave them —
+of ``--head-dim`` (12 of 64 as the models have them; 6 of 128 are the same
+768 columns), bf16, the kernels' own block sizes as the models leave them —
 under ``jax.profiler.trace`` and prints one JSON line per shape: the
 median device microseconds of ``hvd_flash_fwd`` / ``hvd_flash_bwd_dkv`` /
 ``hvd_flash_bwd_dq`` per call, read from the device plane's ``XLA Ops``
@@ -30,16 +32,15 @@ import numpy as np
 from jax.profiler import ProfileData
 
 KERNELS = ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd")
-HEAD_DIM = 64
 # name: (batch, sequence, causal)
 SHAPES = {"gpt2-16x1024-causal": (16, 1024, True),
           "bert-32x512": (32, 512, False)}
 
 
-def reference(q, k, v, w, causal):
+def reference(q, k, v, w, causal, head_dim):
     """Loss of float32 attention written out in ``jax.numpy``."""
     b, s, width = q.shape
-    split = lambda x: x.astype(jnp.float32).reshape(b, s, -1, HEAD_DIM)  # noqa: E731
+    split = lambda x: x.astype(jnp.float32).reshape(b, s, -1, head_dim)  # noqa: E731
     q, k, v = split(q), split(k), split(v)
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, precision="highest"
@@ -87,6 +88,7 @@ def main():
         os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--iters", type=int, default=8)
     ap.add_argument("--heads", type=int, default=12)
+    ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--shape", choices=sorted(SHAPES), action="append",
                     help="only this shape (may repeat); default: all")
     ap.add_argument("--block-q", type=int)
@@ -121,7 +123,7 @@ def main():
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
         argv = [
             jax.random.normal(
-                key, (b, s, args.heads * HEAD_DIM), jnp.float32
+                key, (b, s, args.heads * args.head_dim), jnp.float32
             )
             for key in keys
         ]
@@ -130,14 +132,15 @@ def main():
         us = kernel_us(flash, argv, args.iters)
         small = [x[:2] for x in argv]
         exact = jax.jit(
-            jax.grad(reference, argnums=(0, 1, 2)), static_argnums=4
-        )(*small, causal)
+            jax.grad(reference, argnums=(0, 1, 2)), static_argnums=(4, 5)
+        )(*small, causal, args.head_dim)
         errors = [
             float(jnp.max(jnp.abs(a.astype(jnp.float32) - e)))
             for a, e in zip(flash(*small), exact)
         ]
         print(json.dumps(dict(
-            tree=args.tree, shape=shape, heads=args.heads, blocks=blocks,
+            tree=args.tree, shape=shape, heads=args.heads,
+            head_dim=args.head_dim, blocks=blocks,
             device_kind=device.device_kind, us_per_call=us,
             total_us=sum(us.values()), grad_abs_err_vs_f32=errors,
         )), flush=True)
