@@ -1,0 +1,11 @@
+"""Milliseconds per step in the operations under the program's
+``jax.named_scope("mtp")``: the whole multi-token-prediction module (its projection, its block, its norm and logits).
+Device trace, worst device, forward, backward and what rematerialisation
+runs again; a fusion counts under the one scope its label names
+(``lib/by_name.py``). Nothing to read in a program without the scope."""
+
+from benchmark.lib.by_name import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "mtp")

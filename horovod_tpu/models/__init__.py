@@ -15,3 +15,4 @@ from .gpt2 import GPT2Config, GPT2LMModel  # noqa: F401
 from .bert import BertConfig, BertModel  # noqa: F401
 from .vit import ViT, ViTConfig  # noqa: F401
 from .moe import MoEConfig, SwitchTransformerLM  # noqa: F401
+from .latent_moe import LatentMoEConfig, LatentMoELM  # noqa: F401

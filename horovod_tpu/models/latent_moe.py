@@ -1,0 +1,313 @@
+"""Latent attention, routed experts held by share, multi-token prediction:
+the DeepSeek-V3 layer as a causal language model.
+
+One model for every configuration of that family (the benchmark's
+configuration file gives the published sizes); "supported" means training,
+on the normal path (``dp.make_train_step`` on :func:`lm_loss`), of ONE
+CHIP'S SHARE of a layer that expert parallelism divides over several: this
+chip holds ``n_experts_held`` of each layer's ``n_experts`` routed experts
+(``first_expert`` on) and a slice of the vocabulary, routes over all
+experts, and computes the part of the result that its experts give
+(``parallel/ep.local_experts``). What the absent experts would add is left
+out and the partial sum goes on. Serving (the latent cache) is not built.
+
+Equations (no bias anywhere, ``eps`` 1e-6). Block::
+
+    h = x + Attn(RMSNorm(x));  y = h + FFN(RMSNorm(h))
+
+Attention (``H`` heads, ``n`` = ``qk_nope_dim``, ``r`` = ``qk_rope_dim``,
+``v`` = ``v_dim``)::
+
+    c_q = RMSNorm(x W_qa)                      [q_lora_rank]
+    q = c_q W_qb            -> H x (n + r):    [q_nope | q_rope]
+    [c_kv | k_r] = x W_kva                     [kv_lora_rank + r]
+    [k_nope | v] = RMSNorm(c_kv) W_kvb  -> H x (n + v)
+    q_rope, k_r <- rotary(theta, interleaved pairs, no scaling); every head
+                   of a position shares the one k_r
+    q = [q_nope | q_rope], k = [k_nope | k_r]
+    out = concat_H(causal softmax(q k^T / sqrt(n + r)) v) W_o
+
+so q / k heads are ``n + r`` wide and v / out heads ``v`` wide: the flash
+kernels take the two widths (``ops/pallas_kernels.flash_attention``).
+
+Expert layer (``E`` experts, ``k`` = ``top_k``)::
+
+    s = sigmoid(x W_r)  in fp32 over all E;  chosen = the k largest of s + b
+    w = s_chosen / sum(s_chosen) * routed_scale
+    y = sum_j w_j E_{chosen_j}(x) + E_shared(x),  E(x) = W_d (silu(W_g x) * W_u x)
+
+``b`` (``e_score_correction_bias``) is a constant zero buffer here: it takes
+no gradient, no balance update is made and there is no auxiliary loss. The
+first ``n_dense_layers`` blocks use the same ``E`` as one dense FFN of width
+``d_ff_dense``. Output: final RMSNorm, an untied head over the vocabulary
+slice, logits in fp32.
+
+Multi-token prediction (DeepSeek-V3 report, section 2.2; one module)::
+
+    h' = W_eh [RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))]
+    one expert block, its own final RMSNorm, the shared embedding and head
+    loss = CE(main, t_{i+1}) + mtp_weight CE(mtp, t_{i+2})
+
+so a batch carries ``seq_len + 1 + n_mtp`` tokens: the model takes all but
+the last, :func:`lm_loss` all of them.
+
+Departures from the published model, all stated in the configuration file:
+rotary is applied to adjacent pairs directly (the published code permutes
+to halves first; the scores are the same), ``b`` is not updated, weights
+are drawn N(0, ``init_std``), parameters are fp32 and computed in bf16 with
+the router, softmax, norms and logits in fp32.
+
+Scopes (``jax.named_scope``): ``mla_proj`` (the projections and rotary),
+``moe_route``, ``moe_experts`` (``parallel/ep.py``),
+``mtp`` (the whole module).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from ..context import device_platform
+from ..parallel import ep
+from .transformer import GatedMlp, RMSNorm, dot_product_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    vocab_size: int = 129280  # rows of the vocabulary held here
+    d_model: int = 2048
+    n_layers: int = 40
+    n_dense_layers: int = 1  # leading blocks with a dense FFN
+    n_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_dim: int = 128
+    rope_theta: float = 32e6
+    d_ff_dense: int = 7168
+    d_ff_expert: int = 768
+    n_experts: int = 256  # the router's width
+    n_experts_held: int = 256  # routed experts whose weights live here
+    first_expert: int = 0  # ... and the first of them
+    top_k: int = 8
+    routed_scale: float = 2.5
+    n_shared_experts: int = 1
+    n_mtp: int = 1  # multi-token-prediction modules (0 or 1)
+    mtp_weight: float = 0.3
+    eps: float = 1e-6
+    init_std: float = 0.02
+    dtype: Any = jnp.bfloat16
+    # None: the flash kernels where the world's devices are TPUs
+    use_flash: Optional[bool] = None
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @staticmethod
+    def tiny(**kw) -> "LatentMoEConfig":
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=2, n_heads=2,
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
+            v_dim=16, d_ff_dense=96, d_ff_expert=24, n_experts=32,
+            n_experts_held=8, top_k=4,
+        )
+        base.update(kw)
+        return LatentMoEConfig(**base)
+
+
+def rotary(x, *, theta: float):
+    """Rotates adjacent pairs ``(x[2i], x[2i+1])`` of the last axis by
+    ``pos * theta^(-2i/d)``; ``x`` is ``[B, S, ..., d]``, positions 0 on.
+    Computed in fp32, returned in ``x``'s dtype."""
+    d, s = x.shape[-1], x.shape[1]
+    freq = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    angle = np.arange(s, dtype=np.float64)[:, None] * freq[None, :]
+    shape = (1, s) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos = jnp.asarray(np.cos(angle), jnp.float32).reshape(shape)
+    sin = jnp.asarray(np.sin(angle), jnp.float32).reshape(shape)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _init(cfg: LatentMoEConfig):
+    return nn.initializers.normal(cfg.init_std)
+
+
+class LatentAttention(nn.Module):
+    cfg: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        b, s, _ = x.shape
+        h, n, r, v = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_dim
+        dense = lambda width, name: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=cfg.dtype, name=name,
+            kernel_init=_init(cfg),
+        )
+        norm = lambda name: RMSNorm(cfg.eps, cfg.dtype, name=name)  # noqa: E731
+        with jax.named_scope("mla_proj"):
+            q = dense(h * (n + r), "q_b")(
+                norm("q_norm")(dense(cfg.q_lora_rank, "q_a")(x))
+            ).reshape(b, s, h, n + r)
+            kv_a = dense(cfg.kv_lora_rank + r, "kv_a")(x)
+            kv = dense(h * (n + v), "kv_b")(
+                norm("kv_norm")(kv_a[..., :cfg.kv_lora_rank])
+            ).reshape(b, s, h, n + v)
+            k_rope = rotary(
+                kv_a[..., None, cfg.kv_lora_rank:], theta=cfg.rope_theta
+            )
+            q = jnp.concatenate([
+                q[..., :n], rotary(q[..., n:], theta=cfg.rope_theta)
+            ], axis=-1)
+            k = jnp.concatenate([
+                kv[..., :n], jnp.broadcast_to(k_rope, (b, s, h, r))
+            ], axis=-1)
+            value = kv[..., n:]
+        use_flash = cfg.use_flash
+        if use_flash is None:
+            use_flash = device_platform() == "tpu"
+        if use_flash:
+            from ..ops.pallas_kernels import flash_attention
+
+            # the packed layout: heads on the lanes, no relayout
+            out = flash_attention(
+                q.reshape(b, s, h * (n + r)), k.reshape(b, s, h * (n + r)),
+                value.reshape(b, s, h * v), causal=True, layout="bsm",
+                n_heads=h,
+            )
+        else:
+            out = dot_product_attention(
+                q, k, value, causal=True
+            ).reshape(b, s, h * v)
+        with jax.named_scope("mla_proj"):
+            return dense(cfg.d_model, "o")(out)
+
+
+class RoutedExperts(nn.Module):
+    """The expert layer's FFN: the router over all experts, the held
+    experts' part, the shared expert(s)."""
+
+    cfg: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        b, s, d = x.shape
+        held, f = cfg.n_experts_held, cfg.d_ff_expert
+        router = self.param(
+            "router", _init(cfg), (d, cfg.n_experts), jnp.float32
+        )
+        # e_score_correction_bias: a constant buffer, not a parameter
+        score_bias = jnp.zeros((cfg.n_experts,), jnp.float32)
+        stacked = lambda name, shape: self.param(  # noqa: E731
+            name, _init(cfg), shape, jnp.float32
+        )
+        gate = stacked("experts_gate", (held, d, f))
+        up = stacked("experts_up", (held, d, f))
+        down = stacked("experts_down", (held, f, d))
+        tokens = x.reshape(b * s, d)
+        with jax.named_scope("moe_route"):
+            chosen, weights = ep.topk_route(
+                tokens, router, score_bias, top_k=cfg.top_k,
+                scale=cfg.routed_scale,
+            )
+        out = ep.local_experts(
+            tokens, chosen, weights, gate, up, down,
+            first_expert=cfg.first_expert, n_experts=cfg.n_experts,
+        ).reshape(b, s, d)
+        if cfg.n_shared_experts:
+            out = out + GatedMlp(
+                cfg.n_shared_experts * f, cfg.dtype, _init(cfg),
+                name="shared",
+            )(x)
+        return out
+
+
+class LatentMoEBlock(nn.Module):
+    cfg: LatentMoEConfig
+    dense_ffn: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        norm = lambda name: RMSNorm(cfg.eps, cfg.dtype, name=name)  # noqa: E731
+        x = x + LatentAttention(cfg, name="attn")(norm("attn_norm")(x))
+        h = norm("ffn_norm")(x)
+        if self.dense_ffn:
+            return x + GatedMlp(
+                cfg.d_ff_dense, cfg.dtype, _init(cfg), name="ffn"
+            )(h)
+        return x + RoutedExperts(cfg, name="ffn")(h)
+
+
+class LatentMoELM(nn.Module):
+    """``tokens [B, S + n_mtp] -> (logits, mtp_logits)``, both fp32
+    ``[B, S, vocab]``; ``logits[:, i]`` predicts ``tokens[:, i + 1]`` and
+    ``mtp_logits[:, i]`` (None without the module) ``tokens[:, i + 2]``."""
+
+    cfg: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, tokens):
+        cfg = self.cfg
+        s = tokens.shape[1] - cfg.n_mtp
+        embed = nn.Embed(
+            cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed",
+            embedding_init=_init(cfg),
+        )
+        head = self.param(
+            "head", _init(cfg), (cfg.d_model, cfg.vocab_size), jnp.float32
+        )
+
+        def logits_of(hidden, name):
+            hidden = RMSNorm(cfg.eps, cfg.dtype, name=name)(hidden)
+            return jnp.dot(
+                hidden, head.astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            )
+
+        x = embed(tokens[:, :s])
+        for i in range(cfg.n_layers):
+            x = LatentMoEBlock(
+                cfg, dense_ffn=i < cfg.n_dense_layers, name=f"block_{i}"
+            )(x)
+        logits = logits_of(x, "final_norm")
+        if not cfg.n_mtp:
+            return logits, None
+        with jax.named_scope("mtp"):
+            merged = nn.Dense(
+                cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                name="mtp_proj", kernel_init=_init(cfg),
+            )(jnp.concatenate([
+                RMSNorm(cfg.eps, cfg.dtype, name="mtp_hidden_norm")(x),
+                RMSNorm(cfg.eps, cfg.dtype, name="mtp_embed_norm")(
+                    embed(tokens[:, 1:s + 1])
+                ),
+            ], axis=-1))
+            merged = LatentMoEBlock(cfg, name="mtp_block")(merged)
+            return logits, logits_of(merged, "mtp_final_norm")
+
+
+def lm_loss(logits, mtp_logits, tokens, *, mtp_weight: float):
+    """``CE(logits, t_{i+1}) + mtp_weight CE(mtp_logits, t_{i+2})``, each a
+    mean over every position; ``tokens [B, S + 1 + n_mtp]``: what the
+    model took and one more, the last target."""
+    s = logits.shape[1]
+    cross_entropy = optax.softmax_cross_entropy_with_integer_labels
+    loss = cross_entropy(logits, tokens[:, 1:s + 1]).mean()
+    if mtp_logits is not None:
+        loss = loss + mtp_weight * cross_entropy(
+            mtp_logits, tokens[:, 2:s + 2]
+        ).mean()
+    return loss

@@ -155,6 +155,46 @@ class MlpBlock(nn.Module):
         )(h)
 
 
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``, no bias; computed in fp32
+    and returned in ``dtype``. The norm of the latent-attention / expert
+    blocks (``models/latent_moe.py``); ``Block`` keeps its LayerNorm."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), jnp.float32
+        )
+        x = x.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps
+        )
+        return (y * scale).astype(self.dtype)
+
+
+class GatedMlp(nn.Module):
+    """SwiGLU feed-forward, no bias: ``down(silu(gate(x)) * up(x))`` at
+    width ``d_ff``. A dense layer's FFN and a shared expert are this
+    module; the routed experts are the same function on stacked weights
+    (``parallel/ep.local_experts``)."""
+
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name,
+            kernel_init=self.kernel_init,
+        )
+        h = nn.silu(dense(self.d_ff, "gate")(x)) * dense(self.d_ff, "up")(x)
+        return dense(x.shape[-1], "down")(h)
+
+
 class Block(nn.Module):
     """Pre-LN transformer block (GPT-2 style; BERT uses it too here —
     pre-LN trains more stably and the parity target is capability, not

@@ -79,7 +79,7 @@ def _use_interpret() -> bool:
 
 
 def _head_group(h: int, block_q: int, block_k: int, d: int,
-                packed: bool) -> int:
+                packed: bool, dv: Optional[int] = None) -> int:
     """Heads per program.  At short sequences a single head's two
     ``d``-thin matmuls underfill the MXU pipeline and per-program overhead
     (scalar DMAs, grid bookkeeping) dominates, so each program handles a
@@ -96,14 +96,23 @@ def _head_group(h: int, block_q: int, block_k: int, d: int,
     Mosaic takes a block's minor dimension only as a multiple of the 128
     lanes or as the whole array's: 3 heads of 64 (192 lanes) are refused,
     2 and 6 of 6 are not.  Where no legal group fits the budget the
-    smallest legal one is taken, and the compiler has the last word."""
+    smallest legal one is taken, and the compiler has the last word.
+
+    ``d`` is the width of a q / k head, ``dv`` that of a v / out head
+    (``None``: the same): the accumulator and the out block are ``dv``
+    wide, and a packed group has to be lane-legal at both widths."""
+    dv = d if dv is None else dv
+
     def legal(g):
-        return not packed or (g * d) % _LANES == 0 or g == h
+        return not packed or g == h or (
+            (g * d) % _LANES == 0 and (g * dv) % _LANES == 0
+        )
 
     groups = [g for g in (12, 8, 6, 4, 3, 2, 1) if h % g == 0 and legal(g)]
     for g in groups:
-        acc = g * block_q * d * 4
-        blocks = 2 * g * (block_q + 2 * block_k + block_q) * d * 2
+        acc = g * block_q * dv * 4
+        blocks = 2 * g * ((block_q + block_k) * d
+                          + (block_k + block_q) * dv) * 2
         if acc + blocks <= 4 << 20:
             return g
     return groups[-1] if groups else h
@@ -369,6 +378,7 @@ def _fwd_kernel(
     tiles: Tuple[int, int],
     packed: bool = False,
     d: int = 0,
+    dv: int = 0,
 ):
     """One (batch*head group, q-block, k-block) grid step of the online
     softmax.
@@ -406,10 +416,12 @@ def _fwd_kernel(
     updates the whole block at once, masked only if K/V is padded.
 
     qoff_ref / kvoff_ref / kvlen_ref: SMEM int32 [1, 1]; q_ref:
-    [1, G, block_q, d]; k_ref/v_ref: [1, G, block_k, d]; o_ref:
-    [1, G, block_q, d]; lse_ref: [1, G, 8, block_q] (8 = min sublane
-    tile; caller reads sublane 0); acc_ref: [G, d, block_q]; m_ref /
-    l_ref: [G, 1, block_q].
+    [1, G, block_q, d]; k_ref: [1, G, block_k, d]; v_ref: [1, G, block_k,
+    dv]; o_ref: [1, G, block_q, dv]; lse_ref: [1, G, 8, block_q] (8 = min
+    sublane tile; caller reads sublane 0); acc_ref: [G, dv, block_q];
+    m_ref / l_ref: [G, 1, block_q].  ``d`` is the width of a q / k head,
+    ``dv`` of a v / out head (latent attention: 192 and 128); nothing in
+    the body is score-by-width, so one body serves both.
     """
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
@@ -460,11 +472,11 @@ def _fwd_kernel(
             # keeps the reduction exact; the p rounding is the standard
             # flash trade).  Vᵀ·pᵀ: the thin operand is the one turned.
             acc_ref[g, :, rq] = acc_ref[g, :, rq] * corr + jax.lax.dot_general(
-                _head(v_ref, g, d, packed, rk),
+                _head(v_ref, g, dv, packed, rk),
                 p_t.astype(v_ref.dtype),
                 dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [d, rows]
+            )  # [dv, rows]
 
     _drive_tiles(update, geom, qi, kj, q_len=None, block_q=block_q,
                  block_k=block_k, causal=causal, masked=masked, tiles=tiles)
@@ -484,7 +496,7 @@ def _fwd_kernel(
                 l_safe = l
                 lse = m_ref[g, :, :] + jnp.log(l_safe)
             _head_store(
-                o_ref, g, d, packed,
+                o_ref, g, dv, packed,
                 (acc_ref[g, :, :] / l_safe).T.astype(o_ref.dtype),
             )
             lse_ref[0, g] = jnp.broadcast_to(
@@ -508,6 +520,7 @@ class _Plan(NamedTuple):
     group: int
     tiles: Tuple[int, int]  # compute tile; the whole block unless causal
     interpret: bool
+    dv: int  # width of a v / out head; ``d`` is that of a q / k head
 
     def pad_seq(self, x, s: int, s_pad: int):
         if s_pad != s:
@@ -525,16 +538,18 @@ class _Plan(NamedTuple):
         )
 
 
-def _plan(q, k, *, causal: bool, block_q: int, block_k: int,
+def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
           interpret: Optional[bool], n_heads: int) -> _Plan:
     packed = n_heads > 0
     if packed:
         b, sq, hd = q.shape
         h = n_heads
         d = hd // h
+        dv = v.shape[2] // h
         skv = k.shape[1]
     else:
         b, h, sq, d = q.shape
+        dv = v.shape[3]
         skv = k.shape[2]
     if interpret is None:
         interpret = _use_interpret()
@@ -549,7 +564,8 @@ def _plan(q, k, *, causal: bool, block_q: int, block_k: int,
     return _Plan(
         packed, b, h, d, sq, skv, block_q, block_k,
         _round_up(sq, block_q), skv_pad,
-        _head_group(h, block_q, block_k, d, packed), tiles, interpret,
+        _head_group(h, block_q, block_k, d, packed, dv), tiles, interpret,
+        dv,
     )
 
 
@@ -634,10 +650,12 @@ def _fwd_pallas(
     ``static_offsets``: the two offsets where the caller gave Python
     ints, for the build-time tile counters only.
     """
-    p = _plan(q, k, causal=causal, block_q=block_q, block_k=block_k,
+    p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
               interpret=interpret, n_heads=n_heads)
     if causal:
         _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
+    if p.dv != p.d:
+        _registry.always().counter("flash.calls.split_widths").inc()
     return _flash_fwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv),
         p=p, sm_scale=sm_scale, causal=causal,
@@ -654,7 +672,7 @@ def _fwd_pallas(
 )
 def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
                     causal: bool):
-    b, h, d, group = p.b, p.h, p.d, p.group
+    b, h, d, dv, group = p.b, p.h, p.d, p.dv, p.group
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
     )
@@ -667,46 +685,53 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
             return kj
         return jnp.minimum(kj, _last_kv_block(qi, geom, p))
 
-    if p.packed:
-        q_spec = _vspec(
-            (1, block_q, group * d), lambda bi, hi, qi, kj, *geom: (bi, qi, hi)
-        )
-        kv_spec = _vspec(
-            (1, block_k, group * d),
-            lambda bi, hi, qi, kj, *geom: (bi, kv_block(qi, kj, geom), hi),
-        )
-        o_shape = jax.ShapeDtypeStruct((b, sq_pad, h * d), q.dtype)
-    else:
-        q_spec = _vspec(
-            (1, group, block_q, d),
+    def q_side(width):
+        if p.packed:
+            return _vspec(
+                (1, block_q, group * width),
+                lambda bi, hi, qi, kj, *geom: (bi, qi, hi),
+            )
+        return _vspec(
+            (1, group, block_q, width),
             lambda bi, hi, qi, kj, *geom: (bi, hi, qi, 0),
         )
-        kv_spec = _vspec(
-            (1, group, block_k, d),
+
+    def kv_side(width):
+        if p.packed:
+            return _vspec(
+                (1, block_k, group * width),
+                lambda bi, hi, qi, kj, *geom: (
+                    bi, kv_block(qi, kj, geom), hi),
+            )
+        return _vspec(
+            (1, group, block_k, width),
             lambda bi, hi, qi, kj, *geom: (
                 bi, hi, kv_block(qi, kj, geom), 0),
         )
-        o_shape = jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype)
+
+    o_shape = jax.ShapeDtypeStruct(
+        (b, sq_pad, h * dv) if p.packed else (b, h, sq_pad, dv), q.dtype
+    )
 
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, causal=causal,
             masked=causal or skv_pad != p.skv, tiles=p.tiles,
-            packed=p.packed, d=d,
+            packed=p.packed, d=d, dv=dv,
         ),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_side(d), kv_side(d), kv_side(dv)],
             out_specs=[
-                q_spec,
+                q_side(dv),
                 _vspec(
                     (1, group, 8, block_q),
                     lambda bi, hi, qi, kj, *geom: (bi, hi, 0, qi),
                 ),
             ],
             scratch_shapes=[
-                _VMEM((group, d, block_q), jnp.float32),
+                _VMEM((group, dv, block_q), jnp.float32),
                 _VMEM((group, 1, block_q), jnp.float32),
                 _VMEM((group, 1, block_q), jnp.float32),
             ],
@@ -721,9 +746,9 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * sq_pad * skv_pad * d,
+            flops=2 * b * h * sq_pad * skv_pad * (d + dv),
             bytes_accessed=(qr.size + kr.size + vr.size) * qr.dtype.itemsize
-            + b * h * sq_pad * d * qr.dtype.itemsize,
+            + b * h * sq_pad * dv * qr.dtype.itemsize,
             transcendentals=b * h * sq_pad * skv_pad,
         ),
         interpret=p.interpret,
@@ -789,7 +814,7 @@ def _dkv_streams_thin(d: int) -> bool:
 
 def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
                     g_ref, g, rq, rk, valid, *, sm_scale: float,
-                    packed: bool = False, d: int = 0):
+                    packed: bool = False, d: int = 0, dv: int = 0):
     """Shared per-(q rows ``rq``, K/V rows ``rk``, head) recompute:
     returns (pᵀ, dsᵀ, q_blk, g_blk, k_blk), the two score-sized arrays
     keys-by-queries, ``[cols, rows]``, like ``valid`` (``None`` is the
@@ -802,9 +827,9 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
     # Storage-dtype (bf16) matmul inputs with fp32 accumulation — see the
     # forward kernel note; only the softmax/ds algebra runs in fp32.
     q_blk = _head(q_ref, g, d, packed, rq)
-    g_blk = _head(g_ref, g, d, packed, rq)
+    g_blk = _head(g_ref, g, dv, packed, rq)
     k_blk = _head(k_ref, g, d, packed, rk)
-    v_blk = _head(v_ref, g, d, packed, rk)
+    v_blk = _head(v_ref, g, dv, packed, rk)
 
     s_t = jax.lax.dot_general(
         k_blk,
@@ -839,7 +864,7 @@ def _bwd_kernel_dkdv(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
     q_ref, k_ref, v_ref, g_ref, dk_ref, dv_ref, dk_acc, dv_acc,
     *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
-    q_len: int, packed: bool = False, d: int = 0,
+    q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
 ):
     """grid (b, h-group, kj, qi): each K tile accumulates over streamed
     Q blocks; the per-head loop is a static unroll (see forward).  Heads
@@ -847,42 +872,47 @@ def _bwd_kernel_dkdv(
     (:func:`_dkv_streams_thin`): the accumulators are ``dkᵀ`` / ``dvᵀ``,
     ``[G, d, block_k]``, the K/V rows on lanes (a causal slab is a static
     lane slice from 0, a whole number of K/V tiles), turned back once per
-    head where the K/V block is written.  Else ``[G, block_k, d]``."""
+    head where the K/V block is written.  Else ``[G, block_k, d]``.  Each
+    accumulator takes its form from its own width (dK's ``d``, dV's
+    ``dv``)."""
     qi = pl.program_id(3)
     kj = pl.program_id(2)
     nq = pl.num_programs(3)
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
-    thin = _dkv_streams_thin(d)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:, :, :] = jnp.zeros_like(dk_acc)
         dv_acc[:, :, :] = jnp.zeros_like(dv_acc)
 
-    def product(scores_t, blk):
-        """``blkᵀ·scores`` as ``[d, cols]`` (the ``[rows, d]`` g / Q tile
-        streamed and turned, ``pᵀ`` / ``dsᵀ`` standing as the right
-        operand), or ``scores_t·blk`` as ``[cols, d]``; fp32."""
+    def accumulate(acc, g, rk, scores_t, blk, scale=None):
+        """Adds ``blkᵀ·scores`` as ``[width, cols]`` (the ``[rows, width]``
+        g / Q tile streamed and turned, ``pᵀ`` / ``dsᵀ`` standing as the
+        right operand), or ``scores_t·blk`` as ``[cols, width]``; fp32."""
+        thin = _dkv_streams_thin(blk.shape[1])
+        at = (g, slice(None), rk) if thin else (g, rk, slice(None))
+        so_far = acc[at]  # read before the product, as the kernel always did
         scores_t = scores_t.astype(blk.dtype)
         lhs, rhs, contract = (
             (blk, scores_t, ((0,), (1,))) if thin
             else (scores_t, blk, ((1,), (0,)))
         )
-        return jax.lax.dot_general(
+        product = jax.lax.dot_general(
             lhs, rhs, dimension_numbers=(contract, ((), ())),
             preferred_element_type=jnp.float32,
         )
+        acc[at] = so_far + (product if scale is None else product * scale)
 
     def update(rq, rk, valid):
         for g in range(group):
             p_t, ds_t, q_blk, g_blk, _ = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
+                dv=dv,
             )
-            at = (g, slice(None), rk) if thin else (g, rk, slice(None))
-            dv_acc[at] = dv_acc[at] + product(p_t, g_blk)
-            dk_acc[at] = dk_acc[at] + product(ds_t, q_blk) * sm_scale
+            accumulate(dv_acc, g, rk, p_t, g_blk)
+            accumulate(dk_acc, g, rk, ds_t, q_blk, sm_scale)
 
     _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
                  block_k=block_k, causal=causal, masked=masked, tiles=tiles)
@@ -890,16 +920,18 @@ def _bwd_kernel_dkdv(
     @pl.when(qi == nq - 1)
     def _finalize():
         for g in range(group):
-            for ref, acc in ((dk_ref, dk_acc), (dv_ref, dv_acc)):
-                grad = acc[g, :, :].T if thin else acc[g, :, :]
-                _head_store(ref, g, d, packed, grad.astype(ref.dtype))
+            for ref, acc, width in ((dk_ref, dk_acc, d), (dv_ref, dv_acc, dv)):
+                grad = acc[g, :, :]
+                if _dkv_streams_thin(width):
+                    grad = grad.T
+                _head_store(ref, g, width, packed, grad.astype(ref.dtype))
 
 
 def _bwd_kernel_dq(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
     q_ref, k_ref, v_ref, g_ref, dq_ref, dq_acc,
     *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
-    q_len: int, packed: bool = False, d: int = 0,
+    q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
@@ -919,6 +951,7 @@ def _bwd_kernel_dq(
             _, ds_t, _, _, k_blk = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
+                dv=dv,
             )
             dq_acc[g, :, rq] = dq_acc[g, :, rq] + jax.lax.dot_general(
                 k_blk, ds_t.astype(k_blk.dtype),
@@ -943,13 +976,13 @@ def _bwd_pallas(
     interpret: Optional[bool], n_heads: int = 0,
     static_offsets: Optional[Tuple[int, int]] = None,
 ):
-    p = _plan(q, k, causal=causal, block_q=block_q, block_k=block_k,
+    p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
               interpret=interpret, n_heads=n_heads)
     if causal:
         # one count for each of the two kernels
         for _ in range(2):
             _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=True))
-    if _dkv_streams_thin(p.d):
+    if _dkv_streams_thin(p.d) or _dkv_streams_thin(p.dv):
         _registry.always().counter("flash.dkv.thin_streamed").inc()
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
@@ -962,7 +995,7 @@ def _bwd_pallas(
 )
 def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
                     sm_scale: float, causal: bool):
-    b, h, d, group, sq, skv = p.b, p.h, p.d, p.group, p.sq, p.skv
+    b, h, d, dv, group, sq, skv = p.b, p.h, p.d, p.dv, p.group, p.sq, p.skv
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
     )
@@ -984,8 +1017,8 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         # [B,S,H*D] → per-head row dot via a free reshape (no transpose).
         delta = jnp.einsum(
             "bqhd,bqhd->bhq",
-            g_out.astype(jnp.float32).reshape(b, sq, h, d),
-            out.astype(jnp.float32).reshape(b, sq, h, d),
+            g_out.astype(jnp.float32).reshape(b, sq, h, dv),
+            out.astype(jnp.float32).reshape(b, sq, h, dv),
         )
     else:
         delta = jnp.einsum(
@@ -1001,7 +1034,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
     kernel_params = dict(
         sm_scale=sm_scale, causal=causal,
         masked=causal or skv_pad != skv or sq_pad != sq,
-        tiles=p.tiles, q_len=sq, packed=p.packed, d=d,
+        tiles=p.tiles, q_len=sq, packed=p.packed, d=d, dv=dv,
     )
     call_params = dict(
         compiler_params=pltpu.CompilerParams(
@@ -1011,11 +1044,11 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         interpret=p.interpret,
     )
     def specs(order):
-        """(row-statistics, q-side, K/V-side) block specs for a grid
-        whose last two axes are ``order``: "kq" (dK/dV: q streams
-        innermost, its skipped steps clamped to the first q block
-        needed) or "qk" (dQ: K/V streams innermost, clamped to the last
-        K/V block needed)."""
+        """(row-statistics, q, k, v, g) block specs for a grid whose last
+        two axes are ``order``: "kq" (dK/dV: q streams innermost, its
+        skipped steps clamped to the first q block needed) or "qk" (dQ:
+        K/V streams innermost, clamped to the last K/V block needed).
+        q and k blocks are ``d`` wide, v and g blocks ``dv``."""
 
         def blocks(i, j, geom):
             qi, kj = (j, i) if order == "kq" else (i, j)
@@ -1037,53 +1070,61 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             qi, _ = blocks(i, j, geom)
             return (bi, hi, 0, qi)
 
-        q_shape = (1, block_q, group * d) if p.packed else (
-            1, group, block_q, d)
-        kv_shape = (1, block_k, group * d) if p.packed else (
-            1, group, block_k, d)
+        def block(rows, width, index_map):
+            return _vspec(
+                (1, rows, group * width) if p.packed
+                else (1, group, rows, width), index_map,
+            )
+
         return (
             _vspec((1, group, 8, block_q), stat_map),
-            _vspec(q_shape, q_map),
-            _vspec(kv_shape, kv_map),
+            block(block_q, d, q_map), block(block_k, d, kv_map),
+            block(block_k, dv, kv_map), block(block_q, dv, q_map),
         )
 
-    def shape_like(x, s_pad):
+    def shape_like(x, s_pad, width):
         return jax.ShapeDtypeStruct(
-            (b, s_pad, h * d) if p.packed else (b, h, s_pad, d), x.dtype
+            (b, s_pad, h * width) if p.packed else (b, h, s_pad, width),
+            x.dtype,
         )
 
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
-    stat_spec, q_spec, kv_spec = specs("kq")
-    dkv_acc = (group, d, block_k) if _dkv_streams_thin(d) else (
-        group, block_k, d)
-    dk, dv = pl.pallas_call(
+    stat_spec, q_spec, k_spec, v_spec, g_spec = specs("kq")
+
+    def dkv_acc(width):
+        return _VMEM(
+            (group, width, block_k) if _dkv_streams_thin(width)
+            else (group, block_k, width), jnp.float32,
+        )
+
+    grad_k, grad_v = pl.pallas_call(
         functools.partial(_bwd_kernel_dkdv, **kernel_params),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, skv_pad // block_k, sq_pad // block_q),
             in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, kv_spec, kv_spec, q_spec],
-            out_specs=[kv_spec, kv_spec],
-            scratch_shapes=[_VMEM(dkv_acc, jnp.float32)] * 2,
+                      q_spec, k_spec, v_spec, g_spec],
+            out_specs=[k_spec, v_spec],
+            scratch_shapes=[dkv_acc(d), dkv_acc(dv)],
         ),
-        out_shape=[shape_like(k, skv_pad), shape_like(v, skv_pad)],
+        out_shape=[shape_like(k, skv_pad, d), shape_like(v, skv_pad, dv)],
         **call_params,
         name="hvd_flash_bwd_dkv",
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
-    stat_spec, q_spec, kv_spec = specs("qk")
+    stat_spec, q_spec, k_spec, v_spec, g_spec = specs("qk")
     dq = pl.pallas_call(
         functools.partial(_bwd_kernel_dq, **kernel_params),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
             in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, kv_spec, kv_spec, q_spec],
+                      q_spec, k_spec, v_spec, g_spec],
             out_specs=q_spec,
             scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
         ),
-        out_shape=shape_like(q, sq_pad),
+        out_shape=shape_like(q, sq_pad, d),
         **call_params,
         name="hvd_flash_bwd_dq",
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
@@ -1091,13 +1132,13 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
     if p.packed:
         return (
             dq[:, :sq].astype(q.dtype),
-            dk[:, :skv].astype(k.dtype),
-            dv[:, :skv].astype(v.dtype),
+            grad_k[:, :skv].astype(k.dtype),
+            grad_v[:, :skv].astype(v.dtype),
         )
     return (
         dq[:, :, :sq].astype(q.dtype),
-        dk[:, :, :skv].astype(k.dtype),
-        dv[:, :, :skv].astype(v.dtype),
+        grad_k[:, :, :skv].astype(k.dtype),
+        grad_v[:, :, :skv].astype(v.dtype),
     )
 
 
@@ -1191,6 +1232,9 @@ def flash_attention_with_lse(
     q/k/v/out need no relayout at all (the r4 ``bhsd`` path still paid
     the head transpose by folding it into the projection dots, which
     then ran at ~43%% of MXU peak — ``docs/perf_analysis_bert_r04.md``).
+    v (and so ``out``) may have another head width than q and k in every
+    layout (latent attention: q / k heads 192 wide, v / out heads 128);
+    the scores scale by the q / k width unless ``sm_scale`` is given.
     ``lse`` is fp32 ``[B, H, Sq]`` in every layout — the log-sum-exp of
     each row's (masked) scores, the residual needed to merge partial
     attention across K/V shards (:func:`combine_blocks`) and to run the
@@ -1200,13 +1244,14 @@ def flash_attention_with_lse(
     packed = layout == "bsm"
     if packed and n_heads <= 0:
         raise ValueError("layout='bsm' requires n_heads")
-    if packed and (q.shape[-1] // n_heads) % 64 != 0 and not (
-        interpret if interpret is not None else _use_interpret()
-    ):
+    if packed and any(
+        (x.shape[-1] // n_heads) % 64 != 0 for x in (q, v)
+    ) and not (interpret if interpret is not None else _use_interpret()):
         raise ValueError(
             "layout='bsm' needs head_dim % 64 == 0 on TPU (Mosaic lane "
             f"slicing is 64-aligned); got head_dim="
-            f"{q.shape[-1] // n_heads} — use layout='bhsd'"
+            f"{q.shape[-1] // n_heads} (v: {v.shape[-1] // n_heads}) — use "
+            "layout='bhsd'"
         )
     if sm_scale is None:
         d = q.shape[-1] // n_heads if packed else q.shape[-1]

@@ -1,4 +1,5 @@
-"""Expert parallelism: Switch-style top-1 MoE with all_to_all dispatch.
+"""Expert parallelism: Switch-style top-1 MoE with all_to_all dispatch, and
+top-k routing over all experts computed for the experts held here.
 
 NEW capability relative to the reference (SURVEY.md §2.3: EP absent; the
 reference's ``alltoall`` — ``operations.cc:1101-1162`` — was added for
@@ -6,6 +7,13 @@ exactly this use case). Each device on the ``ep`` axis owns one expert;
 token routing is expressed as one-hot dispatch/combine einsums (large
 MXU-friendly matmuls, the mesh-tensorflow formulation) around a pair of
 ``lax.all_to_all`` exchanges on the ICI.
+
+:func:`topk_route` and :func:`local_experts` are the other half: the
+router scores every expert of a layer that is shared by several chips, and
+the chip computes the part of the result that ITS experts give, for the
+tokens routed to them, dropping none. On one chip there is no exchange;
+where tokens of other chips arrive (the exchange of a four-chip mesh) they
+join ``x`` before :func:`local_experts` and leave after it.
 """
 
 from __future__ import annotations
@@ -16,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from ..obs import registry as _registry
 
 
 
@@ -138,3 +148,110 @@ def switch_moe_stacked(
     back = lax.all_to_all(back, axis, split_axis=0, concat_axis=0, tiled=True)
     out = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), back)
     return out, lax.pmean(aux, axis)
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing over all experts; the experts held here compute their part.
+#
+# The device work is a function of (tokens, experts held) alone, never of
+# where the router sends what, and no token is dropped whatever it does. A
+# token chooses ``k`` DIFFERENT experts, so an expert held here receives at
+# most every token once: ``T`` rows. That bound is the buffer. Expert ``e``'s
+# segment is ``T`` rows and token ``t`` sits at row ``t`` of it, so there is
+# no slot to find, no sort, no cumulative sum, no gather and no scatter: the
+# three matmuls run over all ``held x T`` rows, a row's output is weighed by
+# the router's weight for that (token, expert) and by zero where the token
+# did not choose the expert, and the weighing is folded in before the down
+# projection, which then sums over experts as well: one ``[T, held F] x
+# [held F, D]`` matmul.
+#
+# Why not a smaller buffer (``slack`` x the expected ``T k held / experts``
+# rows, overflow passes under ``lax.cond``)? It was built and measured
+# (PERF.md, PR 36): under AdamW at a constant 3e-4 the hidden state of
+# every token collapses onto one vector within ten steps, every token then
+# chooses the same ``k`` experts, and the rows an expert-parallel chip
+# receives swing between 0 and ``k T`` from one step to the next, with or
+# without a balance update of the score bias (a bias cannot tell apart
+# tokens whose scores are the same). Only the bound is static.
+# ---------------------------------------------------------------------------
+
+
+def topk_route(x, router_kernel, score_bias, *, top_k: int, scale: float):
+    """Sigmoid top-k routing over ALL experts of the layer (``noaux_tc``).
+
+    ``s = sigmoid(x W_r)`` in float32 at full matmul precision; the ``top_k``
+    largest of ``s + score_bias`` are chosen (the bias steers the choice
+    only and takes no gradient); their weights are ``s_sel / sum(s_sel) *
+    scale``.
+
+    Args: x ``[T, D]``; router_kernel ``[D, E]``; score_bias ``[E]``.
+    Returns: expert ids ``[T, top_k]`` int32, weights ``[T, top_k]`` fp32.
+    """
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    ))
+    _, chosen = lax.top_k(
+        scores + lax.stop_gradient(score_bias.astype(jnp.float32)), top_k
+    )
+    # s[t, chosen[t, j]] as a masked sum: vectorised both ways, where a
+    # take_along_axis is T k scalar gathers and as many scatter-adds back
+    picked = jnp.einsum(
+        "tke,te->tk",
+        jax.nn.one_hot(chosen, scores.shape[-1], dtype=jnp.float32), scores,
+        precision=lax.Precision.HIGHEST,
+    )
+    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    return chosen.astype(jnp.int32), weights
+
+
+@jax.checkpoint
+def _held_experts(x, share, gate, up, down):
+    """``sum_e share[t, e] E_e(x[t])``. Nothing of it is kept for the
+    backward but its arguments: the two ``[T, held, F]`` matmul outputs
+    are computed again, which costs two matmuls and saves a layer of
+    them."""
+    as_x = lambda w: w.astype(x.dtype)  # noqa: E731
+    hidden = jax.nn.silu(
+        jnp.einsum("td,edf->tef", x, as_x(gate))
+    ) * jnp.einsum("td,edf->tef", x, as_x(up))
+    return jnp.einsum(
+        "tef,efd->td", hidden * as_x(share)[..., None], as_x(down)
+    )
+
+
+def local_experts(x, chosen, weights, gate, up, down, *, first_expert: int,
+                  n_experts: int):
+    """The part of a top-k expert layer that the experts held here give:
+    ``y[t] = sum_j weights[t, j] E_{chosen[t, j]}(x[t])`` over the choices
+    whose expert is one of ``first_expert .. first_expert + held - 1``,
+    ``E(x) = W_d (silu(W_g x) * W_u x)``. What the other experts would add
+    is left out. No token is dropped, and the work is the same whatever
+    was chosen (see the section comment above).
+
+    Args:
+      x: ``[T, D]`` tokens. chosen / weights: ``[T, k]`` from
+      :func:`topk_route`. gate, up: ``[held, D, F]``; down: ``[held, F, D]``
+      (any float dtype; computed in ``x``'s).
+      n_experts: the router's width; read by the counters only.
+    Returns: ``[T, D]`` in ``x``'s dtype.
+
+    Build-time counters, per call traced: ``moe.rows_buffered`` (the rows
+    the matmuls compute, ``held x T``) and ``moe.rows_expected`` (the rows
+    the router is expected to fill, ``T k held / experts``).
+    """
+    n_tokens, top_k = chosen.shape
+    n_held = gate.shape[0]
+    reg = _registry.always()
+    reg.counter("moe.rows_buffered").inc(n_held * n_tokens)
+    reg.counter("moe.rows_expected").inc(
+        round(n_tokens * top_k * n_held / n_experts)
+    )
+    with jax.named_scope("moe_experts"):
+        # [T, held]: the token's weight for each held expert, zero where it
+        # chose another (an index outside 0 .. held - 1 is no column)
+        share = jnp.sum(
+            jax.nn.one_hot(chosen - first_expert, n_held, dtype=jnp.float32)
+            * weights.astype(jnp.float32)[..., None], axis=1,
+        )
+        return _held_experts(x, share, gate, up, down)

@@ -706,3 +706,183 @@ def test_fwd_and_dq_kernels_keep_rows_on_lanes(kernel, matmuls, cell):
     ]
     # one per head of the program's group
     assert set(turned) == {(64, 512)} and 12 % len(turned) == 0, turned
+
+
+# ---------------------------------------------------------------------------
+# Two head widths: q / k heads ``d`` wide, v / out heads ``dv`` (latent
+# attention: 192 and 128).  One kernel body serves both; with equal widths
+# the traced kernels are what they were (the four cells' compiled HLO is
+# byte-identical across PR 36).
+# ---------------------------------------------------------------------------
+
+# name: (q / k width, v width, layout, sequence, block, causal)
+_SPLIT_CASES = {
+    "24-16-bsm-causal": (24, 16, "bsm", 72, 32, True),
+    "24-16-bshd-causal": (24, 16, "bshd", 72, 32, True),
+    "24-16-bhsd-padded": (24, 16, "bhsd", 40, 16, False),
+    "16-24-bsm-wider-v": (16, 24, "bsm", 48, 16, True),
+    "192-128-bsm-causal": (192, 128, "bsm", 64, 32, True),
+    "128-64-bsm": (128, 64, "bsm", 32, 16, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_SPLIT_CASES))
+def test_flash_split_widths_match_reference(case):
+    """Forward, ``lse`` and the three gradients against
+    ``dot_product_attention`` (interpreter), in every layout."""
+    d, dv, layout, s, block, causal = _SPLIT_CASES[case]
+    h = 2
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(keys[0], (1, s, h, d))
+    k = jax.random.normal(keys[1], (1, s, h, d))
+    v = jax.random.normal(keys[2], (1, s, h, dv))
+    w = jax.random.normal(keys[3], (1, s, h, dv))
+
+    def flash(q, k, v):
+        if layout == "bsm":
+            out, lse = flash_attention_with_lse(
+                q.reshape(1, s, h * d), k.reshape(1, s, h * d),
+                v.reshape(1, s, h * dv), causal=causal, layout="bsm",
+                n_heads=h, block_q=block, block_k=block,
+            )
+            return out.reshape(1, s, h, dv), lse
+        if layout == "bhsd":
+            out, lse = flash_attention_with_lse(
+                *(jnp.moveaxis(x, 1, 2) for x in (q, k, v)), causal=causal,
+                layout="bhsd", block_q=block, block_k=block,
+            )
+            return jnp.moveaxis(out, 1, 2), lse
+        return flash_attention_with_lse(
+            q, k, v, causal=causal, block_q=block, block_k=block
+        )
+
+    def reference(q, k, v):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -1e30)
+        return (dot_product_attention(q, k, v, causal=causal),
+                jax.scipy.special.logsumexp(scores, axis=-1))
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * w) + 0.1 * jnp.sum(lse ** 2), (out, lse)
+        # one trace gives the outputs and the three gradients
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        got, (out, lse) = loss(flash)(q, k, v)
+        want, (ref_out, ref_lse) = loss(reference)(q, k, v)
+    assert out.shape == (1, s, h, dv)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def _split_kernel_eqns(kernel, d, dv, heads=4, s=1024):
+    """Traced body of ``kernel`` in forward + backward of a causal packed
+    call at q / k width ``d`` and v width ``dv``, as the chip compiles it."""
+    wide = jax.ShapeDtypeStruct((1, s, heads * d), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((1, s, heads * dv), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, layout="bsm", n_heads=heads,
+            interpret=False,
+        ).astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        wide, wide, narrow
+    )
+    calls = [
+        e for e in _walk(traced.jaxpr)
+        if e.primitive.name == "pallas_call" and e.params["name"] == kernel
+    ]
+    assert len(calls) == 1, (kernel, len(calls))
+    return list(_walk(calls[0].params["jaxpr"]))
+
+
+def test_dkv_kernel_at_192_and_128_streams_the_scores():
+    """Latent attention's widths, both at or over the lanes: dK/dV keeps
+    ``[cols, width]`` accumulators for both (dK 192 wide, dV 128), every
+    matmul contracts dimension 1 of its left operand, and the body holds
+    no transpose and no ``[rows, 1]`` column."""
+    eqns = _split_kernel_eqns("hvd_flash_bwd_dkv", 192, 128)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert dots and len(dots) % 4 == 0
+    widths = []
+    for dot in dots:
+        (lhs_contract, rhs_contract), _ = dot.params["dimension_numbers"]
+        lhs, rhs = (v.aval.shape for v in dot.invars)
+        assert tuple(lhs_contract) == (1,), dot
+        if tuple(rhs_contract) == (0,):  # pᵀ g (128) or dsᵀ Q (192)
+            assert lhs[1] == rhs[0] == 256, (lhs, rhs)
+            widths.append(rhs[1])
+        else:  # K Qᵀ over 192, V gᵀ over 128
+            assert lhs[1] == rhs[1] and lhs[1] in (192, 128), (lhs, rhs)
+    assert sorted(set(widths)) == [128, 192]
+    assert widths.count(128) == widths.count(192) == len(dots) // 4
+    assert not [e for e in eqns if e.primitive.name == "transpose"]
+    assert not _column_reshapes(eqns)
+
+
+def test_dkv_accumulators_take_their_form_from_their_own_width():
+    """q / k at the lanes, v under them (128 / 64): dK accumulates
+    ``dsᵀ Q`` into ``[cols, 128]`` and dV streams its thin operand,
+    ``gᵀ p`` into ``[64, cols]``, turned back once a head."""
+    eqns = _split_kernel_eqns("hvd_flash_bwd_dkv", 128, 64)
+    outs = [
+        tuple(e.outvars[0].aval.shape) for e in eqns
+        if e.primitive.name == "dot_general"
+        and tuple(e.params["dimension_numbers"][0][0]) in ((0,), (1,))
+        and e.outvars[0].aval.shape[-1] != 256  # not a score tile
+    ]
+    assert {o for o in outs if o[0] == 64} and {o for o in outs if o[1] == 128}
+    turned = {
+        tuple(e.invars[0].aval.shape) for e in eqns
+        if e.primitive.name == "transpose"
+    }
+    assert turned == {(64, 1024)}, turned
+
+
+@pytest.mark.parametrize(
+    "kernel,acc_width", [("hvd_flash_fwd", 128), ("hvd_flash_bwd_dq", 192)]
+)
+def test_fwd_and_dq_keep_rows_on_lanes_at_split_widths(kernel, acc_width):
+    """The forward accumulates ``[dv, rows]`` and dQ ``[d, rows]``: the one
+    transpose a head is of that accumulator, and nothing kept per q row is
+    a ``[rows, 1]`` column."""
+    eqns = _split_kernel_eqns(kernel, 192, 128)
+    assert not _column_reshapes(eqns)
+    turned = {
+        tuple(e.invars[0].aval.shape) for e in eqns
+        if e.primitive.name == "transpose"
+    }
+    assert turned == {(acc_width, 512)}, turned
+
+
+def test_split_widths_counter_and_group():
+    from horovod_tpu.obs import registry
+    from horovod_tpu.ops.pallas_kernels import _head_group
+
+    counter = registry.always().counter("flash.calls.split_widths")
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, layout="bsm", n_heads=4)
+
+    x = lambda w: jax.ShapeDtypeStruct((1, 256, 4 * w), jnp.bfloat16)  # noqa: E731
+    before = counter.get()
+    jax.eval_shape(fwd, x(64), x(64), x(64))
+    assert counter.get() == before
+    jax.eval_shape(fwd, x(192), x(192), x(128))
+    assert counter.get() == before + 1
+    # 32 heads of 192 / 128, blocks of 512 x 1024: no group fits the 4 MB
+    # budget, and one head of 192 is no legal packed block, so 2
+    assert _head_group(32, 512, 1024, 192, True, 128) == 2
+    # equal widths: what the one-width rule gave
+    for h in (6, 12, 16):
+        assert _head_group(h, 512, 1024, 64, True, 64) == _head_group(
+            h, 512, 1024, 64, True
+        )

@@ -88,6 +88,57 @@ def test_flash_attention_fwd_bwd_compiles(v5e, batch, seq, causal, heads):
     assert hlo.count("tpu_custom_call") >= 3  # fwd + dkdv + dq
 
 
+@pytest.mark.parametrize(
+    "batch,seq,heads,qk,v",
+    [(2, 4096, 32, 192, 128), (4, 1024, 8, 192, 128), (2, 1024, 16, 128, 64)],
+    ids=["latent-2x4096x32", "latent-s1024x8", "qk128-v64"],
+)
+def test_flash_attention_split_widths_compile(v5e, batch, seq, heads, qk, v):
+    """q / k heads wider than v / out heads, packed: the latent
+    attention's 192 / 128 at its cell's shape (32 heads, s 4096: groups of
+    2 heads, two K/V blocks of 1024 resident in turn) and at a shorter
+    one, and a v narrower than the lanes under a q / k at them (dV's
+    accumulator streams its thin operand, dK's does not)."""
+
+    def loss(q, k, value):
+        out = pk.flash_attention(
+            q, k, value, causal=True, layout="bsm", n_heads=heads,
+            interpret=False,
+        )
+        return out.astype(jnp.float32).sum()
+
+    wide = ((batch, seq, qk * heads), jnp.bfloat16)
+    narrow = ((batch, seq, v * heads), jnp.bfloat16)
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, wide, wide, narrow)
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_local_expert_layer_compiles_at_the_cell_buffer_shapes(v5e):
+    """The expert layer of the latent-attention cell: 8,192 tokens, top-8
+    of 256, 16 experts held at width 2048 x 768, bf16 rows: the three
+    matmuls over all 16 x 8,192 rows, forward and backward, and nothing
+    whose length could follow the routing."""
+    from horovod_tpu.parallel import ep
+
+    def loss(x, router, gate, up, down):
+        chosen, weights = ep.topk_route(
+            x, router, jnp.zeros((256,), jnp.float32), top_k=8, scale=2.5
+        )
+        out = ep.local_experts(
+            x, chosen, weights, gate, up, down, first_expert=0,
+            n_experts=256,
+        )
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), v5e,
+        ((8192, 2048), jnp.bfloat16), ((2048, 256), jnp.float32),
+        ((16, 2048, 768), jnp.float32), ((16, 2048, 768), jnp.float32),
+        ((16, 768, 2048), jnp.float32),
+    )
+    assert "conditional" not in hlo and "while" not in hlo
+
+
 # name: (heads, sq, skv, d, dtype, causal)
 _BHSD_CASES = {
     "d16-causal": (4, 512, 512, 16, jnp.bfloat16, True),

@@ -457,7 +457,9 @@ def test_hang_scenario_flight_recorder_end_to_end():
     clock-ordered (injection before expiry on the aligned clock)."""
     import tools.chaos_soak as soak
 
-    res = soak.run_scenario("hang", steps=5, timeout=150.0)
+    # 20 s alone; beside five other xdist workers compiling, 150 s was
+    # missed twice (PR 36), so it takes the 240 s its sibling scenarios have
+    res = soak.run_scenario("hang", steps=5, timeout=240.0)
     problems = soak.check_invariants(res, steps=5)
     assert not problems, problems
     ht, merged = _merged_trace(res)
