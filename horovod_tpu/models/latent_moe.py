@@ -27,8 +27,10 @@ Attention (``H`` heads, ``n`` = ``qk_nope_dim``, ``r`` = ``qk_rope_dim``,
     q = [q_nope | q_rope], k = [k_nope | k_r]
     out = concat_H(causal softmax(q k^T / sqrt(n + r)) v) W_o
 
-so q / k heads are ``n + r`` wide and v / out heads ``v`` wide: the flash
-kernels take the two widths (``ops/pallas_kernels.flash_attention``).
+so q / k heads are ``n + r`` wide and v / out heads ``v`` wide. The flash
+kernels take ``W_kvb``'s output and ``k_r`` as they are
+(``ops/pallas_kernels.flash_attention_latent``): ``k`` is built only for
+the XLA path.
 
 Expert layer (``E`` experts, ``k`` = ``top_k``)::
 
@@ -161,34 +163,35 @@ class LatentAttention(nn.Module):
                 norm("q_norm")(dense(cfg.q_lora_rank, "q_a")(x))
             ).reshape(b, s, h, n + r)
             kv_a = dense(cfg.kv_lora_rank + r, "kv_a")(x)
+            # [k_nope | v] a head, heads on the lanes: as the kernels take it
             kv = dense(h * (n + v), "kv_b")(
                 norm("kv_norm")(kv_a[..., :cfg.kv_lora_rank])
-            ).reshape(b, s, h, n + v)
+            )
             k_rope = rotary(
-                kv_a[..., None, cfg.kv_lora_rank:], theta=cfg.rope_theta
+                kv_a[..., cfg.kv_lora_rank:], theta=cfg.rope_theta
             )
             q = jnp.concatenate([
                 q[..., :n], rotary(q[..., n:], theta=cfg.rope_theta)
             ], axis=-1)
-            k = jnp.concatenate([
-                kv[..., :n], jnp.broadcast_to(k_rope, (b, s, h, r))
-            ], axis=-1)
-            value = kv[..., n:]
         use_flash = cfg.use_flash
         if use_flash is None:
             use_flash = device_platform() == "tpu"
         if use_flash:
-            from ..ops.pallas_kernels import flash_attention
+            from ..ops.pallas_kernels import flash_attention_latent
 
-            # the packed layout: heads on the lanes, no relayout
-            out = flash_attention(
-                q.reshape(b, s, h * (n + r)), k.reshape(b, s, h * (n + r)),
-                value.reshape(b, s, h * v), causal=True, layout="bsm",
+            out, _ = flash_attention_latent(
+                q.reshape(b, s, h * (n + r)), kv, k_rope, causal=True,
                 n_heads=h,
             )
         else:
+            with jax.named_scope("mla_proj"):
+                kv = kv.reshape(b, s, h, n + v)
+                k = jnp.concatenate([
+                    kv[..., :n],
+                    jnp.broadcast_to(k_rope[:, :, None], (b, s, h, r)),
+                ], axis=-1)
             out = dot_product_attention(
-                q, k, value, causal=True
+                q, k, kv[..., n:], causal=True
             ).reshape(b, s, h * v)
         with jax.named_scope("mla_proj"):
             return dense(cfg.d_model, "o")(out)

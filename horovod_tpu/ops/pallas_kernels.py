@@ -19,6 +19,10 @@ model in ``models/``.  This module provides it:
   ring attention merges one pair per ring hop with
   :func:`combine_blocks`, so the Pallas kernel is the per-step compute of
   the sequence-parallel path too.
+* :func:`flash_attention_latent` — the same three kernels fed as latent
+  attention holds its keys and values: one packed ``[k_nope | v]`` operand
+  (``kv_b``'s output as it leaves the matmul) and the one rotary key every
+  head of a position shares, so K is never built in HBM.
 
 Causality across ring steps needs *global* positions, so the kernel takes
 ``q_offset``/``kv_offset`` (traced scalars, prefetched to SMEM): block r
@@ -58,6 +62,7 @@ _VMEM = pltpu.VMEM
 __all__ = [
     "flash_attention",
     "flash_attention_with_lse",
+    "flash_attention_latent",
     "combine_blocks",
     "quantize_blockwise_pallas",
     "dequantize_blockwise_pallas",
@@ -79,7 +84,7 @@ def _use_interpret() -> bool:
 
 
 def _head_group(h: int, block_q: int, block_k: int, d: int,
-                packed: bool, dv: Optional[int] = None) -> int:
+                packed: bool, dv: Optional[int] = None, rope: int = 0) -> int:
     """Heads per program.  At short sequences a single head's two
     ``d``-thin matmuls underfill the MXU pipeline and per-program overhead
     (scalar DMAs, grid bookkeeping) dominates, so each program handles a
@@ -100,12 +105,15 @@ def _head_group(h: int, block_q: int, block_k: int, d: int,
 
     ``d`` is the width of a q / k head, ``dv`` that of a v / out head
     (``None``: the same): the accumulator and the out block are ``dv``
-    wide, and a packed group has to be lane-legal at both widths."""
+    wide, and a packed group has to be lane-legal at both widths; with
+    ``rope`` (:func:`flash_attention_latent`) also at ``d - rope + dv``,
+    the width of a head of the packed ``kv``."""
     dv = d if dv is None else dv
+    widths = (d, dv) + ((d - rope + dv,) if rope else ())
 
     def legal(g):
-        return not packed or g == h or (
-            (g * d) % _LANES == 0 and (g * dv) % _LANES == 0
+        return not packed or g == h or all(
+            (g * w) % _LANES == 0 for w in widths
         )
 
     groups = [g for g in (12, 8, 6, 4, 3, 2, 1) if h % g == 0 and legal(g)]
@@ -306,6 +314,37 @@ def _head_store(ref, g, d, packed, value):
         ref[0, g] = value
 
 
+# Where a kernel finds head ``g``'s keys and values in its second and third
+# block operand.  ``rope == 0``: they are K, ``[.., d]`` a head, and V,
+# ``[.., dv]`` a head.  ``rope == r > 0`` (:func:`flash_attention_latent`):
+# the second is the packed ``kv``, ``[k_nope | v]`` a head (``d - r`` and
+# ``dv`` wide, both static lane slices of the one copied block), and the
+# third the ``[rows, r]`` rotary key that every head shares.  A head's
+# ``[rows, d]`` key tile is then put together in VMEM, tile by tile, and no
+# key of that width exists in HBM.  Measured on the v5e at the expert
+# cell's shape (PERF.md, PR 37): the scores as two dots into one sum,
+# ``k_nope·q_nopeᵀ + k_rope·q_ropeᵀ``, cost the forward and dK/dV the same
+# and dQ, whose ``Kᵀ·dsᵀ`` then falls into two products as well, 5% more.
+
+
+def _k_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope):
+    """Head ``g``'s ``[rows, d]`` key tile."""
+    if rope:
+        lo = g * (d - rope + dv)
+        return jnp.concatenate(
+            [k_ref[0, rows, lo:lo + d - rope], v_ref[0, rows, :]], axis=1
+        )
+    return _head(k_ref, g, d, packed, rows)
+
+
+def _v_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope):
+    """Head ``g``'s ``[rows, dv]`` value tile."""
+    if rope:
+        hi = (g + 1) * (d - rope + dv)
+        return k_ref[0, rows, hi - dv:hi]
+    return _head(v_ref, g, dv, packed, rows)
+
+
 def _block_dims(q_ref, k_ref, packed: bool, d: int):
     """``(group, block_q, block_k)`` of a kernel's q and K/V blocks."""
     if packed:
@@ -379,6 +418,7 @@ def _fwd_kernel(
     packed: bool = False,
     d: int = 0,
     dv: int = 0,
+    rope: int = 0,
 ):
     """One (batch*head group, q-block, k-block) grid step of the online
     softmax.
@@ -421,10 +461,13 @@ def _fwd_kernel(
     sublane tile; caller reads sublane 0); acc_ref: [G, dv, block_q];
     m_ref / l_ref: [G, 1, block_q].  ``d`` is the width of a q / k head,
     ``dv`` of a v / out head (latent attention: 192 and 128); nothing in
-    the body is score-by-width, so one body serves both.
+    the body is score-by-width, so one body serves both.  With ``rope``
+    k_ref is the packed ``kv`` block, [1, block_k, G*(d - rope + dv)],
+    and v_ref the shared key's, [1, block_k, rope] (``_k_head``).
     """
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
+    heads = dict(packed=packed, d=d, dv=dv, rope=rope)
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -446,7 +489,7 @@ def _fwd_kernel(
             # costs ~4-6 MXU passes per dot (measured 15% kernel
             # efficiency before this).  Softmax statistics are fp32.
             s_t = jax.lax.dot_general(
-                _head(k_ref, g, d, packed, rk),
+                _k_head(k_ref, v_ref, g, rk, **heads),
                 _head(q_ref, g, d, packed, rq),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -472,7 +515,7 @@ def _fwd_kernel(
             # keeps the reduction exact; the p rounding is the standard
             # flash trade).  Vᵀ·pᵀ: the thin operand is the one turned.
             acc_ref[g, :, rq] = acc_ref[g, :, rq] * corr + jax.lax.dot_general(
-                _head(v_ref, g, dv, packed, rk),
+                _v_head(k_ref, v_ref, g, rk, **heads),
                 p_t.astype(v_ref.dtype),
                 dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -521,6 +564,9 @@ class _Plan(NamedTuple):
     tiles: Tuple[int, int]  # compute tile; the whole block unless causal
     interpret: bool
     dv: int  # width of a v / out head; ``d`` is that of a q / k head
+    # 0: k and v are operands of their own.  r > 0: the packed ``kv`` and
+    # the shared ``[.., r]`` key stand in their place (``_k_head``)
+    rope: int = 0
 
     def pad_seq(self, x, s: int, s_pad: int):
         if s_pad != s:
@@ -539,13 +585,13 @@ class _Plan(NamedTuple):
 
 
 def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
-          interpret: Optional[bool], n_heads: int) -> _Plan:
+          interpret: Optional[bool], n_heads: int, rope: int = 0) -> _Plan:
     packed = n_heads > 0
     if packed:
         b, sq, hd = q.shape
         h = n_heads
         d = hd // h
-        dv = v.shape[2] // h
+        dv = k.shape[2] // h - (d - rope) if rope else v.shape[2] // h
         skv = k.shape[1]
     else:
         b, h, sq, d = q.shape
@@ -564,8 +610,8 @@ def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
     return _Plan(
         packed, b, h, d, sq, skv, block_q, block_k,
         _round_up(sq, block_q), skv_pad,
-        _head_group(h, block_q, block_k, d, packed, dv), tiles, interpret,
-        dv,
+        _head_group(h, block_q, block_k, d, packed, dv, rope), tiles,
+        interpret, dv, rope,
     )
 
 
@@ -632,6 +678,7 @@ def _fwd_pallas(
     interpret: Optional[bool],
     n_heads: int = 0,
     static_offsets: Optional[Tuple[int, int]] = None,
+    rope: int = 0,
 ):
     """Run the kernel.
 
@@ -645,17 +692,22 @@ def _fwd_pallas(
     them statically (``_head``), so q/k/v/o need **no relayout at all**:
     the r4 head-major path still paid the ``[B,S,H·D]→[B,H,S,D]``
     transpose by letting XLA fold it into the projection dots, which then
-    ran at ~43%% of peak (``docs/perf_analysis_bert_r04.md``).
+    ran at ~43%% of peak (measured before PR 1; the record went in PR 30).
+
+    ``rope`` (packed mode only): k is the packed ``kv`` ``[B,Skv,H*(n+Dv)]``
+    and v the shared key ``[B,Skv,rope]`` (``_k_head``).
 
     ``static_offsets``: the two offsets where the caller gave Python
     ints, for the build-time tile counters only.
     """
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-              interpret=interpret, n_heads=n_heads)
+              interpret=interpret, n_heads=n_heads, rope=rope)
     if causal:
         _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
     if p.dv != p.d:
         _registry.always().counter("flash.calls.split_widths").inc()
+    if rope:
+        _registry.always().counter("flash.calls.latent_kv").inc()
     return _flash_fwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv),
         p=p, sm_scale=sm_scale, causal=causal,
@@ -672,7 +724,7 @@ def _fwd_pallas(
 )
 def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
                     causal: bool):
-    b, h, d, dv, group = p.b, p.h, p.d, p.dv, p.group
+    b, h, d, dv, group, rope = p.b, p.h, p.d, p.dv, p.group, p.rope
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
     )
@@ -696,12 +748,12 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
             lambda bi, hi, qi, kj, *geom: (bi, hi, qi, 0),
         )
 
-    def kv_side(width):
+    def kv_side(width, shared=False):
         if p.packed:
             return _vspec(
-                (1, block_k, group * width),
+                (1, block_k, width if shared else group * width),
                 lambda bi, hi, qi, kj, *geom: (
-                    bi, kv_block(qi, kj, geom), hi),
+                    bi, kv_block(qi, kj, geom), 0 if shared else hi),
             )
         return _vspec(
             (1, group, block_k, width),
@@ -717,12 +769,15 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, causal=causal,
             masked=causal or skv_pad != p.skv, tiles=p.tiles,
-            packed=p.packed, d=d, dv=dv,
+            packed=p.packed, d=d, dv=dv, rope=rope,
         ),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
-            in_specs=[q_side(d), kv_side(d), kv_side(dv)],
+            in_specs=[q_side(d)] + (
+                [kv_side(d - rope + dv), kv_side(rope, shared=True)] if rope
+                else [kv_side(d), kv_side(dv)]
+            ),
             out_specs=[
                 q_side(dv),
                 _vspec(
@@ -814,7 +869,8 @@ def _dkv_streams_thin(d: int) -> bool:
 
 def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
                     g_ref, g, rq, rk, valid, *, sm_scale: float,
-                    packed: bool = False, d: int = 0, dv: int = 0):
+                    packed: bool = False, d: int = 0, dv: int = 0,
+                    rope: int = 0):
     """Shared per-(q rows ``rq``, K/V rows ``rk``, head) recompute:
     returns (pᵀ, dsᵀ, q_blk, g_blk, k_blk), the two score-sized arrays
     keys-by-queries, ``[cols, rows]``, like ``valid`` (``None`` is the
@@ -826,10 +882,11 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
     """
     # Storage-dtype (bf16) matmul inputs with fp32 accumulation — see the
     # forward kernel note; only the softmax/ds algebra runs in fp32.
+    heads = dict(packed=packed, d=d, dv=dv, rope=rope)
     q_blk = _head(q_ref, g, d, packed, rq)
     g_blk = _head(g_ref, g, dv, packed, rq)
-    k_blk = _head(k_ref, g, d, packed, rk)
-    v_blk = _head(v_ref, g, dv, packed, rk)
+    k_blk = _k_head(k_ref, v_ref, g, rk, **heads)
+    v_blk = _v_head(k_ref, v_ref, g, rk, **heads)
 
     s_t = jax.lax.dot_general(
         k_blk,
@@ -863,8 +920,10 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
 def _bwd_kernel_dkdv(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
     q_ref, k_ref, v_ref, g_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-    *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
+    *shared_acc,
+    sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
+    rope: int = 0,
 ):
     """grid (b, h-group, kj, qi): each K tile accumulates over streamed
     Q blocks; the per-head loop is a static unroll (see forward).  Heads
@@ -874,7 +933,16 @@ def _bwd_kernel_dkdv(
     lane slice from 0, a whole number of K/V tiles), turned back once per
     head where the K/V block is written.  Else ``[G, block_k, d]``.  Each
     accumulator takes its form from its own width (dK's ``d``, dV's
-    ``dv``)."""
+    ``dv``).
+
+    With ``rope`` (``_k_head``) dk_ref is the ``[dk_nope | dv]`` block in
+    ``kv``'s own layout, written by head where each accumulator ends, and
+    dK's accumulator is ``d - rope`` wide; what the shared key receives
+    from the group's heads adds up, in float32, in one more accumulator of
+    width ``rope`` (``shared_acc``), and dv_ref, ``[1, 1, rope, block_k]``
+    float32, takes that partial sum: one a head group, the groups summed
+    outside."""
+    n = d - rope
     qi = pl.program_id(3)
     kj = pl.program_id(2)
     nq = pl.num_programs(3)
@@ -883,13 +951,14 @@ def _bwd_kernel_dkdv(
 
     @pl.when(qi == 0)
     def _init():
-        dk_acc[:, :, :] = jnp.zeros_like(dk_acc)
-        dv_acc[:, :, :] = jnp.zeros_like(dv_acc)
+        for acc in (dk_acc, dv_acc) + shared_acc:
+            acc[:, :, :] = jnp.zeros_like(acc)
 
     def accumulate(acc, g, rk, scores_t, blk, scale=None):
         """Adds ``blkᵀ·scores`` as ``[width, cols]`` (the ``[rows, width]``
         g / Q tile streamed and turned, ``pᵀ`` / ``dsᵀ`` standing as the
-        right operand), or ``scores_t·blk`` as ``[cols, width]``; fp32."""
+        right operand), or ``scores_t·blk`` as ``[cols, width]``; fp32.
+        Returns the scores as the matmul took them, for a second use."""
         thin = _dkv_streams_thin(blk.shape[1])
         at = (g, slice(None), rk) if thin else (g, rk, slice(None))
         so_far = acc[at]  # read before the product, as the kernel always did
@@ -903,28 +972,48 @@ def _bwd_kernel_dkdv(
             preferred_element_type=jnp.float32,
         )
         acc[at] = so_far + (product if scale is None else product * scale)
+        return scores_t
 
     def update(rq, rk, valid):
         for g in range(group):
             p_t, ds_t, q_blk, g_blk, _ = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
-                dv=dv,
+                dv=dv, rope=rope,
             )
             accumulate(dv_acc, g, rk, p_t, g_blk)
-            accumulate(dk_acc, g, rk, ds_t, q_blk, sm_scale)
+            ds_t = accumulate(
+                dk_acc, g, rk, ds_t, q_blk[:, :n] if rope else q_blk, sm_scale
+            )
+            if rope:
+                accumulate(shared_acc[0], 0, rk, ds_t, q_blk[:, n:], sm_scale)
 
     _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
                  block_k=block_k, causal=causal, masked=masked, tiles=tiles)
 
+    def grad(acc, g, width):
+        """Head ``g`` of an accumulator as ``[block_k, width]``."""
+        out = acc[g, :, :]
+        return out.T if _dkv_streams_thin(width) else out
+
     @pl.when(qi == nq - 1)
     def _finalize():
         for g in range(group):
+            if rope:  # [dk_nope | dv] side by side, as kv holds the head
+                head = g * (n + dv)
+                for acc, lo, width in ((dk_acc, head, n), (dv_acc, head + n, dv)):
+                    dk_ref[0, :, lo:lo + width] = grad(acc, g, width).astype(
+                        dk_ref.dtype
+                    )
+                continue
             for ref, acc, width in ((dk_ref, dk_acc, d), (dv_ref, dv_acc, dv)):
-                grad = acc[g, :, :]
-                if _dkv_streams_thin(width):
-                    grad = grad.T
-                _head_store(ref, g, width, packed, grad.astype(ref.dtype))
+                _head_store(
+                    ref, g, width, packed,
+                    grad(acc, g, width).astype(ref.dtype),
+                )
+        if rope:  # rows on the lanes, as the thin form holds it
+            shared = shared_acc[0][0, :, :]
+            dv_ref[0, 0] = shared if _dkv_streams_thin(rope) else shared.T
 
 
 def _bwd_kernel_dq(
@@ -932,6 +1021,7 @@ def _bwd_kernel_dq(
     q_ref, k_ref, v_ref, g_ref, dq_ref, dq_acc,
     *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
+    rope: int = 0,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
@@ -951,7 +1041,7 @@ def _bwd_kernel_dq(
             _, ds_t, _, _, k_blk = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
-                dv=dv,
+                dv=dv, rope=rope,
             )
             dq_acc[g, :, rq] = dq_acc[g, :, rq] + jax.lax.dot_general(
                 k_blk, ds_t.astype(k_blk.dtype),
@@ -974,16 +1064,21 @@ def _bwd_pallas(
     q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, *,
     sm_scale: float, causal: bool, block_q: int, block_k: int,
     interpret: Optional[bool], n_heads: int = 0,
-    static_offsets: Optional[Tuple[int, int]] = None,
+    static_offsets: Optional[Tuple[int, int]] = None, rope: int = 0,
 ):
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-              interpret=interpret, n_heads=n_heads)
+              interpret=interpret, n_heads=n_heads, rope=rope)
     if causal:
         # one count for each of the two kernels
         for _ in range(2):
             _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=True))
-    if _dkv_streams_thin(p.d) or _dkv_streams_thin(p.dv):
+    # dK/dV's accumulators: dK's (with rope its own columns only), dV's
+    # and, with rope, the shared key's
+    accumulated = (p.d - rope, p.dv) + ((rope,) if rope else ())
+    if any(_dkv_streams_thin(width) for width in accumulated):
         _registry.always().counter("flash.dkv.thin_streamed").inc()
+    if rope:
+        _registry.always().counter("flash.calls.latent_kv").inc(2)
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
         g_lse, p=p, sm_scale=sm_scale, causal=causal,
@@ -996,6 +1091,7 @@ def _bwd_pallas(
 def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
                     sm_scale: float, causal: bool):
     b, h, d, dv, group, sq, skv = p.b, p.h, p.d, p.dv, p.group, p.sq, p.skv
+    rope, n = p.rope, p.d - p.rope
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
     )
@@ -1034,7 +1130,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
     kernel_params = dict(
         sm_scale=sm_scale, causal=causal,
         masked=causal or skv_pad != skv or sq_pad != sq,
-        tiles=p.tiles, q_len=sq, packed=p.packed, d=d, dv=dv,
+        tiles=p.tiles, q_len=sq, packed=p.packed, d=d, dv=dv, rope=rope,
     )
     call_params = dict(
         compiler_params=pltpu.CompilerParams(
@@ -1048,7 +1144,9 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         two axes are ``order``: "kq" (dK/dV: q streams innermost, its
         skipped steps clamped to the first q block needed) or "qk" (dQ:
         K/V streams innermost, clamped to the last K/V block needed).
-        q and k blocks are ``d`` wide, v and g blocks ``dv``."""
+        q and k blocks are ``d`` wide, v and g blocks ``dv``; with
+        ``rope`` the k block is the packed ``kv``'s and the v block the
+        shared key's."""
 
         def blocks(i, j, geom):
             qi, kj = (j, i) if order == "kq" else (i, j)
@@ -1070,6 +1168,10 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             qi, _ = blocks(i, j, geom)
             return (bi, hi, 0, qi)
 
+        def shared_map(bi, hi, i, j, *geom):
+            _, kj = blocks(i, j, geom)
+            return (bi, kj, 0)
+
         def block(rows, width, index_map):
             return _vspec(
                 (1, rows, group * width) if p.packed
@@ -1078,8 +1180,11 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
 
         return (
             _vspec((1, group, 8, block_q), stat_map),
-            block(block_q, d, q_map), block(block_k, d, kv_map),
-            block(block_k, dv, kv_map), block(block_q, dv, q_map),
+            block(block_q, d, q_map),
+            block(block_k, n + dv if rope else d, kv_map),
+            _vspec((1, block_k, rope), shared_map) if rope
+            else block(block_k, dv, kv_map),
+            block(block_q, dv, q_map),
         )
 
     def shape_like(x, s_pad, width):
@@ -1091,12 +1196,28 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
     stat_spec, q_spec, k_spec, v_spec, g_spec = specs("kq")
 
-    def dkv_acc(width):
+    def dkv_acc(width, heads=group):
         return _VMEM(
-            (group, width, block_k) if _dkv_streams_thin(width)
-            else (group, block_k, width), jnp.float32,
+            (heads, width, block_k) if _dkv_streams_thin(width)
+            else (heads, block_k, width), jnp.float32,
         )
 
+    dkv_out_specs, dkv_out_shape = [k_spec, v_spec], [
+        shape_like(k, skv_pad, d), shape_like(v, skv_pad, dv)
+    ]
+    if rope:
+        # [dk_nope | dv] in kv's layout, and the shared key's gradient as
+        # one float32 partial sum a head group, the K/V rows on the lanes
+        dkv_out_specs[1] = _vspec(
+            (1, 1, rope, block_k),
+            lambda bi, hi, kj, qi, *geom: (bi, hi, 0, kj),
+        )
+        dkv_out_shape = [
+            shape_like(k, skv_pad, n + dv),
+            jax.ShapeDtypeStruct(
+                (b, h // group, rope, skv_pad), jnp.float32
+            ),
+        ]
     grad_k, grad_v = pl.pallas_call(
         functools.partial(_bwd_kernel_dkdv, **kernel_params),
         grid_spec=_grid_spec(
@@ -1104,13 +1225,17 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             grid=(b, h // group, skv_pad // block_k, sq_pad // block_q),
             in_specs=[stat_spec, stat_spec, stat_spec,
                       q_spec, k_spec, v_spec, g_spec],
-            out_specs=[k_spec, v_spec],
-            scratch_shapes=[dkv_acc(d), dkv_acc(dv)],
+            out_specs=dkv_out_specs,
+            scratch_shapes=[dkv_acc(n), dkv_acc(dv)] + (
+                [dkv_acc(rope, 1)] if rope else []
+            ),
         ),
-        out_shape=[shape_like(k, skv_pad, d), shape_like(v, skv_pad, dv)],
+        out_shape=dkv_out_shape,
         **call_params,
         name="hvd_flash_bwd_dkv",
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
+    if rope:
+        grad_v = grad_v.sum(axis=1).swapaxes(1, 2)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
     stat_spec, q_spec, k_spec, v_spec, g_spec = specs("qk")
@@ -1143,10 +1268,13 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12)
 )
 def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-           interpret, n_heads=0, static_offsets=None):
+           interpret, n_heads=0, static_offsets=None, rope=0):
+    """``(out, lse)`` with the exact backward.  With ``rope`` the operands
+    ``k`` and ``v`` are the packed ``kv`` and the shared key (``_k_head``),
+    and so are their cotangents."""
     return _fwd_pallas(
         q,
         k,
@@ -1160,20 +1288,21 @@ def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         interpret=interpret,
         n_heads=n_heads,
         static_offsets=static_offsets,
+        rope=rope,
     )
 
 
 def _flash_fwd(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
-               block_k, interpret, n_heads=0, static_offsets=None):
+               block_k, interpret, n_heads=0, static_offsets=None, rope=0):
     out, lse = _flash(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-        interpret, n_heads, static_offsets
+        interpret, n_heads, static_offsets, rope
     )
     return (out, lse), (q, k, v, q_offset, kv_offset, out, lse)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
-               static_offsets, res, g):
+               static_offsets, rope, res, g):
     q, k, v, q_offset, kv_offset, out, lse = res
     g_out, g_lse = g
     dq, dk, dv = _bwd_pallas(
@@ -1193,6 +1322,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
         interpret=interpret,
         n_heads=n_heads,
         static_offsets=static_offsets,
+        rope=rope,
     )
     # Integer offsets take float0 cotangents.
     zero = np.zeros((), dtype=jax.dtypes.float0)
@@ -1205,6 +1335,32 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
+
+
+def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
+                block_k, interpret, n_heads, rope=0):
+    """``_flash`` on a public entry's arguments: ``(out, lse)``."""
+    # Offsets given as Python ints (the model path: 0, 0) are also kept
+    # static, for the build-time tile counters; the kernels read the
+    # traced scalars either way.
+    static_offsets = None
+    if all(isinstance(x, (int, np.integer)) for x in (q_offset, kv_offset)):
+        static_offsets = (int(q_offset), int(kv_offset))
+    return _flash(
+        q,
+        k,
+        v,
+        jnp.asarray(q_offset, jnp.int32),
+        jnp.asarray(kv_offset, jnp.int32),
+        float(sm_scale),
+        bool(causal),
+        int(block_q),
+        int(block_k),
+        interpret,
+        int(n_heads),
+        static_offsets,
+        rope,
+    )
 
 
 def flash_attention_with_lse(
@@ -1231,7 +1387,8 @@ def flash_attention_with_lse(
     layout; heads are sliced from the minor axis inside the kernel, so
     q/k/v/out need no relayout at all (the r4 ``bhsd`` path still paid
     the head transpose by folding it into the projection dots, which
-    then ran at ~43%% of MXU peak — ``docs/perf_analysis_bert_r04.md``).
+    then ran at ~43%% of MXU peak: measured before PR 1, the record went
+    in PR 30).
     v (and so ``out``) may have another head width than q and k in every
     layout (latent attention: q / k heads 192 wide, v / out heads 128);
     the scores scale by the q / k width unless ``sm_scale`` is given.
@@ -1264,25 +1421,9 @@ def flash_attention_with_lse(
         raise ValueError(
             f"layout must be 'bshd', 'bhsd' or 'bsm', got {layout!r}"
         )
-    # Offsets given as Python ints (the model path: 0, 0) are also kept
-    # static, for the build-time tile counters; the kernels read the
-    # traced scalars either way.
-    static_offsets = None
-    if all(isinstance(x, (int, np.integer)) for x in (q_offset, kv_offset)):
-        static_offsets = (int(q_offset), int(kv_offset))
-    out, lse = _flash(
-        q,
-        k,
-        v,
-        jnp.asarray(q_offset, jnp.int32),
-        jnp.asarray(kv_offset, jnp.int32),
-        float(sm_scale),
-        bool(causal),
-        int(block_q),
-        int(block_k),
-        interpret,
-        int(n_heads) if packed else 0,
-        static_offsets,
+    out, lse = _call_flash(
+        q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
+        interpret, n_heads if packed else 0,
     )
     if layout == "bshd":
         out = jnp.moveaxis(out, 1, 2)
@@ -1327,6 +1468,65 @@ def flash_attention(
         n_heads=n_heads,
     )
     return out
+
+
+def flash_attention_latent(
+    q,
+    kv,
+    k_rope,
+    *,
+    n_heads: int,
+    causal: bool = False,
+    q_offset=0,
+    kv_offset=0,
+    sm_scale: Optional[float] = None,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Blockwise attention over keys and values as latent attention holds
+    them, returning ``(out, lse)`` like :func:`flash_attention_with_lse`.
+
+    With ``H`` heads whose keys are ``n`` unshared columns and ``r`` rotary
+    columns that every head of a position shares, and values ``dv`` wide:
+    q ``[B, Sq, H*(n+r)]``, ``[q_nope | q_rope]`` a head (rotary applied);
+    kv ``[B, Skv, H*(n+dv)]``, ``[k_nope | v]`` a head, which is the
+    ``kv_b`` projection's output as the matmul leaves it; k_rope
+    ``[B, Skv, r]`` (rotary applied).  out ``[B, Sq, H*dv]``, lse fp32
+    ``[B, H, Sq]``.  The widths are read off the shapes.
+
+    The same three kernels as the ``q, k, v`` entry run, with the same
+    plan; their K/V block is one block of ``kv`` and one of ``k_rope``, a
+    head's ``[rows, n+r]`` key tile is put together in VMEM (``_k_head``),
+    and neither ``[.., H, n+r]`` keys nor a separate v exist in HBM.  The
+    backward returns cotangents in the operands' own layouts: ``[dk_nope |
+    dv]`` a head, and ``k_rope``'s summed over the heads in float32.
+    Scores scale by ``1/sqrt(n+r)`` unless ``sm_scale`` is given.
+    Compiled, ``n``, ``r`` and ``dv`` have to be multiples of 64 (lane
+    slicing).
+    """
+    r = k_rope.shape[-1]
+    n = q.shape[-1] // n_heads - r
+    dv = kv.shape[-1] // n_heads - n
+    if (q.shape[-1] % n_heads or kv.shape[-1] % n_heads
+            or min(n, dv) <= 0 or kv.shape[:2] != k_rope.shape[:2]):
+        raise ValueError(
+            f"q {q.shape}, kv {kv.shape}, k_rope {k_rope.shape} are not "
+            f"[B,Sq,H*(n+r)], [B,Skv,H*(n+dv)], [B,Skv,r] for H={n_heads}"
+        )
+    if any(w % 64 for w in (n, r, dv)) and not (
+        interpret if interpret is not None else _use_interpret()
+    ):
+        raise ValueError(
+            "flash_attention_latent needs widths that are multiples of 64 "
+            f"on TPU (Mosaic lane slicing); got n={n}, r={r}, dv={dv}"
+        )
+    if sm_scale is None:
+        sm_scale = 1.0 / float(np.sqrt(n + r))
+    return _call_flash(
+        q, kv, k_rope.astype(kv.dtype), q_offset, kv_offset, sm_scale,
+        causal, block_q, block_k, interpret, n_heads, rope=r,
+    )
 
 
 # ---------------------------------------------------------------------------

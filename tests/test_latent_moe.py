@@ -15,6 +15,7 @@ import pytest
 from benchmark.lib import plain_latent_moe as plain
 from horovod_tpu.analysis.jaxpr_walk import _sub_jaxprs_generic
 from horovod_tpu.models.latent_moe import (
+    LatentAttention,
     LatentMoEConfig,
     LatentMoELM,
     RoutedExperts,
@@ -91,6 +92,53 @@ def test_loss_and_every_gradient_leaf_match_the_plain_reference(n_mtp, flash):
     ))(params, tokens)
     np.testing.assert_allclose(got[0], want[0], rtol=2e-6)
     _assert_trees_close(got[1], want[1], 2e-4)
+
+
+def test_flash_path_hands_the_kernels_kv_b_output_and_the_one_rotary_key():
+    """Traced at the cell's widths (32 heads, 128 + 64 / 128): the flash
+    path's kernels get ``kv_b``'s matmul output itself and the
+    ``[B, S, 64]`` rotary key; the only ``[B, S, H, 192]`` concatenation
+    left is q's, and nothing broadcasts the rotary key to the heads. The
+    XLA path still builds K. The kernels are counted 3 a block."""
+    b, s, h, n, r, v = 2, 4096, 32, 128, 64, 128
+    x = jax.ShapeDtypeStruct((b, s, 2048), jnp.bfloat16)
+    counter = registry.always().counter("flash.calls.latent_kv")
+
+    params = jax.eval_shape(
+        LatentAttention(LatentMoEConfig(use_flash=False)).init,
+        jax.random.PRNGKey(0), x,
+    )
+
+    def eqns_of(use_flash, grad=False):
+        attn = LatentAttention(LatentMoEConfig(use_flash=use_flash))
+        fn = lambda p, x: attn.apply(p, x).astype(jnp.float32).sum()  # noqa: E731
+        traced = jax.make_jaxpr(jax.grad(fn) if grad else fn)(params, x)
+        return traced.jaxpr.eqns
+
+    def built_keys(eqns):
+        return (
+            [e for e in eqns if e.primitive.name == "concatenate"
+             and e.outvars[0].aval.shape == (b, s, h, n + r)],
+            [e for e in eqns if e.primitive.name == "broadcast_in_dim"
+             and e.outvars[0].aval.shape == (b, s, h, r)],
+        )
+
+    before = counter.get()
+    eqns = eqns_of(True)
+    assert counter.get() == before + 1
+    concatenated, broadcast = built_keys(eqns)
+    assert len(concatenated) == 1 and not broadcast  # q alone
+    (call,) = [e for e in eqns if e.primitive.name == "custom_vjp_call"]
+    q, kv, k_rope = call.invars[:3]
+    assert kv.aval.shape == (b, s, h * (n + v))
+    assert k_rope.aval.shape == (b, s, r)
+    (made_kv,) = [e for e in eqns if kv in e.outvars]
+    assert made_kv.primitive.name == "dot_general"  # kv_b, nothing between
+    eqns_of(True, grad=True)
+    assert counter.get() == before + 1 + 3
+    concatenated, broadcast = built_keys(eqns_of(False))
+    assert len(concatenated) == 2 and len(broadcast) == 1
+    assert counter.get() == before + 4
 
 
 def test_rotary_rotates_adjacent_pairs_and_keeps_relative_position():

@@ -14,6 +14,7 @@ from horovod_tpu.models.transformer import dot_product_attention
 from horovod_tpu.ops.pallas_kernels import (
     combine_blocks,
     flash_attention,
+    flash_attention_latent,
     flash_attention_with_lse,
 )
 
@@ -885,4 +886,186 @@ def test_split_widths_counter_and_group():
     for h in (6, 12, 16):
         assert _head_group(h, 512, 1024, 64, True, 64) == _head_group(
             h, 512, 1024, 64, True
+        )
+
+
+# ---------------------------------------------------------------------------
+# Latent attention's operands: the packed ``kv`` (``[k_nope | v]`` a head)
+# and the one rotary key every head shares, through the same three kernels.
+# ---------------------------------------------------------------------------
+
+# name: (heads, n, r, dv, sq, skv, block, causal)
+_LATENT_CASES = {
+    "16+8-16-two-heads": (2, 16, 8, 16, 72, 72, 32, True),
+    "16+8-16-one-head-padded": (1, 16, 8, 16, 40, 40, 16, True),
+    "128+64-128-two-heads": (2, 128, 64, 128, 64, 64, 32, True),
+    "128+64-128-one-head-padded": (1, 128, 64, 128, 40, 40, 16, True),
+    "16+8-16-cross-skv-padded": (2, 16, 8, 16, 32, 40, 16, False),
+}
+
+
+def _built_keys(kv, k_rope, n):
+    """K as it was built before the kernels read ``kv``: ``[k_nope | the
+    shared key, broadcast to every head]``; and v."""
+    b, s, h, _ = kv.shape
+    k_rope = jnp.broadcast_to(k_rope[:, :, None], (b, s, h, k_rope.shape[-1]))
+    return jnp.concatenate([kv[..., :n], k_rope], axis=-1), kv[..., n:]
+
+
+@pytest.mark.parametrize("case", list(_LATENT_CASES))
+def test_flash_latent_matches_reference_on_built_keys(case):
+    """Forward, ``lse`` and the cotangents of q, ``kv`` and the shared key
+    from one trace, against ``dot_product_attention`` on K built the old
+    way (interpreter); groups of one head and of two."""
+    h, n, r, dv, sq, skv, block, causal = _LATENT_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(13), 4)
+    q = jax.random.normal(keys[0], (1, sq, h, n + r))
+    kv = jax.random.normal(keys[1], (1, skv, h, n + dv))
+    k_rope = jax.random.normal(keys[2], (1, skv, r))
+    w = jax.random.normal(keys[3], (1, sq, h, dv))
+
+    def flash(q, kv, k_rope):
+        out, lse = flash_attention_latent(
+            q.reshape(1, sq, h * (n + r)), kv.reshape(1, skv, h * (n + dv)),
+            k_rope, n_heads=h, causal=causal, block_q=block, block_k=block,
+        )
+        return out.reshape(1, sq, h, dv), lse
+
+    def reference(q, kv, k_rope):
+        k, v = _built_keys(kv, k_rope, n)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(n + r)
+        if causal:
+            scores = jnp.where(
+                jnp.tril(jnp.ones((sq, skv), bool)), scores, -1e30
+            )
+        return (dot_product_attention(q, k, v, causal=causal),
+                jax.scipy.special.logsumexp(scores, axis=-1))
+
+    def loss(fn):
+        def f(q, kv, k_rope):
+            out, lse = fn(q, kv, k_rope)
+            return jnp.sum(out * w) + 0.1 * jnp.sum(lse ** 2), (out, lse)
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        got, (out, lse) = loss(flash)(q, kv, k_rope)
+        want, (ref_out, ref_lse) = loss(reference)(q, kv, k_rope)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def _latent_calls(heads=4, s=1024, n=128, r=64, dv=128):
+    """The three ``pallas_call`` equations of forward + backward of a causal
+    latent call, as the chip compiles it, by kernel name."""
+    shapes = [
+        jax.ShapeDtypeStruct((1, s, width), jnp.bfloat16)
+        for width in (heads * (n + r), heads * (n + dv), r)
+    ]
+
+    def loss(q, kv, k_rope):
+        out, _ = flash_attention_latent(
+            q, kv, k_rope, n_heads=heads, causal=True, interpret=False
+        )
+        return out.astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*shapes)
+    calls = {
+        e.params["name"]: e for e in _walk(traced.jaxpr)
+        if e.primitive.name == "pallas_call"
+    }
+    assert sorted(calls) == [
+        "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd"
+    ]
+    return calls
+
+
+def test_latent_kernels_take_kv_and_the_shared_key_as_they_are():
+    """At the cell's widths (128 + 64 / 128, groups of 2 heads): every
+    kernel's K/V operands are the packed ``[1, s, H*256]`` and the shared
+    ``[1, s, 64]`` themselves, no key of ``H*192`` columns is an operand
+    or a result of anything but q and dq, dK/dV writes ``[dk_nope | dv]``
+    in ``kv``'s layout and one float32 partial of the shared key's
+    gradient a head group (K/V rows on the lanes, unpadded), and the one
+    thing a body concatenates is a head's key tile, ``[cols, 128 + 64]``,
+    in VMEM."""
+    calls = _latent_calls()
+    for name, call in calls.items():
+        operands = [tuple(v.aval.shape) for v in call.invars]
+        assert (1, 1024, 4 * 256) in operands, (name, operands)
+        assert (1, 1024, 64) in operands, (name, operands)
+        # q, and in the backward nothing else of that width
+        assert operands.count((1, 1024, 4 * 192)) == 1, (name, operands)
+        built = {
+            tuple(tuple(v.aval.shape) for v in e.invars)
+            for e in _walk(call.params["jaxpr"])
+            if e.primitive.name == "concatenate"
+        }
+        assert built and all(
+            len(tiles) == 2 and tiles[0][0] == tiles[1][0]
+            and (tiles[0][1], tiles[1][1]) == (128, 64) for tiles in built
+        ), (name, built)
+    results = lambda name: [  # noqa: E731
+        (tuple(v.aval.shape), v.aval.dtype) for v in calls[name].outvars
+    ]
+    assert results("hvd_flash_bwd_dkv") == [
+        ((1, 1024, 4 * 256), jnp.bfloat16), ((1, 2, 64, 1024), jnp.float32)
+    ]
+    assert results("hvd_flash_bwd_dq") == [((1, 1024, 4 * 192), jnp.bfloat16)]
+    assert results("hvd_flash_fwd")[0] == ((1, 1024, 4 * 128), jnp.bfloat16)
+
+
+def test_latent_dkv_accumulators_take_their_form_from_their_own_width():
+    """dK's unshared part and dV, both at the lanes, keep ``[cols, 128]``
+    (``dsᵀ·q_nope``, ``pᵀ·g``); the shared key's 64 columns stream their
+    thin operand into ``[64, cols]``, which is written as it lies: the body
+    holds no transpose."""
+    body = list(_walk(_latent_calls()["hvd_flash_bwd_dkv"].params["jaxpr"]))
+    outs = {
+        tuple(e.outvars[0].aval.shape) for e in body
+        if e.primitive.name == "dot_general"
+        and 256 not in e.outvars[0].aval.shape[-1:]  # not a score tile
+    }
+    assert {o[1] for o in outs if o[0] != 64} == {128}, outs
+    assert {o[0] for o in outs if o[1] != 128} == {64}, outs
+    assert not [e for e in body if e.primitive.name == "transpose"]
+
+
+def test_latent_kv_counter_counts_three_a_block():
+    from horovod_tpu.obs import registry
+
+    counter = registry.always().counter("flash.calls.latent_kv")
+    split = registry.always().counter("flash.calls.split_widths")
+    x = lambda w: jax.ShapeDtypeStruct((1, 256, w), jnp.bfloat16)  # noqa: E731
+
+    def latent(q, kv, k_rope):
+        out, _ = flash_attention_latent(q, kv, k_rope, n_heads=4, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    def three(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, layout="bsm", n_heads=4
+        ).astype(jnp.float32).sum()
+
+    before, split_before = counter.get(), split.get()
+    jax.eval_shape(jax.grad(three, argnums=(0, 1, 2)),
+                   x(4 * 192), x(4 * 192), x(4 * 128))
+    assert counter.get() == before
+    jax.eval_shape(latent, x(4 * 192), x(4 * 256), x(64))
+    assert counter.get() == before + 1  # the forward
+    jax.eval_shape(jax.grad(latent, argnums=(0, 1, 2)),
+                   x(4 * 192), x(4 * 256), x(64))
+    assert counter.get() == before + 4  # + forward, dK/dV, dQ
+    assert split.get() == split_before + 3  # forwards with dv != d
+
+
+def test_flash_latent_refuses_shapes_that_are_not_its_layout():
+    x = lambda w: jnp.zeros((1, 16, w))  # noqa: E731
+    with pytest.raises(ValueError, match="H=2"):
+        flash_attention_latent(x(48), x(64), jnp.zeros((1, 8, 8)), n_heads=2)
+    with pytest.raises(ValueError, match="multiples of 64"):
+        flash_attention_latent(
+            x(48), x(64), x(8), n_heads=2, interpret=False
         )

@@ -113,6 +113,100 @@ def test_flash_attention_split_widths_compile(v5e, batch, seq, heads, qk, v):
     assert hlo.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize(
+    "batch,seq,heads", [(2, 4096, 32), (4, 1000, 8)],
+    ids=["latent-2x4096x32", "latent-s1000x8-padded"],
+)
+def test_flash_attention_latent_compiles(v5e, batch, seq, heads):
+    """The packed ``kv`` and the shared rotary key through the three
+    kernels at 128 + 64 / 128: the expert cell's shape (groups of 2 heads,
+    a ``[1, 1024, 512]`` block of ``kv`` beside a ``[1, 1024, 64]`` block
+    of the key, dK/dV's float32 partial of the key's gradient) and a
+    padded length."""
+
+    def loss(q, kv, k_rope):
+        out, lse = pk.flash_attention_latent(
+            q, kv, k_rope, causal=True, n_heads=heads, interpret=False
+        )
+        return out.astype(jnp.float32).sum() + (lse ** 2).sum()
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), v5e,
+        ((batch, seq, 192 * heads), jnp.bfloat16),
+        ((batch, seq, 256 * heads), jnp.bfloat16),
+        ((batch, seq, 64), jnp.bfloat16),
+    )
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):
+    """One ``LatentAttention``'s ``value_and_grad`` at the expert cell's
+    shape, compiled: what the program did to K and V between ``kv_b`` and
+    the kernels is gone by ``op_name`` (the rotary key's broadcast to the
+    heads, the keys' concatenation, the split of dK and the sum that
+    rebuilt ``[dk_nope | dv]``), and ``kv_b``'s matmul output is the
+    forward kernel's operand, no ``copy`` between."""
+    import re
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models.latent_moe import LatentAttention, LatentMoEConfig
+
+    attn = LatentAttention(LatentMoEConfig())
+    x = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)
+
+    def loss(params, x):
+        return attn.apply(params, x).astype(jnp.float32).sum()
+
+    hvd.init(devices=v5e_topology.devices[:1])  # the world's devices: TPUs
+    try:
+        params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            (params, x),
+        )
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            *args
+        ).compile().as_text()
+    finally:
+        hvd.shutdown()
+    assert hlo.count("tpu_custom_call") == 3
+    entry = hlo[hlo.index("\nENTRY"):]
+    defined = {
+        m.group(1): line for line in entry.splitlines()
+        if (m := re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line))
+    }
+    def results(label):
+        """Result shapes of the entry's instructions labelled ``label``."""
+        return [
+            line.split(" = ", 1)[1].split(" ", 1)[0]
+            for line in defined.values() if f'mla_proj/{label}"' in line
+        ]
+
+    assert results("kv_b/dot_general")
+    # the rotary key is no longer broadcast to 32 heads
+    assert not results("broadcast_in_dim")
+    # of the two [.., 32, 192] concatenations q's is left, and its sum
+    # in the backward; dK is not cut into dk_nope and the rotary part, and
+    # nothing puts [dk_nope | dv] together for kv_b's backward
+    wide = lambda shapes, dims: [s for s in shapes if f"[2,4096,{dims}]" in s]  # noqa: E731
+    assert len(wide(results("concatenate"), "32,192")) == 1
+    assert len(wide(results("add_any"), "32,192")) == 1
+    assert not wide(results("split"), "32,128")
+    assert not wide(results("add_any"), "32,256")
+    assert not wide(results("add_any"), "8192")
+    (fwd,) = [
+        line for line in defined.values()
+        if "custom-call(" in line and "hvd_flash_fwd" in line.split(" = ")[0]
+    ]
+    operands = re.findall(r"%([\w.\-]+)", fwd.split("custom-call(", 1)[1].split(")", 1)[0])
+    (kv,) = [
+        name for name in operands
+        if re.search(r"bf16\[2,4096,8192\]", defined[name].split(" = ", 1)[1])
+    ]
+    assert " copy(" not in defined[kv], defined[kv]
+    assert "kv_b/dot_general" in defined[kv], defined[kv]
+
+
 def test_local_expert_layer_compiles_at_the_cell_buffer_shapes(v5e):
     """The expert layer of the latent-attention cell: 8,192 tokens, top-8
     of 256, 16 experts held at width 2048 x 768, bf16 rows: the three

@@ -1,19 +1,25 @@
 """Kernels only, on the chip: the three flash kernels by name.
 
     python tools/bench_attention.py [--tree CHECKOUT] [--iters 8]
-        [--heads 12] [--head-dim 64] [--shape NAME]
+        [--heads 12] [--head-dim 64] [--v-head-dim 64] [--shape NAME]
         [--block-q N --block-k N]
 
 Runs forward + backward of ``flash_attention`` alone (one layer's worth) at
 the shapes of the benchmark's flash cells — GPT-2-small (16 x 1024, causal)
 and BERT-base MLM (32 x 512, non-causal), packed ``bsm`` layout, ``--heads`` heads
 of ``--head-dim`` (12 of 64 as the models have them; 6 of 128 are the same
-768 columns), bf16, the kernels' own block sizes as the models leave them —
-under ``jax.profiler.trace`` and prints one JSON line per shape: the
+768 columns; ``--v-head-dim`` for v / out heads of another width), bf16, the
+kernels' own block sizes as the models leave them — under
+``jax.profiler.trace`` and prints one JSON line per shape and entry: the
 median device microseconds of ``hvd_flash_fwd`` / ``hvd_flash_bwd_dkv`` /
 ``hvd_flash_bwd_dq`` per call, read from the device plane's ``XLA Ops``
 line, and the largest absolute error of the three gradients against
-float32 ``jax.numpy`` attention at batch 2. ``--tree`` imports
+float32 ``jax.numpy`` attention at batch 2 and at most 1024 positions. The
+``latent`` shape is the expert cell's (2 x 4096, causal, 32 heads of 128 + 64
+/ 128) and runs through both entries: ``qkv`` (``flash_attention`` on K built
+with the rotary key broadcast to every head) and ``latent``
+(``flash_attention_latent`` on the packed ``kv`` and the one rotary key;
+skipped where ``--tree`` has none). ``--tree`` imports
 ``horovod_tpu`` from another checkout (a parent commit unpacked beside
 this one), so two commits can be timed in one chip call. This is where a
 kernel change is judged before a cell is run; ``benchmark/split.py`` gives
@@ -32,15 +38,31 @@ import numpy as np
 from jax.profiler import ProfileData
 
 KERNELS = ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd")
-# name: (batch, sequence, causal)
-SHAPES = {"gpt2-16x1024-causal": (16, 1024, True),
-          "bert-32x512": (32, 512, False)}
+# name: (batch, sequence, causal, heads, q / k head, v / out head, of the
+# q / k head the rotary columns every head of a key shares)
+SHAPES = {"gpt2-16x1024-causal": (16, 1024, True, 12, 64, 64, 0),
+          "bert-32x512": (32, 512, False, 12, 64, 64, 0),
+          "latent": (2, 4096, True, 32, 192, 128, 64)}
 
 
-def reference(q, k, v, w, causal, head_dim):
+def built_keys(kv, k_rope, heads, n):
+    """``(k, v)`` in the packed layout out of latent attention's operands:
+    the shared rotary key broadcast to every head and set beside each
+    head's own ``n`` columns, as the model did before the kernels read
+    ``kv``."""
+    b, s, r = k_rope.shape
+    kv = kv.reshape(b, s, heads, -1)
+    k = jnp.concatenate(
+        [kv[..., :n], jnp.broadcast_to(k_rope[:, :, None], (b, s, heads, r))],
+        axis=-1,
+    )
+    return k.reshape(b, s, -1), kv[..., n:].reshape(b, s, -1)
+
+
+def reference(q, k, v, w, causal, heads):
     """Loss of float32 attention written out in ``jax.numpy``."""
-    b, s, width = q.shape
-    split = lambda x: x.astype(jnp.float32).reshape(b, s, -1, head_dim)  # noqa: E731
+    b, s, width = v.shape
+    split = lambda x: x.astype(jnp.float32).reshape(b, s, heads, -1)  # noqa: E731
     q, k, v = split(q), split(k), split(v)
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, precision="highest"
@@ -87,15 +109,16 @@ def main():
     ap.add_argument("--tree", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--iters", type=int, default=8)
-    ap.add_argument("--heads", type=int, default=12)
-    ap.add_argument("--head-dim", type=int, default=64)
+    ap.add_argument("--heads", type=int, help="default: the shape's own")
+    ap.add_argument("--head-dim", type=int, help="q / k head width")
+    ap.add_argument("--v-head-dim", type=int, help="v / out head width")
     ap.add_argument("--shape", choices=sorted(SHAPES), action="append",
                     help="only this shape (may repeat); default: all")
     ap.add_argument("--block-q", type=int)
     ap.add_argument("--block-k", type=int)
     args = ap.parse_args()
     sys.path.insert(0, args.tree)
-    from horovod_tpu.ops.pallas_kernels import flash_attention
+    from horovod_tpu.ops import pallas_kernels
     from horovod_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
@@ -106,44 +129,63 @@ def main():
             f"({device.platform}) and the interpreter's time means nothing"
         )
     for shape in args.shape or SHAPES:
-        b, s, causal = SHAPES[shape]
+        b, s, causal, heads, d, dv, rope = SHAPES[shape]
+        heads, d = args.heads or heads, args.head_dim or d
+        dv = args.v_head_dim or dv
         blocks = {}
         if args.block_q:
             blocks["block_q"] = args.block_q
         if args.block_k:
             blocks["block_k"] = args.block_k
 
-        def loss(q, k, v, w, causal=causal, blocks=blocks):
-            out = flash_attention(
-                q, k, v, causal=causal, layout="bsm", n_heads=args.heads,
-                **blocks
+        def qkv(q, k, v):
+            return pallas_kernels.flash_attention(
+                q, k, v, causal=causal, layout="bsm", n_heads=heads, **blocks
             )
-            return (out.astype(jnp.float32) * w).sum()
 
-        keys = jax.random.split(jax.random.PRNGKey(0), 4)
-        argv = [
-            jax.random.normal(
-                key, (b, s, args.heads * args.head_dim), jnp.float32
+        def latent(q, kv, k_rope):
+            return pallas_kernels.flash_attention_latent(
+                q, kv, k_rope, causal=causal, n_heads=heads, **blocks
+            )[0]
+
+        def exact(q, k, v, w):
+            return reference(q, k, v, w, causal, heads)
+
+        # entry: (the kernels' call, operand widths, float32 loss)
+        entries = {"qkv": (qkv, (heads * d, heads * d, heads * dv), exact)}
+        if rope and hasattr(pallas_kernels, "flash_attention_latent"):
+            entries["latent"] = (
+                latent, (heads * d, heads * (d - rope + dv), rope),
+                lambda q, kv, k_rope, w: exact(
+                    q, *built_keys(kv, k_rope, heads, d - rope), w
+                ),
             )
-            for key in keys
-        ]
-        argv = [x.astype(jnp.bfloat16) for x in argv[:3]] + argv[3:]
-        flash = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        us = kernel_us(flash, argv, args.iters)
-        small = [x[:2] for x in argv]
-        exact = jax.jit(
-            jax.grad(reference, argnums=(0, 1, 2)), static_argnums=(4, 5)
-        )(*small, causal, args.head_dim)
-        errors = [
-            float(jnp.max(jnp.abs(a.astype(jnp.float32) - e)))
-            for a, e in zip(flash(*small), exact)
-        ]
-        print(json.dumps(dict(
-            tree=args.tree, shape=shape, heads=args.heads,
-            head_dim=args.head_dim, blocks=blocks,
-            device_kind=device.device_kind, us_per_call=us,
-            total_us=sum(us.values()), grad_abs_err_vs_f32=errors,
-        )), flush=True)
+        for entry, (call, widths, exact_loss) in entries.items():
+            keys = jax.random.split(jax.random.PRNGKey(0), 4)
+            argv = [
+                jax.random.normal(key, (b, s, width), jnp.float32)
+                for key, width in zip(keys, widths + (heads * dv,))
+            ]
+            argv = [x.astype(jnp.bfloat16) for x in argv[:3]] + argv[3:]
+
+            def loss(*operands, call=call):
+                out = call(*operands[:3])
+                return (out.astype(jnp.float32) * operands[3]).sum()
+
+            flash = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            us = kernel_us(flash, argv, args.iters)
+            small = [x[:2, :1024] for x in argv]
+            want = jax.jit(jax.grad(exact_loss, argnums=(0, 1, 2)))(*small)
+            errors = [
+                float(jnp.max(jnp.abs(a.astype(jnp.float32) - e)))
+                for a, e in zip(flash(*small), want)
+            ]
+            print(json.dumps(dict(
+                tree=args.tree, shape=shape, entry=entry, heads=heads,
+                head_dim=d, v_head_dim=dv, blocks=blocks,
+                device_kind=device.device_kind, us_per_call=us,
+                total_us=sum(us.values()), grad_abs_err_vs_f32=errors,
+            )), flush=True)
 
 
 if __name__ == "__main__":
