@@ -1,0 +1,15 @@
+"""Milliseconds per step in the operations under the program's
+``jax.named_scope("head")``: the logits matmul(s) and what a model puts
+before them (BERT's ``mlm_dense`` + GELU, ``pooler``, ``classifier``); the
+user's loss on the logits is not under it (``grad_unnamed_ms``). Device
+trace, worst device, forward, backward and what rematerialisation runs
+again; a fusion counts under the one scope its label names
+(``lib/by_name.py``; ``lib/parts.py`` has the whole cut of ``xla_ops_ms``).
+No Mosaic kernel lies under it. Nothing to read in a program without the
+scope."""
+
+from benchmark.lib.by_name import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "head")

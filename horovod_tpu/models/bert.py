@@ -8,6 +8,7 @@ import dataclasses
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from .transformer import Transformer, TransformerConfig
@@ -55,25 +56,36 @@ class BertModel(nn.Module):
         mask = None
         if attention_mask is not None:
             # [B, S] -> [B, 1, 1, S] broadcast over heads & query positions.
-            mask = attention_mask[:, None, None, :].astype(bool)
+            with jax.named_scope("attn_xla"):
+                mask = attention_mask[:, None, None, :].astype(bool)
         h = Transformer(cfg, name="encoder")(
             tokens, token_types=token_types, mask=mask
         )
         if self.num_labels is not None:
-            pooled = nn.tanh(nn.Dense(cfg.d_model, dtype=cfg.dtype, name="pooler")(
-                h[:, 0]
-            ))
-            return nn.Dense(self.num_labels, dtype=jnp.float32, name="classifier")(
-                pooled
-            )
+            with jax.named_scope("head"):
+                pooled = nn.tanh(
+                    nn.Dense(cfg.d_model, dtype=cfg.dtype, name="pooler")(
+                        h[:, 0]
+                    )
+                )
+                return nn.Dense(
+                    self.num_labels, dtype=jnp.float32, name="classifier"
+                )(pooled)
         # MLM head: transform + tied decoder would need wte; use a dense
         # decoder (capability parity, not checkpoint compatibility).
-        x = nn.gelu(nn.Dense(cfg.d_model, dtype=cfg.dtype, name="mlm_dense")(h))
-        x = nn.LayerNorm(dtype=cfg.dtype, name="mlm_ln")(x)
+        with jax.named_scope("head"):
+            x = nn.gelu(
+                nn.Dense(cfg.d_model, dtype=cfg.dtype, name="mlm_dense")(h)
+            )
+        with jax.named_scope("norm"):
+            x = nn.LayerNorm(dtype=cfg.dtype, name="mlm_ln")(x)
         if return_hidden:
             return x
         # fp32 logits: measured r4 that bf16 logits do not change the step
         # time (the vocab matmuls are compute-bound, and XLA fuses the
         # softmax recompute into the dW matmul rather than re-reading a
         # dlogits buffer), so the numerically safer dtype stays.
-        return nn.Dense(cfg.vocab_size, dtype=jnp.float32, name="mlm_decoder")(x)
+        with jax.named_scope("head"):
+            return nn.Dense(
+                cfg.vocab_size, dtype=jnp.float32, name="mlm_decoder"
+            )(x)

@@ -59,9 +59,18 @@ to halves first; the scores are the same), ``b`` is not updated, weights
 are drawn N(0, ``init_std``), parameters are fp32 and computed in bf16 with
 the router, softmax, norms and logits in fp32.
 
-Scopes (``jax.named_scope``): ``mla_proj`` (the projections and rotary),
-``moe_route``, ``moe_experts`` (``parallel/ep.py``),
-``mtp`` (the whole module).
+Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
+operation of ``apply`` lies under exactly one of: ``embed`` (the token
+lookups and, in the multi-token module, the concatenation and ``W_eh``),
+``norm`` (the blocks' RMSNorms and residual sums, the expert layer's sum of
+routed and shared output, the norms before the heads), ``mla_proj`` (the
+six projections, their two norms, rotary, the concatenation that builds q),
+``attn_layout`` (the reshapes between the projections and the kernels, and
+the kernels' entry's own glue), ``attn_xla`` (attention where flash is
+bypassed), ``mlp`` (``GatedMlp``: the dense FFN and the shared experts),
+``moe_route``, ``moe_experts`` (``parallel/ep.py``), ``head`` (the logits
+matmuls). The Mosaic kernels carry none of them. ``mtp`` lies over the
+whole multi-token module, and so over a part of each of the others.
 """
 
 from __future__ import annotations
@@ -179,9 +188,10 @@ class LatentAttention(nn.Module):
         if use_flash:
             from ..ops.pallas_kernels import flash_attention_latent
 
+            with jax.named_scope("attn_layout"):
+                q = q.reshape(b, s, h * (n + r))
             out, _ = flash_attention_latent(
-                q.reshape(b, s, h * (n + r)), kv, k_rope, causal=True,
-                n_heads=h,
+                q, kv, k_rope, causal=True, n_heads=h
             )
         else:
             with jax.named_scope("mla_proj"):
@@ -190,9 +200,10 @@ class LatentAttention(nn.Module):
                     kv[..., :n],
                     jnp.broadcast_to(k_rope[:, :, None], (b, s, h, r)),
                 ], axis=-1)
-            out = dot_product_attention(
-                q, k, kv[..., n:], causal=True
-            ).reshape(b, s, h * v)
+            with jax.named_scope("attn_xla"):
+                out = dot_product_attention(q, k, kv[..., n:], causal=True)
+            with jax.named_scope("attn_layout"):
+                out = out.reshape(b, s, h * v)
         with jax.named_scope("mla_proj"):
             return dense(cfg.d_model, "o")(out)
 
@@ -212,14 +223,16 @@ class RoutedExperts(nn.Module):
             "router", _init(cfg), (d, cfg.n_experts), jnp.float32
         )
         # e_score_correction_bias: a constant buffer, not a parameter
-        score_bias = jnp.zeros((cfg.n_experts,), jnp.float32)
+        with jax.named_scope("moe_route"):
+            score_bias = jnp.zeros((cfg.n_experts,), jnp.float32)
         stacked = lambda name, shape: self.param(  # noqa: E731
             name, _init(cfg), shape, jnp.float32
         )
         gate = stacked("experts_gate", (held, d, f))
         up = stacked("experts_up", (held, d, f))
         down = stacked("experts_down", (held, f, d))
-        tokens = x.reshape(b * s, d)
+        with jax.named_scope("norm"):  # free: the token-major view
+            tokens = x.reshape(b * s, d)
         with jax.named_scope("moe_route"):
             chosen, weights = ep.topk_route(
                 tokens, router, score_bias, top_k=cfg.top_k,
@@ -228,12 +241,16 @@ class RoutedExperts(nn.Module):
         out = ep.local_experts(
             tokens, chosen, weights, gate, up, down,
             first_expert=cfg.first_expert, n_experts=cfg.n_experts,
-        ).reshape(b, s, d)
+        )
+        with jax.named_scope("norm"):
+            out = out.reshape(b, s, d)
         if cfg.n_shared_experts:
-            out = out + GatedMlp(
+            shared = GatedMlp(
                 cfg.n_shared_experts * f, cfg.dtype, _init(cfg),
                 name="shared",
             )(x)
+            with jax.named_scope("norm"):  # a sum of branches, as a residual
+                out = out + shared
         return out
 
 
@@ -245,13 +262,18 @@ class LatentMoEBlock(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.eps, cfg.dtype, name=name)  # noqa: E731
-        x = x + LatentAttention(cfg, name="attn")(norm("attn_norm")(x))
-        h = norm("ffn_norm")(x)
+        with jax.named_scope("norm"):
+            h = norm("attn_norm")(x)
+        h = LatentAttention(cfg, name="attn")(h)
+        with jax.named_scope("norm"):
+            x = x + h
+            h = norm("ffn_norm")(x)
         if self.dense_ffn:
-            return x + GatedMlp(
-                cfg.d_ff_dense, cfg.dtype, _init(cfg), name="ffn"
-            )(h)
-        return x + RoutedExperts(cfg, name="ffn")(h)
+            h = GatedMlp(cfg.d_ff_dense, cfg.dtype, _init(cfg), name="ffn")(h)
+        else:
+            h = RoutedExperts(cfg, name="ffn")(h)
+        with jax.named_scope("norm"):
+            return x + h
 
 
 class LatentMoELM(nn.Module):
@@ -274,13 +296,16 @@ class LatentMoELM(nn.Module):
         )
 
         def logits_of(hidden, name):
-            hidden = RMSNorm(cfg.eps, cfg.dtype, name=name)(hidden)
-            return jnp.dot(
-                hidden, head.astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            )
+            with jax.named_scope("norm"):
+                hidden = RMSNorm(cfg.eps, cfg.dtype, name=name)(hidden)
+            with jax.named_scope("head"):
+                return jnp.dot(
+                    hidden, head.astype(cfg.dtype),
+                    preferred_element_type=jnp.float32,
+                )
 
-        x = embed(tokens[:, :s])
+        with jax.named_scope("embed"):
+            x = embed(tokens[:, :s])
         for i in range(cfg.n_layers):
             x = LatentMoEBlock(
                 cfg, dense_ffn=i < cfg.n_dense_layers, name=f"block_{i}"
@@ -289,15 +314,20 @@ class LatentMoELM(nn.Module):
         if not cfg.n_mtp:
             return logits, None
         with jax.named_scope("mtp"):
-            merged = nn.Dense(
-                cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                name="mtp_proj", kernel_init=_init(cfg),
-            )(jnp.concatenate([
-                RMSNorm(cfg.eps, cfg.dtype, name="mtp_hidden_norm")(x),
-                RMSNorm(cfg.eps, cfg.dtype, name="mtp_embed_norm")(
-                    embed(tokens[:, 1:s + 1])
-                ),
-            ], axis=-1))
+            mtp_norm = lambda name: RMSNorm(  # noqa: E731
+                cfg.eps, cfg.dtype, name=f"mtp_{name}_norm"
+            )
+            with jax.named_scope("norm"):
+                hidden = mtp_norm("hidden")(x)
+            with jax.named_scope("embed"):
+                shifted = embed(tokens[:, 1:s + 1])
+            with jax.named_scope("norm"):
+                shifted = mtp_norm("embed")(shifted)
+            with jax.named_scope("embed"):  # W_eh: the module's input
+                merged = nn.Dense(
+                    cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                    name="mtp_proj", kernel_init=_init(cfg),
+                )(jnp.concatenate([hidden, shifted], axis=-1))
             merged = LatentMoEBlock(cfg, name="mtp_block")(merged)
             return logits, logits_of(merged, "mtp_final_norm")
 

@@ -86,7 +86,12 @@ class MultiHeadAttention(nn.Module):
             (cfg.n_heads, head_dim), dtype=cfg.dtype, name=name,
             dot_general_cls=dg_cls,
         )
-        q, k, v = dense("query")(x), dense("key")(x), dense("value")(x)
+        out = lambda axis: nn.DenseGeneral(  # noqa: E731
+            cfg.d_model, axis=axis, dtype=cfg.dtype, name="out",
+            dot_general_cls=dg_cls,
+        )
+        with jax.named_scope("attn_proj"):
+            q, k, v = dense("query")(x), dense("key")(x), dense("value")(x)
         attn = self.attention_fn
         if attn is None:
             use_flash = cfg.use_flash
@@ -101,44 +106,43 @@ class MultiHeadAttention(nn.Module):
                 # No relayout exists anywhere on this path: the r4
                 # head-major variant moveaxis'd to [B,H,S,D], and XLA
                 # folded that transpose into the projection dots, which
-                # then ran at ~43% of MXU peak
-                # (docs/perf_analysis_bert_r04.md). Mosaic lane slicing
-                # needs 64-aligned offsets, so head_dim % 64 != 0 keeps
-                # the head-major path below.
+                # then ran at ~43% of MXU peak (measured before PR 1).
+                # Mosaic lane slicing needs 64-aligned offsets, so
+                # head_dim % 64 != 0 keeps the head-major path below.
                 b, s = q.shape[0], q.shape[1]
+                with jax.named_scope("attn_layout"):
+                    q, k, v = (
+                        t.reshape(b, s, cfg.d_model) for t in (q, k, v)
+                    )
                 y = flash_attention(
-                    q.reshape(b, s, cfg.d_model),
-                    k.reshape(b, s, cfg.d_model),
-                    v.reshape(b, s, cfg.d_model),
-                    causal=cfg.causal,
-                    layout="bsm",
+                    q, k, v, causal=cfg.causal, layout="bsm",
                     n_heads=cfg.n_heads,
                 )
-                return nn.DenseGeneral(
-                    cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, name="out",
-                    dot_general_cls=dg_cls,
-                )(y.reshape(b, s, cfg.n_heads, head_dim))
+                with jax.named_scope("attn_layout"):
+                    y = y.reshape(b, s, cfg.n_heads, head_dim)
+                with jax.named_scope("attn_proj"):
+                    return out((-2, -1))(y)
             if use_flash and mask is None:
                 from ..ops.pallas_kernels import flash_attention
 
                 # Head-major fallback for lane-unaligned head dims.
+                with jax.named_scope("attn_layout"):
+                    q, k, v = (jnp.moveaxis(t, 1, 2) for t in (q, k, v))
                 y = flash_attention(
-                    jnp.moveaxis(q, 1, 2),
-                    jnp.moveaxis(k, 1, 2),
-                    jnp.moveaxis(v, 1, 2),
-                    causal=cfg.causal,
-                    layout="bhsd",
+                    q, k, v, causal=cfg.causal, layout="bhsd"
                 )
-                return nn.DenseGeneral(
-                    cfg.d_model, axis=(1, 3), dtype=cfg.dtype, name="out",
-                    dot_general_cls=dg_cls,
-                )(y)
-            attn = dot_product_attention
-        y = attn(q, k, v, causal=cfg.causal, mask=mask)
-        return nn.DenseGeneral(
-            cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, name="out",
-            dot_general_cls=dg_cls,
-        )(y)
+                with jax.named_scope("attn_proj"):
+                    return out((1, 3))(y)
+            with jax.named_scope("attn_xla"):
+                y = dot_product_attention(
+                    q, k, v, causal=cfg.causal, mask=mask
+                )
+        else:
+            # a caller's attention (ring attention over the sequence axis)
+            # names itself: it may hold kernels, which carry no part
+            y = attn(q, k, v, causal=cfg.causal, mask=mask)
+        with jax.named_scope("attn_proj"):
+            return out((-2, -1))(y)
 
 
 class MlpBlock(nn.Module):
@@ -148,11 +152,12 @@ class MlpBlock(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         dg_cls = fp8_dot_general_cls(cfg.compute_dtype)
-        h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, dot_general_cls=dg_cls)(x)
-        h = nn.gelu(h)
-        return nn.Dense(
-            cfg.d_model, dtype=cfg.dtype, dot_general_cls=dg_cls
-        )(h)
+        with jax.named_scope("mlp"):
+            h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, dot_general_cls=dg_cls)(x)
+            h = nn.gelu(h)
+            return nn.Dense(
+                cfg.d_model, dtype=cfg.dtype, dot_general_cls=dg_cls
+            )(h)
 
 
 class RMSNorm(nn.Module):
@@ -191,8 +196,11 @@ class GatedMlp(nn.Module):
             n, use_bias=False, dtype=self.dtype, name=name,
             kernel_init=self.kernel_init,
         )
-        h = nn.silu(dense(self.d_ff, "gate")(x)) * dense(self.d_ff, "up")(x)
-        return dense(x.shape[-1], "down")(h)
+        with jax.named_scope("mlp"):
+            h = nn.silu(dense(self.d_ff, "gate")(x)) * dense(
+                self.d_ff, "up"
+            )(x)
+            return dense(x.shape[-1], "down")(h)
 
 
 class Block(nn.Module):
@@ -206,10 +214,15 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None):
         cfg = self.cfg
-        h = nn.LayerNorm(dtype=cfg.dtype)(x)
-        x = x + MultiHeadAttention(cfg, attention_fn=self.attention_fn)(h, mask)
-        h = nn.LayerNorm(dtype=cfg.dtype)(x)
-        return x + MlpBlock(cfg)(h)
+        with jax.named_scope("norm"):
+            h = nn.LayerNorm(dtype=cfg.dtype)(x)
+        h = MultiHeadAttention(cfg, attention_fn=self.attention_fn)(h, mask)
+        with jax.named_scope("norm"):
+            x = x + h
+            h = nn.LayerNorm(dtype=cfg.dtype)(x)
+        h = MlpBlock(cfg)(h)
+        with jax.named_scope("norm"):
+            return x + h
 
 
 class Transformer(nn.Module):
@@ -229,13 +242,17 @@ class Transformer(nn.Module):
         same params either way, the head is the wte table)."""
         cfg = self.cfg
         emb = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="wte")
-        x = emb(tokens)
-        pos = jnp.arange(tokens.shape[-1])
-        x = x + nn.Embed(cfg.max_len, cfg.d_model, dtype=cfg.dtype, name="wpe")(pos)
-        if cfg.type_vocab_size and token_types is not None:
+        with jax.named_scope("embed"):
+            x = emb(tokens)
+            pos = jnp.arange(tokens.shape[-1])
             x = x + nn.Embed(
-                cfg.type_vocab_size, cfg.d_model, dtype=cfg.dtype, name="wtt"
-            )(token_types)
+                cfg.max_len, cfg.d_model, dtype=cfg.dtype, name="wpe"
+            )(pos)
+            if cfg.type_vocab_size and token_types is not None:
+                x = x + nn.Embed(
+                    cfg.type_vocab_size, cfg.d_model, dtype=cfg.dtype,
+                    name="wtt",
+                )(token_types)
         block = remat_module(Block, cfg.remat)
         for i in range(cfg.n_layers):
             x = block(cfg, attention_fn=self.attention_fn, name=f"block_{i}")(
@@ -244,7 +261,9 @@ class Transformer(nn.Module):
             # int8 activation-storage boundary (identity unless an
             # act-quant trace is active — see ops/actquant.boundary).
             x = _actquant.boundary(x)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+        with jax.named_scope("norm"):
+            x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         if self.lm_head and not return_hidden:
-            return emb.attend(x).astype(jnp.float32)
+            with jax.named_scope("head"):
+                return emb.attend(x).astype(jnp.float32)
         return x

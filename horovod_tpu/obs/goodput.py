@@ -203,14 +203,12 @@ class GoodputLedger:
             if len(self._pending) > self._window:
                 self._settle_oldest_locked()
 
-    def record_step(
-        self, w0: float, total_s: float, dispatch_s: float, device_s: float
-    ) -> None:
+    def record_step(self, w0: float, total_s: float, dispatch_s: float) -> None:
         """One training-step bracket: ``[w0, w0+dispatch_s]`` is
-        host_dispatch, the rest compute (``device_s``, as the step
-        wrapper books it: ``total_s - dispatch_s``). A bracket that
-        stretches stays compute: the host clock cannot say whether the
-        device waited on a collective, a straggler or its own work."""
+        host_dispatch, the rest (``total_s - dispatch_s``) compute. A
+        bracket that stretches stays compute: the host clock cannot say
+        whether the device waited on a collective, a straggler or its
+        own work."""
         if total_s <= 0:
             return
         self.add("host_dispatch", w0, dispatch_s)
@@ -420,12 +418,10 @@ def _fed() -> None:
         publish()
 
 
-def record_step(
-    w0: float, total_s: float, dispatch_s: float, device_s: float
-) -> None:
+def record_step(w0: float, total_s: float, dispatch_s: float) -> None:
     if not enabled():
         return
-    ledger().record_step(w0, total_s, dispatch_s, device_s)
+    ledger().record_step(w0, total_s, dispatch_s)
     _fed()
 
 

@@ -73,6 +73,11 @@ __all__ = [
 
 _NEG_INF = float(np.finfo(np.float32).min)
 _LANES = 128
+# ``jax.named_scope`` of the flash entries' own XLA operations around the
+# three kernels (padding, the row statistics' layout, ``rowsum(g * out)``,
+# the slices back): the models' part ``attn_layout`` (docs/api.md). The
+# ``pallas_call``s themselves stay outside it, under their ``name=`` only.
+_GLUE_SCOPE = "attn_layout"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -620,10 +625,11 @@ def _geometry(q_offset, kv_offset, skv: int):
     ``q_offset``, ``kv_offset``, ``kv_len`` (XLA hands a ``[1, 1]``
     constant to the kernel as it is; a longer vector costs a copy per
     call)."""
-    return [
-        jnp.asarray(x, jnp.int32).reshape(1, 1)
-        for x in (q_offset, kv_offset, skv)
-    ]
+    with jax.named_scope(_GLUE_SCOPE):
+        return [
+            jnp.asarray(x, jnp.int32).reshape(1, 1)
+            for x in (q_offset, kv_offset, skv)
+        ]
 
 
 def _last_kv_block(qi, geom, p: _Plan):
@@ -728,9 +734,10 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
     )
-    qr = p.pad_seq(q, p.sq, sq_pad)
-    kr = p.pad_seq(k, p.skv, skv_pad)
-    vr = p.pad_seq(v, p.skv, skv_pad)
+    with jax.named_scope(_GLUE_SCOPE):
+        qr = p.pad_seq(q, p.sq, sq_pad)
+        kr = p.pad_seq(k, p.skv, skv_pad)
+        vr = p.pad_seq(v, p.skv, skv_pad)
 
     def kv_block(qi, kj, geom):
         if not causal:
@@ -810,11 +817,12 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         name="hvd_flash_fwd",
     )(*geom, qr, kr, vr)
 
-    if p.packed:
-        out = out[:, :p.sq]  # [B,Sq,H*D]
-    else:
-        out = out[:, :, :p.sq]  # [B,H,Sq,D]
-    lse = lse[:, :, 0, :p.sq]  # [B,H,Sq]
+    with jax.named_scope(_GLUE_SCOPE):
+        if p.packed:
+            out = out[:, :p.sq]  # [B,Sq,H*D]
+        else:
+            out = out[:, :, :p.sq]  # [B,H,Sq,D]
+        lse = lse[:, :, 0, :p.sq]  # [B,H,Sq]
     return out, lse
 
 
@@ -1095,37 +1103,38 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
     )
-    qr = p.pad_seq(q, sq, sq_pad)
-    kr = p.pad_seq(k, skv, skv_pad)
-    vr = p.pad_seq(v, skv, skv_pad)
-    gr = p.pad_seq(g_out.astype(q.dtype), sq, sq_pad)
+    with jax.named_scope(_GLUE_SCOPE):
+        qr = p.pad_seq(q, sq, sq_pad)
+        kr = p.pad_seq(k, skv, skv_pad)
+        vr = p.pad_seq(v, skv, skv_pad)
+        gr = p.pad_seq(g_out.astype(q.dtype), sq, sq_pad)
 
-    # Row statistics in the kernel's [b, h, 8, sq_pad] layout (8 = min
-    # sublane tile; kernels read sublane 0).
-    def rows(x, pad_value):
-        x = x.reshape(b, h, sq)
-        if sq_pad != sq:
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, sq_pad - sq)),
-                        constant_values=pad_value)
-        return jnp.broadcast_to(x[:, :, None, :], (b, h, 8, sq_pad))
+        # Row statistics in the kernel's [b, h, 8, sq_pad] layout (8 = min
+        # sublane tile; kernels read sublane 0).
+        def rows(x, pad_value):
+            x = x.reshape(b, h, sq)
+            if sq_pad != sq:
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, sq_pad - sq)),
+                            constant_values=pad_value)
+            return jnp.broadcast_to(x[:, :, None, :], (b, h, 8, sq_pad))
 
-    if p.packed:
-        # [B,S,H*D] → per-head row dot via a free reshape (no transpose).
-        delta = jnp.einsum(
-            "bqhd,bqhd->bhq",
-            g_out.astype(jnp.float32).reshape(b, sq, h, dv),
-            out.astype(jnp.float32).reshape(b, sq, h, dv),
-        )
-    else:
-        delta = jnp.einsum(
-            "bhqd,bhqd->bhq",
-            g_out.astype(jnp.float32),
-            out.astype(jnp.float32),
-        )
-    lse_rows = rows(lse, -jnp.inf)  # padded rows masked via row_ok
-    delta_rows = rows(delta, 0.0)
-    glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
-    glse_rows = rows(glse.astype(jnp.float32), 0.0)
+        if p.packed:
+            # [B,S,H*D] → per-head row dot via a free reshape (no transpose).
+            delta = jnp.einsum(
+                "bqhd,bqhd->bhq",
+                g_out.astype(jnp.float32).reshape(b, sq, h, dv),
+                out.astype(jnp.float32).reshape(b, sq, h, dv),
+            )
+        else:
+            delta = jnp.einsum(
+                "bhqd,bhqd->bhq",
+                g_out.astype(jnp.float32),
+                out.astype(jnp.float32),
+            )
+        lse_rows = rows(lse, -jnp.inf)  # padded rows masked via row_ok
+        delta_rows = rows(delta, 0.0)
+        glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
+        glse_rows = rows(glse.astype(jnp.float32), 0.0)
 
     kernel_params = dict(
         sm_scale=sm_scale, causal=causal,
@@ -1235,7 +1244,8 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         name="hvd_flash_bwd_dkv",
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
     if rope:
-        grad_v = grad_v.sum(axis=1).swapaxes(1, 2)
+        with jax.named_scope(_GLUE_SCOPE):
+            grad_v = grad_v.sum(axis=1).swapaxes(1, 2)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
     stat_spec, q_spec, k_spec, v_spec, g_spec = specs("qk")
@@ -1254,17 +1264,18 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         name="hvd_flash_bwd_dq",
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
-    if p.packed:
+    with jax.named_scope(_GLUE_SCOPE):
+        if p.packed:
+            return (
+                dq[:, :sq].astype(q.dtype),
+                grad_k[:, :skv].astype(k.dtype),
+                grad_v[:, :skv].astype(v.dtype),
+            )
         return (
-            dq[:, :sq].astype(q.dtype),
-            grad_k[:, :skv].astype(k.dtype),
-            grad_v[:, :skv].astype(v.dtype),
+            dq[:, :, :sq].astype(q.dtype),
+            grad_k[:, :, :skv].astype(k.dtype),
+            grad_v[:, :, :skv].astype(v.dtype),
         )
-    return (
-        dq[:, :, :sq].astype(q.dtype),
-        grad_k[:, :, :skv].astype(k.dtype),
-        grad_v[:, :, :skv].astype(v.dtype),
-    )
 
 
 @functools.partial(
@@ -1414,9 +1425,10 @@ def flash_attention_with_lse(
         d = q.shape[-1] // n_heads if packed else q.shape[-1]
         sm_scale = 1.0 / float(np.sqrt(d))
     if layout == "bshd":
-        q = jnp.moveaxis(q, 2, 1)
-        k = jnp.moveaxis(k, 2, 1)
-        v = jnp.moveaxis(v, 2, 1)
+        with jax.named_scope(_GLUE_SCOPE):
+            q = jnp.moveaxis(q, 2, 1)
+            k = jnp.moveaxis(k, 2, 1)
+            v = jnp.moveaxis(v, 2, 1)
     elif layout not in ("bhsd", "bsm"):
         raise ValueError(
             f"layout must be 'bshd', 'bhsd' or 'bsm', got {layout!r}"
@@ -1426,7 +1438,8 @@ def flash_attention_with_lse(
         interpret, n_heads if packed else 0,
     )
     if layout == "bshd":
-        out = jnp.moveaxis(out, 1, 2)
+        with jax.named_scope(_GLUE_SCOPE):
+            out = jnp.moveaxis(out, 1, 2)
     return out, lse
 
 
@@ -1523,9 +1536,11 @@ def flash_attention_latent(
         )
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(n + r))
+    with jax.named_scope(_GLUE_SCOPE):
+        k_rope = k_rope.astype(kv.dtype)
     return _call_flash(
-        q, kv, k_rope.astype(kv.dtype), q_offset, kv_offset, sm_scale,
-        causal, block_q, block_k, interpret, n_heads, rope=r,
+        q, kv, k_rope, q_offset, kv_offset, sm_scale, causal, block_q,
+        block_k, interpret, n_heads, rope=r,
     )
 
 

@@ -188,7 +188,8 @@ def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
     ``step(state, batch)`` call took to return, ``step.device_ms`` the
     rest of the gap. N calls book N-1 steps; the last one stays pending.
     The ring's ``step`` / ``step.host_dispatch`` / ``step.device``
-    events and ``goodput.record_step`` get the same three quantities;
+    events get the same three quantities, ``goodput.record_step`` the
+    first two (the third is their difference);
     the reporter is ticked with the call count so JSONL/Prometheus
     flushes and the psum'd rank-0 summary ride the training loop with no
     extra threads.
@@ -237,7 +238,7 @@ def _instrument_step(fn: Callable, tokens_per_step, flops_per_step,
             rec.complete(
                 "step.device", "train", w_us + disp_us, int(device * 1e6)
             )
-        _goodput.record_step(w_begin, total, dispatch, device)
+        _goodput.record_step(w_begin, total, dispatch)
         reg = _obs.metrics()
         reg.histogram("step.total_ms").observe(total * 1e3)
         reg.histogram("step.host_dispatch_ms").observe(dispatch * 1e3)

@@ -26,9 +26,19 @@ def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself, so
-    nothing is configured here and no other path is ever set in code.
+    no path is configured here and no other path is ever set in code.
     Unset: the cache goes to :data:`DEFAULT_DIR` inside the checkout.
+
+    Either way the cache's key takes in each operation's metadata. The
+    step is read by the names it carries (``op_name``: the phases, the
+    models' parts, the kernels' names; ``docs/api.md``), and an executable
+    from the cache carries the names of whoever compiled it first: with
+    JAX's default key, which leaves the metadata out, a program whose
+    scopes changed would be handed one that predates them, and a profile
+    read by scope would find nothing. The price is one compile after an
+    edit that moves a traced line.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

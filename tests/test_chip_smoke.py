@@ -160,9 +160,12 @@ def test_compile_cache_helper_placement(monkeypatch):
     from horovod_tpu.utils import compile_cache
 
     before = jax.config.jax_compilation_cache_dir
+    names_in_key = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
         assert compile_cache.enable_compile_cache() == "/some/dir"
+        # an executable from the cache carries its first compiler's names
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         # JAX reads the variable itself; nothing was set in code.
         assert jax.config.jax_compilation_cache_dir == before
 
@@ -173,3 +176,42 @@ def test_compile_cache_helper_placement(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == first
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", names_in_key
+        )
+
+
+@pytest.mark.parametrize("names_in_key", [False, True])
+def test_a_scope_alone_moves_the_cache_key_only_with_names_in_it(
+    names_in_key
+):
+    """Why ``enable_compile_cache`` puts the metadata in the key: two
+    programs that differ in a ``jax.named_scope`` alone share JAX's default
+    key, so the second would be handed the first one's executable, names
+    and all; with the metadata in the key each has its own."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    def key_of(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) + 1
+
+        device = jax.devices()[0]
+        return cache_key.get(
+            jax.jit(f).lower(jnp.ones((4,))).compiler_ir(),
+            np.array([device]), compiler.get_compile_options(1, 1),
+            device.client,
+        )
+
+    was = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update(
+        "jax_compilation_cache_include_metadata_in_key", names_in_key
+    )
+    try:
+        assert (key_of("embed") != key_of("norm")) == names_in_key
+    finally:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", was
+        )

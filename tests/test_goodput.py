@@ -190,7 +190,7 @@ def test_add_validates_category_and_duration():
 
 def test_record_step_splits_dispatch_and_compute():
     led = GoodputLedger(window=64)
-    led.record_step(0.0, 1.0, 0.25, 0.75)
+    led.record_step(0.0, 1.0, 0.25)
     totals, _ = _assert_conserved(led)
     assert totals["host_dispatch"] == pytest.approx(0.25)
     assert totals["compute"] == pytest.approx(0.75)
@@ -204,10 +204,10 @@ def test_stretched_device_bracket_stays_compute(tmp_path, capsys):
     led = GoodputLedger(window=256)
     t = 0.0
     for _ in range(8):  # steady: 0.2 s dispatch, 0.8 s device
-        led.record_step(t, 1.0, 0.2, 0.8)
+        led.record_step(t, 1.0, 0.2)
         t += 1.0
     for _ in range(4):  # device bracket doubled
-        led.record_step(t, 1.8, 0.2, 1.6)
+        led.record_step(t, 1.8, 0.2)
         t += 1.8
     totals, elapsed = _assert_conserved(led)
     assert elapsed == pytest.approx(t)  # wall-clock, no residual
@@ -231,7 +231,7 @@ def test_stretched_device_bracket_stays_compute(tmp_path, capsys):
 
 def test_guard_skip_reclassifies_previous_step():
     led = GoodputLedger(window=64)
-    led.record_step(0.0, 1.0, 0.2, 0.8)
+    led.record_step(0.0, 1.0, 0.2)
     led.record_guard_skip()  # verdict for step N read at N+1
     totals, _ = _assert_conserved(led)
     assert totals["guard_retry"] == pytest.approx(1.0)
@@ -247,7 +247,7 @@ def test_disabled_feeds_are_noops(monkeypatch):
     goodput._reset_for_tests()
     try:
         assert not goodput.enabled()
-        goodput.record_step(0.0, 1.0, 0.2, 0.8)
+        goodput.record_step(0.0, 1.0, 0.2)
         goodput.record_serve("idle", 0.0, 1.0)
         goodput.record_rescale(0.0, 1.0)
         # Nothing was fed: the singleton was never even created.

@@ -12,6 +12,7 @@ time, so no fast-tier test may load it from a child process.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -58,6 +59,71 @@ def _compile(fn, sharding, *shapes):
         for shape, dtype in shapes
     ]
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# -- the models' parts change the compiled step in its metadata only --------
+# (first in the file: these compiles use every core, and the file's last
+# tests run beside test_trace.py's deadline-bound scenarios as it is)
+
+
+def _described_family_step(topo, case):
+    """HLO text of a tiny family's default step (``tests/model_parts.py``)
+    compiled for one described chip, with and without its metadata."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    import model_parts
+    from horovod_tpu.parallel import dp
+
+    hvd.init(devices=topo.devices[:1])
+    try:
+        _, _, loss_fn, params, batch = model_parts.build(case, 2)
+        step, wrapped = dp.make_train_step(loss_fn, optax.adamw(3e-4))
+        state = jax.eval_shape(lambda p: dp.init_state(p, wrapped), params)
+        placed = lambda tree, spec: jax.tree.map(  # noqa: E731
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(hvd.mesh(), spec)
+            ), tree,
+        )
+        hlo = step.lower(
+            placed(state, P()), placed(batch, P(hvd.WORLD_AXIS))
+        ).compile().as_text()
+    finally:
+        hvd.shutdown()
+    # without its metadata and the tables of call sites the metadata indexes
+    bare = re.sub(r",? ?metadata=\{[^{}]*\}", "", hlo)
+    bare = re.sub(
+        r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+        r"(?:\d+ .*\n)*", "\n", bare,
+    )
+    return bare, hlo
+
+
+@pytest.mark.parametrize(
+    "case", ["gpt2-flash", "bert-cls-padded", "latent-moe-flash"]
+)
+def test_parts_change_the_compiled_step_in_its_metadata_only(
+    v5e_topology, case
+):
+    """The step of each family compiled for the described v5e, against
+    the same model with no part opened (a patch of ``jax.named_scope``,
+    local to this test): the same instructions under the same names; the
+    parts are in the scoped build's ``op_name``s and in none of the
+    bare one's."""
+    import model_parts
+
+    # a Mosaic kernel's payload carries the call sites of whoever traced
+    # it first in this process: both builds trace it anew
+    jax.clear_caches()
+    scoped, scoped_full = _described_family_step(v5e_topology, case)
+    with model_parts.parts_disabled():
+        bare, bare_full = _described_family_step(v5e_topology, case)
+    assert scoped == bare
+    kernels = 0 if "padded" in case else 9 if "moe" in case else 6
+    assert scoped.count("tpu_custom_call") == kernels
+    part = re.compile(r'op_name="[^"]*/(?:%s)/' % "|".join(model_parts.PARTS))
+    assert part.search(scoped_full) and not part.search(bare_full)
 
 
 @pytest.mark.parametrize(
