@@ -162,8 +162,37 @@ def switch_moe_stacked(
 # three matmuls run over all ``held x T`` rows, a row's output is weighed by
 # the router's weight for that (token, expert) and by zero where the token
 # did not choose the expert, and the weighing is folded in before the down
-# projection, which then sums over experts as well: one ``[T, held F] x
-# [held F, D]`` matmul.
+# projection, which then sums over experts as well: one contraction over
+# ``(held, F)``.
+#
+# The form of the dots (PERF.md, PR 39). The stacks are stored ``[held, D,
+# F]`` (gate, up) and ``[held, F, D]`` (down): the plain reference reads the
+# same tree, so the shapes stay. Gate and up contract ``D`` with the expert
+# axis as a BATCH dimension of the dot, the tokens broadcast over it
+# (``etd,edf->etf``): each dot's free dimension is ``F`` alone and
+# ``[held, D, F]`` is the operand as it is stored. Written as one dot whose
+# free dimension is ``(held, F)`` merged (``td,edf->tef``) the contracted
+# ``D`` lies between the two in memory; the TPU's compiler then wants the
+# array ``[held][F][D]``, carries that layout through the cast to the
+# parameter and through the fused AdamW to both moments, and copies all
+# three round on entry and back on exit, every step: 60 copies of
+# ``f32[16,2048,768]``, a seventeenth of the expert cell's step. The hidden
+# rows are expert-major (``[held, T, F]``) and the down projection
+# (``etf,efd->td``) merges ``(held, F)`` as ``down`` is stored.
+#
+# The backward of the two batched dots is written out (``_gate_up``'s
+# ``custom_vjp``), because what autodiff derives from them costs more than
+# the copies did. dx stays what it was in the merged form, ONE contraction
+# over ``(held, F)`` a dot (``etf,edf->td``: the expert axis a contracted
+# window of the convolution, float32 accumulation, rounded once); derived,
+# it is a batched dot that writes the ``[held, T, D]`` partial products to
+# HBM (537 MB a dot in the expert cell) and a sum over rounded partials.
+# dW has to be batched over the experts to come out as the stacks are
+# stored, and every batched dW reads the tokens TRANSPOSED (33.5 MB); with
+# one dW for gate and one for up the compiler keeps that array in fast
+# memory for the first and reads it from HBM for the second, which then
+# takes twice its time. So gate's and up's dW are ONE batched dot over the
+# two cotangents side by side (``[held, T, 2F]``), cut in two afterwards.
 #
 # Why not a smaller buffer (``slack`` x the expected ``T k held / experts``
 # rows, overflow passes under ``lax.cond``)? It was built and measured
@@ -205,18 +234,56 @@ def topk_route(x, router_kernel, score_bias, *, top_k: int, scale: float):
     return chosen.astype(jnp.int32), weights
 
 
+def _for_each_expert(x, held):
+    """``[T, D]`` as ``[held, T, D]``: the batch operand of a dot over the
+    experts. The compiler folds the broadcast into the dot."""
+    return jnp.broadcast_to(x[None], (held, *x.shape))
+
+
+@jax.custom_vjp
+def _gate_up(x, gate, up):
+    """``x W_gate[e]`` and ``x W_up[e]`` for every held expert: ``[T, D]``
+    and two ``[held, D, F]`` (all in one dtype) to two ``[held, T, F]``.
+    The expert axis is a batch dimension of both dots, so each reads its
+    stack as it is stored."""
+    rows = _for_each_expert(x, gate.shape[0])
+    return (jnp.einsum("etd,edf->etf", rows, gate),
+            jnp.einsum("etd,edf->etf", rows, up))
+
+
+def _gate_up_fwd(x, gate, up):
+    return _gate_up(x, gate, up), (x, gate, up)
+
+
+def _gate_up_bwd(residuals, cotangents):
+    """dx: one contraction over ``(held, F)`` a dot; dW: one dot batched
+    over the experts for both stacks (see the section comment above)."""
+    x, gate, up = residuals
+    g_gate, g_up = cotangents
+    dx = jnp.einsum("etf,edf->td", g_gate, gate) + jnp.einsum(
+        "etf,edf->td", g_up, up
+    )
+    dw = jnp.einsum(
+        "etd,etf->edf", _for_each_expert(x, gate.shape[0]),
+        jnp.concatenate([g_gate, g_up], axis=-1),
+    )
+    return dx, dw[..., :gate.shape[-1]], dw[..., gate.shape[-1]:]
+
+
+_gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
+
+
 @jax.checkpoint
 def _held_experts(x, share, gate, up, down):
     """``sum_e share[t, e] E_e(x[t])``. Nothing of it is kept for the
-    backward but its arguments: the two ``[T, held, F]`` matmul outputs
+    backward but its arguments: the two ``[held, T, F]`` matmul outputs
     are computed again, which costs two matmuls and saves a layer of
     them."""
     as_x = lambda w: w.astype(x.dtype)  # noqa: E731
-    hidden = jax.nn.silu(
-        jnp.einsum("td,edf->tef", x, as_x(gate))
-    ) * jnp.einsum("td,edf->tef", x, as_x(up))
+    gated, raised = _gate_up(x, as_x(gate), as_x(up))
     return jnp.einsum(
-        "tef,efd->td", hidden * as_x(share)[..., None], as_x(down)
+        "etf,efd->td",
+        jax.nn.silu(gated) * raised * as_x(share).T[..., None], as_x(down),
     )
 
 

@@ -297,6 +297,62 @@ def test_local_expert_layer_compiles_at_the_cell_buffer_shapes(v5e):
         ((16, 768, 2048), jnp.float32),
     )
     assert "conditional" not in hlo and "while" not in hlo
+    # dx is one contraction over (held, F) for gate and one for up: the
+    # per-expert partial products are not built in HBM (they are, 537 MB
+    # of them, in the backward that autodiff derives from the two dots)
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert not re.findall(r"= bf16\[16,8192,2048\]", entry)
+
+
+def test_expert_stacks_and_their_moments_are_read_as_stored(v5e):
+    """One AdamW step over the three expert stacks at the cell's shapes,
+    state donated: gate and up, and their dW, are dots batched over the
+    expert axis, so the compiler reads ``[16, 2048, 768]`` as it is stored
+    and turns neither the weights nor their moments round and back (the
+    merged ``td,edf->tef`` form wanted ``{1,2,0}``: 12 such copies a
+    layer)."""
+    import optax
+
+    from horovod_tpu.parallel import ep
+
+    optimizer = optax.adamw(3e-4)
+
+    def step(stacks, opt_state, x, chosen, weights):
+        def loss(stacks):
+            out = ep.local_experts(
+                x, chosen, weights, *stacks, first_expert=0, n_experts=256
+            )
+            return out.astype(jnp.float32).sum()
+
+        value, grads = jax.value_and_grad(loss)(stacks)
+        updates, opt_state = optimizer.update(grads, opt_state, stacks)
+        return optax.apply_updates(stacks, updates), opt_state, value
+
+    placed = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e
+    )
+    stacks = tuple(
+        placed(shape, jnp.float32)
+        for shape in ((16, 2048, 768), (16, 2048, 768), (16, 768, 2048))
+    )
+    opt_state = jax.tree.map(
+        lambda a: placed(a.shape, a.dtype),
+        jax.eval_shape(optimizer.init, stacks),
+    )
+    hlo = jax.jit(step, donate_argnums=(0, 1)).lower(
+        stacks, opt_state, placed((8192, 2048), jnp.bfloat16),
+        placed((8192, 8), jnp.int32), placed((8192, 8), jnp.float32),
+    ).compile().as_text()
+    stack = r"f32\[16,(?:2048,768|768,2048)\]\{([\d,]*)[^}]*\}"
+    assert not re.findall(rf"= {stack} copy\(", hlo)
+    entry = hlo[hlo.index("\nENTRY"):]
+    layouts = re.findall(rf"= {stack} parameter\(", entry)
+    assert len(layouts) == 9 and set(layouts) == {"2,1,0"}, layouts
+    # gate / up / down, gate and up again in the backward, then dhidden,
+    # down's dW and ONE dW for gate and up: the merged form's nine
+    # matmuls' work in eight dots
+    assert len(re.findall(r" convolution\(", hlo)) == 8
+    assert "conditional" not in hlo and "while" not in hlo
 
 
 # name: (heads, sq, skv, d, dtype, causal)
