@@ -16,3 +16,4 @@ from .bert import BertConfig, BertModel  # noqa: F401
 from .vit import ViT, ViTConfig  # noqa: F401
 from .moe import MoEConfig, SwitchTransformerLM  # noqa: F401
 from .latent_moe import LatentMoEConfig, LatentMoELM  # noqa: F401
+from .window_moe import WindowMoEConfig, WindowMoELM  # noqa: F401

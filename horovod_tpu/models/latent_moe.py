@@ -81,12 +81,12 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-import optax
 
 from ..context import device_platform
 from ..parallel import ep
-from .transformer import GatedMlp, RMSNorm, dot_product_attention
+from .transformer import (  # noqa: F401  (rotary, lm_loss: re-exported)
+    GatedMlp, RMSNorm, dot_product_attention, lm_loss, rotary,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,22 +132,6 @@ class LatentMoEConfig:
         )
         base.update(kw)
         return LatentMoEConfig(**base)
-
-
-def rotary(x, *, theta: float):
-    """Rotates adjacent pairs ``(x[2i], x[2i+1])`` of the last axis by
-    ``pos * theta^(-2i/d)``; ``x`` is ``[B, S, ..., d]``, positions 0 on.
-    Computed in fp32, returned in ``x``'s dtype."""
-    d, s = x.shape[-1], x.shape[1]
-    freq = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    angle = np.arange(s, dtype=np.float64)[:, None] * freq[None, :]
-    shape = (1, s) + (1,) * (x.ndim - 3) + (d // 2,)
-    cos = jnp.asarray(np.cos(angle), jnp.float32).reshape(shape)
-    sin = jnp.asarray(np.sin(angle), jnp.float32).reshape(shape)
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
 
 
 def _init(cfg: LatentMoEConfig):
@@ -330,17 +314,3 @@ class LatentMoELM(nn.Module):
                 )(jnp.concatenate([hidden, shifted], axis=-1))
             merged = LatentMoEBlock(cfg, name="mtp_block")(merged)
             return logits, logits_of(merged, "mtp_final_norm")
-
-
-def lm_loss(logits, mtp_logits, tokens, *, mtp_weight: float):
-    """``CE(logits, t_{i+1}) + mtp_weight CE(mtp_logits, t_{i+2})``, each a
-    mean over every position; ``tokens [B, S + 1 + n_mtp]``: what the
-    model took and one more, the last target."""
-    s = logits.shape[1]
-    cross_entropy = optax.softmax_cross_entropy_with_integer_labels
-    loss = cross_entropy(logits, tokens[:, 1:s + 1]).mean()
-    if mtp_logits is not None:
-        loss = loss + mtp_weight * cross_entropy(
-            mtp_logits, tokens[:, 2:s + 2]
-        ).mean()
-    return loss

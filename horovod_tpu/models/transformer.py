@@ -17,6 +17,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 
 from ..context import device_platform
 from ..ops import actquant as _actquant
@@ -267,3 +268,45 @@ class Transformer(nn.Module):
             with jax.named_scope("head"):
                 return emb.attend(x).astype(jnp.float32)
         return x
+
+
+def rotary(x, *, theta: float, halves: bool = False):
+    """Rotates pairs of the last axis by ``pos * theta^(-2i/d)``: adjacent
+    pairs ``(x[2i], x[2i+1])``, or with ``halves`` the pairs ``(x[i],
+    x[i + d/2])`` (the ``rotate_half`` convention of the published
+    decoder-only code; the two give the same scores up to one fixed
+    permutation of a head's columns in q and k alike).  ``x`` is ``[B, S,
+    ..., d]``, positions 0 on.  Computed in fp32, returned in ``x``'s
+    dtype.  Shared by ``latent_moe.py`` and ``window_moe.py``."""
+    d, s = x.shape[-1], x.shape[1]
+    freq = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    angle = np.arange(s, dtype=np.float64)[:, None] * freq[None, :]
+    shape = (1, s) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos = jnp.asarray(np.cos(angle), jnp.float32).reshape(shape)
+    sin = jnp.asarray(np.sin(angle), jnp.float32).reshape(shape)
+    if halves:
+        x32 = x.astype(jnp.float32)
+        a, b = x32[..., :d // 2], x32[..., d // 2:]
+        return jnp.concatenate(
+            [a * cos - b * sin, a * sin + b * cos], axis=-1
+        ).astype(x.dtype)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def lm_loss(logits, mtp_logits, tokens, *, mtp_weight: float):
+    """``CE(logits, t_{i+1}) + mtp_weight CE(mtp_logits, t_{i+2})``, each a
+    mean over every position; ``tokens [B, S + 1 + n_mtp]``: what the
+    model took and one more, the last target.  Without the multi-token
+    module (``mtp_logits`` None) it is the plain next-token loss.  Shared
+    by ``latent_moe.py`` and ``window_moe.py``."""
+    s = logits.shape[1]
+    cross_entropy = optax.softmax_cross_entropy_with_integer_labels
+    loss = cross_entropy(logits, tokens[:, 1:s + 1]).mean()
+    if mtp_logits is not None:
+        loss = loss + mtp_weight * cross_entropy(
+            mtp_logits, tokens[:, 2:s + 2]
+        ).mean()
+    return loss

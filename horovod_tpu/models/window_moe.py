@@ -1,0 +1,252 @@
+"""Window and full attention layers mixed, query heads in groups, routed
+experts held by share, the router ahead of the attention: the SmallThinker
+layer as a causal language model.
+
+One model for every configuration of that family (the benchmark's
+configuration file gives the published sizes); "supported" means training,
+on the normal path (``dp.make_train_step`` on :func:`lm_loss`), of ONE
+CHIP'S SHARE of a layer that expert parallelism divides over several, as
+``latent_moe.py`` has it: this chip holds ``n_experts_held`` of each
+layer's ``n_experts`` routed experts (``first_expert`` on) and a slice of
+the vocabulary, routes over all experts and computes the part of the result
+that its experts give (``parallel/ep.local_experts``). Serving (a cache of
+two kinds of layer) is not built.
+
+Equations (no bias anywhere, ``eps`` 1e-6). Layer ``l`` with input ``h``
+``[S, d_model]``, ``H`` query heads and ``H_kv`` K/V heads of ``head_dim``::
+
+    u = RMSNorm1(h)
+    r = u W_r  in fp32 over all E;  C = the k largest of r
+    w = softmax(r[C])          (= softmax over E renormalised over C)
+    q = u W_q -> H x head_dim;  k = u W_k, v = u W_v -> H_kv x head_dim
+    rope_layout[l] = 1:    q, k <- rotary(theta, halves, no scaling)
+    window_layout[l] = 1:  valid(i, j): 0 <= i - j < window
+                     = 0:  valid(i, j): j <= i
+    a = softmax_j(q_i k_j / sqrt(head_dim)) v_j;  query head n reads K/V
+        head n // (H / H_kv)
+    h' = h + concat(a) W_o
+    m = RMSNorm2(h')
+    out = h' + sum_{e in C, held} w_e W_down,e (relu(W_gate,e m) * W_up,e m)
+
+The router reads the ATTENTION's input, so the experts of a layer are
+chosen before its attention runs; the experts themselves read
+``RMSNorm2(h')``. Every layer is an expert layer: no shared expert, no
+dense layer. Output: final RMSNorm, an untied head over the vocabulary
+slice, logits in fp32, next-token cross entropy (``transformer.lm_loss``).
+
+The two layouts are tuples a layer indexes modulo their length, so a
+period (``(0, 1, 1, 1)``: full, window, window, window) is data. The flash
+kernels take q ``[B, S, H * head_dim]`` and k / v ``[B, S, H_kv *
+head_dim]`` as the projections leave them (``layout="bsm"``,
+``n_kv_heads``, ``window``): no K or V of ``H`` heads exists. A window
+layer's kernels are named ``hvd_flash_*_window``, a full layer's
+``hvd_flash_*``. Off the TPU attention is ``dot_product_attention`` with
+the explicit band mask over K/V repeated to ``H`` heads.
+
+Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
+operation of ``apply`` lies under exactly one of: ``embed``, ``norm`` (the
+RMSNorms, the residual sums and the token-major views), ``attn_proj`` (the
+four projections and rotary), ``attn_layout`` (the reshapes between the
+projections and the kernels, and the kernels' entry's own glue),
+``attn_xla`` (attention where flash is bypassed), ``moe_route``,
+``moe_experts`` (``parallel/ep.py``), ``head``. There is no ``mlp`` part:
+the model has no dense feed-forward. The Mosaic kernels carry none of them.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..context import device_platform
+from ..parallel import ep
+from .transformer import (  # noqa: F401  (lm_loss: the model's loss)
+    RMSNorm, dot_product_attention, lm_loss, rotary,
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowMoEConfig:
+    vocab_size: int = 151936  # rows of the vocabulary held here
+    d_model: int = 2560
+    n_layers: int = 52
+    n_heads: int = 28
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    window: int = 4096
+    # per layer, indexed modulo the length: 1 = window / rotary
+    window_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    rope_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    rope_theta: float = 1.5e6
+    d_ff_expert: int = 768
+    n_experts: int = 64  # the router's width
+    n_experts_held: int = 64  # routed experts whose weights live here
+    first_expert: int = 0  # ... and the first of them
+    top_k: int = 6
+    eps: float = 1e-6
+    init_std: float = 0.02
+    dtype: Any = jnp.bfloat16
+    # None: the flash kernels where the world's devices are TPUs
+    use_flash: Optional[bool] = None
+
+    def windowed(self, layer: int) -> bool:
+        return bool(self.window_layout[layer % len(self.window_layout)])
+
+    def rotated(self, layer: int) -> bool:
+        return bool(self.rope_layout[layer % len(self.rope_layout)])
+
+    @staticmethod
+    def tiny(**kw) -> "WindowMoEConfig":
+        base = dict(
+            vocab_size=256, d_model=48, n_layers=4, n_heads=6, n_kv_heads=2,
+            head_dim=16, window=8, d_ff_expert=24, n_experts=16,
+            n_experts_held=4, top_k=3,
+        )
+        base.update(kw)
+        return WindowMoEConfig(**base)
+
+
+def band_mask(s: int, window: Optional[int]):
+    """``[s, s]`` bool: ``valid(i, j) = 0 <= i - j (< window)``."""
+    ahead = np.arange(s)[:, None] - np.arange(s)[None, :]
+    valid = ahead >= 0
+    return valid if window is None else valid & (ahead < window)
+
+
+def _init(cfg: WindowMoEConfig):
+    return nn.initializers.normal(cfg.init_std)
+
+
+class GroupedAttention(nn.Module):
+    """Causal attention with ``n_heads`` query heads over ``n_kv_heads``
+    K/V heads, under a ``window`` (None: every earlier position) and with
+    or without rotary."""
+
+    cfg: WindowMoEConfig
+    window: Optional[int] = None
+    rotate: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        b, s, _ = x.shape
+        h, h_kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        dense = lambda width, name: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=cfg.dtype, name=name,
+            kernel_init=_init(cfg),
+        )
+        with jax.named_scope("attn_proj"):
+            q = dense(h * d, "q")(x)
+            k = dense(h_kv * d, "k")(x)
+            v = dense(h_kv * d, "v")(x)
+            if self.rotate:
+                turn = lambda t, heads: rotary(  # noqa: E731
+                    t.reshape(b, s, heads, d), theta=cfg.rope_theta,
+                    halves=True,
+                ).reshape(b, s, heads * d)
+                q, k = turn(q, h), turn(k, h_kv)
+        use_flash = cfg.use_flash
+        if use_flash is None:
+            use_flash = device_platform() == "tpu"
+        if use_flash:
+            from ..ops.pallas_kernels import flash_attention
+
+            # q, k and v as the projections leave them: no relayout, and
+            # no K or V of ``h`` heads
+            out = flash_attention(
+                q, k, v, causal=True, window=self.window, layout="bsm",
+                n_heads=h, n_kv_heads=h_kv,
+            )
+        else:
+            with jax.named_scope("attn_xla"):
+                heads = lambda t, n: t.reshape(b, s, n, d)  # noqa: E731
+                shared = lambda t: jnp.repeat(  # noqa: E731
+                    heads(t, h_kv), h // h_kv, axis=2
+                )
+                out = dot_product_attention(
+                    heads(q, h), shared(k), shared(v), causal=False,
+                    mask=jnp.asarray(band_mask(s, self.window)),
+                )
+            with jax.named_scope("attn_layout"):
+                out = out.reshape(b, s, h * d)
+        with jax.named_scope("attn_proj"):
+            return dense(cfg.d_model, "o")(out)
+
+
+class WindowMoEBlock(nn.Module):
+    """One layer: the router reads ``RMSNorm1(h)`` before the attention
+    does, the held experts read ``RMSNorm2(h')``."""
+
+    cfg: WindowMoEConfig
+    windowed: bool = False
+    rotate: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        b, s, d = x.shape
+        held, f = cfg.n_experts_held, cfg.d_ff_expert
+        norm = lambda name: RMSNorm(cfg.eps, cfg.dtype, name=name)  # noqa: E731
+        param = lambda name, shape: self.param(  # noqa: E731
+            name, _init(cfg), shape, jnp.float32
+        )
+        router = param("router", (d, cfg.n_experts))
+        gate = param("experts_gate", (held, d, f))
+        up = param("experts_up", (held, d, f))
+        down = param("experts_down", (held, f, d))
+        with jax.named_scope("norm"):
+            u = norm("attn_norm")(x)
+            tokens = u.reshape(b * s, d)  # free: the token-major view
+        with jax.named_scope("moe_route"):
+            chosen, weights = ep.topk_route(
+                tokens, router, None, top_k=cfg.top_k, scoring="softmax"
+            )
+        a = GroupedAttention(
+            cfg, window=cfg.window if self.windowed else None,
+            rotate=self.rotate, name="attn",
+        )(u)
+        with jax.named_scope("norm"):
+            x = x + a
+            tokens = norm("ffn_norm")(x).reshape(b * s, d)
+        y = ep.local_experts(
+            tokens, chosen, weights, gate, up, down,
+            first_expert=cfg.first_expert, n_experts=cfg.n_experts,
+            activation="relu",
+        )
+        with jax.named_scope("norm"):
+            return x + y.reshape(b, s, d)
+
+
+class WindowMoELM(nn.Module):
+    """``tokens [B, S] -> logits`` fp32 ``[B, S, vocab]``; ``logits[:, i]``
+    predicts the token after ``tokens[:, i]``."""
+
+    cfg: WindowMoEConfig
+
+    @nn.compact
+    def __call__(self, tokens):
+        cfg = self.cfg
+        head = self.param(
+            "head", _init(cfg), (cfg.d_model, cfg.vocab_size), jnp.float32
+        )
+        with jax.named_scope("embed"):
+            x = nn.Embed(
+                cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed",
+                embedding_init=_init(cfg),
+            )(tokens)
+        for i in range(cfg.n_layers):
+            x = WindowMoEBlock(
+                cfg, windowed=cfg.windowed(i), rotate=cfg.rotated(i),
+                name=f"block_{i}",
+            )(x)
+        with jax.named_scope("norm"):
+            x = RMSNorm(cfg.eps, cfg.dtype, name="final_norm")(x)
+        with jax.named_scope("head"):
+            return jnp.dot(
+                x, head.astype(cfg.dtype), preferred_element_type=jnp.float32
+            )

@@ -24,6 +24,12 @@ model in ``models/``.  This module provides it:
   (``kv_b``'s output as it leaves the matmul) and the one rotary key every
   head of a position shares, so K is never built in HBM.
 
+Both ``q, k, v`` entries take a ``window`` (a band under the diagonal: the
+tiles and blocks outside it are skipped at both ends, in all three
+kernels) and query groups (K and V with fewer heads than q: a program
+reads ONE K/V head's block for the query heads that share it, and dK / dV
+add up over them in the kernel).
+
 Causality across ring steps needs *global* positions, so the kernel takes
 ``q_offset``/``kv_offset`` (traced scalars, prefetched to SMEM): block r
 of an ``sp``-sharded sequence holds global rows ``r*S .. (r+1)*S-1``.
@@ -80,6 +86,14 @@ _LANES = 128
 _GLUE_SCOPE = "attn_layout"
 
 
+# ``_head_group``'s budget for query heads that share a K/V head, and what
+# such a call asks the compiler for (the default scoped limit is 16 MB and
+# the dK/dV kernel's q, g, K, V blocks, two outputs and two accumulators
+# pass it at 7 heads of 128 and blocks of 512 x 1024)
+_GROUPED_VMEM = 10 << 20
+_GROUPED_VMEM_LIMIT = 48 << 20
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
@@ -89,7 +103,8 @@ def _use_interpret() -> bool:
 
 
 def _head_group(h: int, block_q: int, block_k: int, d: int,
-                packed: bool, dv: Optional[int] = None, rope: int = 0) -> int:
+                packed: bool, dv: Optional[int] = None, rope: int = 0,
+                kv_ratio: int = 1) -> int:
     """Heads per program.  At short sequences a single head's two
     ``d``-thin matmuls underfill the MXU pipeline and per-program overhead
     (scalar DMAs, grid bookkeeping) dominates, so each program handles a
@@ -112,8 +127,28 @@ def _head_group(h: int, block_q: int, block_k: int, d: int,
     (``None``: the same): the accumulator and the out block are ``dv``
     wide, and a packed group has to be lane-legal at both widths; with
     ``rope`` (:func:`flash_attention_latent`) also at ``d - rope + dv``,
-    the width of a head of the packed ``kv``."""
+    the width of a head of the packed ``kv``.
+
+    ``kv_ratio`` > 1 (query groups: that many query heads share one K/V
+    head): a program's K/V block is ONE K/V head's, so its query heads lie
+    within that head's ``kv_ratio`` and the group divides it; the K/V
+    blocks do not scale with the group.  The budget is then the whole of
+    what the kernels hold, 10 MB of the 16 (``_GROUPED_VMEM``): at 7
+    heads of 128 the 4 MB rule, written for groups that multiply K/V too,
+    would leave one head a program, and one head a program fetches every
+    K/V block seven times and pays the grid step seven times."""
     dv = d if dv is None else dv
+    if kv_ratio > 1:
+        for g in range(kv_ratio, 0, -1):
+            if kv_ratio % g or (packed and g != h and any(
+                (g * w) % _LANES for w in (d, dv)
+            )):
+                continue
+            acc = g * block_q * dv * 4
+            blocks = 2 * (g * block_q + block_k) * (d + dv) * 2
+            if acc + blocks <= _GROUPED_VMEM or g == 1:
+                return g
+        return 1
     widths = (d, dv) + ((d - rope + dv,) if rope else ())
 
     def legal(g):
@@ -142,8 +177,13 @@ def _head_group(h: int, block_q: int, block_k: int, d: int,
 #                                                    one non-causal calls run;
 #   straddling it (or holding padded columns, or
 #   padded q rows in the backward)                -> the masked path.
-# For one q tile the K/V tiles of a block come in that order — interior,
-# straddling, skipped — so two counts describe it (``_tile_spans``).  The q
+# A ``window`` (a band: an entry is valid only where ``row - col < window``)
+# adds the same two classes at the other end: tiles wholly left of the lower
+# edge are not visited (and a block of only such tiles is neither fetched
+# nor given a grid step), tiles that straddle it run the masked path.
+# For one q tile the K/V tiles of a block come in that order — (left of the
+# band, straddling its edge,) interior, straddling, skipped — so three
+# counts describe it (``_tile_spans``).  The q
 # tile then takes its visited tiles as one slab (``_for_causal_tiles``):
 # unmasked if the whole block is interior, else masked.  The offsets stay
 # traced scalars (ring attention passes ``rank * s``), so the class is a
@@ -201,16 +241,21 @@ def _clip(x, lo, hi):
 
 
 def _tile_spans(row0, col0, cols_real, q_padded, *, tq: int, tk: int,
-                nkt: int):
+                nkt: int, window: Optional[int] = None):
     """Classes of the ``nkt`` K/V compute tiles of one block against one
-    q tile, as ``(n_interior, n_visited)``: tiles ``[0, n_interior)`` hold
-    only valid entries, ``[n_interior, n_visited)`` straddle the diagonal
-    or hold padding, ``[n_visited, nkt)`` hold no valid entry.
+    q tile, as ``(n_left, n_interior, n_visited)``: tiles ``[0, n_left)``
+    lie wholly left of the band's lower edge and hold no valid entry
+    (``window`` only; else 0), tiles ``[n_left, n_visited)`` are visited,
+    ``[n_visited, nkt)`` lie beyond the diagonal and hold no valid entry.
+    Of the visited tiles ``n_interior`` hold only valid entries (they lie
+    together: after those that straddle the lower edge, before those that
+    straddle the diagonal or hold padding).
 
     ``row0``: global position of the q tile's first row; ``col0``: global
     position of the block's first column; ``cols_real``: how many of the
     block's columns lie before the K/V length (any integer); ``q_padded``:
-    the q tile holds padded rows whose ``lse`` is ``-inf`` (backward only).
+    the q tile holds padded rows whose ``lse`` is ``-inf`` (backward only);
+    ``window``: an entry is valid only where ``row - col < window``.
     Python ints give Python ints (the build-time counter and the tests);
     traced scalars give traced scalars (the kernels)."""
     span = nkt * tk
@@ -219,20 +264,40 @@ def _tile_spans(row0, col0, cols_real, q_padded, *, tq: int, tk: int,
     # ... and interior iff its last column col0 + (j+1)*tk - 1 <= row0
     below = _clip(row0 - col0 + 1, 0, span) // tk
     unpadded = _clip(cols_real, 0, span) // tk
-    if isinstance(below, int) and isinstance(unpadded, int):
+    static = isinstance(below, int) and isinstance(unpadded, int)
+    n_left = 0
+    if window is not None:
+        # tile j lies left of the band iff its last column is below the
+        # FIRST row's lowest, col0 + (j+1)*tk - 1 < row0 - window + 1 ...
+        n_left = _clip(row0 - window + 1 - col0, 0, span) // tk
+        # ... and is cut by the lower edge iff its first column is below
+        # the LAST row's lowest, col0 + j*tk < row0 + tq - window
+        on_edge = _clip(row0 + tq - window - col0 + tk - 1, 0, span) // tk
+        if static:
+            n_left = min(n_left, n_visited)
+            below, unpadded = (max(x - on_edge, 0) for x in (below, unpadded))
+        else:
+            n_left = jnp.minimum(n_left, n_visited)
+            below, unpadded = (
+                jnp.maximum(x - on_edge, 0) for x in (below, unpadded)
+            )
+    if static:
         n_interior = 0 if q_padded else min(below, unpadded)
     else:
         n_interior = jnp.where(q_padded, 0, jnp.minimum(below, unpadded))
-    return n_interior, n_visited
+    return n_left, n_interior, n_visited
 
 
 def _for_causal_tiles(tile, row0, col0, cols_real, rows_real, *,
-                      block_q: int, block_k: int, tq: int, tk: int):
-    """Inside a kernel: call ``tile(r, width, masked)`` once for every q
-    tile of one copied block that sees a valid entry of it: ``r`` the
-    tile's first row within the block (traced), ``width`` (static) how
-    many of the block's leading columns it visits, a whole number of
-    K/V tiles.  A q tile takes its visited columns as ONE slab: the
+                      block_q: int, block_k: int, tq: int, tk: int,
+                      window: Optional[int] = None):
+    """Inside a kernel: call ``tile(r, start, width, masked)`` once for
+    every q tile of one copied block that sees a valid entry of it: ``r``
+    the tile's first row within the block (traced), ``start`` the first
+    column it visits (0, or traced under a ``window``: the tiles left of
+    the band are passed over) and ``width`` (static) how many columns from
+    there, a whole number of K/V tiles.  A q tile takes its visited
+    columns as ONE slab: the
     running max / sum / accumulator are read, rescaled and written once
     per slab, and that per-row work, not the entries, is what narrow
     tiles multiply.  The slab is unmasked when every tile of the block is
@@ -249,13 +314,15 @@ def _for_causal_tiles(tile, row0, col0, cols_real, rows_real, *,
     def q_tile(i, carry):
         r = i * tq
         q_padded = False if rows_real is None else r + tq > rows_real
-        n_interior, n_visited = _tile_spans(
-            row0 + r, col0, cols_real, q_padded, tq=tq, tk=tk, nkt=nkt
+        n_left, n_interior, n_visited = _tile_spans(
+            row0 + r, col0, cols_real, q_padded, tq=tq, tk=tk, nkt=nkt,
+            window=window,
         )
-        pl.when(n_interior == nkt)(lambda: tile(r, block_k, False))
+        n_slab = n_visited if window is None else n_visited - n_left
+        pl.when(n_interior == nkt)(lambda: tile(r, 0, block_k, False))
         for w in range(1, nkt + 1):
-            pl.when(jnp.logical_and(n_visited == w, n_interior < nkt))(
-                functools.partial(tile, r, w * tk, True)
+            pl.when(jnp.logical_and(n_slab == w, n_interior < nkt))(
+                functools.partial(tile, r, n_left * tk, w * tk, True)
             )
         return carry
 
@@ -264,22 +331,33 @@ def _for_causal_tiles(tile, row0, col0, cols_real, rows_real, *,
 
 def _count_tiles(q_offset: int, kv_offset: int, *, sq: int, skv: int,
                  sq_pad: int, skv_pad: int, block_q: int, block_k: int,
-                 tq: int, tk: int, guard_q_pad: bool):
+                 tq: int, tk: int, guard_q_pad: bool,
+                 window: Optional[int] = None):
     """``(visited, masked, skipped)`` compute tiles of one batch element
     and head, by the kernels' own rule (``_tile_spans``; a q tile's
     visited tiles of one block run masked unless all are interior)."""
     visited = masked = 0
     for q0 in range(0, sq_pad, tq):
         for k0 in range(0, skv_pad, block_k):
-            n_interior, n_visited = _tile_spans(
+            n_left, n_interior, n_visited = _tile_spans(
                 q_offset + q0, kv_offset + k0, skv - k0,
                 guard_q_pad and q0 + tq > sq,
-                tq=tq, tk=tk, nkt=block_k // tk,
+                tq=tq, tk=tk, nkt=block_k // tk, window=window,
             )
-            visited += n_visited
+            visited += n_visited - n_left
             if n_interior < block_k // tk:  # the slab is masked as a whole
-                masked += n_visited
+                masked += n_visited - n_left
     return visited, masked, (sq_pad // tq) * (skv_pad // tk) - visited
+
+
+def _book_call_kinds(p: "_Plan", kernels: int) -> None:
+    """Build-time counters of what kind of call ``kernels`` kernels were
+    built for: ``flash.calls.latent_kv``, ``.windowed``, ``.grouped_kv``."""
+    reg = _registry.always()
+    for name, on in (("latent_kv", p.rope), ("windowed", p.window),
+                     ("grouped_kv", p.kv_ratio > 1)):
+        if on:
+            reg.counter(f"flash.calls.{name}").inc(kernels)
 
 
 def _book_tiles(static_offsets, **geometry) -> None:
@@ -332,8 +410,11 @@ def _head_store(ref, g, d, packed, value):
 # and dQ, whose ``Kᵀ·dsᵀ`` then falls into two products as well, 5% more.
 
 
-def _k_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope):
-    """Head ``g``'s ``[rows, d]`` key tile."""
+def _k_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope,
+            kv_shared=False):
+    """Head ``g``'s ``[rows, d]`` key tile.  ``kv_shared`` (query groups):
+    the block is the ONE K/V head every query head of the program reads."""
+    g = 0 if kv_shared else g
     if rope:
         lo = g * (d - rope + dv)
         return jnp.concatenate(
@@ -342,8 +423,10 @@ def _k_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope):
     return _head(k_ref, g, d, packed, rows)
 
 
-def _v_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope):
+def _v_head(k_ref, v_ref, g, rows, *, packed, d, dv, rope,
+            kv_shared=False):
     """Head ``g``'s ``[rows, dv]`` value tile."""
+    g = 0 if kv_shared else g
     if rope:
         hi = (g + 1) * (d - rope + dv)
         return k_ref[0, rows, hi - dv:hi]
@@ -357,7 +440,8 @@ def _block_dims(q_ref, k_ref, packed: bool, d: int):
     return q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
 
 
-def _valid_mask(geom, row0, col0, tq: int, tk: int, causal: bool):
+def _valid_mask(geom, row0, col0, tq: int, tk: int, causal: bool,
+                window: Optional[int] = None):
     """[tk, tq] validity of the keys-by-queries scores whose first q row
     sits at global position ``row0`` and whose first K/V column is
     ``col0``: columns on sublanes, q positions on lanes."""
@@ -366,12 +450,25 @@ def _valid_mask(geom, row0, col0, tq: int, tk: int, causal: bool):
     if causal:
         q_pos = row0 + lax.broadcasted_iota(jnp.int32, (1, tq), 1)
         valid = jnp.logical_and(valid, q_pos >= geom[1] + col)
+        if window is not None:
+            valid = jnp.logical_and(valid, q_pos < geom[1] + col + window)
     return valid
+
+
+def _in_band(band, block, blocks: int):
+    """Runs a function at once, or under a window only where the block
+    the grid step stands for (number ``block`` of ``blocks``) lies inside
+    the padded sequence: a windowed grid axis starts at the band's first
+    block, so its last steps can lie beyond the end, where the index map
+    repeats a block that must not be counted twice."""
+    if band is None:
+        return lambda f: f()
+    return pl.when(block < blocks)
 
 
 def _drive_tiles(update, geom, qi, kj, *, q_len: Optional[int],
                  block_q: int, block_k: int, causal: bool, masked: bool,
-                 tiles: Tuple[int, int]):
+                 tiles: Tuple[int, int], window: Optional[int] = None):
     """Drive a kernel's ``update(rq, rk, valid)`` over one (q block, K/V
     block) pair: ``rq`` / ``rk`` select the q / K/V rows, ``valid`` is
     their ``[K/V rows, q rows]`` validity mask or ``None`` on the unmasked
@@ -381,19 +478,26 @@ def _drive_tiles(update, geom, qi, kj, *, q_len: Optional[int],
     masked path (the backward), else ``None``."""
     row0 = geom[0] + qi * block_q  # global position of the block's row 0
     if causal:
-        tq, _ = tiles
+        tq, tk = tiles
 
-        def tile(r, width, tile_masked):
+        def tile(r, start, width, tile_masked):
+            # a slab starts at column 0 unless a window's lower edge cuts
+            # the block: then at a K/V tile, a traced one
+            cut = not isinstance(start, int)
+            rk = pl.ds(pl.multiple_of(start, tk), width) if cut else slice(
+                0, width
+            )
             valid = _valid_mask(
-                geom, row0 + r, kj * block_k, tq, width, True
+                geom, row0 + r, kj * block_k + start if cut else kj * block_k,
+                tq, width, True, window,
             ) if tile_masked else None
-            update(pl.ds(pl.multiple_of(r, tq), tq), slice(0, width), valid)
+            update(pl.ds(pl.multiple_of(r, tq), tq), rk, valid)
 
         _for_causal_tiles(
             tile, row0, geom[1] + kj * block_k,
             geom[2] - kj * block_k,
             None if q_len is None else q_len - qi * block_q,
-            block_q=block_q, block_k=block_k, tq=tq, tk=tiles[1],
+            block_q=block_q, block_k=block_k, tq=tq, tk=tk, window=window,
         )
     else:
         update(
@@ -424,6 +528,8 @@ def _fwd_kernel(
     d: int = 0,
     dv: int = 0,
     rope: int = 0,
+    kv_shared: bool = False,
+    band: Optional["_Plan"] = None,
 ):
     """One (batch*head group, q-block, k-block) grid step of the online
     softmax.
@@ -468,15 +574,24 @@ def _fwd_kernel(
     ``dv`` of a v / out head (latent attention: 192 and 128); nothing in
     the body is score-by-width, so one body serves both.  With ``rope``
     k_ref is the packed ``kv`` block, [1, block_k, G*(d - rope + dv)],
-    and v_ref the shared key's, [1, block_k, rope] (``_k_head``).
+    and v_ref the shared key's, [1, block_k, rope] (``_k_head``).  With
+    ``kv_shared`` (query groups) k_ref / v_ref hold ONE head, which every
+    query head of the program reads.  ``band`` (the call's plan, under a
+    window only): the K/V grid axis covers the blocks the band can reach
+    and step 0 is the q block's first (``_first_kv_block``).
     """
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
-    heads = dict(packed=packed, d=d, dv=dv, rope=rope)
+    heads = dict(packed=packed, d=d, dv=dv, rope=rope, kv_shared=kv_shared)
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    kj = step = pl.program_id(3)
     nk = pl.num_programs(3)
-    @pl.when(kj == 0)
+    if band is not None:
+        kj = step + _first_kv_block(
+            qi, (qoff_ref, kvoff_ref, kvlen_ref), band
+        )
+
+    @pl.when(step == 0)
     def _init():
         acc_ref[:, :, :] = jnp.zeros_like(acc_ref)
         m_ref[:, :, :] = jnp.full_like(m_ref, _NEG_INF)
@@ -526,10 +641,13 @@ def _fwd_kernel(
                 preferred_element_type=jnp.float32,
             )  # [dv, rows]
 
-    _drive_tiles(update, geom, qi, kj, q_len=None, block_q=block_q,
-                 block_k=block_k, causal=causal, masked=masked, tiles=tiles)
+    _in_band(band, kj, band and band.skv_pad // block_k)(lambda: _drive_tiles(
+        update, geom, qi, kj, q_len=None, block_q=block_q, block_k=block_k,
+        causal=causal, masked=masked, tiles=tiles,
+        window=band and band.window,
+    ))
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         for g in range(group):
             l = l_ref[g, :, :]  # [1, block_q]
@@ -572,6 +690,42 @@ class _Plan(NamedTuple):
     # 0: k and v are operands of their own.  r > 0: the packed ``kv`` and
     # the shared ``[.., r]`` key stand in their place (``_k_head``)
     rope: int = 0
+    # query heads that share one K/V head (1: each its own)
+    kv_ratio: int = 1
+    # 0: none.  W > 0: an entry is valid only where ``row - col < W``
+    window: int = 0
+
+    @property
+    def kv_group(self) -> int:
+        """K/V heads in a program's K/V block."""
+        return self.group if self.kv_ratio == 1 else 1
+
+    @property
+    def subs(self) -> int:
+        """Programs (groups of query heads) that share one K/V head."""
+        return self.kv_ratio // self.group if self.kv_ratio > 1 else 1
+
+    @property
+    def kv_steps(self) -> int:
+        """Grid steps of the K/V axis a q block takes (forward, dQ): all
+        K/V blocks, or under a window as many as ``window + block_q - 1``
+        columns in a row can touch wherever they start."""
+        blocks = self.skv_pad // self.block_k
+        if not self.window:
+            return blocks
+        return min(
+            blocks, (self.window + self.block_q - 3) // self.block_k + 2
+        )
+
+    @property
+    def q_steps(self) -> int:
+        """Grid steps of the q axis a K/V block takes (dK/dV)."""
+        blocks = self.sq_pad // self.block_q
+        if not self.window:
+            return blocks
+        return min(
+            blocks, (self.window + self.block_k - 3) // self.block_q + 2
+        )
 
     def pad_seq(self, x, s: int, s_pad: int):
         if s_pad != s:
@@ -586,22 +740,27 @@ class _Plan(NamedTuple):
             sq=self.sq, skv=self.skv, sq_pad=self.sq_pad,
             skv_pad=self.skv_pad, block_q=self.block_q,
             block_k=self.block_k, tq=tq, tk=tk, guard_q_pad=guard_q_pad,
+            window=self.window or None,
         )
 
 
 def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
-          interpret: Optional[bool], n_heads: int, rope: int = 0) -> _Plan:
+          interpret: Optional[bool], n_heads: int, rope: int = 0,
+          n_kv_heads: int = 0, window: int = 0) -> _Plan:
     packed = n_heads > 0
     if packed:
         b, sq, hd = q.shape
         h = n_heads
         d = hd // h
-        dv = k.shape[2] // h - (d - rope) if rope else v.shape[2] // h
+        h_kv = n_kv_heads or h
+        dv = k.shape[2] // h - (d - rope) if rope else v.shape[2] // h_kv
         skv = k.shape[1]
     else:
         b, h, sq, d = q.shape
+        h_kv = k.shape[1]
         dv = v.shape[3]
         skv = k.shape[2]
+    kv_ratio = h // h_kv
     if interpret is None:
         interpret = _use_interpret()
     block_q = min(block_q, _round_up(sq, 8))
@@ -615,8 +774,8 @@ def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
     return _Plan(
         packed, b, h, d, sq, skv, block_q, block_k,
         _round_up(sq, block_q), skv_pad,
-        _head_group(h, block_q, block_k, d, packed, dv, rope), tiles,
-        interpret, dv, rope,
+        _head_group(h, block_q, block_k, d, packed, dv, rope, kv_ratio),
+        tiles, interpret, dv, rope, kv_ratio, window,
     )
 
 
@@ -647,8 +806,48 @@ def _first_q_block(kj, geom, p: _Plan):
     return jnp.clip(before, 0, p.sq_pad - 1) // p.block_q
 
 
+def _first_kv_block(qi, geom, p: _Plan):
+    """Index of the first K/V block that q block ``qi`` of a windowed call
+    can see: ``_last_kv_block``'s twin at the band's lower edge.  Step 0 of
+    the K/V grid axis is this block, so the blocks left of the band get
+    neither a copy nor a grid step."""
+    lowest = geom[0][0, 0] + qi * p.block_q - (p.window - 1) - geom[1][0, 0]
+    return jnp.clip(lowest, 0, p.skv_pad - 1) // p.block_k
+
+
+def _last_q_block(kj, geom, p: _Plan):
+    """Index of the last q block that sees K/V block ``kj`` of a windowed
+    call: ``_first_q_block``'s twin at the band's lower edge."""
+    highest = (geom[1][0, 0] + (kj + 1) * p.block_k - 1 + (p.window - 1)
+               - geom[0][0, 0])
+    return jnp.clip(highest, 0, p.sq_pad - 1) // p.block_q
+
+
 def _vspec(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
+
+
+def _kind_params(p: _Plan) -> dict:
+    """The kernels' static parameters that a grouped or windowed call
+    sets."""
+    return dict(kv_shared=p.kv_ratio > 1, band=p if p.window else None)
+
+
+def _compiler_params(p: _Plan):
+    semantics = ("parallel", "parallel", "parallel", "arbitrary")
+    if p.kv_ratio > 1:
+        return pltpu.CompilerParams(
+            dimension_semantics=semantics,
+            vmem_limit_bytes=_GROUPED_VMEM_LIMIT,
+        )
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+def _kernel_name(base: str, p: _Plan) -> str:
+    """A windowed call's kernels carry ``_window`` after the name every
+    call's carry, so a model's window and full layers are told apart in a
+    device trace and a reader that asks by prefix finds both."""
+    return base + "_window" if p.window else base
 
 
 def _grid_spec(causal: bool, *, grid, in_specs, out_specs, scratch_shapes):
@@ -685,6 +884,8 @@ def _fwd_pallas(
     n_heads: int = 0,
     static_offsets: Optional[Tuple[int, int]] = None,
     rope: int = 0,
+    n_kv_heads: int = 0,
+    window: int = 0,
 ):
     """Run the kernel.
 
@@ -703,17 +904,21 @@ def _fwd_pallas(
     ``rope`` (packed mode only): k is the packed ``kv`` ``[B,Skv,H*(n+Dv)]``
     and v the shared key ``[B,Skv,rope]`` (``_k_head``).
 
+    ``n_kv_heads`` (packed mode; head-major K/V carry their own head
+    axis): k/v hold that many heads, each shared by ``H / n_kv_heads``
+    query heads.  ``window``: see :func:`flash_attention_with_lse`.
+
     ``static_offsets``: the two offsets where the caller gave Python
     ints, for the build-time tile counters only.
     """
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-              interpret=interpret, n_heads=n_heads, rope=rope)
+              interpret=interpret, n_heads=n_heads, rope=rope,
+              n_kv_heads=n_kv_heads, window=window)
     if causal:
         _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
     if p.dv != p.d:
         _registry.always().counter("flash.calls.split_widths").inc()
-    if rope:
-        _registry.always().counter("flash.calls.latent_kv").inc()
+    _book_call_kinds(p, 1)
     return _flash_fwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv),
         p=p, sm_scale=sm_scale, causal=causal,
@@ -742,7 +947,12 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
     def kv_block(qi, kj, geom):
         if not causal:
             return kj
+        if p.window:
+            kj = kj + _first_kv_block(qi, geom, p)
         return jnp.minimum(kj, _last_kv_block(qi, geom, p))
+
+    def kv_head(hi):
+        return hi if p.kv_ratio == 1 else hi // p.subs
 
     def q_side(width):
         if p.packed:
@@ -758,14 +968,14 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
     def kv_side(width, shared=False):
         if p.packed:
             return _vspec(
-                (1, block_k, width if shared else group * width),
+                (1, block_k, width if shared else p.kv_group * width),
                 lambda bi, hi, qi, kj, *geom: (
-                    bi, kv_block(qi, kj, geom), 0 if shared else hi),
+                    bi, kv_block(qi, kj, geom), 0 if shared else kv_head(hi)),
             )
         return _vspec(
-            (1, group, block_k, width),
+            (1, p.kv_group, block_k, width),
             lambda bi, hi, qi, kj, *geom: (
-                bi, hi, kv_block(qi, kj, geom), 0),
+                bi, kv_head(hi), kv_block(qi, kj, geom), 0),
         )
 
     o_shape = jax.ShapeDtypeStruct(
@@ -776,11 +986,11 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, causal=causal,
             masked=causal or skv_pad != p.skv, tiles=p.tiles,
-            packed=p.packed, d=d, dv=dv, rope=rope,
+            packed=p.packed, d=d, dv=dv, rope=rope, **_kind_params(p),
         ),
         grid_spec=_grid_spec(
             causal,
-            grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
+            grid=(b, h // group, sq_pad // block_q, p.kv_steps),
             in_specs=[q_side(d)] + (
                 [kv_side(d - rope + dv), kv_side(rope, shared=True)] if rope
                 else [kv_side(d), kv_side(dv)]
@@ -804,9 +1014,7 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         ],
         # batch/head/qi programs are independent; only the K/V stream (kj)
         # carries state — lets Mosaic parallelize/pipeline the outer grid.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_compiler_params(p),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * h * sq_pad * skv_pad * (d + dv),
             bytes_accessed=(qr.size + kr.size + vr.size) * qr.dtype.itemsize
@@ -814,7 +1022,7 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
             transcendentals=b * h * sq_pad * skv_pad,
         ),
         interpret=p.interpret,
-        name="hvd_flash_fwd",
+        name=_kernel_name("hvd_flash_fwd", p),
     )(*geom, qr, kr, vr)
 
     with jax.named_scope(_GLUE_SCOPE):
@@ -878,7 +1086,7 @@ def _dkv_streams_thin(d: int) -> bool:
 def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
                     g_ref, g, rq, rk, valid, *, sm_scale: float,
                     packed: bool = False, d: int = 0, dv: int = 0,
-                    rope: int = 0):
+                    rope: int = 0, kv_shared: bool = False):
     """Shared per-(q rows ``rq``, K/V rows ``rk``, head) recompute:
     returns (pᵀ, dsᵀ, q_blk, g_blk, k_blk), the two score-sized arrays
     keys-by-queries, ``[cols, rows]``, like ``valid`` (``None`` is the
@@ -890,7 +1098,7 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
     """
     # Storage-dtype (bf16) matmul inputs with fp32 accumulation — see the
     # forward kernel note; only the softmax/ds algebra runs in fp32.
-    heads = dict(packed=packed, d=d, dv=dv, rope=rope)
+    heads = dict(packed=packed, d=d, dv=dv, rope=rope, kv_shared=kv_shared)
     q_blk = _head(q_ref, g, d, packed, rq)
     g_blk = _head(g_ref, g, dv, packed, rq)
     k_blk = _k_head(k_ref, v_ref, g, rk, **heads)
@@ -931,7 +1139,8 @@ def _bwd_kernel_dkdv(
     *shared_acc,
     sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
-    rope: int = 0,
+    rope: int = 0, kv_shared: bool = False, q_steps: int = 0,
+    band: Optional[_Plan] = None,
 ):
     """grid (b, h-group, kj, qi): each K tile accumulates over streamed
     Q blocks; the per-head loop is a static unroll (see forward).  Heads
@@ -949,15 +1158,28 @@ def _bwd_kernel_dkdv(
     from the group's heads adds up, in float32, in one more accumulator of
     width ``rope`` (``shared_acc``), and dv_ref, ``[1, 1, rope, block_k]``
     float32, takes that partial sum: one a head group, the groups summed
-    outside."""
+    outside.
+
+    With ``kv_shared`` (query groups) the K/V block, dk_ref, dv_ref and the
+    two accumulators are ONE K/V head's, and what the program's query heads
+    give it adds up in the accumulators.  Where a K/V head's query heads
+    take several programs (``q_steps`` > 0: the q blocks a program streams)
+    they follow one another along the last grid axis, ``(heads' program, q
+    block)`` merged, and the accumulators run on through all of them.  With
+    ``band`` (windowed) step 0 of a program's q blocks is the K/V block's
+    first (``_first_q_block``) and the axis ends with the band."""
     n = d - rope
-    qi = pl.program_id(3)
+    qi = step = pl.program_id(3)
     kj = pl.program_id(2)
     nq = pl.num_programs(3)
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
+    if q_steps:
+        qi = lax.rem(step, q_steps)
+    if band is not None:
+        qi = qi + _first_q_block(kj, (qoff_ref, kvoff_ref, kvlen_ref), band)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         for acc in (dk_acc, dv_acc) + shared_acc:
             acc[:, :, :] = jnp.zeros_like(acc)
@@ -987,26 +1209,31 @@ def _bwd_kernel_dkdv(
             p_t, ds_t, q_blk, g_blk, _ = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
-                dv=dv, rope=rope,
+                dv=dv, rope=rope, kv_shared=kv_shared,
             )
-            accumulate(dv_acc, g, rk, p_t, g_blk)
+            to = 0 if kv_shared else g  # the accumulators' head
+            accumulate(dv_acc, to, rk, p_t, g_blk)
             ds_t = accumulate(
-                dk_acc, g, rk, ds_t, q_blk[:, :n] if rope else q_blk, sm_scale
+                dk_acc, to, rk, ds_t, q_blk[:, :n] if rope else q_blk,
+                sm_scale,
             )
             if rope:
                 accumulate(shared_acc[0], 0, rk, ds_t, q_blk[:, n:], sm_scale)
 
-    _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
-                 block_k=block_k, causal=causal, masked=masked, tiles=tiles)
+    _in_band(band, qi, band and band.sq_pad // block_q)(lambda: _drive_tiles(
+        update, geom, qi, kj, q_len=q_len, block_q=block_q, block_k=block_k,
+        causal=causal, masked=masked, tiles=tiles,
+        window=band and band.window,
+    ))
 
     def grad(acc, g, width):
         """Head ``g`` of an accumulator as ``[block_k, width]``."""
         out = acc[g, :, :]
         return out.T if _dkv_streams_thin(width) else out
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
-        for g in range(group):
+        for g in range(1 if kv_shared else group):
             if rope:  # [dk_nope | dv] side by side, as kv holds the head
                 head = g * (n + dv)
                 for acc, lo, width in ((dk_acc, head, n), (dv_acc, head + n, dv)):
@@ -1029,18 +1256,23 @@ def _bwd_kernel_dq(
     q_ref, k_ref, v_ref, g_ref, dq_ref, dq_acc,
     *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
-    rope: int = 0,
+    rope: int = 0, kv_shared: bool = False, band: Optional[_Plan] = None,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
-    static unroll (see forward)."""
+    static unroll (see forward).  ``kv_shared`` / ``band``: as the
+    forward."""
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    kj = step = pl.program_id(3)
     nk = pl.num_programs(3)
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
+    if band is not None:
+        kj = step + _first_kv_block(
+            qi, (qoff_ref, kvoff_ref, kvlen_ref), band
+        )
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:, :, :] = jnp.zeros_like(dq_acc)
 
@@ -1049,7 +1281,7 @@ def _bwd_kernel_dq(
             _, ds_t, _, _, k_blk = _recompute_p_ds(
                 lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
-                dv=dv, rope=rope,
+                dv=dv, rope=rope, kv_shared=kv_shared,
             )
             dq_acc[g, :, rq] = dq_acc[g, :, rq] + jax.lax.dot_general(
                 k_blk, ds_t.astype(k_blk.dtype),
@@ -1057,10 +1289,13 @@ def _bwd_kernel_dq(
                 preferred_element_type=jnp.float32,
             ) * sm_scale  # [d, rows]
 
-    _drive_tiles(update, geom, qi, kj, q_len=q_len, block_q=block_q,
-                 block_k=block_k, causal=causal, masked=masked, tiles=tiles)
+    _in_band(band, kj, band and band.skv_pad // block_k)(lambda: _drive_tiles(
+        update, geom, qi, kj, q_len=q_len, block_q=block_q, block_k=block_k,
+        causal=causal, masked=masked, tiles=tiles,
+        window=band and band.window,
+    ))
 
-    @pl.when(kj == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         for g in range(group):
             _head_store(
@@ -1073,9 +1308,11 @@ def _bwd_pallas(
     sm_scale: float, causal: bool, block_q: int, block_k: int,
     interpret: Optional[bool], n_heads: int = 0,
     static_offsets: Optional[Tuple[int, int]] = None, rope: int = 0,
+    n_kv_heads: int = 0, window: int = 0,
 ):
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-              interpret=interpret, n_heads=n_heads, rope=rope)
+              interpret=interpret, n_heads=n_heads, rope=rope,
+              n_kv_heads=n_kv_heads, window=window)
     if causal:
         # one count for each of the two kernels
         for _ in range(2):
@@ -1085,8 +1322,7 @@ def _bwd_pallas(
     accumulated = (p.d - rope, p.dv) + ((rope,) if rope else ())
     if any(_dkv_streams_thin(width) for width in accumulated):
         _registry.always().counter("flash.dkv.thin_streamed").inc()
-    if rope:
-        _registry.always().counter("flash.calls.latent_kv").inc(2)
+    _book_call_kinds(p, 2)
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
         g_lse, p=p, sm_scale=sm_scale, causal=causal,
@@ -1140,12 +1376,10 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         sm_scale=sm_scale, causal=causal,
         masked=causal or skv_pad != skv or sq_pad != sq,
         tiles=p.tiles, q_len=sq, packed=p.packed, d=d, dv=dv, rope=rope,
+        **_kind_params(p),
     )
     call_params = dict(
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")
-        ),
+        compiler_params=_compiler_params(p),
         interpret=p.interpret,
     )
     def specs(order):
@@ -1159,60 +1393,82 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
 
         def blocks(i, j, geom):
             qi, kj = (j, i) if order == "kq" else (i, j)
-            if causal and order == "kq":
+            if order == "kq" and p.subs > 1:
+                qi = lax.rem(qi, p.q_steps)
+            if causal and order == "kq" and p.window:
+                qi = jnp.minimum(qi + _first_q_block(kj, geom, p),
+                                 _last_q_block(kj, geom, p))
+            elif causal and order == "kq":
                 qi = jnp.maximum(qi, _first_q_block(kj, geom, p))
             elif causal:
+                if p.window:
+                    kj = kj + _first_kv_block(qi, geom, p)
                 kj = jnp.minimum(kj, _last_kv_block(qi, geom, p))
             return qi, kj
 
+        def heads(hi, j):
+            """(query heads' program, K/V head or group) of a grid step
+            whose second index is ``hi``: the same unless query heads
+            share K/V heads; dK/dV's grid then runs over the K/V heads and
+            a head's programs follow one another along its last axis."""
+            if p.kv_ratio == 1 or p.subs == 1:
+                return hi, hi
+            if order == "kq":
+                return hi * p.subs + j // p.q_steps, hi
+            return hi, hi // p.subs
+
         def q_map(bi, hi, i, j, *geom):
             qi, _ = blocks(i, j, geom)
+            hi, _ = heads(hi, j)
             return (bi, qi, hi) if p.packed else (bi, hi, qi, 0)
 
         def kv_map(bi, hi, i, j, *geom):
             _, kj = blocks(i, j, geom)
+            _, hi = heads(hi, j)
             return (bi, kj, hi) if p.packed else (bi, hi, kj, 0)
 
         def stat_map(bi, hi, i, j, *geom):
             qi, _ = blocks(i, j, geom)
+            hi, _ = heads(hi, j)
             return (bi, hi, 0, qi)
 
         def shared_map(bi, hi, i, j, *geom):
             _, kj = blocks(i, j, geom)
             return (bi, kj, 0)
 
-        def block(rows, width, index_map):
+        def block(rows, width, index_map, heads=group):
             return _vspec(
-                (1, rows, group * width) if p.packed
-                else (1, group, rows, width), index_map,
+                (1, rows, heads * width) if p.packed
+                else (1, heads, rows, width), index_map,
             )
 
         return (
             _vspec((1, group, 8, block_q), stat_map),
             block(block_q, d, q_map),
-            block(block_k, n + dv if rope else d, kv_map),
+            block(block_k, n + dv if rope else d, kv_map, p.kv_group),
             _vspec((1, block_k, rope), shared_map) if rope
-            else block(block_k, dv, kv_map),
+            else block(block_k, dv, kv_map, p.kv_group),
             block(block_q, dv, q_map),
         )
 
-    def shape_like(x, s_pad, width):
+    def shape_like(x, s_pad, width, heads=h):
         return jax.ShapeDtypeStruct(
-            (b, s_pad, h * width) if p.packed else (b, h, s_pad, width),
-            x.dtype,
+            (b, s_pad, heads * width) if p.packed
+            else (b, heads, s_pad, width), x.dtype,
         )
 
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
     stat_spec, q_spec, k_spec, v_spec, g_spec = specs("kq")
 
-    def dkv_acc(width, heads=group):
+    def dkv_acc(width, heads=p.kv_group):
         return _VMEM(
             (heads, width, block_k) if _dkv_streams_thin(width)
             else (heads, block_k, width), jnp.float32,
         )
 
+    h_kv = h // p.kv_ratio
     dkv_out_specs, dkv_out_shape = [k_spec, v_spec], [
-        shape_like(k, skv_pad, d), shape_like(v, skv_pad, dv)
+        shape_like(k, skv_pad, d, h_kv), shape_like(v, skv_pad, dv, h_kv)
     ]
     if rope:
         # [dk_nope | dv] in kv's layout, and the shared key's gradient as
@@ -1228,10 +1484,14 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             ),
         ]
     grad_k, grad_v = pl.pallas_call(
-        functools.partial(_bwd_kernel_dkdv, **kernel_params),
+        functools.partial(
+            _bwd_kernel_dkdv, **kernel_params,
+            q_steps=p.q_steps if p.subs > 1 else 0,
+        ),
         grid_spec=_grid_spec(
             causal,
-            grid=(b, h // group, skv_pad // block_k, sq_pad // block_q),
+            grid=(b, h // group // p.subs, skv_pad // block_k,
+                  p.subs * p.q_steps),
             in_specs=[stat_spec, stat_spec, stat_spec,
                       q_spec, k_spec, v_spec, g_spec],
             out_specs=dkv_out_specs,
@@ -1241,7 +1501,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         ),
         out_shape=dkv_out_shape,
         **call_params,
-        name="hvd_flash_bwd_dkv",
+        name=_kernel_name("hvd_flash_bwd_dkv", p),
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
     if rope:
         with jax.named_scope(_GLUE_SCOPE):
@@ -1253,7 +1513,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         functools.partial(_bwd_kernel_dq, **kernel_params),
         grid_spec=_grid_spec(
             causal,
-            grid=(b, h // group, sq_pad // block_q, skv_pad // block_k),
+            grid=(b, h // group, sq_pad // block_q, p.kv_steps),
             in_specs=[stat_spec, stat_spec, stat_spec,
                       q_spec, k_spec, v_spec, g_spec],
             out_specs=q_spec,
@@ -1261,7 +1521,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         ),
         out_shape=shape_like(q, sq_pad, d),
         **call_params,
-        name="hvd_flash_bwd_dq",
+        name=_kernel_name("hvd_flash_bwd_dq", p),
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
 
     with jax.named_scope(_GLUE_SCOPE):
@@ -1279,10 +1539,11 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13, 14)
 )
 def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-           interpret, n_heads=0, static_offsets=None, rope=0):
+           interpret, n_heads=0, static_offsets=None, rope=0, n_kv_heads=0,
+           window=0):
     """``(out, lse)`` with the exact backward.  With ``rope`` the operands
     ``k`` and ``v`` are the packed ``kv`` and the shared key (``_k_head``),
     and so are their cotangents."""
@@ -1300,20 +1561,23 @@ def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         n_heads=n_heads,
         static_offsets=static_offsets,
         rope=rope,
+        n_kv_heads=n_kv_heads,
+        window=window,
     )
 
 
 def _flash_fwd(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
-               block_k, interpret, n_heads=0, static_offsets=None, rope=0):
+               block_k, interpret, n_heads=0, static_offsets=None, rope=0,
+               n_kv_heads=0, window=0):
     out, lse = _flash(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-        interpret, n_heads, static_offsets, rope
+        interpret, n_heads, static_offsets, rope, n_kv_heads, window
     )
     return (out, lse), (q, k, v, q_offset, kv_offset, out, lse)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
-               static_offsets, rope, res, g):
+               static_offsets, rope, n_kv_heads, window, res, g):
     q, k, v, q_offset, kv_offset, out, lse = res
     g_out, g_lse = g
     dq, dk, dv = _bwd_pallas(
@@ -1334,6 +1598,8 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
         n_heads=n_heads,
         static_offsets=static_offsets,
         rope=rope,
+        n_kv_heads=n_kv_heads,
+        window=window,
     )
     # Integer offsets take float0 cotangents.
     zero = np.zeros((), dtype=jax.dtypes.float0)
@@ -1349,7 +1615,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
-                block_k, interpret, n_heads, rope=0):
+                block_k, interpret, n_heads, rope=0, n_kv_heads=0,
+                window=None):
     """``_flash`` on a public entry's arguments: ``(out, lse)``."""
     # Offsets given as Python ints (the model path: 0, 0) are also kept
     # static, for the build-time tile counters; the kernels read the
@@ -1357,6 +1624,13 @@ def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
     static_offsets = None
     if all(isinstance(x, (int, np.integer)) for x in (q_offset, kv_offset)):
         static_offsets = (int(q_offset), int(kv_offset))
+    window = int(window or 0)
+    if window and static_offsets is not None:
+        # a window no row reaches the edge of is no window: the call then
+        # traces what the causal call traces
+        sq = q.shape[1 if n_heads else 2]
+        if window >= static_offsets[0] + sq - static_offsets[1]:
+            window = 0
     return _flash(
         q,
         k,
@@ -1371,6 +1645,8 @@ def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
         int(n_heads),
         static_offsets,
         rope,
+        int(n_kv_heads),
+        window,
     )
 
 
@@ -1388,6 +1664,8 @@ def flash_attention_with_lse(
     interpret: Optional[bool] = None,
     layout: str = "bshd",
     n_heads: int = 0,
+    n_kv_heads: int = 0,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Blockwise attention returning ``(out, lse)``.
 
@@ -1408,17 +1686,66 @@ def flash_attention_with_lse(
     attention across K/V shards (:func:`combine_blocks`) and to run the
     exact backward.  ``q_offset``/``kv_offset`` are the global positions
     of row 0 (may be traced), used only for causal masking.
+
+    Query groups: k and v may hold fewer heads than q, ``H % H_kv == 0``;
+    query head ``n`` then reads K/V head ``n // (H / H_kv)``.  In the
+    head-major layouts K and V's own head axis says so; in ``"bsm"`` pass
+    ``n_kv_heads`` (k / v are ``[B, Skv, H_kv * D]``).  A program's K/V
+    block is ONE K/V head's, read once for the query heads of the program,
+    and dK / dV add up over the heads that share it in the kernel's float32
+    accumulator: no K, V, dK or dV of ``H`` heads exists in HBM.
+
+    ``window`` (needs ``causal=True``): row ``i`` sees column ``j`` only
+    where ``0 <= i - j < window`` in global positions.  Tiles and blocks
+    wholly outside the band are neither visited nor copied nor given a grid
+    step, in all three passes; a window that no row reaches the edge of
+    (``window >= q_offset + Sq - kv_offset``, static offsets) traces the
+    causal call.  The windowed kernels' names end in ``_window``.
     """
     packed = layout == "bsm"
     if packed and n_heads <= 0:
         raise ValueError("layout='bsm' requires n_heads")
-    if packed and any(
-        (x.shape[-1] // n_heads) % 64 != 0 for x in (q, v)
-    ) and not (interpret if interpret is not None else _use_interpret()):
+    if layout not in ("bshd", "bhsd", "bsm"):
+        raise ValueError(
+            f"layout must be 'bshd', 'bhsd' or 'bsm', got {layout!r}"
+        )
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window} needs causal=True and window >= 1 (got "
+            f"causal={causal}; q {q.shape}, k {k.shape})"
+        )
+    head_axis = 2 if layout == "bshd" else 1
+    h = n_heads if packed else q.shape[head_axis]
+    h_kv = (n_kv_heads or n_heads) if packed else k.shape[head_axis]
+    if not packed and n_kv_heads and n_kv_heads != h_kv:
+        raise ValueError(
+            f"n_kv_heads={n_kv_heads} is for layout='bsm'; in "
+            f"layout={layout!r} k {k.shape} carries its own head axis "
+            f"({h_kv} heads)"
+        )
+    if h % h_kv or (packed and (k.shape[-1] % h_kv or v.shape[-1] % h_kv)):
+        raise ValueError(
+            f"{h} query heads (q {q.shape}) do not share {h_kv} K/V heads "
+            f"(k {k.shape}, v {v.shape}) evenly: n_heads % n_kv_heads != 0"
+        )
+    compiled = not (interpret if interpret is not None else _use_interpret())
+    if packed and compiled and any(
+        (x.shape[-1] // heads) % 64 != 0
+        for x, heads in ((q, h), (v, h_kv))
+    ):
         raise ValueError(
             "layout='bsm' needs head_dim % 64 == 0 on TPU (Mosaic lane "
             f"slicing is 64-aligned); got head_dim="
-            f"{q.shape[-1] // n_heads} (v: {v.shape[-1] // n_heads}) — use "
+            f"{q.shape[-1] // h} (v: {v.shape[-1] // h_kv}) — use "
+            "layout='bhsd'"
+        )
+    if packed and compiled and 1 < h_kv < h and any(
+        (x.shape[-1] // h_kv) % _LANES for x in (k, v)
+    ):
+        raise ValueError(
+            "layout='bsm' with query groups needs K/V heads that are "
+            f"multiples of {_LANES} wide on TPU (one head is a block's lane "
+            f"axis); got k {k.shape}, v {v.shape} for {h_kv} heads — use "
             "layout='bhsd'"
         )
     if sm_scale is None:
@@ -1429,13 +1756,10 @@ def flash_attention_with_lse(
             q = jnp.moveaxis(q, 2, 1)
             k = jnp.moveaxis(k, 2, 1)
             v = jnp.moveaxis(v, 2, 1)
-    elif layout not in ("bhsd", "bsm"):
-        raise ValueError(
-            f"layout must be 'bshd', 'bhsd' or 'bsm', got {layout!r}"
-        )
     out, lse = _call_flash(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         interpret, n_heads if packed else 0,
+        n_kv_heads=h_kv if packed and h_kv != h else 0, window=window,
     )
     if layout == "bshd":
         with jax.named_scope(_GLUE_SCOPE):
@@ -1456,9 +1780,12 @@ def flash_attention(
     interpret: Optional[bool] = None,
     layout: str = "bshd",
     n_heads: int = 0,
+    n_kv_heads: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Drop-in memory-efficient replacement for
-    ``models.transformer.dot_product_attention`` (same signature shape).
+    ``models.transformer.dot_product_attention`` (same signature shape);
+    ``n_kv_heads`` and ``window`` as :func:`flash_attention_with_lse`.
 
     Dense ``mask`` is not supported by the blockwise kernel — callers that
     need one fall back to the XLA path.
@@ -1479,6 +1806,8 @@ def flash_attention(
         interpret=interpret,
         layout=layout,
         n_heads=n_heads,
+        n_kv_heads=n_kv_heads,
+        window=window,
     )
     return out
 

@@ -18,6 +18,7 @@ join ``x`` before :func:`local_experts` and leave after it.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -205,24 +206,35 @@ def switch_moe_stacked(
 # ---------------------------------------------------------------------------
 
 
-def topk_route(x, router_kernel, score_bias, *, top_k: int, scale: float):
-    """Sigmoid top-k routing over ALL experts of the layer (``noaux_tc``).
+def topk_route(x, router_kernel, score_bias, *, top_k: int,
+               scale: float = 1.0, scoring: str = "sigmoid"):
+    """Top-k routing over ALL experts of the layer, the scores in float32
+    at full matmul precision.
 
-    ``s = sigmoid(x W_r)`` in float32 at full matmul precision; the ``top_k``
-    largest of ``s + score_bias`` are chosen (the bias steers the choice
-    only and takes no gradient); their weights are ``s_sel / sum(s_sel) *
-    scale``.
+    ``scoring="sigmoid"`` (``noaux_tc``): ``s = sigmoid(x W_r)``; the
+    ``top_k`` largest of ``s + score_bias`` are chosen (the bias steers the
+    choice only and takes no gradient); their weights are ``s_sel /
+    sum(s_sel) * scale``.  ``scoring="softmax"``: the ``top_k`` largest of
+    the logits ``x W_r`` (``+ score_bias``, which may be ``None``) are
+    chosen and their weights are the softmax over the CHOSEN logits, times
+    ``scale``: the softmax over all experts renormalised over the chosen
+    (``norm_topk_prob``), in which the other logits cancel.
 
     Args: x ``[T, D]``; router_kernel ``[D, E]``; score_bias ``[E]``.
     Returns: expert ids ``[T, top_k]`` int32, weights ``[T, top_k]`` fp32.
     """
-    scores = jax.nn.sigmoid(jnp.dot(
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring must be 'sigmoid' or 'softmax': {scoring!r}")
+    scores = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
-    ))
-    _, chosen = lax.top_k(
-        scores + lax.stop_gradient(score_bias.astype(jnp.float32)), top_k
     )
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
+    steered = scores if score_bias is None else scores + lax.stop_gradient(
+        score_bias.astype(jnp.float32)
+    )
+    _, chosen = lax.top_k(steered, top_k)
     # s[t, chosen[t, j]] as a masked sum: vectorised both ways, where a
     # take_along_axis is T k scalar gathers and as many scatter-adds back
     picked = jnp.einsum(
@@ -230,7 +242,10 @@ def topk_route(x, router_kernel, score_bias, *, top_k: int, scale: float):
         jax.nn.one_hot(chosen, scores.shape[-1], dtype=jnp.float32), scores,
         precision=lax.Precision.HIGHEST,
     )
-    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    if scoring == "softmax":
+        weights = jax.nn.softmax(picked, axis=-1) * scale
+    else:
+        weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
     return chosen.astype(jnp.int32), weights
 
 
@@ -273,8 +288,11 @@ def _gate_up_bwd(residuals, cotangents):
 _gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
 
 
-@jax.checkpoint
-def _held_experts(x, share, gate, up, down):
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+@functools.partial(jax.checkpoint, static_argnums=(5,))
+def _held_experts(x, share, gate, up, down, activation="silu"):
     """``sum_e share[t, e] E_e(x[t])``. Nothing of it is kept for the
     backward but its arguments: the two ``[held, T, F]`` matmul outputs
     are computed again, which costs two matmuls and saves a layer of
@@ -283,17 +301,19 @@ def _held_experts(x, share, gate, up, down):
     gated, raised = _gate_up(x, as_x(gate), as_x(up))
     return jnp.einsum(
         "etf,efd->td",
-        jax.nn.silu(gated) * raised * as_x(share).T[..., None], as_x(down),
+        _ACTIVATIONS[activation](gated) * raised * as_x(share).T[..., None],
+        as_x(down),
     )
 
 
 def local_experts(x, chosen, weights, gate, up, down, *, first_expert: int,
-                  n_experts: int):
+                  n_experts: int, activation: str = "silu"):
     """The part of a top-k expert layer that the experts held here give:
     ``y[t] = sum_j weights[t, j] E_{chosen[t, j]}(x[t])`` over the choices
     whose expert is one of ``first_expert .. first_expert + held - 1``,
-    ``E(x) = W_d (silu(W_g x) * W_u x)``. What the other experts would add
-    is left out. No token is dropped, and the work is the same whatever
+    ``E(x) = W_d (act(W_g x) * W_u x)``, ``act`` the static ``activation``:
+    ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU). What the other experts would
+    add is left out. No token is dropped, and the work is the same whatever
     was chosen (see the section comment above).
 
     Args:
@@ -307,6 +327,10 @@ def local_experts(x, chosen, weights, gate, up, down, *, first_expert: int,
     the matmuls compute, ``held x T``) and ``moe.rows_expected`` (the rows
     the router is expected to fill, ``T k held / experts``).
     """
+    if activation not in _ACTIVATIONS:
+        raise ValueError(
+            f"activation must be one of {sorted(_ACTIVATIONS)}: {activation!r}"
+        )
     n_tokens, top_k = chosen.shape
     n_held = gate.shape[0]
     reg = _registry.always()
@@ -321,4 +345,4 @@ def local_experts(x, chosen, weights, gate, up, down, *, first_expert: int,
             jax.nn.one_hot(chosen - first_expert, n_held, dtype=jnp.float32)
             * weights.astype(jnp.float32)[..., None], axis=1,
         )
-        return _held_experts(x, share, gate, up, down)
+        return _held_experts(x, share, gate, up, down, activation)

@@ -1,4 +1,4 @@
-"""Tiny builds of the three model families and a walk over a traced step,
+"""Tiny builds of the four model families and a walk over a traced step,
 for the tests that hold the models' parts (``jax.named_scope``) to their
 rules: ``test_step_tracing.py`` on the CPU, ``test_tpu_compile.py`` compiled
 for the described v5e. Widths are the smallest the compiled kernels take
@@ -95,6 +95,28 @@ def _latent_moe():
     return "LatentMoELM", build
 
 
+def _window_moe():
+    from horovod_tpu.models.window_moe import (
+        WindowMoEConfig, WindowMoELM, lm_loss,
+    )
+
+    def build(use_flash):
+        # heads of 128: a packed K/V block of ONE head fills the lanes
+        cfg = WindowMoEConfig.tiny(
+            d_model=128, n_heads=4, n_kv_heads=2, head_dim=128, window=64,
+            use_flash=use_flash,
+        )
+        model = WindowMoELM(cfg)
+
+        def loss(model, params, tokens):
+            logits = model.apply({"params": params}, tokens[:, :-1])
+            return lm_loss(logits, None, tokens, mtp_weight=0.0)
+
+        return model, _lm(model, loss), {"tokens": (SEQ + 1,)}
+
+    return "WindowMoELM", build
+
+
 # id -> (family, use_flash); the golden parameter paths are per family
 CASES = {
     "gpt2-flash": ("gpt2", True),
@@ -104,6 +126,8 @@ CASES = {
     "bert-cls-padded": ("bert_cls", False),
     "latent-moe-flash": ("latent_moe", True),
     "latent-moe-xla": ("latent_moe", False),
+    "window-moe-flash": ("window_moe", True),
+    "window-moe-xla": ("window_moe", False),
 }
 _FAMILIES = {
     "gpt2": lambda: _gpt2(),
@@ -111,6 +135,7 @@ _FAMILIES = {
     "bert_mlm": lambda: _bert(None),
     "bert_cls": lambda: _bert(2),
     "latent_moe": _latent_moe,
+    "window_moe": _window_moe,
 }
 
 

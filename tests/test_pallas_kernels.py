@@ -383,38 +383,58 @@ def test_causal_tiles_match_reference(geometry, offsets):
         (40, 0, 64, 200, 64, 16),
     ],
 )
+@pytest.mark.parametrize("window", [None, 5, 16, 40, 64, 1000])
 def test_tile_classes_against_brute_force(q_offset, kv_offset, sq, skv,
-                                          block, tile):
+                                          block, tile, window):
     """No tile classed interior holds a masked entry (or, under the
-    backward's guard, a padded q row); no skipped tile holds a valid
-    one; the counts are the classifier's."""
+    backward's guard, a padded q row); no skipped tile, beyond the
+    diagonal or left of a window's band, holds a valid one; the interior
+    tiles lie together among the visited; the counts are the
+    classifier's.  Windows smaller than a tile, of a whole number of tiles
+    and blocks, of neither, and wider than the sequence."""
     from horovod_tpu.ops.pallas_kernels import _count_tiles, _tile_spans
 
     sq_pad = -(-sq // block) * block
     skv_pad = -(-skv // block) * block
     rows = np.arange(sq_pad)[:, None]
     cols = np.arange(skv_pad)[None, :]
-    valid = (q_offset + rows >= kv_offset + cols) & (cols < skv)
+    ahead = (q_offset + rows) - (kv_offset + cols)
+    valid = (ahead >= 0) & (cols < skv)
+    band = {}
+    if window is not None:
+        valid &= ahead < window
+        band = {"window": window}
     nkt = block // tile
     for guard in (False, True):
         ok = valid & (rows < sq) if guard else valid
         visited = masked = 0
         for q0 in range(0, sq_pad, tile):
             for k0 in range(0, skv_pad, block):
-                n_interior, n_visited = _tile_spans(
+                n_left, n_interior, n_visited = _tile_spans(
                     q_offset + q0, kv_offset + k0, skv - k0,
                     guard and q0 + tile > sq, tq=tile, tk=tile, nkt=nkt,
+                    **band,
                 )
-                assert 0 <= n_interior <= n_visited <= nkt
-                visited += n_visited
+                assert 0 <= n_left <= n_visited <= nkt
+                assert 0 <= n_interior <= n_visited - n_left
+                assert window is not None or n_left == 0
+                visited += n_visited - n_left
                 if n_interior < nkt:  # the q tile's slab is masked whole
-                    masked += n_visited
+                    masked += n_visited - n_left
+                whole = [
+                    ok[q0:q0 + tile, k0 + j * tile:k0 + (j + 1) * tile].all()
+                    for j in range(nkt)
+                ]
+                # as many interior tiles as the brute force finds wholly
+                # valid, or fewer (a tile of valid entries only beside
+                # padding is run masked); never a tile that is not
+                assert n_interior <= sum(whole)
+                if n_interior:
+                    first = whole.index(True)
+                    assert all(whole[first:first + n_interior])
+                    assert n_left <= first < first + n_interior <= n_visited
                 for j in range(nkt):
-                    entries = ok[q0:q0 + tile,
-                                 k0 + j * tile:k0 + (j + 1) * tile]
-                    if j < n_interior:
-                        assert entries.all(), (q0, k0, j)
-                    elif j >= n_visited:
+                    if j < n_left or j >= n_visited:
                         assert not valid[
                             q0:q0 + tile, k0 + j * tile:k0 + (j + 1) * tile
                         ].any(), (q0, k0, j)
@@ -422,7 +442,7 @@ def test_tile_classes_against_brute_force(q_offset, kv_offset, sq, skv,
         assert _count_tiles(
             q_offset, kv_offset, sq=sq, skv=skv, sq_pad=sq_pad,
             skv_pad=skv_pad, block_q=block, block_k=block, tq=tile,
-            tk=tile, guard_q_pad=guard,
+            tk=tile, guard_q_pad=guard, **band,
         ) == (visited, masked, total - visited)
 
 
@@ -1069,3 +1089,320 @@ def test_flash_latent_refuses_shapes_that_are_not_its_layout():
         flash_attention_latent(
             x(48), x(64), x(8), n_heads=2, interpret=False
         )
+
+
+# ---------------------------------------------------------------------------
+# A window (band) and query groups (K/V heads shared by several query heads)
+# ---------------------------------------------------------------------------
+
+
+def _band(sq, skv, window, q_offset=0, kv_offset=0):
+    ahead = (q_offset + np.arange(sq)[:, None]) - (
+        kv_offset + np.arange(skv)[None, :]
+    )
+    return (ahead >= 0) & (True if window is None else ahead < window)
+
+
+def _band_reference(q, k, v, window):
+    """``dot_product_attention`` under the explicit band mask with K and V
+    repeated to the query heads, and the masked log-sum-exp; ``bshd``."""
+    ratio = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, ratio, axis=2) for t in (k, v))
+    mask = jnp.asarray(_band(q.shape[1], k.shape[1], window))
+    out = dot_product_attention(q, k, v, causal=False, mask=mask)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    lse = jax.scipy.special.logsumexp(
+        jnp.where(mask, scores, -1e30), axis=-1
+    )
+    return out, lse
+
+
+def _in_layout(x, layout):
+    if layout == "bsm":
+        return x.reshape(x.shape[:2] + (-1,))
+    return jnp.moveaxis(x, 2, 1) if layout == "bhsd" else x
+
+
+def _from_layout(x, layout, d):
+    if layout == "bsm":
+        return x.reshape(x.shape[:2] + (-1, d))
+    return jnp.moveaxis(x, 1, 2) if layout == "bhsd" else x
+
+
+# name: (s, query heads, K/V heads, head width, window, (block_q, block_k),
+# layout).  Compute tiles are half a block here (the interpreter's rule).
+# Windows smaller than a tile (5 of 16), of a whole number of blocks (64 of
+# 32), of neither (20, 33, 40); s no multiple of the block (100, 200);
+# groups of 7 at width 128 and of 3, 2 and 1; a K/V block wider than the
+# q block and the reverse, so both grids lose steps to the band.
+_BAND_CASES = {
+    "w20-7to1-bsm": (96, 7, 1, 16, 20, (32, 32), "bsm"),
+    "w20-7to1-bhsd": (96, 7, 1, 16, 20, (32, 32), "bhsd"),
+    "w20-7to1-bshd": (96, 7, 1, 16, 20, (32, 32), "bshd"),
+    "w5-14to2-s100-bsm": (100, 14, 2, 16, 5, (32, 32), "bsm"),
+    "w64-6to2-bk64-bsm": (128, 6, 2, 16, 64, (32, 64), "bsm"),
+    "w33-4to4-s200-bq64-bsm": (200, 4, 4, 16, 33, (64, 32), "bsm"),
+    "full-4to2-bsm": (96, 4, 2, 16, None, (32, 32), "bsm"),
+    "full-6to2-bhsd": (96, 6, 2, 16, None, (32, 32), "bhsd"),
+    "w40-14to2-d128-bsm": (96, 14, 2, 128, 40, (32, 32), "bsm"),
+    "w40-14to2-d128-bhsd": (96, 14, 2, 128, 40, (32, 32), "bhsd"),
+}
+
+
+def _band_case_grads(case, heads_a_program=None):
+    """Forward, ``lse`` and the three gradients of the kernels and of the
+    reference, ONE trace each (a loss over both outputs)."""
+    s, h, h_kv, d, window, block, layout = _BAND_CASES[case]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(kq, (1, s, h, d))
+    k = jax.random.normal(kk, (1, s, h_kv, d))
+    v = jax.random.normal(kv, (1, s, h_kv, d))
+    packed = dict(n_heads=h, n_kv_heads=h_kv) if layout == "bsm" else {}
+
+    def flash(q, k, v):
+        out, lse = flash_attention_with_lse(
+            *(_in_layout(t, layout) for t in (q, k, v)), causal=True,
+            window=window, block_q=block[0], block_k=block[1],
+            layout=layout, **packed,
+        )
+        return _from_layout(out, layout, d), lse
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(lse ** 2), (out, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), got_grads = loss(flash)(q, k, v)
+        (_, want), want_grads = loss(
+            lambda q, k, v: _band_reference(q, k, v, window)
+        )(q, k, v)
+    return got + got_grads, want + want_grads
+
+
+@pytest.mark.parametrize("case", list(_BAND_CASES))
+def test_flash_window_and_groups_match_reference(case):
+    """Window x query groups against ``dot_product_attention`` with the
+    explicit band mask and repeated K/V: out, ``lse``, dQ, dK, dV."""
+    got, want = _band_case_grads(case)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "case", ["w20-7to1-bsm", "w5-14to2-s100-bsm", "w64-6to2-bk64-bsm",
+             "w40-14to2-d128-bhsd"],
+)
+def test_flash_groups_split_over_programs_match_reference(case, monkeypatch):
+    """One query head a program (the VMEM budget at nothing): a K/V head's
+    query heads take several programs, which follow one another along
+    dK/dV's last grid axis with the accumulators running on."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_GROUPED_VMEM", 0)
+    got, want = _band_case_grads(case)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+def _grouped_calls(window, *, s=512, h=14, h_kv=2, d=128, budget=None):
+    """``name -> pallas_call equation`` of a grouped call's three kernels
+    as they are traced for the chip (``interpret=False``; nothing runs)."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    x = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, s, heads * d), jnp.bfloat16
+    )
+
+    def f(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, window=window, layout="bsm", n_heads=h,
+            n_kv_heads=h_kv, interpret=False,
+        ).astype(jnp.float32).sum()
+
+    was = pk._GROUPED_VMEM
+    pk._GROUPED_VMEM = was if budget is None else budget
+    try:
+        jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(
+            x(h), x(h_kv), x(h_kv)
+        )
+    finally:
+        pk._GROUPED_VMEM = was
+    return {
+        e.params["name"]: e
+        for e in _walk(jaxpr.jaxpr) if e.primitive.name == "pallas_call"
+    }
+
+
+@pytest.mark.parametrize("budget,group", [(None, 7), (0, 1)])
+def test_grouped_kv_block_is_one_head_and_no_wide_dk_exists(budget, group):
+    """A program's K/V block is ONE K/V head's beside ``group`` query
+    heads' q block, dK/dV leave the kernel at the K/V heads' width, and
+    dK/dV's grid runs over the K/V heads with a head's programs along its
+    last axis: neither K, V, dK nor dV exists at the query heads' width."""
+    calls = _grouped_calls(128, budget=budget)
+    assert sorted(calls) == ["hvd_flash_bwd_dkv_window",
+                             "hvd_flash_bwd_dq_window", "hvd_flash_fwd_window"]
+    for name, e in calls.items():
+        widths = {v.aval.shape[-1] for v in e.invars if v.aval.ndim == 3}
+        assert widths == {14 * 128, 2 * 128}, (name, widths)
+        blocks = {
+            tuple(getattr(x, "block_size", x) for x in b.block_shape)
+            for b in e.params["grid_mapping"].block_mappings
+        }
+        # ``group`` query heads' q block beside ONE K/V head's block
+        assert (1, 512, group * 128) in blocks, (name, blocks)
+        assert (1, 512, 128) in blocks, (name, blocks)
+        assert (1, 512, 2 * 128) not in blocks, (name, blocks)
+    dkv = calls["hvd_flash_bwd_dkv_window"]
+    assert [tuple(v.aval.shape) for v in dkv.outvars] == [
+        (1, 512, 2 * 128), (1, 512, 2 * 128)
+    ]
+    grid = dkv.params["grid_mapping"].grid
+    assert grid[1] == 2 and grid[3] % (7 // group) == 0, grid
+    assert calls["hvd_flash_fwd_window"].params["grid_mapping"].grid[1] == (
+        14 // group
+    )
+
+
+def test_window_drops_grid_steps_at_both_ends():
+    """s 4096, blocks of 512 (K/V resident 1024), window 512: a q block
+    reaches at most 2 of the 4 K/V blocks and a K/V block at most 4 of
+    the 8 q blocks, and the grids are that long; the causal call's are
+    4 and 8."""
+    band = _grouped_calls(512, s=4096)
+    full = _grouped_calls(None, s=4096)
+    grid = lambda calls, name: tuple(  # noqa: E731
+        calls[name].params["grid_mapping"].grid
+    )
+    assert grid(full, "hvd_flash_fwd") == (1, 2, 8, 4)
+    assert grid(band, "hvd_flash_fwd_window") == (1, 2, 8, 2)
+    assert grid(band, "hvd_flash_bwd_dq_window") == (1, 2, 8, 2)
+    assert grid(full, "hvd_flash_bwd_dkv") == (1, 2, 4, 8)
+    assert grid(band, "hvd_flash_bwd_dkv_window") == (1, 2, 4, 4)
+
+
+@pytest.mark.parametrize("layout", ["bsm", "bhsd"])
+def test_window_no_row_reaches_is_the_causal_call_bit_for_bit(layout):
+    """``window >= S`` (static offsets) traces the causal call: the same
+    jaxpr, the same kernels' names, the same bits."""
+    q, k, v = (
+        _in_layout(t, layout)
+        for t in _rand_qkv(jax.random.PRNGKey(12), 1, 96, 2, 16)
+    )
+    packed = dict(n_heads=2) if layout == "bsm" else {}
+
+    def f(window):
+        def loss(q, k, v):
+            out, lse = flash_attention_with_lse(
+                q, k, v, causal=True, window=window, block_q=32,
+                block_k=32, layout=layout, **packed,
+            )
+            return jnp.sum(jnp.sin(out)) + jnp.sum(lse ** 2)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+    assert str(jax.make_jaxpr(f(96))(q, k, v)) == str(
+        jax.make_jaxpr(f(None))(q, k, v)
+    )
+    wide, causal = f(4096)(q, k, v), f(None)(q, k, v)
+    for a, b in zip(jax.tree.leaves(wide), jax.tree.leaves(causal)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # one position short of the sequence is a window
+    assert "hvd_flash_fwd_window" in str(jax.make_jaxpr(f(95))(q, k, v))
+
+
+def test_windowed_and_grouped_counters_count_a_kernel_each():
+    from horovod_tpu.obs import registry
+
+    reg = registry.always()
+    names = ("flash.calls.windowed", "flash.calls.grouped_kv",
+             "flash.tiles.visited", "flash.tiles.masked",
+             "flash.tiles.skipped")
+    x = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 2048, heads * 128), jnp.bfloat16
+    )
+
+    def counted(window, h_kv, grad):
+        def f(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, window=window, layout="bsm",
+                n_heads=14, n_kv_heads=h_kv,
+            ).astype(jnp.float32).sum()
+
+        before = [reg.counter(n).get() for n in names]
+        jax.eval_shape(jax.grad(f, argnums=(0, 1, 2)) if grad else f,
+                       x(14), x(h_kv), x(h_kv))
+        return tuple(reg.counter(n).get() - b for n, b in zip(names, before))
+
+    # s 2048 in 8 x 8 tiles of 256: 36 under the diagonal (see the GPT-2
+    # shape's test); a window of 512 leaves each q tile 3 tiles, the
+    # first two q tiles 1 and 2: 21, all masked, 43 skipped
+    assert counted(None, 14, False) == (0, 0, 36, 20, 28)
+    assert counted(512, 14, False) == (1, 0, 21, 21, 43)
+    assert counted(512, 2, False) == (1, 1, 21, 21, 43)
+    assert counted(None, 2, True) == (0, 3, 108, 60, 84)
+    assert counted(512, 2, True) == (3, 3, 63, 63, 129)
+    assert counted(4096, 2, False) == (0, 1, 36, 20, 28)  # no window at all
+
+
+@pytest.mark.parametrize(
+    "kwargs,match",
+    [
+        (dict(causal=False, window=8), "window=8 needs causal=True"),
+        (dict(causal=True, window=0), "window=0 needs causal=True and"),
+        (dict(causal=True, layout="bsm", n_heads=6, n_kv_heads=4),
+         "n_heads % n_kv_heads != 0"),
+        (dict(causal=True, layout="bhsd", n_kv_heads=3),
+         "n_kv_heads=3 is for layout='bsm'"),
+    ],
+    ids=["window-without-causal", "window-zero", "heads-not-shared-evenly",
+         "n-kv-heads-outside-bsm"],
+)
+def test_flash_refuses_a_window_or_groups_it_cannot_take(kwargs, match):
+    """Plain ``ValueError``s with the shapes in the message."""
+    x = jnp.zeros((1, 6, 16, 8) if kwargs.get("layout") == "bhsd"
+                  else (1, 16, 48))
+    if kwargs.get("layout") is None:
+        x = jnp.zeros((1, 16, 6, 8))
+    with pytest.raises(ValueError, match=match) as refused:
+        flash_attention(x, x, x, **kwargs)
+    assert str(tuple(x.shape)) in str(refused.value)
+
+
+def test_flash_refuses_grouped_heads_off_the_lanes_when_compiled():
+    """Compiled, a packed K/V block of one head has to fill the lanes."""
+    q, kv = jnp.zeros((1, 16, 4 * 64)), jnp.zeros((1, 16, 2 * 64))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        flash_attention(q, kv, kv, causal=True, layout="bsm", n_heads=4,
+                        n_kv_heads=2, interpret=False)
+
+
+@pytest.mark.parametrize(
+    "ratio,d,block_q,group",
+    [(7, 128, 512, 7), (7, 128, 2048, 1), (4, 128, 512, 4), (8, 64, 512, 8),
+     (3, 64, 512, 2), (1, 64, 512, None)],
+)
+def test_head_group_lies_within_one_kv_heads_query_heads(ratio, d, block_q,
+                                                         group):
+    """With query groups a program's heads divide one K/V head's: 7 of 7 at
+    the cell's blocks, 1 where 7 q blocks pass the budget; packed groups
+    stay lane-legal (2 of 3 heads of 64 are not: 1... of 3 is not either,
+    so all 3 only where they are all the heads).  A ratio of 1 is the
+    rule it was."""
+    from horovod_tpu.ops.pallas_kernels import _head_group
+
+    h = 4 * ratio
+    if group is None:
+        assert _head_group(h, block_q, 1024, d, True, kv_ratio=1) == (
+            _head_group(h, block_q, 1024, d, True)
+        )
+        return
+    if (ratio, d) == (3, 64):
+        # no divisor of 3 is 128-lane aligned at width 64 and 3 != h: one
+        # head a program, which the entry refuses compiled
+        assert _head_group(h, block_q, 1024, d, True, kv_ratio=ratio) == 1
+        assert _head_group(h, block_q, 1024, d, False, kv_ratio=ratio) == 3
+        return
+    g = _head_group(h, block_q, 1024, d, True, kv_ratio=ratio)
+    assert g == group and ratio % g == 0

@@ -96,6 +96,11 @@ def test_every_pallas_call_has_a_distinct_name():
             and node.func.attr == "pallas_call"
         ):
             given = [k.value for k in node.keywords if k.arg == "name"]
+            # a literal, or ``_kernel_name(<literal>, plan)``: the literal
+            # and, for a windowed call, ``_window`` after it
+            if given and isinstance(given[0], ast.Call):
+                assert given[0].func.id == "_kernel_name"
+                given = given[0].args[:1]
             assert given and isinstance(given[0], ast.Constant), (
                 f"pallas_call at line {node.lineno} has no literal name="
             )
@@ -164,12 +169,16 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
         seen.update(held)
     assert inside > 500
     family, use_flash = model_parts.CASES[case]
-    assert kernels == (0 if not use_flash else 9 if "moe" in family else 6)
+    per_family = {"latent_moe": 9, "window_moe": 12}  # 3 and 4 blocks
+    assert kernels == (per_family.get(family, 6) if use_flash else 0)
     # each family opens what the table in docs/api.md says it does
     attention = {"attn_layout"} if use_flash else {"attn_xla"}
     if family == "latent_moe":
         want = {"embed", "norm", "mlp", "head", "attn_layout",
                 *model_parts.EXPERT_SCOPES} | attention
+    elif family == "window_moe":  # no dense feed-forward, so no ``mlp``
+        want = {"embed", "norm", "head", "attn_proj", "attn_layout",
+                "moe_route", "moe_experts"} | attention
     else:
         want = {"embed", "norm", "mlp", "head", "attn_proj"} | attention
     assert seen == want
@@ -177,7 +186,7 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
 
 @pytest.mark.parametrize(
     "case", ["gpt2-flash", "bert-mlm-flash", "bert-cls-padded",
-             "latent-moe-flash"],
+             "latent-moe-flash", "window-moe-flash"],
 )
 def test_parts_change_the_traced_step_in_its_names_only(world, case):
     """With no part opened (a patch of ``jax.named_scope``, local to this

@@ -101,7 +101,8 @@ def _described_family_step(topo, case):
 
 
 @pytest.mark.parametrize(
-    "case", ["gpt2-flash", "bert-cls-padded", "latent-moe-flash"]
+    "case", ["gpt2-flash", "bert-cls-padded", "latent-moe-flash",
+             "window-moe-flash"]
 )
 def test_parts_change_the_compiled_step_in_its_metadata_only(
     v5e_topology, case
@@ -120,7 +121,8 @@ def test_parts_change_the_compiled_step_in_its_metadata_only(
     with model_parts.parts_disabled():
         bare, bare_full = _described_family_step(v5e_topology, case)
     assert scoped == bare
-    kernels = 0 if "padded" in case else 9 if "moe" in case else 6
+    kernels = {"bert-cls-padded": 0, "latent-moe-flash": 9,
+               "window-moe-flash": 12}.get(case, 6)
     assert scoped.count("tpu_custom_call") == kernels
     part = re.compile(r'op_name="[^"]*/(?:%s)/' % "|".join(model_parts.PARTS))
     assert part.search(scoped_full) and not part.search(bare_full)
@@ -203,6 +205,80 @@ def test_flash_attention_latent_compiles(v5e, batch, seq, heads):
         ((batch, seq, 64), jnp.bfloat16),
     )
     assert hlo.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_flash_attention_window_and_groups_compile(v5e, window):
+    """The window cell's attention, 1 x 16,384 with 28 query heads on 4
+    K/V heads of 128, packed: the three kernels compile with 7 query heads
+    and ONE K/V head a program, under the band (whose grids are 6 and 11
+    steps long where the causal call's are 16 and 32) and without it; K, V,
+    dK and dV are ``[1, 16384, 512]`` wherever a kernel touches them."""
+    def loss(q, k, v):
+        out, lse = pk.flash_attention_with_lse(
+            q, k, v, causal=True, window=window, layout="bsm", n_heads=28,
+            n_kv_heads=4, interpret=False,
+        )
+        return out.astype(jnp.float32).sum() + (lse ** 2).sum()
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), v5e,
+        ((1, 16384, 28 * 128), jnp.bfloat16),
+        ((1, 16384, 4 * 128), jnp.bfloat16),
+        ((1, 16384, 4 * 128), jnp.bfloat16),
+    )
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    suffix = "_window" if window else ""
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"):
+        (call,) = [c for c in calls if f"{name}{suffix}" in c.split(" = ")[0]
+                   or f'/{name}{suffix}"' in c]
+        wide = len(re.findall(r"bf16\[1,16384,3584\]", call))
+        narrow = len(re.findall(r"bf16\[1,16384,512\]", call))
+        # fwd: q, out / k, v; dkv: q, g / k, v, dk, dv; dq: q, g, dq / k, v
+        assert (wide, narrow) == {
+            "hvd_flash_fwd": (2, 2), "hvd_flash_bwd_dkv": (2, 4),
+            "hvd_flash_bwd_dq": (3, 2),
+        }[name], (name, wide, narrow)
+    p = pk._plan(
+        *(jax.ShapeDtypeStruct((1, 16384, h * 128), jnp.bfloat16)
+          for h in (28, 4, 4)),
+        causal=True, block_q=512, block_k=512, interpret=False, n_heads=28,
+        n_kv_heads=4, window=window or 0,
+    )
+    assert (p.group, p.kv_group, p.subs, p.block_k) == (7, 1, 1, 1024)
+    assert (p.kv_steps, p.q_steps) == ((6, 11) if window else (16, 32))
+
+
+def test_reglu_expert_layer_compiles_at_the_window_cell_shapes(v5e):
+    """The expert layer of the window cell: 16,384 tokens, top-6 of 64 by
+    the softmax over the chosen, 8 ReLU-gated experts held at 2560 x 768:
+    PR 39's batched dots at their second shape."""
+    from horovod_tpu.parallel import ep
+
+    def loss(x, router, gate, up, down):
+        chosen, weights = ep.topk_route(
+            x, router, None, top_k=6, scoring="softmax"
+        )
+        out = ep.local_experts(
+            x, chosen, weights, gate, up, down, first_expert=0,
+            n_experts=64, activation="relu",
+        )
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), v5e,
+        ((16384, 2560), jnp.bfloat16), ((2560, 64), jnp.float32),
+        ((8, 2560, 768), jnp.float32), ((8, 2560, 768), jnp.float32),
+        ((8, 768, 2560), jnp.float32),
+    )
+    assert "conditional" not in hlo and "while" not in hlo
+    entry = hlo[hlo.index("\nENTRY"):]
+    assert not re.findall(r"= bf16\[8,16384,2560\]", entry)
+    # the stacks are read as stored: no copy of an expert stack
+    assert not re.findall(r"= f32\[8,(?:768,2560|2560,768)\]\S* copy\(",
+                          entry)
 
 
 def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):

@@ -2,7 +2,7 @@
 
     python tools/bench_attention.py [--tree CHECKOUT] [--iters 8]
         [--heads 12] [--head-dim 64] [--v-head-dim 64] [--shape NAME]
-        [--block-q N --block-k N]
+        [--block-q N --block-k N] [--kv-heads N] [--window N]
 
 Runs forward + backward of ``flash_attention`` alone (one layer's worth) at
 the shapes of the benchmark's flash cells — GPT-2-small (16 x 1024, causal)
@@ -19,7 +19,11 @@ float32 ``jax.numpy`` attention at batch 2 and at most 1024 positions. The
 / 128) and runs through both entries: ``qkv`` (``flash_attention`` on K built
 with the rotary key broadcast to every head) and ``latent``
 (``flash_attention_latent`` on the packed ``kv`` and the one rotary key;
-skipped where ``--tree`` has none). ``--tree`` imports
+skipped where ``--tree`` has none). ``window-1x16384`` is the window cell's
+attention (1 x 16,384, causal, 28 query heads on 4 K/V heads of 128, window
+4,096; ``--window 0`` times its full layers, ``--kv-heads 28`` a head of K/V a
+query head; the error check's 1,024 positions lie inside the window, so it
+checks the groups, and ``tests/test_pallas_kernels.py`` the band). ``--tree`` imports
 ``horovod_tpu`` from another checkout (a parent commit unpacked beside
 this one), so two commits can be timed in one chip call. This is where a
 kernel change is judged before a cell is run; ``benchmark/split.py`` gives
@@ -42,7 +46,10 @@ KERNELS = ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd")
 # q / k head the rotary columns every head of a key shares)
 SHAPES = {"gpt2-16x1024-causal": (16, 1024, True, 12, 64, 64, 0),
           "bert-32x512": (32, 512, False, 12, 64, 64, 0),
-          "latent": (2, 4096, True, 32, 192, 128, 64)}
+          "latent": (2, 4096, True, 32, 192, 128, 64),
+          "window-1x16384": (1, 16384, True, 28, 128, 128, 0)}
+# name: (K/V heads, window) where they are not the query heads' and none
+GROUPED = {"window-1x16384": (4, 4096)}
 
 
 def built_keys(kv, k_rope, heads, n):
@@ -59,21 +66,25 @@ def built_keys(kv, k_rope, heads, n):
     return k.reshape(b, s, -1), kv[..., n:].reshape(b, s, -1)
 
 
-def reference(q, k, v, w, causal, heads):
+def reference(q, k, v, w, causal, heads, kv_heads=None, window=None):
     """Loss of float32 attention written out in ``jax.numpy``."""
-    b, s, width = v.shape
-    split = lambda x: x.astype(jnp.float32).reshape(b, s, heads, -1)  # noqa: E731
-    q, k, v = split(q), split(k), split(v)
+    b, s, _ = v.shape
+    kv_heads = kv_heads or heads
+    split = lambda x, n: x.astype(jnp.float32).reshape(b, s, n, -1)  # noqa: E731
+    q, k, v = split(q, heads), split(k, kv_heads), split(v, kv_heads)
+    k, v = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (k, v))
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, precision="highest"
     ) / np.sqrt(q.shape[-1])
     if causal:
-        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+        ahead = np.arange(s)[:, None] - np.arange(s)[None, :]
+        valid = (ahead >= 0) & (True if not window else ahead < window)
+        scores = jnp.where(valid, scores, -jnp.inf)
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v,
         precision="highest",
     )
-    return (out.reshape(b, s, width) * w).sum()
+    return (out.reshape(w.shape) * w).sum()
 
 
 def kernel_us(fn, argv, iters):
@@ -116,6 +127,9 @@ def main():
                     help="only this shape (may repeat); default: all")
     ap.add_argument("--block-q", type=int)
     ap.add_argument("--block-k", type=int)
+    ap.add_argument("--kv-heads", type=int,
+                    help="K/V heads that the query heads share")
+    ap.add_argument("--window", type=int, help="0: none")
     args = ap.parse_args()
     sys.path.insert(0, args.tree)
     from horovod_tpu.ops import pallas_kernels
@@ -132,11 +146,18 @@ def main():
         b, s, causal, heads, d, dv, rope = SHAPES[shape]
         heads, d = args.heads or heads, args.head_dim or d
         dv = args.v_head_dim or dv
+        kv_heads, window = GROUPED.get(shape, (heads, None))
+        kv_heads = args.kv_heads or kv_heads
+        window = (args.window or None) if args.window is not None else window
         blocks = {}
         if args.block_q:
             blocks["block_q"] = args.block_q
         if args.block_k:
             blocks["block_k"] = args.block_k
+        if kv_heads != heads:  # a tree before PR 40 takes neither
+            blocks["n_kv_heads"] = kv_heads
+        if window:
+            blocks["window"] = window
 
         def qkv(q, k, v):
             return pallas_kernels.flash_attention(
@@ -149,10 +170,12 @@ def main():
             )[0]
 
         def exact(q, k, v, w):
-            return reference(q, k, v, w, causal, heads)
+            return reference(q, k, v, w, causal, heads, kv_heads, window)
 
         # entry: (the kernels' call, operand widths, float32 loss)
-        entries = {"qkv": (qkv, (heads * d, heads * d, heads * dv), exact)}
+        entries = {"qkv": (
+            qkv, (heads * d, kv_heads * d, kv_heads * dv), exact
+        )}
         if rope and hasattr(pallas_kernels, "flash_attention_latent"):
             entries["latent"] = (
                 latent, (heads * d, heads * (d - rope + dv), rope),
