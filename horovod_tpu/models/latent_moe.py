@@ -29,8 +29,10 @@ Attention (``H`` heads, ``n`` = ``qk_nope_dim``, ``r`` = ``qk_rope_dim``,
 
 so q / k heads are ``n + r`` wide and v / out heads ``v`` wide. The flash
 kernels take ``W_kvb``'s output and ``k_r`` as they are
-(``ops/pallas_kernels.flash_attention_latent``): ``k`` is built only for
-the XLA path.
+(``ops/pallas_kernels.flash_attention_latent``), and ``W_qb``'s output too:
+they rotate ``q_rope`` themselves (``q_rotary``) and hand back the gradient
+of the projection's output. ``k`` and a rotated ``q`` are built only for the
+XLA path.
 
 Expert layer (``E`` experts, ``k`` = ``top_k``)::
 
@@ -64,7 +66,8 @@ operation of ``apply`` lies under exactly one of: ``embed`` (the token
 lookups and, in the multi-token module, the concatenation and ``W_eh``),
 ``norm`` (the blocks' RMSNorms and residual sums, the expert layer's sum of
 routed and shared output, the norms before the heads), ``mla_proj`` (the
-six projections, their two norms, rotary, the concatenation that builds q),
+six projections, their two norms, the shared key's rotary; off the flash
+path also q's rotary and the concatenations that build q and k),
 ``attn_layout`` (the reshapes between the projections and the kernels, and
 the kernels' entry's own glue), ``attn_xla`` (attention where flash is
 bypassed), ``mlp`` (``GatedMlp``: the dense FFN and the shared experts),
@@ -86,6 +89,7 @@ from ..context import device_platform
 from ..parallel import ep
 from .transformer import (  # noqa: F401  (rotary, lm_loss: re-exported)
     GatedMlp, RMSNorm, dot_product_attention, lm_loss, rotary,
+    rotary_tables,
 )
 
 
@@ -151,10 +155,13 @@ class LatentAttention(nn.Module):
             kernel_init=_init(cfg),
         )
         norm = lambda name: RMSNorm(cfg.eps, cfg.dtype, name=name)  # noqa: E731
+        use_flash = cfg.use_flash
+        if use_flash is None:
+            use_flash = device_platform() == "tpu"
         with jax.named_scope("mla_proj"):
             q = dense(h * (n + r), "q_b")(
                 norm("q_norm")(dense(cfg.q_lora_rank, "q_a")(x))
-            ).reshape(b, s, h, n + r)
+            )
             kv_a = dense(cfg.kv_lora_rank + r, "kv_a")(x)
             # [k_nope | v] a head, heads on the lanes: as the kernels take it
             kv = dense(h * (n + v), "kv_b")(
@@ -163,22 +170,22 @@ class LatentAttention(nn.Module):
             k_rope = rotary(
                 kv_a[..., cfg.kv_lora_rank:], theta=cfg.rope_theta
             )
-            q = jnp.concatenate([
-                q[..., :n], rotary(q[..., n:], theta=cfg.rope_theta)
-            ], axis=-1)
-        use_flash = cfg.use_flash
-        if use_flash is None:
-            use_flash = device_platform() == "tpu"
         if use_flash:
-            from ..ops.pallas_kernels import flash_attention_latent
+            from ..ops.pallas_kernels import QRotary, flash_attention_latent
 
-            with jax.named_scope("attn_layout"):
-                q = q.reshape(b, s, h * (n + r))
+            # q as ``q_b`` leaves it: the kernels rotate each head's last
+            # ``r`` lanes and hand back the gradient of ``q_b``'s output
             out, _ = flash_attention_latent(
-                q, kv, k_rope, causal=True, n_heads=h
+                q, kv, k_rope, causal=True, n_heads=h, q_rotary=QRotary(
+                    *rotary_tables(s, r, theta=cfg.rope_theta), start=n
+                ),
             )
         else:
             with jax.named_scope("mla_proj"):
+                q = q.reshape(b, s, h, n + r)
+                q = jnp.concatenate([
+                    q[..., :n], rotary(q[..., n:], theta=cfg.rope_theta)
+                ], axis=-1)
                 kv = kv.reshape(b, s, h, n + v)
                 k = jnp.concatenate([
                     kv[..., :n],

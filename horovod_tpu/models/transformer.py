@@ -270,6 +270,17 @@ class Transformer(nn.Module):
         return x
 
 
+def rotary_tables(s: int, d: int, *, theta: float):
+    """``(cos, sin)`` of ``pos * theta^(-2i/d)``, float32 numpy ``[s, d/2]``
+    (positions 0 on): what :func:`rotary` multiplies by, and what the flash
+    kernels take to rotate q themselves
+    (``ops/pallas_kernels.QRotary``)."""
+    freq = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    angle = np.arange(s, dtype=np.float64)[:, None] * freq[None, :]
+    return (np.cos(angle).astype(np.float32),
+            np.sin(angle).astype(np.float32))
+
+
 def rotary(x, *, theta: float, halves: bool = False):
     """Rotates pairs of the last axis by ``pos * theta^(-2i/d)``: adjacent
     pairs ``(x[2i], x[2i+1])``, or with ``halves`` the pairs ``(x[i],
@@ -279,11 +290,9 @@ def rotary(x, *, theta: float, halves: bool = False):
     ..., d]``, positions 0 on.  Computed in fp32, returned in ``x``'s
     dtype.  Shared by ``latent_moe.py`` and ``window_moe.py``."""
     d, s = x.shape[-1], x.shape[1]
-    freq = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    angle = np.arange(s, dtype=np.float64)[:, None] * freq[None, :]
     shape = (1, s) + (1,) * (x.ndim - 3) + (d // 2,)
-    cos = jnp.asarray(np.cos(angle), jnp.float32).reshape(shape)
-    sin = jnp.asarray(np.sin(angle), jnp.float32).reshape(shape)
+    cos, sin = (jnp.asarray(t).reshape(shape)
+                for t in rotary_tables(s, d, theta=theta))
     if halves:
         x32 = x.astype(jnp.float32)
         a, b = x32[..., :d // 2], x32[..., d // 2:]

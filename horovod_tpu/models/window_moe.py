@@ -38,7 +38,9 @@ The two layouts are tuples a layer indexes modulo their length, so a
 period (``(0, 1, 1, 1)``: full, window, window, window) is data. The flash
 kernels take q ``[B, S, H * head_dim]`` and k / v ``[B, S, H_kv *
 head_dim]`` as the projections leave them (``layout="bsm"``,
-``n_kv_heads``, ``window``): no K or V of ``H`` heads exists. A window
+``n_kv_heads``, ``window``): no K or V of ``H`` heads exists, and in a
+rotated layer q goes in unrotated (``q_rotary``: the kernels rotate it and
+hand back the gradient of the projection's output; k is rotated here). A window
 layer's kernels are named ``hvd_flash_*_window``, a full layer's
 ``hvd_flash_*``. Off the TPU attention is ``dot_product_attention`` with
 the explicit band mask over K/V repeated to ``H`` heads.
@@ -46,7 +48,7 @@ the explicit band mask over K/V repeated to ``H`` heads.
 Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
 operation of ``apply`` lies under exactly one of: ``embed``, ``norm`` (the
 RMSNorms, the residual sums and the token-major views), ``attn_proj`` (the
-four projections and rotary), ``attn_layout`` (the reshapes between the
+four projections and k's rotary; off the flash path q's too), ``attn_layout`` (the reshapes between the
 projections and the kernels, and the kernels' entry's own glue),
 ``attn_xla`` (attention where flash is bypassed), ``moe_route``,
 ``moe_experts`` (``parallel/ep.py``), ``head``. There is no ``mlp`` part:
@@ -66,7 +68,7 @@ import numpy as np
 from ..context import device_platform
 from ..parallel import ep
 from .transformer import (  # noqa: F401  (lm_loss: the model's loss)
-    RMSNorm, dot_product_attention, lm_loss, rotary,
+    RMSNorm, dot_product_attention, lm_loss, rotary, rotary_tables,
 )
 
 
@@ -140,6 +142,9 @@ class GroupedAttention(nn.Module):
             width, use_bias=False, dtype=cfg.dtype, name=name,
             kernel_init=_init(cfg),
         )
+        use_flash = cfg.use_flash
+        if use_flash is None:
+            use_flash = device_platform() == "tpu"
         with jax.named_scope("attn_proj"):
             q = dense(h * d, "q")(x)
             k = dense(h_kv * d, "k")(x)
@@ -149,18 +154,22 @@ class GroupedAttention(nn.Module):
                     t.reshape(b, s, heads, d), theta=cfg.rope_theta,
                     halves=True,
                 ).reshape(b, s, heads * d)
-                q, k = turn(q, h), turn(k, h_kv)
-        use_flash = cfg.use_flash
-        if use_flash is None:
-            use_flash = device_platform() == "tpu"
+                k = turn(k, h_kv)
+                if not use_flash:
+                    q = turn(q, h)
         if use_flash:
-            from ..ops.pallas_kernels import flash_attention
+            from ..ops.pallas_kernels import QRotary, flash_attention
 
             # q, k and v as the projections leave them: no relayout, and
-            # no K or V of ``h`` heads
+            # no K or V of ``h`` heads; the kernels rotate q (28 heads) and
+            # hand back the gradient of the projection's output, k (4) is
+            # rotated above
             out = flash_attention(
                 q, k, v, causal=True, window=self.window, layout="bsm",
                 n_heads=h, n_kv_heads=h_kv,
+                q_rotary=QRotary(
+                    *rotary_tables(s, d, theta=cfg.rope_theta), halves=True
+                ) if self.rotate else None,
             )
         else:
             with jax.named_scope("attn_xla"):

@@ -30,6 +30,12 @@ kernels) and query groups (K and V with fewer heads than q: a program
 reads ONE K/V head's block for the query heads that share it, and dK / dV
 add up over them in the kernel).
 
+Every entry takes ``q_rotary`` (:class:`QRotary`): q then enters as its
+projection leaves it and a q block's rotated lanes are turned in VMEM, once
+a block, where a lane rotation costs nothing; the forward hands the turned
+block on as the backward's residual and dQ leaves turned back, so no
+rotated q and no gradient of one is built by XLA ("Rotary at the door").
+
 Causality across ring steps needs *global* positions, so the kernel takes
 ``q_offset``/``kv_offset`` (traced scalars, prefetched to SMEM): block r
 of an ``sp``-sharded sequence holds global rows ``r*S .. (r+1)*S-1``.
@@ -49,7 +55,7 @@ CPU test mesh) the kernels run in Pallas interpret mode automatically.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +75,7 @@ __all__ = [
     "flash_attention",
     "flash_attention_with_lse",
     "flash_attention_latent",
+    "QRotary",
     "combine_blocks",
     "quantize_blockwise_pallas",
     "dequantize_blockwise_pallas",
@@ -352,12 +359,16 @@ def _count_tiles(q_offset: int, kv_offset: int, *, sq: int, skv: int,
 
 def _book_call_kinds(p: "_Plan", kernels: int) -> None:
     """Build-time counters of what kind of call ``kernels`` kernels were
-    built for: ``flash.calls.latent_kv``, ``.windowed``, ``.grouped_kv``."""
+    built for: ``flash.calls.latent_kv``, ``.windowed``, ``.grouped_kv``;
+    and ``flash.calls.rotary_q``, one a kernel that turns (the forward and
+    dQ: dK/dV reads the forward's turned q)."""
     reg = _registry.always()
     for name, on in (("latent_kv", p.rope), ("windowed", p.window),
                      ("grouped_kv", p.kv_ratio > 1)):
         if on:
             reg.counter(f"flash.calls.{name}").inc(kernels)
+    if p.turn is not None:
+        reg.counter("flash.calls.rotary_q").inc()
 
 
 def _book_tiles(static_offsets, **geometry) -> None:
@@ -395,6 +406,112 @@ def _head_store(ref, g, d, packed, value):
         ref[0, :, g * d:(g + 1) * d] = value
     else:
         ref[0, g] = value
+
+
+# Rotary at the door (``q_rotary=``, :class:`QRotary`).  A call given the
+# tables takes q as its projection leaves it.  The forward turns a q block's
+# rotated lanes when the block arrives (grid step 0 of its K/V axis: once a
+# block and head, not once a tile visited), in float32, rounded once to q's
+# dtype, into a further OUTPUT block that stays in VMEM through the K/V steps
+# and is what the updates read; written back, it is the backward's residual
+# in place of q, so dK/dV and dQ read the turned q and turn nothing.  dQ's
+# finalize, where the ``[d, block_q]`` accumulator is turned to row-major
+# anyway, turns the rotated lanes back by the transposed rotation in
+# float32: what leaves is the gradient of the UNROTATED q, rounded once.
+# The tables reach the kernels as one float32 ``[S, 2 r]`` operand, ``[cos |
+# sin]`` spread over the ``r`` rotated lanes with the sign each lane takes
+# (``_rotary_operand``), so a turn is ``x * cos + partner(x) * sin`` and the
+# partner is a lane rotation: by half the width (halves), or by one lane
+# either way and a select on the lane's parity (adjacent pairs).  Both turns
+# work on the block's lanes as they lie, vreg tile by vreg tile
+# (``_turn_lanes``): head by head, the 64 rotated lanes of a 192-lane head
+# were cut out, widened, turned, set back between their neighbours and
+# stored at a 64-lane offset, and the forward paid 0.68 us a head for it
+# (kernels alone, PERF.md PR 41).
+# ---------------------------------------------------------------------------
+
+
+def _turn_tile(x, rot, inside, turn, back: bool):
+    """One lane tile ``x``, ``[rows, T]``, in float32 with the lanes of
+    ``inside`` (``[(first, last + 1)]``, each ``r`` wide) turned; ``rot``:
+    the rows' ``[rows, 2 r]`` table block.  The other lanes meet a cosine of
+    one and a sine of zero, so the whole tile is one multiply-add and what
+    the rotations bring into them does not count."""
+    _, r, halves = turn
+    rows, width = x.shape
+    x = x.astype(jnp.float32)
+    # a lane rotation takes whole vregs: a narrower tile is set beside
+    # itself, and the first ``width`` lanes of the ring are its own rotation
+    ring = x if width % _LANES == 0 else jnp.concatenate([x, x], axis=1)
+    lanes = ring.shape[1]
+    lane = lax.broadcasted_iota(jnp.int32, ring.shape, 1)
+    if not halves:  # ranges start on even lanes (``_rotary_operand``)
+        partner = jnp.where(
+            lane % 2 == 0, pltpu.roll(ring, lanes - 1, 1),
+            pltpu.roll(ring, 1, 1),
+        )
+    elif r == lanes:
+        partner = pltpu.roll(ring, r // 2, 1)
+    else:
+        upper = functools.reduce(jnp.logical_or, [
+            jnp.logical_and(lane >= a + r // 2, lane < b) for a, b in inside
+        ])
+        partner = jnp.where(
+            upper, pltpu.roll(ring, r // 2, 1),
+            pltpu.roll(ring, lanes - r // 2, 1),
+        )
+    if lanes != width:
+        partner = partner[:, :width]
+
+    def spread(table, fill):
+        pieces, at = [], 0
+        for a, b in inside:
+            if a > at:
+                pieces.append(jnp.full((rows, a - at), fill, jnp.float32))
+            pieces.append(table)
+            at = b
+        if at < width:
+            pieces.append(jnp.full((rows, width - at), fill, jnp.float32))
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(
+            pieces, axis=1
+        )
+
+    sin = rot[:, r:]
+    return x * spread(rot[:, :r], 1.0) + partner * spread(
+        -sin if back else sin, 0.0
+    )
+
+
+def _lane_views(packed: bool, group: int):
+    """Index prefixes of a q block's ``[rows, lanes]`` views, with the heads
+    each holds: the packed block's one view of all ``group`` heads, or a
+    view a head."""
+    if packed:
+        return [((0,), list(range(group)))]
+    return [((0, g), [g]) for g in range(group)]
+
+
+def _turn_lanes(load, store, width: int, heads: int, d: int, rot, turn,
+                back: bool = False):
+    """``store(a, b, turned)`` for every lane tile ``[a, b)`` of a
+    ``[rows, width]`` array that ``load(a, b)`` reads, ``heads`` heads of
+    ``d`` lanes side by side, with each head's rotated lanes turned
+    (``back``: by the transposed rotation) in float32; a tile without any is
+    handed on as loaded.  The tiles are the 128 lanes of a vreg where they
+    divide the array and cut no head's rotated lanes (the cells: 2 heads of
+    128 + 64 are three tiles, two of which hold 64 rotated lanes in one
+    half; a head of 128 is one), so nothing is shifted along the lanes but
+    what the rotation itself shifts; else the whole array is one tile."""
+    lo, r, _ = turn
+    ranges = [(g * d + lo, g * d + lo + r) for g in range(heads)]
+    tile = _LANES if width % _LANES == 0 and all(
+        a // _LANES == (b - 1) // _LANES for a, b in ranges
+    ) else width
+    for t in range(0, width, tile):
+        inside = [(a - t, b - t) for a, b in ranges if t <= a < t + tile]
+        x = load(t, t + tile)
+        store(t, t + tile,
+              _turn_tile(x, rot, inside, turn, back) if inside else x)
 
 
 # Where a kernel finds head ``g``'s keys and values in its second and third
@@ -514,12 +631,7 @@ def _fwd_kernel(
     q_ref,
     k_ref,
     v_ref,
-    o_ref,
-    lse_ref,
-    acc_ref,
-    m_ref,
-    l_ref,
-    *,
+    *refs,
     sm_scale: float,
     causal: bool,
     masked: bool,
@@ -530,6 +642,7 @@ def _fwd_kernel(
     rope: int = 0,
     kv_shared: bool = False,
     band: Optional["_Plan"] = None,
+    turn: Optional[Tuple[int, int, bool]] = None,
 ):
     """One (batch*head group, q-block, k-block) grid step of the online
     softmax.
@@ -578,8 +691,17 @@ def _fwd_kernel(
     ``kv_shared`` (query groups) k_ref / v_ref hold ONE head, which every
     query head of the program reads.  ``band`` (the call's plan, under a
     window only): the K/V grid axis covers the blocks the band can reach
-    and step 0 is the q block's first (``_first_kv_block``).
+    and step 0 is the q block's first (``_first_kv_block``).  With
+    ``turn`` ("Rotary at the door") the refs after v_ref are ``rot_ref,
+    o_ref, lse_ref, qt_ref`` and the scratch: the rows' table block
+    ``[block_q, 2 r]`` and a further output shaped like the q block, which
+    step 0 fills with the turned q and every update reads in q_ref's place.
     """
+    if turn is None:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+        qt_ref = q_ref
+    else:
+        rot_ref, o_ref, lse_ref, qt_ref, acc_ref, m_ref, l_ref = refs
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
     heads = dict(packed=packed, d=d, dv=dv, rope=rope, kv_shared=kv_shared)
@@ -596,6 +718,19 @@ def _fwd_kernel(
         acc_ref[:, :, :] = jnp.zeros_like(acc_ref)
         m_ref[:, :, :] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:, :, :] = jnp.zeros_like(l_ref)
+        if turn is not None:
+            for at, heads in _lane_views(packed, group):
+                def put(a, b, x, at=at):
+                    qt_ref[(*at, slice(None), slice(a, b))] = x.astype(
+                        qt_ref.dtype
+                    )
+
+                _turn_lanes(
+                    lambda a, b, at=at: q_ref[
+                        (*at, slice(None), slice(a, b))
+                    ], put, q_ref.shape[-1], len(heads), d, rot_ref[...],
+                    turn,
+                )
 
     def update(rq, rk, valid):
         """Online-softmax update of q rows ``rq`` with K/V rows ``rk``.
@@ -610,7 +745,7 @@ def _fwd_kernel(
             # efficiency before this).  Softmax statistics are fp32.
             s_t = jax.lax.dot_general(
                 _k_head(k_ref, v_ref, g, rk, **heads),
-                _head(q_ref, g, d, packed, rq),
+                _head(qt_ref, g, d, packed, rq),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale  # [cols, rows] fp32
@@ -694,6 +829,9 @@ class _Plan(NamedTuple):
     kv_ratio: int = 1
     # 0: none.  W > 0: an entry is valid only where ``row - col < W``
     window: int = 0
+    # None: q arrives rotated, if at all.  ``(first rotated lane of a head,
+    # rotated lanes, halves)``: the kernels turn it ("Rotary at the door")
+    turn: Optional[Tuple[int, int, bool]] = None
 
     @property
     def kv_group(self) -> int:
@@ -746,7 +884,8 @@ class _Plan(NamedTuple):
 
 def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
           interpret: Optional[bool], n_heads: int, rope: int = 0,
-          n_kv_heads: int = 0, window: int = 0) -> _Plan:
+          n_kv_heads: int = 0, window: int = 0,
+          turn: Optional[Tuple[int, int, bool]] = None) -> _Plan:
     packed = n_heads > 0
     if packed:
         b, sq, hd = q.shape
@@ -775,7 +914,7 @@ def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
         packed, b, h, d, sq, skv, block_q, block_k,
         _round_up(sq, block_q), skv_pad,
         _head_group(h, block_q, block_k, d, packed, dv, rope, kv_ratio),
-        tiles, interpret, dv, rope, kv_ratio, window,
+        tiles, interpret, dv, rope, kv_ratio, window, turn,
     )
 
 
@@ -869,12 +1008,20 @@ def _grid_spec(causal: bool, *, grid, in_specs, out_specs, scratch_shapes):
     )
 
 
+def _rot_rows(rot, p: "_Plan"):
+    """The rotation's ``[Sq, 2 r]`` table padded to the q blocks."""
+    if p.sq_pad != p.sq:
+        rot = jnp.pad(rot, ((0, p.sq_pad - p.sq), (0, 0)))
+    return rot
+
+
 def _fwd_pallas(
     q,
     k,
     v,
     q_offset,
     kv_offset,
+    rot=None,
     *,
     sm_scale: float,
     causal: bool,
@@ -886,6 +1033,7 @@ def _fwd_pallas(
     rope: int = 0,
     n_kv_heads: int = 0,
     window: int = 0,
+    turn: Optional[Tuple[int, int, bool]] = None,
 ):
     """Run the kernel.
 
@@ -910,17 +1058,20 @@ def _fwd_pallas(
 
     ``static_offsets``: the two offsets where the caller gave Python
     ints, for the build-time tile counters only.
+
+    ``rot`` / ``turn`` ("Rotary at the door"): q is unrotated, and a third
+    result is the turned q, shaped like q: the backward's residual.
     """
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
               interpret=interpret, n_heads=n_heads, rope=rope,
-              n_kv_heads=n_kv_heads, window=window)
+              n_kv_heads=n_kv_heads, window=window, turn=turn)
     if causal:
         _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
     if p.dv != p.d:
         _registry.always().counter("flash.calls.split_widths").inc()
     _book_call_kinds(p, 1)
     return _flash_fwd_call(
-        q, k, v, _geometry(q_offset, kv_offset, p.skv),
+        q, k, v, _geometry(q_offset, kv_offset, p.skv), rot,
         p=p, sm_scale=sm_scale, causal=causal,
     )
 
@@ -933,7 +1084,7 @@ def _fwd_pallas(
 @functools.partial(
     jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
 )
-def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
+def _flash_fwd_call(q, k, v, geom, rot=None, *, p: _Plan, sm_scale: float,
                     causal: bool):
     b, h, d, dv, group, rope = p.b, p.h, p.d, p.dv, p.group, p.rope
     block_q, block_k, sq_pad, skv_pad = (
@@ -943,6 +1094,7 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         qr = p.pad_seq(q, p.sq, sq_pad)
         kr = p.pad_seq(k, p.skv, skv_pad)
         vr = p.pad_seq(v, p.skv, skv_pad)
+        turned = [] if p.turn is None else [_rot_rows(rot, p)]
 
     def kv_block(qi, kj, geom):
         if not causal:
@@ -982,11 +1134,12 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         (b, sq_pad, h * dv) if p.packed else (b, h, sq_pad, dv), q.dtype
     )
 
-    out, lse = pl.pallas_call(
+    out, lse, *qt = pl.pallas_call(
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, causal=causal,
             masked=causal or skv_pad != p.skv, tiles=p.tiles,
             packed=p.packed, d=d, dv=dv, rope=rope, **_kind_params(p),
+            turn=p.turn,
         ),
         grid_spec=_grid_spec(
             causal,
@@ -994,14 +1147,19 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
             in_specs=[q_side(d)] + (
                 [kv_side(d - rope + dv), kv_side(rope, shared=True)] if rope
                 else [kv_side(d), kv_side(dv)]
-            ),
+            ) + [
+                _vspec(
+                    (block_q, x.shape[1]),
+                    lambda bi, hi, qi, kj, *geom: (qi, 0),
+                ) for x in turned
+            ],
             out_specs=[
                 q_side(dv),
                 _vspec(
                     (1, group, 8, block_q),
                     lambda bi, hi, qi, kj, *geom: (bi, hi, 0, qi),
                 ),
-            ],
+            ] + [q_side(d) for _ in turned],
             scratch_shapes=[
                 _VMEM((group, dv, block_q), jnp.float32),
                 _VMEM((group, 1, block_q), jnp.float32),
@@ -1011,7 +1169,7 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         out_shape=[
             o_shape,
             jax.ShapeDtypeStruct((b, h, 8, sq_pad), jnp.float32),
-        ],
+        ] + [jax.ShapeDtypeStruct(qr.shape, qr.dtype) for _ in turned],
         # batch/head/qi programs are independent; only the K/V stream (kj)
         # carries state — lets Mosaic parallelize/pipeline the outer grid.
         compiler_params=_compiler_params(p),
@@ -1023,15 +1181,17 @@ def _flash_fwd_call(q, k, v, geom, *, p: _Plan, sm_scale: float,
         ),
         interpret=p.interpret,
         name=_kernel_name("hvd_flash_fwd", p),
-    )(*geom, qr, kr, vr)
+    )(*geom, qr, kr, vr, *turned)
 
     with jax.named_scope(_GLUE_SCOPE):
         if p.packed:
             out = out[:, :p.sq]  # [B,Sq,H*D]
+            qt = [x[:, :p.sq] for x in qt]
         else:
             out = out[:, :, :p.sq]  # [B,H,Sq,D]
+            qt = [x[:, :, :p.sq] for x in qt]
         lse = lse[:, :, 0, :p.sq]  # [B,H,Sq]
-    return out, lse
+    return (out, lse, *qt)
 
 
 # ---------------------------------------------------------------------------
@@ -1253,15 +1413,23 @@ def _bwd_kernel_dkdv(
 
 def _bwd_kernel_dq(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
-    q_ref, k_ref, v_ref, g_ref, dq_ref, dq_acc,
-    *, sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
+    q_ref, k_ref, v_ref, g_ref, *refs,
+    sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
     rope: int = 0, kv_shared: bool = False, band: Optional[_Plan] = None,
+    turn: Optional[Tuple[int, int, bool]] = None,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
     static unroll (see forward).  ``kv_shared`` / ``band``: as the
-    forward."""
+    forward.  With ``turn`` ("Rotary at the door") q_ref holds the turned
+    q the forward wrote, the refs after g_ref are ``rot_ref, dq_ref,
+    dq_acc``, and each head's gradient is turned back where it is written:
+    dq_ref takes the gradient of the unrotated q."""
+    if turn is None:
+        dq_ref, dq_acc = refs
+    else:
+        rot_ref, dq_ref, dq_acc = refs
     qi = pl.program_id(2)
     kj = step = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -1297,22 +1465,51 @@ def _bwd_kernel_dq(
 
     @pl.when(step == nk - 1)
     def _finalize():
-        for g in range(group):
-            _head_store(
-                dq_ref, g, d, packed, dq_acc[g, :, :].T.astype(dq_ref.dtype)
+        if turn is None:
+            for g in range(group):
+                _head_store(
+                    dq_ref, g, d, packed,
+                    dq_acc[g, :, :].T.astype(dq_ref.dtype),
+                )
+            return
+        # the accumulators' rows are the block's lanes: turned tile by tile
+        # as they are transposed, a tile's rows from one head or from two
+        for at, heads in _lane_views(packed, group):
+            def rows(a, b, heads=heads):
+                """Lanes ``[a, b)`` of the heads side by side, as
+                ``[block_q, b - a]``."""
+                pieces = [
+                    dq_acc[g, max(a - i * d, 0):min(b - i * d, d), :]
+                    for i, g in enumerate(heads) if i * d < b and a < (i + 1) * d
+                ]
+                return (pieces[0] if len(pieces) == 1
+                        else jnp.concatenate(pieces, axis=0)).T
+
+            def put(a, b, x, at=at):
+                dq_ref[(*at, slice(None), slice(a, b))] = x.astype(
+                    dq_ref.dtype
+                )
+
+            _turn_lanes(
+                rows, put, dq_ref.shape[-1], len(heads), d, rot_ref[...],
+                turn, back=True,
             )
 
 
 def _bwd_pallas(
-    q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, *,
+    q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot=None, *,
     sm_scale: float, causal: bool, block_q: int, block_k: int,
     interpret: Optional[bool], n_heads: int = 0,
     static_offsets: Optional[Tuple[int, int]] = None, rope: int = 0,
     n_kv_heads: int = 0, window: int = 0,
+    turn: Optional[Tuple[int, int, bool]] = None,
 ):
+    """``(dq, dk, dv)``.  With ``rot`` / ``turn`` ("Rotary at the door")
+    ``q`` is the forward's turned q and ``dq`` the gradient of the
+    unrotated one."""
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
               interpret=interpret, n_heads=n_heads, rope=rope,
-              n_kv_heads=n_kv_heads, window=window)
+              n_kv_heads=n_kv_heads, window=window, turn=turn)
     if causal:
         # one count for each of the two kernels
         for _ in range(2):
@@ -1325,15 +1522,15 @@ def _bwd_pallas(
     _book_call_kinds(p, 2)
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
-        g_lse, p=p, sm_scale=sm_scale, causal=causal,
+        g_lse, rot, p=p, sm_scale=sm_scale, causal=causal,
     )
 
 
 @functools.partial(
     jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
 )
-def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
-                    sm_scale: float, causal: bool):
+def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
+                    p: _Plan, sm_scale: float, causal: bool):
     b, h, d, dv, group, sq, skv = p.b, p.h, p.d, p.dv, p.group, p.sq, p.skv
     rope, n = p.rope, p.d - p.rope
     block_q, block_k, sq_pad, skv_pad = (
@@ -1371,6 +1568,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         delta_rows = rows(delta, 0.0)
         glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
         glse_rows = rows(glse.astype(jnp.float32), 0.0)
+        turned = [] if p.turn is None else [_rot_rows(rot, p)]
 
     kernel_params = dict(
         sm_scale=sm_scale, causal=causal,
@@ -1436,6 +1634,9 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             _, kj = blocks(i, j, geom)
             return (bi, kj, 0)
 
+        def rot_map(bi, hi, i, j, *geom):
+            return (blocks(i, j, geom)[0], 0)
+
         def block(rows, width, index_map, heads=group):
             return _vspec(
                 (1, rows, heads * width) if p.packed
@@ -1449,6 +1650,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             _vspec((1, block_k, rope), shared_map) if rope
             else block(block_k, dv, kv_map, p.kv_group),
             block(block_q, dv, q_map),
+            [_vspec((block_q, x.shape[1]), rot_map) for x in turned],
         )
 
     def shape_like(x, s_pad, width, heads=h):
@@ -1458,7 +1660,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
         )
 
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
-    stat_spec, q_spec, k_spec, v_spec, g_spec = specs("kq")
+    stat_spec, q_spec, k_spec, v_spec, g_spec, _ = specs("kq")
 
     def dkv_acc(width, heads=p.kv_group):
         return _VMEM(
@@ -1508,21 +1710,21 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
             grad_v = grad_v.sum(axis=1).swapaxes(1, 2)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
-    stat_spec, q_spec, k_spec, v_spec, g_spec = specs("qk")
+    stat_spec, q_spec, k_spec, v_spec, g_spec, rot_spec = specs("qk")
     dq = pl.pallas_call(
-        functools.partial(_bwd_kernel_dq, **kernel_params),
+        functools.partial(_bwd_kernel_dq, **kernel_params, turn=p.turn),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, sq_pad // block_q, p.kv_steps),
             in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, k_spec, v_spec, g_spec],
+                      q_spec, k_spec, v_spec, g_spec] + rot_spec,
             out_specs=q_spec,
             scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
         ),
         out_shape=shape_like(q, sq_pad, d),
         **call_params,
         name=_kernel_name("hvd_flash_bwd_dq", p),
-    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
+    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *turned)
 
     with jax.named_scope(_GLUE_SCOPE):
         if p.packed:
@@ -1539,20 +1741,24 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, *, p: _Plan,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13, 14)
+    jax.custom_vjp,
+    nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16),
 )
-def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-           interpret, n_heads=0, static_offsets=None, rope=0, n_kv_heads=0,
-           window=0):
+def _flash(q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
+           block_k, interpret, n_heads=0, static_offsets=None, rope=0,
+           n_kv_heads=0, window=0, turn=None):
     """``(out, lse)`` with the exact backward.  With ``rope`` the operands
     ``k`` and ``v`` are the packed ``kv`` and the shared key (``_k_head``),
-    and so are their cotangents."""
+    and so are their cotangents.  ``rot`` (None, or with ``turn`` the
+    rotation's table: "Rotary at the door"): q is unrotated, and so is its
+    cotangent."""
     return _fwd_pallas(
         q,
         k,
         v,
         q_offset,
         kv_offset,
+        rot,
         sm_scale=sm_scale,
         causal=causal,
         block_q=block_q,
@@ -1563,22 +1769,33 @@ def _flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         rope=rope,
         n_kv_heads=n_kv_heads,
         window=window,
-    )
+        turn=turn,
+    )[:2]
 
 
-def _flash_fwd(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
+def _flash_fwd(q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
                block_k, interpret, n_heads=0, static_offsets=None, rope=0,
-               n_kv_heads=0, window=0):
-    out, lse = _flash(
-        q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
-        interpret, n_heads, static_offsets, rope, n_kv_heads, window
-    )
-    return (out, lse), (q, k, v, q_offset, kv_offset, out, lse)
+               n_kv_heads=0, window=0, turn=None):
+    if turn is None:
+        out, lse = _flash(
+            q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
+            block_k, interpret, n_heads, static_offsets, rope, n_kv_heads,
+            window, turn
+        )
+    else:  # the turned q is kept, and q is not
+        out, lse, q = _fwd_pallas(
+            q, k, v, q_offset, kv_offset, rot, sm_scale=sm_scale,
+            causal=causal, block_q=block_q, block_k=block_k,
+            interpret=interpret, n_heads=n_heads,
+            static_offsets=static_offsets, rope=rope, n_kv_heads=n_kv_heads,
+            window=window, turn=turn,
+        )
+    return (out, lse), (q, k, v, q_offset, kv_offset, rot, out, lse)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
-               static_offsets, rope, n_kv_heads, window, res, g):
-    q, k, v, q_offset, kv_offset, out, lse = res
+               static_offsets, rope, n_kv_heads, window, turn, res, g):
+    q, k, v, q_offset, kv_offset, rot, out, lse = res
     g_out, g_lse = g
     dq, dk, dv = _bwd_pallas(
         q,
@@ -1590,6 +1807,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
         lse,
         g_out,
         g_lse,
+        rot,
         sm_scale=sm_scale,
         causal=causal,
         block_q=block_q,
@@ -1600,10 +1818,12 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
         rope=rope,
         n_kv_heads=n_kv_heads,
         window=window,
+        turn=turn,
     )
-    # Integer offsets take float0 cotangents.
+    # Integer offsets take float0 cotangents; the tables are constants.
     zero = np.zeros((), dtype=jax.dtypes.float0)
-    return dq, dk, dv, zero, zero
+    return (dq, dk, dv, zero, zero,
+            None if rot is None else jnp.zeros_like(rot))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1614,9 +1834,64 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # ---------------------------------------------------------------------------
 
 
+class QRotary(NamedTuple):
+    """``q_rotary=`` of the flash entries: q arrives as its projection
+    leaves it and the kernels rotate it ("Rotary at the door").
+
+    ``cos`` / ``sin``: float32 ``[Sq, r/2]``, the angles' cosines and sines
+    for q's rows as they are passed (``models.transformer.rotary_tables``;
+    numpy arrays stay constants of the program).  ``halves``: the pairs are
+    ``(x[i], x[i + r/2])``, else adjacent, ``(x[2i], x[2i+1])``.  ``start``:
+    the first rotated lane of a head; the ``r`` lanes from there turn, the
+    others pass.  K is the caller's to rotate.  The function is that of
+    ``rotary`` on those lanes followed by the same call without the
+    argument; the cotangent of q is that of the unrotated q."""
+
+    cos: Any
+    sin: Any
+    halves: bool = False
+    start: int = 0
+
+
+def _rotary_operand(q_rotary: QRotary, sq: int, d: int, compiled: bool):
+    """``(rot, turn)`` of a :class:`QRotary` for q heads ``d`` wide: the
+    kernels' one float32 ``[Sq, 2 r]`` table, ``[cos | sin]`` spread over
+    the ``r`` rotated lanes, each sine with the sign its lane takes (the
+    first of a pair ``-``, the second ``+``), and the static ``(start, r,
+    halves)``."""
+    cos, sin, halves, start = q_rotary
+    r = 2 * cos.shape[-1]
+    if (cos.shape != (sq, r // 2) or sin.shape != cos.shape
+            or start < 0 or start + r > d):
+        raise ValueError(
+            f"q_rotary: cos {cos.shape} / sin {sin.shape} have to be "
+            f"[Sq={sq}, r/2] with start={start} + r <= head width {d}"
+        )
+    if not halves and (start % 2 or d % 2):
+        raise ValueError(
+            "q_rotary: adjacent pairs start on even lanes; got "
+            f"start={start} in heads {d} wide"
+        )
+    if compiled and (start % 64 or r % 64):
+        raise ValueError(
+            "q_rotary needs start and the rotated width to be multiples of "
+            f"64 on TPU (Mosaic lane slicing); got start={start}, r={r}"
+        )
+    xp = np if all(isinstance(x, np.ndarray) for x in (cos, sin)) else jnp
+    with jax.named_scope(_GLUE_SCOPE):
+        if halves:
+            spread = [xp.concatenate(pair, axis=-1)
+                      for pair in ((cos, cos), (-sin, sin))]
+        else:
+            spread = [xp.stack(pair, axis=-1).reshape(sq, r)
+                      for pair in ((cos, cos), (-sin, sin))]
+        rot = xp.concatenate(spread, axis=-1).astype(np.float32)
+    return rot, (int(start), r, bool(halves))
+
+
 def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
                 block_k, interpret, n_heads, rope=0, n_kv_heads=0,
-                window=None):
+                window=None, q_rotary=None):
     """``_flash`` on a public entry's arguments: ``(out, lse)``."""
     # Offsets given as Python ints (the model path: 0, 0) are also kept
     # static, for the build-time tile counters; the kernels read the
@@ -1631,12 +1906,22 @@ def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
         sq = q.shape[1 if n_heads else 2]
         if window >= static_offsets[0] + sq - static_offsets[1]:
             window = 0
+    rot = turn = None
+    if q_rotary is not None:
+        compiled = not (
+            interpret if interpret is not None else _use_interpret()
+        )
+        rot, turn = _rotary_operand(
+            QRotary(*q_rotary), q.shape[1 if n_heads else 2],
+            q.shape[-1] // (n_heads or 1), compiled,
+        )
     return _flash(
         q,
         k,
         v,
         jnp.asarray(q_offset, jnp.int32),
         jnp.asarray(kv_offset, jnp.int32),
+        rot,
         float(sm_scale),
         bool(causal),
         int(block_q),
@@ -1647,6 +1932,7 @@ def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
         rope,
         int(n_kv_heads),
         window,
+        turn,
     )
 
 
@@ -1666,6 +1952,7 @@ def flash_attention_with_lse(
     n_heads: int = 0,
     n_kv_heads: int = 0,
     window: Optional[int] = None,
+    q_rotary: Optional[QRotary] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Blockwise attention returning ``(out, lse)``.
 
@@ -1701,6 +1988,9 @@ def flash_attention_with_lse(
     step, in all three passes; a window that no row reaches the edge of
     (``window >= q_offset + Sq - kv_offset``, static offsets) traces the
     causal call.  The windowed kernels' names end in ``_window``.
+
+    ``q_rotary`` (:class:`QRotary`): q is passed unrotated and rotated in
+    the kernels; k is passed rotated.
     """
     packed = layout == "bsm"
     if packed and n_heads <= 0:
@@ -1760,6 +2050,7 @@ def flash_attention_with_lse(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         interpret, n_heads if packed else 0,
         n_kv_heads=h_kv if packed and h_kv != h else 0, window=window,
+        q_rotary=q_rotary,
     )
     if layout == "bshd":
         with jax.named_scope(_GLUE_SCOPE):
@@ -1782,10 +2073,12 @@ def flash_attention(
     n_heads: int = 0,
     n_kv_heads: int = 0,
     window: Optional[int] = None,
+    q_rotary: Optional[QRotary] = None,
 ) -> jax.Array:
     """Drop-in memory-efficient replacement for
     ``models.transformer.dot_product_attention`` (same signature shape);
-    ``n_kv_heads`` and ``window`` as :func:`flash_attention_with_lse`.
+    ``n_kv_heads``, ``window`` and ``q_rotary`` as
+    :func:`flash_attention_with_lse`.
 
     Dense ``mask`` is not supported by the blockwise kernel — callers that
     need one fall back to the XLA path.
@@ -1808,6 +2101,7 @@ def flash_attention(
         n_heads=n_heads,
         n_kv_heads=n_kv_heads,
         window=window,
+        q_rotary=q_rotary,
     )
     return out
 
@@ -1825,13 +2119,17 @@ def flash_attention_latent(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
+    q_rotary: Optional[QRotary] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Blockwise attention over keys and values as latent attention holds
     them, returning ``(out, lse)`` like :func:`flash_attention_with_lse`.
 
     With ``H`` heads whose keys are ``n`` unshared columns and ``r`` rotary
     columns that every head of a position shares, and values ``dv`` wide:
-    q ``[B, Sq, H*(n+r)]``, ``[q_nope | q_rope]`` a head (rotary applied);
+    q ``[B, Sq, H*(n+r)]``, ``[q_nope | q_rope]`` a head (rotary applied,
+    or with ``q_rotary`` (:class:`QRotary`, ``start=n``) the ``q_b``
+    projection's output as it is: the kernels rotate it and hand back the
+    gradient of that output);
     kv ``[B, Skv, H*(n+dv)]``, ``[k_nope | v]`` a head, which is the
     ``kv_b`` projection's output as the matmul leaves it; k_rope
     ``[B, Skv, r]`` (rotary applied).  out ``[B, Sq, H*dv]``, lse fp32
@@ -1869,7 +2167,7 @@ def flash_attention_latent(
         k_rope = k_rope.astype(kv.dtype)
     return _call_flash(
         q, kv, k_rope, q_offset, kv_offset, sm_scale, causal, block_q,
-        block_k, interpret, n_heads, rope=r,
+        block_k, interpret, n_heads, rope=r, q_rotary=q_rotary,
     )
 
 

@@ -97,12 +97,16 @@ def test_loss_and_every_gradient_leaf_match_the_plain_reference(n_mtp, flash):
 def test_flash_path_hands_the_kernels_kv_b_output_and_the_one_rotary_key():
     """Traced at the cell's widths (32 heads, 128 + 64 / 128): the flash
     path's kernels get ``kv_b``'s matmul output itself and the
-    ``[B, S, 64]`` rotary key; the only ``[B, S, H, 192]`` concatenation
-    left is q's, and nothing broadcasts the rotary key to the heads. The
-    XLA path still builds K. The kernels are counted 3 a block."""
+    ``[B, S, 64]`` rotary key; since PR 41 q is ``q_b``'s matmul output
+    itself too (the kernels rotate it: no ``[B, S, H, 192]`` concatenation
+    is left, nothing is reshaped to pairs of 32 and nothing of q's is
+    raised to float32), and nothing broadcasts the rotary key to the heads.
+    The XLA path still builds q and K. The kernels are counted 3 a block,
+    and the two that turn (forward, dQ) once each."""
     b, s, h, n, r, v = 2, 4096, 32, 128, 64, 128
     x = jax.ShapeDtypeStruct((b, s, 2048), jnp.bfloat16)
     counter = registry.always().counter("flash.calls.latent_kv")
+    turning = registry.always().counter("flash.calls.rotary_q")
 
     params = jax.eval_shape(
         LatentAttention(LatentMoEConfig(use_flash=False)).init,
@@ -123,22 +127,68 @@ def test_flash_path_hands_the_kernels_kv_b_output_and_the_one_rotary_key():
              and e.outvars[0].aval.shape == (b, s, h, r)],
         )
 
-    before = counter.get()
+    before, turned_before = counter.get(), turning.get()
     eqns = eqns_of(True)
     assert counter.get() == before + 1
+    assert turning.get() == turned_before + 1  # the forward
     concatenated, broadcast = built_keys(eqns)
-    assert len(concatenated) == 1 and not broadcast  # q alone
+    assert not concatenated and not broadcast
     (call,) = [e for e in eqns if e.primitive.name == "custom_vjp_call"]
     q, kv, k_rope = call.invars[:3]
+    assert q.aval.shape == (b, s, h * (n + r))
     assert kv.aval.shape == (b, s, h * (n + v))
     assert k_rope.aval.shape == (b, s, r)
-    (made_kv,) = [e for e in eqns if kv in e.outvars]
-    assert made_kv.primitive.name == "dot_general"  # kv_b, nothing between
-    eqns_of(True, grad=True)
+    for operand in (q, kv):  # q_b and kv_b, nothing between
+        (made,) = [e for e in eqns if operand in e.outvars]
+        assert made.primitive.name == "dot_general"
+    # the tables: one float32 [S, 2 r] operand, [cos | sin] over the lanes
+    assert call.invars[5].aval.shape == (s, 2 * r)
+    q_path = [e for e in eqns if any(
+        tuple(o.aval.shape[2:]) in ((h, r // 2, 2), (h, r // 2), (h, r))
+        for o in e.outvars
+    )]
+    assert not q_path, q_path  # no [.., 32, 32, 2] pairs, no cut of q
+    grad_eqns = eqns_of(True, grad=True)
     assert counter.get() == before + 1 + 3
+    assert turning.get() == turned_before + 1 + 2  # + forward and dQ
+    # dQ's result reaches q_b's backward as it is: bfloat16, row-major
+    assert not [
+        e for e in grad_eqns
+        if e.outvars and e.outvars[0].aval.shape == (b, s, h * (n + r))
+        and e.outvars[0].aval.dtype == jnp.float32
+    ]
     concatenated, broadcast = built_keys(eqns_of(False))
     assert len(concatenated) == 2 and len(broadcast) == 1
     assert counter.get() == before + 4
+    assert turning.get() == turned_before + 3
+
+
+def test_flash_path_with_the_kernels_rotary_equals_the_xla_path():
+    """The model's output and every gradient, kernels (which rotate q
+    themselves, interpreter) against ``use_flash=False`` (``rotary`` in
+    XLA), at a length that is no multiple of the block."""
+    cfg = _tiny(n_mtp=0)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, cfg.d_model))
+    w = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+    params = LatentAttention(cfg).init(jax.random.PRNGKey(0), x)
+
+    def run(use_flash):
+        attn = LatentAttention(dataclasses.replace(cfg, use_flash=use_flash))
+
+        def loss(p, x):
+            out = attn.apply(p, x)
+            return (out * w).sum(), out
+
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(
+                params, x
+            )
+
+    (got, got_dx), got_out = run(True)
+    (want, want_dx), want_out = run(False)
+    np.testing.assert_allclose(got_out, want_out, atol=2e-6)
+    np.testing.assert_allclose(got_dx, want_dx, atol=2e-5)
+    _assert_trees_close(got, want, 2e-4)
 
 
 def test_rotary_rotates_adjacent_pairs_and_keeps_relative_position():

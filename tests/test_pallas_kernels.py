@@ -1406,3 +1406,234 @@ def test_head_group_lies_within_one_kv_heads_query_heads(ratio, d, block_q,
         return
     g = _head_group(h, block_q, 1024, d, True, kv_ratio=ratio)
     assert g == group and ratio % g == 0
+
+
+# ---------------------------------------------------------------------------
+# Rotary at the door (``q_rotary=``): q enters as its projection leaves it,
+# the forward and dQ turn in VMEM, dK/dV reads the forward's turned q.
+# ---------------------------------------------------------------------------
+
+# name: (entry, heads, K/V heads, n, r, dv, sq, block, window, halves)
+_ROTARY_CASES = {
+    "latent-pairs-16+8-16": ("latent", 2, 2, 16, 8, 16, 72, 32, None, False),
+    "latent-pairs-128+64-128": (
+        "latent", 2, 2, 128, 64, 128, 64, 32, None, False),
+    "latent-pairs-128+64-128-one-head-padded": (
+        "latent", 1, 1, 128, 64, 128, 40, 16, None, False),
+    "qkv-halves-16-groups-window": (
+        "bsm", 4, 2, 0, 16, 16, 72, 32, 24, True),
+    "qkv-halves-128-groups-window": (
+        "bsm", 4, 2, 0, 128, 128, 64, 32, 24, True),
+    "qkv-halves-16-groups-window-padded": (
+        "bsm", 6, 2, 0, 16, 16, 50, 16, 20, True),
+    "qkv-pairs-16-causal-head-major": (
+        "bhsd", 2, 2, 0, 16, 16, 48, 16, None, False),
+    "qkv-halves-last-8-of-24-cross": (
+        "bshd", 2, 2, 16, 8, 24, 40, 16, None, True),
+}
+
+
+def _rotary_case(case, rotary_in_kernels):
+    """Loss -> ((out, lse), (dq, dk, dv)) of one case, either the kernels
+    rotating q (``q_rotary``) or ``rotary`` in front of the same call."""
+    from horovod_tpu.models.transformer import rotary, rotary_tables
+    from horovod_tpu.ops.pallas_kernels import QRotary
+
+    entry, h, h_kv, n, r, dv, sq, block, window, halves = _ROTARY_CASES[case]
+    d, theta = n + r, 1e4
+    causal = "cross" not in case
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    q = jax.random.normal(keys[0], (1, sq, h, d))
+    w = jax.random.normal(keys[3], (1, sq, h, dv))
+    if entry == "latent":
+        k = jax.random.normal(keys[1], (1, sq, h * (n + dv)))
+        v = jax.random.normal(keys[2], (1, sq, r))
+    else:
+        k = jax.random.normal(keys[1], (1, sq, h_kv, d))
+        v = jax.random.normal(keys[2], (1, sq, h_kv, dv))
+    tables = QRotary(
+        *rotary_tables(sq, r, theta=theta), halves=halves, start=n
+    )
+
+    def loss(q, k, v):
+        kw = dict(causal=causal, block_q=block, block_k=block)
+        if rotary_in_kernels:
+            kw["q_rotary"] = tables
+        else:
+            q = jnp.concatenate([
+                q[..., :n], rotary(q[..., n:], theta=theta, halves=halves)
+            ], axis=-1)
+        if entry == "latent":
+            out, lse = flash_attention_latent(
+                q.reshape(1, sq, h * d), k, v, n_heads=h, **kw
+            )
+            out = out.reshape(1, sq, h, dv)
+        else:
+            layout = lambda x: _in_layout(x, entry)  # noqa: E731
+            out, lse = flash_attention_with_lse(
+                layout(q), layout(k), layout(v), layout=entry, window=window,
+                **(dict(n_heads=h, n_kv_heads=h_kv) if entry == "bsm" else {}),
+                **kw,
+            )
+            out = _from_layout(out, entry, dv)
+        return jnp.sum(out * w) + 0.1 * jnp.sum(lse ** 2), (out, lse)
+
+    with jax.default_matmul_precision("highest"):
+        grads, results = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+        )(q, k, v)
+    return results, grads
+
+
+@pytest.mark.parametrize("case", list(_ROTARY_CASES))
+def test_flash_rotates_q_as_rotary_in_front_of_the_same_call(case):
+    """out, ``lse``, dq, dk and dv (with the latent entry ``d[k_nope | v]``
+    and the shared key's) from one trace, the kernels' own rotation against
+    ``rotary`` + the same entry without tables: adjacent pairs on the last
+    lanes of a latent head, halves with query groups under a window, padded
+    lengths, every layout.  dq is the gradient of the UNROTATED q: the
+    other side reaches it by autodiff through ``rotary``."""
+    (out, lse), got = _rotary_case(case, True)
+    (ref_out, ref_lse), want = _rotary_case(case, False)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def _rotary_calls(tables, *, latent, interpret=False):
+    """The three ``pallas_call`` equations of forward + backward at the
+    cells' widths (4 heads of 128 + 64 / 128 in pairs, or 14 on 2 K/V heads
+    of 128 in halves under a window), by kernel name."""
+    from horovod_tpu.models.transformer import rotary_tables
+    from horovod_tpu.ops.pallas_kernels import QRotary
+
+    s = 1024
+    x = lambda w: jax.ShapeDtypeStruct((1, s, w), jnp.bfloat16)  # noqa: E731
+    if latent:
+        q_rotary = QRotary(*rotary_tables(s, 64, theta=32e6), start=128)
+        shapes = (x(4 * 192), x(4 * 256), x(64))
+    else:
+        q_rotary = QRotary(*rotary_tables(s, 128, theta=1.5e6), halves=True)
+        shapes = (x(14 * 128), x(2 * 128), x(2 * 128))
+    kw = dict(q_rotary=q_rotary) if tables else {}
+
+    def loss(q, k, v):
+        if latent:
+            out, _ = flash_attention_latent(
+                q, k, v, n_heads=4, causal=True, interpret=interpret, **kw
+            )
+        else:
+            out = flash_attention(
+                q, k, v, causal=True, window=512, layout="bsm", n_heads=14,
+                n_kv_heads=2, interpret=interpret, **kw
+            )
+        return out.astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*shapes)
+    return traced, {
+        e.params["name"].replace("_window", ""): e
+        for e in _walk(traced.jaxpr) if e.primitive.name == "pallas_call"
+    }
+
+
+@pytest.mark.parametrize("latent", [True, False], ids=["latent", "window"])
+def test_rotary_turns_in_the_forward_and_dq_and_nowhere_else(latent):
+    """With tables: the forward takes the ``[S, 2 r]`` table and writes a
+    second array shaped like q, the turned q, which dK/dV and dQ read in
+    q's place (dK/dV is the call it was: no table, no rotation); dQ takes
+    the table and writes q's gradient in q's dtype.  The rotations are lane
+    rolls in the forward's and dQ's bodies; between the kernels nothing
+    touches q or dq."""
+    traced, calls = _rotary_calls(True, latent=latent)
+    _, plain = _rotary_calls(False, latent=latent)
+    assert sorted(calls) == sorted(plain) == [
+        "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd"
+    ]
+    r, q_shape = (64, (1, 1024, 768)) if latent else (128, (1, 1024, 1792))
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]  # noqa: E731
+    rolls = lambda e: sum(  # noqa: E731
+        1 for x in _walk(e.params["jaxpr"]) if x.primitive.name == "roll"
+    )
+    for name, call in calls.items():
+        extra = [s for s in shapes(call.invars)
+                 if s not in shapes(plain[name].invars)]
+        assert extra == ([] if "dkv" in name else [(1024, 2 * r)]), name
+        assert bool(rolls(call)) == ("dkv" not in name), name
+        assert not rolls(plain[name])
+    fwd, dq = calls["hvd_flash_fwd"], calls["hvd_flash_bwd_dq"]
+    assert shapes(fwd.outvars).count(q_shape) == (
+        1 + shapes(plain["hvd_flash_fwd"].outvars).count(q_shape)
+    )
+    turned_q = fwd.outvars[-1]
+    for name in ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"):
+        assert turned_q in calls[name].invars, name
+    assert str(calls["hvd_flash_bwd_dkv"].params["jaxpr"]) == str(
+        plain["hvd_flash_bwd_dkv"].params["jaxpr"]
+    )
+    (dq_out,) = dq.outvars
+    assert dq_out.aval.dtype == jnp.bfloat16
+    assert dq_out in traced.jaxpr.outvars  # as it leaves the kernel
+    # a head of the program's (2 / 7): pairs a roll either way, halves one
+    # roll by half the width
+    assert rolls(dq) == rolls(fwd) == (2 * 2 if latent else 7)
+
+
+def test_a_call_without_tables_traces_what_it_traced():
+    """``q_rotary=None`` is the call without the argument, equation for
+    equation, in both entries and with the kernels compiled or interpreted
+    (the parent's jaxprs themselves were compared when the argument came:
+    CHANGES.md, PR 41)."""
+    x = lambda w: jax.ShapeDtypeStruct((2, 256, w), jnp.bfloat16)  # noqa: E731
+
+    def traced(entry, **kw):
+        def loss(q, k, v):
+            out = entry(q, k, v, causal=True, n_heads=4, **kw)
+            out = out[0] if isinstance(out, tuple) else out
+            return out.astype(jnp.float32).sum()
+        return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))
+
+    for interpret in (False, True):
+        for entry, kw, shapes in (
+            (flash_attention, dict(layout="bsm"), (x(256), x(256), x(256))),
+            (flash_attention_latent, {}, (x(768), x(1024), x(64))),
+        ):
+            kw = dict(kw, interpret=interpret)
+            assert str(traced(entry, **kw)(*shapes)) == str(
+                traced(entry, q_rotary=None, **kw)(*shapes)
+            )
+
+
+def test_rotary_q_counter_counts_the_kernels_that_turn():
+    from horovod_tpu.obs import registry
+
+    counter = registry.always().counter("flash.calls.rotary_q")
+
+    def counted(tables, latent):
+        before = counter.get()
+        _rotary_calls(tables, latent=latent, interpret=True)
+        return counter.get() - before
+
+    assert counted(False, True) == counted(False, False) == 0
+    assert counted(True, True) == 2 == counted(True, False)  # forward, dQ
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(rows=71), "Sq=72"), (dict(start=20), "head width"),
+    (dict(compiled=True), "multiples of 64"),
+])
+def test_rotary_tables_that_do_not_fit_q_are_refused(bad, match):
+    from horovod_tpu.models.transformer import rotary_tables
+    from horovod_tpu.ops.pallas_kernels import QRotary
+
+    q = jnp.zeros((1, 72, 2 * (64 if "compiled" in bad else 24)))
+    tables = QRotary(
+        *rotary_tables(bad.get("rows", 72), 8, theta=1e4),
+        start=bad.get("start", 16),
+    )
+    with pytest.raises(ValueError, match=match):
+        flash_attention(
+            q, q, q, causal=True, layout="bsm", n_heads=2, q_rotary=tables,
+            interpret=not bad.get("compiled", False),
+        )

@@ -251,6 +251,65 @@ def test_flash_attention_window_and_groups_compile(v5e, window):
     assert (p.kv_steps, p.q_steps) == ((6, 11) if window else (16, 32))
 
 
+@pytest.mark.parametrize(
+    "cell", ["latent-pairs-2x4096x32", "window-halves-1x16384x28",
+             "latent-pairs-s1000x8-padded"],
+)
+def test_flash_entries_with_rotary_tables_compile(v5e, cell):
+    """``q_rotary`` at the two expert cells' widths: adjacent pairs on the
+    last 64 of a head's 192 lanes (latent entry, groups of 2 heads) and
+    halves on the whole of a head's 128 under the window with 7 query heads
+    a program; and a padded length.  Forward, dK/dV and dQ stay three
+    calls; the forward writes a second array shaped like q (the turned q,
+    the backward's residual) and dQ writes the unrotated q's gradient in
+    q's dtype: no float32 array of q's shape exists around the kernels."""
+    from horovod_tpu.models.transformer import rotary_tables
+
+    latent = cell.startswith("latent")
+    padded = cell.endswith("padded")
+    b, s, h = (4, 1000, 8) if padded else (2, 4096, 32) if latent else (
+        1, 16384, 28
+    )
+
+    def loss(q, k, v):
+        if latent:
+            out, lse = pk.flash_attention_latent(
+                q, k, v, causal=True, n_heads=h, interpret=False,
+                q_rotary=pk.QRotary(
+                    *rotary_tables(s, 64, theta=32e6), start=128
+                ),
+            )
+        else:
+            out, lse = pk.flash_attention_with_lse(
+                q, k, v, causal=True, window=4096, layout="bsm", n_heads=h,
+                n_kv_heads=4, interpret=False,
+                q_rotary=pk.QRotary(
+                    *rotary_tables(s, 128, theta=1.5e6), halves=True
+                ),
+            )
+        return out.astype(jnp.float32).sum() + (lse ** 2).sum()
+
+    widths = (192 * h, 256 * h, 64) if latent else (128 * h, 512, 512)
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), v5e,
+        *(((b, s, w), jnp.bfloat16) for w in widths),
+    )
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    if latent:  # (in the window call ``out`` has q's shape, and delta reads it)
+        assert not re.search(rf"f32\[{b},{s},{widths[0]}\]", hlo)
+    if not padded:
+        # q's cotangent is the dQ kernel's result itself
+        root = hlo[hlo.index("\nENTRY"):].split("ROOT ")[1]
+        assert re.search(r"tuple\(%[\w.]*hvd_flash_bwd_dq", root), root[:300]
+        q_shape = rf"bf16\[{b},{s},{widths[0]}\]"
+        (fwd,) = [c for c in calls if "hvd_flash_fwd" in c]
+        # q in; out is narrower in the latent call, so: q, the turned q
+        # (and out, where a head's v is as wide as its q)
+        assert len(re.findall(q_shape, fwd)) == (2 if latent else 3)
+
+
 def test_reglu_expert_layer_compiles_at_the_window_cell_shapes(v5e):
     """The expert layer of the window cell: 16,384 tokens, top-6 of 64 by
     the softmax over the chosen, 8 ReLU-gated experts held at 2560 x 768:
@@ -287,7 +346,10 @@ def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):
     the kernels is gone by ``op_name`` (the rotary key's broadcast to the
     heads, the keys' concatenation, the split of dK and the sum that
     rebuilt ``[dk_nope | dv]``), and ``kv_b``'s matmul output is the
-    forward kernel's operand, no ``copy`` between."""
+    forward kernel's operand, no ``copy`` between.  Since PR 41 the same
+    holds of q: the kernels rotate it, so neither q's concatenation nor its
+    sum in the backward is left, nothing is shaped ``[.., 32, 32, 2]``, and
+    ``q_b``'s matmul output is the forward kernel's operand."""
     import re
 
     import horovod_tpu as hvd
@@ -327,12 +389,14 @@ def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):
     assert results("kv_b/dot_general")
     # the rotary key is no longer broadcast to 32 heads
     assert not results("broadcast_in_dim")
-    # of the two [.., 32, 192] concatenations q's is left, and its sum
-    # in the backward; dK is not cut into dk_nope and the rotary part, and
-    # nothing puts [dk_nope | dv] together for kv_b's backward
+    # of the two [.., 32, 192] concatenations neither is left (q's, and
+    # its sum in the backward, went in PR 41); dK is not cut into dk_nope
+    # and the rotary part, and nothing puts [dk_nope | dv] together for
+    # kv_b's backward
     wide = lambda shapes, dims: [s for s in shapes if f"[2,4096,{dims}]" in s]  # noqa: E731
-    assert len(wide(results("concatenate"), "32,192")) == 1
-    assert len(wide(results("add_any"), "32,192")) == 1
+    assert not wide(results("concatenate"), "32,192")
+    assert not wide(results("add_any"), "32,192")
+    assert "[2,4096,32,32,2]" not in hlo and "[2,4096,32,32,1]" not in hlo
     assert not wide(results("split"), "32,128")
     assert not wide(results("add_any"), "32,256")
     assert not wide(results("add_any"), "8192")
@@ -347,6 +411,12 @@ def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):
     ]
     assert " copy(" not in defined[kv], defined[kv]
     assert "kv_b/dot_general" in defined[kv], defined[kv]
+    (q,) = [  # q, and not the turned q the call itself writes
+        name for name in operands
+        if re.search(r"bf16\[2,4096,6144\]", defined[name].split(" = ", 1)[1])
+    ]
+    assert " copy(" not in defined[q], defined[q]
+    assert "q_b/dot_general" in defined[q], defined[q]
 
 
 def test_local_expert_layer_compiles_at_the_cell_buffer_shapes(v5e):

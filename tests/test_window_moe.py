@@ -150,6 +150,56 @@ def test_flash_path_hands_the_kernels_the_projections_as_they_are(
             for v in calls[f"hvd_flash_bwd_dkv{suffix}"].outvars] == [
         (1, SEQ, narrow), (1, SEQ, narrow)
     ]
+    # PR 41: a rotated layer's kernels rotate q themselves, so the q they
+    # get is the projection's matmul output, and q's cotangent is dQ's
+    # result as it leaves; the table is the one 2-D operand beside them
+    forward = jax.make_jaxpr(loss)(params, x).jaxpr.eqns
+    (entry,) = [e for e in forward if e.primitive.name == "custom_vjp_call"]
+    (made_q,) = [e for e in forward if entry.invars[0] in e.outvars]
+    assert made_q.primitive.name == "dot_general"
+    for name, call in calls.items():
+        tables = [tuple(v.aval.shape) for v in call.invars
+                  if v.aval.ndim == 2 and v.aval.shape[0] == SEQ]
+        turns = rotate and "dkv" not in name
+        assert tables == ([(SEQ, 2 * cfg.head_dim)] if turns else []), name
+    halves = [e for e in traced.jaxpr.eqns if e.outvars and tuple(
+        e.outvars[0].aval.shape) == (1, SEQ, cfg.n_heads, cfg.head_dim // 2)]
+    assert not halves, halves  # only k (n_kv_heads) is rotated outside
+
+
+@pytest.mark.parametrize("windowed", [True, False])
+def test_rotated_layer_through_the_kernels_equals_the_xla_path(windowed):
+    """Output and every gradient of a rotated layer: the kernels rotating
+    q (interpreter) against ``use_flash=False``'s ``rotary`` in XLA."""
+    cfg = _tiny()
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, cfg.d_model))
+    w = jax.random.normal(jax.random.PRNGKey(6), x.shape)
+    window = cfg.window if windowed else None
+    params = GroupedAttention(cfg, window=window, rotate=True).init(
+        jax.random.PRNGKey(0), x
+    )
+
+    def run(use_flash):
+        attn = GroupedAttention(
+            dataclasses.replace(cfg, use_flash=use_flash), window=window,
+            rotate=True,
+        )
+
+        def loss(p, x):
+            out = attn.apply(p, x)
+            return (out * w).sum(), out
+
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(
+                params, x
+            )
+
+    (got, got_dx), got_out = run(True)
+    (want, want_dx), want_out = run(False)
+    np.testing.assert_allclose(got_out, want_out, atol=2e-6)
+    np.testing.assert_allclose(got_dx, want_dx, atol=2e-5)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()))
 
 
 def test_band_mask_is_the_written_rule():

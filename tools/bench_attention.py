@@ -3,6 +3,7 @@
     python tools/bench_attention.py [--tree CHECKOUT] [--iters 8]
         [--heads 12] [--head-dim 64] [--v-head-dim 64] [--shape NAME]
         [--block-q N --block-k N] [--kv-heads N] [--window N]
+        [--rotary none|pairs|halves]
 
 Runs forward + backward of ``flash_attention`` alone (one layer's worth) at
 the shapes of the benchmark's flash cells — GPT-2-small (16 x 1024, causal)
@@ -23,7 +24,14 @@ skipped where ``--tree`` has none). ``window-1x16384`` is the window cell's
 attention (1 x 16,384, causal, 28 query heads on 4 K/V heads of 128, window
 4,096; ``--window 0`` times its full layers, ``--kv-heads 28`` a head of K/V a
 query head; the error check's 1,024 positions lie inside the window, so it
-checks the groups, and ``tests/test_pallas_kernels.py`` the band). ``--tree`` imports
+checks the groups, and ``tests/test_pallas_kernels.py`` the band).
+``--rotary pairs|halves`` adds, after each entry's plain row, a row
+``<entry>+rotary`` of the same call given ``q_rotary`` tables (the kernels
+rotate q and turn dQ back; at ``latent`` a head's last 64 lanes, elsewhere
+the whole head), checked against float32 attention on q rotated by
+``models.transformer.rotary``: the two rows' difference is what the turn
+costs the kernels; a tree without the argument prints the plain rows only.
+``--tree`` imports
 ``horovod_tpu`` from another checkout (a parent commit unpacked beside
 this one), so two commits can be timed in one chip call. This is where a
 kernel change is judged before a cell is run; ``benchmark/split.py`` gives
@@ -130,6 +138,8 @@ def main():
     ap.add_argument("--kv-heads", type=int,
                     help="K/V heads that the query heads share")
     ap.add_argument("--window", type=int, help="0: none")
+    ap.add_argument("--rotary", choices=("none", "pairs", "halves"),
+                    default="none", help="also time each entry with q_rotary")
     args = ap.parse_args()
     sys.path.insert(0, args.tree)
     from horovod_tpu.ops import pallas_kernels
@@ -159,15 +169,40 @@ def main():
         if window:
             blocks["window"] = window
 
-        def qkv(q, k, v):
+        def qkv(q, k, v, **rotary):
             return pallas_kernels.flash_attention(
-                q, k, v, causal=causal, layout="bsm", n_heads=heads, **blocks
+                q, k, v, causal=causal, layout="bsm", n_heads=heads,
+                **blocks, **rotary
             )
 
-        def latent(q, kv, k_rope):
+        def latent(q, kv, k_rope, **rotary):
             return pallas_kernels.flash_attention_latent(
-                q, kv, k_rope, causal=causal, n_heads=heads, **blocks
+                q, kv, k_rope, causal=causal, n_heads=heads, **blocks,
+                **rotary
             )[0]
+
+        # the rotated lanes of a q head: its last ``rope``, or all of it
+        turned = rope or d
+        variants = [("", None)]
+        if args.rotary != "none" and hasattr(pallas_kernels, "QRotary"):
+            from horovod_tpu.models import transformer
+
+            halves = args.rotary == "halves"
+            variants.append(("+rotary", lambda length: dict(
+                q_rotary=pallas_kernels.QRotary(
+                    *transformer.rotary_tables(length, turned, theta=1e6),
+                    halves=halves, start=d - turned,
+                )
+            )))
+
+            def rotated(q):
+                """q as ``rotary`` turns it, for the float32 loss."""
+                q4 = q.astype(jnp.float32).reshape(*q.shape[:2], heads, d)
+                return jnp.concatenate([
+                    q4[..., :d - turned], transformer.rotary(
+                        q4[..., d - turned:], theta=1e6, halves=halves
+                    ),
+                ], axis=-1).reshape(q.shape)
 
         def exact(q, k, v, w):
             return reference(q, k, v, w, causal, heads, kv_heads, window)
@@ -183,7 +218,9 @@ def main():
                     q, *built_keys(kv, k_rope, heads, d - rope), w
                 ),
             )
-        for entry, (call, widths, exact_loss) in entries.items():
+        for (entry, (call, widths, exact_loss)), (suffix, tables) in (
+            (e, v) for e in entries.items() for v in variants
+        ):
             keys = jax.random.split(jax.random.PRNGKey(0), 4)
             argv = [
                 jax.random.normal(key, (b, s, width), jnp.float32)
@@ -191,10 +228,14 @@ def main():
             ]
             argv = [x.astype(jnp.bfloat16) for x in argv[:3]] + argv[3:]
 
-            def loss(*operands, call=call):
-                out = call(*operands[:3])
+            def loss(*operands, call=call, tables=tables):
+                rotary = tables(operands[0].shape[1]) if tables else {}
+                out = call(*operands[:3], **rotary)
                 return (out.astype(jnp.float32) * operands[3]).sum()
 
+            if tables:
+                exact_loss = (lambda q, *rest, f=exact_loss:  # noqa: E731
+                              f(rotated(q), *rest))
             flash = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
             us = kernel_us(flash, argv, args.iters)
             small = [x[:2, :1024] for x in argv]
@@ -204,8 +245,8 @@ def main():
                 for a, e in zip(flash(*small), want)
             ]
             print(json.dumps(dict(
-                tree=args.tree, shape=shape, entry=entry, heads=heads,
-                head_dim=d, v_head_dim=dv, blocks=blocks,
+                tree=args.tree, shape=shape, entry=entry + suffix,
+                heads=heads, head_dim=d, v_head_dim=dv, blocks=blocks,
                 device_kind=device.device_kind, us_per_call=us,
                 total_us=sum(us.values()), grad_abs_err_vs_f32=errors,
             )), flush=True)
