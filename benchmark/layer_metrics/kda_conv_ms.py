@@ -1,0 +1,13 @@
+"""Milliseconds per step in the operations under the program's
+``jax.named_scope("kda_conv")``: the Kimi Delta Attention layers' three
+depthwise causal convolutions and SiLU: forward, the forward run again from
+the projections' outputs, and backward. Device trace, worst device,
+forward, backward and what rematerialisation runs again; a fusion counts
+under the one scope its label names (``lib/by_name.py``). Nothing to read
+in a program without the scope."""
+
+from benchmark.lib.by_name import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "kda_conv")

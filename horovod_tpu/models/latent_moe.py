@@ -20,10 +20,12 @@ Attention (``H`` heads, ``n`` = ``qk_nope_dim``, ``r`` = ``qk_rope_dim``,
 
     c_q = RMSNorm(x W_qa)                      [q_lora_rank]
     q = c_q W_qb            -> H x (n + r):    [q_nope | q_rope]
+                   (``q_lora_rank=None``: q = x W_q, one projection)
     [c_kv | k_r] = x W_kva                     [kv_lora_rank + r]
     [k_nope | v] = RMSNorm(c_kv) W_kvb  -> H x (n + v)
     q_rope, k_r <- rotary(theta, interleaved pairs, no scaling); every head
-                   of a position shares the one k_r
+                   of a position shares the one k_r (``use_rope=False``,
+                   NoPE: nothing is rotated)
     q = [q_nope | q_rope], k = [k_nope | k_r]
     out = concat_H(causal softmax(q k^T / sqrt(n + r)) v) W_o
 
@@ -100,12 +102,16 @@ class LatentMoEConfig:
     n_layers: int = 40
     n_dense_layers: int = 1  # leading blocks with a dense FFN
     n_heads: int = 32
-    q_lora_rank: int = 1536
+    # None: one ``q`` projection (no ``q_a`` / ``q_norm`` / ``q_b``)
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_dim: int = 128
     rope_theta: float = 32e6
+    # False (NoPE): nothing is rotated, the shared key goes to the kernels
+    # as ``kv_a`` leaves it
+    use_rope: bool = True
     d_ff_dense: int = 7168
     d_ff_expert: int = 768
     n_experts: int = 256  # the router's width
@@ -159,17 +165,20 @@ class LatentAttention(nn.Module):
         if use_flash is None:
             use_flash = device_platform() == "tpu"
         with jax.named_scope("mla_proj"):
-            q = dense(h * (n + r), "q_b")(
-                norm("q_norm")(dense(cfg.q_lora_rank, "q_a")(x))
-            )
+            if cfg.q_lora_rank is None:
+                q = dense(h * (n + r), "q")(x)
+            else:
+                q = dense(h * (n + r), "q_b")(
+                    norm("q_norm")(dense(cfg.q_lora_rank, "q_a")(x))
+                )
             kv_a = dense(cfg.kv_lora_rank + r, "kv_a")(x)
             # [k_nope | v] a head, heads on the lanes: as the kernels take it
             kv = dense(h * (n + v), "kv_b")(
                 norm("kv_norm")(kv_a[..., :cfg.kv_lora_rank])
             )
-            k_rope = rotary(
-                kv_a[..., cfg.kv_lora_rank:], theta=cfg.rope_theta
-            )
+            k_rope = kv_a[..., cfg.kv_lora_rank:]
+            if cfg.use_rope:
+                k_rope = rotary(k_rope, theta=cfg.rope_theta)
         if use_flash:
             from ..ops.pallas_kernels import QRotary, flash_attention_latent
 
@@ -178,14 +187,15 @@ class LatentAttention(nn.Module):
             out, _ = flash_attention_latent(
                 q, kv, k_rope, causal=True, n_heads=h, q_rotary=QRotary(
                     *rotary_tables(s, r, theta=cfg.rope_theta), start=n
-                ),
+                ) if cfg.use_rope else None,
             )
         else:
             with jax.named_scope("mla_proj"):
                 q = q.reshape(b, s, h, n + r)
-                q = jnp.concatenate([
-                    q[..., :n], rotary(q[..., n:], theta=cfg.rope_theta)
-                ], axis=-1)
+                if cfg.use_rope:
+                    q = jnp.concatenate([
+                        q[..., :n], rotary(q[..., n:], theta=cfg.rope_theta)
+                    ], axis=-1)
                 kv = kv.reshape(b, s, h, n + v)
                 k = jnp.concatenate([
                     kv[..., :n],

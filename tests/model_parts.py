@@ -1,4 +1,4 @@
-"""Tiny builds of the four model families and a walk over a traced step,
+"""Tiny builds of the five model families and a walk over a traced step,
 for the tests that hold the models' parts (``jax.named_scope``) to their
 rules: ``test_step_tracing.py`` on the CPU, ``test_tpu_compile.py`` compiled
 for the described v5e. Widths are the smallest the compiled kernels take
@@ -14,6 +14,7 @@ from jax.extend import core as jcore
 PARTS = ("embed", "norm", "attn_proj", "attn_xla", "attn_layout", "mlp",
          "head")
 EXPERT_SCOPES = ("mla_proj", "moe_route", "moe_experts")
+KDA_SCOPES = ("kda_proj", "kda_conv", "kda_gate")
 SEQ = 128
 
 
@@ -117,6 +118,29 @@ def _window_moe():
     return "WindowMoELM", build
 
 
+def _linear_moe():
+    from horovod_tpu.models.linear_moe import (
+        LinearMoEConfig, LinearMoELM, lm_loss,
+    )
+
+    def build(use_flash):
+        # KDA heads of 128: a head's tile fills the lanes; both kernel
+        # families or neither
+        cfg = LinearMoEConfig.tiny(
+            d_model=128, kda_head_dim=128, qk_nope_dim=64, qk_rope_dim=64,
+            v_dim=64, use_flash=use_flash, use_kernel=use_flash,
+        )
+        model = LinearMoELM(cfg)
+
+        def loss(model, params, tokens):
+            logits = model.apply({"params": params}, tokens[:, :-1])
+            return lm_loss(logits, None, tokens, mtp_weight=0.0)
+
+        return model, _lm(model, loss), {"tokens": (SEQ + 1,)}
+
+    return "LinearMoELM", build
+
+
 # id -> (family, use_flash); the golden parameter paths are per family
 CASES = {
     "gpt2-flash": ("gpt2", True),
@@ -128,6 +152,8 @@ CASES = {
     "latent-moe-xla": ("latent_moe", False),
     "window-moe-flash": ("window_moe", True),
     "window-moe-xla": ("window_moe", False),
+    "linear-moe-kernels": ("linear_moe", True),
+    "linear-moe-xla": ("linear_moe", False),
 }
 _FAMILIES = {
     "gpt2": lambda: _gpt2(),
@@ -136,6 +162,7 @@ _FAMILIES = {
     "bert_cls": lambda: _bert(2),
     "latent_moe": _latent_moe,
     "window_moe": _window_moe,
+    "linear_moe": _linear_moe,
 }
 
 

@@ -144,14 +144,16 @@ def _traced_step(world, case):
 def test_every_operation_of_apply_carries_exactly_one_part(world, case):
     """Inside the model's ``apply`` (the top module's name is in the
     stack) every operation lies under ``hvd_grad`` and under exactly one
-    of the seven parts or the expert model's three scopes, so the readers
+    of the seven parts, the expert model's three scopes or the Kimi Delta
+    Attention mixer's three, so the readers
     by scope count each operation once; ``mtp`` is the one scope that lies
     over a part; a Mosaic call carries none, so no reader by scope counts
     a kernel that ``flash_ms`` has. An operation with literals only for
     operands is a constant the compiler folds (the zero cotangent that
     ``custom_vjp`` makes for the unused ``lse``): no device time, no rule."""
     top, jaxpr = _traced_step(world, case)
-    one_of = model_parts.PARTS + model_parts.EXPERT_SCOPES
+    one_of = (model_parts.PARTS + model_parts.EXPERT_SCOPES
+              + model_parts.KDA_SCOPES)
     inside, kernels, seen = 0, 0, set()
     for primitive, stack, computed in model_parts.operations(jaxpr.jaxpr):
         if top not in stack:
@@ -163,13 +165,14 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
         if primitive == "pallas_call":
             kernels += 1
             assert not held, (stack, held)
-            assert segments[-1].startswith("hvd_flash_"), stack
+            assert segments[-1].startswith(("hvd_flash_", "hvd_kda_")), stack
         elif computed:
             assert len(held) == 1, (primitive, stack, held)
         seen.update(held)
     assert inside > 500
     family, use_flash = model_parts.CASES[case]
-    per_family = {"latent_moe": 9, "window_moe": 12}  # 3 and 4 blocks
+    # 3 and 4 blocks; one latent layer's 3 and four KDA layers' 2 each
+    per_family = {"latent_moe": 9, "window_moe": 12, "linear_moe": 11}
     assert kernels == (per_family.get(family, 6) if use_flash else 0)
     # each family opens what the table in docs/api.md says it does
     attention = {"attn_layout"} if use_flash else {"attn_xla"}
@@ -179,6 +182,10 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
     elif family == "window_moe":  # no dense feed-forward, so no ``mlp``
         want = {"embed", "norm", "head", "attn_proj", "attn_layout",
                 "moe_route", "moe_experts"} | attention
+    elif family == "linear_moe":  # no ``attn_proj``: two mixers' own scopes
+        want = {"embed", "norm", "mlp", "head", "attn_layout",
+                *model_parts.EXPERT_SCOPES,
+                *model_parts.KDA_SCOPES} | attention
     else:
         want = {"embed", "norm", "mlp", "head", "attn_proj"} | attention
     assert seen == want

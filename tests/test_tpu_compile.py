@@ -207,6 +207,36 @@ def test_flash_attention_latent_compiles(v5e, batch, seq, heads):
     assert hlo.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize(
+    "batch,seq,heads", [(1, 8192, 32), (2, 1000, 4)],
+    ids=["kda-1x8192x32", "kda-s1000x4-padded"],
+)
+def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads):
+    """The Kimi Delta Attention kernels, forward and backward, at the
+    cell's shape (1 x 8,192, 32 heads of 128 key and value channels,
+    bfloat16 operands, float32 ``g`` and ``beta``) and at a padded length:
+    two Mosaic calls, the chunks' entry states the only array between
+    them that the entry did not take or hand back."""
+    from horovod_tpu.ops.kda_kernels import kda_attention
+
+    def loss(q, k, v, g, beta):
+        return kda_attention(
+            q, k, v, g, beta, n_heads=heads, use_kernel=True,
+            interpret=False,
+        ).astype(jnp.float32).sum()
+
+    wide = ((batch, seq, 128 * heads), jnp.bfloat16)
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), v5e, wide, wide, wide,
+        ((batch, seq, 128 * heads), jnp.float32),
+        ((batch, seq, heads), jnp.float32),
+    )
+    assert hlo.count("tpu_custom_call") == 2
+    assert "hvd_kda_fwd" in hlo and "hvd_kda_bwd" in hlo
+    padded = -(-seq // 128) * 128
+    assert f"bf16[{batch},{heads},{padded // 64},128,128]" in hlo
+
+
 @pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
 def test_flash_attention_window_and_groups_compile(v5e, window):
     """The window cell's attention, 1 x 16,384 with 28 query heads on 4

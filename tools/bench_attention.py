@@ -95,8 +95,9 @@ def reference(q, k, v, w, causal, heads, kv_heads=None, window=None):
     return (out.reshape(w.shape) * w).sum()
 
 
-def kernel_us(fn, argv, iters):
-    """Median device microseconds per call of each flash kernel."""
+def kernel_us(fn, argv, iters, kernels=KERNELS):
+    """Median device microseconds per call of each kernel named in
+    ``kernels`` (substrings of the trace's names)."""
     for _ in range(2):  # compile, then settle
         jax.block_until_ready(fn(*argv))
     trace_dir = tempfile.mkdtemp(prefix="flash_trace")
@@ -104,7 +105,7 @@ def kernel_us(fn, argv, iters):
         for _ in range(iters):
             out = fn(*argv)
         jax.block_until_ready(out)
-    durations = {name: [] for name in KERNELS}
+    durations = {name: [] for name in kernels}
     for path in glob.glob(
         os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb")
     ):
@@ -115,7 +116,7 @@ def kernel_us(fn, argv, iters):
                 if line.name != "XLA Ops":
                     continue
                 for ev in line.events:
-                    name = next((k for k in KERNELS if k in ev.name), None)
+                    name = next((k for k in kernels if k in ev.name), None)
                     if name:
                         durations[name].append(ev.duration_ns / 1e3)
     if not all(durations.values()):

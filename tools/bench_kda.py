@@ -1,0 +1,145 @@
+"""Kernels only, on the chip: the Kimi Delta Attention family by name.
+
+    python tools/bench_kda.py [--tree CHECKOUT] [--iters 8] [--shape NAME]
+        [--heads 32] [--sub N ...] [--block-chunks N ...] [--no-recurrence]
+
+Runs forward + backward of ``ops/kda_kernels.kda_attention`` alone (one
+layer's call) at the shape of the benchmark's cell, ``kda-1x8192``: 1 x
+8,192 positions, 32 heads of 128 key and value channels, bf16 operands,
+float32 ``g`` and ``beta``, ``g`` drawn as the configuration's ``assumed``
+initial values draw it (``-A softplus(.)``, ``A`` log-uniform in 1..16 a
+head, the step log-uniform in 1e-3..1e-1 a channel). Under
+``jax.profiler.trace`` it prints one JSON line a variant: the median device
+microseconds a call of ``hvd_kda_fwd`` / ``hvd_kda_bwd`` (read from the
+device plane's ``XLA Ops`` line), their sum's share of the floor that
+``benchmark/lib/flops_linear_moe.kda_cost`` gives the recurrence on the
+device's peaks, and the largest absolute error of the output and of the
+five gradients against the ``use_kernel=False`` path (the recurrence a
+position at a time in float32) on the same operands, whose own wall time
+per call (host clock, forward + backward) is printed beside them.
+``--sub`` / ``--block-chunks`` (may repeat) time the plan's statics at other
+values than the module's; ``--tree`` imports ``horovod_tpu`` and
+``benchmark`` from another checkout, as ``tools/bench_attention.py`` does.
+"""
+import argparse
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from bench_attention import kernel_us  # tools/ is this script's directory
+
+KERNELS = ("hvd_kda_fwd", "hvd_kda_bwd")
+# name: (batch, sequence, heads, key channels, value channels)
+SHAPES = {"kda-1x8192": (1, 8192, 32, 128, 128)}
+
+
+def operands(key, b, s, h, dk, dv):
+    """q, k, v as a convolution's SiLU leaves them (bf16), g and beta
+    (float32) at the assumed initial values, and the loss's weights."""
+    keys = jax.random.split(key, 8)
+    silu = lambda k, d: jax.nn.silu(  # noqa: E731
+        jax.random.normal(k, (b, s, h * d), jnp.float32)
+    ).astype(jnp.bfloat16)
+    a = jnp.exp(jax.random.uniform(keys[3], (h, 1), minval=0.0,
+                                   maxval=np.log(16.0)))
+    dt = jnp.exp(jax.random.uniform(keys[4], (h, dk), minval=np.log(1e-3),
+                                    maxval=np.log(1e-1)))
+    pre = jnp.log(jnp.expm1(dt)) + 0.1 * jax.random.normal(
+        keys[5], (b, s, h, dk)
+    )
+    g = (-a * jax.nn.softplus(pre)).reshape(b, s, h * dk)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[6], (b, s, h)))
+    w = jax.random.normal(keys[7], (b, s, h * dv), jnp.float32)
+    return [silu(keys[0], dk), silu(keys[1], dk), silu(keys[2], dv), g,
+            beta, w]
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tree", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--iters", type=int, default=8)
+    ap.add_argument("--shape", choices=sorted(SHAPES), default="kda-1x8192")
+    ap.add_argument("--heads", type=int, help="default: the shape's own")
+    ap.add_argument("--sub", type=int, action="append",
+                    help="rows a sub-block (default: the module's)")
+    ap.add_argument("--block-chunks", type=int, action="append",
+                    help="chunks a grid step (default: the module's)")
+    ap.add_argument("--no-recurrence", action="store_true",
+                    help="skip the use_kernel=False path and the errors")
+    args = ap.parse_args()
+    sys.path.insert(0, args.tree)
+    from benchmark.lib.flops import roofline
+    from benchmark.lib.flops_linear_moe import kda_cost
+    from benchmark.lib.peaks import peak_for
+    from horovod_tpu.ops import kda_kernels
+    from horovod_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        raise SystemExit(
+            "bench_kda times the compiled kernels; no TPU found "
+            f"({device.platform}) and the interpreter's time means nothing"
+        )
+    peak = peak_for(device.device_kind)
+    b, s, h, dk, dv = SHAPES[args.shape]
+    h = args.heads or h
+    argv = operands(jax.random.PRNGKey(0), b, s, h, dk, dv)
+    cost = kda_cost(batch=b, seq_len=s, n_heads=h, d_k=dk, d_v=dv, layers=1)
+    floor = roofline(cost["flops"], cost["bytes"], peak.bf16_flops,
+                     peak.hbm_bytes_per_s)
+
+    def grads(use_kernel, **statics):
+        def loss(q, k, v, g, beta, w):
+            out = kda_kernels.kda_attention(
+                q, k, v, g, beta, n_heads=h, use_kernel=use_kernel, **statics
+            )
+            return (out.astype(jnp.float32) * w).sum(), out
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    want = seconds = None
+    if not args.no_recurrence:
+        plain = grads(False)
+        jax.block_until_ready(plain(*argv))
+        start = time.perf_counter()
+        want = jax.block_until_ready(plain(*argv))
+        seconds = time.perf_counter() - start
+    module_chunks = kda_kernels.BLOCK_CHUNKS
+    for sub in args.sub or [None]:
+        for chunks in args.block_chunks or [module_chunks]:
+            kda_kernels.BLOCK_CHUNKS = chunks
+            fn = grads(True, sub=sub)
+            us = kernel_us(fn, argv, args.iters, KERNELS)
+            errors = None
+            if want is not None:
+                got = fn(*argv)
+                errors = {
+                    name: float(jnp.max(jnp.abs(
+                        a.astype(jnp.float32) - e.astype(jnp.float32)
+                    ))) for name, a, e in zip(
+                        ("dq", "dk", "dv", "dg", "dbeta", "out"),
+                        (*got[0], got[1]), (*want[0], want[1]),
+                    )
+                }
+            total = sum(us.values())
+            print(json.dumps(dict(
+                tree=args.tree, shape=args.shape, heads=h,
+                sub=sub or kda_kernels.SUB, block_chunks=chunks,
+                device_kind=device.device_kind, us_per_call=us,
+                total_us=total, floor_us=floor["seconds"] * 1e6,
+                floor_bound=floor["bound"],
+                share_of_floor_pct=100.0 * floor["seconds"] * 1e6 / total,
+                abs_err_vs_recurrence=errors,
+                recurrence_wall_s_per_call=seconds,
+            )), flush=True)
+    kda_kernels.BLOCK_CHUNKS = module_chunks
+
+
+if __name__ == "__main__":
+    main()
