@@ -33,7 +33,11 @@ value channels, ``u = RMSNorm(h)``)::
 
 The recurrence (and the L2 norms in front of it) is
 ``ops/kda_kernels.kda_attention``: the chunked kernels on the TPU, the
-recurrence a position at a time elsewhere. The latent layer is
+recurrence a position at a time elsewhere. On the kernel path the
+convolutions and SiLU are the kernels' too (``conv=``: the mixer hands
+over its taps and ``q~ k~ v~``, and ``q^ k^ v^`` exist in VMEM only); on
+the recurrence path :func:`conv_silu` runs in front of it, the one
+definition of that mathematics outside the kernels. The latent layer is
 ``latent_moe.LatentAttention`` with ``q_lora_rank=None`` and
 ``use_rope=False`` (one ``q`` projection, NOTHING rotated, the shared
 ``k_r`` to the flash kernels as ``kv_a`` leaves it); the expert layer is
@@ -44,16 +48,19 @@ FFN. Output: final RMSNorm, an untied head over the vocabulary slice,
 logits in fp32, next-token cross entropy (``transformer.lm_loss``).
 
 What the backward keeps of a KDA layer: the three projections' outputs
-``q~ k~ v~`` (the convolution and SiLU run again from them: ``conv_silu``
-has its backward written out), the convolution's outputs ``q^ k^ v^`` (the kernels'
-residual; they normalise in VMEM), ``g``, ``beta``, the chunks' entry
-states, ``o`` and the two low-rank gate inputs (``g`` and the gated norm
-run again from those).
+``q~ k~ v~`` (the kernels' residual: the backward kernel convolves,
+gates and normalises a block in VMEM again; on the recurrence path
+``conv_silu``, whose backward is written out, runs again from them and
+its outputs ``q^ k^ v^`` are kept as well), the taps, ``g``, ``beta``, the
+chunks' entry states, ``o`` and the two low-rank gate inputs (``g`` and
+the gated norm run again from those).
 
 Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
 operation of ``apply`` lies under exactly one of: ``embed``, ``norm``,
 ``kda_proj`` (the q / k / v / o projections, both low-rank gate pairs,
-beta's), ``kda_conv`` (the three convolutions and SiLU), ``kda_gate``
+beta's), ``kda_conv`` (the three convolutions and SiLU on the recurrence
+path; on the kernel path the sum and layout of the taps' partial
+gradients, which the kernels' entry opens itself), ``kda_gate``
 (softplus and the decay's scale, beta's sigmoid, the gated head-wise
 RMSNorm), ``mla_proj``, ``attn_layout`` (the kernels' entries' own glue),
 ``attn_xla`` (latent attention where flash is bypassed), ``mlp``,
@@ -72,7 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from ..context import device_platform
-from ..ops.kda_kernels import kda_attention
+from ..ops.kda_kernels import KdaConv, kda_attention
 from .latent_moe import LatentAttention, RoutedExperts
 from .transformer import GatedMlp, RMSNorm, lm_loss  # noqa: F401  (lm_loss)
 
@@ -240,8 +247,10 @@ class KimiDeltaAttention(nn.Module):
         matrix = lambda name, shape: self.param(  # noqa: E731
             name, _init(cfg), shape, jnp.float32
         )
-        taps = {x: self.param(f"conv_{x}", _taps, (cfg.conv_size, width),
-                              jnp.float32) for x in "qkv"}
+        taps = KdaConv(*(
+            self.param(f"conv_{x}", _taps, (cfg.conv_size, width), jnp.float32)
+            for x in "qkv"
+        ))
         f_b, g_b = matrix("f_b", (rank, width)), matrix("g_b", (rank, width))
         w_beta = matrix("b", (cfg.d_model, h))
         a_log = self.param("A_log", _decay_rates, (h,), jnp.float32)
@@ -250,12 +259,16 @@ class KimiDeltaAttention(nn.Module):
             "o_norm", nn.initializers.ones, (d,), jnp.float32
         )
 
+        use_kernel = cfg.use_kernel
+        if use_kernel is None:
+            use_kernel = device_platform() == "tpu"
         with jax.named_scope("kda_proj"):
             q, k, v = (dense(width, x)(u) for x in "qkv")
             f_a, g_a = dense(rank, "f_a")(u), dense(rank, "g_a")(u)
             beta_logits = _to(cfg.dtype, u, w_beta)
-        with jax.named_scope("kda_conv"):
-            q, k, v = (conv_silu(x, taps[n]) for x, n in zip((q, k, v), "qkv"))
+        if not use_kernel:  # the kernels convolve at their door, in VMEM
+            with jax.named_scope("kda_conv"):
+                q, k, v = (conv_silu(x, w) for x, w in zip((q, k, v), taps))
 
         @jax.checkpoint
         def log_decay(f_a, f_b, dt_bias, a_log):
@@ -268,11 +281,9 @@ class KimiDeltaAttention(nn.Module):
         g = log_decay(f_a, f_b, dt_bias, a_log)
         with jax.named_scope("kda_gate"):
             beta = jax.nn.sigmoid(beta_logits)
-        use_kernel = cfg.use_kernel
-        if use_kernel is None:
-            use_kernel = device_platform() == "tpu"
         if use_kernel:
-            o = kda_attention(q, k, v, g, beta, n_heads=h, use_kernel=True)
+            o = kda_attention(q, k, v, g, beta, n_heads=h, conv=taps,
+                              use_kernel=True)
         else:
             with jax.named_scope("attn_xla"):
                 o = kda_attention(q, k, v, g, beta, n_heads=h,

@@ -17,6 +17,27 @@ UN-normalised (the convolution's output after SiLU): both kernels
 normalise a tile in VMEM, so the convolution's output is the one copy of
 each operand the backward keeps.
 
+With ``conv=KdaConv(...)`` (the taps of a depthwise causal convolution
+for each of q, k, v) the operands are the PROJECTIONS' outputs and the
+convolution and SiLU are the kernels' as well: a grid step first stages
+each operand's block in float32 behind the last rows of the block before
+it (a second, 16-row block spec on the same operand; zeros in the
+sequence's first block), reads the staged rows at the taps' static row
+offsets, and writes ``SiLU(conv)`` to a VMEM scratch that the chunks read
+where they read the operand; nothing convolved is live while a chunk is
+worked and nothing convolved ever reaches HBM. The backward (blocks in
+reverse) does the same, keeps SiLU's derivative beside it, stages the
+gradients it forms of the convolved operands (times that derivative)
+``[block + 8, d]`` with the FIRST rows of the block after it behind them
+(left by the grid step before; zeros at the sequence's end), and reads
+the convolution's transpose and the taps' gradients off that stage: ``dx_t
+= sum_i w_i d_{t + n - 1 - i}``, ``dW_i = sum_t d_{t + n - 1 - i} x_t``
+(the same shifted reads serve both). ``dW`` accumulates over a head's
+blocks in one float32 ``[8, d]`` output block (rows >= n zero) that the
+sequence axis revisits; XLA sums it over the batch under the scope
+``kda_conv``. Same two kernels, one body each with a static branch: a
+call without ``conv`` traces what it traced.
+
 The chunked form (``C`` rows a chunk, ``G_i = sum_{j<=i} g_j`` inside the
 chunk, ``S_0`` the state the chunk enters with; rows ``i``, ``j``)::
 
@@ -66,7 +87,8 @@ back ``dq``, ``dk``, ``dv`` (the operands' dtype, of the UN-normalised
 Kernel names: ``hvd_kda_fwd``, ``hvd_kda_bwd``. The entry's own XLA glue
 (padding, ``dbeta``'s layout) is under the scope ``attn_layout``; the
 ``pallas_call``s are under none. Build-time counters (always on):
-``kda.calls`` (one a kernel built), ``kda.chunks`` (chunks a forward call
+``kda.calls`` (one a kernel built), ``kda.calls.conv`` (of those, the
+kernels that convolve), ``kda.chunks`` (chunks a forward call
 visits: ``B H S_pad / C``), ``kda.state_bytes_saved`` (bytes of entry
 states a forward call leaves for its backward).
 
@@ -90,7 +112,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..context import device_platform
 from ..obs import registry as _registry
 
-__all__ = ["kda_attention", "kda_recurrence"]
+__all__ = ["KdaConv", "kda_attention", "kda_recurrence"]
 
 _VMEM = pltpu.VMEM
 _GLUE_SCOPE = "attn_layout"
@@ -105,6 +127,23 @@ _LOG2E = math.log2(math.e)
 CHUNK = 64
 SUB = 8
 BLOCK_CHUNKS = 2
+# With ``conv``: the rows of the block before that a grid step reads beside
+# its own (one packed tile of a 16-bit operand; their last ``_EDGE`` are
+# staged in front of the block's), and the rows a float32 tile has: what a
+# block hands the block before it in the backward.
+_HALO = 16
+_EDGE = 8
+_CONV_SCOPE = "kda_conv"
+
+
+class KdaConv(NamedTuple):
+    """The taps ``[n, H * d]`` float32 of the depthwise causal convolutions
+    that :func:`kda_attention` does at its door, each followed by SiLU:
+    ``y_t = SiLU(sum_i w_i x_{t - (n - 1) + i})``, zeros before row 0 (tap
+    ``n - 1`` meets the position itself)."""
+    q: jax.Array
+    k: jax.Array
+    v: jax.Array
 
 
 class _Plan(NamedTuple):
@@ -121,6 +160,7 @@ class _Plan(NamedTuple):
     # of q, k, v, out, the saved states and the MXU's operands (float32:
     # every matmul at full precision)
     dtype: object
+    taps: int = 0  # of the convolution the kernels do at their door; 0: none
 
     @property
     def n_chunks(self) -> int:
@@ -133,7 +173,8 @@ class _Plan(NamedTuple):
 
 
 def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
-          sub: Optional[int], interpret: Optional[bool]) -> _Plan:
+          sub: Optional[int], interpret: Optional[bool],
+          conv: Optional[KdaConv] = None) -> _Plan:
     b, s, width = q.shape
     h = n_heads
     if width % h or v.shape[-1] % h or beta.shape != (b, s, h):
@@ -158,9 +199,23 @@ def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
     block = chunk * min(per_block, -(-s // chunk))
     if block > 128 and block % 128:
         block = -(-block // 128) * 128
+    taps = 0
+    if conv is not None:
+        taps = conv.q.shape[0]
+        want = [(taps, width), (taps, width), (taps, v.shape[-1])]
+        if [w.shape for w in conv] != want or not 1 <= taps <= _EDGE + 1:
+            raise ValueError(
+                f"conv taps {[w.shape for w in conv]}: not {want} with 1 "
+                f"to {_EDGE + 1} taps"
+            )
+        if block % _HALO:
+            raise ValueError(
+                f"with conv a grid step's rows ({block}: chunk {chunk}) "
+                f"are a multiple of {_HALO}"
+            )
     return _Plan(
         b, s, -(-s // block) * block, h, dk, dv, chunk, sub, block,
-        interpret, q.dtype,
+        interpret, q.dtype, taps,
     )
 
 
@@ -364,15 +419,56 @@ def _as_row(column):
 
 
 # ---------------------------------------------------------------------------
+# The convolution at the door (``conv=``). A grid step stages each operand's
+# block in float32 behind the last ``_EDGE`` rows of the block before it
+# (zeros in the sequence's first block), convolves and gates it into a VMEM
+# scratch BEFORE the chunks are worked, so that nothing of it is live in
+# them; the chunks read that scratch where they read the operand's block.
+# ---------------------------------------------------------------------------
+
+
+def _convolve(x_ref, halo_ref, taps_ref, stage, out, slope, at_start):
+    """One operand at the door: stages ``[the halo's last rows | the
+    block]`` in ``stage [_EDGE + block, d]``, float32, and writes ``SiLU``
+    of the block's convolution to ``out [block, d]`` (and SiLU's derivative
+    there to ``slope``, where the backward brings one). ``at_start``: this
+    is the sequence's first block, whose halo is zeros."""
+    n = taps_ref.shape[0]
+    halo = halo_ref[0, _HALO - _EDGE:, :].astype(jnp.float32)
+    stage[:_EDGE, :] = jnp.where(at_start, 0.0, halo)
+    stage[_EDGE:, :] = x_ref[0].astype(jnp.float32)
+    c = sum(
+        taps_ref[i:i + 1, :]
+        * stage[pl.ds(_EDGE - (n - 1 - i), out.shape[0]), :]
+        for i in range(n)
+    )
+    gate = jax.nn.sigmoid(c)
+    out[...] = c * gate
+    if slope is not None:
+        slope[...] = gate * (1.0 + c * (1.0 - gate))
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
-                state, *, p: _Plan):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, p: _Plan):
     c = p.chunk
     dt = p.dtype
-    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    own = (q_ref, k_ref, v_ref)
+    if p.taps:
+        halos, taps, o_ref, states_ref, state, stages, convolved = rest
+        at_start = pl.program_id(2) == 0
+        for x in range(3):
+            _convolve(own[x], halos[x], taps[x], stages[x], convolved[x],
+                      None, at_start)
+        read = lambda x, rows: convolved[x][rows, :]  # noqa: E731
+    else:
+        o_ref, states_ref, state = rest
+        read = lambda x, rows: own[x][0, rows, :].astype(  # noqa: E731
+            jnp.float32
+        )
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -382,11 +478,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
     for i in range(p.block // c):
         rows = slice(i * c, (i + 1) * c)
         beta = _head_column(beta_ref[0, rows, :], head)
-        ch = _chunk(f32(q_ref[0, rows, :]), f32(k_ref[0, rows, :]),
-                    g_ref[0, rows, :], beta, sub=p.sub, dt=dt)
+        ch = _chunk(read(0, rows), read(1, rows), g_ref[0, rows, :], beta,
+                    sub=p.sub, dt=dt)
         # U = u_hat - w S_0: both through the inverse before S_0 is read
         w = _nn(ch.inv, beta * (ch.k * ch.e), dt)
-        u_hat = _nn(ch.inv, beta * f32(v_ref[0, rows, :]), dt)
+        u_hat = _nn(ch.inv, beta * read(2, rows), dt)
         s0 = state[...]  # [dv, dk]
         states_ref[0, 0, i] = s0.astype(states_ref.dtype)
         u = u_hat - _nt(w, s0, dt)
@@ -415,6 +511,30 @@ def _specs(p: _Plan, block_of):
     return wide, beta, states
 
 
+def _door_specs(p: _Plan, block_of):
+    """With ``conv``: the specs of q, k and v's halos (the ``_HALO`` rows
+    that end where the grid step's block starts; the first block reads its
+    own first rows and masks them) and of their taps (a head's ``[n, d]``)."""
+    per_block = p.block // _HALO
+    halo = lambda d: pl.BlockSpec(  # noqa: E731
+        (1, _HALO, d),
+        lambda bi, hi, i: (
+            bi, jnp.maximum(block_of(i) * per_block - 1, 0), hi
+        ),
+        memory_space=_VMEM,
+    )
+    taps = lambda d: pl.BlockSpec(  # noqa: E731
+        (p.taps, d), lambda bi, hi, i: (0, hi), memory_space=_VMEM
+    )
+    widths = (p.dk, p.dk, p.dv)
+    return [tuple(halo(d) for d in widths), tuple(taps(d) for d in widths)]
+
+
+def _tiles(p: _Plan, rows: int):
+    """A float32 ``[rows, d]`` VMEM scratch for each of q, k and v."""
+    return tuple(_VMEM((rows, d), jnp.float32) for d in (p.dk, p.dk, p.dv))
+
+
 def _params():
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -432,20 +552,29 @@ def _pad(x, p: _Plan):
 def _book(p: _Plan, forward: bool) -> None:
     reg = _registry.always()
     reg.counter("kda.calls").inc()
+    if p.taps:
+        reg.counter("kda.calls.conv").inc()
     if forward:
         reg.counter("kda.chunks").inc(p.b * p.h * p.n_chunks)
         reg.counter("kda.state_bytes_saved").inc(p.state_bytes)
 
 
 @functools.partial(jax.jit, static_argnames=("p",), inline=True)
-def _fwd_call(q, k, v, g, beta, *, p: _Plan):
+def _fwd_call(q, k, v, g, beta, conv=None, *, p: _Plan):
     with jax.named_scope(_GLUE_SCOPE):
         q, k, v, g, beta = (_pad(x, p) for x in (q, k, v, g, beta))
     wide, beta_spec, states_spec = _specs(p, lambda i: i)
+    in_specs = [wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk), beta_spec]
+    operands = [q, k, v, g, beta]
+    scratch = [_VMEM((p.dv, p.dk), jnp.float32)]
+    if p.taps:
+        in_specs += _door_specs(p, lambda i: i)
+        operands += [(q, k, v), tuple(conv)]
+        scratch += [_tiles(p, _EDGE + p.block), _tiles(p, p.block)]
     out, states = pl.pallas_call(
         functools.partial(_fwd_kernel, p=p),
         grid=(p.b, p.h, p.s_pad // p.block),
-        in_specs=[wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk), beta_spec],
+        in_specs=in_specs,
         out_specs=[wide(p.dv), states_spec],
         out_shape=[
             jax.ShapeDtypeStruct((p.b, p.s_pad, p.h * p.dv), p.dtype),
@@ -453,11 +582,11 @@ def _fwd_call(q, k, v, g, beta, *, p: _Plan):
                 (p.b, p.h, p.n_chunks, p.dv, p.dk), p.dtype
             ),
         ],
-        scratch_shapes=[_VMEM((p.dv, p.dk), jnp.float32)],
+        scratch_shapes=scratch,
         compiler_params=_params(),
         interpret=p.interpret,
         name="hvd_kda_fwd",
-    )(q, k, v, g, beta)
+    )(*operands)
     with jax.named_scope(_GLUE_SCOPE):
         return out[:, :p.s], states
 
@@ -524,11 +653,35 @@ def _pair_backward(ch: _Chunk, dp_kk, dp_qk, dp_kk_t, dp_qk_t, *, sub, dt):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
-                p: _Plan):
+                *rest, p: _Plan):
     c = p.chunk
     dt = p.dtype
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    own = (q_ref, k_ref, v_ref)
+    if p.taps:
+        (halos, taps, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dtaps,
+         dstate, stages, convolved, slopes, ahead) = rest
+        at_start = pl.program_id(2) == p.s_pad // p.block - 1
+        for x in range(3):
+            _convolve(own[x], halos[x], taps[x], stages[x], convolved[x],
+                      slopes[x], at_start)
+        read = lambda x, rows: convolved[x][rows, :]  # noqa: E731
+
+        def write(x, rows, grad):  # before the SiLU, float32, staged
+            ahead[x][rows, :] = grad * slopes[x][rows, :]
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            for x in range(3):
+                ahead[x][p.block:, :] = jnp.zeros_like(ahead[x][p.block:, :])
+                dtaps[x][...] = jnp.zeros_like(dtaps[x])
+    else:
+        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate = rest
+        read = lambda x, rows: f32(own[x][0, rows, :])  # noqa: E731
+
+        def write(x, rows, grad):
+            ref = (dq_ref, dk_ref, dv_ref)[x]
+            ref[0, rows, :] = grad.astype(ref.dtype)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -539,11 +692,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     dbeta = {}
     for i in reversed(range(p.block // c)):
         rows = slice(i * c, (i + 1) * c)
-        q_raw, k_raw = f32(q_ref[0, rows, :]), f32(k_ref[0, rows, :])
+        q_raw, k_raw = read(0, rows), read(1, rows)
         beta = _head_column(beta_ref[0, rows, :], head)
         ch = _chunk(q_raw, k_raw, g_ref[0, rows, :], beta, sub=p.sub, dt=dt)
         s0 = f32(states_ref[0, 0, i])  # [dv, dk]
-        z = f32(v_ref[0, rows, :]) - _nt(ch.k * ch.e, s0, dt)
+        z = read(2, rows) - _nt(ch.k * ch.e, s0, dt)
         u = _nn(ch.inv, beta * z, dt)
         ds = dstate[...]  # [dv, dk], of the state this chunk leaves
         do = f32(do_ref[0, rows, :])
@@ -588,13 +741,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
             to_last, 0.0,
         )
         dg_ref[0, rows, :] = _sum_over(col >= row, d_big_g)
-        dq_ref[0, rows, :] = _l2_bwd(
-            q_raw, ch.rq, p.dk ** -0.5, dq
-        ).astype(dq_ref.dtype)
-        dk_ref[0, rows, :] = _l2_bwd(k_raw, ch.rk, 1.0, dk).astype(
-            dk_ref.dtype
-        )
-        dv_ref[0, rows, :] = dz.astype(dv_ref.dtype)
+        write(0, rows, _l2_bwd(q_raw, ch.rq, p.dk ** -0.5, dq))
+        write(1, rows, _l2_bwd(k_raw, ch.rk, 1.0, dk))
+        write(2, rows, dz)
         dstate[...] = ds * ch.e_last + _tn(
             jnp.concatenate([do, -dz], axis=0),
             jnp.concatenate([ch.q * ch.e, ch.k * ch.e], axis=0), dt,
@@ -610,9 +759,30 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         )
         dbeta_ref[0, 0, :, first:first + piece] = _as_row(column)
 
+    if p.taps:
+        # the convolution's transpose: a row's gradient reaches the rows
+        # up to n - 1 BEFORE it, so the block's first rows wait in
+        # ``ahead`` for the block before, which the next grid step works
+        for x, d_ref in enumerate((dq_ref, dk_ref, dv_ref)):
+            grads, d_taps = ahead[x], dtaps[x]
+            mine = stages[x][_EDGE:, :]  # the block's own rows, float32
+            tap = lax.broadcasted_iota(jnp.int32, d_taps.shape[2:], 0)
+            dx = jnp.zeros_like(mine)
+            dw = jnp.zeros(d_taps.shape[2:], jnp.float32)
+            for i in range(p.taps):
+                later = grads[pl.ds(p.taps - 1 - i, p.block), :]
+                dx = dx + taps[x][i:i + 1, :] * later
+                dw = dw + jnp.where(
+                    tap == i, jnp.sum(later * mine, axis=0, keepdims=True),
+                    0.0,
+                )
+            d_ref[0] = dx.astype(d_ref.dtype)
+            d_taps[0, 0] += dw
+            grads[p.block:, :] = grads[:_EDGE, :]
+
 
 @functools.partial(jax.jit, static_argnames=("p",), inline=True)
-def _bwd_call(q, k, v, g, beta, states, d_out, *, p: _Plan):
+def _bwd_call(q, k, v, g, beta, states, d_out, conv=None, *, p: _Plan):
     with jax.named_scope(_GLUE_SCOPE):
         q, k, v, g, beta, d_out = (
             _pad(x, p) for x in (q, k, v, g, beta, d_out)
@@ -620,47 +790,80 @@ def _bwd_call(q, k, v, g, beta, states, d_out, *, p: _Plan):
     last = p.s_pad // p.block - 1
     wide, beta_spec, states_spec = _specs(p, lambda i: last - i)
     like = lambda x, dtype: jax.ShapeDtypeStruct(x.shape, dtype)  # noqa: E731
-    dq, dk, dv, dg, dbeta = pl.pallas_call(
+    in_specs = [wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk), beta_spec,
+                states_spec, wide(p.dv)]
+    operands = [q, k, v, g, beta, states, d_out]
+    out_specs = [
+        wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk),
+        pl.BlockSpec(
+            (1, 1, 1, p.block), lambda bi, hi, i: (bi, hi, 0, last - i),
+            memory_space=_VMEM,
+        ),
+    ]
+    out_shape = [
+        like(q, p.dtype), like(k, p.dtype), like(v, p.dtype),
+        like(g, jnp.float32),
+        jax.ShapeDtypeStruct((p.b, p.h, 1, p.s_pad), jnp.float32),
+    ]
+    scratch = [_VMEM((p.dv, p.dk), jnp.float32)]
+    if p.taps:
+        in_specs += _door_specs(p, lambda i: last - i)
+        operands += [(q, k, v), tuple(conv)]
+        # the taps' gradients: a head's partial sums, one block that the
+        # sequence axis revisits, rows >= n zero
+        out_specs.append(tuple(
+            pl.BlockSpec((1, 1, _EDGE, d), lambda bi, hi, i: (bi, hi, 0, 0),
+                         memory_space=_VMEM)
+            for d in (p.dk, p.dk, p.dv)
+        ))
+        out_shape.append(tuple(
+            jax.ShapeDtypeStruct((p.b, p.h, _EDGE, d), jnp.float32)
+            for d in (p.dk, p.dk, p.dv)
+        ))
+        # the staged blocks, the convolved ones, SiLU's derivative there,
+        # the gradients before the SiLU with the block after's first rows
+        scratch += [_tiles(p, _EDGE + p.block), _tiles(p, p.block),
+                    _tiles(p, p.block), _tiles(p, p.block + _EDGE)]
+    dq, dk, dv, dg, dbeta, *d_taps = pl.pallas_call(
         functools.partial(_bwd_kernel, p=p),
         grid=(p.b, p.h, p.s_pad // p.block),
-        in_specs=[wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk), beta_spec,
-                  states_spec, wide(p.dv)],
-        out_specs=[
-            wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk),
-            pl.BlockSpec(
-                (1, 1, 1, p.block), lambda bi, hi, i: (bi, hi, 0, last - i),
-                memory_space=_VMEM,
-            ),
-        ],
-        out_shape=[
-            like(q, p.dtype), like(k, p.dtype), like(v, p.dtype),
-            like(g, jnp.float32),
-            jax.ShapeDtypeStruct((p.b, p.h, 1, p.s_pad), jnp.float32),
-        ],
-        scratch_shapes=[_VMEM((p.dv, p.dk), jnp.float32)],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=_params(),
         interpret=p.interpret,
         name="hvd_kda_bwd",
-    )(q, k, v, g, beta, states, d_out)
+    )(*operands)
     with jax.named_scope(_GLUE_SCOPE):
         dbeta = jnp.swapaxes(dbeta[:, :, 0, :p.s], 1, 2)  # [B, S, H]
-        return (dq[:, :p.s], dk[:, :p.s], dv[:, :p.s], dg[:, :p.s], dbeta)
+        grads = (dq[:, :p.s], dk[:, :p.s], dv[:, :p.s], dg[:, :p.s], dbeta)
+    if not p.taps:
+        return grads, None
+    with jax.named_scope(_CONV_SCOPE):
+        # [B, H, _EDGE, d] partial sums -> [n, H d], summed over the batch
+        return grads, KdaConv(*(
+            jnp.moveaxis(x.sum(axis=0)[:, :p.taps], 0, 1).reshape(p.taps, -1)
+            for x in d_taps[0]
+        ))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda(q, k, v, g, beta, p: _Plan):
-    return _kda_fwd(q, k, v, g, beta, p)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _kda(q, k, v, g, beta, conv, p: _Plan):
+    return _kda_fwd(q, k, v, g, beta, conv, p)[0]
 
 
-def _kda_fwd(q, k, v, g, beta, p: _Plan):
+def _kda_fwd(q, k, v, g, beta, conv, p: _Plan):
     _book(p, forward=True)
-    out, states = _fwd_call(q, k, v, g, beta, p=p)
-    return out, (q, k, v, g, beta, states)
+    out, states = _fwd_call(q, k, v, g, beta, conv, p=p)
+    return out, (q, k, v, g, beta, states, conv)
 
 
 def _kda_bwd(p: _Plan, residuals, d_out):
     _book(p, forward=False)
-    return _bwd_call(*residuals, d_out, p=p)
+    *operands, conv = residuals
+    grads, d_taps = _bwd_call(*operands, d_out, conv, p=p)
+    return (*grads, d_taps)
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
@@ -708,6 +911,7 @@ def kda_recurrence(q, k, v, g, beta, *, n_heads: int, group: int = 64):
 
 
 def kda_attention(q, k, v, g, beta, *, n_heads: int,
+                  conv: Optional[KdaConv] = None,
                   use_kernel: Optional[bool] = None,
                   interpret: Optional[bool] = None,
                   chunk: Optional[int] = None, sub: Optional[int] = None):
@@ -718,6 +922,15 @@ def kda_attention(q, k, v, g, beta, *, n_heads: int,
     of each key channel (<= 0) and ``beta`` the write strength, both
     float32. Differentiable in all five.
 
+    ``conv``: a :class:`KdaConv` of three tap arrays ``[n, H * d]`` float32
+    (kernels only). ``q``, ``k``, ``v`` are then the PROJECTIONS' outputs:
+    both kernels convolve a block's rows (depthwise, causal, zeros before
+    row 0) and gate them with SiLU in VMEM, in float32, before anything
+    else reads them, so the convolved operands never exist in HBM and the
+    projections' outputs are the one copy the backward keeps.
+    Differentiable in the taps too. ``None`` is the call without the
+    argument, equation for equation.
+
     ``use_kernel``: None takes the Pallas kernels where the world's
     devices are TPUs and the recurrence (:func:`kda_recurrence`) elsewhere;
     True runs the kernels anywhere (interpreted off the TPU). ``chunk`` /
@@ -725,12 +938,19 @@ def kda_attention(q, k, v, g, beta, *, n_heads: int,
     if use_kernel is None:
         use_kernel = device_platform() == "tpu"
     if not use_kernel:
+        if conv is not None:
+            raise ValueError(
+                "conv= is the kernels': on the recurrence path "
+                "(use_kernel=False) hand in q, k, v convolved"
+            )
         return kda_recurrence(q, k, v, g, beta, n_heads=n_heads).astype(
             v.dtype
         )
     p = _plan(q, v, beta, n_heads=n_heads, chunk=chunk, sub=sub,
-              interpret=interpret)
+              interpret=interpret, conv=conv)
+    if conv is not None:
+        conv = KdaConv(*(w.astype(jnp.float32) for w in conv))
     return _kda(
         q, k.astype(q.dtype), v.astype(q.dtype), g.astype(jnp.float32),
-        beta.astype(jnp.float32), p,
+        beta.astype(jnp.float32), conv, p,
     )

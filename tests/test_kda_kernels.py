@@ -4,7 +4,11 @@ at a time in float32: the output and every gradient (q, k, v, g, beta),
 over chunk counts, head widths, batches and heads; the strongest decay the
 configuration's initial values give; the two reductions the delta rule
 has; that neither the chunk nor the sub-block size changes the result; the
-counters.
+counters. With ``conv=`` (the kernels convolve and gate q, k, v at their
+door) the same cases again, the three taps' gradients too, against
+``models.linear_moe.conv_silu`` in front of the recurrence AND in front of
+the kernels without taps; a block's border is causal; a call without taps
+builds the kernels it built.
 """
 
 import jax
@@ -12,9 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.models.linear_moe import conv_silu
 from horovod_tpu.obs import registry
 from horovod_tpu.ops import kda_kernels
-from horovod_tpu.ops.kda_kernels import kda_attention, kda_recurrence
+from horovod_tpu.ops.kda_kernels import KdaConv, kda_attention, kda_recurrence
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -56,25 +61,57 @@ def operands(b, s, h, d, *, seed=0, decay=0.3, dtype=jnp.float32):
     return (q, k, v, g, beta), draw(keys[5], h * d)
 
 
-def both(argv, weights, h, **statics):
-    """``(out, gradients)`` of the kernels and of the recurrence."""
-    def loss(fn):
-        def of(*a):
-            out = fn(*a)
-            return jnp.sum(out.astype(jnp.float32) * weights), out
-        return jax.value_and_grad(of, argnums=(0, 1, 2, 3, 4), has_aux=True)
+def taps_for(h, d, *, n=4, seed=7):
+    """Taps as the model draws them, U(-0.5, 0.5)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return KdaConv(*(
+        jax.random.uniform(k, (n, h * d), jnp.float32, -0.5, 0.5)
+        for k in keys
+    ))
 
-    kernels = lambda *a: kda_attention(  # noqa: E731
-        *a, n_heads=h, use_kernel=True, **statics
+
+def value_and_grads(fn, weights, argv):
+    """``(out, gradients...)`` of ``fn`` in every operand of ``argv``."""
+    def of(*a):
+        out = fn(*a)
+        return jnp.sum(out.astype(jnp.float32) * weights), out
+    (_, out), grads = jax.value_and_grad(
+        of, argnums=tuple(range(len(argv))), has_aux=True
+    )(*argv)
+    return (out, *grads)
+
+
+def both(argv, weights, h, conv=None, **statics):
+    """``(out, gradients)`` of the kernels and of the recurrence; with
+    ``conv`` the kernels are given the taps (three more gradients) and
+    there are two references: ``conv_silu`` in front of the recurrence
+    and in front of the kernels without taps."""
+    kernels = lambda *a, **kw: kda_attention(  # noqa: E731
+        *a, n_heads=h, use_kernel=True, **statics, **kw
     )
     plain = lambda *a: recurrence(*a, n_heads=h)  # noqa: E731
-    (_, out), grads = loss(kernels)(*argv)
-    (_, want), want_grads = loss(plain)(*argv)
-    return (out, *grads), (want, *want_grads)
+    if conv is None:
+        return (value_and_grads(kernels, weights, argv),
+                value_and_grads(plain, weights, argv))
+
+    def convolved(fn):
+        return lambda q, k, v, g, beta, *taps: fn(
+            *(conv_silu(x, w) for x, w in zip((q, k, v), taps)), g, beta
+        )
+
+    argv = (*argv, *conv)
+    inside = lambda *a: kernels(*a[:5], conv=KdaConv(*a[5:]))  # noqa: E731
+    return (value_and_grads(inside, weights, argv),
+            value_and_grads(convolved(plain), weights, argv),
+            value_and_grads(convolved(kernels), weights, argv))
+
+
+NAMES = ("out", "dq", "dk", "dv", "dg", "dbeta", "dtaps_q", "dtaps_k",
+         "dtaps_v")
 
 
 def assert_close(got, want, tol):
-    for name, a, e in zip(("out", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+    for name, a, e in zip(NAMES, got, want):
         a, e = np.asarray(a, np.float32), np.asarray(e, np.float32)
         assert np.isfinite(a).all(), name
         scale = max(float(np.abs(e).max()), 1e-6)
@@ -83,18 +120,29 @@ def assert_close(got, want, tol):
         )
 
 
+CONV = pytest.mark.parametrize("conv", [False, True], ids=["plain", "conv"])
+
+
+@CONV
 @pytest.mark.parametrize("b,s,h,d,chunk,sub", [
     (1, 16, 1, 16, 16, 8),    # one chunk
     (2, 48, 2, 16, 16, 8),    # three chunks, batch and heads > 1
     (1, 40, 2, 16, 16, 16),   # no multiple of the chunk: padded
     (1, 40, 1, 128, 32, 8),   # heads of 128, four sub-blocks a chunk
     (1, 128, 1, 128, 64, 8),  # the plan's own chunk and sub-block
-], ids=["1-chunk", "3-chunks", "ragged", "d128", "plan"])
-def test_out_and_every_gradient_equal_the_recurrence(b, s, h, d, chunk, sub):
+    (1, 3, 1, 16, 16, 8),     # shorter than a convolution's taps
+    (2, 200, 2, 16, 16, 8),   # two grid steps a head, the second ragged
+], ids=["1-chunk", "3-chunks", "ragged", "d128", "plan", "3-rows",
+        "2-blocks"])
+def test_out_and_every_gradient_equal_the_recurrence(b, s, h, d, chunk, sub,
+                                                     conv):
     argv, weights = operands(b, s, h, d)
-    got, want = both(argv, weights, h, chunk=chunk, sub=sub)
+    got, *wants = both(argv, weights, h, conv=taps_for(h, d) if conv else None,
+                       chunk=chunk, sub=sub)
     assert got[0].shape == (b, s, h * d)
-    assert_close(got, want, 2e-5)
+    assert len(got) == (9 if conv else 6) and len(wants) == 1 + conv
+    for want in wants:
+        assert_close(got, want, 2e-5)
 
 
 def test_the_strongest_assumed_decay_stays_finite_and_exact():
@@ -112,11 +160,17 @@ def test_the_strongest_assumed_decay_stays_finite_and_exact():
     assert_close(got, want, 2e-5)
 
 
-def test_bfloat16_operands_stay_within_their_rounding():
+@CONV
+def test_bfloat16_operands_stay_within_their_rounding(conv):
+    """With taps the convolved values stay float32 into the norm (one
+    rounding fewer than ``conv_silu``'s bfloat16 output, never one more)."""
     argv, weights = operands(1, 64, 2, 16, dtype=jnp.bfloat16)
-    got, want = both(argv, weights, 2, chunk=16, sub=8)
+    got, *wants = both(argv, weights, 2, conv=taps_for(2, 16) if conv else
+                       None, chunk=16, sub=8)
     assert got[0].dtype == jnp.bfloat16 and got[4].dtype == jnp.float32
-    assert_close(got, want, 4e-2)
+    assert all(x.dtype == jnp.float32 for x in got[6:])
+    for want in wants:
+        assert_close(got, want, 4e-2)
 
 
 def test_no_decay_and_full_writes_are_the_plain_delta_rule():
@@ -182,36 +236,42 @@ def test_recurrence_path_is_the_default_off_the_tpu_and_agrees():
     )
 
 
-def test_counters_count_what_they_say():
+@CONV
+def test_counters_count_what_they_say(conv):
     reg = registry.always()
-    names = ("kda.calls", "kda.chunks", "kda.state_bytes_saved")
+    names = ("kda.calls", "kda.chunks", "kda.state_bytes_saved",
+             "kda.calls.conv")
     before = [reg.counter(n).get() for n in names]
     b, s, h, d = 2, 40, 2, 16
     argv, weights = operands(b, s, h, d, seed=6)
+    taps = taps_for(h, d) if conv else None
     jax.eval_shape(jax.grad(lambda *a: jnp.sum(kda_attention(
-        *a, n_heads=h, use_kernel=True, chunk=16, sub=8
+        *a, n_heads=h, conv=taps, use_kernel=True, chunk=16, sub=8
     ) * weights), argnums=(0, 1, 2, 3, 4)), *argv)  # built, not run
-    calls, chunks, saved = (
+    calls, chunks, saved, convolving = (
         reg.counter(n).get() - was for n, was in zip(names, before)
     )
     assert calls == 2  # the forward and the backward
+    assert convolving == (2 if conv else 0)  # of them, those that convolve
     assert chunks == b * h * 3  # 40 rows padded to 48: three chunks of 16
     assert saved == chunks * d * d * 4  # one float32 [d_v, d_k] state each
     plan = kda_kernels._plan(
         argv[0].astype(jnp.bfloat16), argv[2], argv[4], n_heads=h,
-        chunk=None, sub=None, interpret=True,
+        chunk=None, sub=None, interpret=True, conv=taps,
     )
     # the cell's call: 64-row chunks, bfloat16 states
-    assert (plan.chunk, plan.sub) == (64, 8)
+    assert (plan.chunk, plan.sub, plan.taps) == (64, 8, 4 if conv else 0)
     assert plan.state_bytes == b * h * d * d * 2
 
 
-def test_kernels_are_named_for_the_trace_and_carry_no_scope():
+def _kernel_calls(conv):
+    """The traced ``pallas_call`` equations of forward + backward, by name."""
+    from horovod_tpu.analysis.jaxpr_walk import _sub_jaxprs_generic
+
     argv, weights = operands(1, 32, 1, 16)
     traced = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(kda_attention(
-        *a, n_heads=1, use_kernel=True, chunk=16, sub=8
+        *a, n_heads=1, conv=conv, use_kernel=True, chunk=16, sub=8
     ) * weights)))(*argv)
-    from horovod_tpu.analysis.jaxpr_walk import _sub_jaxprs_generic
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
@@ -219,9 +279,90 @@ def test_kernels_are_named_for_the_trace_and_carry_no_scope():
             for sub in _sub_jaxprs_generic(eqn):
                 yield from walk(sub)
 
-    calls = [e for e in walk(traced.jaxpr) if e.primitive.name == "pallas_call"]
-    assert sorted(e.params["name"] for e in calls) == [
-        "hvd_kda_bwd", "hvd_kda_fwd"
-    ]
-    for e in calls:
-        assert "attn_layout" not in str(e.source_info.name_stack)
+    return {e.params["name"]: e for e in walk(traced.jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+@CONV
+def test_kernels_are_named_for_the_trace_and_carry_no_scope(conv):
+    calls = _kernel_calls(taps_for(1, 16) if conv else None)
+    assert sorted(calls) == ["hvd_kda_bwd", "hvd_kda_fwd"]
+    for e in calls.values():
+        stack = str(e.source_info.name_stack)
+        assert "attn_layout" not in stack and "kda_conv" not in stack
+    # q, k, v, g, beta (the backward: the states and dO too); with taps
+    # three halo blocks and three tap blocks more, and the taps' partial
+    # gradients beside dq, dk, dv, dg, dbeta
+    more = 6 if conv else 0
+    assert len(calls["hvd_kda_fwd"].invars) == 5 + more
+    assert len(calls["hvd_kda_bwd"].invars) == 7 + more
+    assert len(calls["hvd_kda_bwd"].outvars) == 5 + more // 2
+
+
+def test_a_call_without_taps_traces_what_it_traced():
+    """``conv=None`` is the call without the argument, equation for
+    equation, compiled or interpreted (the parent's jaxprs themselves were
+    compared when the argument came: CHANGES.md, PR 43)."""
+    x = lambda w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 200, w), dtype
+    )
+    shapes = (x(256), x(256), x(256), x(256, jnp.float32),
+              x(2, jnp.float32))
+
+    def traced(**kw):
+        return str(jax.make_jaxpr(jax.grad(lambda *a: kda_attention(
+            *a, n_heads=2, use_kernel=True, **kw
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*shapes))
+
+    for interpret in (False, True):
+        assert traced(interpret=interpret) == traced(
+            interpret=interpret, conv=None
+        )
+
+
+@pytest.mark.parametrize("moved", ["q", "v"])
+def test_a_blocks_border_is_causal(moved):
+    """Two grid steps of 128 rows. Moving ``q~`` at row 127, the first
+    block's last, moves the output at rows 127..130 (the four rows whose
+    convolution reaches it, three of them in the NEXT block) and at no
+    other: q never enters the state. Moving ``v~`` there, with ``beta`` 0
+    at that row so that its own value is never written, moves nothing up
+    to row 127 and, through rows 128..130's values and the state, what
+    follows."""
+    b, s, h, d = 1, 256, 1, 16
+    (q, k, v, g, beta), _ = operands(b, s, h, d, seed=8)
+    taps = taps_for(h, d)
+    beta = beta.at[:, 127].set(0.0)
+    run = lambda q, v: np.asarray(kda_attention(  # noqa: E731
+        q, k, v, g, beta, n_heads=h, conv=taps, use_kernel=True, chunk=16,
+        sub=8,
+    ))
+    plan = kda_kernels._plan(q, v, beta, n_heads=h, chunk=16, sub=8,
+                             interpret=True, conv=taps)
+    assert (plan.block, plan.s_pad) == (128, 256)
+    base = run(q, v)
+    if moved == "q":
+        changed = np.abs(run(q.at[0, 127].add(1.0), v) - base).max(axis=-1)[0]
+        assert (changed[127:131] > 1e-4).all()
+        assert (np.delete(changed, range(127, 131)) == 0).all()
+    else:
+        changed = np.abs(run(q, v.at[0, 127].add(1.0)) - base).max(axis=-1)[0]
+        assert (changed[:128] == 0).all()
+        assert (changed[128:131] > 1e-4).all() and (changed[131:] > 0).any()
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(use_kernel=False), "recurrence path"),
+    (dict(taps=KdaConv(*[jnp.zeros((4, 8))] * 3)), "conv taps"),
+    (dict(taps=KdaConv(*[jnp.zeros((10, 16))] * 3)), "1 to 9 taps"),
+    (dict(chunk=8), "multiple of 16"),
+], ids=["recurrence", "widths", "too-many", "short-block"])
+def test_taps_that_do_not_fit_are_refused(bad, match):
+    (q, k, v, g, beta), _ = operands(1, 8, 1, 16)
+    with pytest.raises(ValueError, match=match):
+        kda_attention(
+            q, k, v, g, beta, n_heads=1,
+            conv=bad.get("taps", taps_for(1, 16)),
+            use_kernel=bad.get("use_kernel", True), chunk=bad.get("chunk", 16),
+            sub=8,
+        )

@@ -218,6 +218,37 @@ def test_convolution_is_causal_and_four_taps_deep():
     assert changed.tolist() == [8 <= t <= 11 for t in range(12)]
 
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+def test_the_convolutions_run_where_the_mixer_runs(kernels):
+    """On the kernel path the mixer hands its taps to ``kda_attention``
+    (``conv=``): the four KDA layers build eight kernels that convolve and
+    the forward traces no operation under ``kda_conv`` (what is left of
+    that scope is the backward's sum of the taps' partial gradients). On
+    the recurrence path ``conv_silu`` runs under ``kda_conv`` in front of
+    it and no kernel is built."""
+    import model_parts
+    from horovod_tpu.obs import registry
+
+    cfg = _tiny(use_flash=False, use_kernel=kernels)
+    params, tokens = _params(cfg), _tokens(cfg, 3)
+    logits, loss = _system(cfg)
+    counter = registry.always().counter("kda.calls.conv")
+    before = counter.get()
+    forward = jax.make_jaxpr(logits)(params, tokens)
+    backward = jax.make_jaxpr(jax.grad(loss))(params, tokens)
+    n_kda = len(cfg.kda_layers)
+    assert counter.get() - before == (3 * n_kda if kernels else 0)
+
+    def under_conv(jaxpr):
+        return sum(
+            "kda_conv" in stack.split("/") and computed
+            for _, stack, computed in model_parts.operations(jaxpr.jaxpr)
+        )
+
+    assert (under_conv(forward) == 0) == kernels
+    assert under_conv(backward) > under_conv(forward)
+
+
 def test_mixer_keeps_the_decay_and_the_state_in_float32():
     """``g``, ``beta`` reach the kernels' entry as float32 whatever the
     compute dtype, and ``g <= 0``."""

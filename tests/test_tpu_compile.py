@@ -207,34 +207,40 @@ def test_flash_attention_latent_compiles(v5e, batch, seq, heads):
     assert hlo.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("conv", [False, True], ids=["plain", "conv"])
 @pytest.mark.parametrize(
     "batch,seq,heads", [(1, 8192, 32), (2, 1000, 4)],
     ids=["kda-1x8192x32", "kda-s1000x4-padded"],
 )
-def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads):
+def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads, conv):
     """The Kimi Delta Attention kernels, forward and backward, at the
     cell's shape (1 x 8,192, 32 heads of 128 key and value channels,
     bfloat16 operands, float32 ``g`` and ``beta``) and at a padded length:
     two Mosaic calls, the chunks' entry states the only array between
-    them that the entry did not take or hand back."""
-    from horovod_tpu.ops.kda_kernels import kda_attention
+    them that the entry did not take or hand back. With ``conv`` (the
+    cell's call since PR 43: four taps an operand, convolved and gated in
+    VMEM) the same two calls, the taps' gradients leaving the backward as
+    one float32 ``[8, 128]`` block a head and batch row."""
+    from horovod_tpu.ops.kda_kernels import KdaConv, kda_attention
 
-    def loss(q, k, v, g, beta):
+    def loss(q, k, v, g, beta, *taps):
         return kda_attention(
             q, k, v, g, beta, n_heads=heads, use_kernel=True,
-            interpret=False,
+            conv=KdaConv(*taps) if conv else None, interpret=False,
         ).astype(jnp.float32).sum()
 
     wide = ((batch, seq, 128 * heads), jnp.bfloat16)
+    taps = [((4, 128 * heads), jnp.float32)] * (3 if conv else 0)
     hlo = _compile(
-        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), v5e, wide, wide, wide,
-        ((batch, seq, 128 * heads), jnp.float32),
-        ((batch, seq, heads), jnp.float32),
+        jax.grad(loss, argnums=tuple(range(5 + len(taps)))), v5e, wide, wide,
+        wide, ((batch, seq, 128 * heads), jnp.float32),
+        ((batch, seq, heads), jnp.float32), *taps,
     )
     assert hlo.count("tpu_custom_call") == 2
     assert "hvd_kda_fwd" in hlo and "hvd_kda_bwd" in hlo
     padded = -(-seq // 128) * 128
     assert f"bf16[{batch},{heads},{padded // 64},128,128]" in hlo
+    assert (f"f32[{batch},{heads},8,128]" in hlo) == conv
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])

@@ -95,30 +95,36 @@ def reference(q, k, v, w, causal, heads, kv_heads=None, window=None):
     return (out.reshape(w.shape) * w).sum()
 
 
-def kernel_us(fn, argv, iters, kernels=KERNELS):
-    """Median device microseconds per call of each kernel named in
-    ``kernels`` (substrings of the trace's names)."""
-    for _ in range(2):  # compile, then settle
+def device_events(fn, argv, iters):
+    """``(name, microseconds)`` of every event on the device plane's ``XLA
+    Ops`` line over ``iters`` traced calls of ``fn`` (after two to compile
+    and settle)."""
+    for _ in range(2):
         jax.block_until_ready(fn(*argv))
     trace_dir = tempfile.mkdtemp(prefix="flash_trace")
     with jax.profiler.trace(trace_dir):
         for _ in range(iters):
             out = fn(*argv)
         jax.block_until_ready(out)
+    return [
+        (ev.name, ev.duration_ns / 1e3)
+        for path in glob.glob(
+            os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb"))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/device:TPU:0")
+        for line in plane.lines if line.name == "XLA Ops"
+        for ev in line.events
+    ]
+
+
+def kernel_us(fn, argv, iters, kernels=KERNELS):
+    """Median device microseconds per call of each kernel named in
+    ``kernels`` (substrings of the trace's names)."""
     durations = {name: [] for name in kernels}
-    for path in glob.glob(
-        os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb")
-    ):
-        for plane in ProfileData.from_file(path).planes:
-            if not plane.name.startswith("/device:TPU:0"):
-                continue
-            for line in plane.lines:
-                if line.name != "XLA Ops":
-                    continue
-                for ev in line.events:
-                    name = next((k for k in kernels if k in ev.name), None)
-                    if name:
-                        durations[name].append(ev.duration_ns / 1e3)
+    for event, us in device_events(fn, argv, iters):
+        name = next((k for k in kernels if k in event), None)
+        if name:
+            durations[name].append(us)
     if not all(durations.values()):
         raise SystemExit(f"kernels missing from the trace: {durations}")
     return {name: float(np.median(v)) for name, v in durations.items()}

@@ -2,6 +2,7 @@
 
     python tools/bench_kda.py [--tree CHECKOUT] [--iters 8] [--shape NAME]
         [--heads 32] [--sub N ...] [--block-chunks N ...] [--no-recurrence]
+        [--conv]
 
 Runs forward + backward of ``ops/kda_kernels.kda_attention`` alone (one
 layer's call) at the shape of the benchmark's cell, ``kda-1x8192``: 1 x
@@ -17,11 +18,21 @@ device's peaks, and the largest absolute error of the output and of the
 five gradients against the ``use_kernel=False`` path (the recurrence a
 position at a time in float32) on the same operands, whose own wall time
 per call (host clock, forward + backward) is printed beside them.
+``--conv`` prints one table of three more lines in place of those: the two
+kernels as above (``row: kernels``), the two kernels given ``conv=`` taps on
+operands as the projections leave them (``kernels+conv``; errors of out and
+of all eight gradients against ``models.linear_moe.conv_silu`` followed by
+the kernels without taps), and XLA's ``conv_silu`` of the three operands
+alone, forward + backward from a given cotangent (``xla-conv_silu``: every
+device operation of that program, microseconds a call): what the kernels'
+door costs beside what it replaces. A tree without the argument prints
+the first and the last.
 ``--sub`` / ``--block-chunks`` (may repeat) time the plan's statics at other
 values than the module's; ``--tree`` imports ``horovod_tpu`` and
 ``benchmark`` from another checkout, as ``tools/bench_attention.py`` does.
 """
 import argparse
+import functools
 import json
 import os
 import sys
@@ -31,7 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench_attention import kernel_us  # tools/ is this script's directory
+# tools/ is this script's directory
+from bench_attention import device_events, kernel_us
 
 KERNELS = ("hvd_kda_fwd", "hvd_kda_bwd")
 # name: (batch, sequence, heads, key channels, value channels)
@@ -59,6 +71,57 @@ def operands(key, b, s, h, dk, dv):
             beta, w]
 
 
+def conv_table(args, kda_kernels, argv, h, dk, dv, emit):
+    """``--conv``: the kernels, the kernels that convolve, XLA's
+    convolutions alone."""
+    from horovod_tpu.models.linear_moe import conv_silu
+
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+    q, k, v, g, beta, w = argv
+    # as a projection leaves them, and taps as the model draws them
+    raw = [jax.random.normal(key, x.shape, jnp.float32).astype(x.dtype)
+           for key, x in zip(keys[:3], (q, k, v))]
+    taps = [jax.random.uniform(key, (4, h * d), jnp.float32, -0.5, 0.5)
+            for key, d in zip(keys[3:], (dk, dk, dv))]
+
+    def grads(entry, n):
+        def loss(*a):
+            out = entry(*a)
+            return (out.astype(jnp.float32) * w).sum(), out
+        return jax.jit(jax.grad(loss, argnums=tuple(range(n)), has_aux=True))
+
+    attend = functools.partial(kda_kernels.kda_attention, n_heads=h,
+                               use_kernel=True)
+    plain = grads(attend, 5)
+    emit("kernels", kernel_us(plain, (q, k, v, g, beta), args.iters, KERNELS))
+    if hasattr(kda_kernels, "KdaConv"):
+        outside = grads(lambda q, k, v, g, beta, *t: attend(
+            *(conv_silu(x, c) for x, c in zip((q, k, v), t)), g, beta), 8)
+        inside = grads(lambda q, k, v, g, beta, *t: attend(
+            q, k, v, g, beta, conv=kda_kernels.KdaConv(*t)), 8)
+        operands = (*raw, g, beta, *taps)
+        us = kernel_us(inside, operands, args.iters, KERNELS)
+        got, want = inside(*operands), outside(*operands)
+        names = ("dq", "dk", "dv", "dg", "dbeta", "dtaps_q", "dtaps_k",
+                 "dtaps_v", "out")
+        emit("kernels+conv", us, abs_err_vs_conv_silu_then_kernels={
+            name: [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                         - e.astype(jnp.float32)))),
+                   float(jnp.max(jnp.abs(e.astype(jnp.float32))))]
+            for name, a, e in zip(names, (*got[0], got[1]),
+                                  (*want[0], want[1]))
+        })
+
+    def convolutions(xs, ts, dys):
+        out = [jax.vjp(conv_silu, x, t) for x, t in zip(xs, ts)]
+        return [y for y, _ in out], [pull(dy) for (_, pull), dy in
+                                     zip(out, dys)]
+    events = device_events(jax.jit(convolutions), (raw, taps, [q, k, v]),
+                           args.iters)
+    emit("xla-conv_silu",
+         {"every_op": sum(us for _, us in events) / args.iters})
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tree", default=os.path.dirname(
@@ -72,6 +135,9 @@ def main():
                     help="chunks a grid step (default: the module's)")
     ap.add_argument("--no-recurrence", action="store_true",
                     help="skip the use_kernel=False path and the errors")
+    ap.add_argument("--conv", action="store_true",
+                    help="the kernels with and without taps, and XLA's "
+                         "conv_silu alone: one table")
     args = ap.parse_args()
     sys.path.insert(0, args.tree)
     from benchmark.lib.flops import roofline
@@ -102,6 +168,15 @@ def main():
             )
             return (out.astype(jnp.float32) * w).sum(), out
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    if args.conv:
+        def emit(row, us, **more):
+            print(json.dumps(dict(
+                tree=args.tree, shape=args.shape, heads=h, row=row,
+                device_kind=device.device_kind, us_per_call=us,
+                total_us=sum(us.values()), **more,
+            )), flush=True)
+        return conv_table(args, kda_kernels, argv, h, dk, dv, emit)
 
     want = seconds = None
     if not args.no_recurrence:
