@@ -37,7 +37,13 @@ recurrence a position at a time elsewhere. On the kernel path the
 convolutions and SiLU are the kernels' too (``conv=``: the mixer hands
 over its taps and ``q~ k~ v~``, and ``q^ k^ v^`` exist in VMEM only); on
 the recurrence path :func:`conv_silu` runs in front of it, the one
-definition of that mathematics outside the kernels. The latent layer is
+definition of that mathematics outside the kernels. On the kernel path
+the statistic of ``RMSNorm_d`` is the kernels' as well (``out_norm=eps``:
+a head's output is normalised in VMEM where its tile lies, and ``o^ = o /
+rms`` is what leaves them), so ``z = o^ tile(o_norm) sigmoid(gate)`` is
+elementwise on ``[S, H d]`` and nothing is reshaped to heads; on the
+recurrence path the gated norm is written out on ``[S, H, d]``, the one
+definition outside the kernels. The latent layer is
 ``latent_moe.LatentAttention`` with ``q_lora_rank=None`` and
 ``use_rope=False`` (one ``q`` projection, NOTHING rotated, the shared
 ``k_r`` to the flash kernels as ``kv_a`` leaves it); the expert layer is
@@ -52,7 +58,8 @@ What the backward keeps of a KDA layer: the three projections' outputs
 gates and normalises a block in VMEM again; on the recurrence path
 ``conv_silu``, whose backward is written out, runs again from them and
 its outputs ``q^ k^ v^`` are kept as well), the taps, ``g``, ``beta``, the
-chunks' entry states, ``o`` and the two low-rank gate inputs (``g`` and
+chunks' entry states, ``o`` (on the kernel path ``o^`` and ``1 / rms``
+a row and head in its place) and the two low-rank gate inputs (``g`` and
 the gated norm run again from those).
 
 Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
@@ -62,7 +69,8 @@ beta's), ``kda_conv`` (the three convolutions and SiLU on the recurrence
 path; on the kernel path the sum and layout of the taps' partial
 gradients, which the kernels' entry opens itself), ``kda_gate``
 (softplus and the decay's scale, beta's sigmoid, the gated head-wise
-RMSNorm), ``mla_proj``, ``attn_layout`` (the kernels' entries' own glue),
+RMSNorm: on the kernel path its scale and gate, the statistic being the
+kernels'), ``mla_proj``, ``attn_layout`` (the kernels' entries' own glue),
 ``attn_xla`` (latent attention where flash is bypassed), ``mlp``,
 ``moe_route``, ``moe_experts``, ``head``. The Mosaic kernels carry none of
 them; off the TPU the recurrence's ``lax.scan`` stands under
@@ -230,7 +238,8 @@ def _to(dtype, x, w):
 
 class KimiDeltaAttention(nn.Module):
     """The KDA mixer: projections, convolutions, the two low-rank gates
-    and the gated head-wise RMSNorm around ``kda_attention``."""
+    and the gated head-wise RMSNorm around ``kda_attention`` (which on the
+    kernel path convolves at its door and normalises at its exit)."""
 
     cfg: LinearMoEConfig
 
@@ -281,9 +290,9 @@ class KimiDeltaAttention(nn.Module):
         g = log_decay(f_a, f_b, dt_bias, a_log)
         with jax.named_scope("kda_gate"):
             beta = jax.nn.sigmoid(beta_logits)
-        if use_kernel:
+        if use_kernel:  # o^: each head's output normalised at the exit
             o = kda_attention(q, k, v, g, beta, n_heads=h, conv=taps,
-                              use_kernel=True)
+                              out_norm=cfg.eps, use_kernel=True)
         else:
             with jax.named_scope("attn_xla"):
                 o = kda_attention(q, k, v, g, beta, n_heads=h,
@@ -294,12 +303,16 @@ class KimiDeltaAttention(nn.Module):
             with jax.named_scope("kda_proj"):
                 gate = _to(cfg.dtype, g_a, g_b)
             with jax.named_scope("kda_gate"):
-                heads = o.astype(jnp.float32).reshape(b, s, h, d)
-                heads = heads * jax.lax.rsqrt(
-                    jnp.mean(heads * heads, axis=-1, keepdims=True) + cfg.eps
-                ) * out_scale
-                return (heads.reshape(b, s, width)
-                        * jax.nn.sigmoid(gate)).astype(cfg.dtype)
+                if use_kernel:  # normalised already: no reshape to heads
+                    normed = o.astype(jnp.float32) * jnp.tile(out_scale, h)
+                else:
+                    heads = o.astype(jnp.float32).reshape(b, s, h, d)
+                    heads = heads * jax.lax.rsqrt(
+                        jnp.mean(heads * heads, axis=-1, keepdims=True)
+                        + cfg.eps
+                    ) * out_scale
+                    normed = heads.reshape(b, s, width)
+                return (normed * jax.nn.sigmoid(gate)).astype(cfg.dtype)
 
         z = gated_norm(o, g_a, g_b, out_scale)
         with jax.named_scope("kda_proj"):

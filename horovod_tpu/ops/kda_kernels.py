@@ -38,6 +38,21 @@ sequence axis revisits; XLA sums it over the batch under the scope
 ``kda_conv``. Same two kernels, one body each with a static branch: a
 call without ``conv`` traces what it traced.
 
+With ``out_norm=eps`` the result is each head's output RMS-normalised over
+its ``d_v`` channels, ``o^ = o rsqrt(mean_d(o^2) + eps)`` (no scale: the
+caller's), the statistic taken where the tile already lies: the forward
+normalises a chunk's float32 ``[C, d_v]`` output in VMEM the moment before
+it rounds and writes it (one rounding, of the normalised value), and
+leaves ``rstd = 1 / rms``, float32, as a row vector a head (``[B, H, 1,
+S_pad]``, ``dbeta``'s layout; the entry's glue hands it on as ``[B, S,
+H]``). The backward takes ``do^`` where it took ``do`` and two more
+operands, ``o^`` (the forward's output, the one copy kept) and ``rstd``
+(read as ``beta`` is), forms ``do = rstd (do^ - o^ mean_d(do^ o^))`` in
+float32 a chunk and goes on as without. No float32 ``[B, S, H, d]`` array
+of the output is ever built around the kernels for a norm to reduce. One
+more static branch of the same bodies: a call without ``out_norm`` traces
+what it traced.
+
 The chunked form (``C`` rows a chunk, ``G_i = sum_{j<=i} g_j`` inside the
 chunk, ``S_0`` the state the chunk enters with; rows ``i``, ``j``)::
 
@@ -85,10 +100,12 @@ back ``dq``, ``dk``, ``dv`` (the operands' dtype, of the UN-normalised
 ``q`` / ``k``), ``dg`` and ``dbeta`` (float32).
 
 Kernel names: ``hvd_kda_fwd``, ``hvd_kda_bwd``. The entry's own XLA glue
-(padding, ``dbeta``'s layout) is under the scope ``attn_layout``; the
-``pallas_call``s are under none. Build-time counters (always on):
+(padding, ``dbeta``'s and ``rstd``'s layout) is under the scope
+``attn_layout``; the ``pallas_call``s are under none. Build-time counters
+(always on):
 ``kda.calls`` (one a kernel built), ``kda.calls.conv`` (of those, the
-kernels that convolve), ``kda.chunks`` (chunks a forward call
+kernels that convolve), ``kda.calls.out_norm`` (of those, the kernels
+that normalise their exit), ``kda.chunks`` (chunks a forward call
 visits: ``B H S_pad / C``), ``kda.state_bytes_saved`` (bytes of entry
 states a forward call leaves for its backward).
 
@@ -161,6 +178,8 @@ class _Plan(NamedTuple):
     # every matmul at full precision)
     dtype: object
     taps: int = 0  # of the convolution the kernels do at their door; 0: none
+    # eps of the head-wise RMS norm the kernels do at their exit; None: none
+    out_norm: Optional[float] = None
 
     @property
     def n_chunks(self) -> int:
@@ -174,7 +193,8 @@ class _Plan(NamedTuple):
 
 def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
           sub: Optional[int], interpret: Optional[bool],
-          conv: Optional[KdaConv] = None) -> _Plan:
+          conv: Optional[KdaConv] = None,
+          out_norm: Optional[float] = None) -> _Plan:
     b, s, width = q.shape
     h = n_heads
     if width % h or v.shape[-1] % h or beta.shape != (b, s, h):
@@ -216,6 +236,7 @@ def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
     return _Plan(
         b, s, -(-s // block) * block, h, dk, dv, chunk, sub, block,
         interpret, q.dtype, taps,
+        None if out_norm is None else float(out_norm),
     )
 
 
@@ -418,6 +439,20 @@ def _as_row(column):
     return jnp.sum(jnp.where(row == col, column, 0.0), 0, keepdims=True)
 
 
+def _write_as_rows(ref, columns, p: _Plan):
+    """The chunks' ``[C, 1]`` columns (``columns[i]`` chunk ``i``'s) leave
+    as a row vector, rows on the lanes, a 128-lane tile (or the whole short
+    block) at a time."""
+    piece = min(p.block, 128)
+    for first in range(0, p.block, piece):
+        column = jnp.concatenate(
+            [columns[i]
+             for i in range(first // p.chunk, (first + piece) // p.chunk)],
+            axis=0,
+        )
+        ref[0, 0, :, first:first + piece] = _as_row(column)
+
+
 # ---------------------------------------------------------------------------
 # The convolution at the door (``conv=``). A grid step stages each operand's
 # block in float32 behind the last ``_EDGE`` rows of the block before it
@@ -457,15 +492,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, p: _Plan):
     c = p.chunk
     dt = p.dtype
     own = (q_ref, k_ref, v_ref)
+    rest = list(rest)
+    halos, taps = (rest.pop(0), rest.pop(0)) if p.taps else (None, None)
+    o_ref, states_ref = rest.pop(0), rest.pop(0)
+    rstd_ref = rest.pop(0) if p.out_norm is not None else None
     if p.taps:
-        halos, taps, o_ref, states_ref, state, stages, convolved = rest
+        state, stages, convolved = rest
         at_start = pl.program_id(2) == 0
         for x in range(3):
             _convolve(own[x], halos[x], taps[x], stages[x], convolved[x],
                       None, at_start)
         read = lambda x, rows: convolved[x][rows, :]  # noqa: E731
     else:
-        o_ref, states_ref, state = rest
+        state, = rest
         read = lambda x, rows: own[x][0, rows, :].astype(  # noqa: E731
             jnp.float32
         )
@@ -475,6 +514,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, p: _Plan):
         state[...] = jnp.zeros_like(state)
 
     head = pl.program_id(1)
+    rstd = {}
     for i in range(p.block // c):
         rows = slice(i * c, (i + 1) * c)
         beta = _head_column(beta_ref[0, rows, :], head)
@@ -486,10 +526,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, p: _Plan):
         s0 = state[...]  # [dv, dk]
         states_ref[0, 0, i] = s0.astype(states_ref.dtype)
         u = u_hat - _nt(w, s0, dt)
-        o_ref[0, rows, :] = (
-            _nt(ch.q * ch.e, s0, dt) + _nn(ch.p_qk, u, dt)
-        ).astype(o_ref.dtype)
+        o = _nt(ch.q * ch.e, s0, dt) + _nn(ch.p_qk, u, dt)
+        if p.out_norm is not None:  # the float32 tile, before its rounding
+            rstd[i] = lax.rsqrt(
+                jnp.mean(o * o, axis=-1, keepdims=True) + p.out_norm
+            )
+            o = o * rstd[i]
+        o_ref[0, rows, :] = o.astype(o_ref.dtype)
         state[...] = s0 * ch.e_last + _tn(u, ch.k * ch.e_end, dt)
+    if p.out_norm is not None:
+        _write_as_rows(rstd_ref, rstd, p)
 
 
 def _specs(p: _Plan, block_of):
@@ -530,6 +576,21 @@ def _door_specs(p: _Plan, block_of):
     return [tuple(halo(d) for d in widths), tuple(taps(d) for d in widths)]
 
 
+def _rows(p: _Plan, block_of):
+    """The spec and shape of an output that holds one float32 a row and
+    head as row vectors, ``[B, H, 1, S_pad]`` (``dbeta``, ``rstd``)."""
+    spec = pl.BlockSpec(
+        (1, 1, 1, p.block), lambda bi, hi, i: (bi, hi, 0, block_of(i)),
+        memory_space=_VMEM,
+    )
+    return spec, jax.ShapeDtypeStruct((p.b, p.h, 1, p.s_pad), jnp.float32)
+
+
+def _from_rows(x, p: _Plan):
+    """``[B, H, 1, S_pad] -> [B, S, H]``."""
+    return jnp.swapaxes(x[:, :, 0, :p.s], 1, 2)
+
+
 def _tiles(p: _Plan, rows: int):
     """A float32 ``[rows, d]`` VMEM scratch for each of q, k and v."""
     return tuple(_VMEM((rows, d), jnp.float32) for d in (p.dk, p.dk, p.dv))
@@ -554,6 +615,8 @@ def _book(p: _Plan, forward: bool) -> None:
     reg.counter("kda.calls").inc()
     if p.taps:
         reg.counter("kda.calls.conv").inc()
+    if p.out_norm is not None:
+        reg.counter("kda.calls.out_norm").inc()
     if forward:
         reg.counter("kda.chunks").inc(p.b * p.h * p.n_chunks)
         reg.counter("kda.state_bytes_saved").inc(p.state_bytes)
@@ -571,23 +634,29 @@ def _fwd_call(q, k, v, g, beta, conv=None, *, p: _Plan):
         in_specs += _door_specs(p, lambda i: i)
         operands += [(q, k, v), tuple(conv)]
         scratch += [_tiles(p, _EDGE + p.block), _tiles(p, p.block)]
-    out, states = pl.pallas_call(
+    out_specs = [wide(p.dv), states_spec]
+    out_shape = [
+        jax.ShapeDtypeStruct((p.b, p.s_pad, p.h * p.dv), p.dtype),
+        jax.ShapeDtypeStruct((p.b, p.h, p.n_chunks, p.dv, p.dk), p.dtype),
+    ]
+    if p.out_norm is not None:
+        row_spec, row_shape = _rows(p, lambda i: i)
+        out_specs.append(row_spec)
+        out_shape.append(row_shape)
+    out, states, *rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, p=p),
         grid=(p.b, p.h, p.s_pad // p.block),
         in_specs=in_specs,
-        out_specs=[wide(p.dv), states_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((p.b, p.s_pad, p.h * p.dv), p.dtype),
-            jax.ShapeDtypeStruct(
-                (p.b, p.h, p.n_chunks, p.dv, p.dk), p.dtype
-            ),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=_params(),
         interpret=p.interpret,
         name="hvd_kda_fwd",
     )(*operands)
     with jax.named_scope(_GLUE_SCOPE):
+        if rstd:
+            return out[:, :p.s], states, _from_rows(rstd[0], p)
         return out[:, :p.s], states
 
 
@@ -658,6 +727,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     dt = p.dtype
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
     own = (q_ref, k_ref, v_ref)
+    rest = list(rest)
+    if p.out_norm is not None:  # the forward's normalised output and 1 / rms
+        out_ref, rstd_ref = rest.pop(0), rest.pop(0)
     if p.taps:
         (halos, taps, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dtaps,
          dstate, stages, convolved, slopes, ahead) = rest
@@ -700,6 +772,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         u = _nn(ch.inv, beta * z, dt)
         ds = dstate[...]  # [dv, dk], of the state this chunk leaves
         do = f32(do_ref[0, rows, :])
+        if p.out_norm is not None:  # of o, given that of o rstd
+            o_hat = f32(out_ref[0, rows, :])
+            do = _head_column(rstd_ref[0, rows, :], head) * (
+                do - o_hat * jnp.mean(do * o_hat, axis=-1, keepdims=True)
+            )
         k_end = ch.k * ch.e_end
         du = _tn(ch.p_qk, do, dt) + _nt(k_end, ds, dt)
         dr = _tn(ch.inv, du, dt)
@@ -749,15 +826,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
             jnp.concatenate([ch.q * ch.e, ch.k * ch.e], axis=0), dt,
         )
 
-    # dbeta leaves as a row vector, rows on the lanes, a 128-lane tile (or
-    # the whole short block) at a time
-    piece = min(p.block, 128)
-    for first in range(0, p.block, piece):
-        column = jnp.concatenate(
-            [dbeta[i] for i in range(first // c, (first + piece) // c)],
-            axis=0,
-        )
-        dbeta_ref[0, 0, :, first:first + piece] = _as_row(column)
+    _write_as_rows(dbeta_ref, dbeta, p)
 
     if p.taps:
         # the convolution's transpose: a row's gradient reaches the rows
@@ -782,7 +851,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("p",), inline=True)
-def _bwd_call(q, k, v, g, beta, states, d_out, conv=None, *, p: _Plan):
+def _bwd_call(q, k, v, g, beta, states, d_out, conv=None, normed=None, *,
+              p: _Plan):
     with jax.named_scope(_GLUE_SCOPE):
         q, k, v, g, beta, d_out = (
             _pad(x, p) for x in (q, k, v, g, beta, d_out)
@@ -793,19 +863,19 @@ def _bwd_call(q, k, v, g, beta, states, d_out, conv=None, *, p: _Plan):
     in_specs = [wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk), beta_spec,
                 states_spec, wide(p.dv)]
     operands = [q, k, v, g, beta, states, d_out]
-    out_specs = [
-        wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk),
-        pl.BlockSpec(
-            (1, 1, 1, p.block), lambda bi, hi, i: (bi, hi, 0, last - i),
-            memory_space=_VMEM,
-        ),
-    ]
+    dbeta_spec, dbeta_shape = _rows(p, lambda i: last - i)
+    out_specs = [wide(p.dk), wide(p.dk), wide(p.dv), wide(p.dk), dbeta_spec]
     out_shape = [
         like(q, p.dtype), like(k, p.dtype), like(v, p.dtype),
-        like(g, jnp.float32),
-        jax.ShapeDtypeStruct((p.b, p.h, 1, p.s_pad), jnp.float32),
+        like(g, jnp.float32), dbeta_shape,
     ]
     scratch = [_VMEM((p.dv, p.dk), jnp.float32)]
+    if p.out_norm is not None:
+        # the forward's output and its rows' 1 / rms ([B, S, H], as beta):
+        # zeros in the padding, where d_out's zeros then stay zeros
+        with jax.named_scope(_GLUE_SCOPE):
+            operands += [_pad(x, p) for x in normed]
+        in_specs += [wide(p.dv), beta_spec]
     if p.taps:
         in_specs += _door_specs(p, lambda i: last - i)
         operands += [(q, k, v), tuple(conv)]
@@ -836,7 +906,7 @@ def _bwd_call(q, k, v, g, beta, states, d_out, conv=None, *, p: _Plan):
         name="hvd_kda_bwd",
     )(*operands)
     with jax.named_scope(_GLUE_SCOPE):
-        dbeta = jnp.swapaxes(dbeta[:, :, 0, :p.s], 1, 2)  # [B, S, H]
+        dbeta = _from_rows(dbeta, p)
         grads = (dq[:, :p.s], dk[:, :p.s], dv[:, :p.s], dg[:, :p.s], dbeta)
     if not p.taps:
         return grads, None
@@ -855,14 +925,17 @@ def _kda(q, k, v, g, beta, conv, p: _Plan):
 
 def _kda_fwd(q, k, v, g, beta, conv, p: _Plan):
     _book(p, forward=True)
-    out, states = _fwd_call(q, k, v, g, beta, conv, p=p)
-    return out, (q, k, v, g, beta, states, conv)
+    out, states, *rstd = _fwd_call(q, k, v, g, beta, conv, p=p)
+    # with the exit norm the normalised output is the backward's too: the
+    # one copy kept, as the caller keeps it
+    normed = (out, *rstd) if rstd else None
+    return out, (q, k, v, g, beta, states, conv, normed)
 
 
 def _kda_bwd(p: _Plan, residuals, d_out):
     _book(p, forward=False)
-    *operands, conv = residuals
-    grads, d_taps = _bwd_call(*operands, d_out, conv, p=p)
+    *operands, conv, normed = residuals
+    grads, d_taps = _bwd_call(*operands, d_out, conv, normed, p=p)
     return (*grads, d_taps)
 
 
@@ -912,6 +985,7 @@ def kda_recurrence(q, k, v, g, beta, *, n_heads: int, group: int = 64):
 
 def kda_attention(q, k, v, g, beta, *, n_heads: int,
                   conv: Optional[KdaConv] = None,
+                  out_norm: Optional[float] = None,
                   use_kernel: Optional[bool] = None,
                   interpret: Optional[bool] = None,
                   chunk: Optional[int] = None, sub: Optional[int] = None):
@@ -931,6 +1005,14 @@ def kda_attention(q, k, v, g, beta, *, n_heads: int,
     Differentiable in the taps too. ``None`` is the call without the
     argument, equation for equation.
 
+    ``out_norm``: the ``eps`` (a Python float, static) of an RMS norm over
+    each head's ``d_v`` channels at the kernels' exit (kernels only). The
+    result is then ``o rsqrt(mean_d(o^2) + eps)``, no scale: the forward
+    normalises a chunk's float32 tile in VMEM before its one rounding and
+    leaves ``1 / rms`` a row and head beside it for the backward, which
+    takes the normalised output's gradient, forms the plain one in VMEM
+    and goes on as without. ``None`` is the call without the argument.
+
     ``use_kernel``: None takes the Pallas kernels where the world's
     devices are TPUs and the recurrence (:func:`kda_recurrence`) elsewhere;
     True runs the kernels anywhere (interpreted off the TPU). ``chunk`` /
@@ -943,11 +1025,16 @@ def kda_attention(q, k, v, g, beta, *, n_heads: int,
                 "conv= is the kernels': on the recurrence path "
                 "(use_kernel=False) hand in q, k, v convolved"
             )
+        if out_norm is not None:
+            raise ValueError(
+                "out_norm= is the kernels': on the recurrence path "
+                "(use_kernel=False) normalise the result"
+            )
         return kda_recurrence(q, k, v, g, beta, n_heads=n_heads).astype(
             v.dtype
         )
     p = _plan(q, v, beta, n_heads=n_heads, chunk=chunk, sub=sub,
-              interpret=interpret, conv=conv)
+              interpret=interpret, conv=conv, out_norm=out_norm)
     if conv is not None:
         conv = KdaConv(*(w.astype(jnp.float32) for w in conv))
     return _kda(

@@ -8,8 +8,15 @@ counters. With ``conv=`` (the kernels convolve and gate q, k, v at their
 door) the same cases again, the three taps' gradients too, against
 ``models.linear_moe.conv_silu`` in front of the recurrence AND in front of
 the kernels without taps; a block's border is causal; a call without taps
-builds the kernels it built.
+builds the kernels it built. With ``out_norm=`` (the kernels normalise a
+head's output at their exit) cases of the same tests again: the output,
+``1 / rms`` and every gradient against the model's head-wise float32 RMS
+norm written out here, behind the recurrence AND behind the kernels without
+the argument; a row of zeros, which ``eps`` alone keeps finite; a call
+without the argument builds the kernels it built.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +77,20 @@ def taps_for(h, d, *, n=4, seed=7):
     ))
 
 
+EPS = 1e-5  # the configuration's
+
+
+def head_norm(o, h, eps=EPS):
+    """``o / sqrt(mean_d(o^2) + eps)`` over each of ``h`` heads' channels,
+    in float32: ``models.linear_moe``'s gated norm without its scale and
+    gate."""
+    b, s, width = o.shape
+    heads = o.astype(jnp.float32).reshape(b, s, h, -1)
+    return (heads / jnp.sqrt(
+        jnp.mean(heads * heads, axis=-1, keepdims=True) + eps
+    )).reshape(b, s, width)
+
+
 def value_and_grads(fn, weights, argv):
     """``(out, gradients...)`` of ``fn`` in every operand of ``argv``."""
     def of(*a):
@@ -81,29 +102,37 @@ def value_and_grads(fn, weights, argv):
     return (out, *grads)
 
 
-def both(argv, weights, h, conv=None, **statics):
+def both(argv, weights, h, conv=None, norm=False, **statics):
     """``(out, gradients)`` of the kernels and of the recurrence; with
     ``conv`` the kernels are given the taps (three more gradients) and
     there are two references: ``conv_silu`` in front of the recurrence
-    and in front of the kernels without taps."""
+    and in front of the kernels without taps. With ``norm`` the kernels
+    are given ``out_norm=EPS`` and the references are :func:`head_norm`
+    behind the recurrence and behind the SAME kernels without it."""
     kernels = lambda *a, **kw: kda_attention(  # noqa: E731
         *a, n_heads=h, use_kernel=True, **statics, **kw
     )
     plain = lambda *a: recurrence(*a, n_heads=h)  # noqa: E731
-    if conv is None:
-        return (value_and_grads(kernels, weights, argv),
-                value_and_grads(plain, weights, argv))
+    run = lambda fn, argv: value_and_grads(fn, weights, argv)  # noqa: E731
 
     def convolved(fn):
         return lambda q, k, v, g, beta, *taps: fn(
             *(conv_silu(x, w) for x, w in zip((q, k, v), taps)), g, beta
         )
 
-    argv = (*argv, *conv)
-    inside = lambda *a: kernels(*a[:5], conv=KdaConv(*a[5:]))  # noqa: E731
-    return (value_and_grads(inside, weights, argv),
-            value_and_grads(convolved(plain), weights, argv),
-            value_and_grads(convolved(kernels), weights, argv))
+    if conv is None:
+        mine, others = kernels, [plain]
+    else:
+        argv = (*argv, *conv)
+        mine = lambda *a, **kw: kernels(  # noqa: E731
+            *a[:5], conv=KdaConv(*a[5:]), **kw
+        )
+        others = [convolved(plain), convolved(kernels)]
+    if not norm:
+        return (run(mine, argv), *(run(fn, argv) for fn in others))
+    behind = lambda fn: lambda *a: head_norm(fn(*a), h)  # noqa: E731
+    return (run(functools.partial(mine, out_norm=EPS), argv),
+            run(behind(others[0]), argv), run(behind(mine), argv))
 
 
 NAMES = ("out", "dq", "dk", "dv", "dg", "dbeta", "dtaps_q", "dtaps_k",
@@ -121,31 +150,42 @@ def assert_close(got, want, tol):
 
 
 CONV = pytest.mark.parametrize("conv", [False, True], ids=["plain", "conv"])
+NORM = pytest.mark.parametrize("norm", [False, True], ids=["", "out-norm"])
+
+SHAPES = {
+    "1-chunk": (1, 16, 1, 16, 16, 8),
+    "3-chunks": (2, 48, 2, 16, 16, 8),  # batch and heads > 1
+    "ragged": (1, 40, 2, 16, 16, 16),  # no multiple of the chunk: padded
+    "d128": (1, 40, 1, 128, 32, 8),  # heads of 128, four sub-blocks a chunk
+    "plan": (1, 128, 1, 128, 64, 8),  # the plan's own chunk and sub-block
+    "3-rows": (1, 3, 1, 16, 16, 8),  # shorter than a convolution's taps
+    "2-blocks": (2, 200, 2, 16, 16, 8),  # two grid steps, the second ragged
+}
+# the exit norm: where 1 / rms leaves as one row vector (one chunk), as a
+# piece of one (three chunks), past the sequence's end (ragged, two blocks)
+# and as the plan's two chunks a 128-lane tile
+NORMED = ("1-chunk", "3-chunks", "ragged", "plan", "2-blocks")
 
 
 @CONV
-@pytest.mark.parametrize("b,s,h,d,chunk,sub", [
-    (1, 16, 1, 16, 16, 8),    # one chunk
-    (2, 48, 2, 16, 16, 8),    # three chunks, batch and heads > 1
-    (1, 40, 2, 16, 16, 16),   # no multiple of the chunk: padded
-    (1, 40, 1, 128, 32, 8),   # heads of 128, four sub-blocks a chunk
-    (1, 128, 1, 128, 64, 8),  # the plan's own chunk and sub-block
-    (1, 3, 1, 16, 16, 8),     # shorter than a convolution's taps
-    (2, 200, 2, 16, 16, 8),   # two grid steps a head, the second ragged
-], ids=["1-chunk", "3-chunks", "ragged", "d128", "plan", "3-rows",
-        "2-blocks"])
-def test_out_and_every_gradient_equal_the_recurrence(b, s, h, d, chunk, sub,
-                                                     conv):
+@pytest.mark.parametrize("shape,norm", [
+    pytest.param(name, norm, id=name + ("-out-norm" if norm else ""))
+    for norm in (False, True) for name in (NORMED if norm else SHAPES)
+])
+def test_out_and_every_gradient_equal_the_recurrence(shape, norm, conv):
+    b, s, h, d, chunk, sub = SHAPES[shape]
     argv, weights = operands(b, s, h, d)
     got, *wants = both(argv, weights, h, conv=taps_for(h, d) if conv else None,
-                       chunk=chunk, sub=sub)
+                       norm=norm, chunk=chunk, sub=sub)
     assert got[0].shape == (b, s, h * d)
-    assert len(got) == (9 if conv else 6) and len(wants) == 1 + conv
+    assert len(got) == (9 if conv else 6)
+    assert len(wants) == (2 if norm else 1 + conv)
     for want in wants:
         assert_close(got, want, 2e-5)
 
 
-def test_the_strongest_assumed_decay_stays_finite_and_exact():
+@NORM
+def test_the_strongest_assumed_decay_stays_finite_and_exact(norm):
     """``A`` 16 and ``dt`` 0.1 over a whole chunk of 64: ``g`` = -1.6 a
     step, 102 over the chunk, so ``exp(-G)`` is past float32; every
     exponent the kernels form is <= 0, so nothing overflows and the small
@@ -155,22 +195,68 @@ def test_the_strongest_assumed_decay_stays_finite_and_exact():
     q, k, v, _, beta = argv
     g = jnp.full((b, s, h * d), -16.0 * 0.1, jnp.float32)
     assert float(jnp.exp(64 * 1.6)) == np.inf  # what the cheap form meets
-    got, want = both((q, k, v, g, beta), weights, h, chunk=64, sub=8)
-    assert float(jnp.abs(want[0]).max()) > 1e-3  # not a comparison of zeros
-    assert_close(got, want, 2e-5)
+    got, *wants = both((q, k, v, g, beta), weights, h, norm=norm, chunk=64,
+                       sub=8)
+    assert float(jnp.abs(wants[0][0]).max()) > 1e-3  # no comparison of zeros
+    for want in wants:
+        assert_close(got, want, 2e-5)
 
 
+@NORM
 @CONV
-def test_bfloat16_operands_stay_within_their_rounding(conv):
+def test_bfloat16_operands_stay_within_their_rounding(conv, norm):
     """With taps the convolved values stay float32 into the norm (one
-    rounding fewer than ``conv_silu``'s bfloat16 output, never one more)."""
+    rounding fewer than ``conv_silu``'s bfloat16 output, never one more);
+    with the exit norm the float32 output is normalised and rounded once
+    (where the norm behind the kernels reads the rounded one)."""
     argv, weights = operands(1, 64, 2, 16, dtype=jnp.bfloat16)
     got, *wants = both(argv, weights, 2, conv=taps_for(2, 16) if conv else
-                       None, chunk=16, sub=8)
+                       None, norm=norm, chunk=16, sub=8)
     assert got[0].dtype == jnp.bfloat16 and got[4].dtype == jnp.float32
     assert all(x.dtype == jnp.float32 for x in got[6:])
     for want in wants:
         assert_close(got, want, 4e-2)
+
+
+def _rstd(argv, h, conv=None, **statics):
+    """``1 / rms`` as the forward leaves it for the backward, ``[B, S,
+    H]``, beside the normalised output."""
+    plan = kda_kernels._plan(argv[0], argv[2], argv[4], n_heads=h,
+                             interpret=True, conv=conv, out_norm=EPS,
+                             **statics)
+    out, (*_, normed) = kda_kernels._kda_fwd(*argv, conv, plan)
+    assert normed[0] is out  # the one copy kept
+    return out, normed[1]
+
+
+@CONV
+def test_a_row_of_zeros_is_normalised_by_eps_alone(conv):
+    """Rows 8..11 of q are zeros (with taps: row 11 of the convolved q,
+    and ``SiLU(0) = 0``), so row 11 of the output is zeros for every head
+    and ``1 / rms`` there is ``eps^-1/2``, finite; the row's gradients are
+    the reference's, ``eps^-1/2`` times the cotangent behind the norm.
+    Everywhere else ``1 / rms`` is the recurrence's, in the layout the
+    backward reads (two grid steps, the second ragged)."""
+    b, s, h, d, chunk, sub = SHAPES["2-blocks"]
+    (q, k, v, g, beta), weights = operands(b, s, h, d, seed=9)
+    argv = (q.at[:, 8:12].set(0.0), k, v, g, beta)
+    taps = taps_for(h, d) if conv else None
+    out, rstd = _rstd(argv, h, conv=taps, chunk=chunk, sub=sub)
+    assert rstd.shape == (b, s, h) and rstd.dtype == jnp.float32
+    assert (np.asarray(out)[:, 11] == 0).all()
+    np.testing.assert_allclose(rstd[:, 11], EPS ** -0.5, rtol=1e-6)
+    plain = recurrence(*(
+        conv_silu(x, w) for x, w in zip(argv[:3], taps)
+    ), g, beta, n_heads=h) if conv else recurrence(*argv, n_heads=h)
+    want = 1.0 / np.sqrt(np.mean(
+        np.asarray(plain).reshape(b, s, h, d) ** 2, axis=-1
+    ) + EPS)
+    np.testing.assert_allclose(rstd, want, rtol=2e-5)
+    got, *wants = both(argv, weights, h, conv=taps, norm=True, chunk=chunk,
+                       sub=sub)
+    assert float(np.abs(np.asarray(got[1])[:, 11]).max()) > 1.0  # dq there
+    for want in wants:
+        assert_close(got, want, 2e-5)
 
 
 def test_no_decay_and_full_writes_are_the_plain_delta_rule():
@@ -236,23 +322,26 @@ def test_recurrence_path_is_the_default_off_the_tpu_and_agrees():
     )
 
 
+@NORM
 @CONV
-def test_counters_count_what_they_say(conv):
+def test_counters_count_what_they_say(conv, norm):
     reg = registry.always()
     names = ("kda.calls", "kda.chunks", "kda.state_bytes_saved",
-             "kda.calls.conv")
+             "kda.calls.conv", "kda.calls.out_norm")
     before = [reg.counter(n).get() for n in names]
     b, s, h, d = 2, 40, 2, 16
     argv, weights = operands(b, s, h, d, seed=6)
     taps = taps_for(h, d) if conv else None
     jax.eval_shape(jax.grad(lambda *a: jnp.sum(kda_attention(
-        *a, n_heads=h, conv=taps, use_kernel=True, chunk=16, sub=8
+        *a, n_heads=h, conv=taps, out_norm=EPS if norm else None,
+        use_kernel=True, chunk=16, sub=8,
     ) * weights), argnums=(0, 1, 2, 3, 4)), *argv)  # built, not run
-    calls, chunks, saved, convolving = (
+    calls, chunks, saved, convolving, normalising = (
         reg.counter(n).get() - was for n, was in zip(names, before)
     )
     assert calls == 2  # the forward and the backward
     assert convolving == (2 if conv else 0)  # of them, those that convolve
+    assert normalising == (2 if norm else 0)  # ... that normalise their exit
     assert chunks == b * h * 3  # 40 rows padded to 48: three chunks of 16
     assert saved == chunks * d * d * 4  # one float32 [d_v, d_k] state each
     plan = kda_kernels._plan(
@@ -264,13 +353,14 @@ def test_counters_count_what_they_say(conv):
     assert plan.state_bytes == b * h * d * d * 2
 
 
-def _kernel_calls(conv):
+def _kernel_calls(conv, out_norm=None):
     """The traced ``pallas_call`` equations of forward + backward, by name."""
     from horovod_tpu.analysis.jaxpr_walk import _sub_jaxprs_generic
 
     argv, weights = operands(1, 32, 1, 16)
     traced = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(kda_attention(
-        *a, n_heads=1, conv=conv, use_kernel=True, chunk=16, sub=8
+        *a, n_heads=1, conv=conv, out_norm=out_norm, use_kernel=True,
+        chunk=16, sub=8,
     ) * weights)))(*argv)
 
     def walk(jaxpr):
@@ -283,41 +373,64 @@ def _kernel_calls(conv):
             if e.primitive.name == "pallas_call"}
 
 
+@NORM
 @CONV
-def test_kernels_are_named_for_the_trace_and_carry_no_scope(conv):
-    calls = _kernel_calls(taps_for(1, 16) if conv else None)
+def test_kernels_are_named_for_the_trace_and_carry_no_scope(conv, norm):
+    calls = _kernel_calls(taps_for(1, 16) if conv else None,
+                          EPS if norm else None)
     assert sorted(calls) == ["hvd_kda_bwd", "hvd_kda_fwd"]
     for e in calls.values():
         stack = str(e.source_info.name_stack)
         assert "attn_layout" not in stack and "kda_conv" not in stack
     # q, k, v, g, beta (the backward: the states and dO too); with taps
     # three halo blocks and three tap blocks more, and the taps' partial
-    # gradients beside dq, dk, dv, dg, dbeta
+    # gradients beside dq, dk, dv, dg, dbeta; with the exit norm 1 / rms
+    # beside out and the states, and it and out back into the backward
     more = 6 if conv else 0
     assert len(calls["hvd_kda_fwd"].invars) == 5 + more
-    assert len(calls["hvd_kda_bwd"].invars) == 7 + more
+    assert len(calls["hvd_kda_fwd"].outvars) == 2 + norm
+    assert len(calls["hvd_kda_bwd"].invars) == 7 + more + 2 * norm
     assert len(calls["hvd_kda_bwd"].outvars) == 5 + more // 2
+    if norm:  # [B, H, 1, S_pad] float32, as dbeta leaves
+        assert (calls["hvd_kda_fwd"].outvars[2].aval.shape
+                == calls["hvd_kda_bwd"].outvars[4].aval.shape == (1, 1, 1, 32))
+
+
+def _traced_grad(**kw):
+    """The jaxpr, as text, of the gradient in q, k, v, g, beta of a call at
+    2 x 200 (padded) x 2 heads of 128, bfloat16 operands."""
+    x = lambda w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 200, w), dtype
+    )
+    shapes = (x(256), x(256), x(256), x(256, jnp.float32),
+              x(2, jnp.float32))
+    return str(jax.make_jaxpr(jax.grad(lambda *a: kda_attention(
+        *a, n_heads=2, use_kernel=True, **kw
+    ).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*shapes))
 
 
 def test_a_call_without_taps_traces_what_it_traced():
     """``conv=None`` is the call without the argument, equation for
     equation, compiled or interpreted (the parent's jaxprs themselves were
     compared when the argument came: CHANGES.md, PR 43)."""
-    x = lambda w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
-        (2, 200, w), dtype
-    )
-    shapes = (x(256), x(256), x(256), x(256, jnp.float32),
-              x(2, jnp.float32))
-
-    def traced(**kw):
-        return str(jax.make_jaxpr(jax.grad(lambda *a: kda_attention(
-            *a, n_heads=2, use_kernel=True, **kw
-        ).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*shapes))
-
     for interpret in (False, True):
-        assert traced(interpret=interpret) == traced(
+        assert _traced_grad(interpret=interpret) == _traced_grad(
             interpret=interpret, conv=None
         )
+
+
+@CONV
+def test_a_call_without_the_exit_norm_traces_what_it_traced(conv):
+    """``out_norm=None`` is the call without the argument, equation for
+    equation, with and without taps, compiled or interpreted (the parent's
+    jaxprs themselves were compared when the argument came: CHANGES.md,
+    PR 45); with it the traced program is another."""
+    for interpret in (False, True):
+        call = dict(interpret=interpret)
+        if conv:
+            call["conv"] = taps_for(2, 128)
+        assert _traced_grad(**call) == _traced_grad(**call, out_norm=None)
+        assert _traced_grad(**call) != _traced_grad(**call, out_norm=EPS)
 
 
 @pytest.mark.parametrize("moved", ["q", "v"])
@@ -356,13 +469,17 @@ def test_a_blocks_border_is_causal(moved):
     (dict(taps=KdaConv(*[jnp.zeros((4, 8))] * 3)), "conv taps"),
     (dict(taps=KdaConv(*[jnp.zeros((10, 16))] * 3)), "1 to 9 taps"),
     (dict(chunk=8), "multiple of 16"),
-], ids=["recurrence", "widths", "too-many", "short-block"])
+    (dict(use_kernel=False, taps=None, out_norm=EPS), "normalise the result"),
+], ids=["recurrence", "widths", "too-many", "short-block",
+        "recurrence-out-norm"])
 def test_taps_that_do_not_fit_are_refused(bad, match):
+    """And the exit norm off the kernel path, as the taps are."""
     (q, k, v, g, beta), _ = operands(1, 8, 1, 16)
     with pytest.raises(ValueError, match=match):
         kda_attention(
             q, k, v, g, beta, n_heads=1,
             conv=bad.get("taps", taps_for(1, 16)),
+            out_norm=bad.get("out_norm"),
             use_kernel=bad.get("use_kernel", True), chunk=bad.get("chunk", 16),
             sub=8,
         )

@@ -249,6 +249,55 @@ def test_the_convolutions_run_where_the_mixer_runs(kernels):
     assert under_conv(backward) > under_conv(forward)
 
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+def test_the_head_wise_norm_runs_where_the_mixer_runs(kernels):
+    """On the kernel path the mixer asks ``kda_attention`` for the norm
+    (``out_norm=``): the four KDA layers build kernels that normalise their
+    exit, and under ``kda_gate`` the forward traces neither the mean of
+    squares nor its ``rsqrt`` nor a 4-d array of heads (what is left there
+    of the gated norm is its scale and gate, elementwise on ``[B, S, H
+    d]``). On the recurrence path the gated norm is XLA's, written out on
+    heads, and no kernel is built."""
+    import model_parts
+    from horovod_tpu.obs import registry
+
+    cfg = _tiny(use_flash=False, use_kernel=kernels)
+    params, tokens = _params(cfg), _tokens(cfg, 3)
+    logits, loss = _system(cfg)
+    counter = registry.always().counter("kda.calls.out_norm")
+    before = counter.get()
+    forward = jax.make_jaxpr(logits)(params, tokens)
+    jax.make_jaxpr(jax.grad(loss))(params, tokens)
+    assert counter.get() - before == (
+        3 * len(cfg.kda_layers) if kernels else 0
+    )
+    gate = {
+        primitive
+        for primitive, stack, _ in model_parts.operations(forward.jaxpr)
+        if "kda_gate" in stack.split("/")
+    }
+    assert "logistic" in gate and "mul" in gate
+    statistic = {"rsqrt", "reduce_sum"} & gate
+    assert statistic == (set() if kernels else {"rsqrt", "reduce_sum"})
+
+
+def test_the_kernel_path_is_the_recurrence_path():
+    """The same weights and tokens through both paths of the model: the
+    kernels convolve at their door and normalise at their exit, the
+    recurrence path does both in XLA; loss and every gradient leaf agree
+    at the tolerance the plain reference is held to."""
+    paths = [_tiny(use_flash=False, use_kernel=k) for k in (True, False)]
+    params, tokens = _params(paths[0]), _tokens(paths[0], 4)
+    with jax.default_matmul_precision("highest"):
+        (got, got_grads), (want, want_grads) = (
+            jax.jit(jax.value_and_grad(_system(cfg)[1]))(params, tokens)
+            for cfg in paths
+        )
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    _assert_trees_close(got_grads, want_grads, 1e-4)
+    assert float(jnp.abs(got_grads["block_0"]["attn"]["o_norm"]).max()) > 0
+
+
 def test_mixer_keeps_the_decay_and_the_state_in_float32():
     """``g``, ``beta`` reach the kernels' entry as float32 whatever the
     compute dtype, and ``g <= 0``."""

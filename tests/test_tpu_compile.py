@@ -207,12 +207,13 @@ def test_flash_attention_latent_compiles(v5e, batch, seq, heads):
     assert hlo.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("norm", [False, True], ids=["", "out-norm"])
 @pytest.mark.parametrize("conv", [False, True], ids=["plain", "conv"])
 @pytest.mark.parametrize(
     "batch,seq,heads", [(1, 8192, 32), (2, 1000, 4)],
     ids=["kda-1x8192x32", "kda-s1000x4-padded"],
 )
-def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads, conv):
+def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads, conv, norm):
     """The Kimi Delta Attention kernels, forward and backward, at the
     cell's shape (1 x 8,192, 32 heads of 128 key and value channels,
     bfloat16 operands, float32 ``g`` and ``beta``) and at a padded length:
@@ -220,13 +221,17 @@ def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads, conv):
     them that the entry did not take or hand back. With ``conv`` (the
     cell's call since PR 43: four taps an operand, convolved and gated in
     VMEM) the same two calls, the taps' gradients leaving the backward as
-    one float32 ``[8, 128]`` block a head and batch row."""
+    one float32 ``[8, 128]`` block a head and batch row. With ``out_norm``
+    (the cell's call since PR 45: a head's output normalised at the exit)
+    the same two calls again, ``1 / rms`` leaving the forward as a float32
+    row vector a head, as ``dbeta`` leaves the backward."""
     from horovod_tpu.ops.kda_kernels import KdaConv, kda_attention
 
     def loss(q, k, v, g, beta, *taps):
         return kda_attention(
             q, k, v, g, beta, n_heads=heads, use_kernel=True,
-            conv=KdaConv(*taps) if conv else None, interpret=False,
+            conv=KdaConv(*taps) if conv else None,
+            out_norm=1e-5 if norm else None, interpret=False,
         ).astype(jnp.float32).sum()
 
     wide = ((batch, seq, 128 * heads), jnp.bfloat16)
@@ -241,6 +246,46 @@ def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads, conv):
     padded = -(-seq // 128) * 128
     assert f"bf16[{batch},{heads},{padded // 64},128,128]" in hlo
     assert (f"f32[{batch},{heads},8,128]" in hlo) == conv
+    (fwd,) = [line for line in hlo.splitlines()
+              if "tpu_custom_call" in line and "hvd_kda_fwd" in line]
+    assert (f"f32[{batch},{heads},1,{padded}]" in fwd.split(" custom-call(")[0]
+            ) == norm
+
+
+def test_kda_mixer_turns_no_float32_heads_around_its_norm(v5e_topology, v5e):
+    """One ``KimiDeltaAttention``'s ``value_and_grad`` at the cell's shape
+    (1 x 8,192 x 2,304, 32 heads of 128), compiled: the kernels normalise a
+    head's output at their exit, so the program reshapes nothing to heads
+    and the compiler sets no ``copy`` of a float32 ``[.., 32, 128]`` array
+    around the norm (until PR 45 three a layer, 268 MB of traffic each,
+    with three fusions that existed only to make the float32 array the
+    copy turned), and no float32 array of that shape exists at all."""
+    import horovod_tpu as hvd
+    from horovod_tpu.models.linear_moe import (
+        KimiDeltaAttention, LinearMoEConfig,
+    )
+
+    mixer = KimiDeltaAttention(LinearMoEConfig())
+    x = jax.ShapeDtypeStruct((1, 8192, 2304), jnp.bfloat16)
+
+    def loss(params, x):
+        return mixer.apply(params, x).astype(jnp.float32).sum()
+
+    hvd.init(devices=v5e_topology.devices[:1])  # the world's devices: TPUs
+    try:
+        params = jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            (params, x),
+        )
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            *args
+        ).compile().as_text()
+    finally:
+        hvd.shutdown()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.findall(r"= f32\[[\d,]*32,128\]\S* copy\(", hlo)
+    assert not re.findall(r"f32\[[\d,]*,32,128\]", hlo)
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])

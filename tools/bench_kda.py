@@ -2,7 +2,7 @@
 
     python tools/bench_kda.py [--tree CHECKOUT] [--iters 8] [--shape NAME]
         [--heads 32] [--sub N ...] [--block-chunks N ...] [--no-recurrence]
-        [--conv]
+        [--conv] [--out-norm]
 
 Runs forward + backward of ``ops/kda_kernels.kda_attention`` alone (one
 layer's call) at the shape of the benchmark's cell, ``kda-1x8192``: 1 x
@@ -27,6 +27,18 @@ alone, forward + backward from a given cotangent (``xla-conv_silu``: every
 device operation of that program, microseconds a call): what the kernels'
 door costs beside what it replaces. A tree without the argument prints
 the first and the last.
+``--out-norm`` (with ``--conv``: every kernel row with taps, as the cell
+calls them) prints one table of four lines: the two kernels (``kernels``
+or ``kernels+conv``), the two kernels given ``out_norm=`` (``...+out_norm``;
+errors of out and of every gradient against the model's head-wise float32
+RMS norm behind the kernels without it), XLA's gated head-wise norm alone
+as ``models.linear_moe`` writes it on the recurrence path, forward +
+backward from a given cotangent (``xla-gated_norm``: the reshape to heads,
+the mean of squares, the scale and the gate's sigmoid; every device
+operation, microseconds a call), and what XLA still does behind kernels
+that normalise (``xla-gate``: ``o^ x tile(scale) x sigmoid(gate)``,
+elementwise, forward + backward): what the kernels' exit costs beside what
+it replaces.
 ``--sub`` / ``--block-chunks`` (may repeat) time the plan's statics at other
 values than the module's; ``--tree`` imports ``horovod_tpu`` and
 ``benchmark`` from another checkout, as ``tools/bench_attention.py`` does.
@@ -71,25 +83,49 @@ def operands(key, b, s, h, dk, dv):
             beta, w]
 
 
+NORM_EPS = 1e-5  # the configuration's eps
+GRADS = ("dq", "dk", "dv", "dg", "dbeta", "dtaps_q", "dtaps_k", "dtaps_v")
+
+
+def door_operands(argv, h, dk, dv):
+    """q, k, v as a projection leaves them, and taps as the model draws
+    them."""
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+    raw = [jax.random.normal(key, x.shape, jnp.float32).astype(x.dtype)
+           for key, x in zip(keys[:3], argv[:3])]
+    taps = [jax.random.uniform(key, (4, h * d), jnp.float32, -0.5, 0.5)
+            for key, d in zip(keys[3:], (dk, dk, dv))]
+    return raw, taps
+
+
+def weighted_grads(entry, n, w):
+    """``(gradients in the first n operands, out)`` of ``sum(entry(...) w)``,
+    jitted."""
+    def loss(*a):
+        out = entry(*a)
+        return (out.astype(jnp.float32) * w).sum(), out
+    return jax.jit(jax.grad(loss, argnums=tuple(range(n)), has_aux=True))
+
+
+def errors(got, want):
+    """name: [largest absolute difference, largest absolute value]."""
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    return {
+        name: [float(jnp.max(jnp.abs(f32(a) - f32(e)))),
+               float(jnp.max(jnp.abs(f32(e))))]
+        for name, a, e in zip((*GRADS[:len(got[0])], "out"),
+                              (*got[0], got[1]), (*want[0], want[1]))
+    }
+
+
 def conv_table(args, kda_kernels, argv, h, dk, dv, emit):
     """``--conv``: the kernels, the kernels that convolve, XLA's
     convolutions alone."""
     from horovod_tpu.models.linear_moe import conv_silu
 
-    keys = jax.random.split(jax.random.PRNGKey(1), 6)
     q, k, v, g, beta, w = argv
-    # as a projection leaves them, and taps as the model draws them
-    raw = [jax.random.normal(key, x.shape, jnp.float32).astype(x.dtype)
-           for key, x in zip(keys[:3], (q, k, v))]
-    taps = [jax.random.uniform(key, (4, h * d), jnp.float32, -0.5, 0.5)
-            for key, d in zip(keys[3:], (dk, dk, dv))]
-
-    def grads(entry, n):
-        def loss(*a):
-            out = entry(*a)
-            return (out.astype(jnp.float32) * w).sum(), out
-        return jax.jit(jax.grad(loss, argnums=tuple(range(n)), has_aux=True))
-
+    raw, taps = door_operands(argv, h, dk, dv)
+    grads = functools.partial(weighted_grads, w=w)
     attend = functools.partial(kda_kernels.kda_attention, n_heads=h,
                                use_kernel=True)
     plain = grads(attend, 5)
@@ -101,16 +137,9 @@ def conv_table(args, kda_kernels, argv, h, dk, dv, emit):
             q, k, v, g, beta, conv=kda_kernels.KdaConv(*t)), 8)
         operands = (*raw, g, beta, *taps)
         us = kernel_us(inside, operands, args.iters, KERNELS)
-        got, want = inside(*operands), outside(*operands)
-        names = ("dq", "dk", "dv", "dg", "dbeta", "dtaps_q", "dtaps_k",
-                 "dtaps_v", "out")
-        emit("kernels+conv", us, abs_err_vs_conv_silu_then_kernels={
-            name: [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                         - e.astype(jnp.float32)))),
-                   float(jnp.max(jnp.abs(e.astype(jnp.float32))))]
-            for name, a, e in zip(names, (*got[0], got[1]),
-                                  (*want[0], want[1]))
-        })
+        emit("kernels+conv", us, abs_err_vs_conv_silu_then_kernels=errors(
+            inside(*operands), outside(*operands)
+        ))
 
     def convolutions(xs, ts, dys):
         out = [jax.vjp(conv_silu, x, t) for x, t in zip(xs, ts)]
@@ -120,6 +149,60 @@ def conv_table(args, kda_kernels, argv, h, dk, dv, emit):
                            args.iters)
     emit("xla-conv_silu",
          {"every_op": sum(us for _, us in events) / args.iters})
+
+
+def norm_table(args, kda_kernels, argv, h, dk, dv, emit):
+    """``--out-norm``: the kernels, the kernels that normalise their exit,
+    XLA's gated head-wise norm alone and the gate that stays XLA's."""
+    q, k, v, g, beta, w = argv
+    b, s, width = v.shape
+    attend = functools.partial(kda_kernels.kda_attention, n_heads=h,
+                               use_kernel=True)
+    operands, call, row = (q, k, v, g, beta), attend, "kernels"
+    if args.conv:
+        raw, taps = door_operands(argv, h, dk, dv)
+        operands, row = (*raw, g, beta, *taps), "kernels+conv"
+        call = lambda *a, **kw: attend(  # noqa: E731
+            *a[:5], conv=kda_kernels.KdaConv(*a[5:]), **kw
+        )
+
+    def head_norm(o):  # the model's, on the recurrence path
+        heads = o.astype(jnp.float32).reshape(b, s, h, dv)
+        return heads * jax.lax.rsqrt(
+            jnp.mean(heads * heads, axis=-1, keepdims=True) + NORM_EPS
+        )
+
+    grads = functools.partial(weighted_grads, n=len(operands), w=w)
+    emit(row, kernel_us(grads(call), operands, args.iters, KERNELS))
+    inside = grads(functools.partial(call, out_norm=NORM_EPS))
+    behind = grads(lambda *a: head_norm(call(*a)).reshape(b, s, width).astype(
+        v.dtype
+    ))
+    us = kernel_us(inside, operands, args.iters, KERNELS)
+    emit(row + "+out_norm", us, abs_err_vs_norm_behind_kernels=errors(
+        inside(*operands), behind(*operands)
+    ))
+
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    gate = jax.random.normal(keys[0], (b, s, width), jnp.float32)
+    scale = 1.0 + 0.1 * jax.random.normal(keys[1], (dv,), jnp.float32)
+
+    def gated_norm(o, gate, scale):
+        return ((head_norm(o) * scale).reshape(b, s, width)
+                * jax.nn.sigmoid(gate)).astype(o.dtype)
+
+    def gate_only(o, gate, scale):
+        return (o.astype(jnp.float32) * jnp.tile(scale, h)
+                * jax.nn.sigmoid(gate)).astype(o.dtype)
+
+    dz = w.astype(v.dtype)
+    for name, fn in (("xla-gated_norm", gated_norm), ("xla-gate", gate_only)):
+        def both(o, gate, scale, dz, fn=fn):
+            z, pull = jax.vjp(fn, o, gate, scale)
+            return z, pull(dz)
+        events = device_events(jax.jit(both), (v, gate, scale, dz),
+                               args.iters)
+        emit(name, {"every_op": sum(us for _, us in events) / args.iters})
 
 
 def main():
@@ -138,6 +221,10 @@ def main():
     ap.add_argument("--conv", action="store_true",
                     help="the kernels with and without taps, and XLA's "
                          "conv_silu alone: one table")
+    ap.add_argument("--out-norm", action="store_true",
+                    help="the kernels with and without the exit norm (with "
+                         "--conv: all with taps), XLA's gated norm alone "
+                         "and the gate that stays: one table")
     args = ap.parse_args()
     sys.path.insert(0, args.tree)
     from benchmark.lib.flops import roofline
@@ -169,14 +256,15 @@ def main():
             return (out.astype(jnp.float32) * w).sum(), out
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
 
-    if args.conv:
+    if args.conv or args.out_norm:
         def emit(row, us, **more):
             print(json.dumps(dict(
                 tree=args.tree, shape=args.shape, heads=h, row=row,
                 device_kind=device.device_kind, us_per_call=us,
                 total_us=sum(us.values()), **more,
             )), flush=True)
-        return conv_table(args, kda_kernels, argv, h, dk, dv, emit)
+        table = norm_table if args.out_norm else conv_table
+        return table(args, kda_kernels, argv, h, dk, dv, emit)
 
     want = seconds = None
     if not args.no_recurrence:
