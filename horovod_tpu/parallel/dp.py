@@ -10,6 +10,7 @@ one fused psum per gradient bucket, identical optimizer update everywhere.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import time
 import warnings
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
@@ -26,6 +27,7 @@ from ..optimizer import (
     DistributedOptimizer,
     ShardedDistributedOptimizer,
     ef_residual_norm,
+    guarded_commit,
     sharded_state_specs,
 )
 from ..ops.collectives import Average, ReduceOp, allreduce
@@ -563,104 +565,109 @@ def make_train_step(
     quantized error feedback, ``fused_update``) pin the fusion
     threshold too — see docs/api.md "Autotuning" for when not to.
     """
-    autotune_cfg = None
-    if autotune is not False:
-        from .. import tune as _tune
-
-        autotune_cfg = _tune.resolve(autotune)
-    if autotune_cfg is not None:
-        ctx = _get_context()
-        build_kwargs = dict(
-            has_aux=has_aux, distribute_optimizer=distribute_optimizer,
-            op=op, compression=compression, axis=axis, donate=donate,
-            mesh=mesh, batch_spec=batch_spec, sharded=sharded,
-            gather_compression=gather_compression,
-            threshold_bytes=threshold_bytes,
-            tokens_per_step=tokens_per_step, flops_per_step=flops_per_step,
-            overlap=overlap, accum_steps=accum_steps, stagger=stagger,
-            lint=lint, lint_allow=lint_allow,
-            error_feedback=error_feedback, guard=guard,
-            fused_update=fused_update, remat=remat,
-            compute_dtype=compute_dtype, act_quant=act_quant,
-            autotune=False,
-        )
-        pinned = []
-        if threshold_bytes is not None:
-            pinned.append(_env.FUSION_THRESHOLD)
-        if compute_dtype is not None:
-            pinned.append(_env.COMPUTE_DTYPE)
-        if act_quant is not None:
-            pinned.append(_env.ACT_QUANT)
-        overlap_on = overlap if overlap is not None else _env.overlap_default()
-        if stagger is not None or not overlap_on:
-            # Explicitly pinned, or inert without the overlap pipeline
-            # (its env default only arms as part of overlap) — either
-            # way tuning it would score noise.
-            pinned.append(_env.OVERLAP_STAGGER)
-        quant_on = (
-            is_quantized(compression) if compression is not None
-            else bool(_env.quant_mode())
-        )
-        structure_locked = bool(
-            sharded or fused_update or (quant_on and error_feedback)
-        )
-        step = _tune.attach_train_autotuner(
-            lambda: make_train_step(loss_fn, optimizer, **build_kwargs),
-            autotune_cfg,
-            pinned=pinned,
-            mesh_shape={a: ctx.mesh.shape[a] for a in ctx.mesh.axis_names},
-            cross_axes=tuple(ctx.cross_axes or ()),
-            structure_locked=structure_locked,
-        )
-        if step is not None:
-            return step, step.opt
+    # First statement: here locals() holds the call's arguments and no more.
+    args = _StepArgs(**locals())
+    options = _resolve(args)
+    if options.autotune is not None:
+        tuned = _attach_autotuner(args, options)
+        if tuned is not None:
+            return tuned, tuned.opt
         # Empty effective space (every live knob pinned by this build):
         # fall through and build the plain untuned step.
+    return _build(options)
+
+
+# The arguments as the caller gave them (unset stays None): one frozen
+# record cut from the signature itself, so that no second list of them can
+# drift from it. The autotuner rebuilds from this record, not from the
+# resolved options, because a rebuild must read the environment anew.
+_StepArgs = dataclasses.make_dataclass(
+    "_StepArgs", list(inspect.signature(make_train_step).parameters),
+    frozen=True,
+)
+
+
+# Options as resolved: the same fields, each holding what the build uses.
+# No None is left where the environment has a default; ``lint`` is a mode
+# ("" | "warn" | "raise"), ``guard`` a GuardConfig or None, ``autotune`` an
+# AutotuneConfig or None, ``publish`` a cadence (0: no weight stream),
+# ``mesh`` and ``batch_spec`` are filled in, a quantized ``compression`` has
+# its block pinned. Five more follow from them: ``quantized``, the
+# context's ``world_axes``, and the compile-time half of the gradient
+# exchange (ops/layout.py). Wherever the replicated step's reduction axis
+# spans more than one device the compiler is told to lower all-reduces
+# asynchronously and to merge no gradient leaf that passes the size rule
+# with another: ``reduction_limit`` is the most bytes one reduction of this
+# step holds, and ``certify``'s wire layout follows it. ``overlap=True``
+# passes the same options on the other paths, at the fusion threshold.
+# Per-compile options only (``copts``); {} on the CPU test platform and
+# none at all on one device, so that step compiles as it always did.
+_Options = dataclasses.make_dataclass(
+    "_Options",
+    [f.name for f in dataclasses.fields(_StepArgs)] + [
+        "quantized", "world_axes", "overlapped_exchange", "reduction_limit",
+        "copts",
+    ],
+    frozen=True,
+)
+
+
+def _resolve(args):
+    """Arguments as given -> options as resolved: the one place where an
+    argument of :func:`make_train_step` meets its ``HVDTPU_*`` twin, and
+    the one place that validates. Traces nothing and builds nothing.
+
+    (``HVDTPU_CERT`` is read on the step's first call and
+    ``HVDTPU_HBM_BUDGET_GB`` on each ``step.lint``: when they are read is
+    behaviour. ``fused_update`` and ``threshold_bytes`` meet their twins
+    in ``optimizer.py``.)"""
+    autotune = None
+    if args.autotune is not False:
+        from .. import tune as _tune
+
+        autotune = _tune.resolve(args.autotune)
     ctx = _get_context()
+    compression = args.compression
     if compression is None:
         # Unset (None, the parameter default): HVDTPU_QUANT=int8|fp8
         # arms the quantized wire. An explicit compression= — including
         # an explicit Compression.none — always wins over the env.
         q = _env.quant_mode()
-        compression = (
-            Compression.by_name(q) if q else Compression.none
-        )
+        compression = Compression.by_name(q) if q else Compression.none
     quantized = is_quantized(compression)
     if quantized:
         # Pin the block size now so the optimizer's residual layout and
-        # the lint prediction below can never read different env values.
+        # the lint prediction can never read different env values.
         compression = compression.with_block(compression.block_size())
-    if overlap is None:
-        overlap = _env.overlap_default()
+    overlap = _env.overlap_default() if args.overlap is None else args.overlap
+    accum_steps = args.accum_steps
     if accum_steps is None:
         accum_steps = _env.overlap_accum_steps()
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
+    stagger = args.stagger
     if stagger is None:
         # Default only arms chaining as part of the overlap pipeline; an
         # EXPLICIT stagger=True is honored standalone (measuring bucket
         # chaining without the scheduler compile options is legitimate).
         stagger = bool(overlap) and _env.overlap_stagger()
-    if lint is None:
-        lint = _env.lint_mode()
-    lint_mode = "warn" if lint is True else (lint or "")
-    if lint_mode in ("off", "none", "no", "false", "0"):
+    lint_arg = _env.lint_mode() if args.lint is None else args.lint
+    lint = "warn" if lint_arg is True else (lint_arg or "")
+    if lint in ("off", "none", "no", "false", "0"):
         # Accept the documented HVDTPU_LINT spellings so a caller can
         # mirror the env value to force-disable over an env default.
-        lint_mode = ""
-    if lint_mode not in ("", "warn", "raise"):
+        lint = ""
+    if lint not in ("", "warn", "raise"):
         raise ValueError(
-            f"lint must be one of False/'off'/'warn'/'raise', got {lint!r}"
+            "lint must be one of False/'off'/'warn'/'raise', "
+            f"got {lint_arg!r}"
         )
-    from ..guard import check_gradients as _guard_check
     from ..guard import resolve as _guard_resolve
-    from ..ops.remat import checkpoint_fn as _remat_wrap
-
     from ..ops import actquant as _actquant
-    from ..ops.fp8 import fp8_state_optimizer as _fp8_state_optimizer
+    from ..ops.remat import resolve_policy as _remat_policy
 
-    if remat is None:
-        remat = _env.remat_mode()
+    remat = _env.remat_mode() if args.remat is None else args.remat
+    compute_dtype = args.compute_dtype
     if compute_dtype is None:
         compute_dtype = _env.compute_dtype_mode()
     if compute_dtype not in ("", "fp8"):
@@ -668,90 +675,43 @@ def make_train_step(
             f"compute_dtype={compute_dtype!r} is not recognized; "
             "use ''|'fp8'"
         )
-    act_quant = _actquant.resolve_mode(act_quant)
+    act_quant = _actquant.resolve_mode(args.act_quant)
     if compute_dtype == "fp8":
-        if sharded:
+        if args.sharded:
             raise NotImplementedError(
                 "compute_dtype='fp8' is replicated-path only: the ZeRO-1 "
                 "flat-shard update cannot see which bucket slices are fp8 "
                 "scale state, so the overwrite-with-gradient commit has "
                 "no leaf boundary to mask on"
             )
-        if op is not Average:
+        if args.op is not Average:
             raise ValueError(
                 "compute_dtype='fp8' requires op=Average: the delayed-"
                 "scaling state rides the gradient reduction, and only "
                 "the mean keeps amax histories replica-uniform"
             )
-        # Masked optimizer split BEFORE the distributed wrapper: fp8_*
-        # leaves commit their gradient-carried new values verbatim (no
-        # Adam moments), every other leaf sees the base optimizer. A
-        # harmless no-op when the model declares no fp8 state.
-        optimizer = _fp8_state_optimizer(optimizer)
-    # Resolve (and validate) the policy now, before any tracing: the
-    # wrapped loss is what accumulate_gradients differentiates, so the
-    # policy governs every microbatch's backward identically.
-    if act_quant:
-        base_loss_fn = loss_fn
-
-        def _armed_loss(params, batch):
-            # Arm the model-side boundaries for exactly this trace; the
-            # thread-local keeps concurrently-traced plain steps plain.
-            with _actquant.activate(act_quant):
-                return base_loss_fn(params, batch)
-
-        loss_fn = _actquant.checkpoint_fn(_armed_loss, remat, act_quant)
-    else:
-        loss_fn = _remat_wrap(loss_fn, remat)
-
-    guard_cfg = _guard_resolve(guard)
-    m = mesh if mesh is not None else ctx.mesh
+    # Validate the policy now, before any tracing: the wrapped loss is
+    # what accumulate_gradients differentiates, so the policy governs
+    # every microbatch's backward identically.
+    _remat_policy(remat)
+    guard = _guard_resolve(args.guard)
+    if args.distribute_optimizer and args.fused_update and not args.sharded:
+        raise ValueError(
+            "fused_update requires the ZeRO-1 flat-shard layout; "
+            "pass sharded=True"
+        )
+    m = args.mesh if args.mesh is not None else ctx.mesh
     world_axes = ctx.world_axes
-    bspec = batch_spec if batch_spec is not None else P(
+    batch_spec = args.batch_spec if args.batch_spec is not None else P(
         world_axes if len(world_axes) > 1 else world_axes[0]
     )
-    if not distribute_optimizer:
-        opt = optimizer
-    elif sharded:
-        opt = ShardedDistributedOptimizer(
-            optimizer,
-            op=op,
-            compression=compression,
-            gather_compression=gather_compression,
-            axis=axis,
-            threshold_bytes=threshold_bytes,
-            stagger=stagger,
-            error_feedback=error_feedback,
-            fused_update=fused_update,
-        )
-    else:
-        if fused_update:
-            raise ValueError(
-                "fused_update requires the ZeRO-1 flat-shard layout; "
-                "pass sharded=True"
-            )
-        opt = DistributedOptimizer(
-            optimizer, op=op, compression=compression, axis=axis,
-            threshold_bytes=threshold_bytes, stagger=stagger,
-            error_feedback=error_feedback,
-        )
-
-    # The compile-time half of the gradient exchange (ops/layout.py).
-    # Wherever the replicated step's reduction axis spans more than one
-    # device the compiler is told to lower all-reduces asynchronously and
-    # to merge no gradient leaf that passes the size rule with another:
-    # ``reduction_limit`` is the most bytes one reduction of this step
-    # holds, and ``certify``'s wire layout follows it. ``overlap=True``
-    # passes the same options on the other paths, at the fusion threshold.
-    # Per-compile options only; {} on the CPU test platform and none at
-    # all on one device, so that step compiles as it always did.
     overlapped_exchange = (
-        distribute_optimizer and not sharded and not quantized
-        and int(np.prod([m.shape[a] for a in _axis_or_world(axis)])) > 1
+        args.distribute_optimizer and not args.sharded and not quantized
+        and int(np.prod([m.shape[a] for a in _axis_or_world(args.axis)])) > 1
     )
     reduction_limit = (
-        overlap_threshold_bytes(threshold_bytes) if overlapped_exchange
-        else threshold_bytes
+        overlap_threshold_bytes(args.threshold_bytes) if overlapped_exchange
+        else args.threshold_bytes
     )
     copts = None
     if overlap or overlapped_exchange:
@@ -760,6 +720,122 @@ def make_train_step(
             **collective_compiler_options(reduction_limit, platform=platform),
             **overlap_compiler_options(platform),
         } or None
+    publish = (
+        _env.publish_every() if args.publish is None
+        else max(0, int(args.publish))
+    )
+    return _Options(**vars(args) | dict(
+        autotune=autotune, compression=compression, quantized=quantized,
+        overlap=overlap, accum_steps=accum_steps, stagger=stagger, lint=lint,
+        remat=remat, compute_dtype=compute_dtype, act_quant=act_quant,
+        guard=guard, publish=publish, mesh=m, world_axes=world_axes,
+        batch_spec=batch_spec, overlapped_exchange=overlapped_exchange,
+        reduction_limit=reduction_limit, copts=copts,
+    ))
+
+
+def _attach_autotuner(args, o):
+    """The step wrapped in the worker half of the knob search, or None
+    when every live knob is pinned by this build. The tuner builds, and
+    after a retrace switch rebuilds, from the arguments AS GIVEN with
+    ``autotune=False``: the switch has written new knob values to the
+    environment and the rebuild must resolve against them."""
+    from .. import tune as _tune
+
+    ctx = _get_context()
+    pinned = []
+    if args.threshold_bytes is not None:
+        pinned.append(_env.FUSION_THRESHOLD)
+    if args.compute_dtype is not None:
+        pinned.append(_env.COMPUTE_DTYPE)
+    if args.act_quant is not None:
+        pinned.append(_env.ACT_QUANT)
+    if args.stagger is not None or not o.overlap:
+        # Explicitly pinned, or inert without the overlap pipeline
+        # (its env default only arms as part of overlap) — either
+        # way tuning it would score noise.
+        pinned.append(_env.OVERLAP_STAGGER)
+    untuned = dataclasses.replace(args, autotune=False)
+    return _tune.attach_train_autotuner(
+        lambda: _build(_resolve(untuned)),
+        o.autotune,
+        pinned=pinned,
+        mesh_shape={a: ctx.mesh.shape[a] for a in ctx.mesh.axis_names},
+        cross_axes=tuple(ctx.cross_axes or ()),
+        structure_locked=bool(
+            o.sharded or o.fused_update
+            or (o.quantized and o.error_feedback)
+        ),
+    )
+
+
+def _build(o):
+    """The untuned build: optimizer, program, static surfaces, host
+    wrappers. Returns ``(step, wrapped_optimizer)``."""
+    opt = _build_optimizer(o)
+    program = _build_program(opt, o)
+    return _wrap(program, _static_surfaces(program, o), o), opt
+
+
+def _build_optimizer(o):
+    """The optimizer the step updates with: the caller's, split for fp8
+    state where armed, behind the replicated or the ZeRO-1 wrapper."""
+    from ..ops.fp8 import fp8_state_optimizer
+
+    optimizer = o.optimizer
+    if o.compute_dtype == "fp8":
+        # Masked optimizer split BEFORE the distributed wrapper: fp8_*
+        # leaves commit their gradient-carried new values verbatim (no
+        # Adam moments), every other leaf sees the base optimizer. A
+        # harmless no-op when the model declares no fp8 state.
+        optimizer = fp8_state_optimizer(optimizer)
+    if not o.distribute_optimizer:
+        return optimizer
+    wire = dict(
+        op=o.op, compression=o.compression, axis=o.axis,
+        threshold_bytes=o.threshold_bytes, stagger=o.stagger,
+        error_feedback=o.error_feedback,
+    )
+    if o.sharded:
+        return ShardedDistributedOptimizer(
+            optimizer, gather_compression=o.gather_compression,
+            fused_update=o.fused_update, **wire,
+        )
+    return DistributedOptimizer(optimizer, **wire)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Program:
+    """The traced and compiled program of one build, and nothing of the
+    host planes."""
+
+    dispatch: Callable  # (state, batch) -> (state, loss[, aux])
+    mapped_for: Callable  # state -> the shard_map'd step, before jit
+    jitted_for: Callable  # state -> the jax.jit that dispatch calls
+    # state (arrays or shapes) -> the state where dispatch puts it
+    as_dispatched: Callable = lambda state: state
+
+
+def _build_program(opt, o) -> _Program:
+    """Everything that decides the traced and the compiled program: the
+    loss's wrapping, ``hvd_train_step``, ``shard_map``, ``jit`` with the
+    exchange's compiler options, and the placing of a state that is not
+    on the mesh. Knows none of the host planes."""
+    from ..guard import check_gradients as _guard_check
+    from ..ops import actquant as _actquant
+    from ..ops.remat import checkpoint_fn as _remat_wrap
+
+    has_aux, axis, guard_cfg, m = o.has_aux, o.axis, o.guard, o.mesh
+    if o.act_quant:
+        def _armed_loss(params, batch):
+            # Arm the model-side boundaries for exactly this trace; the
+            # thread-local keeps concurrently-traced plain steps plain.
+            with _actquant.activate(o.act_quant):
+                return o.loss_fn(params, batch)
+
+        loss_fn = _actquant.checkpoint_fn(_armed_loss, o.remat, o.act_quant)
+    else:
+        loss_fn = _remat_wrap(o.loss_fn, o.remat)
 
     # The jitted function has a name of its own, so its builds are not
     # mixed with anything else called ``_step`` (obs/build.py), and it
@@ -771,8 +847,9 @@ def make_train_step(
     def hvd_train_step(state: TrainState, batch):
         with jax.named_scope("hvd_grad"):
             loss, aux, grads = accumulate_gradients(
-                loss_fn, state.params, batch, accum_steps, has_aux=has_aux
+                loss_fn, state.params, batch, o.accum_steps, has_aux=has_aux
             )
+        guard = state.guard
         if guard_cfg is not None:
             # In-graph gradient guard: screen BEFORE anything commits.
             # The update (and its collectives) still executes
@@ -781,49 +858,142 @@ def make_train_step(
             # by the replica-uniform verdict, so a poisoned step leaves
             # params/opt-state/EF-residuals untouched and the step
             # counter does not advance (the pipeline retries).
-            from ..optimizer import guarded_commit
-
             with jax.named_scope("hvd_grad"):
-                ok, _gnorm, new_guard = _guard_check(
+                ok, _gnorm, guard = _guard_check(
                     grads, state.guard, guard_cfg, axis=axis
                 )
-            updates, new_opt = opt.update(
-                grads, state.opt_state, state.params
-            )
-            with jax.named_scope("hvd_update"):
-                cand = optax.apply_updates(state.params, updates)
-                params, opt_state = guarded_commit(
-                    ok, cand, new_opt, state.params, state.opt_state
-                )
-            with jax.named_scope("hvd_loss_avg"):
-                loss = allreduce(loss, op=Average, axis=axis)
-            new_state = TrainState(
-                params,
-                opt_state,
-                state.step + ok.astype(state.step.dtype),
-                state.extra,
-                new_guard,
-            )
-            if has_aux:
-                return new_state, loss, aux
-            return new_state, loss
-        updates, new_opt = opt.update(grads, state.opt_state, state.params)
+        updates, opt_state = opt.update(grads, state.opt_state, state.params)
         with jax.named_scope("hvd_update"):
             params = optax.apply_updates(state.params, updates)
+            if guard_cfg is not None:
+                params, opt_state = guarded_commit(
+                    ok, params, opt_state, state.params, state.opt_state
+                )
         with jax.named_scope("hvd_loss_avg"):
             loss = allreduce(loss, op=Average, axis=axis)
+        advance = 1 if guard_cfg is None else ok.astype(state.step.dtype)
         new_state = TrainState(
-            params, new_opt, state.step + 1, state.extra, state.guard
+            params, opt_state, state.step + advance, state.extra, guard
         )
-        if has_aux:
-            return new_state, loss, aux
-        return new_state, loss
+        return (new_state, loss, aux) if has_aux else (new_state, loss)
 
-    def _seeded_for_trace(state):
-        if guard_cfg is not None and state.guard is None:
-            # The on-demand lint surface traces the step directly, before
-            # the guard wrapper's first-call seeding has run — give the
-            # trace the same seeded structure the wrapper would.
+    def mapped(state_spec):
+        out_specs = (state_spec, P(), P()) if has_aux else (state_spec, P())
+        return jax.shard_map(
+            hvd_train_step, mesh=m, in_specs=(state_spec, o.batch_spec),
+            out_specs=out_specs, check_vma=False,
+        )
+
+    def jitted(mapped_step):
+        return jax.jit(
+            mapped_step,
+            donate_argnums=(0,) if o.donate else (),
+            compiler_options=o.copts,
+        )
+
+    # The replicated-without-EF step has structure-independent specs;
+    # the sharded path AND the quantized-with-error-feedback replicated
+    # path carry dim-0-sharded flat buffers (opt-state buckets / EF
+    # residuals) whose specs depend on the state's structure.
+    if o.sharded or (
+        o.quantized and o.error_feedback and o.distribute_optimizer
+    ):
+        return _program_per_structure(mapped, jitted, axis)
+    step_mapped = mapped(P())
+    step_jitted = jitted(step_mapped)
+    program = _Program(
+        step_jitted, lambda state: step_mapped, lambda state: step_jitted
+    )
+    if not o.overlapped_exchange or m.is_multi_process:
+        return program
+    # The step returns its state replicated over the mesh. A state that
+    # arrives otherwise (``init_state`` leaves it on the default device)
+    # makes the second call build a second program for the new input
+    # shardings (ROADMAP D1b), and the overlapped exchange's programs
+    # are the larger ones to build and to load: such a state is placed
+    # before it is dispatched, and ``step.lower`` lowers for that place.
+    on_mesh = NamedSharding(m, P())
+
+    def placed(state: TrainState, put):
+        at = getattr(state.step, "sharding", None)
+        if at is not None and at.is_equivalent_to(on_mesh, 0):
+            return state
+        return jax.tree.map(lambda x: put(x, on_mesh), state)
+
+    def abstract(x, sharding):
+        return jax.ShapeDtypeStruct(
+            np.shape(x), jnp.result_type(x), sharding=sharding
+        )
+
+    return dataclasses.replace(
+        program,
+        dispatch=lambda state, batch: step_jitted(
+            placed(state, jax.device_put), batch
+        ),
+        as_dispatched=lambda state: placed(state, abstract),
+    )
+
+
+def _program_per_structure(mapped, jitted, axis) -> _Program:
+    """Structure-dependent path: the opt-state specs depend on the
+    state's structure (which flat buckets the params pack into), so
+    the shard_map is built lazily on first call and cached per state
+    treedef. The specs shard every FlatBuckets buffer (ZeRO-1 bucket
+    or EF residual) dim-0 over the world axis — the global view of the
+    state is the full padded buffer, each device holds its 1/N slice,
+    and donation of the TrainState works exactly as in the plain path."""
+    cache = {}
+
+    def mapped_for(state: TrainState):
+        return mapped(TrainState(
+            P(),
+            sharded_state_specs(state.opt_state, axis=axis),
+            P(),
+            P(),
+            P(),  # guard scalars (empty subtree when unguarded)
+        ))
+
+    def jitted_for(state: TrainState):
+        key = jax.tree.structure(state)
+        fn = cache.get(key)
+        if fn is None:
+            fn = cache[key] = jitted(mapped_for(state))
+        return fn
+
+    return _Program(
+        lambda state, batch: jitted_for(state)(state, batch),
+        mapped_for, jitted_for,
+    )
+
+
+def _static_surfaces(program: _Program, o) -> dict:
+    """``lint`` / ``memplan`` / ``trace`` / ``certify`` / ``lower`` of the
+    as-built step, by the names they take on it: each traces or lowers
+    the exact program and executes nothing, so all are safe on live
+    (donatable) state. ``jaxpr=`` reuses a caller-held trace, so sweep
+    callers trace once per variant and share it between them."""
+    world = int(np.prod([o.mesh.shape[a] for a in o.world_axes]))
+    donate_argnums = (0,) if o.donate else ()
+    # One description of the build; ``memplan`` and ``certify`` each cut
+    # their ``meta`` from it, every key and value as it was.
+    built = {
+        "sharded": o.sharded,
+        "accum_steps": o.accum_steps,
+        "overlap": bool(o.overlap),
+        "quant": (
+            getattr(getattr(o.compression, "spec", None), "name", "")
+            if o.quantized else ""
+        ),
+        "remat": str(o.remat or ""),
+        "compute_dtype": o.compute_dtype,
+        "act_quant": o.act_quant,
+    }
+
+    def seeded(state):
+        if o.guard is not None and state.guard is None:
+            # These surfaces trace the step directly, before the guard
+            # wrapper's first-call seeding has run — give the trace the
+            # same seeded structure the wrapper would.
             from ..guard import fresh_state as _guard_fresh
 
             state = TrainState(
@@ -832,403 +1002,281 @@ def make_train_step(
             )
         return state
 
-    def _lint_findings(state, batch, mapped_for, jaxpr=None,
-                       memory_cfg=None):
-        """Trace the exact mapped program and run the static passes —
-        compute-free, so safe to run on live (donatable) state.
-        ``jaxpr`` reuses a caller-held trace (the harness's per-variant
-        cache); ``memory_cfg`` overrides the env-derived memory gate."""
+    def lint(state, batch, jaxpr=None, memory=None):
+        """The static passes over the exact mapped program; ``memory``
+        overrides the env-derived memory gate."""
         from .. import analysis as _analysis
 
-        state = _seeded_for_trace(state)
-        world = int(np.prod([m.shape[a] for a in world_axes]))
-        allow_lp = (
-            compression is not Compression.none
-            or gather_compression is not Compression.none
-        )
-        wire_dtype = getattr(compression, "wire_dtype", None)
-        if memory_cfg is None:
+        state = seeded(state)
+        if memory is None:
             # The memory pass always runs with step.lint: oom-risk gates
             # only when a budget is declared (HVDTPU_HBM_BUDGET_GB), and
             # donation-missed-reuse is structural (a properly-donating
             # step has no candidates).
-            memory_cfg = _analysis.MemoryLintConfig(
+            memory = _analysis.MemoryLintConfig(
                 budget_bytes=_env.hbm_budget_bytes()
             )
         return _analysis.lint_traced(
-            mapped_for(state),
+            program.mapped_for(state),
             (state, batch),
-            donate_argnums=(0,) if donate else (),
-            declared_axes=set(m.axis_names),
+            donate_argnums=donate_argnums,
+            declared_axes=set(o.mesh.axis_names),
             params=state.params,
-            sharded=sharded,
-            threshold_bytes=threshold_bytes,
+            sharded=o.sharded,
+            threshold_bytes=o.threshold_bytes,
             world=world,
-            allow_low_precision_collectives=allow_lp,
-            allowlist=tuple(lint_allow),
-            jaxpr=jaxpr,
-            quant=compression if quantized else None,
-            compute_dtype=compute_dtype,
-            act_quant=act_quant,
-            wire_dtype=wire_dtype,
-            gather_wire_dtype=getattr(
-                gather_compression, "wire_dtype", None
+            allow_low_precision_collectives=(
+                o.compression is not Compression.none
+                or o.gather_compression is not Compression.none
             ),
-            memory=memory_cfg,
+            allowlist=tuple(o.lint_allow),
+            jaxpr=jaxpr,
+            quant=o.compression if o.quantized else None,
+            compute_dtype=o.compute_dtype,
+            act_quant=o.act_quant,
+            wire_dtype=getattr(o.compression, "wire_dtype", None),
+            gather_wire_dtype=getattr(
+                o.gather_compression, "wire_dtype", None
+            ),
+            memory=memory,
         )
 
-    def _memplan(state, batch, mapped_for, jaxpr=None):
+    def memplan(state, batch, jaxpr=None):
         """Static per-device HBM plan of the exact as-built step (see
         :mod:`horovod_tpu.analysis.memory`) — the number every ROADMAP
         memory bet is priced against. Publishes ``memplan.peak_bytes``
         when the metrics plane is on."""
         from .. import analysis as _analysis
 
-        state = _seeded_for_trace(state)
-        world = int(np.prod([m.shape[a] for a in world_axes]))
+        state = seeded(state)
         plan = _analysis.plan_traced(
-            mapped_for(state),
+            program.mapped_for(state),
             (state, batch),
-            donate_argnums=(0,) if donate else (),
+            donate_argnums=donate_argnums,
             world=world,
             jaxpr=jaxpr,
-            meta={
-                "sharded": sharded,
-                "accum_steps": accum_steps,
-                "overlap": bool(overlap),
-                "quant": (
-                    getattr(getattr(compression, "spec", None), "name", "")
-                    if quantized
-                    else ""
-                ),
-                "remat": str(remat or ""),
-                "compute_dtype": compute_dtype,
-                "act_quant": act_quant,
-                "donate": donate,
-            },
+            meta={**built, "donate": o.donate},
         )
         _analysis.publish_peak_bytes(plan)
         return plan
 
-    def _certify(state, batch, mapped_for, jaxpr=None):
+    def trace(state, batch):
+        state = seeded(state)
+        return jax.make_jaxpr(program.mapped_for(state))(state, batch)
+
+    def certify(state, batch, jaxpr=None):
         """Fingerprint the exact as-built program (see
         :mod:`horovod_tpu.analysis.certify`): the collective schedule of
         the traced jaxpr plus the predicted wire layout, hashed into a
-        cross-rank-comparable ``ScheduleCert``. ``jaxpr=`` shares a
-        caller-held trace like lint/memplan."""
+        cross-rank-comparable ``ScheduleCert``."""
         from .. import analysis as _analysis
         from ..ops.fusion import bucket_byte_layout, quantized_bucket_layout
 
-        state = _seeded_for_trace(state)
+        state = seeded(state)
         if jaxpr is None:
-            jaxpr = jax.make_jaxpr(mapped_for(state))(state, batch)
-        world = int(np.prod([m.shape[a] for a in world_axes]))
-        if quantized:
+            jaxpr = trace(state, batch)
+        if o.quantized:
             wire = [
                 dict(b)
                 for b in quantized_bucket_layout(
-                    state.params, threshold_bytes,
-                    world=world, compression=compression,
+                    state.params, o.threshold_bytes,
+                    world=world, compression=o.compression,
                 )
             ]
         else:
             wire = [
                 [d, int(n)]
-                for d, n in bucket_byte_layout(state.params, reduction_limit)
+                for d, n in bucket_byte_layout(
+                    state.params, o.reduction_limit
+                )
             ]
         return _analysis.schedule_cert(
             jaxpr,
             world=world,
             wire=wire,
             meta={
-                "sharded": sharded,
-                "overlap": bool(overlap),
-                "accum_steps": accum_steps,
-                "quant": (
-                    getattr(getattr(compression, "spec", None), "name", "")
-                    if quantized
-                    else ""
-                ),
-                "compute_dtype": compute_dtype,
-                "act_quant": act_quant,
-                "remat": str(remat or ""),
+                k: built[k]
+                for k in ("sharded", "overlap", "accum_steps", "quant",
+                          "compute_dtype", "act_quant", "remat")
             },
         )
 
-    def _finish(step_fn, mapped_for, jitted_for, as_dispatched=lambda s: s):
-        # Always wrapped: the wrapper itself checks enablement per call,
-        # so obs.enable()/disable() after the step is built take effect.
-        from ..obs import trace as _trace
+    def lower(state, batch):
+        """The jax.stages.Lowered of the exact jitted program this step
+        dispatches (same donation, same compiler options):
+        ``.compile().as_text()`` is its HLO, ``.memory_analysis()`` its
+        device memory. Arrays or ShapeDtypeStructs; nothing executes."""
+        state = program.as_dispatched(seeded(state))
+        return program.jitted_for(state).lower(state, batch)
 
-        # Innermost: JAX's own dispatch of the jitted function, as a span
-        # and (on by default, one perf_counter pair) a histogram; what
-        # ``hvd.step.dispatch`` takes beyond it is this module's wrappers.
-        def jit_call(state, batch):
-            with _trace.span("hvd.step.jit", "train"):
-                t0 = time.perf_counter()
-                out = step_fn(state, batch)
-                _obs.always().histogram("step.jit_dispatch_ms").observe(
-                    (time.perf_counter() - t0) * 1e3
-                )
-            return out
-
-        fn = jit_call
-        if lint_mode:
-            from ..analysis import LintError
-            from ..analysis import errors as _lint_errors
-
-            linted = False
-
-            def checked(state, batch):
-                # First call lints BEFORE dispatch: tracing is pure, so
-                # ERROR findings abort with the state buffers untouched
-                # (donation has not run yet). The latch is only set
-                # after a lint that did NOT raise — a retried call after
-                # LintError (or a transient tracing failure) must lint
-                # again, not dispatch the broken program unlinted.
-                nonlocal linted
-                if not linted:
-                    with _trace.span("hvd.step.lint", "train"):
-                        findings = _lint_findings(state, batch, mapped_for)
-                    errs = _lint_errors(findings)
-                    if lint_mode == "raise" and errs:
-                        raise LintError(errs)
-                    linted = True
-                    for f in findings:
-                        warnings.warn(f"hvdtpu lint: {f}", stacklevel=2)
-                return jit_call(state, batch)
-
-            fn = checked
-
-        def _preflight(state, batch, tag="", mode=None, jaxpr=None):
-            """Cross-rank cert gate: publish this build's fingerprint to
-            the elastic KV and verify all ranks match BEFORE the first
-            dispatch (a mismatched world hangs at its first divergent
-            collective with no diagnostics otherwise). No-op — beyond
-            the env read — outside an elastic world."""
-            if mode is None:
-                mode = _env.cert_mode()
-            if not mode:
-                return None
-            from ..elastic.worker import cert_channel
-
-            channel = cert_channel()
-            if channel is None:
-                return None
-            cert = _certify(state, batch, mapped_for, jaxpr=jaxpr)
-            return channel.preflight(cert, tag=tag, mode=mode)
-
-        cert_latch = {"done": False}
-        inner = fn
-
-        def preflighted(state, batch):
-            # Same first-call latch discipline as the lint hook: the
-            # latch is only set after a preflight that did NOT raise, so
-            # a retried call after CertMismatchError re-verifies instead
-            # of dispatching the divergent program. The autotune retrace
-            # path flips the latch itself and preflights under a trial
-            # tag (tune.AutotunedStep) to avoid racing the pre-rebuild
-            # KV entry.
-            if not cert_latch["done"]:
-                with _trace.span("hvd.step.preflight", "train"):
-                    _preflight(state, batch)
-                cert_latch["done"] = True
-            return inner(state, batch)
-
-        fn = preflighted
-        guard_runtime = None
-        if guard_cfg is not None:
-            # Host-side guard runtime OUTSIDE the lint hook (lint must
-            # trace the program, not the escalation/audit wrapper) and
-            # INSIDE the metrics bracket, so instrumented timings see
-            # the guarded step end to end.
-            from ..guard import GuardRuntime
-
-            guard_runtime = GuardRuntime(guard_cfg, sharded=sharded)
-            fn = guard_runtime.wrap(fn)
-        stream_publisher = None
-        stream_every = (
-            _env.publish_every() if publish is None else max(0, int(publish))
-        )
-        if stream_every > 0:
-            # Weight-stream publisher OUTSIDE the guard wrapper (it reads
-            # the audit verdict, it must not be audited) and inside the
-            # metrics bracket. The cadence check runs on a host-side step
-            # counter anchored once, so off-cadence steps pay no device
-            # sync; the authoritative version stamp is the real committed
-            # step, read only on cadence hits.
-            from ..stream import WeightPublisher
-
-            stream_publisher = WeightPublisher(
-                publish_every=stream_every,
-                guard_runtime=guard_runtime,
-                threshold_bytes=threshold_bytes,
-            )
-            stream_inner = fn
-            stream_clock = {"base": None, "n": 0}
-
-            def streamed(state, batch):
-                out = stream_inner(state, batch)
-                new_state = out[0]
-                if stream_clock["base"] is None:
-                    # One host sync, first step only: anchor the cadence
-                    # clock to the real (possibly resumed-from-ckpt) step.
-                    stream_clock["base"] = int(new_state.step) - 1
-                stream_clock["n"] += 1
-                hint = stream_clock["base"] + stream_clock["n"]
-                if hint % stream_every == 0:
-                    # The device sync is already being paid on cadence
-                    # hits — use it to catch an elastic restore / guard
-                    # walk-back that moved state.step since the anchor,
-                    # and re-anchor so the host clock tracks the real
-                    # committed step again (a silently desynced hint
-                    # would stop ever hitting the true cadence).
-                    real_step = int(new_state.step)
-                    if real_step != hint:
-                        stream_clock["base"] = real_step - stream_clock["n"]
-                    # Off-cadence real steps fall through to the flush
-                    # path inside maybe_publish: nothing is captured,
-                    # but pendings keep draining.
-                    stream_publisher.maybe_publish(
-                        new_state.params, real_step
-                    )
-                elif stream_publisher._pending:
-                    # Something is queued behind the guard gate or a KV
-                    # outage: retry the flush each step until it drains.
-                    stream_publisher.flush()
-                return out
-
-            fn = streamed
-        wrapped = _instrument_step(
-            fn, tokens_per_step, flops_per_step,
-            overlap=bool(overlap), accum_steps=accum_steps,
-            quantized=quantized and error_feedback,
-            fp8=compute_dtype == "fp8",
-        )
-        # On-demand lint of the as-built step (CLI/harness entry point),
-        # plus the mapped (pre-jit) program for custom static analysis
-        # (horovod_tpu.analysis.trace_collectives and the parity checks).
-        # ``jaxpr=`` lets sweep callers trace once per variant and share
-        # the trace between lint and memplan.
-        wrapped.lint = lambda state, batch, jaxpr=None, memory=None: (
-            _lint_findings(
-                state, batch, mapped_for, jaxpr=jaxpr, memory_cfg=memory
-            )
-        )
-        wrapped.memplan = lambda state, batch, jaxpr=None: _memplan(
-            state, batch, mapped_for, jaxpr=jaxpr
-        )
-        wrapped.trace = lambda state, batch: jax.make_jaxpr(
-            mapped_for(_seeded_for_trace(state))
-        )(_seeded_for_trace(state), batch)
-        wrapped.certify = lambda state, batch, jaxpr=None: _certify(
-            state, batch, mapped_for, jaxpr=jaxpr
-        )
-        # The jax.stages.Lowered of the exact jitted program this step
-        # dispatches (same donation, same compiler options):
-        # ``.compile().as_text()`` is its HLO, ``.memory_analysis()`` its
-        # device memory. Arrays or ShapeDtypeStructs; nothing executes.
-        def _lower(state, batch):
-            state = as_dispatched(_seeded_for_trace(state))
-            return jitted_for(state).lower(state, batch)
-
-        wrapped.lower = _lower
-        wrapped.preflight = _preflight
-        wrapped._cert_latch = cert_latch
-        wrapped._mapped_for = mapped_for
-        wrapped.guard_config = guard_cfg
-        wrapped.guard_runtime = guard_runtime
-        wrapped.stream_publisher = stream_publisher
-        return wrapped, opt
-
-    # The replicated-without-EF step has structure-independent specs;
-    # the sharded path AND the quantized-with-error-feedback replicated
-    # path carry dim-0-sharded flat buffers (opt-state buckets / EF
-    # residuals) whose specs depend on the state's structure.
-    needs_state_specs = sharded or (
-        quantized and error_feedback and distribute_optimizer
+    return dict(
+        lint=lint, memplan=memplan, trace=trace, certify=certify, lower=lower
     )
-    if not needs_state_specs:
-        out_specs = (P(), P(), P()) if has_aux else (P(), P())
-        mapped = jax.shard_map(
-            hvd_train_step, mesh=m, in_specs=(P(), bspec),
-            out_specs=out_specs, check_vma=False,
-        )
-        jitted = jax.jit(
-            mapped,
-            donate_argnums=(0,) if donate else (),
-            compiler_options=copts,
-        )
-        if not overlapped_exchange or m.is_multi_process:
-            return _finish(jitted, lambda state: mapped, lambda state: jitted)
-        # The step returns its state replicated over the mesh. A state that
-        # arrives otherwise (``init_state`` leaves it on the default device)
-        # makes the second call build a second program for the new input
-        # shardings (ROADMAP D1b), and the overlapped exchange's programs
-        # are the larger ones to build and to load: such a state is placed
-        # before it is dispatched, and ``step.lower`` lowers for that place.
-        on_mesh = NamedSharding(m, P())
 
-        def placed(state: TrainState, put):
-            at = getattr(state.step, "sharding", None)
-            if at is not None and at.is_equivalent_to(on_mesh, 0):
-                return state
-            return jax.tree.map(lambda x: put(x, on_mesh), state)
 
-        def abstract(x, sharding):
-            return jax.ShapeDtypeStruct(
-                np.shape(x), jnp.result_type(x), sharding=sharding
+def _streamed(inner: Callable, publisher, every: int) -> Callable:
+    """``inner`` followed by the weight stream's cadence check. It runs
+    on a host-side step counter anchored once, so off-cadence steps pay
+    no device sync; the authoritative version stamp is the real
+    committed step, read only on cadence hits."""
+    clock = {"base": None, "n": 0}
+
+    def streamed(state, batch):
+        out = inner(state, batch)
+        new_state = out[0]
+        if clock["base"] is None:
+            # One host sync, first step only: anchor the cadence
+            # clock to the real (possibly resumed-from-ckpt) step.
+            clock["base"] = int(new_state.step) - 1
+        clock["n"] += 1
+        hint = clock["base"] + clock["n"]
+        if hint % every == 0:
+            # The device sync is already being paid on cadence
+            # hits — use it to catch an elastic restore / guard
+            # walk-back that moved state.step since the anchor,
+            # and re-anchor so the host clock tracks the real
+            # committed step again (a silently desynced hint
+            # would stop ever hitting the true cadence).
+            real_step = int(new_state.step)
+            if real_step != hint:
+                clock["base"] = real_step - clock["n"]
+            # Off-cadence real steps fall through to the flush
+            # path inside maybe_publish: nothing is captured,
+            # but pendings keep draining.
+            publisher.maybe_publish(new_state.params, real_step)
+        elif publisher._pending:
+            # Something is queued behind the guard gate or a KV
+            # outage: retry the flush each step until it drains.
+            publisher.flush()
+        return out
+
+    return streamed
+
+
+def _wrap(program: _Program, surfaces: dict, o) -> Callable:
+    """The host planes around the program, innermost first: jit span,
+    lint, preflight, guard, weight stream, metrics. The order is a
+    correctness condition, not a list to append to: lint traces the
+    program and not the guard's retry loop; the guard runtime sits inside
+    the metrics bracket, so instrumented timings see the guarded step end
+    to end; the publisher reads the audit verdict and must not be
+    audited. Then the step's attributes."""
+    from ..obs import trace as _trace
+
+    # Innermost: JAX's own dispatch of the jitted function, as a span
+    # and (on by default, one perf_counter pair) a histogram; what
+    # ``hvd.step.dispatch`` takes beyond it is this module's wrappers.
+    def jit_call(state, batch):
+        with _trace.span("hvd.step.jit", "train"):
+            t0 = time.perf_counter()
+            out = program.dispatch(state, batch)
+            _obs.always().histogram("step.jit_dispatch_ms").observe(
+                (time.perf_counter() - t0) * 1e3
             )
+        return out
 
-        return _finish(
-            lambda state, batch: jitted(placed(state, jax.device_put), batch),
-            lambda state: mapped,
-            lambda state: jitted,
-            lambda state: placed(state, abstract),
+    fn = jit_call
+    if o.lint:
+        from ..analysis import LintError
+        from ..analysis import errors as _lint_errors
+
+        linted = False
+
+        def checked(state, batch):
+            # First call lints BEFORE dispatch: tracing is pure, so
+            # ERROR findings abort with the state buffers untouched
+            # (donation has not run yet). The latch is only set
+            # after a lint that did NOT raise — a retried call after
+            # LintError (or a transient tracing failure) must lint
+            # again, not dispatch the broken program unlinted.
+            nonlocal linted
+            if not linted:
+                with _trace.span("hvd.step.lint", "train"):
+                    findings = surfaces["lint"](state, batch)
+                errs = _lint_errors(findings)
+                if o.lint == "raise" and errs:
+                    raise LintError(errs)
+                linted = True
+                for f in findings:
+                    warnings.warn(f"hvdtpu lint: {f}", stacklevel=2)
+            return jit_call(state, batch)
+
+        fn = checked
+
+    def preflight(state, batch, tag="", mode=None, jaxpr=None):
+        """Cross-rank cert gate: publish this build's fingerprint to
+        the elastic KV and verify all ranks match BEFORE the first
+        dispatch (a mismatched world hangs at its first divergent
+        collective with no diagnostics otherwise). No-op — beyond
+        the env read — outside an elastic world."""
+        if mode is None:
+            mode = _env.cert_mode()
+        if not mode:
+            return None
+        from ..elastic.worker import cert_channel
+
+        channel = cert_channel()
+        if channel is None:
+            return None
+        cert = surfaces["certify"](state, batch, jaxpr=jaxpr)
+        return channel.preflight(cert, tag=tag, mode=mode)
+
+    cert_latch = {"done": False}
+    inner = fn
+
+    def preflighted(state, batch):
+        # Same first-call latch discipline as the lint hook: the
+        # latch is only set after a preflight that did NOT raise, so
+        # a retried call after CertMismatchError re-verifies instead
+        # of dispatching the divergent program. The autotune retrace
+        # path flips the latch itself and preflights under a trial
+        # tag (tune.AutotunedStep) to avoid racing the pre-rebuild
+        # KV entry.
+        if not cert_latch["done"]:
+            with _trace.span("hvd.step.preflight", "train"):
+                preflight(state, batch)
+            cert_latch["done"] = True
+        return inner(state, batch)
+
+    fn = preflighted
+    guard_runtime = None
+    if o.guard is not None:
+        from ..guard import GuardRuntime
+
+        guard_runtime = GuardRuntime(o.guard, sharded=o.sharded)
+        fn = guard_runtime.wrap(fn)
+    stream_publisher = None
+    if o.publish > 0:
+        from ..stream import WeightPublisher
+
+        stream_publisher = WeightPublisher(
+            publish_every=o.publish,
+            guard_runtime=guard_runtime,
+            threshold_bytes=o.threshold_bytes,
         )
-
-    # Structure-dependent path: the opt-state specs depend on the
-    # state's structure (which flat buckets the params pack into), so
-    # the shard_map is built lazily on first call and cached per state
-    # treedef. The specs shard every FlatBuckets buffer (ZeRO-1 bucket
-    # or EF residual) dim-0 over the world axis — the global view of the
-    # state is the full padded buffer, each device holds its 1/N slice,
-    # and donation of the TrainState works exactly as in the plain path.
-    cache = {}
-
-    def _sharded_mapped(state: TrainState):
-        sspec = TrainState(
-            P(),
-            sharded_state_specs(state.opt_state, axis=axis),
-            P(),
-            P(),
-            P(),  # guard scalars (empty subtree when unguarded)
-        )
-        out_specs = (sspec, P(), P()) if has_aux else (sspec, P())
-        return jax.shard_map(
-            hvd_train_step,
-            mesh=m,
-            in_specs=(sspec, bspec),
-            out_specs=out_specs,
-            check_vma=False,
-        )
-
-    def _sharded_jitted(state: TrainState):
-        key = jax.tree.structure(state)
-        fn = cache.get(key)
-        if fn is None:
-            fn = jax.jit(
-                _sharded_mapped(state),
-                donate_argnums=(0,) if donate else (),
-                compiler_options=copts,
-            )
-            cache[key] = fn
-        return fn
-
-    def step_fn(state: TrainState, batch):
-        return _sharded_jitted(state)(state, batch)
-
-    return _finish(step_fn, _sharded_mapped, _sharded_jitted)
+        fn = _streamed(fn, stream_publisher, o.publish)
+    # Always wrapped: the wrapper itself checks enablement per call,
+    # so obs.enable()/disable() after the step is built take effect.
+    wrapped = _instrument_step(
+        fn, o.tokens_per_step, o.flops_per_step,
+        overlap=bool(o.overlap), accum_steps=o.accum_steps,
+        quantized=o.quantized and o.error_feedback,
+        fp8=o.compute_dtype == "fp8",
+    )
+    # On-demand lint of the as-built step (CLI/harness entry point) and
+    # its fellows, plus the mapped (pre-jit) program for custom static
+    # analysis (horovod_tpu.analysis.trace_collectives and the parity
+    # checks).
+    vars(wrapped).update(
+        surfaces,
+        preflight=preflight,
+        _cert_latch=cert_latch,
+        _mapped_for=program.mapped_for,
+        guard_config=o.guard,
+        guard_runtime=guard_runtime,
+        stream_publisher=stream_publisher,
+    )
+    return wrapped
 
 
 def init_state(params, wrapped_optimizer, extra=None, guard=None) -> TrainState:

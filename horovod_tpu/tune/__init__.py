@@ -208,7 +208,7 @@ def attach_train_autotuner(build: Callable[[], tuple],
 
             warnings.warn(
                 f"autotune requested but the search space is empty "
-                f"({e}); building the step untuned", stacklevel=3,
+                f"({e}); building the step untuned", stacklevel=4,
             )
             return None
         search = AutotuneSearch(

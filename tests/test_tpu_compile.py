@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import tools.step_hash as step_hash
 from horovod_tpu.ops import pallas_kernels as pk
 
 
@@ -91,13 +92,7 @@ def _described_family_step(topo, case):
         ).compile().as_text()
     finally:
         hvd.shutdown()
-    # without its metadata and the tables of call sites the metadata indexes
-    bare = re.sub(r",? ?metadata=\{[^{}]*\}", "", hlo)
-    bare = re.sub(
-        r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
-        r"(?:\d+ .*\n)*", "\n", bare,
-    )
-    return bare, hlo
+    return step_hash.bare_hlo(hlo), hlo
 
 
 @pytest.mark.parametrize(
@@ -126,6 +121,36 @@ def test_parts_change_the_compiled_step_in_its_metadata_only(
     assert scoped.count("tpu_custom_call") == kernels
     part = re.compile(r'op_name="[^"]*/(?:%s)/' % "|".join(model_parts.PARTS))
     assert part.search(scoped_full) and not part.search(bare_full)
+
+
+def test_step_hash_repeats_and_is_blind_to_metadata_only(
+    v5e_topology, capsys
+):
+    """``tools/step_hash.py`` on a cell at its files' ``tiny`` sizes, for
+    the described v5e: twice it prints the same line; with no part opened
+    in the model, the same program (``stablehlo``, ``hlo_bare``, the
+    count of kernels) under other names (``hlo``, ``op_names``). The MLM
+    cell: GPT-2's 32 tiny positions are fewer than Mosaic takes a causal
+    kernel for."""
+    import json
+
+    import model_parts
+
+    argv = ["--described", "--tiny", "--workload", "bert-base.mlm-b32-s512"]
+
+    def line():
+        assert step_hash.main(argv) == 0
+        return json.loads(capsys.readouterr().out)
+
+    first, again = line(), line()
+    with model_parts.parts_disabled():
+        unnamed = line()
+    assert first == again
+    assert first["custom_calls"] == 6
+    same = ("stablehlo", "hlo_bare", "custom_calls")
+    assert [first[k] for k in same] == [unnamed[k] for k in same]
+    assert first["op_names"] != unnamed["op_names"]
+    assert first["hlo"] != unnamed["hlo"]
 
 
 @pytest.mark.parametrize(

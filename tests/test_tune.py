@@ -833,6 +833,34 @@ class TestTrainStepWrapper:
         state = dp.init_state(params, opt)
         state, loss = step(state, batch)  # plain step still trains
 
+    def test_publish_rides_the_tuned_build_and_its_rebuilds(self):
+        """``publish=N`` beside ``autotune=``: the tuner builds, and after
+        a retrace switch rebuilds, from the call's own arguments, so the
+        inner step streams at the N the caller passed and not at
+        ``HVDTPU_PUBLISH_EVERY`` (the rebuild once went through a list of
+        its own that had lost the argument)."""
+        import optax
+
+        import horovod_tpu as hvd
+        from horovod_tpu.parallel import dp
+
+        hvd.init()
+        _, _, loss_fn = self._mlp()
+        os.environ.pop("HVDTPU_PUBLISH_EVERY", None)
+        cfg = tune.AutotuneConfig(window_steps=1, warmup_steps=0,
+                                  max_trials=2, patience=2, seed=3)
+        step, _ = dp.make_train_step(
+            loss_fn, optax.adamw(1e-3), lint=False, autotune=cfg, publish=3
+        )
+        assert step.registry.names  # a space that is not empty
+        assert step.stream_publisher is not None
+        assert step.stream_publisher.publish_every == 3
+        first = step._inner
+        step._inner, step.opt = step._build()  # what a retrace switch does
+        assert step._inner is not first
+        assert step.stream_publisher is not None
+        assert step.stream_publisher.publish_every == 3
+
     def test_structure_locked_pins_threshold(self):
         import optax
 
