@@ -31,7 +31,30 @@ Equations (no bias anywhere, ``eps`` 1e-6). Layer ``l`` with input ``h``
 The router reads the ATTENTION's input, so the experts of a layer are
 chosen before its attention runs; the experts themselves read
 ``RMSNorm2(h')``. Every layer is an expert layer: no shared expert, no
-dense layer. Output: final RMSNorm, an untied head over the vocabulary
+dense layer.
+
+Static settings of the same block, each at the SmallThinker layer's value
+by default (a default configuration traces what it traced):
+``router_input`` (``"ffn_norm"``: the router reads ``m``, what the experts
+read), ``expert_activation`` (``"silu"``), ``qk_norm`` (q and k are
+RMS-normalised over each head's ``head_dim`` columns, one learned scale a
+projection, before the rotary), and ``index_top_k`` > 0: learned sparse
+attention (``ops/dsa_kernels.py`` has the equations). A lightning indexer
+(``index_heads`` query heads of ``index_head_dim`` on ONE key head, reading
+``stop_gradient(u)``)::
+
+    qI = x W_qI;  kI = LayerNorm(x W_kI);  w = (x W_wI) H_I^-1/2 d_I^-1/2
+    qI, kI <- rotary(theta, halves) over their d_I columns
+    I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])   fp32, s <= t
+    sel(t)  = the index_top_k largest of I[t, 0..t], ties all kept
+
+scores every earlier position, attention runs over ``sel(t)`` alone (a
+constant of the step: the flash kernels under ``keep=``), and the layer
+adds ``L_I = mean_t KL(stop_gradient(mean_n p_n[t]) || softmax_sel(I[t]))``
+to an index loss that ``apply`` then returns beside the logits, ``(logits,
+index_loss)``: the training loss is ``lm_loss(logits, ...) + index_loss``.
+The indexer's leaves learn from ``L_I`` alone and every other leaf from the
+cross entropy alone. Output: final RMSNorm, an untied head over the vocabulary
 slice, logits in fp32, next-token cross entropy (``transformer.lm_loss``).
 
 The two layouts are tuples a layer indexes modulo their length, so a
@@ -48,7 +71,10 @@ the explicit band mask over K/V repeated to ``H`` heads.
 Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
 operation of ``apply`` lies under exactly one of: ``embed``, ``norm`` (the
 RMSNorms, the residual sums and the token-major views), ``attn_proj`` (the
-four projections and k's rotary; off the flash path q's too), ``attn_layout`` (the reshapes between the
+four projections, the head-wise norms and k's rotary; off the flash path and
+for the index loss q's too), ``index_proj`` (the indexer's three
+projections, its LayerNorm and rotary, and the select kernels' own glue),
+``attn_layout`` (the reshapes between the
 projections and the kernels, and the kernels' entry's own glue),
 ``attn_xla`` (attention where flash is bypassed), ``moe_route``,
 ``moe_experts`` (``parallel/ep.py``), ``head``. There is no ``mlp`` part:
@@ -95,6 +121,17 @@ class WindowMoEConfig:
     dtype: Any = jnp.bfloat16
     # None: the flash kernels where the world's devices are TPUs
     use_flash: Optional[bool] = None
+    # "attn_norm": the router reads RMSNorm1(h), ahead of the attention;
+    # "ffn_norm": RMSNorm2(h'), what the experts read
+    router_input: str = "attn_norm"
+    expert_activation: str = "relu"  # or "silu"
+    qk_norm: bool = False  # RMSNorm over each head of q and k
+    # > 0: a lightning indexer keeps each query's index_top_k best keys
+    index_top_k: int = 0
+    index_heads: int = 16
+    index_head_dim: int = 64
+    # (queries a select program takes, the key tile it scores them against)
+    index_blocks: Tuple[int, int] = (128, 512)
 
     def windowed(self, layer: int) -> bool:
         return bool(self.window_layout[layer % len(self.window_layout)])
@@ -127,7 +164,8 @@ def _init(cfg: WindowMoEConfig):
 class GroupedAttention(nn.Module):
     """Causal attention with ``n_heads`` query heads over ``n_kv_heads``
     K/V heads, under a ``window`` (None: every earlier position) and with
-    or without rotary."""
+    or without rotary. With ``cfg.index_top_k`` each query attends to the
+    keys its indexer keeps, and the call returns ``(out, index_loss)``."""
 
     cfg: WindowMoEConfig
     window: Optional[int] = None
@@ -145,31 +183,52 @@ class GroupedAttention(nn.Module):
         use_flash = cfg.use_flash
         if use_flash is None:
             use_flash = device_platform() == "tpu"
+        turn = lambda t, heads: rotary(  # noqa: E731
+            t.reshape(b, s, heads, -1), theta=cfg.rope_theta, halves=True,
+        ).reshape(b, s, -1)
         with jax.named_scope("attn_proj"):
             q = dense(h * d, "q")(x)
             k = dense(h_kv * d, "k")(x)
             v = dense(h_kv * d, "v")(x)
+            if cfg.qk_norm:
+                by_head = lambda t, heads, name: RMSNorm(  # noqa: E731
+                    cfg.eps, cfg.dtype, name=name
+                )(t.reshape(b, s, heads, d)).reshape(b, s, heads * d)
+                q, k = by_head(q, h, "q_norm"), by_head(k, h_kv, "k_norm")
+            q_turned = q
             if self.rotate:
-                turn = lambda t, heads: rotary(  # noqa: E731
-                    t.reshape(b, s, heads, d), theta=cfg.rope_theta,
-                    halves=True,
-                ).reshape(b, s, heads * d)
                 k = turn(k, h_kv)
                 if not use_flash:
-                    q = turn(q, h)
+                    q = q_turned = turn(q, h)
+                elif cfg.index_top_k:  # the index loss's target reads it
+                    q_turned = turn(jax.lax.stop_gradient(q), h)
+        keep = None
+        if cfg.index_top_k:
+            from ..ops.dsa_kernels import dsa_index_loss, dsa_select
+
+            q_idx, k_idx, w = self.index(x, turn)
+            keep, _, lse_idx = dsa_select(
+                q_idx, k_idx, w, top_k=cfg.index_top_k,
+                use_kernel=use_flash, block_q=cfg.index_blocks[0],
+                block_k=cfg.index_blocks[1],
+            )
+        lse = None
         if use_flash:
-            from ..ops.pallas_kernels import QRotary, flash_attention
+            from ..ops.pallas_kernels import (
+                QRotary, flash_attention_with_lse,
+            )
 
             # q, k and v as the projections leave them: no relayout, and
             # no K or V of ``h`` heads; the kernels rotate q (28 heads) and
             # hand back the gradient of the projection's output, k (4) is
             # rotated above
-            out = flash_attention(
+            out, lse = flash_attention_with_lse(
                 q, k, v, causal=True, window=self.window, layout="bsm",
                 n_heads=h, n_kv_heads=h_kv,
                 q_rotary=QRotary(
                     *rotary_tables(s, d, theta=cfg.rope_theta), halves=True
                 ) if self.rotate else None,
+                keep=keep,
             )
         else:
             with jax.named_scope("attn_xla"):
@@ -177,19 +236,56 @@ class GroupedAttention(nn.Module):
                 shared = lambda t: jnp.repeat(  # noqa: E731
                     heads(t, h_kv), h // h_kv, axis=2
                 )
+                mask = jnp.asarray(band_mask(s, self.window))
+                if keep is not None:  # [b, keys, queries] -> [b, 1, q, k]
+                    mask = mask & (keep.swapaxes(1, 2) != 0)[:, None]
                 out = dot_product_attention(
                     heads(q, h), shared(k), shared(v), causal=False,
-                    mask=jnp.asarray(band_mask(s, self.window)),
+                    mask=mask,
                 )
             with jax.named_scope("attn_layout"):
                 out = out.reshape(b, s, h * d)
         with jax.named_scope("attn_proj"):
-            return dense(cfg.d_model, "o")(out)
+            out = dense(cfg.d_model, "o")(out)
+        if not cfg.index_top_k:
+            return out
+        return out, dsa_index_loss(
+            q_turned, k, lse, q_idx, k_idx, w, keep, lse_idx, n_heads=h,
+            n_kv_heads=h_kv, use_kernel=use_flash,
+        )
+
+    def index(self, x, turn):
+        """The indexer's ``(q_idx [B, S, H_I d_I], k_idx [B, S, d_I], w [B,
+        S, H_I] fp32)`` from ``x``, to which they carry no gradient."""
+        cfg = self.cfg
+        n, width = cfg.index_heads, cfg.index_head_dim
+        weigh = self.param(
+            "index_w", _init(cfg), (cfg.d_model, n), jnp.float32
+        )
+        with jax.named_scope("index_proj"):
+            x = jax.lax.stop_gradient(x)
+            q_idx = nn.Dense(
+                n * width, use_bias=False, dtype=cfg.dtype, name="index_q",
+                kernel_init=_init(cfg),
+            )(x)
+            k_idx = nn.LayerNorm(
+                epsilon=cfg.eps, dtype=cfg.dtype, name="index_k_norm"
+            )(nn.Dense(
+                width, use_bias=False, dtype=cfg.dtype, name="index_k",
+                kernel_init=_init(cfg),
+            )(x))
+            w = jnp.dot(
+                x, weigh.astype(cfg.dtype), preferred_element_type=jnp.float32
+            ) * (n ** -0.5 * width ** -0.5)
+            if self.rotate:
+                q_idx, k_idx = turn(q_idx, n), turn(k_idx, 1)
+        return q_idx, k_idx, w
 
 
 class WindowMoEBlock(nn.Module):
     """One layer: the router reads ``RMSNorm1(h)`` before the attention
-    does, the held experts read ``RMSNorm2(h')``."""
+    does (or ``RMSNorm2(h')``: ``cfg.router_input``), the held experts read
+    ``RMSNorm2(h')``. With ``cfg.index_top_k``: ``(x, index_loss)``."""
 
     cfg: WindowMoEConfig
     windowed: bool = False
@@ -200,6 +296,8 @@ class WindowMoEBlock(nn.Module):
         cfg = self.cfg
         b, s, d = x.shape
         held, f = cfg.n_experts_held, cfg.d_ff_expert
+        if cfg.router_input not in ("attn_norm", "ffn_norm"):
+            raise ValueError(f"router_input: {cfg.router_input!r}")
         norm = lambda name: RMSNorm(cfg.eps, cfg.dtype, name=name)  # noqa: E731
         param = lambda name, shape: self.param(  # noqa: E731
             name, _init(cfg), shape, jnp.float32
@@ -208,32 +306,44 @@ class WindowMoEBlock(nn.Module):
         gate = param("experts_gate", (held, d, f))
         up = param("experts_up", (held, d, f))
         down = param("experts_down", (held, f, d))
+
+        def route(tokens):
+            with jax.named_scope("moe_route"):
+                return ep.topk_route(
+                    tokens, router, None, top_k=cfg.top_k, scoring="softmax"
+                )
+
         with jax.named_scope("norm"):
             u = norm("attn_norm")(x)
             tokens = u.reshape(b * s, d)  # free: the token-major view
-        with jax.named_scope("moe_route"):
-            chosen, weights = ep.topk_route(
-                tokens, router, None, top_k=cfg.top_k, scoring="softmax"
-            )
+        if cfg.router_input == "attn_norm":
+            chosen, weights = route(tokens)
         a = GroupedAttention(
             cfg, window=cfg.window if self.windowed else None,
             rotate=self.rotate, name="attn",
         )(u)
+        index_loss = None
+        if cfg.index_top_k:
+            a, index_loss = a
         with jax.named_scope("norm"):
             x = x + a
             tokens = norm("ffn_norm")(x).reshape(b * s, d)
+        if cfg.router_input == "ffn_norm":
+            chosen, weights = route(tokens)
         y = ep.local_experts(
             tokens, chosen, weights, gate, up, down,
             first_expert=cfg.first_expert, n_experts=cfg.n_experts,
-            activation="relu",
+            activation=cfg.expert_activation,
         )
         with jax.named_scope("norm"):
-            return x + y.reshape(b, s, d)
+            x = x + y.reshape(b, s, d)
+        return x if index_loss is None else (x, index_loss)
 
 
 class WindowMoELM(nn.Module):
     """``tokens [B, S] -> logits`` fp32 ``[B, S, vocab]``; ``logits[:, i]``
-    predicts the token after ``tokens[:, i]``."""
+    predicts the token after ``tokens[:, i]``. With ``cfg.index_top_k``:
+    ``(logits, index_loss)``, the layers' ``L_I`` summed."""
 
     cfg: WindowMoEConfig
 
@@ -248,14 +358,20 @@ class WindowMoELM(nn.Module):
                 cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed",
                 embedding_init=_init(cfg),
             )(tokens)
+        index_loss = 0.0
         for i in range(cfg.n_layers):
             x = WindowMoEBlock(
                 cfg, windowed=cfg.windowed(i), rotate=cfg.rotated(i),
                 name=f"block_{i}",
             )(x)
+            if cfg.index_top_k:
+                x, layer_loss = x
+                with jax.named_scope("index_proj"):
+                    index_loss = index_loss + layer_loss
         with jax.named_scope("norm"):
             x = RMSNorm(cfg.eps, cfg.dtype, name="final_norm")(x)
         with jax.named_scope("head"):
-            return jnp.dot(
+            logits = jnp.dot(
                 x, head.astype(cfg.dtype), preferred_element_type=jnp.float32
             )
+        return (logits, index_loss) if cfg.index_top_k else logits

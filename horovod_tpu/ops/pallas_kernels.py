@@ -585,14 +585,18 @@ def _in_band(band, block, blocks: int):
 
 def _drive_tiles(update, geom, qi, kj, *, q_len: Optional[int],
                  block_q: int, block_k: int, causal: bool, masked: bool,
-                 tiles: Tuple[int, int], window: Optional[int] = None):
+                 tiles: Tuple[int, int], window: Optional[int] = None,
+                 keep_ref=None):
     """Drive a kernel's ``update(rq, rk, valid)`` over one (q block, K/V
     block) pair: ``rq`` / ``rk`` select the q / K/V rows, ``valid`` is
     their ``[K/V rows, q rows]`` validity mask or ``None`` on the unmasked
     path.  Causal: slab by slab (``_for_causal_tiles``); else the whole
     block at once, masked only if ``masked``.  ``geom``: the scalars ``(q_offset, kv_offset,
     kv_len)``; ``q_len``: the real q length where padded q rows need the
-    masked path (the backward), else ``None``."""
+    masked path (the backward), else ``None``.  ``keep_ref`` (a causal call
+    handed a mask, ``keep=``): the pair's ``[1, block_k, block_q]`` int8
+    block of it, ANDed into what the geometry gives; an interior slab's
+    validity is then the mask's alone."""
     row0 = geom[0] + qi * block_q  # global position of the block's row 0
     if causal:
         tq, tk = tiles
@@ -608,7 +612,11 @@ def _drive_tiles(update, geom, qi, kj, *, q_len: Optional[int],
                 geom, row0 + r, kj * block_k + start if cut else kj * block_k,
                 tq, width, True, window,
             ) if tile_masked else None
-            update(pl.ds(pl.multiple_of(r, tq), tq), rk, valid)
+            rq = pl.ds(pl.multiple_of(r, tq), tq)
+            if keep_ref is not None:
+                kept = keep_ref[0, rk, rq].astype(jnp.int32) != 0
+                valid = kept if valid is None else jnp.logical_and(valid, kept)
+            update(rq, rk, valid)
 
         _for_causal_tiles(
             tile, row0, geom[1] + kj * block_k,
@@ -643,6 +651,7 @@ def _fwd_kernel(
     kv_shared: bool = False,
     band: Optional["_Plan"] = None,
     turn: Optional[Tuple[int, int, bool]] = None,
+    select: bool = False,
 ):
     """One (batch*head group, q-block, k-block) grid step of the online
     softmax.
@@ -696,12 +705,17 @@ def _fwd_kernel(
     o_ref, lse_ref, qt_ref`` and the scratch: the rows' table block
     ``[block_q, 2 r]`` and a further output shaped like the q block, which
     step 0 fills with the turned q and every update reads in q_ref's place.
+    With ``select`` (``keep=``) the last input is the pair's block of the
+    mask, ``[1, block_k, block_q]`` int8 (``_drive_tiles``).
     """
+    refs = list(refs)
+    rot_ref = refs.pop(0) if turn is not None else None
+    keep_ref = refs.pop(0) if select else None
     if turn is None:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
         qt_ref = q_ref
     else:
-        rot_ref, o_ref, lse_ref, qt_ref, acc_ref, m_ref, l_ref = refs
+        o_ref, lse_ref, qt_ref, acc_ref, m_ref, l_ref = refs
     geom = (qoff_ref[0, 0], kvoff_ref[0, 0], kvlen_ref[0, 0])
     group, block_q, block_k = _block_dims(q_ref, k_ref, packed, d)
     heads = dict(packed=packed, d=d, dv=dv, rope=rope, kv_shared=kv_shared)
@@ -779,7 +793,7 @@ def _fwd_kernel(
     _in_band(band, kj, band and band.skv_pad // block_k)(lambda: _drive_tiles(
         update, geom, qi, kj, q_len=None, block_q=block_q, block_k=block_k,
         causal=causal, masked=masked, tiles=tiles,
-        window=band and band.window,
+        window=band and band.window, keep_ref=keep_ref,
     ))
 
     @pl.when(step == nk - 1)
@@ -832,6 +846,9 @@ class _Plan(NamedTuple):
     # None: q arrives rotated, if at all.  ``(first rotated lane of a head,
     # rotated lanes, halves)``: the kernels turn it ("Rotary at the door")
     turn: Optional[Tuple[int, int, bool]] = None
+    # the call was handed a mask (``keep=``): one more int8 operand, a
+    # ``[block_k, block_q]`` block a pair, in all three kernels
+    select: bool = False
 
     @property
     def kv_group(self) -> int:
@@ -885,7 +902,8 @@ class _Plan(NamedTuple):
 def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
           interpret: Optional[bool], n_heads: int, rope: int = 0,
           n_kv_heads: int = 0, window: int = 0,
-          turn: Optional[Tuple[int, int, bool]] = None) -> _Plan:
+          turn: Optional[Tuple[int, int, bool]] = None,
+          select: bool = False) -> _Plan:
     packed = n_heads > 0
     if packed:
         b, sq, hd = q.shape
@@ -914,7 +932,7 @@ def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
         packed, b, h, d, sq, skv, block_q, block_k,
         _round_up(sq, block_q), skv_pad,
         _head_group(h, block_q, block_k, d, packed, dv, rope, kv_ratio),
-        tiles, interpret, dv, rope, kv_ratio, window, turn,
+        tiles, interpret, dv, rope, kv_ratio, window, turn, select,
     )
 
 
@@ -967,9 +985,10 @@ def _vspec(shape, index_map):
 
 
 def _kind_params(p: _Plan) -> dict:
-    """The kernels' static parameters that a grouped or windowed call
-    sets."""
-    return dict(kv_shared=p.kv_ratio > 1, band=p if p.window else None)
+    """The kernels' static parameters that a grouped, windowed or masked
+    call sets."""
+    return dict(kv_shared=p.kv_ratio > 1, band=p if p.window else None,
+                select=p.select)
 
 
 def _compiler_params(p: _Plan):
@@ -985,8 +1004,10 @@ def _compiler_params(p: _Plan):
 def _kernel_name(base: str, p: _Plan) -> str:
     """A windowed call's kernels carry ``_window`` after the name every
     call's carry, so a model's window and full layers are told apart in a
-    device trace and a reader that asks by prefix finds both."""
-    return base + "_window" if p.window else base
+    device trace and a reader that asks by prefix finds both; a call handed
+    a mask (``keep=``) carries ``_select`` last."""
+    base = base + "_window" if p.window else base
+    return base + "_select" if p.select else base
 
 
 def _grid_spec(causal: bool, *, grid, in_specs, out_specs, scratch_shapes):
@@ -1008,6 +1029,15 @@ def _grid_spec(causal: bool, *, grid, in_specs, out_specs, scratch_shapes):
     )
 
 
+def _keep_blocks(keep, p: "_Plan"):
+    """The mask, ``[B, Skv, Sq]`` int8, padded with zeros to the blocks."""
+    if (p.skv_pad, p.sq_pad) != (p.skv, p.sq):
+        keep = jnp.pad(
+            keep, ((0, 0), (0, p.skv_pad - p.skv), (0, p.sq_pad - p.sq))
+        )
+    return keep
+
+
 def _rot_rows(rot, p: "_Plan"):
     """The rotation's ``[Sq, 2 r]`` table padded to the q blocks."""
     if p.sq_pad != p.sq:
@@ -1022,6 +1052,7 @@ def _fwd_pallas(
     q_offset,
     kv_offset,
     rot=None,
+    keep=None,
     *,
     sm_scale: float,
     causal: bool,
@@ -1061,17 +1092,20 @@ def _fwd_pallas(
 
     ``rot`` / ``turn`` ("Rotary at the door"): q is unrotated, and a third
     result is the turned q, shaped like q: the backward's residual.
+
+    ``keep``: see :func:`flash_attention_with_lse`.
     """
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
               interpret=interpret, n_heads=n_heads, rope=rope,
-              n_kv_heads=n_kv_heads, window=window, turn=turn)
+              n_kv_heads=n_kv_heads, window=window, turn=turn,
+              select=keep is not None)
     if causal:
         _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
     if p.dv != p.d:
         _registry.always().counter("flash.calls.split_widths").inc()
     _book_call_kinds(p, 1)
     return _flash_fwd_call(
-        q, k, v, _geometry(q_offset, kv_offset, p.skv), rot,
+        q, k, v, _geometry(q_offset, kv_offset, p.skv), rot, keep,
         p=p, sm_scale=sm_scale, causal=causal,
     )
 
@@ -1084,8 +1118,8 @@ def _fwd_pallas(
 @functools.partial(
     jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
 )
-def _flash_fwd_call(q, k, v, geom, rot=None, *, p: _Plan, sm_scale: float,
-                    causal: bool):
+def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
+                    sm_scale: float, causal: bool):
     b, h, d, dv, group, rope = p.b, p.h, p.d, p.dv, p.group, p.rope
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
@@ -1095,6 +1129,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, *, p: _Plan, sm_scale: float,
         kr = p.pad_seq(k, p.skv, skv_pad)
         vr = p.pad_seq(v, p.skv, skv_pad)
         turned = [] if p.turn is None else [_rot_rows(rot, p)]
+        kept = [] if keep is None else [_keep_blocks(keep, p)]
 
     def kv_block(qi, kj, geom):
         if not causal:
@@ -1152,6 +1187,12 @@ def _flash_fwd_call(q, k, v, geom, rot=None, *, p: _Plan, sm_scale: float,
                     (block_q, x.shape[1]),
                     lambda bi, hi, qi, kj, *geom: (qi, 0),
                 ) for x in turned
+            ] + [
+                _vspec(
+                    (1, block_k, block_q),
+                    lambda bi, hi, qi, kj, *geom: (
+                        bi, kv_block(qi, kj, geom), qi),
+                ) for _ in kept
             ],
             out_specs=[
                 q_side(dv),
@@ -1181,7 +1222,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, *, p: _Plan, sm_scale: float,
         ),
         interpret=p.interpret,
         name=_kernel_name("hvd_flash_fwd", p),
-    )(*geom, qr, kr, vr, *turned)
+    )(*geom, qr, kr, vr, *turned, *kept)
 
     with jax.named_scope(_GLUE_SCOPE):
         if p.packed:
@@ -1295,12 +1336,11 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
 
 def _bwd_kernel_dkdv(
     qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
-    q_ref, k_ref, v_ref, g_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-    *shared_acc,
+    q_ref, k_ref, v_ref, g_ref, *refs,
     sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
     rope: int = 0, kv_shared: bool = False, q_steps: int = 0,
-    band: Optional[_Plan] = None,
+    band: Optional[_Plan] = None, select: bool = False,
 ):
     """grid (b, h-group, kj, qi): each K tile accumulates over streamed
     Q blocks; the per-head loop is a static unroll (see forward).  Heads
@@ -1327,7 +1367,13 @@ def _bwd_kernel_dkdv(
     they follow one another along the last grid axis, ``(heads' program, q
     block)`` merged, and the accumulators run on through all of them.  With
     ``band`` (windowed) step 0 of a program's q blocks is the K/V block's
-    first (``_first_q_block``) and the axis ends with the band."""
+    first (``_first_q_block``) and the axis ends with the band.  The refs
+    after g_ref are, with ``select``, the mask's block (``_drive_tiles``),
+    then ``dk_ref, dv_ref, dk_acc, dv_acc`` and ``shared_acc``."""
+    refs = list(refs)
+    keep_ref = refs.pop(0) if select else None
+    dk_ref, dv_ref, dk_acc, dv_acc, *shared_acc = refs
+    shared_acc = tuple(shared_acc)
     n = d - rope
     qi = step = pl.program_id(3)
     kj = pl.program_id(2)
@@ -1383,7 +1429,7 @@ def _bwd_kernel_dkdv(
     _in_band(band, qi, band and band.sq_pad // block_q)(lambda: _drive_tiles(
         update, geom, qi, kj, q_len=q_len, block_q=block_q, block_k=block_k,
         causal=causal, masked=masked, tiles=tiles,
-        window=band and band.window,
+        window=band and band.window, keep_ref=keep_ref,
     ))
 
     def grad(acc, g, width):
@@ -1417,7 +1463,7 @@ def _bwd_kernel_dq(
     sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
     rope: int = 0, kv_shared: bool = False, band: Optional[_Plan] = None,
-    turn: Optional[Tuple[int, int, bool]] = None,
+    turn: Optional[Tuple[int, int, bool]] = None, select: bool = False,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
@@ -1425,11 +1471,12 @@ def _bwd_kernel_dq(
     forward.  With ``turn`` ("Rotary at the door") q_ref holds the turned
     q the forward wrote, the refs after g_ref are ``rot_ref, dq_ref,
     dq_acc``, and each head's gradient is turned back where it is written:
-    dq_ref takes the gradient of the unrotated q."""
-    if turn is None:
-        dq_ref, dq_acc = refs
-    else:
-        rot_ref, dq_ref, dq_acc = refs
+    dq_ref takes the gradient of the unrotated q.  With ``select`` the
+    mask's block comes before dq_ref."""
+    refs = list(refs)
+    rot_ref = refs.pop(0) if turn is not None else None
+    keep_ref = refs.pop(0) if select else None
+    dq_ref, dq_acc = refs
     qi = pl.program_id(2)
     kj = step = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -1460,7 +1507,7 @@ def _bwd_kernel_dq(
     _in_band(band, kj, band and band.skv_pad // block_k)(lambda: _drive_tiles(
         update, geom, qi, kj, q_len=q_len, block_q=block_q, block_k=block_k,
         causal=causal, masked=masked, tiles=tiles,
-        window=band and band.window,
+        window=band and band.window, keep_ref=keep_ref,
     ))
 
     @pl.when(step == nk - 1)
@@ -1497,7 +1544,8 @@ def _bwd_kernel_dq(
 
 
 def _bwd_pallas(
-    q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot=None, *,
+    q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot=None,
+    keep=None, *,
     sm_scale: float, causal: bool, block_q: int, block_k: int,
     interpret: Optional[bool], n_heads: int = 0,
     static_offsets: Optional[Tuple[int, int]] = None, rope: int = 0,
@@ -1509,7 +1557,8 @@ def _bwd_pallas(
     unrotated one."""
     p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
               interpret=interpret, n_heads=n_heads, rope=rope,
-              n_kv_heads=n_kv_heads, window=window, turn=turn)
+              n_kv_heads=n_kv_heads, window=window, turn=turn,
+              select=keep is not None)
     if causal:
         # one count for each of the two kernels
         for _ in range(2):
@@ -1522,15 +1571,15 @@ def _bwd_pallas(
     _book_call_kinds(p, 2)
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
-        g_lse, rot, p=p, sm_scale=sm_scale, causal=causal,
+        g_lse, rot, keep, p=p, sm_scale=sm_scale, causal=causal,
     )
 
 
 @functools.partial(
     jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
 )
-def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
-                    p: _Plan, sm_scale: float, causal: bool):
+def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
+                    keep=None, *, p: _Plan, sm_scale: float, causal: bool):
     b, h, d, dv, group, sq, skv = p.b, p.h, p.d, p.dv, p.group, p.sq, p.skv
     rope, n = p.rope, p.d - p.rope
     block_q, block_k, sq_pad, skv_pad = (
@@ -1569,6 +1618,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
         glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
         glse_rows = rows(glse.astype(jnp.float32), 0.0)
         turned = [] if p.turn is None else [_rot_rows(rot, p)]
+        kept = [] if keep is None else [_keep_blocks(keep, p)]
 
     kernel_params = dict(
         sm_scale=sm_scale, causal=causal,
@@ -1581,7 +1631,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
         interpret=p.interpret,
     )
     def specs(order):
-        """(row-statistics, q, k, v, g) block specs for a grid whose last
+        """(row-statistics, q, k, v, g, rotation, mask) block specs for a grid whose last
         two axes are ``order``: "kq" (dK/dV: q streams innermost, its
         skipped steps clamped to the first q block needed) or "qk" (dQ:
         K/V streams innermost, clamped to the last K/V block needed).
@@ -1637,6 +1687,10 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
         def rot_map(bi, hi, i, j, *geom):
             return (blocks(i, j, geom)[0], 0)
 
+        def keep_map(bi, hi, i, j, *geom):
+            qi, kj = blocks(i, j, geom)
+            return (bi, kj, qi)
+
         def block(rows, width, index_map, heads=group):
             return _vspec(
                 (1, rows, heads * width) if p.packed
@@ -1651,6 +1705,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
             else block(block_k, dv, kv_map, p.kv_group),
             block(block_q, dv, q_map),
             [_vspec((block_q, x.shape[1]), rot_map) for x in turned],
+            [_vspec((1, block_k, block_q), keep_map) for _ in kept],
         )
 
     def shape_like(x, s_pad, width, heads=h):
@@ -1660,7 +1715,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
         )
 
     # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
-    stat_spec, q_spec, k_spec, v_spec, g_spec, _ = specs("kq")
+    stat_spec, q_spec, k_spec, v_spec, g_spec, _, keep_spec = specs("kq")
 
     def dkv_acc(width, heads=p.kv_group):
         return _VMEM(
@@ -1695,7 +1750,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
             grid=(b, h // group // p.subs, skv_pad // block_k,
                   p.subs * p.q_steps),
             in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, k_spec, v_spec, g_spec],
+                      q_spec, k_spec, v_spec, g_spec] + keep_spec,
             out_specs=dkv_out_specs,
             scratch_shapes=[dkv_acc(n), dkv_acc(dv)] + (
                 [dkv_acc(rope, 1)] if rope else []
@@ -1704,27 +1759,30 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
         out_shape=dkv_out_shape,
         **call_params,
         name=_kernel_name("hvd_flash_bwd_dkv", p),
-    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr)
+    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *kept)
     if rope:
         with jax.named_scope(_GLUE_SCOPE):
             grad_v = grad_v.sum(axis=1).swapaxes(1, 2)
 
     # dq: grid (b, h-group, qi, kj) — k streams innermost.
-    stat_spec, q_spec, k_spec, v_spec, g_spec, rot_spec = specs("qk")
+    stat_spec, q_spec, k_spec, v_spec, g_spec, rot_spec, keep_spec = specs(
+        "qk"
+    )
     dq = pl.pallas_call(
         functools.partial(_bwd_kernel_dq, **kernel_params, turn=p.turn),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, sq_pad // block_q, p.kv_steps),
             in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, k_spec, v_spec, g_spec] + rot_spec,
+                      q_spec, k_spec, v_spec, g_spec] + rot_spec + keep_spec,
             out_specs=q_spec,
             scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
         ),
         out_shape=shape_like(q, sq_pad, d),
         **call_params,
         name=_kernel_name("hvd_flash_bwd_dq", p),
-    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *turned)
+    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *turned,
+      *kept)
 
     with jax.named_scope(_GLUE_SCOPE):
         if p.packed:
@@ -1742,16 +1800,16 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None, *,
 
 @functools.partial(
     jax.custom_vjp,
-    nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16),
+    nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17),
 )
-def _flash(q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
-           block_k, interpret, n_heads=0, static_offsets=None, rope=0,
-           n_kv_heads=0, window=0, turn=None):
+def _flash(q, k, v, q_offset, kv_offset, rot, keep, sm_scale, causal,
+           block_q, block_k, interpret, n_heads=0, static_offsets=None,
+           rope=0, n_kv_heads=0, window=0, turn=None):
     """``(out, lse)`` with the exact backward.  With ``rope`` the operands
     ``k`` and ``v`` are the packed ``kv`` and the shared key (``_k_head``),
     and so are their cotangents.  ``rot`` (None, or with ``turn`` the
     rotation's table: "Rotary at the door"): q is unrotated, and so is its
-    cotangent."""
+    cotangent.  ``keep`` (None, or the int8 mask): a constant of the call."""
     return _fwd_pallas(
         q,
         k,
@@ -1759,6 +1817,7 @@ def _flash(q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
         q_offset,
         kv_offset,
         rot,
+        keep,
         sm_scale=sm_scale,
         causal=causal,
         block_q=block_q,
@@ -1773,29 +1832,29 @@ def _flash(q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
     )[:2]
 
 
-def _flash_fwd(q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
-               block_k, interpret, n_heads=0, static_offsets=None, rope=0,
-               n_kv_heads=0, window=0, turn=None):
+def _flash_fwd(q, k, v, q_offset, kv_offset, rot, keep, sm_scale, causal,
+               block_q, block_k, interpret, n_heads=0, static_offsets=None,
+               rope=0, n_kv_heads=0, window=0, turn=None):
     if turn is None:
         out, lse = _flash(
-            q, k, v, q_offset, kv_offset, rot, sm_scale, causal, block_q,
-            block_k, interpret, n_heads, static_offsets, rope, n_kv_heads,
-            window, turn
+            q, k, v, q_offset, kv_offset, rot, keep, sm_scale, causal,
+            block_q, block_k, interpret, n_heads, static_offsets, rope,
+            n_kv_heads, window, turn
         )
     else:  # the turned q is kept, and q is not
         out, lse, q = _fwd_pallas(
-            q, k, v, q_offset, kv_offset, rot, sm_scale=sm_scale,
+            q, k, v, q_offset, kv_offset, rot, keep, sm_scale=sm_scale,
             causal=causal, block_q=block_q, block_k=block_k,
             interpret=interpret, n_heads=n_heads,
             static_offsets=static_offsets, rope=rope, n_kv_heads=n_kv_heads,
             window=window, turn=turn,
         )
-    return (out, lse), (q, k, v, q_offset, kv_offset, rot, out, lse)
+    return (out, lse), (q, k, v, q_offset, kv_offset, rot, keep, out, lse)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
                static_offsets, rope, n_kv_heads, window, turn, res, g):
-    q, k, v, q_offset, kv_offset, rot, out, lse = res
+    q, k, v, q_offset, kv_offset, rot, keep, out, lse = res
     g_out, g_lse = g
     dq, dk, dv = _bwd_pallas(
         q,
@@ -1808,6 +1867,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
         g_out,
         g_lse,
         rot,
+        keep,
         sm_scale=sm_scale,
         causal=causal,
         block_q=block_q,
@@ -1820,10 +1880,14 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
         window=window,
         turn=turn,
     )
-    # Integer offsets take float0 cotangents; the tables are constants.
+    # Integer offsets and the int8 mask take float0 cotangents; the tables
+    # are constants.
     zero = np.zeros((), dtype=jax.dtypes.float0)
     return (dq, dk, dv, zero, zero,
-            None if rot is None else jnp.zeros_like(rot))
+            None if rot is None else jnp.zeros_like(rot),
+            None if keep is None else np.zeros(
+                keep.shape, dtype=jax.dtypes.float0
+            ))
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1891,7 +1955,7 @@ def _rotary_operand(q_rotary: QRotary, sq: int, d: int, compiled: bool):
 
 def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
                 block_k, interpret, n_heads, rope=0, n_kv_heads=0,
-                window=None, q_rotary=None):
+                window=None, q_rotary=None, keep=None):
     """``_flash`` on a public entry's arguments: ``(out, lse)``."""
     # Offsets given as Python ints (the model path: 0, 0) are also kept
     # static, for the build-time tile counters; the kernels read the
@@ -1922,6 +1986,7 @@ def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
         jnp.asarray(q_offset, jnp.int32),
         jnp.asarray(kv_offset, jnp.int32),
         rot,
+        keep,
         float(sm_scale),
         bool(causal),
         int(block_q),
@@ -1953,6 +2018,7 @@ def flash_attention_with_lse(
     n_kv_heads: int = 0,
     window: Optional[int] = None,
     q_rotary: Optional[QRotary] = None,
+    keep=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Blockwise attention returning ``(out, lse)``.
 
@@ -1991,6 +2057,16 @@ def flash_attention_with_lse(
 
     ``q_rotary`` (:class:`QRotary`): q is passed unrotated and rotated in
     the kernels; k is passed rotated.
+
+    ``keep`` (needs ``causal=True``): int8 ``[B, Skv, Sq]``, keys by queries
+    as the kernels hold their scores; row ``i`` of every head sees column
+    ``j`` only where the causal geometry (and ``window``) lets it AND
+    ``keep[b, j, i] != 0``.  A constant of the call (no cotangent).  The
+    grids and the tile classes stay the geometry's: the kernels read one
+    more ``[block_k, block_q]`` block a pair and skip nothing for it, so a
+    mask is for kept sets that are scattered (``ops/dsa_kernels.py``
+    builds one).  A row that keeps nothing gives zeros and ``lse = -inf``.
+    The kernels' names end in ``_select``.
     """
     packed = layout == "bsm"
     if packed and n_heads <= 0:
@@ -2038,6 +2114,14 @@ def flash_attention_with_lse(
             f"axis); got k {k.shape}, v {v.shape} for {h_kv} heads — use "
             "layout='bhsd'"
         )
+    if keep is not None:
+        seq_axis = 1 if layout in ("bshd", "bsm") else 2
+        want = (q.shape[0], k.shape[seq_axis], q.shape[seq_axis])
+        if not causal or keep.shape != want or keep.dtype != jnp.int8:
+            raise ValueError(
+                f"keep needs causal=True and an int8 [B, Skv, Sq] = {want} "
+                f"mask (got causal={causal}, {keep.dtype} {keep.shape})"
+            )
     if sm_scale is None:
         d = q.shape[-1] // n_heads if packed else q.shape[-1]
         sm_scale = 1.0 / float(np.sqrt(d))
@@ -2050,7 +2134,7 @@ def flash_attention_with_lse(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         interpret, n_heads if packed else 0,
         n_kv_heads=h_kv if packed and h_kv != h else 0, window=window,
-        q_rotary=q_rotary,
+        q_rotary=q_rotary, keep=keep,
     )
     if layout == "bshd":
         with jax.named_scope(_GLUE_SCOPE):
@@ -2074,10 +2158,11 @@ def flash_attention(
     n_kv_heads: int = 0,
     window: Optional[int] = None,
     q_rotary: Optional[QRotary] = None,
+    keep=None,
 ) -> jax.Array:
     """Drop-in memory-efficient replacement for
     ``models.transformer.dot_product_attention`` (same signature shape);
-    ``n_kv_heads``, ``window`` and ``q_rotary`` as
+    ``n_kv_heads``, ``window``, ``q_rotary`` and ``keep`` as
     :func:`flash_attention_with_lse`.
 
     Dense ``mask`` is not supported by the blockwise kernel — callers that
@@ -2102,6 +2187,7 @@ def flash_attention(
         n_kv_heads=n_kv_heads,
         window=window,
         q_rotary=q_rotary,
+        keep=keep,
     )
     return out
 

@@ -15,6 +15,7 @@ PARTS = ("embed", "norm", "attn_proj", "attn_xla", "attn_layout", "mlp",
          "head")
 EXPERT_SCOPES = ("mla_proj", "moe_route", "moe_experts")
 KDA_SCOPES = ("kda_proj", "kda_conv", "kda_gate")
+SELECT_SCOPES = ("index_proj",)
 SEQ = 128
 
 
@@ -96,7 +97,9 @@ def _latent_moe():
     return "LatentMoELM", build
 
 
-def _window_moe():
+def _window_moe(**settings):
+    """``settings``: the block's static settings; with ``index_top_k`` the
+    learned-sparse-attention layer, whose loss adds the index loss."""
     from horovod_tpu.models.window_moe import (
         WindowMoEConfig, WindowMoELM, lm_loss,
     )
@@ -105,13 +108,16 @@ def _window_moe():
         # heads of 128: a packed K/V block of ONE head fills the lanes
         cfg = WindowMoEConfig.tiny(
             d_model=128, n_heads=4, n_kv_heads=2, head_dim=128, window=64,
-            use_flash=use_flash,
+            use_flash=use_flash, **settings,
         )
         model = WindowMoELM(cfg)
 
         def loss(model, params, tokens):
             logits = model.apply({"params": params}, tokens[:, :-1])
-            return lm_loss(logits, None, tokens, mtp_weight=0.0)
+            index_loss = 0.0
+            if cfg.index_top_k:
+                logits, index_loss = logits
+            return lm_loss(logits, None, tokens, mtp_weight=0.0) + index_loss
 
         return model, _lm(model, loss), {"tokens": (SEQ + 1,)}
 
@@ -152,6 +158,8 @@ CASES = {
     "latent-moe-xla": ("latent_moe", False),
     "window-moe-flash": ("window_moe", True),
     "window-moe-xla": ("window_moe", False),
+    "select-moe-kernels": ("select_moe", True),
+    "select-moe-xla": ("select_moe", False),
     "linear-moe-kernels": ("linear_moe", True),
     "linear-moe-xla": ("linear_moe", False),
 }
@@ -162,6 +170,12 @@ _FAMILIES = {
     "bert_cls": lambda: _bert(2),
     "latent_moe": _latent_moe,
     "window_moe": _window_moe,
+    "select_moe": lambda: _window_moe(
+        n_layers=2, window_layout=(0,), rope_layout=(1,),
+        router_input="ffn_norm", expert_activation="silu", qk_norm=True,
+        index_top_k=32, index_heads=2, index_head_dim=64,
+        index_blocks=(128, 128),
+    ),
     "linear_moe": _linear_moe,
 }
 

@@ -153,7 +153,7 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
     ``custom_vjp`` makes for the unused ``lse``): no device time, no rule."""
     top, jaxpr = _traced_step(world, case)
     one_of = (model_parts.PARTS + model_parts.EXPERT_SCOPES
-              + model_parts.KDA_SCOPES)
+              + model_parts.KDA_SCOPES + model_parts.SELECT_SCOPES)
     inside, kernels, seen = 0, 0, set()
     for primitive, stack, computed in model_parts.operations(jaxpr.jaxpr):
         if top not in stack:
@@ -165,14 +165,18 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
         if primitive == "pallas_call":
             kernels += 1
             assert not held, (stack, held)
-            assert segments[-1].startswith(("hvd_flash_", "hvd_kda_")), stack
+            assert segments[-1].startswith(
+                ("hvd_flash_", "hvd_kda_", "hvd_dsa_")
+            ), stack
         elif computed:
             assert len(held) == 1, (primitive, stack, held)
         seen.update(held)
     assert inside > 500
     family, use_flash = model_parts.CASES[case]
     # 3 and 4 blocks; one latent layer's 3 and four KDA layers' 2 each
-    per_family = {"latent_moe": 9, "window_moe": 12, "linear_moe": 11}
+    # ... two sparse layers' 5 each (select, three masked flash, index loss)
+    per_family = {"latent_moe": 9, "window_moe": 12, "linear_moe": 11,
+                  "select_moe": 10}
     assert kernels == (per_family.get(family, 6) if use_flash else 0)
     # each family opens what the table in docs/api.md says it does
     attention = {"attn_layout"} if use_flash else {"attn_xla"}
@@ -182,6 +186,9 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
     elif family == "window_moe":  # no dense feed-forward, so no ``mlp``
         want = {"embed", "norm", "head", "attn_proj", "attn_layout",
                 "moe_route", "moe_experts"} | attention
+    elif family == "select_moe":  # the window block with its indexer on
+        want = {"embed", "norm", "head", "attn_proj", "attn_layout",
+                "moe_route", "moe_experts", "index_proj"} | attention
     elif family == "linear_moe":  # no ``attn_proj``: two mixers' own scopes
         want = {"embed", "norm", "mlp", "head", "attn_layout",
                 *model_parts.EXPERT_SCOPES,

@@ -357,6 +357,58 @@ def test_flash_attention_window_and_groups_compile(v5e, window):
     assert (p.kv_steps, p.q_steps) == ((6, 11) if window else (16, 32))
 
 
+def test_select_family_compiles_at_the_sparse_cells_shapes(v5e):
+    """Learned sparse attention at 1 x 8,192, 32 query heads on 4 K/V heads
+    of 128, an indexer of 16 x 64 on one key head: the select kernel (whose
+    ``[8192, 512]`` block of ordered keys is 16 MB of VMEM), the three flash
+    kernels under its int8 mask with q rotated at their door, and the
+    index-loss kernel whose ``dk_idx`` stays in VMEM through the grid. Five
+    Mosaic calls, each under its name."""
+    from horovod_tpu.models.transformer import rotary_tables
+    from horovod_tpu.ops import dsa_kernels as dsa
+
+    s, h, h_kv, d, h_i, d_i = 8192, 32, 4, 128, 16, 64
+    table = pk.QRotary(*rotary_tables(s, d, theta=1e7), halves=True)
+
+    def loss(q, k, v, q_idx, k_idx, w):
+        keep, _, lse_idx = dsa.dsa_select(
+            q_idx, k_idx, w, top_k=2048, use_kernel=True, interpret=False,
+            block_q=512, block_k=512,
+        )
+        out, lse = pk.flash_attention_with_lse(
+            q, k, v, causal=True, layout="bsm", n_heads=h, n_kv_heads=h_kv,
+            q_rotary=table, keep=keep, interpret=False,
+        )
+        return out.astype(jnp.float32).sum() + dsa.dsa_index_loss(
+            q, k, lse, q_idx, k_idx, w, keep, lse_idx, n_heads=h,
+            n_kv_heads=h_kv, use_kernel=True, interpret=False,
+        )
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)), v5e,
+        ((1, s, h * d), jnp.bfloat16), ((1, s, h_kv * d), jnp.bfloat16),
+        ((1, s, h_kv * d), jnp.bfloat16), ((1, s, h_i * d_i), jnp.bfloat16),
+        ((1, s, d_i), jnp.bfloat16), ((1, s, h_i), jnp.float32),
+    )
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 5
+    for name in ("hvd_dsa_select", "hvd_flash_fwd_select",
+                 "hvd_flash_bwd_dkv_select", "hvd_flash_bwd_dq_select",
+                 "hvd_dsa_kl"):
+        (call,) = [c for c in calls if f'/{name}"' in c
+                   or name in c.split(" = ")[0]]
+        # each reads or writes the mask as it lies, keys by queries
+        assert "s8[1,8192,8192]" in call, name
+    p = pk._plan(
+        *(jax.ShapeDtypeStruct((1, s, n * d), jnp.bfloat16)
+          for n in (h, h_kv, h_kv)),
+        causal=True, block_q=512, block_k=512, interpret=False, n_heads=h,
+        n_kv_heads=h_kv, select=True,
+    )
+    assert (p.group, p.kv_group, p.subs, p.block_k) == (8, 1, 1, 1024)
+
+
 @pytest.mark.parametrize(
     "cell", ["latent-pairs-2x4096x32", "window-halves-1x16384x28",
              "latent-pairs-s1000x8-padded"],

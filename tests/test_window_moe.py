@@ -429,3 +429,191 @@ def test_default_scoring_and_activation_leave_the_latent_models_jaxpr():
 def test_unknown_scoring_or_activation_is_refused(call, match):
     with pytest.raises(ValueError, match=match):
         call()
+
+
+# -- learned sparse attention: the block's static settings ------------------
+# (``index_top_k`` with the router on ``RMSNorm2(h')``, SiLU experts and
+# head-wise q / k norms) against ``benchmark/lib/plain_select_moe.py``
+
+
+def _select_cfg(**kw):
+    base = dict(
+        window_layout=(0,), rope_layout=(1,), n_layers=2,
+        router_input="ffn_norm", expert_activation="silu", qk_norm=True,
+        index_top_k=8, index_heads=4, index_head_dim=8,
+        index_blocks=(16, 16),
+    )
+    base.update(kw)
+    return _tiny(**base)
+
+
+def _select_sizes(cfg, **kw):
+    from benchmark.lib import plain_select_moe
+
+    return plain_select_moe.Sizes(
+        n_layers=cfg.n_layers, n_heads=cfg.n_heads,
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        index_heads=cfg.index_heads, index_head_dim=cfg.index_head_dim,
+        index_top_k=cfg.index_top_k, rope_theta=cfg.rope_theta,
+        first_expert=cfg.first_expert, top_k=cfg.top_k, eps=cfg.eps,
+        q_block=8, **kw,
+    )
+
+
+def _select_losses(cfg):
+    """``params, tokens -> (cross entropy, index loss)`` of the program."""
+    model = WindowMoELM(cfg)
+
+    def parts(params, tokens):
+        logits, index_loss = model.apply({"params": params}, tokens[:, :-1])
+        return lm_loss(logits, None, tokens, mtp_weight=0.0), index_loss
+
+    return parts
+
+
+def _leaves(tree):
+    return {
+        "/".join(str(k.key) for k in path): leaf
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["xla", "kernels"])
+def test_select_model_matches_its_plain_reference_leaf_by_leaf(flash):
+    """Loss, index loss and every leaf's gradient, through XLA and through
+    the select, masked flash and index-loss kernels (interpreted): 32
+    positions of which a query keeps 8, 3:1 groups, two layers."""
+    from benchmark.lib import plain_select_moe
+
+    cfg = _select_cfg(use_flash=flash)
+    params, tokens = _params(cfg, scale=3.0), _tokens(cfg, 2)
+    parts = _select_losses(cfg)
+    z = _select_sizes(cfg)
+    with jax.default_matmul_precision("highest"):
+        got_ce, got_index = parts(params, tokens)
+        want_ce, want_index = plain_select_moe.loss_parts(params, tokens, z)
+        got_grads = jax.grad(lambda p: sum(parts(p, tokens)))(params)
+        want_grads = jax.grad(
+            lambda p: plain_select_moe.loss(p, tokens, z)
+        )(params)
+    assert float(want_index) > 1e-2
+    assert float(got_ce) == pytest.approx(float(want_ce), rel=1e-5)
+    assert float(got_index) == pytest.approx(float(want_index), rel=1e-4)
+    got, want = _leaves(got_grads), _leaves(want_grads)
+    assert sorted(got) == sorted(want)
+    for name in want:
+        np.testing.assert_allclose(
+            got[name], want[name], rtol=2e-3,
+            atol=2e-5 * float(jnp.abs(want[name]).max()) + 1e-9,
+            err_msg=name,
+        )
+    # selection changes the result: the reference that keeps everything
+    everything = plain_select_moe.loss_parts(
+        params, tokens, _select_sizes(cfg, departure="keep_all")
+    )
+    assert abs(float(everything[0]) - float(want_ce)) > 1e-4
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["xla", "kernels"])
+def test_the_two_stop_gradients(flash):
+    """The indexer's leaves are unmoved by the cross entropy and every
+    other leaf by the index loss."""
+    cfg = _select_cfg(use_flash=flash)
+    params, tokens = _params(cfg, scale=3.0), _tokens(cfg, 3)
+    parts = _select_losses(cfg)
+    from_ce = _leaves(jax.grad(lambda p: parts(p, tokens)[0])(params))
+    from_index = _leaves(jax.grad(lambda p: parts(p, tokens)[1])(params))
+    indexer = [n for n in from_ce if "/index_" in n]
+    assert len(indexer) == cfg.n_layers * 5  # q, k, its norm's two, w
+    for name in from_ce:
+        ce, index = (float(jnp.abs(g[name]).max())
+                     for g in (from_ce, from_index))
+        if name in indexer:
+            assert ce == 0.0 and index > 0.0, name
+        else:
+            assert index == 0.0 and ce > 0.0, name
+
+
+def test_select_shares_add_up_to_the_uncut_layer():
+    """One layer, 16 experts over 4 chips, selection on: what the four
+    shares' experts give, with what every chip computes alike (attention
+    over the kept set, the residual) counted once, is the uncut
+    reference's layer, and every share reports the layer's index loss."""
+    from benchmark.lib import plain_select_moe
+
+    chips = 4
+    whole = _select_cfg(n_experts_held=16)
+    block = WindowMoEBlock(whole, rotate=True)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, SEQ, whole.d_model))
+    params = block.init(jax.random.PRNGKey(5), x)["params"]
+    params = jax.tree.map(lambda p: p * 3 if p.ndim > 1 else p, params)
+    z = _select_sizes(whole)
+    nothing_held = {
+        **params, "experts_down": jnp.zeros_like(params["experts_down"])
+    }
+    with jax.default_matmul_precision("highest"):
+        uncut, index_loss = plain_select_moe.block(params, x, z)
+        alike, _ = plain_select_moe.block(nothing_held, x, z)
+        total = alike
+        for chip in range(chips):
+            cfg = dataclasses.replace(
+                whole, n_experts_held=4, first_expert=4 * chip
+            )
+            held = slice(4 * chip, 4 * chip + 4)
+            share = {**params, **{
+                name: params[name][held] for name in
+                ("experts_gate", "experts_up", "experts_down")
+            }}
+            out, share_loss = WindowMoEBlock(cfg, rotate=True).apply(
+                {"params": share}, x
+            )
+            total = total + (out - alike)
+            assert float(share_loss) == pytest.approx(float(index_loss),
+                                                      rel=1e-4)
+    np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
+    assert float(jnp.max(jnp.abs(uncut - alike))) > 1e-2
+
+
+def test_router_input_and_activation_are_checked():
+    with pytest.raises(ValueError, match="router_input"):
+        WindowMoEBlock(_tiny(router_input="embedding")).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, 48))
+        )
+    with pytest.raises(ValueError, match="activation"):
+        WindowMoEBlock(_tiny(expert_activation="gelu")).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, 48))
+        )
+
+
+@pytest.mark.parametrize(
+    "flash,sha",
+    [(False,
+      "bab2287090853cedf323e51f3fca44ff6315ad86747b11c27d4642873a0d3c79"),
+     (True,
+      "b2e46b30664ccf7b67ca85c1417f0fb8072cfc9438ca3a3f0fdc0c55dded1ade")],
+    ids=["xla", "kernels"],
+)
+def test_default_settings_trace_what_the_block_traced(flash, sha):
+    """``WindowMoEConfig``'s defaults (router on ``RMSNorm1(h)``, ReLU
+    experts, no q / k norm, no indexer) trace, gradient and all, the text
+    the block traced before it took the settings, the flash kernels without
+    ``keep=`` theirs: the sha256 of ``str(jax.make_jaxpr(...))`` as the
+    parent of the settings' PR printed it (jax 0.9.0). An edit that means
+    to change the default block's trace records the new one here."""
+    import hashlib
+
+    cfg = WindowMoEConfig.tiny(use_flash=flash)
+    model = WindowMoELM(cfg)
+    shapes = jax.eval_shape(
+        lambda: WindowMoELM(WindowMoEConfig.tiny(use_flash=False)).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+    )
+    tokens = jax.ShapeDtypeStruct((2, SEQ + 1), jnp.int32)
+
+    def loss(params, tokens):
+        return lm_loss(model.apply({"params": params}, tokens[:, :-1]), None,
+                       tokens, mtp_weight=0.0)
+
+    text = str(jax.make_jaxpr(jax.grad(loss))(shapes, tokens))
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
