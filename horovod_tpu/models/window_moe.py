@@ -61,9 +61,12 @@ The two layouts are tuples a layer indexes modulo their length, so a
 period (``(0, 1, 1, 1)``: full, window, window, window) is data. The flash
 kernels take q ``[B, S, H * head_dim]`` and k / v ``[B, S, H_kv *
 head_dim]`` as the projections leave them (``layout="bsm"``,
-``n_kv_heads``, ``window``): no K or V of ``H`` heads exists, and in a
-rotated layer q goes in unrotated (``q_rotary``: the kernels rotate it and
-hand back the gradient of the projection's output; k is rotated here). A window
+``n_kv_heads``, ``window``): no K or V of ``H`` heads exists, and q goes in
+as its projection leaves it: with ``qk_norm`` unnormed (``q_norm``: the
+scale is the ``q_norm/scale`` leaf), in a rotated layer unrotated
+(``q_rotary``); the kernels norm and rotate it in VMEM, hand back the
+gradient of the projection's output and the scale's, and give the index
+loss the q their scores saw (``return_q``); k is normed and rotated here. A window
 layer's kernels are named ``hvd_flash_*_window``, a full layer's
 ``hvd_flash_*``. Off the TPU attention is ``dot_product_attention`` with
 the explicit band mask over K/V repeated to ``H`` heads.
@@ -71,8 +74,8 @@ the explicit band mask over K/V repeated to ``H`` heads.
 Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
 operation of ``apply`` lies under exactly one of: ``embed``, ``norm`` (the
 RMSNorms, the residual sums and the token-major views), ``attn_proj`` (the
-four projections, the head-wise norms and k's rotary; off the flash path and
-for the index loss q's too), ``index_proj`` (the indexer's three
+four projections, k's head-wise norm and rotary; off the flash path q's
+too), ``index_proj`` (the indexer's three
 projections, its LayerNorm and rotary, and the select kernels' own glue),
 ``attn_layout`` (the reshapes between the
 projections and the kernels, and the kernels' entry's own glue),
@@ -161,6 +164,16 @@ def _init(cfg: WindowMoEConfig):
     return nn.initializers.normal(cfg.init_std)
 
 
+class HeadScale(nn.Module):
+    """An ``RMSNorm``'s parameter alone, ``scale`` ``[d]`` float32 at one,
+    under the norm's name: where the flash kernels norm q themselves the
+    model holds the scale and forms nothing with it."""
+
+    @nn.compact
+    def __call__(self, d: int):
+        return self.param("scale", nn.initializers.ones, (d,), jnp.float32)
+
+
 class GroupedAttention(nn.Module):
     """Causal attention with ``n_heads`` query heads over ``n_kv_heads``
     K/V heads, under a ``window`` (None: every earlier position) and with
@@ -183,6 +196,10 @@ class GroupedAttention(nn.Module):
         use_flash = cfg.use_flash
         if use_flash is None:
             use_flash = device_platform() == "tpu"
+        if use_flash:
+            from ..ops.pallas_kernels import (
+                QNorm, QRotary, flash_attention_with_lse,
+            )
         turn = lambda t, heads: rotary(  # noqa: E731
             t.reshape(b, s, heads, -1), theta=cfg.rope_theta, halves=True,
         ).reshape(b, s, -1)
@@ -190,18 +207,20 @@ class GroupedAttention(nn.Module):
             q = dense(h * d, "q")(x)
             k = dense(h_kv * d, "k")(x)
             v = dense(h_kv * d, "v")(x)
+            q_norm = None
             if cfg.qk_norm:
                 by_head = lambda t, heads, name: RMSNorm(  # noqa: E731
                     cfg.eps, cfg.dtype, name=name
                 )(t.reshape(b, s, heads, d)).reshape(b, s, heads * d)
-                q, k = by_head(q, h, "q_norm"), by_head(k, h_kv, "k_norm")
-            q_turned = q
+                if use_flash:  # the kernels norm q: they take the scale
+                    q_norm = QNorm(HeadScale(name="q_norm")(d), cfg.eps)
+                else:
+                    q = by_head(q, h, "q_norm")
+                k = by_head(k, h_kv, "k_norm")
             if self.rotate:
                 k = turn(k, h_kv)
                 if not use_flash:
-                    q = q_turned = turn(q, h)
-                elif cfg.index_top_k:  # the index loss's target reads it
-                    q_turned = turn(jax.lax.stop_gradient(q), h)
+                    q = turn(q, h)
         keep = None
         if cfg.index_top_k:
             from ..ops.dsa_kernels import dsa_index_loss, dsa_select
@@ -214,22 +233,20 @@ class GroupedAttention(nn.Module):
             )
         lse = None
         if use_flash:
-            from ..ops.pallas_kernels import (
-                QRotary, flash_attention_with_lse,
-            )
-
             # q, k and v as the projections leave them: no relayout, and
-            # no K or V of ``h`` heads; the kernels rotate q (28 heads) and
-            # hand back the gradient of the projection's output, k (4) is
-            # rotated above
-            out, lse = flash_attention_with_lse(
+            # no K or V of ``h`` heads; the kernels norm and rotate q (28
+            # heads) and hand back the gradient of the projection's output,
+            # k (4) is normed and rotated above.  The index loss's target
+            # reads the q the kernels' scores saw.
+            out, lse, *q_seen = flash_attention_with_lse(
                 q, k, v, causal=True, window=self.window, layout="bsm",
                 n_heads=h, n_kv_heads=h_kv,
                 q_rotary=QRotary(
                     *rotary_tables(s, d, theta=cfg.rope_theta), halves=True
                 ) if self.rotate else None,
-                keep=keep,
+                keep=keep, q_norm=q_norm, return_q=bool(cfg.index_top_k),
             )
+            q = q_seen[0] if q_seen else q
         else:
             with jax.named_scope("attn_xla"):
                 heads = lambda t, n: t.reshape(b, s, n, d)  # noqa: E731
@@ -250,7 +267,7 @@ class GroupedAttention(nn.Module):
         if not cfg.index_top_k:
             return out
         return out, dsa_index_loss(
-            q_turned, k, lse, q_idx, k_idx, w, keep, lse_idx, n_heads=h,
+            q, k, lse, q_idx, k_idx, w, keep, lse_idx, n_heads=h,
             n_kv_heads=h_kv, use_kernel=use_flash,
         )
 

@@ -35,6 +35,9 @@ projection leaves it and a q block's rotated lanes are turned in VMEM, once
 a block, where a lane rotation costs nothing; the forward hands the turned
 block on as the backward's residual and dQ leaves turned back, so no
 rotated q and no gradient of one is built by XLA ("Rotary at the door").
+The ``q, k, v`` entries take ``q_norm`` (:class:`QNorm`) the same way: the
+head-wise RMS norm of q runs on the block in VMEM ahead of the turn, and dQ
+leaves as the gradient of the projection's output ("Norm at the door").
 
 Causality across ring steps needs *global* positions, so the kernel takes
 ``q_offset``/``kv_offset`` (traced scalars, prefetched to SMEM): block r
@@ -76,6 +79,7 @@ __all__ = [
     "flash_attention_with_lse",
     "flash_attention_latent",
     "QRotary",
+    "QNorm",
     "combine_blocks",
     "quantize_blockwise_pallas",
     "dequantize_blockwise_pallas",
@@ -360,15 +364,16 @@ def _count_tiles(q_offset: int, kv_offset: int, *, sq: int, skv: int,
 def _book_call_kinds(p: "_Plan", kernels: int) -> None:
     """Build-time counters of what kind of call ``kernels`` kernels were
     built for: ``flash.calls.latent_kv``, ``.windowed``, ``.grouped_kv``;
-    and ``flash.calls.rotary_q``, one a kernel that turns (the forward and
-    dQ: dK/dV reads the forward's turned q)."""
+    and ``flash.calls.rotary_q`` / ``flash.calls.norm_q``, one a kernel
+    that turns / norms (the forward and dQ: dK/dV reads the forward's q)."""
     reg = _registry.always()
     for name, on in (("latent_kv", p.rope), ("windowed", p.window),
                      ("grouped_kv", p.kv_ratio > 1)):
         if on:
             reg.counter(f"flash.calls.{name}").inc(kernels)
-    if p.turn is not None:
-        reg.counter("flash.calls.rotary_q").inc()
+    for name, on in (("rotary_q", p.turn), ("norm_q", p.norm)):
+        if on is not None:
+            reg.counter(f"flash.calls.{name}").inc()
 
 
 def _book_tiles(static_offsets, **geometry) -> None:
@@ -514,6 +519,59 @@ def _turn_lanes(load, store, width: int, heads: int, d: int, rot, turn,
               _turn_tile(x, rot, inside, turn, back) if inside else x)
 
 
+# Norm at the door (``q_norm=``, :class:`QNorm`), beside the rotary and at
+# the same two places.  q's head-wise RMS norm, ``z = x rsqrt(mean_d(x x) +
+# eps) scale`` over each head's ``d`` lanes, reshapes ``[S, H d]`` to ``[S,
+# H, d]`` where XLA forms it, which changes the tiling: XLA sets float32
+# copies of q's shape around the statistic, in both directions.  A call given
+# the scale takes q as its PROJECTION leaves it.  The forward norms a head of
+# the block in float32 where the turn runs (step 0 of the K/V axis), hands
+# the float32 lanes to the turn, and what is rounded, once, into the second
+# output is the q the scores see: the backward's residual and what
+# ``return_q`` hands the caller.  dQ's finalize, behind the turn back, holds
+# ``gz``, the float32 gradient of a head's normed lanes; with the raw q
+# block and the scale as two more operands it recomputes ``r = rsqrt(..)``
+# and ``y = x r`` and writes ``dx = r (gz scale - y mean_d(gz scale y))``,
+# the gradient of the projection's output, and ``sum_rows(gz y)`` over the
+# program's heads as one float32 ``[1, d]`` row a program, which XLA adds up
+# to the scale's gradient.  dK/dV reads the residual and is the call it was.
+# ---------------------------------------------------------------------------
+
+
+def _inv_rms(x, eps: float):
+    """``rsqrt(mean(x x) + eps)`` over the lanes of a float32 ``[rows, d]``
+    head, ``[rows, 1]``."""
+    return lax.rsqrt(jnp.mean(x * x, axis=1, keepdims=True) + eps)
+
+
+def _lanes_of(pieces, a: int, b: int):
+    """Lanes ``[a, b)`` out of ``pieces``, ``{(first, last + 1): [rows,
+    last + 1 - first]}`` side by side; a piece that is the range is handed
+    on as it is."""
+    cut = [
+        x if (lo, hi) == (max(a, lo), min(b, hi))
+        else x[:, max(a, lo) - lo:min(b, hi) - lo]
+        for (lo, hi), x in sorted(pieces.items()) if lo < b and a < hi
+    ]
+    return cut[0] if len(cut) == 1 else jnp.concatenate(cut, axis=1)
+
+
+def _normed_lanes(load, heads: int, d: int, scale, eps: float):
+    """``load`` (lanes ``[a, b)`` of ``heads`` heads of ``d`` side by side)
+    with every head normed: float32, each head formed once, when a range
+    first touches it; ``scale``: float32 ``[1, d]``."""
+    normed = {}
+
+    def lanes(a, b):
+        for i in range(a // d, (b - 1) // d + 1):
+            if (i * d, (i + 1) * d) not in normed:
+                x = load(i * d, (i + 1) * d).astype(jnp.float32)
+                normed[i * d, (i + 1) * d] = x * _inv_rms(x, eps) * scale
+        return _lanes_of(normed, a, b)
+
+    return lanes
+
+
 # Where a kernel finds head ``g``'s keys and values in its second and third
 # block operand.  ``rope == 0``: they are K, ``[.., d]`` a head, and V,
 # ``[.., dv]`` a head.  ``rope == r > 0`` (:func:`flash_attention_latent`):
@@ -652,6 +710,7 @@ def _fwd_kernel(
     band: Optional["_Plan"] = None,
     turn: Optional[Tuple[int, int, bool]] = None,
     select: bool = False,
+    norm: Optional[float] = None,
 ):
     """One (batch*head group, q-block, k-block) grid step of the online
     softmax.
@@ -705,13 +764,17 @@ def _fwd_kernel(
     o_ref, lse_ref, qt_ref`` and the scratch: the rows' table block
     ``[block_q, 2 r]`` and a further output shaped like the q block, which
     step 0 fills with the turned q and every update reads in q_ref's place.
+    With ``norm`` (an ``eps``: "Norm at the door") the scale, float32 ``[1,
+    d]``, comes behind the table, and what step 0 writes to that further
+    output is the normed q, turned if ``turn``.
     With ``select`` (``keep=``) the last input is the pair's block of the
     mask, ``[1, block_k, block_q]`` int8 (``_drive_tiles``).
     """
     refs = list(refs)
     rot_ref = refs.pop(0) if turn is not None else None
+    scale_ref = refs.pop(0) if norm is not None else None
     keep_ref = refs.pop(0) if select else None
-    if turn is None:
+    if turn is None and norm is None:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
         qt_ref = q_ref
     else:
@@ -732,17 +795,26 @@ def _fwd_kernel(
         acc_ref[:, :, :] = jnp.zeros_like(acc_ref)
         m_ref[:, :, :] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:, :, :] = jnp.zeros_like(l_ref)
-        if turn is not None:
+        if qt_ref is not q_ref:
             for at, heads in _lane_views(packed, group):
                 def put(a, b, x, at=at):
                     qt_ref[(*at, slice(None), slice(a, b))] = x.astype(
                         qt_ref.dtype
                     )
 
+                def lanes(a, b, at=at):
+                    return q_ref[(*at, slice(None), slice(a, b))]
+
+                if norm is not None:
+                    lanes = _normed_lanes(
+                        lanes, len(heads), d, scale_ref[...], norm
+                    )
+                if turn is None:
+                    for a in range(0, len(heads) * d, d):
+                        put(a, a + d, lanes(a, a + d))
+                    continue
                 _turn_lanes(
-                    lambda a, b, at=at: q_ref[
-                        (*at, slice(None), slice(a, b))
-                    ], put, q_ref.shape[-1], len(heads), d, rot_ref[...],
+                    lanes, put, q_ref.shape[-1], len(heads), d, rot_ref[...],
                     turn,
                 )
 
@@ -849,6 +921,15 @@ class _Plan(NamedTuple):
     # the call was handed a mask (``keep=``): one more int8 operand, a
     # ``[block_k, block_q]`` block a pair, in all three kernels
     select: bool = False
+    # None: q arrives normed, if at all.  An ``eps``: the kernels norm each
+    # head of it ("Norm at the door")
+    norm: Optional[float] = None
+
+    @property
+    def door(self) -> bool:
+        """The forward writes the q its scores see (normed, turned) as a
+        further output, and the backward reads that in q's place."""
+        return self.turn is not None or self.norm is not None
 
     @property
     def kv_group(self) -> int:
@@ -903,7 +984,7 @@ def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
           interpret: Optional[bool], n_heads: int, rope: int = 0,
           n_kv_heads: int = 0, window: int = 0,
           turn: Optional[Tuple[int, int, bool]] = None,
-          select: bool = False) -> _Plan:
+          select: bool = False, norm: Optional[float] = None) -> _Plan:
     packed = n_heads > 0
     if packed:
         b, sq, hd = q.shape
@@ -932,7 +1013,7 @@ def _plan(q, k, v, *, causal: bool, block_q: int, block_k: int,
         packed, b, h, d, sq, skv, block_q, block_k,
         _round_up(sq, block_q), skv_pad,
         _head_group(h, block_q, block_k, d, packed, dv, rope, kv_ratio),
-        tiles, interpret, dv, rope, kv_ratio, window, turn, select,
+        tiles, interpret, dv, rope, kv_ratio, window, turn, select, norm,
     )
 
 
@@ -1045,27 +1126,39 @@ def _rot_rows(rot, p: "_Plan"):
     return rot
 
 
-def _fwd_pallas(
-    q,
-    k,
-    v,
-    q_offset,
-    kv_offset,
-    rot=None,
-    keep=None,
-    *,
-    sm_scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    interpret: Optional[bool],
-    n_heads: int = 0,
-    static_offsets: Optional[Tuple[int, int]] = None,
-    rope: int = 0,
-    n_kv_heads: int = 0,
-    window: int = 0,
-    turn: Optional[Tuple[int, int, bool]] = None,
-):
+class _Static(NamedTuple):
+    """What a flash call fixes when it is traced: ``_flash``'s one argument
+    that is no operand.  ``static_offsets``: the two offsets where the
+    caller gave Python ints, for the build-time tile counters only.
+    ``rope``, ``n_kv_heads``, ``window``, ``turn``, ``norm``: ``_Plan``'s.
+    ``return_q``: the call's third result is the q its scores saw."""
+
+    sm_scale: float
+    causal: bool
+    block_q: int
+    block_k: int
+    interpret: Optional[bool]
+    n_heads: int = 0
+    static_offsets: Optional[Tuple[int, int]] = None
+    rope: int = 0
+    n_kv_heads: int = 0
+    window: int = 0
+    turn: Optional[Tuple[int, int, bool]] = None
+    norm: Optional[float] = None
+    return_q: bool = False
+
+    def plan(self, q, k, v, keep) -> _Plan:
+        return _plan(
+            q, k, v, causal=self.causal, block_q=self.block_q,
+            block_k=self.block_k, interpret=self.interpret,
+            n_heads=self.n_heads, rope=self.rope,
+            n_kv_heads=self.n_kv_heads, window=self.window, turn=self.turn,
+            select=keep is not None, norm=self.norm,
+        )
+
+
+def _fwd_pallas(q, k, v, q_offset, kv_offset, rot, keep, scale,
+                st: _Static):
     """Run the kernel.
 
     Head-major mode (``n_heads=0``): q ``[B,H,Sq,D]``, k/v ``[B,H,Skv,D]``
@@ -1087,26 +1180,22 @@ def _fwd_pallas(
     axis): k/v hold that many heads, each shared by ``H / n_kv_heads``
     query heads.  ``window``: see :func:`flash_attention_with_lse`.
 
-    ``static_offsets``: the two offsets where the caller gave Python
-    ints, for the build-time tile counters only.
-
-    ``rot`` / ``turn`` ("Rotary at the door"): q is unrotated, and a third
-    result is the turned q, shaped like q: the backward's residual.
+    ``rot`` / ``turn`` ("Rotary at the door") and ``scale`` / ``norm``
+    ("Norm at the door"): q is unrotated / the projection's output, and a
+    third result is the q the scores saw, shaped like q: the backward's
+    residual.
 
     ``keep``: see :func:`flash_attention_with_lse`.
     """
-    p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-              interpret=interpret, n_heads=n_heads, rope=rope,
-              n_kv_heads=n_kv_heads, window=window, turn=turn,
-              select=keep is not None)
-    if causal:
-        _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=False))
+    p = st.plan(q, k, v, keep)
+    if st.causal:
+        _book_tiles(st.static_offsets, **p.tile_geometry(guard_q_pad=False))
     if p.dv != p.d:
         _registry.always().counter("flash.calls.split_widths").inc()
     _book_call_kinds(p, 1)
     return _flash_fwd_call(
-        q, k, v, _geometry(q_offset, kv_offset, p.skv), rot, keep,
-        p=p, sm_scale=sm_scale, causal=causal,
+        q, k, v, _geometry(q_offset, kv_offset, p.skv), rot, keep, scale,
+        p=p, sm_scale=st.sm_scale, causal=st.causal,
     )
 
 
@@ -1118,8 +1207,8 @@ def _fwd_pallas(
 @functools.partial(
     jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
 )
-def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
-                    sm_scale: float, causal: bool):
+def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, scale=None, *,
+                    p: _Plan, sm_scale: float, causal: bool):
     b, h, d, dv, group, rope = p.b, p.h, p.d, p.dv, p.group, p.rope
     block_q, block_k, sq_pad, skv_pad = (
         p.block_q, p.block_k, p.sq_pad, p.skv_pad
@@ -1129,7 +1218,9 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
         kr = p.pad_seq(k, p.skv, skv_pad)
         vr = p.pad_seq(v, p.skv, skv_pad)
         turned = [] if p.turn is None else [_rot_rows(rot, p)]
+        normed = [] if p.norm is None else [scale]
         kept = [] if keep is None else [_keep_blocks(keep, p)]
+        seen = [jax.ShapeDtypeStruct(qr.shape, qr.dtype)] if p.door else []
 
     def kv_block(qi, kj, geom):
         if not causal:
@@ -1174,7 +1265,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
             _fwd_kernel, sm_scale=sm_scale, causal=causal,
             masked=causal or skv_pad != p.skv, tiles=p.tiles,
             packed=p.packed, d=d, dv=dv, rope=rope, **_kind_params(p),
-            turn=p.turn,
+            turn=p.turn, norm=p.norm,
         ),
         grid_spec=_grid_spec(
             causal,
@@ -1187,7 +1278,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
                     (block_q, x.shape[1]),
                     lambda bi, hi, qi, kj, *geom: (qi, 0),
                 ) for x in turned
-            ] + [
+            ] + [_vspec(x.shape, lambda *_: (0, 0)) for x in normed] + [
                 _vspec(
                     (1, block_k, block_q),
                     lambda bi, hi, qi, kj, *geom: (
@@ -1200,7 +1291,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
                     (1, group, 8, block_q),
                     lambda bi, hi, qi, kj, *geom: (bi, hi, 0, qi),
                 ),
-            ] + [q_side(d) for _ in turned],
+            ] + [q_side(d) for _ in seen],
             scratch_shapes=[
                 _VMEM((group, dv, block_q), jnp.float32),
                 _VMEM((group, 1, block_q), jnp.float32),
@@ -1210,7 +1301,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
         out_shape=[
             o_shape,
             jax.ShapeDtypeStruct((b, h, 8, sq_pad), jnp.float32),
-        ] + [jax.ShapeDtypeStruct(qr.shape, qr.dtype) for _ in turned],
+        ] + seen,
         # batch/head/qi programs are independent; only the K/V stream (kj)
         # carries state — lets Mosaic parallelize/pipeline the outer grid.
         compiler_params=_compiler_params(p),
@@ -1222,7 +1313,7 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, *, p: _Plan,
         ),
         interpret=p.interpret,
         name=_kernel_name("hvd_flash_fwd", p),
-    )(*geom, qr, kr, vr, *turned, *kept)
+    )(*geom, qr, kr, vr, *turned, *normed, *kept)
 
     with jax.named_scope(_GLUE_SCOPE):
         if p.packed:
@@ -1464,6 +1555,7 @@ def _bwd_kernel_dq(
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
     rope: int = 0, kv_shared: bool = False, band: Optional[_Plan] = None,
     turn: Optional[Tuple[int, int, bool]] = None, select: bool = False,
+    norm: Optional[float] = None,
 ):
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
@@ -1471,12 +1563,20 @@ def _bwd_kernel_dq(
     forward.  With ``turn`` ("Rotary at the door") q_ref holds the turned
     q the forward wrote, the refs after g_ref are ``rot_ref, dq_ref,
     dq_acc``, and each head's gradient is turned back where it is written:
-    dq_ref takes the gradient of the unrotated q.  With ``select`` the
-    mask's block comes before dq_ref."""
+    dq_ref takes the gradient of the unrotated q.  With ``norm`` ("Norm at
+    the door") ``raw_ref, scale_ref`` come behind rot_ref, the projection's
+    q block and the ``[1, d]`` scale, and ``dscale_ref`` behind dq_ref: the
+    gradient, turned back, goes through the norm's backward where it is
+    written, and dscale_ref, ``[1, 1, 1, 1, d]`` float32, takes the
+    program's part of the scale's.  With ``select`` the mask's block comes
+    before dq_ref."""
     refs = list(refs)
     rot_ref = refs.pop(0) if turn is not None else None
+    raw_ref, scale_ref = (
+        (refs.pop(0), refs.pop(0)) if norm is not None else (None, None)
+    )
     keep_ref = refs.pop(0) if select else None
-    dq_ref, dq_acc = refs
+    dq_ref, *dscale_ref, dq_acc = refs
     qi = pl.program_id(2)
     kj = step = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -1512,7 +1612,7 @@ def _bwd_kernel_dq(
 
     @pl.when(step == nk - 1)
     def _finalize():
-        if turn is None:
+        if turn is None and norm is None:
             for g in range(group):
                 _head_store(
                     dq_ref, g, d, packed,
@@ -1521,6 +1621,7 @@ def _bwd_kernel_dq(
             return
         # the accumulators' rows are the block's lanes: turned tile by tile
         # as they are transposed, a tile's rows from one head or from two
+        dscale = 0.0
         for at, heads in _lane_views(packed, group):
             def rows(a, b, heads=heads):
                 """Lanes ``[a, b)`` of the heads side by side, as
@@ -1537,41 +1638,66 @@ def _bwd_kernel_dq(
                     dq_ref.dtype
                 )
 
-            _turn_lanes(
-                rows, put, dq_ref.shape[-1], len(heads), d, rot_ref[...],
-                turn, back=True,
-            )
+            # gz: the gradient of the normed q, float32 lanes kept until
+            # each head's are whole
+            gz = {}
+
+            def hold(a, b, x, gz=gz):
+                gz[a, b] = x
+
+            through = put if norm is None else hold
+            head_lanes = [(i * d, (i + 1) * d) for i in range(len(heads))]
+            if turn is None:
+                for a, b in head_lanes:
+                    through(a, b, rows(a, b))
+            else:
+                _turn_lanes(
+                    rows, through, dq_ref.shape[-1], len(heads), d,
+                    rot_ref[...], turn, back=True,
+                )
+            if norm is None:
+                continue
+            scale = scale_ref[...]
+            for a, b in head_lanes:
+                g_z = _lanes_of(gz, a, b)
+                x = raw_ref[(*at, slice(None), slice(a, b))].astype(
+                    jnp.float32
+                )
+                r = _inv_rms(x, norm)
+                y = x * r
+                g_y = g_z * scale
+                put(a, b, r * (
+                    g_y - y * jnp.mean(g_y * y, axis=1, keepdims=True)
+                ))
+                dscale = dscale + jnp.sum(g_z * y, axis=0, keepdims=True)
+        if norm is not None:
+            dscale_ref[0][0, 0, 0] = dscale
 
 
-def _bwd_pallas(
-    q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot=None,
-    keep=None, *,
-    sm_scale: float, causal: bool, block_q: int, block_k: int,
-    interpret: Optional[bool], n_heads: int = 0,
-    static_offsets: Optional[Tuple[int, int]] = None, rope: int = 0,
-    n_kv_heads: int = 0, window: int = 0,
-    turn: Optional[Tuple[int, int, bool]] = None,
-):
+def _bwd_pallas(q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot,
+                keep, raw, scale, st: _Static):
     """``(dq, dk, dv)``.  With ``rot`` / ``turn`` ("Rotary at the door")
     ``q`` is the forward's turned q and ``dq`` the gradient of the
-    unrotated one."""
-    p = _plan(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-              interpret=interpret, n_heads=n_heads, rope=rope,
-              n_kv_heads=n_kv_heads, window=window, turn=turn,
-              select=keep is not None)
-    if causal:
+    unrotated one.  With ``scale`` / ``norm`` ("Norm at the door") ``q`` is
+    the forward's normed q, ``raw`` the projection's output, ``dq`` its
+    gradient, and the scale's gradient a fourth result."""
+    p = st.plan(q, k, v, keep)
+    if st.causal:
         # one count for each of the two kernels
         for _ in range(2):
-            _book_tiles(static_offsets, **p.tile_geometry(guard_q_pad=True))
+            _book_tiles(
+                st.static_offsets, **p.tile_geometry(guard_q_pad=True)
+            )
     # dK/dV's accumulators: dK's (with rope its own columns only), dV's
     # and, with rope, the shared key's
-    accumulated = (p.d - rope, p.dv) + ((rope,) if rope else ())
+    accumulated = (p.d - p.rope, p.dv) + ((p.rope,) if p.rope else ())
     if any(_dkv_streams_thin(width) for width in accumulated):
         _registry.always().counter("flash.dkv.thin_streamed").inc()
     _book_call_kinds(p, 2)
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
-        g_lse, rot, keep, p=p, sm_scale=sm_scale, causal=causal,
+        g_lse, rot, keep, raw, scale, p=p, sm_scale=st.sm_scale,
+        causal=st.causal,
     )
 
 
@@ -1579,7 +1705,8 @@ def _bwd_pallas(
     jax.jit, static_argnames=("p", "sm_scale", "causal"), inline=True
 )
 def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
-                    keep=None, *, p: _Plan, sm_scale: float, causal: bool):
+                    keep=None, raw=None, scale=None, *, p: _Plan,
+                    sm_scale: float, causal: bool):
     b, h, d, dv, group, sq, skv = p.b, p.h, p.d, p.dv, p.group, p.sq, p.skv
     rope, n = p.rope, p.d - p.rope
     block_q, block_k, sq_pad, skv_pad = (
@@ -1618,6 +1745,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
         glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
         glse_rows = rows(glse.astype(jnp.float32), 0.0)
         turned = [] if p.turn is None else [_rot_rows(rot, p)]
+        normed = [] if p.norm is None else [p.pad_seq(raw, sq, sq_pad), scale]
         kept = [] if keep is None else [_keep_blocks(keep, p)]
 
     kernel_params = dict(
@@ -1768,117 +1896,98 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
     stat_spec, q_spec, k_spec, v_spec, g_spec, rot_spec, keep_spec = specs(
         "qk"
     )
+    dq_spec, dq_shape, norm_spec = q_spec, shape_like(q, sq_pad, d), []
+    if p.norm is not None:
+        # the raw q block and the scale in; the scale's gradient out, one
+        # float32 row a program
+        norm_spec = [q_spec, _vspec(scale.shape, lambda *_: (0, 0))]
+        programs = (b, h // group, sq_pad // block_q)
+        dq_spec = [q_spec, _vspec(
+            (1, 1, 1, 1, d), lambda bi, hi, qi, kj, *geom: (bi, hi, qi, 0, 0)
+        )]
+        dq_shape = [dq_shape, jax.ShapeDtypeStruct(
+            programs + (1, d), jnp.float32
+        )]
     dq = pl.pallas_call(
-        functools.partial(_bwd_kernel_dq, **kernel_params, turn=p.turn),
+        functools.partial(
+            _bwd_kernel_dq, **kernel_params, turn=p.turn, norm=p.norm
+        ),
         grid_spec=_grid_spec(
             causal,
             grid=(b, h // group, sq_pad // block_q, p.kv_steps),
             in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, k_spec, v_spec, g_spec] + rot_spec + keep_spec,
-            out_specs=q_spec,
+                      q_spec, k_spec, v_spec, g_spec] + rot_spec + norm_spec
+            + keep_spec,
+            out_specs=dq_spec,
             scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
         ),
-        out_shape=shape_like(q, sq_pad, d),
+        out_shape=dq_shape,
         **call_params,
         name=_kernel_name("hvd_flash_bwd_dq", p),
     )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *turned,
-      *kept)
+      *normed, *kept)
 
     with jax.named_scope(_GLUE_SCOPE):
+        dscale = []
+        if p.norm is not None:
+            dq, rows = dq
+            dscale = [rows.sum(axis=(0, 1, 2))]  # [1, d], as the scale
         if p.packed:
             return (
                 dq[:, :sq].astype(q.dtype),
                 grad_k[:, :skv].astype(k.dtype),
                 grad_v[:, :skv].astype(v.dtype),
+                *dscale,
             )
         return (
             dq[:, :, :sq].astype(q.dtype),
             grad_k[:, :, :skv].astype(k.dtype),
             grad_v[:, :, :skv].astype(v.dtype),
+            *dscale,
         )
 
 
-@functools.partial(
-    jax.custom_vjp,
-    nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17),
-)
-def _flash(q, k, v, q_offset, kv_offset, rot, keep, sm_scale, causal,
-           block_q, block_k, interpret, n_heads=0, static_offsets=None,
-           rope=0, n_kv_heads=0, window=0, turn=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _flash(q, k, v, q_offset, kv_offset, rot, keep, scale, st: _Static):
     """``(out, lse)`` with the exact backward.  With ``rope`` the operands
     ``k`` and ``v`` are the packed ``kv`` and the shared key (``_k_head``),
     and so are their cotangents.  ``rot`` (None, or with ``turn`` the
     rotation's table: "Rotary at the door"): q is unrotated, and so is its
-    cotangent.  ``keep`` (None, or the int8 mask): a constant of the call."""
-    return _fwd_pallas(
-        q,
-        k,
-        v,
-        q_offset,
-        kv_offset,
-        rot,
-        keep,
-        sm_scale=sm_scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        interpret=interpret,
-        n_heads=n_heads,
-        static_offsets=static_offsets,
-        rope=rope,
-        n_kv_heads=n_kv_heads,
-        window=window,
-        turn=turn,
-    )[:2]
+    cotangent.  ``scale`` (None, or with ``norm`` the head-wise norm's
+    float32 ``[1, d]`` scale: "Norm at the door"): q is the projection's
+    output, and so is its cotangent; the scale takes its own.  ``keep``
+    (None, or the int8 mask): a constant of the call.  With ``return_q`` a
+    third result is the q the scores saw, a constant to the caller: what
+    comes back for it is not read."""
+    out, lse, *seen = _fwd_pallas(
+        q, k, v, q_offset, kv_offset, rot, keep, scale, st
+    )
+    if st.return_q:  # the call's own q where the kernels wrote none
+        return (out, lse, *seen, q)[:3]
+    return out, lse
 
 
-def _flash_fwd(q, k, v, q_offset, kv_offset, rot, keep, sm_scale, causal,
-               block_q, block_k, interpret, n_heads=0, static_offsets=None,
-               rope=0, n_kv_heads=0, window=0, turn=None):
-    if turn is None:
-        out, lse = _flash(
-            q, k, v, q_offset, kv_offset, rot, keep, sm_scale, causal,
-            block_q, block_k, interpret, n_heads, static_offsets, rope,
-            n_kv_heads, window, turn
+def _flash_fwd(q, k, v, q_offset, kv_offset, rot, keep, scale, st: _Static):
+    if st.turn is None and st.norm is None:
+        results = _flash(q, k, v, q_offset, kv_offset, rot, keep, scale, st)
+        seen = q
+    else:  # the q the scores saw is kept in q's place
+        out, lse, seen = _fwd_pallas(
+            q, k, v, q_offset, kv_offset, rot, keep, scale, st
         )
-    else:  # the turned q is kept, and q is not
-        out, lse, q = _fwd_pallas(
-            q, k, v, q_offset, kv_offset, rot, keep, sm_scale=sm_scale,
-            causal=causal, block_q=block_q, block_k=block_k,
-            interpret=interpret, n_heads=n_heads,
-            static_offsets=static_offsets, rope=rope, n_kv_heads=n_kv_heads,
-            window=window, turn=turn,
-        )
-    return (out, lse), (q, k, v, q_offset, kv_offset, rot, keep, out, lse)
+        results = (out, lse, seen) if st.return_q else (out, lse)
+    raw = None if st.norm is None else q  # the norm's backward reads it
+    return results, (
+        seen, k, v, q_offset, kv_offset, rot, keep, raw, scale, *results[:2]
+    )
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
-               static_offsets, rope, n_kv_heads, window, turn, res, g):
-    q, k, v, q_offset, kv_offset, rot, keep, out, lse = res
-    g_out, g_lse = g
-    dq, dk, dv = _bwd_pallas(
-        q,
-        k,
-        v,
-        q_offset,
-        kv_offset,
-        out,
-        lse,
-        g_out,
-        g_lse,
-        rot,
-        keep,
-        sm_scale=sm_scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        interpret=interpret,
-        n_heads=n_heads,
-        static_offsets=static_offsets,
-        rope=rope,
-        n_kv_heads=n_kv_heads,
-        window=window,
-        turn=turn,
+def _flash_bwd(st: _Static, res, g):
+    q, k, v, q_offset, kv_offset, rot, keep, raw, scale, out, lse = res
+    g_out, g_lse = g[:2]
+    dq, dk, dv, *dscale = _bwd_pallas(
+        q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot, keep,
+        raw, scale, st,
     )
     # Integer offsets and the int8 mask take float0 cotangents; the tables
     # are constants.
@@ -1887,7 +1996,8 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, n_heads,
             None if rot is None else jnp.zeros_like(rot),
             None if keep is None else np.zeros(
                 keep.shape, dtype=jax.dtypes.float0
-            ))
+            ),
+            dscale[0] if dscale else None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1915,6 +2025,35 @@ class QRotary(NamedTuple):
     sin: Any
     halves: bool = False
     start: int = 0
+
+
+class QNorm(NamedTuple):
+    """``q_norm=`` of the ``q, k, v`` entries: q arrives as its projection
+    leaves it and the kernels norm each head of it ("Norm at the door").
+
+    ``scale``: float32 ``[d]``, one learned scale over a head's ``d``
+    columns, every head's alike (``models.transformer.RMSNorm`` on ``[B, S,
+    H, d]``); ``eps``: a Python float.  The function is that of ``RMSNorm``
+    on q's heads, then ``rotary`` where ``q_rotary`` is given too, followed
+    by the same call without the argument, with the normed (and turned) q
+    rounded to q's dtype once; the cotangent of q is that of the
+    projection's output, and ``scale`` takes its own, in float32."""
+
+    scale: Any
+    eps: float = 1e-6
+
+
+def _norm_operand(q_norm: QNorm, d: int):
+    """``(scale, eps)`` of a :class:`QNorm` for q heads ``d`` wide: the
+    kernels' float32 ``[1, d]`` operand and the static ``eps``."""
+    scale, eps = q_norm
+    if jnp.shape(scale) != (d,) or not isinstance(eps, (int, float)):
+        raise ValueError(
+            f"q_norm: scale {jnp.shape(scale)} has to be [head width {d}] "
+            f"and eps a Python float (got {eps!r})"
+        )
+    with jax.named_scope(_GLUE_SCOPE):
+        return jnp.asarray(scale, jnp.float32).reshape(1, d), float(eps)
 
 
 def _rotary_operand(q_rotary: QRotary, sq: int, d: int, compiled: bool):
@@ -1955,8 +2094,10 @@ def _rotary_operand(q_rotary: QRotary, sq: int, d: int, compiled: bool):
 
 def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
                 block_k, interpret, n_heads, rope=0, n_kv_heads=0,
-                window=None, q_rotary=None, keep=None):
-    """``_flash`` on a public entry's arguments: ``(out, lse)``."""
+                window=None, q_rotary=None, keep=None, q_norm=None,
+                return_q=False):
+    """``_flash`` on a public entry's arguments: ``(out, lse)``, and with
+    ``return_q`` the q the scores saw, a constant."""
     # Offsets given as Python ints (the model path: 0, 0) are also kept
     # static, for the build-time tile counters; the kernels read the
     # traced scalars either way.
@@ -1970,35 +2111,30 @@ def _call_flash(q, k, v, q_offset, kv_offset, sm_scale, causal, block_q,
         sq = q.shape[1 if n_heads else 2]
         if window >= static_offsets[0] + sq - static_offsets[1]:
             window = 0
-    rot = turn = None
+    rot = turn = scale = norm = None
+    d = q.shape[-1] // (n_heads or 1)
     if q_rotary is not None:
         compiled = not (
             interpret if interpret is not None else _use_interpret()
         )
         rot, turn = _rotary_operand(
-            QRotary(*q_rotary), q.shape[1 if n_heads else 2],
-            q.shape[-1] // (n_heads or 1), compiled,
+            QRotary(*q_rotary), q.shape[1 if n_heads else 2], d, compiled
         )
-    return _flash(
-        q,
-        k,
-        v,
-        jnp.asarray(q_offset, jnp.int32),
-        jnp.asarray(kv_offset, jnp.int32),
-        rot,
-        keep,
-        float(sm_scale),
-        bool(causal),
-        int(block_q),
-        int(block_k),
-        interpret,
-        int(n_heads),
-        static_offsets,
-        rope,
-        int(n_kv_heads),
-        window,
-        turn,
+    if q_norm is not None:
+        scale, norm = _norm_operand(QNorm(*q_norm), d)
+    results = _flash(
+        q, k, v, jnp.asarray(q_offset, jnp.int32),
+        jnp.asarray(kv_offset, jnp.int32), rot, keep, scale,
+        _Static(
+            float(sm_scale), bool(causal), int(block_q), int(block_k),
+            interpret, int(n_heads), static_offsets, rope, int(n_kv_heads),
+            window, turn, norm, bool(return_q),
+        ),
     )
+    if return_q:
+        with jax.named_scope(_GLUE_SCOPE):
+            return (*results[:2], lax.stop_gradient(results[2]))
+    return results
 
 
 def flash_attention_with_lse(
@@ -2019,7 +2155,9 @@ def flash_attention_with_lse(
     window: Optional[int] = None,
     q_rotary: Optional[QRotary] = None,
     keep=None,
-) -> Tuple[jax.Array, jax.Array]:
+    q_norm: Optional[QNorm] = None,
+    return_q: bool = False,
+) -> Tuple[jax.Array, ...]:
     """Blockwise attention returning ``(out, lse)``.
 
     ``layout="bshd"`` (default): q ``[B, Sq, H, D]``, k/v
@@ -2057,6 +2195,14 @@ def flash_attention_with_lse(
 
     ``q_rotary`` (:class:`QRotary`): q is passed unrotated and rotated in
     the kernels; k is passed rotated.
+
+    ``q_norm`` (:class:`QNorm`): q is passed as its projection leaves it and
+    each head is RMS-normed in the kernels, ahead of the rotation; k is
+    passed normed.  ``return_q``: ``(out, lse, q_seen)``, the q the scores
+    saw (normed, rotated, as the kernels rounded it; q itself without
+    either) in q's layout and under ``stop_gradient``: what a second reader
+    of the attention's own operand takes (``ops/dsa_kernels.dsa_index_loss``)
+    where XLA would norm and rotate q once more.
 
     ``keep`` (needs ``causal=True``): int8 ``[B, Skv, Sq]``, keys by queries
     as the kernels hold their scores; row ``i`` of every head sees column
@@ -2130,16 +2276,17 @@ def flash_attention_with_lse(
             q = jnp.moveaxis(q, 2, 1)
             k = jnp.moveaxis(k, 2, 1)
             v = jnp.moveaxis(v, 2, 1)
-    out, lse = _call_flash(
+    out, lse, *q_seen = _call_flash(
         q, k, v, q_offset, kv_offset, sm_scale, causal, block_q, block_k,
         interpret, n_heads if packed else 0,
         n_kv_heads=h_kv if packed and h_kv != h else 0, window=window,
-        q_rotary=q_rotary, keep=keep,
+        q_rotary=q_rotary, keep=keep, q_norm=q_norm, return_q=return_q,
     )
     if layout == "bshd":
         with jax.named_scope(_GLUE_SCOPE):
             out = jnp.moveaxis(out, 1, 2)
-    return out, lse
+            q_seen = [jnp.moveaxis(x, 1, 2) for x in q_seen]
+    return (out, lse, *q_seen)
 
 
 def flash_attention(
@@ -2159,10 +2306,11 @@ def flash_attention(
     window: Optional[int] = None,
     q_rotary: Optional[QRotary] = None,
     keep=None,
+    q_norm: Optional[QNorm] = None,
 ) -> jax.Array:
     """Drop-in memory-efficient replacement for
     ``models.transformer.dot_product_attention`` (same signature shape);
-    ``n_kv_heads``, ``window``, ``q_rotary`` and ``keep`` as
+    ``n_kv_heads``, ``window``, ``q_rotary``, ``keep`` and ``q_norm`` as
     :func:`flash_attention_with_lse`.
 
     Dense ``mask`` is not supported by the blockwise kernel — callers that
@@ -2188,6 +2336,7 @@ def flash_attention(
         window=window,
         q_rotary=q_rotary,
         keep=keep,
+        q_norm=q_norm,
     )
     return out
 

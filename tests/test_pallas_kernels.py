@@ -1637,3 +1637,277 @@ def test_rotary_tables_that_do_not_fit_q_are_refused(bad, match):
             q, q, q, causal=True, layout="bsm", n_heads=2, q_rotary=tables,
             interpret=not bad.get("compiled", False),
         )
+
+
+# ---------------------------------------------------------------------------
+# Norm at the door (``q_norm=``): q enters as its PROJECTION leaves it, the
+# forward norms each head in VMEM ahead of the turn, dQ leaves as the raw q's
+# gradient with the scale's beside it, dK/dV reads the forward's q.
+# ---------------------------------------------------------------------------
+
+# name: (layout, heads, K/V heads, head width, sq, block, rotary, keep)
+_NORM_CASES = {
+    "halves-16-groups": ("bsm", 4, 2, 16, 72, 32, "halves", False),
+    "halves-128-groups-keep": ("bsm", 4, 2, 128, 64, 32, "halves", True),
+    "halves-16-groups-keep-padded": ("bsm", 6, 2, 16, 50, 16, "halves", True),
+    "halves-128-padded": ("bsm", 2, 2, 128, 40, 16, "halves", False),
+    "pairs-16-head-major": ("bhsd", 2, 2, 16, 48, 16, "pairs", False),
+    "no-rotary-16-groups": ("bsm", 4, 2, 16, 48, 16, None, False),
+    "no-rotary-128-keep-bshd": ("bshd", 2, 1, 128, 40, 16, None, True),
+}
+_NORM_EPS = 1e-5
+
+
+def _norm_case(case, norm_in_kernels):
+    """Loss -> ((out, lse, q_seen), (dq, dk, dv, dscale)) of one case, either
+    the kernels norming (and rotating) q or ``RMSNorm`` (+ ``rotary``) in
+    front of the same call."""
+    from horovod_tpu.models.transformer import (
+        RMSNorm, rotary, rotary_tables,
+    )
+    from horovod_tpu.ops.pallas_kernels import QNorm, QRotary
+
+    layout, h, h_kv, d, sq, block, turn, masked = _NORM_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(29), 6)
+    q = jax.random.normal(keys[0], (1, sq, h, d))
+    k = jax.random.normal(keys[1], (1, sq, h_kv, d))
+    v = jax.random.normal(keys[2], (1, sq, h_kv, d))
+    w = jax.random.normal(keys[3], (1, sq, h, d))
+    scale = 1.0 + 0.3 * jax.random.normal(keys[4], (d,))
+    keep = None
+    if masked:  # keys by queries; the diagonal kept, so every row sees one
+        keep = ((jax.random.uniform(keys[5], (1, sq, sq)) < 0.5)
+                | jnp.eye(sq, dtype=bool)).astype(jnp.int8)
+    halves = turn == "halves"
+
+    def loss(q, k, v, scale):
+        kw = dict(causal=True, block_q=block, block_k=block, keep=keep,
+                  return_q=True)
+        if norm_in_kernels:
+            kw["q_norm"] = QNorm(scale, _NORM_EPS)
+            if turn:
+                kw["q_rotary"] = QRotary(
+                    *rotary_tables(sq, d, theta=1e4), halves=halves
+                )
+        else:
+            q = RMSNorm(_NORM_EPS, jnp.float32).apply(
+                {"params": {"scale": scale}}, q
+            )
+            if turn:
+                q = rotary(q, theta=1e4, halves=halves)
+        shaped = lambda x: _in_layout(x, layout)  # noqa: E731
+        out, lse, q_seen = flash_attention_with_lse(
+            shaped(q), shaped(k), shaped(v), layout=layout,
+            **(dict(n_heads=h, n_kv_heads=h_kv) if layout == "bsm" else {}),
+            **kw,
+        )
+        out = _from_layout(out, layout, d)
+        q_seen = _from_layout(q_seen, layout, d)
+        # q_seen is a constant: its term must add nothing to dq
+        return (jnp.sum(out * w) + 0.1 * jnp.sum(lse ** 2)
+                + jnp.sum(q_seen * w)), (out, lse, q_seen)
+
+    with jax.default_matmul_precision("highest"):
+        grads, results = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2, 3), has_aux=True)
+        )(q, k, v, scale)
+    return results, grads
+
+
+@pytest.mark.parametrize("case", list(_NORM_CASES))
+def test_flash_norms_q_as_rmsnorm_in_front_of_the_same_call(case):
+    """out, ``lse``, the q the scores saw, dq, dk, dv and the scale's
+    gradient from one trace, the kernels' own norm (and rotation) against
+    ``RMSNorm`` (+ ``rotary``) + the same entry without the arguments: heads
+    of 16 and 128, halves and pairs, query groups, with and without a
+    ``keep`` mask, padded lengths, every layout, the norm without a rotary.
+    dq is the gradient of the PROJECTION's output: the other side reaches
+    it by autodiff through ``rotary`` and ``RMSNorm``; what ``return_q``
+    hands back carries none."""
+    results, got = _norm_case(case, True)
+    ref_results, want = _norm_case(case, False)
+    for a, b in zip(results, ref_results):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+def _norm_calls(norm, *, rotary=True, interpret=False, return_q=False):
+    """The traced gradient and its three ``pallas_call`` equations at the
+    sparse cell's widths (16 query heads on 2 K/V heads of 128 under a
+    ``keep`` mask, halves), by kernel name."""
+    from horovod_tpu.models.transformer import rotary_tables
+    from horovod_tpu.ops.pallas_kernels import QNorm, QRotary
+
+    s = 1024
+    x = lambda w: jax.ShapeDtypeStruct((1, s, w), jnp.bfloat16)  # noqa: E731
+    shapes = (x(16 * 128), x(2 * 128), x(2 * 128),
+              jax.ShapeDtypeStruct((1, s, s), jnp.int8),
+              jax.ShapeDtypeStruct((128,), jnp.float32))
+
+    def loss(q, k, v, keep, scale):
+        kw = dict(q_norm=QNorm(scale, 1e-6)) if norm else {}
+        if rotary:
+            kw["q_rotary"] = QRotary(
+                *rotary_tables(s, 128, theta=1e7), halves=True
+            )
+        out, _, *q_seen = flash_attention_with_lse(
+            q, k, v, causal=True, layout="bsm", n_heads=16, n_kv_heads=2,
+            keep=keep, interpret=interpret, return_q=return_q, **kw
+        )
+        return out.astype(jnp.float32).sum() + sum(
+            x.astype(jnp.float32).sum() for x in q_seen
+        )
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 4)))(*shapes)
+    return traced, {
+        e.params["name"].replace("_select", ""): e
+        for e in _walk(traced.jaxpr) if e.primitive.name == "pallas_call"
+    }
+
+
+@pytest.mark.parametrize("rotary", [True, False], ids=["rotary", "alone"])
+def test_norm_runs_in_the_forward_and_dq_and_nowhere_else(rotary):
+    """With a scale: the forward takes it as ``[1, d]`` and writes a second
+    array shaped like q, the q its scores see, which dK/dV and dQ read in
+    q's place (dK/dV is the call it was: same operands, same body); dQ takes
+    the scale and the raw q besides and writes the raw q's gradient in q's
+    dtype, which leaves the program as it is, and the scale's gradient as
+    one float32 ``[1, d]`` row a program.  Without a rotary the forward
+    still writes that second array (a call without either writes none)."""
+    traced, calls = _norm_calls(True, rotary=rotary)
+    _, plain = _norm_calls(False, rotary=rotary)
+    assert sorted(calls) == sorted(plain) == [
+        "hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd"
+    ]
+    q_shape = (1, 1024, 2048)
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]  # noqa: E731
+    extra = lambda name: sorted(  # noqa: E731
+        set(shapes(calls[name].invars)) - set(shapes(plain[name].invars))
+    )
+    assert len(calls["hvd_flash_bwd_dkv"].invars) == len(
+        plain["hvd_flash_bwd_dkv"].invars
+    )
+    assert extra("hvd_flash_fwd") == extra("hvd_flash_bwd_dq") == [(1, 128)]
+    fwd, dq = calls["hvd_flash_fwd"], calls["hvd_flash_bwd_dq"]
+    # forward: q in, out and the seen q out
+    assert shapes(fwd.outvars).count(q_shape) == 2
+    assert shapes(plain["hvd_flash_fwd"].outvars).count(q_shape) == (
+        2 if rotary else 1
+    )
+    # dQ reads one more array of q's shape than without: the raw q
+    assert shapes(dq.invars).count(q_shape) == 1 + shapes(
+        plain["hvd_flash_bwd_dq"].invars
+    ).count(q_shape)
+    q_seen, raw_q = fwd.outvars[-1], traced.jaxpr.invars[0]
+    for name in ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"):
+        assert q_seen in calls[name].invars, name
+    assert raw_q in dq.invars and raw_q in fwd.invars
+    assert raw_q not in calls["hvd_flash_bwd_dkv"].invars
+    assert str(calls["hvd_flash_bwd_dkv"].params["jaxpr"]) == str(
+        plain["hvd_flash_bwd_dkv"].params["jaxpr"]
+    )
+    dq_out, dscale_rows = dq.outvars
+    assert dq_out.aval.dtype == jnp.bfloat16
+    assert tuple(dq_out.aval.shape) == q_shape
+    assert dq_out in traced.jaxpr.outvars  # as it leaves the kernel
+    # 16 heads in two groups of 8, two q blocks of 512: a row a program
+    assert tuple(dscale_rows.aval.shape) == (1, 2, 2, 1, 128)
+    assert dscale_rows.aval.dtype == jnp.float32
+    # the statistic: an rsqrt a head in the forward and in dQ, none in dK/dV
+    rsqrts = lambda e: sum(  # noqa: E731
+        1 for x in _walk(e.params["jaxpr"]) if x.primitive.name == "rsqrt"
+    )
+    assert rsqrts(fwd) == rsqrts(dq) == 8
+    assert rsqrts(calls["hvd_flash_bwd_dkv"]) == 0
+    assert not any(rsqrts(e) for e in plain.values())
+
+
+def test_return_q_hands_back_the_forwards_own_q():
+    """``return_q``: the third result is the forward kernel's second array
+    (under ``stop_gradient``: no equation of the backward reads a cotangent
+    of it), and asking for it changes no kernel; without a norm or a rotary
+    it is q itself."""
+    traced, calls = _norm_calls(True, return_q=True)
+    _, silent = _norm_calls(True, return_q=False)
+    for name, call in calls.items():
+        assert str(call.params["jaxpr"]) == str(silent[name].params["jaxpr"])
+    q, k, v = (jnp.ones((1, 32, 2, 16)) * c for c in (1.0, 0.5, 0.25))
+    out, lse, q_seen = flash_attention_with_lse(
+        q, k, v, causal=True, return_q=True, block_q=16, block_k=16
+    )
+    np.testing.assert_array_equal(q_seen, q)
+    two = flash_attention_with_lse(q, k, v, causal=True, block_q=16,
+                                   block_k=16)
+    assert len(two) == 2
+    np.testing.assert_array_equal(out, two[0])
+
+
+def test_a_call_without_a_norm_traces_what_it_traced():
+    """``q_norm=None`` (and ``return_q=False``) is the call without the
+    arguments, equation for equation, with and without tables and a mask,
+    compiled or interpreted (the parent's jaxprs themselves were compared
+    when the argument came: CHANGES.md, PR 48)."""
+    for interpret in (False, True):
+        for rotary in (False, True):
+            kw = dict(rotary=rotary, interpret=interpret)
+
+            def traced(**extra):
+                from horovod_tpu.models.transformer import rotary_tables
+                from horovod_tpu.ops.pallas_kernels import QRotary
+
+                def loss(q, k, v, keep):
+                    out = flash_attention(
+                        q, k, v, causal=True, layout="bsm", n_heads=4,
+                        n_kv_heads=2, keep=keep, interpret=interpret,
+                        q_rotary=QRotary(
+                            *rotary_tables(256, 128, theta=1e4), halves=True
+                        ) if rotary else None, **extra
+                    )
+                    return out.astype(jnp.float32).sum()
+
+                x = lambda w: jax.ShapeDtypeStruct(  # noqa: E731
+                    (1, 256, w), jnp.bfloat16
+                )
+                return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+                    x(512), x(256), x(256),
+                    jax.ShapeDtypeStruct((1, 256, 256), jnp.int8),
+                ))
+
+            assert traced() == traced(q_norm=None), kw
+
+
+def test_norm_q_counter_counts_the_kernels_that_norm():
+    from horovod_tpu.obs import registry
+
+    counter = registry.always().counter("flash.calls.norm_q")
+    turning = registry.always().counter("flash.calls.rotary_q")
+
+    def counted(norm, rotary):
+        before = counter.get(), turning.get()
+        _norm_calls(norm, rotary=rotary, interpret=True)
+        return counter.get() - before[0], turning.get() - before[1]
+
+    assert counted(False, False) == (0, 0)
+    assert counted(False, True) == (0, 2)
+    assert counted(True, True) == (2, 2)  # forward, dQ
+    assert counted(True, False) == (2, 0)
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(scale=jnp.ones((8,))), "head width 16"),
+    (dict(scale=jnp.ones((2, 16))), "head width 16"),
+    (dict(eps=jnp.float32(1e-6)), "Python float"),
+])
+def test_a_norm_that_does_not_fit_q_is_refused(bad, match):
+    from horovod_tpu.ops.pallas_kernels import QNorm
+
+    q = jnp.zeros((1, 32, 2 * 16))
+    with pytest.raises(ValueError, match=match):
+        flash_attention(
+            q, q, q, causal=True, layout="bsm", n_heads=2, interpret=True,
+            q_norm=QNorm(bad.get("scale", jnp.ones((16,))),
+                         bad.get("eps", 1e-6)),
+        )

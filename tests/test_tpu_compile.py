@@ -468,6 +468,117 @@ def test_flash_entries_with_rotary_tables_compile(v5e, cell):
         assert len(re.findall(q_shape, fwd)) == (2 if latent else 3)
 
 
+@pytest.mark.parametrize("cell", ["select-halves-1x8192x32",
+                                  "select-halves-s1000x8-padded"])
+def test_flash_entries_with_a_q_norm_compile(v5e, cell):
+    """``q_norm`` at the sparse cell's widths (32 query heads on 4 K/V heads
+    of 128, halves, under the int8 mask, 8 query heads a program) and at a
+    padded length: forward, dK/dV and dQ stay three calls; the forward
+    takes the projection's q and writes the q its scores see, dQ takes
+    both and the ``[1, 128]`` scale and writes q's gradient in q's dtype
+    and the scale's as float32 rows, one a program."""
+    from horovod_tpu.models.transformer import rotary_tables
+
+    padded = cell.endswith("padded")
+    b, s, h, h_kv = (2, 1000, 8, 2) if padded else (1, 8192, 32, 4)
+
+    def loss(q, k, v, keep, scale):
+        out, lse, q_seen = pk.flash_attention_with_lse(
+            q, k, v, causal=True, layout="bsm", n_heads=h, n_kv_heads=h_kv,
+            keep=keep, interpret=False, return_q=True,
+            q_rotary=pk.QRotary(
+                *rotary_tables(s, 128, theta=1e7), halves=True
+            ),
+            q_norm=pk.QNorm(scale, 1e-6),
+        )
+        return (out.astype(jnp.float32).sum() + (lse ** 2).sum()
+                + q_seen[:, 0].astype(jnp.float32).sum())
+
+    hlo = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 4)), v5e,
+        ((b, s, h * 128), jnp.bfloat16), ((b, s, h_kv * 128), jnp.bfloat16),
+        ((b, s, h_kv * 128), jnp.bfloat16), ((b, s, s), jnp.int8),
+        ((128,), jnp.float32),
+    )
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    s_pad = -(-s // 512) * 512
+    q_shape = rf"bf16\[{b},{s_pad},{h * 128}\]"
+    (fwd,) = [c for c in calls if "hvd_flash_fwd_select" in c]
+    (dq,) = [c for c in calls if "hvd_flash_bwd_dq_select" in c]
+    # forward: q in; out and the q the scores see out.  dQ: that q, the
+    # raw q and g in; dq out
+    assert len(re.findall(q_shape, fwd)) == 3
+    assert len(re.findall(q_shape, dq)) == 4
+    assert "f32[1,128]" in fwd and "f32[1,128]" in dq
+    # a K/V head's query heads are one program's
+    rows = rf"f32\[{b},{h_kv},{s_pad // 512},1,128\]"
+    assert re.search(rows, dq.split(" custom-call(")[0])
+    if not padded:  # q's cotangent is the dQ kernel's first result itself
+        entry = hlo[hlo.index("\nENTRY"):]
+        first = re.search(r"tuple\(%([\w.]+)", entry.split("ROOT ")[1])[1]
+        (made,) = [line for line in entry.splitlines()
+                   if line.lstrip().startswith(f"%{first} = ")]
+        assert re.search(
+            r"get-tuple-element\(%[\w.]*hvd_flash_bwd_dq[\w.]*\), index=0",
+            made,
+        ), made[:300]
+
+
+def test_sparse_layer_norms_and_rotates_q_nowhere_but_in_the_kernels(
+    v5e_topology, v5e
+):
+    """One ``GroupedAttention`` of the sparse cell (1 x 8,192 x 2,048, 32
+    query heads on 4 K/V heads of 128, q / k norms, rotary, an indexer of
+    16 x 64 keeping 2,048), ``value_and_grad`` compiled: the kernels norm
+    and rotate q at their door and the index loss reads the forward's
+    residual, so nothing of q's shape is left under ``attn_proj/q_norm``
+    (until PR 48 five float32 copies of 134 MB a layer among six fusions
+    that existed to feed them), XLA concatenates no rotated q, and no
+    float32 ``[.., 8192, 4096]`` is copied at all."""
+    import horovod_tpu as hvd
+    from horovod_tpu.models.window_moe import (
+        GroupedAttention, WindowMoEConfig,
+    )
+
+    attn = GroupedAttention(WindowMoEConfig(
+        d_model=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+        window_layout=(0,), rope_layout=(1,), rope_theta=1e7, qk_norm=True,
+        index_top_k=2048, index_heads=16, index_head_dim=64,
+        index_blocks=(512, 512),
+    ), rotate=True)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16)
+
+    def loss(params, x):
+        out, index_loss = attn.apply(params, x)
+        return out.astype(jnp.float32).sum() + index_loss
+
+    hvd.init(devices=v5e_topology.devices[:1])  # the world's devices: TPUs
+    try:
+        params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            (params, x),
+        )
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            *args
+        ).compile().as_text()
+    finally:
+        hvd.shutdown()
+    assert hlo.count("tpu_custom_call") == 5
+    assert params["params"]["q_norm"]["scale"].shape == (128,)
+    under_norm = [line for line in hlo.splitlines()
+                  if "attn_proj/q_norm" in line and "8192" in line]
+    assert not under_norm, under_norm[:3]
+    assert "attn_proj/k_norm" in hlo  # k's stays XLA's
+    rotated = [line for line in hlo.splitlines()
+               if "attn_proj/concatenate" in line
+               and re.search(r"\[1,8192,(32,128|32,64|4096)\]", line)]
+    assert not rotated, rotated[:3]
+    assert not re.findall(r"= f32\[(?:1,)?8192,4096\]\S* copy\(", hlo)
+
+
 def test_reglu_expert_layer_compiles_at_the_window_cell_shapes(v5e):
     """The expert layer of the window cell: 16,384 tokens, top-6 of 64 by
     the softmax over the chosen, 8 ReLU-gated experts held at 2560 x 768:

@@ -534,6 +534,109 @@ def test_the_two_stop_gradients(flash):
             assert index == 0.0 and ce > 0.0, name
 
 
+@pytest.mark.parametrize("rope_layout", [(1,), (0, 1)],
+                         ids=["rotated", "nope-then-rotated"])
+def test_select_model_through_the_kernels_equals_the_xla_path(rope_layout):
+    """Cross entropy, index loss and every leaf's gradient (``q_norm/scale``
+    and the indexer's among them): the flash path, whose kernels norm and
+    rotate q and hand the index loss the q their scores saw (interpreted),
+    against ``use_flash=False``'s ``RMSNorm`` and ``rotary`` in XLA; in the
+    second case layer 0 is not rotated, so its kernels norm without a
+    turn."""
+    cfg = _select_cfg(rope_layout=rope_layout)
+    params, tokens = _params(cfg, scale=3.0), _tokens(cfg, 4)
+
+    def run(flash):
+        parts = _select_losses(dataclasses.replace(cfg, use_flash=flash))
+        with jax.default_matmul_precision("highest"):
+            return parts(params, tokens), _leaves(
+                jax.grad(lambda p: sum(parts(p, tokens)))(params)
+            )
+
+    (got_ce, got_index), got = run(True)
+    (want_ce, want_index), want = run(False)
+    assert float(got_ce) == pytest.approx(float(want_ce), rel=1e-5)
+    assert float(got_index) == pytest.approx(float(want_index), rel=1e-4)
+    assert sorted(got) == sorted(want)
+    assert sum("q_norm/scale" in name for name in want) == cfg.n_layers
+    for name in want:
+        assert float(jnp.abs(want[name]).max()) > 0.0, name
+        np.testing.assert_allclose(
+            got[name], want[name], rtol=2e-3,
+            atol=2e-5 * float(jnp.abs(want[name]).max()) + 1e-9,
+            err_msg=name,
+        )
+
+
+def test_flash_path_norms_q_in_the_kernels_and_rotates_it_nowhere_else():
+    """A select layer with q / k norms on the flash path: the forward
+    kernel's q is ``dense("q")``'s matmul output and its scale the
+    ``q_norm/scale`` leaf as ``[1, d]``; the index-loss kernel reads the
+    forward's second array, the q the scores saw, so XLA neither norms nor
+    rotates anything at the query heads' width; the scale's gradient is
+    the sum of dQ's float32 rows; and the parameter tree is the XLA
+    path's."""
+    from horovod_tpu.analysis.jaxpr_walk import _sub_jaxprs_generic
+
+    cfg = _select_cfg(use_flash=True, n_layers=1)
+    attn = GroupedAttention(cfg, rotate=True)
+    x = jnp.zeros((1, SEQ, cfg.d_model), jnp.float32)
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)["params"]
+    off_flash = jax.eval_shape(
+        GroupedAttention(
+            dataclasses.replace(cfg, use_flash=False), rotate=True
+        ).init, jax.random.PRNGKey(0), x,
+    )["params"]
+    assert jax.tree.structure(params) == jax.tree.structure(off_flash)
+    assert _leaves(params)["q_norm/scale"].shape == (cfg.head_dim,)
+
+    def loss(params, x):
+        out, index_loss = attn.apply({"params": params}, x)
+        return out.sum() + index_loss
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in _sub_jaxprs_generic(eqn):
+                yield from walk(sub)
+
+    traced = jax.make_jaxpr(jax.grad(loss))(params, x)
+    calls = {e.params["name"]: e for e in walk(traced.jaxpr)
+             if e.primitive.name == "pallas_call"}
+    fwd, dq = calls["hvd_flash_fwd_select"], calls["hvd_flash_bwd_dq_select"]
+    wide = (1, SEQ, cfg.n_heads * cfg.head_dim)
+    made = {v: e for e in traced.jaxpr.eqns for v in e.outvars}
+    (raw_q,) = [v for v in fwd.invars if tuple(v.aval.shape) == wide]
+    assert made[raw_q].primitive.name == "dot_general"
+    assert raw_q in dq.invars
+    q_seen = fwd.outvars[-1]
+    assert tuple(q_seen.aval.shape) == wide
+    seen, readers = {q_seen}, []  # through the stop_gradients, as it is
+    for eqn in traced.jaxpr.eqns:
+        if any(type(v).__name__ == "Var" and v in seen for v in eqn.invars):
+            if eqn.primitive.name == "stop_gradient":
+                seen.update(eqn.outvars)
+            else:
+                readers.append(eqn.params["name"])
+    assert sorted(set(readers)) == [
+        "hvd_dsa_kl", "hvd_flash_bwd_dkv_select", "hvd_flash_bwd_dq_select"
+    ]
+    # nothing at the query heads' width is normed or rotated by XLA: no
+    # array [.., heads, d] or [.., heads, d / 2] of q's exists
+    h, d = cfg.n_heads, cfg.head_dim
+    by_head = [e for e in walk(traced.jaxpr) if e.primitive.name not in (
+        "pallas_call", "pjit") and any(
+        tuple(v.aval.shape) in ((1, SEQ, h, d), (1, SEQ, h, d // 2))
+        and v.aval.dtype == jnp.float32 for v in e.outvars)]
+    # (``rowsum(g * out)`` reads out by head: the flash entry's own glue)
+    assert all("attn_layout" in str(e.source_info.name_stack)
+               for e in by_head), by_head
+    (rows,) = [v for v in dq.outvars if v.aval.ndim == 5]
+    assert rows.aval.dtype == jnp.float32 and rows.aval.shape[-1] == d
+    (summed,) = [e for e in traced.jaxpr.eqns if rows in e.invars]
+    assert summed.primitive.name == "reduce_sum"
+
+
 def test_select_shares_add_up_to_the_uncut_layer():
     """One layer, 16 experts over 4 chips, selection on: what the four
     shares' experts give, with what every chip computes alike (attention

@@ -3,7 +3,7 @@
     python tools/bench_attention.py [--tree CHECKOUT] [--iters 8]
         [--heads 12] [--head-dim 64] [--v-head-dim 64] [--shape NAME]
         [--block-q N --block-k N] [--kv-heads N] [--window N]
-        [--rotary none|pairs|halves]
+        [--rotary none|pairs|halves] [--norm] [--keep SHARE]
 
 Runs forward + backward of ``flash_attention`` alone (one layer's worth) at
 the shapes of the benchmark's flash cells — GPT-2-small (16 x 1024, causal)
@@ -31,6 +31,15 @@ rotate q and turn dQ back; at ``latent`` a head's last 64 lanes, elsewhere
 the whole head), checked against float32 attention on q rotated by
 ``models.transformer.rotary``: the two rows' difference is what the turn
 costs the kernels; a tree without the argument prints the plain rows only.
+``--norm`` adds, after each of those rows, a row ``..+norm`` of the same call
+given ``q_norm`` (the kernels norm each head of q ahead of the turn and dQ
+leaves as the raw q's gradient), checked against float32 attention on q
+normed as ``models.transformer.RMSNorm`` norms it: what the norm costs the
+kernels is the difference to the row before. ``select-1x8192`` is the
+sparse-attention cell's attention (1 x 8,192, causal, 32 query heads on 4 K/V
+heads of 128); ``--keep SHARE`` hands every call an int8 ``keep`` mask that
+keeps that share of the entries at random and the diagonal (the kernels are
+then the ``_select`` ones; the cell keeps 0.4375 of its causal entries).
 ``--tree`` imports
 ``horovod_tpu`` from another checkout (a parent commit unpacked beside
 this one), so two commits can be timed in one chip call. This is where a
@@ -55,9 +64,11 @@ KERNELS = ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd")
 SHAPES = {"gpt2-16x1024-causal": (16, 1024, True, 12, 64, 64, 0),
           "bert-32x512": (32, 512, False, 12, 64, 64, 0),
           "latent": (2, 4096, True, 32, 192, 128, 64),
-          "window-1x16384": (1, 16384, True, 28, 128, 128, 0)}
+          "window-1x16384": (1, 16384, True, 28, 128, 128, 0),
+          "select-1x8192": (1, 8192, True, 32, 128, 128, 0)}
 # name: (K/V heads, window) where they are not the query heads' and none
-GROUPED = {"window-1x16384": (4, 4096)}
+GROUPED = {"window-1x16384": (4, 4096), "select-1x8192": (4, None)}
+EPS = 1e-6
 
 
 def built_keys(kv, k_rope, heads, n):
@@ -74,8 +85,10 @@ def built_keys(kv, k_rope, heads, n):
     return k.reshape(b, s, -1), kv[..., n:].reshape(b, s, -1)
 
 
-def reference(q, k, v, w, causal, heads, kv_heads=None, window=None):
-    """Loss of float32 attention written out in ``jax.numpy``."""
+def reference(q, k, v, w, causal, heads, kv_heads=None, window=None,
+              keep=None):
+    """Loss of float32 attention written out in ``jax.numpy``; ``keep``:
+    the int8 mask, keys by queries."""
     b, s, _ = v.shape
     kv_heads = kv_heads or heads
     split = lambda x, n: x.astype(jnp.float32).reshape(b, s, n, -1)  # noqa: E731
@@ -87,6 +100,8 @@ def reference(q, k, v, w, causal, heads, kv_heads=None, window=None):
     if causal:
         ahead = np.arange(s)[:, None] - np.arange(s)[None, :]
         valid = (ahead >= 0) & (True if not window else ahead < window)
+        if keep is not None:
+            valid = valid & (keep.swapaxes(1, 2) != 0)[:, None]
         scores = jnp.where(valid, scores, -jnp.inf)
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v,
@@ -147,6 +162,10 @@ def main():
     ap.add_argument("--window", type=int, help="0: none")
     ap.add_argument("--rotary", choices=("none", "pairs", "halves"),
                     default="none", help="also time each entry with q_rotary")
+    ap.add_argument("--norm", action="store_true",
+                    help="also time each of those rows with q_norm")
+    ap.add_argument("--keep", type=float,
+                    help="share of the entries a random keep mask keeps")
     args = ap.parse_args()
     sys.path.insert(0, args.tree)
     from horovod_tpu.ops import pallas_kernels
@@ -175,44 +194,77 @@ def main():
             blocks["n_kv_heads"] = kv_heads
         if window:
             blocks["window"] = window
+        keep = None
+        if args.keep is not None:
+            keep = (jax.random.uniform(jax.random.PRNGKey(1), (b, s, s))
+                    < args.keep) | jnp.eye(s, dtype=bool)
+            keep = keep.astype(jnp.int8)
 
-        def qkv(q, k, v, **rotary):
-            return pallas_kernels.flash_attention(
-                q, k, v, causal=causal, layout="bsm", n_heads=heads,
-                **blocks, **rotary
+        def kept(length):
+            """The mask's keyword for operands of ``length`` positions."""
+            return {} if keep is None else dict(
+                keep=keep[:2, :length, :length]
             )
 
-        def latent(q, kv, k_rope, **rotary):
+        def qkv(q, k, v, **door):
+            return pallas_kernels.flash_attention(
+                q, k, v, causal=causal, layout="bsm", n_heads=heads,
+                **blocks, **kept(q.shape[1]), **door
+            )
+
+        def latent(q, kv, k_rope, **door):
             return pallas_kernels.flash_attention_latent(
                 q, kv, k_rope, causal=causal, n_heads=heads, **blocks,
-                **rotary
+                **door
             )[0]
+
+        from horovod_tpu.models import transformer
 
         # the rotated lanes of a q head: its last ``rope``, or all of it
         turned = rope or d
-        variants = [("", None)]
+        halves = args.rotary == "halves"
+        scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (d,))
+
+        def rotary_tables(length):
+            return dict(q_rotary=pallas_kernels.QRotary(
+                *transformer.rotary_tables(length, turned, theta=1e6),
+                halves=halves, start=d - turned,
+            ))
+
+        def rotated(q):
+            """q as ``rotary`` turns it, for the float32 loss."""
+            q4 = q.astype(jnp.float32).reshape(*q.shape[:2], heads, d)
+            return jnp.concatenate([
+                q4[..., :d - turned], transformer.rotary(
+                    q4[..., d - turned:], theta=1e6, halves=halves
+                ),
+            ], axis=-1).reshape(q.shape)
+
+        def normed(q):
+            """q as ``RMSNorm`` norms its heads, for the float32 loss."""
+            q4 = q.astype(jnp.float32).reshape(*q.shape[:2], heads, d)
+            q4 = q4 * jax.lax.rsqrt(
+                jnp.mean(q4 * q4, axis=-1, keepdims=True) + EPS
+            ) * scale
+            return q4.reshape(q.shape)
+
+        # (the row's suffix, the entry's keywords for a length, q as the
+        # float32 loss takes it)
+        variants = [("", lambda length: {}, lambda q: q)]
         if args.rotary != "none" and hasattr(pallas_kernels, "QRotary"):
-            from horovod_tpu.models import transformer
-
-            halves = args.rotary == "halves"
-            variants.append(("+rotary", lambda length: dict(
-                q_rotary=pallas_kernels.QRotary(
-                    *transformer.rotary_tables(length, turned, theta=1e6),
-                    halves=halves, start=d - turned,
-                )
-            )))
-
-            def rotated(q):
-                """q as ``rotary`` turns it, for the float32 loss."""
-                q4 = q.astype(jnp.float32).reshape(*q.shape[:2], heads, d)
-                return jnp.concatenate([
-                    q4[..., :d - turned], transformer.rotary(
-                        q4[..., d - turned:], theta=1e6, halves=halves
-                    ),
-                ], axis=-1).reshape(q.shape)
+            variants.append(("+rotary", rotary_tables, rotated))
+        if args.norm and hasattr(pallas_kernels, "QNorm"):
+            variants = [row for suffix, door, seen in variants for row in (
+                (suffix, door, seen),
+                (suffix + "+norm", lambda length, door=door: dict(
+                    door(length),
+                    q_norm=pallas_kernels.QNorm(scale, EPS),
+                ), lambda q, seen=seen: seen(normed(q))),
+            )]
 
         def exact(q, k, v, w):
-            return reference(q, k, v, w, causal, heads, kv_heads, window)
+            return reference(q, k, v, w, causal, heads, kv_heads, window,
+                             kept(q.shape[1]).get("keep"))
 
         # entry: (the kernels' call, operand widths, float32 loss)
         entries = {"qkv": (
@@ -225,8 +277,9 @@ def main():
                     q, *built_keys(kv, k_rope, heads, d - rope), w
                 ),
             )
-        for (entry, (call, widths, exact_loss)), (suffix, tables) in (
+        for (entry, (call, widths, exact_loss)), (suffix, door, seen) in (
             (e, v) for e in entries.items() for v in variants
+            if e[0] == "qkv" or "+norm" not in v[0]  # no latent q_norm
         ):
             keys = jax.random.split(jax.random.PRNGKey(0), 4)
             argv = [
@@ -235,14 +288,12 @@ def main():
             ]
             argv = [x.astype(jnp.bfloat16) for x in argv[:3]] + argv[3:]
 
-            def loss(*operands, call=call, tables=tables):
-                rotary = tables(operands[0].shape[1]) if tables else {}
-                out = call(*operands[:3], **rotary)
+            def loss(*operands, call=call, door=door):
+                out = call(*operands[:3], **door(operands[0].shape[1]))
                 return (out.astype(jnp.float32) * operands[3]).sum()
 
-            if tables:
-                exact_loss = (lambda q, *rest, f=exact_loss:  # noqa: E731
-                              f(rotated(q), *rest))
+            exact_loss = (lambda q, *rest, f=exact_loss, seen=seen:  # noqa: E731
+                          f(seen(q), *rest))
             flash = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
             us = kernel_us(flash, argv, args.iters)
             small = [x[:2, :1024] for x in argv]
@@ -254,6 +305,7 @@ def main():
             print(json.dumps(dict(
                 tree=args.tree, shape=shape, entry=entry + suffix,
                 heads=heads, head_dim=d, v_head_dim=dv, blocks=blocks,
+                keep=args.keep,
                 device_kind=device.device_kind, us_per_call=us,
                 total_us=sum(us.values()), grad_abs_err_vs_f32=errors,
             )), flush=True)
