@@ -74,6 +74,25 @@ def dot_product_attention(q, k, v, *, causal: bool, mask=None):
     return jnp.einsum("...hqk,...khd->...qhd", probs, v)
 
 
+def _packed_dot(y, kernel, dimension_numbers, precision=None,
+                preferred_element_type=None):
+    """The out projection's dot on the flash kernels' packed output:
+    ``[B, S, H, D] x [H, D, M]`` over ``(H, D)`` as ``[B, S, H D] x [H D,
+    M]``, y as the kernels wrote it.  The same parameter tree and the same
+    numbers; what changes is what XLA lays out: a 4-D y whose minor
+    dimension of 64 half-fills the lanes is kept S-minor, so the kernels'
+    ``out`` and its cotangent were each turned at the kernels' door, and
+    the backward kernels' row-major ``out`` would be a second residual
+    beside the turned one (PERF.md, PR 49)."""
+    del dimension_numbers  # DenseGeneral's, for the 4-D operands
+    b, s = y.shape[:2]
+    return jax.lax.dot_general(
+        y.reshape(b, s, -1), kernel.reshape(-1, kernel.shape[-1]),
+        (((2,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=preferred_element_type,
+    )
+
+
 class MultiHeadAttention(nn.Module):
     cfg: TransformerConfig
     attention_fn: Optional[Callable] = None
@@ -87,9 +106,9 @@ class MultiHeadAttention(nn.Module):
             (cfg.n_heads, head_dim), dtype=cfg.dtype, name=name,
             dot_general_cls=dg_cls,
         )
-        out = lambda axis: nn.DenseGeneral(  # noqa: E731
+        out = lambda axis, dot=None: nn.DenseGeneral(  # noqa: E731
             cfg.d_model, axis=axis, dtype=cfg.dtype, name="out",
-            dot_general_cls=dg_cls,
+            dot_general_cls=dg_cls, dot_general=dot,
         )
         with jax.named_scope("attn_proj"):
             q, k, v = dense("query")(x), dense("key")(x), dense("value")(x)
@@ -101,14 +120,14 @@ class MultiHeadAttention(nn.Module):
             if use_flash and mask is None and head_dim % 64 == 0:
                 from ..ops.pallas_kernels import flash_attention
 
-                # Packed ("bsm") path: merge the minor [H, D] dims with a
-                # FREE reshape and hand the kernel [B, S, H*D] — its native
-                # packed layout (heads sliced from the lane axis inside).
-                # No relayout exists anywhere on this path: the r4
-                # head-major variant moveaxis'd to [B,H,S,D], and XLA
-                # folded that transpose into the projection dots, which
-                # then ran at ~43% of MXU peak (measured before PR 1).
-                # Mosaic lane slicing needs 64-aligned offsets, so
+                # Packed ("bsm") path: merge the minor [H, D] dims and hand
+                # the kernel [B, S, H*D], its native packed layout (heads
+                # sliced from the lane axis inside).  Not free where D is 64:
+                # XLA lays the 4-D projections out S-minor and sets 96
+                # transposing copies a step at the kernels' doors (PERF.md,
+                # PR 49).  The r4 head-major variant moveaxis'd to [B,H,S,D]
+                # and its projection dots ran at ~43% of MXU peak (before
+                # PR 1).  Mosaic lane slicing needs 64-aligned offsets, so
                 # head_dim % 64 != 0 keeps the head-major path below.
                 b, s = q.shape[0], q.shape[1]
                 with jax.named_scope("attn_layout"):
@@ -122,7 +141,7 @@ class MultiHeadAttention(nn.Module):
                 with jax.named_scope("attn_layout"):
                     y = y.reshape(b, s, cfg.n_heads, head_dim)
                 with jax.named_scope("attn_proj"):
-                    return out((-2, -1))(y)
+                    return out((-2, -1), _packed_dot)(y)
             if use_flash and mask is None:
                 from ..ops.pallas_kernels import flash_attention
 

@@ -91,9 +91,9 @@ __all__ = [
 _NEG_INF = float(np.finfo(np.float32).min)
 _LANES = 128
 # ``jax.named_scope`` of the flash entries' own XLA operations around the
-# three kernels (padding, the row statistics' layout, ``rowsum(g * out)``,
-# the slices back): the models' part ``attn_layout`` (docs/api.md). The
-# ``pallas_call``s themselves stay outside it, under their ``name=`` only.
+# three kernels (padding, the row statistics' layout, the slices back): the
+# models' part ``attn_layout`` (docs/api.md).  The ``pallas_call``s themselves
+# stay outside it, under their ``name=`` only.
 _GLUE_SCOPE = "attn_layout"
 
 
@@ -361,12 +361,17 @@ def _count_tiles(q_offset: int, kv_offset: int, *, sq: int, skv: int,
     return visited, masked, (sq_pad // tq) * (skv_pad // tk) - visited
 
 
-def _book_call_kinds(p: "_Plan", kernels: int) -> None:
+def _book_call_kinds(p: "_Plan", kernels: int,
+                     backward: bool = False) -> None:
     """Build-time counters of what kind of call ``kernels`` kernels were
     built for: ``flash.calls.latent_kv``, ``.windowed``, ``.grouped_kv``;
-    and ``flash.calls.rotary_q`` / ``flash.calls.norm_q``, one a kernel
-    that turns / norms (the forward and dQ: dK/dV reads the forward's q)."""
+    ``flash.calls.rotary_q`` / ``flash.calls.norm_q``, one a kernel that
+    turns / norms (the forward and dQ: dK/dV reads the forward's q); and
+    ``flash.calls.delta_q``, one a ``backward``: its dQ kernel makes the
+    row statistic ("Δ at the door")."""
     reg = _registry.always()
+    if backward:
+        reg.counter("flash.calls.delta_q").inc()
     for name, on in (("latent_kv", p.rope), ("windowed", p.window),
                      ("grouped_kv", p.kv_ratio > 1)):
         if on:
@@ -1332,16 +1337,23 @@ def _flash_fwd_call(q, k, v, geom, rot=None, keep=None, scale=None, *,
 # per Q block.  Standard flash gradients, plus the ``g_lse`` term (``lse``
 # receives real cotangents through ring attention's combine weights):
 #     p  = exp(s - lse)           (masked)
-#     ds = p ⊙ (dP − Δ) + g_lse ⊙ p,   Δ = rowsum(g ⊙ out)
+#     ds = p ⊙ (dP − Δ'),   Δ' = rowsum(g ⊙ out) − g_lse
 #     dq = ds·K·scale, dk = dsᵀ·Q·scale, dv = pᵀ·g
+# The row statistic is made at dQ's door ("Δ at the door"): dQ, which holds
+# a q block's ``g`` in VMEM anyway, reads ``out``'s block beside it and on
+# the block's first K/V step takes ``Δ'`` a head in float32 (``_delta_rows``),
+# keeps it in its second OUTPUT block for its own tiles and writes it back in
+# the row statistics' layout; dK/dV runs after dQ and reads it there.  No
+# ``rowsum(g ⊙ out)`` is XLA's, so no float32 ``g`` or ``out`` exists
+# outside VMEM, and a score tile subtracts ONE vector.
 # Causal calls walk each block pair in the forward's compute tiles and
 # classes; both kernels take a q tile and loop over its K/V tiles (the
 # dk/dv accumulators do not care in which order their tiles are met).
 #
 # Both kernels hold their score tile keys-by-queries like the forward,
 # ``sᵀ = K·Qᵀ`` and ``dPᵀ = V·gᵀ`` as ``[cols, rows]``: the row statistics
-# (``lse``, ``Δ``, ``g_lse``), stored with the rows on lanes, are
-# ``[1, rows]`` reads that broadcast over sublanes as they lie.
+# (``lse``, ``Δ'``), stored with the rows on lanes, are ``[1, rows]`` reads
+# that broadcast over sublanes as they lie.
 #
 # All three accumulating products stream their THIN operand and leave the
 # score-sized one standing in the MXU: dk/dv accumulates ``dvᵀ = gᵀ·p`` and
@@ -1375,14 +1387,50 @@ def _dkv_streams_thin(d: int) -> bool:
     return d < _LANES
 
 
-def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
+def _delta_rows(g_ref, out_ref, glse_ref, delta_ref, *, group: int, dv: int,
+                packed: bool):
+    """``Δ' = rowsum(g ⊙ out) − g_lse`` of a q block into delta_ref, a head
+    a ``[1, block_q]`` row as the statistics lie (written to every sublane
+    of the head's ``[8, block_q]``): float32 products, turned so that the
+    float32 sum runs over sublanes and the rows land on the lanes.  What is
+    turned is a vreg's 128 lanes where the heads fill them whole: the heads
+    of a packed block that share a lane tile (two of 64) go through one
+    turn and no lane shift, and a head wider than the lanes adds its lane
+    tiles up first; any other width is turned a head at a time."""
+    for at, heads in _lane_views(packed, group):
+        width = len(heads) * dv
+        shared = dv < _LANES and _LANES % dv == 0 and width % _LANES == 0
+        span = _LANES if shared else dv
+        for a in range(0, width, span):
+            lanes = (*at, slice(None), slice(a, a + span))
+            products = (
+                g_ref[lanes].astype(jnp.float32)
+                * out_ref[lanes].astype(jnp.float32)
+            )  # [block_q, span]
+            if span > _LANES and span % _LANES == 0:
+                products = functools.reduce(jnp.add, [
+                    products[:, t:t + _LANES]
+                    for t in range(0, span, _LANES)
+                ])
+            turned = products.T  # [lanes, block_q]
+            for i in range(span // dv):
+                g = heads[a // dv + i]
+                head = turned[i * dv:(i + 1) * dv] if shared else turned
+                row = jnp.sum(head, axis=0, keepdims=True)
+                delta_ref[0, g] = jnp.broadcast_to(
+                    row - glse_ref[0, g, 0:1, :], delta_ref.shape[2:]
+                )
+
+
+def _recompute_p_ds(lse_ref, delta_ref, q_ref, k_ref, v_ref,
                     g_ref, g, rq, rk, valid, *, sm_scale: float,
                     packed: bool = False, d: int = 0, dv: int = 0,
                     rope: int = 0, kv_shared: bool = False):
     """Shared per-(q rows ``rq``, K/V rows ``rk``, head) recompute:
     returns (pᵀ, dsᵀ, q_blk, g_blk, k_blk), the two score-sized arrays
     keys-by-queries, ``[cols, rows]``, like ``valid`` (``None`` is the
-    unmasked path); the row statistics are read as stored, ``[1, rows]``.
+    unmasked path); the row statistics are read as stored, ``[1, rows]``
+    (delta_ref holds ``Δ'``, ``g_lse`` inside it).
 
     Padded / fully-masked Q rows carry ``lse == -inf`` and zero ``g``;
     ``row_ok`` zeroes their ``p`` so they contribute nothing (a tile that
@@ -1419,14 +1467,12 @@ def _recompute_p_ds(lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    delta_row = delta_ref[0, g, 0:1, rq]
-    glse_row = glse_ref[0, g, 0:1, rq]
-    ds_t = p_t * (dp_t - delta_row) + glse_row * p_t
+    ds_t = p_t * (dp_t - delta_ref[0, g, 0:1, rq])
     return p_t, ds_t, q_blk, g_blk, k_blk
 
 
 def _bwd_kernel_dkdv(
-    qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
+    qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref,
     q_ref, k_ref, v_ref, g_ref, *refs,
     sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
@@ -1458,9 +1504,10 @@ def _bwd_kernel_dkdv(
     they follow one another along the last grid axis, ``(heads' program, q
     block)`` merged, and the accumulators run on through all of them.  With
     ``band`` (windowed) step 0 of a program's q blocks is the K/V block's
-    first (``_first_q_block``) and the axis ends with the band.  The refs
-    after g_ref are, with ``select``, the mask's block (``_drive_tiles``),
-    then ``dk_ref, dv_ref, dk_acc, dv_acc`` and ``shared_acc``."""
+    first (``_first_q_block``) and the axis ends with the band.  delta_ref
+    is dQ's ``Δ'`` ("Δ at the door").  The refs after g_ref are, with
+    ``select``, the mask's block (``_drive_tiles``), then ``dk_ref, dv_ref,
+    dk_acc, dv_acc`` and ``shared_acc``."""
     refs = list(refs)
     keep_ref = refs.pop(0) if select else None
     dk_ref, dv_ref, dk_acc, dv_acc, *shared_acc = refs
@@ -1504,7 +1551,7 @@ def _bwd_kernel_dkdv(
     def update(rq, rk, valid):
         for g in range(group):
             p_t, ds_t, q_blk, g_blk, _ = _recompute_p_ds(
-                lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
+                lse_ref, delta_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
                 dv=dv, rope=rope, kv_shared=kv_shared,
             )
@@ -1549,8 +1596,8 @@ def _bwd_kernel_dkdv(
 
 
 def _bwd_kernel_dq(
-    qoff_ref, kvoff_ref, kvlen_ref, lse_ref, delta_ref, glse_ref,
-    q_ref, k_ref, v_ref, g_ref, *refs,
+    qoff_ref, kvoff_ref, kvlen_ref, lse_ref, glse_ref,
+    q_ref, k_ref, v_ref, g_ref, out_ref, *refs,
     sm_scale: float, causal: bool, masked: bool, tiles: Tuple[int, int],
     q_len: int, packed: bool = False, d: int = 0, dv: int = 0,
     rope: int = 0, kv_shared: bool = False, band: Optional[_Plan] = None,
@@ -1560,23 +1607,27 @@ def _bwd_kernel_dq(
     """grid (b, h-group, qi, kj): each Q block accumulates over streamed
     K tiles, as ``dqᵀ``, ``[G, d, block_q]``; the per-head loop is a
     static unroll (see forward).  ``kv_shared`` / ``band``: as the
-    forward.  With ``turn`` ("Rotary at the door") q_ref holds the turned
-    q the forward wrote, the refs after g_ref are ``rot_ref, dq_ref,
-    dq_acc``, and each head's gradient is turned back where it is written:
-    dq_ref takes the gradient of the unrotated q.  With ``norm`` ("Norm at
-    the door") ``raw_ref, scale_ref`` come behind rot_ref, the projection's
-    q block and the ``[1, d]`` scale, and ``dscale_ref`` behind dq_ref: the
-    gradient, turned back, goes through the norm's backward where it is
-    written, and dscale_ref, ``[1, 1, 1, 1, d]`` float32, takes the
-    program's part of the scale's.  With ``select`` the mask's block comes
-    before dq_ref."""
+    forward.  out_ref is the forward's out block, laid like g_ref; the
+    block's first step takes ``Δ'`` from the two ("Δ at the door",
+    ``_delta_rows``) into delta_ref, the output behind dq_ref,
+    ``[1, G, 8, block_q]`` like lse_ref, which the tiles read and dK/dV
+    reads after them.  With ``turn`` ("Rotary at the door") q_ref holds the
+    turned q the forward wrote, the refs after out_ref are ``rot_ref,
+    dq_ref, delta_ref, dq_acc``, and each head's gradient is turned back
+    where it is written: dq_ref takes the gradient of the unrotated q.
+    With ``norm`` ("Norm at the door") ``raw_ref, scale_ref`` come behind
+    rot_ref, the projection's q block and the ``[1, d]`` scale, and
+    ``dscale_ref`` behind delta_ref: the gradient, turned back, goes
+    through the norm's backward where it is written, and dscale_ref, ``[1,
+    1, 1, 1, d]`` float32, takes the program's part of the scale's.  With
+    ``select`` the mask's block comes before dq_ref."""
     refs = list(refs)
     rot_ref = refs.pop(0) if turn is not None else None
     raw_ref, scale_ref = (
         (refs.pop(0), refs.pop(0)) if norm is not None else (None, None)
     )
     keep_ref = refs.pop(0) if select else None
-    dq_ref, *dscale_ref, dq_acc = refs
+    dq_ref, delta_ref, *dscale_ref, dq_acc = refs
     qi = pl.program_id(2)
     kj = step = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -1590,11 +1641,15 @@ def _bwd_kernel_dq(
     @pl.when(step == 0)
     def _init():
         dq_acc[:, :, :] = jnp.zeros_like(dq_acc)
+        _delta_rows(
+            g_ref, out_ref, glse_ref, delta_ref, group=group, dv=dv,
+            packed=packed,
+        )
 
     def update(rq, rk, valid):
         for g in range(group):
             _, ds_t, _, _, k_blk = _recompute_p_ds(
-                lse_ref, delta_ref, glse_ref, q_ref, k_ref, v_ref, g_ref,
+                lse_ref, delta_ref, q_ref, k_ref, v_ref, g_ref,
                 g, rq, rk, valid, sm_scale=sm_scale, packed=packed, d=d,
                 dv=dv, rope=rope, kv_shared=kv_shared,
             )
@@ -1693,7 +1748,7 @@ def _bwd_pallas(q, k, v, q_offset, kv_offset, out, lse, g_out, g_lse, rot,
     accumulated = (p.d - p.rope, p.dv) + ((p.rope,) if p.rope else ())
     if any(_dkv_streams_thin(width) for width in accumulated):
         _registry.always().counter("flash.dkv.thin_streamed").inc()
-    _book_call_kinds(p, 2)
+    _book_call_kinds(p, 2, backward=True)
     return _flash_bwd_call(
         q, k, v, _geometry(q_offset, kv_offset, p.skv), out, lse, g_out,
         g_lse, rot, keep, raw, scale, p=p, sm_scale=st.sm_scale,
@@ -1717,6 +1772,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
         kr = p.pad_seq(k, skv, skv_pad)
         vr = p.pad_seq(v, skv, skv_pad)
         gr = p.pad_seq(g_out.astype(q.dtype), sq, sq_pad)
+        outr = p.pad_seq(out, sq, sq_pad)
 
         # Row statistics in the kernel's [b, h, 8, sq_pad] layout (8 = min
         # sublane tile; kernels read sublane 0).
@@ -1727,21 +1783,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
                             constant_values=pad_value)
             return jnp.broadcast_to(x[:, :, None, :], (b, h, 8, sq_pad))
 
-        if p.packed:
-            # [B,S,H*D] → per-head row dot via a free reshape (no transpose).
-            delta = jnp.einsum(
-                "bqhd,bqhd->bhq",
-                g_out.astype(jnp.float32).reshape(b, sq, h, dv),
-                out.astype(jnp.float32).reshape(b, sq, h, dv),
-            )
-        else:
-            delta = jnp.einsum(
-                "bhqd,bhqd->bhq",
-                g_out.astype(jnp.float32),
-                out.astype(jnp.float32),
-            )
         lse_rows = rows(lse, -jnp.inf)  # padded rows masked via row_ok
-        delta_rows = rows(delta, 0.0)
         glse = jnp.zeros((b, h, sq), jnp.float32) if g_lse is None else g_lse
         glse_rows = rows(glse.astype(jnp.float32), 0.0)
         turned = [] if p.turn is None else [_rot_rows(rot, p)]
@@ -1763,9 +1805,9 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
         two axes are ``order``: "kq" (dK/dV: q streams innermost, its
         skipped steps clamped to the first q block needed) or "qk" (dQ:
         K/V streams innermost, clamped to the last K/V block needed).
-        q and k blocks are ``d`` wide, v and g blocks ``dv``; with
-        ``rope`` the k block is the packed ``kv``'s and the v block the
-        shared key's."""
+        q and k blocks are ``d`` wide, v and g blocks ``dv`` (and dQ's out
+        block, which takes g's spec); with ``rope`` the k block is the
+        packed ``kv``'s and the v block the shared key's."""
 
         def blocks(i, j, geom):
             qi, kj = (j, i) if order == "kq" else (i, j)
@@ -1842,7 +1884,45 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
             else (b, heads, s_pad, width), x.dtype,
         )
 
-    # dk/dv: grid (b, h-group, kj, qi) — q streams innermost.
+    # dq: grid (b, h-group, qi, kj) — k streams innermost.  It runs first:
+    # its second result is ``Δ'`` in the statistics' layout, dK/dV's operand.
+    stat_spec, q_spec, k_spec, v_spec, g_spec, rot_spec, keep_spec = specs(
+        "qk"
+    )
+    dq_spec, norm_spec = [q_spec, stat_spec], []
+    dq_shape = [
+        shape_like(q, sq_pad, d),
+        jax.ShapeDtypeStruct((b, h, 8, sq_pad), jnp.float32),
+    ]
+    if p.norm is not None:
+        # the raw q block and the scale in; the scale's gradient out, one
+        # float32 row a program
+        norm_spec = [q_spec, _vspec(scale.shape, lambda *_: (0, 0))]
+        programs = (b, h // group, sq_pad // block_q)
+        dq_spec.append(_vspec(
+            (1, 1, 1, 1, d), lambda bi, hi, qi, kj, *geom: (bi, hi, qi, 0, 0)
+        ))
+        dq_shape.append(jax.ShapeDtypeStruct(programs + (1, d), jnp.float32))
+    dq, delta_rows, *dscale = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel_dq, **kernel_params, turn=p.turn, norm=p.norm
+        ),
+        grid_spec=_grid_spec(
+            causal,
+            grid=(b, h // group, sq_pad // block_q, p.kv_steps),
+            in_specs=[stat_spec, stat_spec,
+                      q_spec, k_spec, v_spec, g_spec, g_spec] + rot_spec
+            + norm_spec + keep_spec,
+            out_specs=dq_spec,
+            scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
+        ),
+        out_shape=dq_shape,
+        **call_params,
+        name=_kernel_name("hvd_flash_bwd_dq", p),
+    )(*geom, lse_rows, glse_rows, qr, kr, vr, gr, outr, *turned, *normed,
+      *kept)
+
+    # dk/dv: grid (b, h-group, kj, qi) — q streams innermost; ``Δ'`` is dQ's.
     stat_spec, q_spec, k_spec, v_spec, g_spec, _, keep_spec = specs("kq")
 
     def dkv_acc(width, heads=p.kv_group):
@@ -1877,7 +1957,7 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
             causal,
             grid=(b, h // group // p.subs, skv_pad // block_k,
                   p.subs * p.q_steps),
-            in_specs=[stat_spec, stat_spec, stat_spec,
+            in_specs=[stat_spec, stat_spec,
                       q_spec, k_spec, v_spec, g_spec] + keep_spec,
             out_specs=dkv_out_specs,
             scratch_shapes=[dkv_acc(n), dkv_acc(dv)] + (
@@ -1887,51 +1967,14 @@ def _flash_bwd_call(q, k, v, geom, out, lse, g_out, g_lse, rot=None,
         out_shape=dkv_out_shape,
         **call_params,
         name=_kernel_name("hvd_flash_bwd_dkv", p),
-    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *kept)
+    )(*geom, lse_rows, delta_rows, qr, kr, vr, gr, *kept)
     if rope:
         with jax.named_scope(_GLUE_SCOPE):
             grad_v = grad_v.sum(axis=1).swapaxes(1, 2)
 
-    # dq: grid (b, h-group, qi, kj) — k streams innermost.
-    stat_spec, q_spec, k_spec, v_spec, g_spec, rot_spec, keep_spec = specs(
-        "qk"
-    )
-    dq_spec, dq_shape, norm_spec = q_spec, shape_like(q, sq_pad, d), []
-    if p.norm is not None:
-        # the raw q block and the scale in; the scale's gradient out, one
-        # float32 row a program
-        norm_spec = [q_spec, _vspec(scale.shape, lambda *_: (0, 0))]
-        programs = (b, h // group, sq_pad // block_q)
-        dq_spec = [q_spec, _vspec(
-            (1, 1, 1, 1, d), lambda bi, hi, qi, kj, *geom: (bi, hi, qi, 0, 0)
-        )]
-        dq_shape = [dq_shape, jax.ShapeDtypeStruct(
-            programs + (1, d), jnp.float32
-        )]
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel_dq, **kernel_params, turn=p.turn, norm=p.norm
-        ),
-        grid_spec=_grid_spec(
-            causal,
-            grid=(b, h // group, sq_pad // block_q, p.kv_steps),
-            in_specs=[stat_spec, stat_spec, stat_spec,
-                      q_spec, k_spec, v_spec, g_spec] + rot_spec + norm_spec
-            + keep_spec,
-            out_specs=dq_spec,
-            scratch_shapes=[_VMEM((group, d, block_q), jnp.float32)],
-        ),
-        out_shape=dq_shape,
-        **call_params,
-        name=_kernel_name("hvd_flash_bwd_dq", p),
-    )(*geom, lse_rows, delta_rows, glse_rows, qr, kr, vr, gr, *turned,
-      *normed, *kept)
-
     with jax.named_scope(_GLUE_SCOPE):
-        dscale = []
-        if p.norm is not None:
-            dq, rows = dq
-            dscale = [rows.sum(axis=(0, 1, 2))]  # [1, d], as the scale
+        # the scale's gradient: [1, d], as the scale
+        dscale = [x.sum(axis=(0, 1, 2)) for x in dscale]
         if p.packed:
             return (
                 dq[:, :sq].astype(q.dtype),

@@ -178,11 +178,18 @@ def test_ring_attention_flash_matches_xla_ring(world8):
         )
 
 
-def test_transformer_use_flash_matches_dense():
+@pytest.mark.parametrize(
+    "d_model", [32, 128], ids=["head-major-d16", "packed-d64"]
+)
+def test_transformer_use_flash_matches_dense(d_model):
+    """Heads of 16 take the head-major flash path, heads of 64 the packed
+    one, whose out projection is one dot on the kernels' ``[B, S, H D]``
+    output (``_packed_dot``): the same parameter tree, the same logits and
+    the same gradient of every leaf as the XLA path."""
     from horovod_tpu.models.gpt2 import GPT2Config, GPT2LMModel
 
     kwargs = dict(
-        vocab_size=128, max_len=32, d_model=32, n_heads=2, n_layers=1,
+        vocab_size=128, max_len=32, d_model=d_model, n_heads=2, n_layers=1,
         d_ff=64, dtype=jnp.float32,
     )
     tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 32), 0, 128)
@@ -191,12 +198,27 @@ def test_transformer_use_flash_matches_dense():
     m1 = GPT2LMModel(GPT2Config(use_flash=False, **kwargs))
     m2 = GPT2LMModel(GPT2Config(use_flash=True, **kwargs))
     params = m1.init(jax.random.PRNGKey(9), tokens)
-    np.testing.assert_allclose(
-        m1.apply(params, tokens),
-        m2.apply(params, tokens),
-        atol=1e-5,
-        rtol=1e-5,
+    assert jax.tree.structure(params) == jax.tree.structure(
+        jax.eval_shape(m2.init, jax.random.PRNGKey(9), tokens)
     )
+    w = jax.random.normal(jax.random.PRNGKey(10), (2, 32, 128))
+
+    def grads(model):
+        def loss(params):
+            logits = model.apply(params, tokens)
+            return jnp.sum(logits * w), logits
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(loss, has_aux=True)(params)
+
+    g1, logits1 = grads(m1)
+    g2, logits2 = grads(m2)
+    np.testing.assert_allclose(logits1, logits2, atol=1e-5, rtol=1e-5)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(g1), jax.tree.leaves(g2)
+    ):
+        np.testing.assert_allclose(
+            a, b, atol=2e-4, rtol=2e-4, err_msg=jax.tree_util.keystr(path)
+        )
 
 
 def test_flash_bsm_layout_matches_bhsd():
@@ -710,7 +732,11 @@ def test_fwd_and_dq_kernels_keep_rows_on_lanes(kernel, matmuls, cell):
     contracts on dimension 0 (so the only one Mosaic must transpose) is a
     thin ``[cols, 64]`` K or V tile against the ``[cols, rows]`` scores,
     and the only transposes are of the ``[64, block_q]`` accumulators,
-    once per head where the q block is written."""
+    once per head where the q block is written, and in dQ of the
+    ``[block_q, 128]`` float32 products ``g * out`` of two heads that share
+    a lane tile, once per pair where the q block arrives ("Δ at the door":
+    the sums then run over sublanes and the statistic lands rows-on-lanes;
+    no lane is shifted)."""
     eqns = _kernel_eqns(kernel, cell)
     assert not _column_reshapes(eqns)
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
@@ -725,8 +751,11 @@ def test_fwd_and_dq_kernels_keep_rows_on_lanes(kernel, matmuls, cell):
         tuple(e.invars[0].aval.shape) for e in eqns
         if e.primitive.name == "transpose"
     ]
-    # one per head of the program's group
-    assert set(turned) == {(64, 512)} and 12 % len(turned) == 0, turned
+    # one per head of the program's group (dQ: and one of the products)
+    heads = turned.count((64, 512))
+    assert heads and 12 % heads == 0, turned
+    pairs = heads // 2 if kernel == "hvd_flash_bwd_dq" else 0
+    assert sorted(turned) == [(64, 512)] * heads + [(512, 128)] * pairs
 
 
 # ---------------------------------------------------------------------------
@@ -873,15 +902,17 @@ def test_dkv_accumulators_take_their_form_from_their_own_width():
 )
 def test_fwd_and_dq_keep_rows_on_lanes_at_split_widths(kernel, acc_width):
     """The forward accumulates ``[dv, rows]`` and dQ ``[d, rows]``: the one
-    transpose a head is of that accumulator, and nothing kept per q row is
-    a ``[rows, 1]`` column."""
+    transpose a head is of that accumulator (in dQ also of the head's
+    ``[rows, dv]`` products ``g * out``, for the row statistic), and
+    nothing kept per q row is a ``[rows, 1]`` column."""
     eqns = _split_kernel_eqns(kernel, 192, 128)
     assert not _column_reshapes(eqns)
     turned = {
         tuple(e.invars[0].aval.shape) for e in eqns
         if e.primitive.name == "transpose"
     }
-    assert turned == {(acc_width, 512)}, turned
+    door = {(512, 128)} if kernel == "hvd_flash_bwd_dq" else set()
+    assert turned == {(acc_width, 512)} | door, turned
 
 
 def test_split_widths_counter_and_group():
@@ -1018,6 +1049,10 @@ def test_latent_kernels_take_kv_and_the_shared_key_as_they_are():
         assert (1, 1024, 64) in operands, (name, operands)
         # q, and in the backward nothing else of that width
         assert operands.count((1, 1024, 4 * 192)) == 1, (name, operands)
+        # g in both backward kernels, and out beside it in dQ alone
+        assert operands.count((1, 1024, 4 * 128)) == {
+            "hvd_flash_fwd": 0, "hvd_flash_bwd_dkv": 1, "hvd_flash_bwd_dq": 2,
+        }[name], (name, operands)
         built = {
             tuple(tuple(v.aval.shape) for v in e.invars)
             for e in _walk(call.params["jaxpr"])
@@ -1033,7 +1068,13 @@ def test_latent_kernels_take_kv_and_the_shared_key_as_they_are():
     assert results("hvd_flash_bwd_dkv") == [
         ((1, 1024, 4 * 256), jnp.bfloat16), ((1, 2, 64, 1024), jnp.float32)
     ]
-    assert results("hvd_flash_bwd_dq") == [((1, 1024, 4 * 192), jnp.bfloat16)]
+    # dq, and the row statistic dK/dV reads, as ``lse`` is laid
+    assert results("hvd_flash_bwd_dq") == [
+        ((1, 1024, 4 * 192), jnp.bfloat16), ((1, 4, 8, 1024), jnp.float32)
+    ]
+    assert calls["hvd_flash_bwd_dq"].outvars[1] in calls[
+        "hvd_flash_bwd_dkv"
+    ].invars
     assert results("hvd_flash_fwd")[0] == ((1, 1024, 4 * 128), jnp.bfloat16)
 
 
@@ -1096,24 +1137,24 @@ def test_flash_latent_refuses_shapes_that_are_not_its_layout():
 # ---------------------------------------------------------------------------
 
 
-def _band(sq, skv, window, q_offset=0, kv_offset=0):
-    ahead = (q_offset + np.arange(sq)[:, None]) - (
-        kv_offset + np.arange(skv)[None, :]
-    )
-    return (ahead >= 0) & (True if window is None else ahead < window)
-
-
-def _band_reference(q, k, v, window):
-    """``dot_product_attention`` under the explicit band mask with K and V
-    repeated to the query heads, and the masked log-sum-exp; ``bshd``."""
-    ratio = q.shape[2] // k.shape[2]
-    k, v = (jnp.repeat(t, ratio, axis=2) for t in (k, v))
-    mask = jnp.asarray(_band(q.shape[1], k.shape[1], window))
-    out = dot_product_attention(q, k, v, causal=False, mask=mask)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
-    lse = jax.scipy.special.logsumexp(
-        jnp.where(mask, scores, -1e30), axis=-1
-    )
+def _xla_attention(q, k, v, *, causal, window=None, keep=None):
+    """``(out, lse)`` of attention written out: q ``[1, sq, h, d]``, k / v
+    ``[1, skv, h_kv, d / dv]`` (a K/V head repeated for its query heads),
+    ``keep`` the int8 mask keys by queries."""
+    sq, h, d = q.shape[1:]
+    skv, h_kv = k.shape[1:3]
+    k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    valid = np.ones((sq, skv), bool)
+    if causal:
+        ahead = np.arange(sq)[:, None] - np.arange(skv)[None, :]
+        valid = (ahead >= 0) & (True if window is None else ahead < window)
+    valid = jnp.asarray(valid)[None, None]
+    if keep is not None:
+        valid = valid & (keep.swapaxes(1, 2)[:, None] != 0)
+    scores = jnp.where(valid, scores, -1e30)
+    lse = jax.scipy.special.logsumexp(scores, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(scores - lse[..., None]), v)
     return out, lse
 
 
@@ -1176,15 +1217,17 @@ def _band_case_grads(case, heads_a_program=None):
     with jax.default_matmul_precision("highest"):
         (_, got), got_grads = loss(flash)(q, k, v)
         (_, want), want_grads = loss(
-            lambda q, k, v: _band_reference(q, k, v, window)
+            lambda q, k, v: _xla_attention(
+                q, k, v, causal=True, window=window
+            )
         )(q, k, v)
     return got + got_grads, want + want_grads
 
 
 @pytest.mark.parametrize("case", list(_BAND_CASES))
 def test_flash_window_and_groups_match_reference(case):
-    """Window x query groups against ``dot_product_attention`` with the
-    explicit band mask and repeated K/V: out, ``lse``, dQ, dK, dV."""
+    """Window x query groups against attention written out under the
+    explicit band mask with repeated K/V: out, ``lse``, dQ, dK, dV."""
     got, want = _band_case_grads(case)
     for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4, err_msg=name)
@@ -1572,7 +1615,7 @@ def test_rotary_turns_in_the_forward_and_dq_and_nowhere_else(latent):
     assert str(calls["hvd_flash_bwd_dkv"].params["jaxpr"]) == str(
         plain["hvd_flash_bwd_dkv"].params["jaxpr"]
     )
-    (dq_out,) = dq.outvars
+    dq_out, _ = dq.outvars  # and the row statistic
     assert dq_out.aval.dtype == jnp.bfloat16
     assert dq_out in traced.jaxpr.outvars  # as it leaves the kernel
     # a head of the program's (2 / 7): pairs a roll either way, halves one
@@ -1809,7 +1852,7 @@ def test_norm_runs_in_the_forward_and_dq_and_nowhere_else(rotary):
     assert str(calls["hvd_flash_bwd_dkv"].params["jaxpr"]) == str(
         plain["hvd_flash_bwd_dkv"].params["jaxpr"]
     )
-    dq_out, dscale_rows = dq.outvars
+    dq_out, _, dscale_rows = dq.outvars  # the row statistic between them
     assert dq_out.aval.dtype == jnp.bfloat16
     assert tuple(dq_out.aval.shape) == q_shape
     assert dq_out in traced.jaxpr.outvars  # as it leaves the kernel
@@ -1911,3 +1954,237 @@ def test_a_norm_that_does_not_fit_q_is_refused(bad, match):
             q_norm=QNorm(bad.get("scale", jnp.ones((16,))),
                          bad.get("eps", 1e-6)),
         )
+
+
+# ---------------------------------------------------------------------------
+# Δ at the door: the backward's row statistic, ``Δ' = rowsum(g ⊙ out) −
+# g_lse``, is made in dQ where a q block arrives and read from dQ's second
+# result by dK/dV; ``g_lse`` reaches the gradients through it alone.  So
+# every kind of call is held against attention written out in XLA under a
+# NON-ZERO ``lse`` cotangent that differs from row to row.
+# ---------------------------------------------------------------------------
+
+
+# name: (entry, query heads, K/V heads, n, r, dv, sq, skv, (block_q,
+# block_k), causal, window, q_rotary + q_norm, keep, weight of lse's term).
+# ``entry``: a layout of the q, k, v entry, or "latent" (a head's key is
+# ``n`` own columns and ``r`` shared ones).  The padded cases leave padded q
+# rows in the last q block (40 of 48, 50 of 64), with and without ``g_lse``.
+_DELTA_CASES = {
+    "bsm-d64-causal": (
+        "bsm", 2, 2, 64, 0, 64, 64, 64, (32, 32), True, None, False, False,
+        0.3),
+    "bsm-d64-full-cross": (
+        "bsm", 2, 2, 64, 0, 64, 32, 48, (16, 16), False, None, False, False,
+        0.3),
+    "bhsd-d16-causal": (
+        "bhsd", 2, 2, 16, 0, 16, 48, 48, (16, 16), True, None, False, False,
+        0.3),
+    "bshd-d128-dv64-full": (
+        "bshd", 2, 2, 128, 0, 64, 32, 32, (16, 16), False, None, False, False,
+        0.3),
+    "latent-128+64-128": (
+        "latent", 2, 2, 128, 64, 128, 64, 64, (32, 32), True, None, False,
+        False, 0.3),
+    "groups-6to2-d16": (
+        "bsm", 6, 2, 16, 0, 16, 64, 64, (32, 32), True, None, False, False,
+        0.3),
+    "window-w20-4to2-d16": (
+        "bsm", 4, 2, 16, 0, 16, 96, 96, (32, 32), True, 20, False, False,
+        0.3),
+    "rotary-norm-4to2-d16": (
+        "bsm", 4, 2, 16, 0, 16, 64, 64, (32, 32), True, None, True, False,
+        0.3),
+    "keep-4to2-d128-rotary-norm": (
+        "bsm", 4, 2, 128, 0, 128, 64, 64, (32, 32), True, None, True, True,
+        0.3),
+    "keep-2to2-d16": (
+        "bsm", 2, 2, 16, 0, 16, 64, 64, (32, 32), True, None, False, True,
+        0.3),
+    "padded-sq40-bsm-d64-lse": (
+        "bsm", 2, 2, 64, 0, 64, 40, 40, (16, 16), True, None, False, False,
+        0.3),
+    "padded-sq40-bsm-d64-no-lse": (
+        "bsm", 2, 2, 64, 0, 64, 40, 40, (16, 16), True, None, False, False,
+        0.0),
+    "padded-sq50-bhsd-full-lse": (
+        "bhsd", 2, 2, 16, 0, 16, 50, 56, (32, 16), False, None, False, False,
+        0.3),
+    "padded-sq50-groups-keep-no-lse": (
+        "bsm", 4, 2, 16, 0, 16, 50, 50, (32, 32), True, None, False, True,
+        0.0),
+}
+
+
+@pytest.mark.parametrize("case", list(_DELTA_CASES))
+def test_flash_grads_under_an_lse_cotangent_match_xla(case):
+    """out, ``lse`` and every gradient of one kind of call a case against
+    attention written out in XLA (norm and rotary in front of it, K built
+    from latent attention's operands), the loss weighting each row's
+    ``lse`` by its own random factor."""
+    from horovod_tpu.models.transformer import RMSNorm, rotary, rotary_tables
+    from horovod_tpu.ops.pallas_kernels import QNorm, QRotary
+
+    (entry, h, h_kv, n, r, dv, sq, skv, block, causal, window, door, masked,
+     lse_weight) = _DELTA_CASES[case]
+    latent = entry == "latent"
+    d = n + r
+    keys = jax.random.split(jax.random.PRNGKey(31), 7)
+    q = jax.random.normal(keys[0], (1, sq, h, d))
+    if latent:
+        k = jax.random.normal(keys[1], (1, skv, h, n + dv))  # kv
+        v = jax.random.normal(keys[2], (1, skv, r))  # the shared key
+    else:
+        k = jax.random.normal(keys[1], (1, skv, h_kv, d))
+        v = jax.random.normal(keys[2], (1, skv, h_kv, dv))
+    w = jax.random.normal(keys[3], (1, sq, h, dv))
+    u = lse_weight * jax.random.normal(keys[4], (1, h, sq))
+    scale = 1.0 + 0.3 * jax.random.normal(keys[5], (d,))
+    keep = None
+    if masked:  # the diagonal kept, so every row sees a key
+        keep = ((jax.random.uniform(keys[6], (1, skv, sq)) < 0.5)
+                | jnp.eye(skv, sq, dtype=bool)).astype(jnp.int8)
+    kw = dict(causal=causal, block_q=block[0], block_k=block[1])
+
+    def flash(q, k, v, scale):
+        if door:
+            kw.update(
+                q_norm=QNorm(scale, _NORM_EPS),
+                q_rotary=QRotary(*rotary_tables(sq, d, theta=1e4),
+                                 halves=True),
+            )
+        if latent:
+            out, lse = flash_attention_latent(
+                q.reshape(1, sq, h * d), k.reshape(1, skv, -1), v, n_heads=h,
+                **kw,
+            )
+            return out.reshape(1, sq, h, dv), lse
+        out, lse = flash_attention_with_lse(
+            *(_in_layout(t, entry) for t in (q, k, v)), layout=entry,
+            window=window, keep=keep,
+            **(dict(n_heads=h, n_kv_heads=h_kv) if entry == "bsm" else {}),
+            **kw,
+        )
+        return _from_layout(out, entry, dv), lse
+
+    def written_out(q, k, v, scale):
+        if door:
+            q = rotary(RMSNorm(_NORM_EPS, jnp.float32).apply(
+                {"params": {"scale": scale}}, q
+            ), theta=1e4, halves=True)
+        if latent:
+            k, v = _built_keys(k, v, n)
+        return _xla_attention(
+            q, k, v, causal=causal, window=window, keep=keep
+        )
+
+    def grads(fn):
+        def loss(*operands):
+            out, lse = fn(*operands)
+            return jnp.sum(out * w) + jnp.sum(lse * u), (out, lse)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(
+                jax.grad(loss, argnums=(0, 1, 2, 3), has_aux=True)
+            )(q, k, v, scale)
+
+    got, (out, lse) = grads(flash)
+    want, (ref_out, ref_lse) = grads(written_out)
+    np.testing.assert_allclose(out, ref_out, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, atol=2e-5, rtol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv", "dscale"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+def _dq_kernel_results(q, k, v, out, lse, g, g_lse, *, causal, n_heads,
+                       block):
+    """What the dQ kernel of a packed call's backward returns, ``(dq, Δ'
+    rows)``: the backward traced as the entries build it, cut off behind
+    that kernel and evaluated (interpreter)."""
+    import functools
+
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    st = pk._Static(
+        1.0 / np.sqrt(q.shape[-1] // n_heads), causal, block, block, True,
+        n_heads,
+    )
+    p = st.plan(q, k, v, None)
+    operands = (q, k, v, pk._geometry(0, 0, p.skv), out, lse, g, g_lse)
+    traced = jax.make_jaxpr(functools.partial(
+        pk._flash_bwd_call.__wrapped__, p=p, sm_scale=st.sm_scale,
+        causal=causal,
+    ))(*operands)
+    eqns = traced.jaxpr.eqns
+    (at,) = [
+        i for i, e in enumerate(eqns) if e.primitive.name == "pallas_call"
+        and e.params["name"] == "hvd_flash_bwd_dq"
+    ]
+    cut = traced.jaxpr.replace(
+        eqns=eqns[:at + 1], outvars=list(eqns[at].outvars),
+        debug_info=traced.jaxpr.debug_info._replace(result_paths=None),
+    )
+    return jax.core.eval_jaxpr(
+        cut, traced.consts, *jax.tree.leaves(operands)
+    )
+
+
+@pytest.mark.parametrize("sq,g_lse_weight", [(64, 0.0), (64, 1.0), (40, 1.0)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_dq_writes_the_row_statistic_to_float32_rounding(causal, sq,
+                                                         g_lse_weight):
+    """dQ's second result is ``einsum(g, out) − g_lse`` with float32
+    products and a float32 sum, from bf16 ``g`` and ``out``, on all eight
+    sublanes of the statistics' layout; padded q rows (40 of 48) read 0."""
+    h, d, block = 2, 64, 16
+    keys = jax.random.split(jax.random.PRNGKey(37), 6)
+    q, k, v, out, g = (
+        jax.random.normal(key, (2, sq, h * d), jnp.bfloat16)
+        for key in keys[:5]
+    )
+    lse = jnp.full((2, h, sq), 3.0)  # any finite value: Δ' does not read it
+    g_lse = g_lse_weight * jax.random.normal(keys[5], (2, h, sq))
+    dq, rows = _dq_kernel_results(
+        q, k, v, out, lse, g, g_lse, causal=causal, n_heads=h, block=block
+    )
+    sq_pad = -(-sq // block) * block
+    assert dq.shape == (2, sq_pad, h * d) and dq.dtype == jnp.bfloat16
+    assert rows.shape == (2, h, 8, sq_pad) and rows.dtype == jnp.float32
+    want = jnp.einsum(
+        "bqhd,bqhd->bhq", g.astype(jnp.float32).reshape(2, sq, h, d),
+        out.astype(jnp.float32).reshape(2, sq, h, d), precision="highest",
+    ) - g_lse
+    for sublane in range(8):
+        np.testing.assert_allclose(
+            rows[:, :, sublane, :sq], want, rtol=2e-6, atol=2e-6
+        )
+    assert not np.asarray(rows[..., sq:]).any()
+
+
+def test_delta_q_counter_counts_a_backward_built():
+    """``flash.calls.delta_q``: one a backward built (its dQ kernel makes
+    the statistic), none for a forward, whatever the entry."""
+    from horovod_tpu.obs import registry
+
+    counter = registry.always().counter("flash.calls.delta_q")
+    x = jax.ShapeDtypeStruct((1, 64, 2 * 64), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 64, 2 * 192), jnp.float32)
+    rope = jax.ShapeDtypeStruct((1, 64, 64), jnp.float32)
+
+    def plain(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, layout="bsm", n_heads=2
+        ).sum()
+
+    def latent(q, kv, rope):
+        return flash_attention_latent(q, kv, rope, n_heads=2)[0].sum()
+
+    def counted(fn, *operands):
+        before = counter.get()
+        jax.eval_shape(fn, *operands)
+        return counter.get() - before
+
+    assert counted(plain, x, x, x) == 0
+    assert counted(jax.grad(plain, argnums=(0, 1, 2)), x, x, x) == 1
+    assert counted(latent, kv, kv, rope) == 0
+    assert counted(jax.grad(latent, argnums=(0, 1, 2)), kv, kv, rope) == 1

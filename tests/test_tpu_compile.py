@@ -62,6 +62,39 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _compile_layer_grad(topo, sharding, module, x, loss):
+    """HLO text of ``value_and_grad(loss)(params, x)`` of one flax layer on
+    shapes, compiled for one described chip as the world's device (so the
+    layer takes its TPU path: the kernels)."""
+    import horovod_tpu as hvd
+
+    hvd.init(devices=topo.devices[:1])
+    try:
+        params = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding),
+            (params, x),
+        )
+        return params, jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1))
+        ).lower(*args).compile().as_text()
+    finally:
+        hvd.shutdown()
+
+
+def _assert_dq_result_is_q_cotangent(hlo):
+    """The program's first result is the dQ kernel's first result as it
+    leaves the kernel (its second is the row statistic)."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    first = re.search(r"tuple\(%([\w.]+)", entry.split("ROOT ")[1])[1]
+    (made,) = [line for line in entry.splitlines()
+               if line.lstrip().startswith(f"%{first} = ")]
+    assert re.search(
+        r"get-tuple-element\(%[\w.]*hvd_flash_bwd_dq[\w.]*\), index=0", made
+    ), made[:300]
+
+
 # -- the models' parts change the compiled step in its metadata only --------
 # (first in the file: these compiles use every core, and the file's last
 # tests run beside test_trace.py's deadline-bound scenarios as it is)
@@ -285,7 +318,6 @@ def test_kda_mixer_turns_no_float32_heads_around_its_norm(v5e_topology, v5e):
     around the norm (until PR 45 three a layer, 268 MB of traffic each,
     with three fusions that existed only to make the float32 array the
     copy turned), and no float32 array of that shape exists at all."""
-    import horovod_tpu as hvd
     from horovod_tpu.models.linear_moe import (
         KimiDeltaAttention, LinearMoEConfig,
     )
@@ -296,18 +328,7 @@ def test_kda_mixer_turns_no_float32_heads_around_its_norm(v5e_topology, v5e):
     def loss(params, x):
         return mixer.apply(params, x).astype(jnp.float32).sum()
 
-    hvd.init(devices=v5e_topology.devices[:1])  # the world's devices: TPUs
-    try:
-        params = jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)
-        args = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            (params, x),
-        )
-        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            *args
-        ).compile().as_text()
-    finally:
-        hvd.shutdown()
+    params, hlo = _compile_layer_grad(v5e_topology, v5e, mixer, x, loss)
     assert hlo.count("tpu_custom_call") == 2
     assert not re.findall(r"= f32\[[\d,]*32,128\]\S* copy\(", hlo)
     assert not re.findall(r"f32\[[\d,]*,32,128\]", hlo)
@@ -342,10 +363,11 @@ def test_flash_attention_window_and_groups_compile(v5e, window):
                    or f'/{name}{suffix}"' in c]
         wide = len(re.findall(r"bf16\[1,16384,3584\]", call))
         narrow = len(re.findall(r"bf16\[1,16384,512\]", call))
-        # fwd: q, out / k, v; dkv: q, g / k, v, dk, dv; dq: q, g, dq / k, v
+        # fwd: q, out / k, v; dkv: q, g / k, v, dk, dv; dq: q, g, out, dq /
+        # k, v
         assert (wide, narrow) == {
             "hvd_flash_fwd": (2, 2), "hvd_flash_bwd_dkv": (2, 4),
-            "hvd_flash_bwd_dq": (3, 2),
+            "hvd_flash_bwd_dq": (4, 2),
         }[name], (name, wide, narrow)
     p = pk._plan(
         *(jax.ShapeDtypeStruct((1, 16384, h * 128), jnp.bfloat16)
@@ -455,12 +477,12 @@ def test_flash_entries_with_rotary_tables_compile(v5e, cell):
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 3
-    if latent:  # (in the window call ``out`` has q's shape, and delta reads it)
-        assert not re.search(rf"f32\[{b},{s},{widths[0]}\]", hlo)
+    # (in the window call ``out`` has q's shape: the row statistic reads it
+    # in dQ, as it lies)
+    assert not re.search(rf"f32\[{b},{s},{widths[0]}\]", hlo)
     if not padded:
-        # q's cotangent is the dQ kernel's result itself
-        root = hlo[hlo.index("\nENTRY"):].split("ROOT ")[1]
-        assert re.search(r"tuple\(%[\w.]*hvd_flash_bwd_dq", root), root[:300]
+        # q's cotangent is the dQ kernel's first result itself
+        _assert_dq_result_is_q_cotangent(hlo)
         q_shape = rf"bf16\[{b},{s},{widths[0]}\]"
         (fwd,) = [c for c in calls if "hvd_flash_fwd" in c]
         # q in; out is narrower in the latent call, so: q, the turned q
@@ -508,22 +530,15 @@ def test_flash_entries_with_a_q_norm_compile(v5e, cell):
     (fwd,) = [c for c in calls if "hvd_flash_fwd_select" in c]
     (dq,) = [c for c in calls if "hvd_flash_bwd_dq_select" in c]
     # forward: q in; out and the q the scores see out.  dQ: that q, the
-    # raw q and g in; dq out
+    # raw q, g and out in; dq out
     assert len(re.findall(q_shape, fwd)) == 3
-    assert len(re.findall(q_shape, dq)) == 4
+    assert len(re.findall(q_shape, dq)) == 5
     assert "f32[1,128]" in fwd and "f32[1,128]" in dq
     # a K/V head's query heads are one program's
     rows = rf"f32\[{b},{h_kv},{s_pad // 512},1,128\]"
     assert re.search(rows, dq.split(" custom-call(")[0])
     if not padded:  # q's cotangent is the dQ kernel's first result itself
-        entry = hlo[hlo.index("\nENTRY"):]
-        first = re.search(r"tuple\(%([\w.]+)", entry.split("ROOT ")[1])[1]
-        (made,) = [line for line in entry.splitlines()
-                   if line.lstrip().startswith(f"%{first} = ")]
-        assert re.search(
-            r"get-tuple-element\(%[\w.]*hvd_flash_bwd_dq[\w.]*\), index=0",
-            made,
-        ), made[:300]
+        _assert_dq_result_is_q_cotangent(hlo)
 
 
 def test_sparse_layer_norms_and_rotates_q_nowhere_but_in_the_kernels(
@@ -537,7 +552,6 @@ def test_sparse_layer_norms_and_rotates_q_nowhere_but_in_the_kernels(
     (until PR 48 five float32 copies of 134 MB a layer among six fusions
     that existed to feed them), XLA concatenates no rotated q, and no
     float32 ``[.., 8192, 4096]`` is copied at all."""
-    import horovod_tpu as hvd
     from horovod_tpu.models.window_moe import (
         GroupedAttention, WindowMoEConfig,
     )
@@ -554,18 +568,7 @@ def test_sparse_layer_norms_and_rotates_q_nowhere_but_in_the_kernels(
         out, index_loss = attn.apply(params, x)
         return out.astype(jnp.float32).sum() + index_loss
 
-    hvd.init(devices=v5e_topology.devices[:1])  # the world's devices: TPUs
-    try:
-        params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
-        args = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            (params, x),
-        )
-        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            *args
-        ).compile().as_text()
-    finally:
-        hvd.shutdown()
+    params, hlo = _compile_layer_grad(v5e_topology, v5e, attn, x, loss)
     assert hlo.count("tpu_custom_call") == 5
     assert params["params"]["q_norm"]["scale"].shape == (128,)
     under_norm = [line for line in hlo.splitlines()
@@ -577,6 +580,38 @@ def test_sparse_layer_norms_and_rotates_q_nowhere_but_in_the_kernels(
                and re.search(r"\[1,8192,(32,128|32,64|4096)\]", line)]
     assert not rotated, rotated[:3]
     assert not re.findall(r"= f32\[(?:1,)?8192,4096\]\S* copy\(", hlo)
+    # "Δ at the door": the backward's row statistic is dQ's, so no float32
+    # array of q's width stands in front of the backward kernels
+    assert not re.search(r"bqhd,bqhd->bhq|bhqd,bhqd->bhq", hlo)
+    assert not re.findall(r"f32\[(?:1,)?8192,(?:4096|32,128)\]", hlo)
+
+
+def test_packed_d64_layer_leaves_the_row_statistic_to_dq(v5e_topology, v5e):
+    """One ``MultiHeadAttention`` of GPT-2-small (16 x 1,024 x 768, 12
+    heads of 64, packed), ``value_and_grad`` compiled: three Mosaic calls,
+    and no ``rowsum(g x out)`` of XLA's (until PR 49 a second output of the
+    ``out`` projection's backward fusion): the statistic is dQ's."""
+    from horovod_tpu.models.transformer import (
+        MultiHeadAttention, TransformerConfig,
+    )
+
+    attn = MultiHeadAttention(TransformerConfig(
+        d_model=768, n_heads=12, causal=True, dtype=jnp.bfloat16,
+    ))
+    x = jax.ShapeDtypeStruct((16, 1024, 768), jnp.bfloat16)
+
+    def loss(params, x):
+        return attn.apply(params, x).astype(jnp.float32).sum()
+
+    params, hlo = _compile_layer_grad(v5e_topology, v5e, attn, x, loss)
+    assert hlo.count("tpu_custom_call") == 3
+    assert not re.search(r"bqhd,bqhd->bhq|bhqd,bhqd->bhq", hlo)
+    # Δ' goes from dQ to dK/dV as the statistics lie
+    (dq,) = re.findall(r"(%\S*hvd_flash_bwd_dq\S*) = \(", hlo)
+    assert re.search(
+        rf"f32\[16,12,8,1024\]\S* get-tuple-element\({re.escape(dq)}\), "
+        r"index=1", hlo,
+    )
 
 
 def test_reglu_expert_layer_compiles_at_the_window_cell_shapes(v5e):
@@ -621,7 +656,6 @@ def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):
     ``q_b``'s matmul output is the forward kernel's operand."""
     import re
 
-    import horovod_tpu as hvd
     from horovod_tpu.models.latent_moe import LatentAttention, LatentMoEConfig
 
     attn = LatentAttention(LatentMoEConfig())
@@ -630,18 +664,7 @@ def test_latent_attention_builds_no_keys_around_the_kernels(v5e_topology, v5e):
     def loss(params, x):
         return attn.apply(params, x).astype(jnp.float32).sum()
 
-    hvd.init(devices=v5e_topology.devices[:1])  # the world's devices: TPUs
-    try:
-        params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
-        args = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            (params, x),
-        )
-        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            *args
-        ).compile().as_text()
-    finally:
-        hvd.shutdown()
+    params, hlo = _compile_layer_grad(v5e_topology, v5e, attn, x, loss)
     assert hlo.count("tpu_custom_call") == 3
     entry = hlo[hlo.index("\nENTRY"):]
     defined = {

@@ -693,7 +693,7 @@ def test_router_input_and_activation_are_checked():
     [(False,
       "bab2287090853cedf323e51f3fca44ff6315ad86747b11c27d4642873a0d3c79"),
      (True,
-      "b2e46b30664ccf7b67ca85c1417f0fb8072cfc9438ca3a3f0fdc0c55dded1ade")],
+      "9f6c0da5af9607843db603f2db47561797636599accec426abe3e1ba06414213")],
     ids=["xla", "kernels"],
 )
 def test_default_settings_trace_what_the_block_traced(flash, sha):
@@ -702,7 +702,9 @@ def test_default_settings_trace_what_the_block_traced(flash, sha):
     the block traced before it took the settings, the flash kernels without
     ``keep=`` theirs: the sha256 of ``str(jax.make_jaxpr(...))`` as the
     parent of the settings' PR printed it (jax 0.9.0). An edit that means
-    to change the default block's trace records the new one here."""
+    to change the default block's trace records the new one here (PR 49:
+    the kernels' text, whose backward makes its row statistic in dQ; the
+    XLA path's is the parent's)."""
     import hashlib
 
     cfg = WindowMoEConfig.tiny(use_flash=flash)

@@ -44,7 +44,10 @@ then the ``_select`` ones; the cell keeps 0.4375 of its causal entries).
 ``horovod_tpu`` from another checkout (a parent commit unpacked beside
 this one), so two commits can be timed in one chip call. This is where a
 kernel change is judged before a cell is run; ``benchmark/split.py`` gives
-the same three names inside a whole step.
+the same three names inside a whole step. Since PR 49 the rows hold the
+backward's row statistic too: dQ makes ``rowsum(g * out) - g_lse`` itself
+and dK/dV reads it from dQ; on an older tree XLA's einsum made it in front
+of the kernels, outside the three names, so such a tree's rows leave it out.
 """
 import argparse
 import glob
