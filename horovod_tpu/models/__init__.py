@@ -18,3 +18,4 @@ from .moe import MoEConfig, SwitchTransformerLM  # noqa: F401
 from .latent_moe import LatentMoEConfig, LatentMoELM  # noqa: F401
 from .window_moe import WindowMoEConfig, WindowMoELM  # noqa: F401
 from .linear_moe import LinearMoEConfig, LinearMoELM  # noqa: F401
+from .linear_dense import LinearDenseConfig, LinearDenseLM  # noqa: F401

@@ -71,6 +71,10 @@ layer's kernels are named ``hvd_flash_*_window``, a full layer's
 ``hvd_flash_*``. Off the TPU attention is ``dot_product_attention`` with
 the explicit band mask over K/V repeated to ``H`` heads.
 
+``GroupedAttention`` has two settings of its own for a model that is not
+this block (``models/linear_dense.py``): ``qk_norm_over`` and
+``axis_name``, on the class; their defaults trace what the layer traced.
+
 Scopes (``jax.named_scope``; ``docs/api.md`` has the table). Every
 operation of ``apply`` lies under exactly one of: ``embed``, ``norm`` (the
 RMSNorms, the residual sums and the token-major views), ``attn_proj`` (the
@@ -174,15 +178,50 @@ class HeadScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (d,), jnp.float32)
 
 
+class ProjectionNorm(nn.Module):
+    """``RMSNorm`` over a WHOLE projection's columns (``scale`` ``[width]``
+    float32 at one), of which this chip may hold a share: under
+    ``axis_name`` the sum of squares is ``psum``med over the shares and the
+    mean is over all their columns; with none it is over the columns held.
+    fp32, returned in ``dtype``."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    axis_name: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x):
+        width = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (width,),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        squares = jnp.sum(x * x, axis=-1, keepdims=True)
+        if self.axis_name is not None:
+            squares = jax.lax.psum(squares, self.axis_name)
+            width = width * jax.lax.psum(1, self.axis_name)
+        y = x * jax.lax.rsqrt(squares / width + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
 class GroupedAttention(nn.Module):
     """Causal attention with ``n_heads`` query heads over ``n_kv_heads``
     K/V heads, under a ``window`` (None: every earlier position) and with
     or without rotary. With ``cfg.index_top_k`` each query attends to the
-    keys its indexer keeps, and the call returns ``(out, index_loss)``."""
+    keys its indexer keeps, and the call returns ``(out, index_loss)``.
+
+    ``qk_norm_over`` (read where ``cfg.qk_norm`` is on): ``"head"`` norms
+    each head of q and k over its ``head_dim`` columns, ``"projection"``
+    each WHOLE projection over all its columns (:class:`ProjectionNorm`;
+    XLA's on both paths, since the kernels' ``q_norm`` is a head's).
+    ``axis_name``: the layer's heads are one share of several under that
+    axis; the projection norm's sum of squares and the output projection's
+    partial result are ``psum``med over it. None: nothing is exchanged."""
 
     cfg: WindowMoEConfig
     window: Optional[int] = None
     rotate: bool = False
+    qk_norm_over: str = "head"
+    axis_name: Optional[str] = None
 
     @nn.compact
     def __call__(self, x):
@@ -208,7 +247,12 @@ class GroupedAttention(nn.Module):
             k = dense(h_kv * d, "k")(x)
             v = dense(h_kv * d, "v")(x)
             q_norm = None
-            if cfg.qk_norm:
+            if cfg.qk_norm and self.qk_norm_over == "projection":
+                whole = lambda name: ProjectionNorm(  # noqa: E731
+                    cfg.eps, cfg.dtype, self.axis_name, name=name
+                )
+                q, k = whole("q_norm")(q), whole("k_norm")(k)
+            elif cfg.qk_norm:
                 by_head = lambda t, heads, name: RMSNorm(  # noqa: E731
                     cfg.eps, cfg.dtype, name=name
                 )(t.reshape(b, s, heads, d)).reshape(b, s, heads * d)
@@ -264,6 +308,8 @@ class GroupedAttention(nn.Module):
                 out = out.reshape(b, s, h * d)
         with jax.named_scope("attn_proj"):
             out = dense(cfg.d_model, "o")(out)
+            if self.axis_name is not None:  # the other shares' heads
+                out = jax.lax.psum(out, self.axis_name)
         if not cfg.index_top_k:
             return out
         return out, dsa_index_loss(

@@ -109,6 +109,17 @@ that normalise their exit), ``kda.chunks`` (chunks a forward call
 visits: ``B H S_pad / C``), ``kda.state_bytes_saved`` (bytes of entry
 states a forward call leaves for its backward).
 
+**The scalar-gate form** (Gated DeltaNet). ``g`` of shape ``[B, S, H]``,
+ONE log-decay a head and position, selects it; no flag does. ``exp(G_i -
+G_j)`` is then one ``[C, C]`` matrix a head that multiplies ``Q K^T`` and
+``K K^T`` whole, no sub-block is formed, and a head's widths need be no
+whole 128-lane tiles: the section "The scalar-gate form" below has the
+chunk, the grid and how the lanes are filled. The inverse, ``U``, the entry
+states, ``conv`` and ``out_norm`` are shared. Kernel names ``hvd_gdn_fwd``,
+``hvd_gdn_bwd``; counters ``gdn.*`` as ``kda.*`` above, and
+``gdn.calls.lanes_padded`` (kernels built for heads that are no whole lane
+tiles).
+
 ``use_kernel=False`` (the default off the TPU) is the recurrence itself, a
 ``lax.scan`` a position in float32 under ``jax.checkpoint`` a group of
 positions: the differential of the tests and the CPU path of the model.
@@ -180,6 +191,8 @@ class _Plan(NamedTuple):
     taps: int = 0  # of the convolution the kernels do at their door; 0: none
     # eps of the head-wise RMS norm the kernels do at their exit; None: none
     out_norm: Optional[float] = None
+    # the scalar-gate form (``g`` one a head): every head a grid step
+    scalar: bool = False
 
     @property
     def n_chunks(self) -> int:
@@ -194,7 +207,7 @@ class _Plan(NamedTuple):
 def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
           sub: Optional[int], interpret: Optional[bool],
           conv: Optional[KdaConv] = None,
-          out_norm: Optional[float] = None) -> _Plan:
+          out_norm: Optional[float] = None, scalar: bool = False) -> _Plan:
     b, s, width = q.shape
     h = n_heads
     if width % h or v.shape[-1] % h or beta.shape != (b, s, h):
@@ -208,7 +221,9 @@ def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
     sub = min(sub or SUB, chunk)
     if chunk % sub or sub & (sub - 1) or (chunk // sub) & (chunk // sub - 1):
         raise ValueError(f"chunk {chunk} in sub-blocks of {sub}")
-    if not interpret and (dk % 128 or dv % 128 or chunk % 8):
+    # the scalar-gate form holds every head's lanes in one block as wide as
+    # the operand and cuts a head out of it in VMEM: any width compiles
+    if not interpret and not scalar and (dk % 128 or dv % 128 or chunk % 8):
         raise ValueError(
             f"compiled for the TPU a head is a whole number of 128-lane "
             f"tiles: d_k {dk}, d_v {dv}"
@@ -236,7 +251,7 @@ def _plan(q, v, beta, *, n_heads: int, chunk: Optional[int],
     return _Plan(
         b, s, -(-s // block) * block, h, dk, dv, chunk, sub, block,
         interpret, q.dtype, taps,
-        None if out_norm is None else float(out_norm),
+        None if out_norm is None else float(out_norm), scalar,
     )
 
 
@@ -483,6 +498,31 @@ def _convolve(x_ref, halo_ref, taps_ref, stage, out, slope, at_start):
         slope[...] = gate * (1.0 + c * (1.0 - gate))
 
 
+def _door_backward(p: _Plan, taps, stages, ahead, dtaps, d_refs):
+    """The convolution's transpose, at the end of a backward grid step: a
+    row's gradient reaches the rows up to ``n - 1`` BEFORE it, so the
+    block's first rows wait in ``ahead`` for the block before, which the
+    next grid step works. ``dtaps[x]``'s block is ``[..., _EDGE, d]`` with
+    unit axes in front."""
+    for x, d_ref in enumerate(d_refs):
+        grads, d_taps = ahead[x], dtaps[x]
+        lead = (0,) * (len(d_taps.shape) - 2)
+        mine = stages[x][_EDGE:, :]  # the block's own rows, float32
+        tap = lax.broadcasted_iota(jnp.int32, d_taps.shape[-2:], 0)
+        dx = jnp.zeros_like(mine)
+        dw = jnp.zeros(d_taps.shape[-2:], jnp.float32)
+        for i in range(p.taps):
+            later = grads[pl.ds(p.taps - 1 - i, p.block), :]
+            dx = dx + taps[x][i:i + 1, :] * later
+            dw = dw + jnp.where(
+                tap == i, jnp.sum(later * mine, axis=0, keepdims=True),
+                0.0,
+            )
+        d_ref[0] = dx.astype(d_ref.dtype)
+        d_taps[lead] += dw
+        grads[p.block:, :] = grads[:_EDGE, :]
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -611,7 +651,24 @@ def _pad(x, p: _Plan):
 
 
 def _book(p: _Plan, forward: bool) -> None:
+    # each form books its own counters, under literal names
+    # (``tools/check_metric_names.py`` reads them off the source)
     reg = _registry.always()
+    if p.scalar:
+        reg.counter("gdn.calls").inc()
+        if p.taps:
+            reg.counter("gdn.calls.conv").inc()
+        if p.out_norm is not None:
+            reg.counter("gdn.calls.out_norm").inc()
+        if p.dk % 128 or p.dv % 128:
+            # a head's lanes are cut out of the operand-wide block and
+            # padded to whole 128-lane tiles in VMEM (the one lane filling
+            # that form has)
+            reg.counter("gdn.calls.lanes_padded").inc()
+        if forward:
+            reg.counter("gdn.chunks").inc(p.b * p.h * p.n_chunks)
+            reg.counter("gdn.state_bytes_saved").inc(p.state_bytes)
+        return
     reg.counter("kda.calls").inc()
     if p.taps:
         reg.counter("kda.calls.conv").inc()
@@ -829,25 +886,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     _write_as_rows(dbeta_ref, dbeta, p)
 
     if p.taps:
-        # the convolution's transpose: a row's gradient reaches the rows
-        # up to n - 1 BEFORE it, so the block's first rows wait in
-        # ``ahead`` for the block before, which the next grid step works
-        for x, d_ref in enumerate((dq_ref, dk_ref, dv_ref)):
-            grads, d_taps = ahead[x], dtaps[x]
-            mine = stages[x][_EDGE:, :]  # the block's own rows, float32
-            tap = lax.broadcasted_iota(jnp.int32, d_taps.shape[2:], 0)
-            dx = jnp.zeros_like(mine)
-            dw = jnp.zeros(d_taps.shape[2:], jnp.float32)
-            for i in range(p.taps):
-                later = grads[pl.ds(p.taps - 1 - i, p.block), :]
-                dx = dx + taps[x][i:i + 1, :] * later
-                dw = dw + jnp.where(
-                    tap == i, jnp.sum(later * mine, axis=0, keepdims=True),
-                    0.0,
-                )
-            d_ref[0] = dx.astype(d_ref.dtype)
-            d_taps[0, 0] += dw
-            grads[p.block:, :] = grads[:_EDGE, :]
+        _door_backward(p, taps, stages, ahead, dtaps,
+                       (dq_ref, dk_ref, dv_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("p",), inline=True)
@@ -918,6 +958,376 @@ def _bwd_call(q, k, v, g, beta, states, d_out, conv=None, normed=None, *,
         ))
 
 
+# ---------------------------------------------------------------------------
+# The scalar-gate form (Gated DeltaNet): ``g`` is ONE log-decay a head and
+# position, so the decay between two rows of a chunk is one ``[C, C]``
+# matrix a head, ``D[i, j] = exp(G_i - G_j)`` (``j <= i``: every exponent
+# <= 0), that multiplies ``Q K^T`` and ``K K^T`` whole:
+#
+#     P_kk = tril_strict(K K^T * D)      P_qk = tril(Q K^T * D)
+#
+# and ``e``, ``e_end``, ``e_last`` are a number a row where the
+# channel-wise form has one a row and key channel. No sub-block is formed
+# and nothing is computed pairwise on the VPU. Everything else is the
+# family's: the inverse, ``U``, the entry states kept, the door and the
+# exit.
+#
+# Lanes. A head's key and value widths need be no multiple of 128 (96 and
+# 192 in the configuration that brought the form), so no block spec can cut
+# a head out of ``[B, S, H d]``. A grid step therefore takes a block of
+# rows as wide as the OPERAND (every head's lanes, as HBM holds them, no
+# padding there) and works the heads one after another, each head's tile a
+# static lane slice of the block in VMEM, which Mosaic pads to whole 128-lane
+# tiles there and only there; the grid is ``(batch, 1, blocks)`` (the
+# channel-wise form's with ONE "head" as wide as the operand, so the block
+# specs, the door's and the scratches are shared: ``_whole_width``) and the
+# scratch holds all ``H`` states ``[H, d_v, d_k]``. ``g``, ``beta``, ``dg``,
+# ``dbeta`` and ``rstd`` are ``[B, S, H]`` blocks as they stand: a head's
+# column is read by a lane mask and written into the ``[C, H]`` tile the
+# same way, so nothing leaves as a row vector and the entry has no layout
+# glue but the padding of the sequence. With ``conv`` the door is worked
+# once a grid step over the block's whole width (lane-dense, elementwise).
+# ---------------------------------------------------------------------------
+
+
+def _chunk_scalar(q_raw, k_raw, g, beta, *, sub: int, dt):
+    """``q_raw``, ``k_raw`` float32 ``[C, dk]``; ``g``, ``beta`` ``[C, 1]``.
+    Returns a :class:`_Chunk` whose decays are ``[C, 1]`` (``e_last [1,
+    1]``), and the decay matrix ``[C, C]`` as ``[row, col]`` and turned."""
+    c, dk = k_raw.shape
+    q, rq = _l2(q_raw, NORM_EPS, dk ** -0.5)
+    k, rk = _l2(k_raw, NORM_EPS, 1.0)
+    row, col, _, _ = _masks(c, 1)
+    # inclusive cumsum down the rows, float32 on the VPU
+    big_g = jnp.sum(jnp.where(col <= row, _as_row(g), 0.0), -1, keepdims=True)
+    along = _as_row(big_g)  # [1, C]: G_j on the lanes
+    g_last = big_g[c - 1:c, :]
+    decay = jnp.exp(jnp.minimum(big_g - along, 0.0))  # [i, j]: exp(G_i - G_j)
+    decay_t = jnp.exp(jnp.minimum(along - big_g, 0.0))  # [j, i]: the same
+    both = _nt(jnp.concatenate([k, q], axis=0), k, dt)  # [2C, C]
+    p_kk = jnp.where(col < row, both[:c] * decay, 0.0)
+    p_qk = jnp.where(col <= row, both[c:] * decay, 0.0)
+    ch = _Chunk(
+        q, k, rq, rk, big_g, jnp.exp(big_g), jnp.exp(g_last - big_g),
+        jnp.exp(g_last), None, None, p_kk, p_qk,
+        _inverse(beta * p_kk, min(sub, c), dt == jnp.float32),
+    )
+    return ch, decay, decay_t
+
+
+def _set_column(tile, head: int, column):
+    """``tile [C, H]`` with column ``head`` set to ``column [C, 1]``."""
+    lanes = lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    return jnp.where(lanes == head, column, tile)
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, p: _Plan):
+    c, dt = p.chunk, p.dtype
+    own = (q_ref, k_ref, v_ref)
+    rest = list(rest)
+    halos, taps = (rest.pop(0), rest.pop(0)) if p.taps else (None, None)
+    o_ref, states_ref = rest.pop(0), rest.pop(0)
+    rstd_ref = rest.pop(0) if p.out_norm is not None else None
+    if p.taps:
+        state, stages, convolved = rest
+        at_start = pl.program_id(2) == 0
+        for x in range(3):
+            _convolve(own[x], halos[x], taps[x], stages[x], convolved[x],
+                      None, at_start)
+        read = lambda x, rows, lanes: convolved[x][rows, lanes]  # noqa: E731
+    else:
+        state, = rest
+        read = lambda x, rows, lanes: own[x][0, rows, lanes].astype(  # noqa: E731
+            jnp.float32
+        )
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    for i in range(p.block // c):
+        rows = slice(i * c, (i + 1) * c)
+        g_all, beta_all = g_ref[0, rows, :], beta_ref[0, rows, :]
+        rstd = jnp.zeros((c, p.h), jnp.float32)
+        for head in range(p.h):
+            wide = slice(head * p.dk, (head + 1) * p.dk)
+            deep = slice(head * p.dv, (head + 1) * p.dv)
+            beta = _head_column(beta_all, head)
+            ch, _, _ = _chunk_scalar(
+                read(0, rows, wide), read(1, rows, wide),
+                _head_column(g_all, head), beta, sub=p.sub, dt=dt,
+            )
+            w = _nn(ch.inv, beta * (ch.k * ch.e), dt)
+            u_hat = _nn(ch.inv, beta * read(2, rows, deep), dt)
+            s0 = state[head]  # [dv, dk]
+            states_ref[0, i, head] = s0.astype(states_ref.dtype)
+            u = u_hat - _nt(w, s0, dt)
+            o = _nt(ch.q * ch.e, s0, dt) + _nn(ch.p_qk, u, dt)
+            if p.out_norm is not None:  # the float32 tile, before rounding
+                r = lax.rsqrt(
+                    jnp.mean(o * o, axis=-1, keepdims=True) + p.out_norm
+                )
+                rstd = _set_column(rstd, head, r)
+                o = o * r
+            o_ref[0, rows, deep] = o.astype(o_ref.dtype)
+            state[head] = s0 * ch.e_last + _tn(u, ch.k * ch.e_end, dt)
+        if p.out_norm is not None:
+            rstd_ref[0, rows, :] = rstd
+
+
+def _whole_width(p: _Plan) -> _Plan:
+    """The plan as the scalar-gate kernels' block specs and scratches see
+    it: ONE "head" as wide as the operand, under a grid ``(batch, 1,
+    blocks)``, so that :func:`_specs`, :func:`_door_specs` and
+    :func:`_tiles` serve both forms."""
+    return p._replace(dk=p.h * p.dk, dv=p.h * p.dv)
+
+
+def _gdn_states_spec(p: _Plan, block_of):
+    return pl.BlockSpec(
+        (1, p.block // p.chunk, p.h, p.dv, p.dk),
+        lambda bi, hi, i: (bi, block_of(i), 0, 0, 0), memory_space=_VMEM,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("p",), inline=True)
+def _gdn_fwd_call(q, k, v, g, beta, conv=None, *, p: _Plan):
+    with jax.named_scope(_GLUE_SCOPE):
+        q, k, v, g, beta = (_pad(x, p) for x in (q, k, v, g, beta))
+    w = _whole_width(p)
+    wide, per_head, _ = _specs(w, lambda i: i)
+    in_specs = [wide(w.dk), wide(w.dk), wide(w.dv), per_head, per_head]
+    operands = [q, k, v, g, beta]
+    scratch = [_VMEM((p.h, p.dv, p.dk), jnp.float32)]
+    if p.taps:
+        in_specs += _door_specs(w, lambda i: i)
+        operands += [(q, k, v), tuple(conv)]
+        scratch += [_tiles(w, _EDGE + p.block), _tiles(w, p.block)]
+    out_specs = [wide(w.dv), _gdn_states_spec(p, lambda i: i)]
+    out_shape = [
+        jax.ShapeDtypeStruct((p.b, p.s_pad, w.dv), p.dtype),
+        jax.ShapeDtypeStruct((p.b, p.n_chunks, p.h, p.dv, p.dk), p.dtype),
+    ]
+    if p.out_norm is not None:
+        out_specs.append(per_head)
+        out_shape.append(
+            jax.ShapeDtypeStruct((p.b, p.s_pad, p.h), jnp.float32)
+        )
+    out, states, *rstd = pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, p=p),
+        grid=(p.b, 1, p.s_pad // p.block),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_params(),
+        interpret=p.interpret,
+        name="hvd_gdn_fwd",
+    )(*operands)
+    with jax.named_scope(_GLUE_SCOPE):
+        if rstd:
+            return out[:, :p.s], states, rstd[0][:, :p.s]
+        return out[:, :p.s], states
+
+
+def _gdn_pair_backward(ch: _Chunk, decay, decay_t, dp_kk, dp_qk, dp_kk_t,
+                       dp_qk_t, *, dt):
+    """The scalar form's :func:`_pair_backward`: the decay matrix
+    multiplies the gradients of ``P`` whole, then three matmuls."""
+    c = ch.k.shape[0]
+    rows_out = _nn(
+        jnp.concatenate([dp_kk * decay, dp_qk * decay], axis=0), ch.k, dt
+    )  # [2C, dk]
+    dk_col = _nn(
+        jnp.concatenate([dp_kk_t * decay_t, dp_qk_t * decay_t], axis=1),
+        jnp.concatenate([ch.k, ch.q], axis=0), dt,
+    )
+    return rows_out[c:], rows_out[:c], dk_col
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
+                    *rest, p: _Plan):
+    c, dt = p.chunk, p.dtype
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    own = (q_ref, k_ref, v_ref)
+    rest = list(rest)
+    if p.out_norm is not None:  # the forward's normalised output and 1 / rms
+        out_ref, rstd_ref = rest.pop(0), rest.pop(0)
+    if p.taps:
+        (halos, taps, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dtaps,
+         dstate, stages, convolved, slopes, ahead) = rest
+        at_start = pl.program_id(2) == p.s_pad // p.block - 1
+        for x in range(3):
+            _convolve(own[x], halos[x], taps[x], stages[x], convolved[x],
+                      slopes[x], at_start)
+        read = lambda x, rows, lanes: convolved[x][rows, lanes]  # noqa: E731
+
+        def write(x, rows, lanes, grad):  # before the SiLU, float32, staged
+            ahead[x][rows, lanes] = grad * slopes[x][rows, lanes]
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            for x in range(3):
+                ahead[x][p.block:, :] = jnp.zeros_like(ahead[x][p.block:, :])
+                dtaps[x][...] = jnp.zeros_like(dtaps[x])
+    else:
+        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate = rest
+        read = lambda x, rows, lanes: f32(own[x][0, rows, lanes])  # noqa: E731
+
+        def write(x, rows, lanes, grad):
+            ref = (dq_ref, dk_ref, dv_ref)[x]
+            ref[0, rows, lanes] = grad.astype(ref.dtype)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    row, col, _, _ = _masks(c, 1)
+    for i in reversed(range(p.block // c)):
+        rows = slice(i * c, (i + 1) * c)
+        g_all, beta_all = g_ref[0, rows, :], beta_ref[0, rows, :]
+        dg_tile = jnp.zeros((c, p.h), jnp.float32)
+        dbeta_tile = jnp.zeros((c, p.h), jnp.float32)
+        for head in range(p.h):
+            wide = slice(head * p.dk, (head + 1) * p.dk)
+            deep = slice(head * p.dv, (head + 1) * p.dv)
+            q_raw, k_raw = read(0, rows, wide), read(1, rows, wide)
+            beta = _head_column(beta_all, head)
+            ch, decay, decay_t = _chunk_scalar(
+                q_raw, k_raw, _head_column(g_all, head), beta, sub=p.sub,
+                dt=dt,
+            )
+            s0 = f32(states_ref[0, i, head])  # [dv, dk]
+            k_e, q_e, k_end = ch.k * ch.e, ch.q * ch.e, ch.k * ch.e_end
+            z = read(2, rows, deep) - _nt(k_e, s0, dt)
+            u = _nn(ch.inv, beta * z, dt)
+            ds = dstate[head]  # [dv, dk], of the state this chunk leaves
+            do = f32(do_ref[0, rows, deep])
+            if p.out_norm is not None:  # of o, given that of o rstd
+                o_hat = f32(out_ref[0, rows, deep])
+                do = _head_column(rstd_ref[0, rows, :], head) * (
+                    do - o_hat * jnp.mean(do * o_hat, axis=-1, keepdims=True)
+                )
+            du = _tn(ch.p_qk, do, dt) + _nt(k_end, ds, dt)
+            dr = _tn(ch.inv, du, dt)
+            dz = beta * dr
+            by_row = _nt(jnp.concatenate([dz, do, dr], axis=0), u, dt)
+            by_col = _nt(u, jnp.concatenate([dz, do], axis=0), dt)
+            below, above = col < row, col > row
+            dbeta_tile = _set_column(dbeta_tile, head, (
+                jnp.sum(dr * z, axis=-1, keepdims=True)
+                - jnp.sum(jnp.where(below, by_row[2 * c:], 0.0) * ch.p_kk,
+                          axis=-1, keepdims=True)
+            ))
+            dq_pair, dk_row, dk_col = _gdn_pair_backward(
+                ch, decay, decay_t,
+                jnp.where(below, -by_row[:c], 0.0),
+                jnp.where(col <= row, by_row[c:2 * c], 0.0),
+                jnp.where(above, -by_col[:, :c], 0.0),
+                jnp.where(col >= row, by_col[:, c:], 0.0), dt=dt,
+            )
+            d_kg = -_nn(dz, s0, dt)  # of K e
+            d_qg = _nn(do, s0, dt)  # of Q e
+            d_kend = _nn(u, ds, dt)  # of K e_end
+            dq = d_qg * ch.e + dq_pair
+            dk = d_kg * ch.e + d_kend * ch.e_end + dk_row + dk_col
+            to_end = jnp.sum(d_kend * k_end, axis=-1, keepdims=True)
+            d_big_g = jnp.sum(
+                d_qg * q_e + d_kg * k_e + ch.q * dq_pair
+                + ch.k * (dk_row - dk_col), axis=-1, keepdims=True,
+            ) - to_end
+            # G_C, the last row's: every row's decay to the chunk's end
+            # and the entry state's own
+            to_last = jnp.sum(to_end, axis=0, keepdims=True) + ch.e_last * (
+                jnp.sum(jnp.sum(s0 * ds, axis=-1, keepdims=True), axis=0,
+                        keepdims=True)
+            )
+            d_big_g = d_big_g + jnp.where(
+                lax.broadcasted_iota(jnp.int32, d_big_g.shape, 0) == c - 1,
+                to_last, 0.0,
+            )
+            dg_tile = _set_column(dg_tile, head, jnp.sum(
+                jnp.where(col >= row, _as_row(d_big_g), 0.0), -1,
+                keepdims=True,
+            ))
+            write(0, rows, wide, _l2_bwd(q_raw, ch.rq, p.dk ** -0.5, dq))
+            write(1, rows, wide, _l2_bwd(k_raw, ch.rk, 1.0, dk))
+            write(2, rows, deep, dz)
+            dstate[head] = ds * ch.e_last + _tn(
+                jnp.concatenate([do, -dz], axis=0),
+                jnp.concatenate([q_e, k_e], axis=0), dt,
+            )
+        dg_ref[0, rows, :] = dg_tile
+        dbeta_ref[0, rows, :] = dbeta_tile
+
+    if p.taps:  # over the block's whole width
+        _door_backward(p, taps, stages, ahead, dtaps,
+                       (dq_ref, dk_ref, dv_ref))
+
+
+@functools.partial(jax.jit, static_argnames=("p",), inline=True)
+def _gdn_bwd_call(q, k, v, g, beta, states, d_out, conv=None, normed=None, *,
+                  p: _Plan):
+    with jax.named_scope(_GLUE_SCOPE):
+        q, k, v, g, beta, d_out = (
+            _pad(x, p) for x in (q, k, v, g, beta, d_out)
+        )
+    last = p.s_pad // p.block - 1
+    w = _whole_width(p)
+    wide, per_head, _ = _specs(w, lambda i: last - i)
+    like = lambda x, dtype: jax.ShapeDtypeStruct(x.shape, dtype)  # noqa: E731
+    in_specs = [wide(w.dk), wide(w.dk), wide(w.dv), per_head, per_head,
+                _gdn_states_spec(p, lambda i: last - i), wide(w.dv)]
+    operands = [q, k, v, g, beta, states, d_out]
+    out_specs = [wide(w.dk), wide(w.dk), wide(w.dv), per_head, per_head]
+    out_shape = [
+        like(q, p.dtype), like(k, p.dtype), like(v, p.dtype),
+        like(g, jnp.float32), like(beta, jnp.float32),
+    ]
+    scratch = [_VMEM((p.h, p.dv, p.dk), jnp.float32)]
+    if p.out_norm is not None:
+        with jax.named_scope(_GLUE_SCOPE):
+            operands += [_pad(x, p) for x in normed]
+        in_specs += [wide(w.dv), per_head]
+    if p.taps:
+        in_specs += _door_specs(w, lambda i: last - i)
+        operands += [(q, k, v), tuple(conv)]
+        # the taps' gradients: one block a batch row that the sequence axis
+        # revisits, rows >= n zero
+        widths = (w.dk, w.dk, w.dv)
+        out_specs.append(tuple(
+            pl.BlockSpec((1, _EDGE, d), lambda bi, hi, i: (bi, 0, 0),
+                         memory_space=_VMEM)
+            for d in widths
+        ))
+        out_shape.append(tuple(
+            jax.ShapeDtypeStruct((p.b, _EDGE, d), jnp.float32)
+            for d in widths
+        ))
+        scratch += [_tiles(w, _EDGE + p.block), _tiles(w, p.block),
+                    _tiles(w, p.block), _tiles(w, p.block + _EDGE)]
+    dq, dk, dv, dg, dbeta, *d_taps = pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, p=p),
+        grid=(p.b, 1, p.s_pad // p.block),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_params(),
+        interpret=p.interpret,
+        name="hvd_gdn_bwd",
+    )(*operands)
+    with jax.named_scope(_GLUE_SCOPE):
+        grads = tuple(x[:, :p.s] for x in (dq, dk, dv, dg, dbeta))
+    if not p.taps:
+        return grads, None
+    with jax.named_scope(_CONV_SCOPE):
+        # [B, _EDGE, H d] partial sums -> [n, H d], summed over the batch
+        return grads, KdaConv(*(
+            x.sum(axis=0)[:p.taps] for x in d_taps[0]
+        ))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _kda(q, k, v, g, beta, conv, p: _Plan):
     return _kda_fwd(q, k, v, g, beta, conv, p)[0]
@@ -925,7 +1335,8 @@ def _kda(q, k, v, g, beta, conv, p: _Plan):
 
 def _kda_fwd(q, k, v, g, beta, conv, p: _Plan):
     _book(p, forward=True)
-    out, states, *rstd = _fwd_call(q, k, v, g, beta, conv, p=p)
+    call = _gdn_fwd_call if p.scalar else _fwd_call
+    out, states, *rstd = call(q, k, v, g, beta, conv, p=p)
     # with the exit norm the normalised output is the backward's too: the
     # one copy kept, as the caller keeps it
     normed = (out, *rstd) if rstd else None
@@ -935,7 +1346,8 @@ def _kda_fwd(q, k, v, g, beta, conv, p: _Plan):
 def _kda_bwd(p: _Plan, residuals, d_out):
     _book(p, forward=False)
     *operands, conv, normed = residuals
-    grads, d_taps = _bwd_call(*operands, d_out, conv, normed, p=p)
+    call = _gdn_bwd_call if p.scalar else _bwd_call
+    grads, d_taps = call(*operands, d_out, conv, normed, p=p)
     return (*grads, d_taps)
 
 
@@ -983,6 +1395,12 @@ def kda_recurrence(q, k, v, g, beta, *, n_heads: int, group: int = 64):
     return out
 
 
+def _scalar_gate(q, g, n_heads: int) -> bool:
+    """Whether ``g`` is one log-decay a head (``[B, S, H]``: the scalar-gate
+    form, Gated DeltaNet) and not one a key channel (``[B, S, H d_k]``)."""
+    return g.shape[-1] == n_heads != q.shape[-1]
+
+
 def kda_attention(q, k, v, g, beta, *, n_heads: int,
                   conv: Optional[KdaConv] = None,
                   out_norm: Optional[float] = None,
@@ -994,7 +1412,9 @@ def kda_attention(q, k, v, g, beta, *, n_heads: int,
     d_v]`` in ``v``'s dtype. ``q`` and ``k`` are normalised here (L2 over a
     head, eps 1e-6; ``q`` also times ``d_k^-1/2``); ``g`` is the log-decay
     of each key channel (<= 0) and ``beta`` the write strength, both
-    float32. Differentiable in all five.
+    float32. Differentiable in all five. ``g`` may instead be ``[B, S, H]``,
+    one log-decay a head (Gated DeltaNet): the scalar-gate form of the
+    kernels, whose heads may be of any width (``d_k`` 96, ``d_v`` 192).
 
     ``conv``: a :class:`KdaConv` of three tap arrays ``[n, H * d]`` float32
     (kernels only). ``q``, ``k``, ``v`` are then the PROJECTIONS' outputs:
@@ -1034,7 +1454,8 @@ def kda_attention(q, k, v, g, beta, *, n_heads: int,
             v.dtype
         )
     p = _plan(q, v, beta, n_heads=n_heads, chunk=chunk, sub=sub,
-              interpret=interpret, conv=conv, out_norm=out_norm)
+              interpret=interpret, conv=conv, out_norm=out_norm,
+              scalar=_scalar_gate(q, g, n_heads))
     if conv is not None:
         conv = KdaConv(*(w.astype(jnp.float32) for w in conv))
     return _kda(

@@ -1,4 +1,4 @@
-"""Tiny builds of the five model families and a walk over a traced step,
+"""Tiny builds of the six model families and a walk over a traced step,
 for the tests that hold the models' parts (``jax.named_scope``) to their
 rules: ``test_step_tracing.py`` on the CPU, ``test_tpu_compile.py`` compiled
 for the described v5e. Widths are the smallest the compiled kernels take
@@ -15,6 +15,7 @@ PARTS = ("embed", "norm", "attn_proj", "attn_xla", "attn_layout", "mlp",
          "head")
 EXPERT_SCOPES = ("mla_proj", "moe_route", "moe_experts")
 KDA_SCOPES = ("kda_proj", "kda_conv", "kda_gate")
+GDN_SCOPES = ("gdn_proj", "gdn_gate")
 SELECT_SCOPES = ("index_proj",)
 SEQ = 128
 
@@ -147,6 +148,29 @@ def _linear_moe():
     return "LinearMoELM", build
 
 
+def _linear_dense():
+    from horovod_tpu.models.linear_dense import (
+        LinearDenseConfig, LinearDenseLM, lm_loss,
+    )
+
+    def build(use_flash):
+        # the published head widths (96 / 192 off the lanes, 128 on them);
+        # both kernel families or neither
+        cfg = LinearDenseConfig.tiny(
+            d_model=128, head_dim=128, gdn_key_dim=96, gdn_value_dim=192,
+            use_flash=use_flash, use_kernel=use_flash,
+        )
+        model = LinearDenseLM(cfg)
+
+        def loss(model, params, tokens):
+            logits = model.apply({"params": params}, tokens[:, :-1])
+            return lm_loss(logits, None, tokens, mtp_weight=0.0)
+
+        return model, _lm(model, loss), {"tokens": (SEQ + 1,)}
+
+    return "LinearDenseLM", build
+
+
 # id -> (family, use_flash); the golden parameter paths are per family
 CASES = {
     "gpt2-flash": ("gpt2", True),
@@ -162,6 +186,8 @@ CASES = {
     "select-moe-xla": ("select_moe", False),
     "linear-moe-kernels": ("linear_moe", True),
     "linear-moe-xla": ("linear_moe", False),
+    "linear-dense-kernels": ("linear_dense", True),
+    "linear-dense-xla": ("linear_dense", False),
 }
 _FAMILIES = {
     "gpt2": lambda: _gpt2(),
@@ -177,6 +203,7 @@ _FAMILIES = {
         index_blocks=(128, 128),
     ),
     "linear_moe": _linear_moe,
+    "linear_dense": _linear_dense,
 }
 
 

@@ -153,7 +153,8 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
     ``custom_vjp`` makes for the unused ``lse``): no device time, no rule."""
     top, jaxpr = _traced_step(world, case)
     one_of = (model_parts.PARTS + model_parts.EXPERT_SCOPES
-              + model_parts.KDA_SCOPES + model_parts.SELECT_SCOPES)
+              + model_parts.KDA_SCOPES + model_parts.SELECT_SCOPES
+              + model_parts.GDN_SCOPES)
     inside, kernels, seen = 0, 0, set()
     for primitive, stack, computed in model_parts.operations(jaxpr.jaxpr):
         if top not in stack:
@@ -166,7 +167,7 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
             kernels += 1
             assert not held, (stack, held)
             assert segments[-1].startswith(
-                ("hvd_flash_", "hvd_kda_", "hvd_dsa_")
+                ("hvd_flash_", "hvd_kda_", "hvd_dsa_", "hvd_gdn_")
             ), stack
         elif computed:
             assert len(held) == 1, (primitive, stack, held)
@@ -175,8 +176,9 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
     family, use_flash = model_parts.CASES[case]
     # 3 and 4 blocks; one latent layer's 3 and four KDA layers' 2 each
     # ... two sparse layers' 5 each (select, three masked flash, index loss)
+    # ... three Gated DeltaNet layers' 2 each and one full layer's 3
     per_family = {"latent_moe": 9, "window_moe": 12, "linear_moe": 11,
-                  "select_moe": 10}
+                  "select_moe": 10, "linear_dense": 9}
     assert kernels == (per_family.get(family, 6) if use_flash else 0)
     # each family opens what the table in docs/api.md says it does
     attention = {"attn_layout"} if use_flash else {"attn_xla"}
@@ -193,6 +195,9 @@ def test_every_operation_of_apply_carries_exactly_one_part(world, case):
         want = {"embed", "norm", "mlp", "head", "attn_layout",
                 *model_parts.EXPERT_SCOPES,
                 *model_parts.KDA_SCOPES} | attention
+    elif family == "linear_dense":  # the kernels' entry opens ``kda_conv``
+        want = {"embed", "norm", "mlp", "head", "attn_proj", "attn_layout",
+                "kda_conv", *model_parts.GDN_SCOPES} | attention
     else:
         want = {"embed", "norm", "mlp", "head", "attn_proj"} | attention
     assert seen == want

@@ -130,7 +130,7 @@ def _described_family_step(topo, case):
 
 @pytest.mark.parametrize(
     "case", ["gpt2-flash", "bert-cls-padded", "latent-moe-flash",
-             "window-moe-flash"]
+             "window-moe-flash", "linear-dense-kernels"]
 )
 def test_parts_change_the_compiled_step_in_its_metadata_only(
     v5e_topology, case
@@ -150,7 +150,7 @@ def test_parts_change_the_compiled_step_in_its_metadata_only(
         bare, bare_full = _described_family_step(v5e_topology, case)
     assert scoped == bare
     kernels = {"bert-cls-padded": 0, "latent-moe-flash": 9,
-               "window-moe-flash": 12}.get(case, 6)
+               "window-moe-flash": 12, "linear-dense-kernels": 9}.get(case, 6)
     assert scoped.count("tpu_custom_call") == kernels
     part = re.compile(r'op_name="[^"]*/(?:%s)/' % "|".join(model_parts.PARTS))
     assert part.search(scoped_full) and not part.search(bare_full)
@@ -308,6 +308,50 @@ def test_kda_attention_fwd_bwd_compiles(v5e, batch, seq, heads, conv, norm):
               if "tpu_custom_call" in line and "hvd_kda_fwd" in line]
     assert (f"f32[{batch},{heads},1,{padded}]" in fwd.split(" custom-call(")[0]
             ) == norm
+
+
+@pytest.mark.parametrize(
+    "seq,door", [(1000, False), (2048, True)],
+    ids=["gdn-s1000-padded", "gdn-s2048-conv-norm"],
+)
+def test_gdn_attention_fwd_bwd_compiles(v5e, seq, door):
+    """The scalar-gate kernels, forward and backward, at the Olmo-Hybrid
+    cell's head widths (96 key and 192 value channels, no whole 128-lane
+    tiles: five heads put a head's first lane at every residue 96 steps
+    reach, 0 / 96 / 64 / 32) on bfloat16 operands that HBM holds at their
+    own widths, with and without the door and the exit: two Mosaic calls,
+    the chunks' entry states ``[B, chunks, H, 192, 96]`` the only array
+    between them that the entry did not take or hand back; ``dg``,
+    ``dbeta`` and ``1 / rms`` leave as ``[B, S, H]``, no row vectors."""
+    from horovod_tpu.ops.kda_kernels import KdaConv, kda_attention
+
+    heads, dk, dv = 5, 96, 192
+
+    def loss(q, k, v, g, beta, *taps):
+        return kda_attention(
+            q, k, v, g, beta, n_heads=heads, use_kernel=True,
+            conv=KdaConv(*taps) if door else None,
+            out_norm=1e-6 if door else None, interpret=False,
+        ).astype(jnp.float32).sum()
+
+    keys = ((1, seq, dk * heads), jnp.bfloat16)
+    values = ((1, seq, dv * heads), jnp.bfloat16)
+    gate = ((1, seq, heads), jnp.float32)
+    taps = [((4, d * heads), jnp.float32) for d in (dk, dk, dv)] * door
+    hlo = _compile(
+        jax.grad(loss, argnums=tuple(range(5 + len(taps)))), v5e, keys, keys,
+        values, gate, gate, *taps,
+    )
+    assert hlo.count("tpu_custom_call") == 2
+    assert "hvd_gdn_fwd" in hlo and "hvd_gdn_bwd" in hlo
+    assert "hvd_kda_" not in hlo
+    padded = -(-seq // 128) * 128
+    assert f"bf16[1,{padded // 64},{heads},{dv},{dk}]" in hlo
+    assert (f"f32[1,8,{dv * heads}]" in hlo) == door  # the taps' partials
+    (fwd,) = [line for line in hlo.splitlines()
+              if "tpu_custom_call" in line and "hvd_gdn_fwd" in line]
+    assert (f"f32[1,{padded},{heads}]" in fwd.split(" custom-call(")[0]
+            ) == door
 
 
 def test_kda_mixer_turns_no_float32_heads_around_its_norm(v5e_topology, v5e):

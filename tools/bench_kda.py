@@ -1,7 +1,8 @@
-"""Kernels only, on the chip: the Kimi Delta Attention family by name.
+"""Kernels only, on the chip: the delta-rule kernel family by name.
 
     python tools/bench_kda.py [--tree CHECKOUT] [--iters 8] [--shape NAME]
-        [--heads 32] [--sub N ...] [--block-chunks N ...] [--no-recurrence]
+        [--heads 32] [--dk 96 --dv 192] [--gate channel|scalar|both]
+        [--sub N ...] [--block-chunks N ...] [--no-recurrence]
         [--conv] [--out-norm]
 
 Runs forward + backward of ``ops/kda_kernels.kda_attention`` alone (one
@@ -39,12 +40,24 @@ operation, microseconds a call), and what XLA still does behind kernels
 that normalise (``xla-gate``: ``o^ x tile(scale) x sigmoid(gate)``,
 elementwise, forward + backward): what the kernels' exit costs beside what
 it replaces.
+``--gate scalar`` times the scalar-gate form (Gated DeltaNet: ``g`` one
+log-decay a head, kernels ``hvd_gdn_fwd`` / ``hvd_gdn_bwd``; the floor is
+``benchmark/lib/flops_linear_dense.gdn_cost``'s), ``--gate both`` that form
+and, beside it on the same operands, the channel-wise kernels with ``g``
+repeated over a head's key channels (where a head's widths are no whole
+128-lane tiles, which that form refuses compiled, each head zero-padded to
+the next tile: ``padded_to`` in its line, no errors printed for it). The
+default is ``channel``, or ``both`` where ``--dk`` / ``--dv`` or a ``gdn-``
+shape is given: ``--shape gdn-1x8192`` is ``--dk 96 --dv 192 --heads 15``,
+the Olmo-Hybrid cell's. ``--conv`` / ``--out-norm`` print their tables for
+the scalar form where it is asked for.
 ``--sub`` / ``--block-chunks`` (may repeat) time the plan's statics at other
 values than the module's; ``--tree`` imports ``horovod_tpu`` and
 ``benchmark`` from another checkout, as ``tools/bench_attention.py`` does.
 """
 import argparse
 import functools
+import itertools
 import json
 import os
 import sys
@@ -58,8 +71,34 @@ import numpy as np
 from bench_attention import device_events, kernel_us
 
 KERNELS = ("hvd_kda_fwd", "hvd_kda_bwd")
+# the form a gate's shape chooses -> the kernels it builds
+FORMS = {"channel": KERNELS, "scalar": ("hvd_gdn_fwd", "hvd_gdn_bwd")}
 # name: (batch, sequence, heads, key channels, value channels)
-SHAPES = {"kda-1x8192": (1, 8192, 32, 128, 128)}
+SHAPES = {"kda-1x8192": (1, 8192, 32, 128, 128),
+          "gdn-1x8192": (1, 8192, 15, 96, 192)}
+
+
+def pad_heads(x, h, to):
+    """``x [B, S, h d]`` with each head zero-padded to ``to`` columns."""
+    b, s, width = x.shape
+    heads = x.reshape(b, s, h, width // h)
+    return jnp.pad(
+        heads, ((0, 0), (0, 0), (0, 0), (0, to - width // h))
+    ).reshape(b, s, h * to)
+
+
+def channel_wise(argv, h, dk, dv):
+    """The scalar-gate operands for the channel-wise kernels: ``g``
+    repeated over a head's key channels, heads padded to whole 128-lane
+    tiles where they are none. Returns ``(argv, padded (dk, dv) or None)``."""
+    q, k, v, g, beta, w = argv
+    to_k, to_v = -(-dk // 128) * 128, -(-dv // 128) * 128
+    g = jnp.repeat(g, to_k, axis=-1)
+    if (to_k, to_v) == (dk, dv):
+        return [q, k, v, g, beta, w], None
+    return [pad_heads(q, h, to_k), pad_heads(k, h, to_k),
+            pad_heads(v, h, to_v), g, beta, pad_heads(w, h, to_v)], (to_k,
+                                                                   to_v)
 
 
 def operands(key, b, s, h, dk, dv):
@@ -129,14 +168,15 @@ def conv_table(args, kda_kernels, argv, h, dk, dv, emit):
     attend = functools.partial(kda_kernels.kda_attention, n_heads=h,
                                use_kernel=True)
     plain = grads(attend, 5)
-    emit("kernels", kernel_us(plain, (q, k, v, g, beta), args.iters, KERNELS))
+    emit("kernels", kernel_us(plain, (q, k, v, g, beta), args.iters,
+                              args.kernels))
     if hasattr(kda_kernels, "KdaConv"):
         outside = grads(lambda q, k, v, g, beta, *t: attend(
             *(conv_silu(x, c) for x, c in zip((q, k, v), t)), g, beta), 8)
         inside = grads(lambda q, k, v, g, beta, *t: attend(
             q, k, v, g, beta, conv=kda_kernels.KdaConv(*t)), 8)
         operands = (*raw, g, beta, *taps)
-        us = kernel_us(inside, operands, args.iters, KERNELS)
+        us = kernel_us(inside, operands, args.iters, args.kernels)
         emit("kernels+conv", us, abs_err_vs_conv_silu_then_kernels=errors(
             inside(*operands), outside(*operands)
         ))
@@ -173,12 +213,12 @@ def norm_table(args, kda_kernels, argv, h, dk, dv, emit):
         )
 
     grads = functools.partial(weighted_grads, n=len(operands), w=w)
-    emit(row, kernel_us(grads(call), operands, args.iters, KERNELS))
+    emit(row, kernel_us(grads(call), operands, args.iters, args.kernels))
     inside = grads(functools.partial(call, out_norm=NORM_EPS))
     behind = grads(lambda *a: head_norm(call(*a)).reshape(b, s, width).astype(
         v.dtype
     ))
-    us = kernel_us(inside, operands, args.iters, KERNELS)
+    us = kernel_us(inside, operands, args.iters, args.kernels)
     emit(row + "+out_norm", us, abs_err_vs_norm_behind_kernels=errors(
         inside(*operands), behind(*operands)
     ))
@@ -212,6 +252,11 @@ def main():
     ap.add_argument("--iters", type=int, default=8)
     ap.add_argument("--shape", choices=sorted(SHAPES), default="kda-1x8192")
     ap.add_argument("--heads", type=int, help="default: the shape's own")
+    ap.add_argument("--dk", type=int, help="key channels a head")
+    ap.add_argument("--dv", type=int, help="value channels a head")
+    ap.add_argument("--gate", choices=("channel", "scalar", "both"),
+                    help="the log-decay: one a key channel, one a head, or "
+                         "both forms on the same operands")
     ap.add_argument("--sub", type=int, action="append",
                     help="rows a sub-block (default: the module's)")
     ap.add_argument("--block-chunks", type=int, action="append",
@@ -242,8 +287,17 @@ def main():
         )
     peak = peak_for(device.device_kind)
     b, s, h, dk, dv = SHAPES[args.shape]
-    h = args.heads or h
+    if args.gate is None:
+        other = args.dk or args.dv or args.shape.startswith("gdn-")
+        args.gate = "both" if other else "channel"
+    h, dk, dv = args.heads or h, args.dk or dk, args.dv or dv
     argv = operands(jax.random.PRNGKey(0), b, s, h, dk, dv)
+    scalar = args.gate != "channel"
+    if scalar:  # one log-decay a head: each head's first channel's
+        from benchmark.lib.flops_linear_dense import gdn_cost as kda_cost
+
+        argv[3] = argv[3][..., ::dk]
+    args.kernels = FORMS["scalar" if scalar else "channel"]
     cost = kda_cost(batch=b, seq_len=s, n_heads=h, d_k=dk, d_v=dv, layers=1)
     floor = roofline(cost["flops"], cost["bytes"], peak.bf16_flops,
                      peak.hbm_bytes_per_s)
@@ -274,33 +328,38 @@ def main():
         want = jax.block_until_ready(plain(*argv))
         seconds = time.perf_counter() - start
     module_chunks = kda_kernels.BLOCK_CHUNKS
-    for sub in args.sub or [None]:
-        for chunks in args.block_chunks or [module_chunks]:
-            kda_kernels.BLOCK_CHUNKS = chunks
-            fn = grads(True, sub=sub)
-            us = kernel_us(fn, argv, args.iters, KERNELS)
-            errors = None
-            if want is not None:
-                got = fn(*argv)
-                errors = {
-                    name: float(jnp.max(jnp.abs(
-                        a.astype(jnp.float32) - e.astype(jnp.float32)
-                    ))) for name, a, e in zip(
-                        ("dq", "dk", "dv", "dg", "dbeta", "out"),
-                        (*got[0], got[1]), (*want[0], want[1]),
-                    )
-                }
-            total = sum(us.values())
-            print(json.dumps(dict(
-                tree=args.tree, shape=args.shape, heads=h,
-                sub=sub or kda_kernels.SUB, block_chunks=chunks,
-                device_kind=device.device_kind, us_per_call=us,
-                total_us=total, floor_us=floor["seconds"] * 1e6,
-                floor_bound=floor["bound"],
-                share_of_floor_pct=100.0 * floor["seconds"] * 1e6 / total,
-                abs_err_vs_recurrence=errors,
-                recurrence_wall_s_per_call=seconds,
-            )), flush=True)
+    rows = [("scalar" if scalar else "channel", argv, None)]
+    if args.gate == "both":
+        rows.append(("channel", *channel_wise(argv, h, dk, dv)))
+    for (form, operands_, padded), sub, chunks in itertools.product(
+            rows, args.sub or [None],
+            args.block_chunks or [module_chunks]):
+        kda_kernels.BLOCK_CHUNKS = chunks
+        fn = grads(True, sub=sub)
+        us = kernel_us(fn, operands_, args.iters, FORMS[form])
+        errors = None
+        if want is not None and padded is None:
+            got = fn(*operands_)
+            errors = {
+                name: float(jnp.max(jnp.abs(
+                    a.astype(jnp.float32) - e.astype(jnp.float32)
+                ))) for name, a, e in zip(
+                    ("dq", "dk", "dv", "dg", "dbeta", "out"),
+                    (*got[0], got[1]), (*want[0], want[1]),
+                )
+            }
+        total = sum(us.values())
+        print(json.dumps(dict(
+            tree=args.tree, shape=args.shape, heads=h, dk=dk, dv=dv,
+            gate=form, padded_to=padded,
+            sub=sub or kda_kernels.SUB, block_chunks=chunks,
+            device_kind=device.device_kind, us_per_call=us,
+            total_us=total, floor_us=floor["seconds"] * 1e6,
+            floor_bound=floor["bound"],
+            share_of_floor_pct=100.0 * floor["seconds"] * 1e6 / total,
+            abs_err_vs_recurrence=errors,
+            recurrence_wall_s_per_call=seconds,
+        )), flush=True)
     kda_kernels.BLOCK_CHUNKS = module_chunks
 
 
