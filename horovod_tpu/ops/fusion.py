@@ -58,6 +58,100 @@ from .quantization import (
 )
 
 
+# On a world of one nothing is exchanged: XLA drops the ``psum`` and the
+# ``/ 1``, the gradient flows straight into the optimizer, and the
+# compiler makes each weight's update the epilogue of the fusion that
+# computes its gradient (p, m, v read and written in float32 per output
+# tile of the dW matmul). For a LARGE weight that fusion runs far over
+# the sum of its halves (PERF.md section 6, PR 52). Above one chip the
+# all-reduce is the seam between the two; on one chip
+# :func:`update_seams` keeps it for the matrices of at least this many
+# elements that :func:`_kept_apart` names, and every other leaf (where
+# fused wins, or where a seam was read and lost) passes as it is.
+UPDATE_SEAM_MIN_SIZE = 2 ** 25
+
+
+def _kept_apart(shape) -> bool:
+    """Whether a gradient of this shape gets a seam: a matrix of at least
+    :data:`UPDATE_SEAM_MIN_SIZE` elements whose sides lie within four of
+    each other, which is an FFN's projection, up or down. A longer leaf
+    is a lookup table or a vocabulary projection (7.4 to 65 to one in
+    the cells here): a table's update stands apart already and the seam
+    only makes its gradient's float32 copy, and three of the four cells
+    read with such seams lost by them (PERF.md section 6, PR 52). A stack
+    of experts (three dimensions) has not been read and keeps its
+    program."""
+    return (
+        len(shape) == 2
+        and shape[0] * shape[1] >= UPDATE_SEAM_MIN_SIZE
+        and max(shape) <= 4 * min(shape)
+    )
+
+
+def _takes_param_along(shape) -> bool:
+    """Whether a seamed leaf's barrier also takes the weight itself: an
+    ``[in, out]`` matrix that widens. One that narrows (and a table
+    ``[rows, width]`` this short) passes its gradient alone.
+
+    This is a tuning to the compiler's plan, not a property of the
+    update (PERF.md section 6, PR 52): a barrier also moves the TPU
+    compiler's pick among its memory schedules for the whole step. With
+    every gradient alone the Olmo-Hybrid step plans 3.7% over the form
+    without seams (every update held to the program's end); with the
+    weight beside the gradient of ``gate``, ``up`` and the head it plans
+    2.5% under it, unless the table's weight rides too. The plan the rule
+    is there for is pinned in ``tests/test_compile_plan.py``."""
+    return shape[0] <= shape[1]
+
+
+def record_update_seams(grads=()):
+    """``fusion.update_seams`` (gradient leaves the trace now made keeps
+    apart from their update) and ``fusion.update_seam_bytes`` (their
+    bytes). Called with nothing by the paths that keep no seam, so the
+    gauges never carry an earlier trace's count."""
+    reg = _obs.always()
+    reg.gauge("fusion.update_seams").set(len(grads))
+    reg.gauge("fusion.update_seam_bytes").set(
+        sum(leaf_nbytes(g) for g in grads)
+    )
+
+
+def update_seams(reduced, params, axis=None):
+    """Keep the seam the exchange gives between a gradient and its update
+    where nothing is exchanged. Returns ``(reduced, params)``.
+
+    On a traced world of one each gradient leaf that :func:`_kept_apart`
+    names (a matrix of at least :data:`UPDATE_SEAM_MIN_SIZE` elements,
+    no longer than four times its width) goes through a
+    ``lax.optimization_barrier`` of its own, so the compiler cannot make
+    the update the epilogue of the fusion that computes the gradient.
+    One barrier a leaf, never one over the tree, which would hold every
+    gradient alive at once. Where :func:`_takes_param_along` says so the
+    weight goes through the same barrier. Numerically the identity. Above
+    one device, and outside a trace, the arguments come back as they are.
+    """
+    axes = _norm_axes(axis)
+    grads, treedef = jax.tree.flatten(reduced)
+    large = [
+        i for i, g in enumerate(grads) if _kept_apart(g.shape)
+    ] if _in_trace(axes) and _traced_size(axes) == 1 else []
+    record_update_seams([grads[i] for i in large])
+    if not large:
+        return reduced, params
+    weights = None if params is None else treedef.flatten_up_to(params)
+    for i in large:
+        if weights is not None and _takes_param_along(grads[i].shape):
+            grads[i], weights[i] = lax.optimization_barrier(
+                (grads[i], weights[i])
+            )
+        else:
+            grads[i] = lax.optimization_barrier(grads[i])
+    return (
+        treedef.unflatten(grads),
+        None if params is None else treedef.unflatten(weights),
+    )
+
+
 def _record_fusion_layout(kind: str, bucket_bytes, n_tensors, threshold):
     """Trace-time metrics for one fused collective: the compiled step
     will move exactly these bytes per call, so the gauges pin per-step

@@ -49,8 +49,10 @@ from .ops.fusion import (
     pack,
     quantized_fused_allreduce,
     quantized_fused_reducescatter,
+    record_update_seams,
     shard_slice,
     unpack,
+    update_seams,
 )
 from .utils import env as _env
 
@@ -421,6 +423,22 @@ def DistributedOptimizer(
     # ``hvd_reduce`` is the gradient exchange, ``hvd_update`` the inner
     # optimizer (dp._step adds ``hvd_grad`` and ``hvd_loss_avg``). The
     # scopes reach the device trace through each instruction's op_name.
+    def reduce_then_update(grads, inner, params):
+        # The exchange, then the seam it gives between a gradient and its
+        # update, kept for large leaves where nothing is exchanged
+        # (ops/fusion.update_seams), then the update.
+        with jax.named_scope("hvd_reduce"):
+            reduced = _reduce_grads(
+                grads, op, compression, prescale_factor, postscale_factor,
+                axis, threshold_bytes, stagger,
+            )
+            if op == Adasum:
+                record_update_seams()
+            else:
+                reduced, params = update_seams(reduced, params, axis=axis)
+        with jax.named_scope("hvd_update"):
+            return optimizer.update(reduced, inner, params)
+
     def update(grads, state: DistributedOptState, params=None):
         if quantized:
             with jax.named_scope("hvd_reduce"):
@@ -436,6 +454,7 @@ def DistributedOptimizer(
                     stagger=stagger,
                 )
             _record_grad_bytes(grads)
+            record_update_seams()
             with jax.named_scope("hvd_update"):
                 updates, inner = optimizer.update(
                     reduced, state.inner, params
@@ -444,15 +463,7 @@ def DistributedOptimizer(
                 inner, None, state.count + 1, new_res
             )
         if bpps == 1:
-            with jax.named_scope("hvd_reduce"):
-                reduced = _reduce_grads(
-                    grads, op, compression, prescale_factor,
-                    postscale_factor, axis, threshold_bytes, stagger,
-                )
-            with jax.named_scope("hvd_update"):
-                updates, inner = optimizer.update(
-                    reduced, state.inner, params
-                )
+            updates, inner = reduce_then_update(grads, state.inner, params)
             return updates, DistributedOptState(inner, None, state.count + 1)
 
         with jax.named_scope("hvd_grad"):
@@ -462,18 +473,12 @@ def DistributedOptimizer(
 
         def sync_branch(operands):
             acc_, inner_ = operands
-            with jax.named_scope("hvd_reduce"):
-                agg = acc_
-                if average_aggregated_gradients:
+            agg = acc_
+            if average_aggregated_gradients:
+                with jax.named_scope("hvd_reduce"):
                     agg = jax.tree.map(lambda g: g / bpps, agg)
-                reduced = _reduce_grads(
-                    agg, op, compression, prescale_factor, postscale_factor,
-                    axis, threshold_bytes, stagger,
-                )
+            updates, new_inner = reduce_then_update(agg, inner_, params)
             with jax.named_scope("hvd_update"):
-                updates, new_inner = optimizer.update(
-                    reduced, inner_, params
-                )
                 zeroed = jax.tree.map(jnp.zeros_like, acc_)
             return updates, new_inner, zeroed
 
@@ -770,6 +775,7 @@ def ShardedDistributedOptimizer(
                 "make_train_step(sharded=True))"
             )
         _record_grad_bytes(grads)
+        record_update_seams()
         new_res = state.residual
         # Scopes as in DistributedOptimizer: the reduce-scatter and the
         # all-gather are both ``hvd_reduce``, the shard update between

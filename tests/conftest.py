@@ -101,3 +101,25 @@ def world_hier():
     )
     yield ctx
     hvd.shutdown()
+
+
+@pytest.fixture(scope="module")
+def v5e_topology():
+    """The described ``v5e:2x2``; the persistent compile cache is off
+    meanwhile (an entry compiled for a described chip cannot be read back
+    without one, and the next compile would warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu / cannot describe the chip here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()

@@ -7,8 +7,9 @@ runs, so these say nothing about results or times — they catch what the
 interpreter cannot (a primitive Mosaic will not legalize, a misaligned
 slice, too much VMEM) before a chip call is spent on it.
 
-One file, one process: the TPU compiler's lock file allows one loader at a
-time, so no fast-tier test may load it from a child process.
+One process: the TPU compiler's lock file allows one loader at a time, so
+no fast-tier test may load it from a child process. (Whole cells compiled
+at their real size, minutes each, are in ``test_compile_plan.py``.)
 """
 
 import os
@@ -23,28 +24,6 @@ from jax.sharding import SingleDeviceSharding
 
 import tools.step_hash as step_hash
 from horovod_tpu.ops import pallas_kernels as pk
-
-
-@pytest.fixture(scope="module")
-def v5e_topology():
-    """The described ``v5e:2x2``; the persistent compile cache is off
-    meanwhile (an entry compiled for a described chip cannot be read back
-    without one, and the next compile would warn)."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # no libtpu / cannot describe the chip here
-        pytest.skip(f"cannot describe a v5e topology: {e}")
-    was_enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo
-    jax.config.update("jax_enable_compilation_cache", was_enabled)
-    compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")
@@ -184,6 +163,64 @@ def test_step_hash_repeats_and_is_blind_to_metadata_only(
     assert [first[k] for k in same] == [unnamed[k] for k in same]
     assert first["op_names"] != unnamed["op_names"]
     assert first["hlo"] != unnamed["hlo"]
+
+
+# -- the seam between a large weight's gradient and its update (PR 52) -------
+# (early in the file for the same reason; ``_described_lm_step`` is below)
+
+
+def _update_fused_into_a_matmul(hlo):
+    """Root shapes of the fused computations that hold both a
+    ``convolution`` (the TPU compiler's matmul) and an instruction of the
+    ``hvd_update`` scope: a weight's update made the epilogue of its
+    dW."""
+    roots = []
+    for body in re.findall(r"(?m)^%fused_computation[^\n]*\{\n(.*?)^\}", hlo,
+                           re.S):
+        if " convolution(" in body and "hvd_update" in body:
+            roots.append(re.search(r"ROOT [^=]*= (.*?) \w[\w-]*\(", body)[1])
+    return roots
+
+
+def test_update_seams_keep_large_updates_out_of_their_dw(
+    v5e_topology, monkeypatch
+):
+    """With the constant patched down to the small model's largest
+    leaves (the tied 2048 x 512 table, the FFN's 512 x 2048 and 2048 x
+    512), the compiled one-chip step fuses none of their updates into the
+    matmul that computes the gradient; with the constant where it is, the
+    compiler does exactly that (what the seam is for). Smaller leaves keep
+    the fused form either way, and the program lowered for four devices,
+    where the exchange stands, does not change by a character."""
+    import horovod_tpu as hvd
+    from horovod_tpu.obs import registry
+    from horovod_tpu.ops import fusion
+
+    large = re.compile(r"f32\[(2048,512|512,2048)\]")
+
+    def lowered(n_devices):
+        try:
+            step, state, batch = _described_lm_step(v5e_topology, n_devices)
+            return step.lower(state, batch)
+        finally:
+            hvd.shutdown()
+
+    fused_plain = _update_fused_into_a_matmul(
+        lowered(1).compile().as_text()
+    )
+    four_plain = lowered(4).as_text()
+    monkeypatch.setattr(fusion, "UPDATE_SEAM_MIN_SIZE", 2 ** 20)
+    fused_seamed = _update_fused_into_a_matmul(
+        lowered(1).compile().as_text()
+    )
+    # the table, and gate and down of four blocks
+    assert registry.always().gauge("fusion.update_seams").get() == 9
+    assert any(large.search(root) for root in fused_plain), fused_plain
+    assert not any(large.search(root) for root in fused_seamed), fused_seamed
+    assert fused_seamed, "small leaves keep their update in the dW fusion"
+    # what the compiler is handed for four devices is the same text
+    assert lowered(4).as_text() == four_plain
+    assert registry.always().gauge("fusion.update_seams").get() == 0
 
 
 @pytest.mark.parametrize(
